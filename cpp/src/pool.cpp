@@ -963,7 +963,7 @@ int fc_pool_step(SearchPool* pool, int group, uint16_t* out_packed,
       if (lk.owns_lock() &&
           pool->prefetch_adaptive.load(std::memory_order_relaxed)) {
         // ROI_PROBE at 512 steps was ~4 minutes of wall clock at the
-        // tunnel's ~2 steps/s — a zeroed budget could not recover
+        // slow link's ~2 steps/s — a zeroed budget could not recover
         // within a bench window. 128 keeps probe overhead negligible
         // (2 slots per 128 steps) while bounding budget-0 stretches to
         // ~1 minute.

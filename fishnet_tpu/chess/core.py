@@ -47,11 +47,15 @@ def _build() -> None:
             text=True,
         )
     except (subprocess.CalledProcessError, OSError) as err:
-        # No toolchain (packaged deployment): fall back to whatever
-        # prebuilt library _candidate_libraries finds — native or a CPU
-        # tier. Only surface the build error when nothing loadable exists.
+        # A packaged deployment ships prebuilt libraries and no sources
+        # (Dockerfile, wheels): fall back to whatever
+        # _candidate_libraries finds there. In a SOURCE checkout a
+        # failed build is an error — an older .so lying next to the
+        # sources (built from other sources, or -march=native on
+        # another machine) must never be what silently runs.
         candidates = [_LIB_PATH, *_CPP_DIR.glob("libfishnetcore-*.so")]
-        if any(p.exists() for p in candidates):
+        source_checkout = (_CPP_DIR / "Makefile").exists()
+        if not source_checkout and any(p.exists() for p in candidates):
             return
         stderr = getattr(err, "stderr", "") or str(err)
         raise NativeCoreError(
@@ -109,7 +113,8 @@ def load() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        _build()
+        if not os.environ.get("FISHNET_TPU_CORE_LIB"):
+            _build()  # an explicit override is loaded as is
         lib = None
         mismatches = []
         for path in _candidate_libraries():

@@ -54,6 +54,13 @@ _RESTARTS = _telemetry.REGISTRY.counter(
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 
+#: Engines whose process evaluates on the JAX device (configure.py).
+_DEVICE_ENGINES = ("tpu-nnue", "az-mcts")
+
+
+class ChipOwnershipError(RuntimeError):
+    """The requested fleet breaks the one-process-per-chip rule."""
+
 
 @dataclass
 class ProcSpec:
@@ -86,6 +93,21 @@ class ProcSpec:
                 f"ProcSpec role must be monolith|frontend|evaluator, "
                 f"got {self.role!r}"
             )
+
+    def owns_device(self) -> bool:
+        """True when this process evaluates on the JAX device itself:
+        the evaluator host, or a monolith whose ``--engine`` (the
+        supervisor's default is mock) is a device engine. A frontend
+        ships its evals over the rings and runs no device program."""
+        if self.role != "monolith":
+            return self.role == "evaluator"
+        engine = "mock"
+        for i, arg in enumerate(self.extra_args):
+            if arg == "--engine" and i + 1 < len(self.extra_args):
+                engine = self.extra_args[i + 1]
+            elif arg.startswith("--engine="):
+                engine = arg.partition("=")[2]
+        return engine in _DEVICE_ENGINES
 
 
 @dataclass
@@ -147,7 +169,34 @@ class FleetSupervisor:
     def _event(self, proc: str, kind: str) -> None:
         self.events.append((round(time.monotonic() - self._t0, 3), proc, kind))
 
+    def _check_chip_ownership(self) -> None:
+        """One process for each chip. A device-owning child claims every
+        chip JAX shows it (``--mesh auto``; libtpu takes the host's
+        chips whole), and a chip belongs to one process at a time: a
+        second owner — or a first one started from a parent that already
+        touched JAX — fails or hangs at backend start-up. Only a fleet
+        held to the CPU (``JAX_PLATFORMS=cpu`` in the environment the
+        children inherit: the tests' venue) may run several."""
+        owners = [s.name for s in self.specs if s.owns_device()]
+        if not owners or os.environ.get("JAX_PLATFORMS") == "cpu":
+            return
+        if len(owners) > 1:
+            raise ChipOwnershipError(
+                f"{len(owners)} device-owning processes ({', '.join(owners)}) "
+                "would each claim this host's accelerator, and a chip "
+                "belongs to one process at a time. Run ONE evaluator "
+                "(role=evaluator) behind role=frontend clients, or hold "
+                "the fleet to the CPU with JAX_PLATFORMS=cpu."
+            )
+        if "jax" in sys.modules:
+            raise ChipOwnershipError(
+                f"this process has imported JAX and may hold the chip "
+                f"that {owners[0]} needs; start device-owning children "
+                "from a parent that never touches JAX."
+            )
+
     async def start(self) -> "FleetSupervisor":
+        self._check_chip_ownership()
         self._t0 = time.monotonic()
         self.workdir.mkdir(parents=True, exist_ok=True)
         for spec in self.specs:
@@ -215,7 +264,11 @@ class FleetSupervisor:
         env["PYTHONPATH"] = (
             f"{_REPO_ROOT}{os.pathsep}{existing}" if existing else str(_REPO_ROOT)
         )
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # A child that evaluates on the device inherits it from the
+        # environment; one that needs no chip (mock engine, frontend)
+        # is held to the CPU so a stray jax import can never claim one.
+        if not spec.owns_device():
+            env["JAX_PLATFORMS"] = "cpu"
         # Chaos lives at the proxy and this supervisor; the child runs
         # a clean, production-shaped client.
         env.pop(PLAN_ENV, None)

@@ -334,7 +334,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Policy+value net checkpoint (.npz) for --engine az-mcts.")
     p.add_argument("--pipeline", type=int, default=None,
                    help="Eval pipeline depth (in-flight device batches). Default: "
-                        "probe the device at startup (serialized tunnels get 1, "
+                        "probe the device at startup (serialized links get 1, "
                         "locally attached TPUs 2-4).")
     p.add_argument("--search-threads", type=int, default=None,
                    help="Scheduler threads driving the search pool (host "

@@ -76,9 +76,21 @@ class AzMctsService:
         # observed per completed search UNDER LOAD — so it already folds
         # in batching/queueing delays, which is what deadline math needs.
         self._visit_rate: Optional[float] = None
+        self._warm_lock = threading.Lock()
+        self._warmed = False
         self._thread = threading.Thread(target=self._drive, daemon=True,
                                         name="az-mcts-driver")
         self._thread.start()
+
+    def warmup(self) -> None:
+        """Compile the evaluator's bucket shapes. Once-only and
+        serialized like SearchService.warmup: the driver thread warms up
+        at start, and the engine factory's prepare() blocks here until
+        that finishes (or re-raises what it raised)."""
+        with self._warm_lock:
+            if not self._warmed:
+                self.pool.warmup()
+                self._warmed = True
 
     async def search(self, root_fen: str, moves: List[str], visits: int,
                      movetime_seconds: Optional[float] = None,
@@ -130,7 +142,7 @@ class AzMctsService:
 
     def _drive(self) -> None:
         try:
-            self.pool.warmup()
+            self.warmup()
             self._drive_inner()
         except Exception as err:  # noqa: BLE001 - driver must not die silently
             with self._lock:
@@ -334,6 +346,11 @@ class AzMctsEngineFactory(EngineFactory):
             return az
         fallback = await self.variant_fallback.create(flavor)
         return _VariantRoutingEngine(az, fallback)
+
+    async def prepare(self) -> None:
+        await asyncio.to_thread(self.service.warmup)
+        if self.variant_fallback is not None:
+            await self.variant_fallback.prepare()
 
     def close(self) -> None:
         self.service.close()

@@ -45,6 +45,13 @@ class EngineFactory(abc.ABC):
     async def create(self, flavor: EngineFlavor) -> Engine:
         ...
 
+    async def prepare(self) -> None:
+        """Bring any shared backend up before the client acquires work:
+        build it and compile what it will run. Called once at start-up;
+        a failure here is a start-up error (the process exits), never a
+        job's. Engines with nothing to warm keep this no-op."""
+        return None
+
     def close(self) -> None:
         """Tear down any shared backend (search service driver threads).
         Called once at client shutdown; a daemon thread left inside

@@ -182,6 +182,26 @@ class TpuNnueEngineFactory(EngineFactory):
             raise EngineError("engine service is not running")
         return TpuNnueEngine(self.service, flavor)
 
+    async def prepare(self) -> None:
+        """Build the first service and compile its eval programs BEFORE
+        any work is acquired: warm-up compiles take seconds to minutes
+        and must not run on an acquired job's clock. A failure is a
+        start-up error naming the eval path — never an EngineError for
+        the worker backoff loop to retry."""
+        if self.service is None:
+            self.service = await asyncio.to_thread(self._builder)
+        try:
+            await asyncio.to_thread(self.service.warm_fused)
+        except Exception as err:
+            # Name what failed: on a TPU this is where a fused kernel
+            # the compiler rejects surfaces, with Mosaic's own message.
+            path = getattr(self.service, "psqt_path", "")
+            raise RuntimeError(
+                f"search service failed to warm up its eval path "
+                f"{path!r} (fused = the ops/ft_gather.py Pallas kernel): "
+                f"{err}"
+            ) from err
+
     def close(self) -> None:
         if self.service is not None:
             self.service.close()

@@ -216,9 +216,28 @@ class ApiStub:
         await self._queue.put(_Message("acquire", future=fut, slow=slow))
         try:
             return await fut
+        except asyncio.CancelledError:
+            self._hand_back(fut)
+            raise
         except Exception:  # noqa: BLE001
             _STUB_ERRORS.inc(endpoint="acquire")
             return None
+
+    def _hand_back(self, fut: "asyncio.Future") -> None:
+        """The caller was cancelled in the loop turn in which the actor
+        fulfilled ``fut`` (the future was still pending, so the actor's
+        callback-dropped path did not fire): nobody will ever see the
+        batch. Close its lifecycle — abandoned + aborted — so the server
+        reassigns it and the ledger stays exactly-once."""
+        if not fut.done() or fut.cancelled() or fut.exception() is not None:
+            return
+        acquired = fut.result()
+        if acquired is None or acquired.body is None:
+            return
+        led = _accounting.get()
+        if led is not None:
+            led.record_abandoned(acquired.body.work.id, "shutdown_cancelled")
+        self.abort(acquired.body.work.id)
 
     def submit_analysis(
         self,
@@ -245,6 +264,9 @@ class ApiStub:
         )
         try:
             return await fut
+        except asyncio.CancelledError:
+            self._hand_back(fut)
+            raise
         except Exception:  # noqa: BLE001
             _STUB_ERRORS.inc(endpoint="submit_move")
             return None

@@ -25,12 +25,15 @@ batch-wide XLA gather over [B, 2, L1] int32 — a full extra HBM pass
 (~2 ms per 16k batch) that this design deletes outright.
 
 The same pass now also produces the [B, 2, 8] PSQT accumulator
-(``ft_psqt`` given): the PSQT columns ride the same decoded index
-stream as 32-byte DMAs next to the 2 KiB feature rows, with the same
-running-anchor discipline and a persistent anchor-PSQT table next to
-the accumulator table — so anchor-code entries resolve ENTIRELY on
-device and the wire no longer needs the host-computed material term
-(doc/wire-format.md).
+(``ft_psqt`` given), with the same running-anchor discipline and a
+persistent anchor-PSQT table next to the accumulator table — so
+anchor-code entries resolve ENTIRELY on device and the wire no longer
+needs the host-computed material term (doc/wire-format.md). The PSQT
+side issues no DMAs: an 8-lane int32 row is not a tile Mosaic can slice
+(its DMA and block shapes must align to 128 lanes), so both PSQT tables
+ride into VMEM whole, re-laid 128 lanes wide (_lane_dense, 0.7 MiB for
+the 22529-row column table), and each row is one dynamic-sublane load
+plus a lane mask.
 
 Used by jax_eval.evaluate_batch on TPU backends; the plain XLA path
 remains the fallback (CPU tests, odd shapes) and the parity test runs
@@ -398,6 +401,21 @@ def _xla_resolve_parents(
 #: NNUE_DELTA_SLOTS).
 _SPARSE_SLOTS = 2 * _DELTA_SLOTS
 
+_LANES = 128
+
+
+def _lane_dense(x: jax.Array) -> jax.Array:
+    """Re-lay a small int32 table 128 lanes wide for VMEM residency:
+    flatten row-major, zero-pad to whole (8, 128) tiles, view as
+    [M, 128]. A [rows, 8] PSQT column table puts row f at sublane
+    f >> 4, lanes [(f & 15) * 8, +8); a [A, 2, 8] anchor-PSQT table puts
+    row a at sublane a >> 3, lanes [(a & 7) * 16, +16). (As [rows, 8]
+    the same data would pad every row to 128 lanes — 11 MiB of VMEM for
+    0.7 MiB of PSQT columns.)"""
+    flat = x.astype(jnp.int32).reshape(-1)
+    flat = jnp.pad(flat, (0, -flat.shape[0] % (8 * _LANES)))
+    return flat.reshape(-1, _LANES)
+
 
 def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
             tab_ref, *rest, delta_base, anchored, with_psqt):
@@ -408,15 +426,18 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
     # drains between positions. Row addresses come from the scalar-
     # prefetched index operand, available before the body runs.
     #
-    # FUSED PSQT (with_psqt): the same index stream also drives a second,
-    # tiny DMA per row — the feature's 8-bucket PSQT column (32 bytes vs
-    # the 2 KiB FT row, so the extra traffic is noise against the row
-    # DMAs it rides with) — and the reduce produces a second [2, 8]
-    # accumulator per position with the SAME anchor discipline (running
-    # in-VMEM anchor, persistent rows from a [A, 2, 8] anchor-PSQT
-    # table). Integer adds commute, so the fused PSQT is bit-identical
-    # to the XLA gather path and to the host-side material walk the wire
-    # used to ship.
+    # FUSED PSQT (with_psqt): the same index stream also selects each
+    # feature's 8-bucket PSQT column out of the VMEM-resident lane-dense
+    # column table (pq_ref, see _lane_dense), and the reduce produces a
+    # second accumulator per position with the SAME anchor discipline
+    # (running in-VMEM anchor, persistent rows from the lane-dense
+    # anchor-PSQT table ptab_ref). A position's PSQT state is ONE
+    # [1, 128] vector in the "canonical" layout: lane l holds
+    # perspective (l >> 3) & 1, bucket l & 7, repeated every 16 lanes —
+    # so a perspective swap is a roll by 8 lanes and the output row's
+    # first 16 lanes are the [2, 8] accumulator. Integer adds commute,
+    # so the fused PSQT is bit-identical to the XLA gather path and to
+    # the host-side material walk the wire used to ship.
     #
     # Per-position flags (scalar-prefetched, so the issuing step for b+1
     # and the waiting step at b+1 always agree): bit 0 = sparse
@@ -433,8 +454,7 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
     # entries back into the table).
     if with_psqt:
         (pq_ref, pcarry_ref, ptab_ref, out_ref, pout_ref, rows, sems,
-         anchor, pa, pa_sems, pq_rows, pq_sems, pq_anchor, pq_pa,
-         pq_pa_sems) = rest
+         anchor, pa, pa_sems, pq_anchor) = rest
     else:
         out_ref, rows, sems, anchor, pa, pa_sems = rest
 
@@ -444,8 +464,7 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
 
     def transfer(pos, slot, start, limit, is_sparse):
         # Each feature row is one native (sub, 128) int16 tile, so
-        # single-row HBM slices stay tile-aligned. The PSQT column rides
-        # the same decoded index (32-byte DMA alongside the 2 KiB row).
+        # single-row HBM slices stay tile-aligned.
         for p in range(2):
             for k in range(limit):
                 idx = idx_ref[pos, p, k]
@@ -456,12 +475,6 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
                     ft_ref.at[idx], rows.at[slot, i], sems.at[slot, i],
                 )
                 dma.start() if start else dma.wait()
-                if with_psqt:
-                    pdma = pltpu.make_async_copy(
-                        pq_ref.at[idx], pq_rows.at[slot, i],
-                        pq_sems.at[slot, i],
-                    )
-                    pdma.start() if start else pdma.wait()
 
     def both_modes(pos, fn):
         # fn(limit, is_sparse); the flag is explicit rather than inferred
@@ -481,10 +494,10 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
             fn(n_active, False)
 
     def anchor_dma(pos, slot, start):
-        # One DMA for the whole [2, sub, 128] anchor row (plus its
-        # [2, 8] PSQT twin when fused); issued/awaited only for
-        # persistent entries (scalar-prefetched flag, so the issuing
-        # step for b+1 and the waiting step at b+1 agree).
+        # One DMA for the whole [2, sub, 128] anchor row; issued/awaited
+        # only for persistent entries (scalar-prefetched flag, so the
+        # issuing step for b+1 and the waiting step at b+1 agree). The
+        # row's PSQT twin is read straight out of VMEM at reduce time.
         if not anchored:
             return
 
@@ -494,12 +507,6 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
                 tab_ref.at[aid_ref[pos]], pa.at[slot], pa_sems.at[slot]
             )
             dma.start() if start else dma.wait()
-            if with_psqt:
-                pdma = pltpu.make_async_copy(
-                    ptab_ref.at[aid_ref[pos]], pq_pa.at[slot],
-                    pq_pa_sems.at[slot],
-                )
-                pdma.start() if start else pdma.wait()
 
     slot = jax.lax.rem(b, 2)
 
@@ -526,6 +533,52 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
 
     bias = bias_ref[...].astype(jnp.int32)
 
+    if with_psqt:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def fold(v, period):
+        # Add up the lane groups of a [1, 128] vector: afterwards every
+        # lane l holds the sum over the lanes congruent to l mod period.
+        shift = _LANES // 2
+        while shift >= period:
+            v = v + pltpu.roll(v, shift, 1)
+            shift //= 2
+        return v
+
+    def tree_sum(terms):
+        # Pairwise, not a serial add chain.
+        while len(terms) > 1:
+            terms = [
+                terms[i] + terms[i + 1] if i + 1 < len(terms) else terms[i]
+                for i in range(0, len(terms), 2)
+            ]
+        return terms[0]
+
+    def pq_sum(limit, is_sparse):
+        # Canonical PSQT vector over the first ``limit`` slots of both
+        # perspectives (sparse: adds minus removals). Feature f's 8
+        # buckets sit in row f >> 4 of the lane-dense column table at
+        # lanes [(f & 15) * 8, +8): one dynamic-sublane load and a lane
+        # mask per slot, then one fold per perspective. Sentinel slots
+        # select the zero row.
+        out = []
+        for p in range(2):
+            terms = []
+            for k in range(limit):
+                f = idx_ref[b, p, k]
+                if is_sparse and k >= _DELTA_SLOTS:
+                    f = f - delta_base  # removal slot: decode
+                row = pq_ref[pl.ds(f >> 4, 1), :]
+                terms.append(jnp.where((lane >> 3) == (f & 15), row, 0))
+            if is_sparse:
+                v = tree_sum(terms[:_DELTA_SLOTS]) - tree_sum(
+                    terms[_DELTA_SLOTS:]
+                )
+            else:
+                v = tree_sum(terms)
+            out.append(fold(v, 8))
+        return jnp.where((lane & 8) == 0, out[0], out[1])
+
     def reduce_full(limit):
         # jnp.sum (tree reduction), not a serial add chain.
         for p in range(2):
@@ -534,17 +587,16 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
                 rows[slot, base : base + limit].astype(jnp.int32), axis=0
             )
             out_ref[0, p] = acc
-            if with_psqt:
-                pq = jnp.sum(pq_rows[slot, base : base + limit], axis=0)
-                pout_ref[0, p] = pq
             if anchored:
                 anchor[p] = acc
-                if with_psqt:
-                    pq_anchor[p] = pq
+        if with_psqt:
+            pq = pq_sum(limit, False)
+            pout_ref[pl.ds(b, 1), :] = pq
+            if anchored:
+                pq_anchor[...] = pq
 
     def reduce_sparse():
         partial = []
-        pq_partial = []
         for p in range(2):
             base = p * n_active
             adds = jnp.sum(
@@ -557,21 +609,13 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
                 axis=0,
             )
             partial.append(adds - rems)
-            if with_psqt:
-                pq_partial.append(
-                    jnp.sum(pq_rows[slot, base : base + _DELTA_SLOTS], axis=0)
-                    - jnp.sum(
-                        pq_rows[
-                            slot, base + _DELTA_SLOTS : base + _SPARSE_SLOTS
-                        ],
-                        axis=0,
-                    )
-                )
+        if with_psqt:
+            pq_partial = pq_sum(_SPARSE_SLOTS, True)
         if not anchored:
             for p in range(2):
                 out_ref[0, p] = bias + partial[p]
-                if with_psqt:
-                    pout_ref[0, p] = pq_partial[p]
+            if with_psqt:
+                pout_ref[pl.ds(b, 1), :] = pq_partial
             return
         # Resolve against the running anchor (the most recent anchor
         # entry), or — persistent entries — the anchor-table row DMA'd
@@ -588,16 +632,17 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
         for p in range(2):
             out_ref[0, p] = res[p]
         if with_psqt:
-            pq_base = [
-                jnp.where(persistent, pq_pa[slot, p], pq_anchor[p])
-                for p in range(2)
-            ]
-            pq_res = [
-                jnp.where(swap, pq_base[1 - p], pq_base[p]) + pq_partial[p]
-                for p in range(2)
-            ]
-            for p in range(2):
-                pout_ref[0, p] = pq_res[p]
+            # Anchor-PSQT row a: 16 values at lanes [(a & 7) * 16, +16)
+            # of table row a >> 3, already in canonical lane order.
+            # Non-persistent entries carry aid 0 and discard the read.
+            a = aid_ref[b]
+            trow = ptab_ref[pl.ds(a >> 3, 1), :]
+            pq_tab = fold(jnp.where((lane >> 4) == (a & 7), trow, 0), 16)
+            pq_base = jnp.where(persistent, pq_tab, pq_anchor[...])
+            pq_res = jnp.where(
+                swap, pltpu.roll(pq_base, 8, 1), pq_base
+            ) + pq_partial
+            pout_ref[pl.ds(b, 1), :] = pq_res
 
         @pl.when(persistent)
         def _():
@@ -605,8 +650,8 @@ def _kernel(idx_ref, flags_ref, aid_ref, ft_ref, bias_ref, carry_ref,
             # in-batch deltas of its block reference it.
             for p in range(2):
                 anchor[p] = res[p]
-                if with_psqt:
-                    pq_anchor[p] = pq_res[p]
+            if with_psqt:
+                pq_anchor[...] = pq_res
 
     if delta_base is None:
         reduce_full(n_active)
@@ -667,23 +712,23 @@ def _pallas_ft_accumulate(
         tab_tiles = jnp.zeros((1, 2, sub, 128), jnp.int32)
     else:
         tab_tiles = anchor_tab.astype(jnp.int32).reshape(-1, 2, sub, 128)
-    n_buckets = 0
-    pq_rows = ptab = None
+    pq_tab = ptab = None
     if with_psqt:
-        n_buckets = ft_psqt.shape[1]
-        pq_rows = ft_psqt.astype(jnp.int32)  # [rows, 8] in HBM
+        # The canonical lane layout (see _kernel) is built for 8 buckets.
+        assert ft_psqt.shape[1] == 8, "PSQT tables must be [rows, 8]"
+        pq_tab = _lane_dense(ft_psqt)
         if psqt_tab is None:
-            ptab = jnp.zeros((1, 2, n_buckets), jnp.int32)
+            ptab = jnp.zeros((8, _LANES), jnp.int32)
         else:
-            ptab = psqt_tab.astype(jnp.int32)
+            ptab = _lane_dense(psqt_tab)
 
     def run_chunk(idx_chunk, flags_chunk, aid_chunk, carry, pcarry):
         chunk = idx_chunk.shape[0]
         in_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),  # ft_w stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # ft_w stays in HBM
             pl.BlockSpec(memory_space=pltpu.VMEM),  # bias
             pl.BlockSpec(memory_space=pltpu.VMEM),  # anchor carry-in
-            pl.BlockSpec(memory_space=pltpu.ANY),  # anchor table (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),  # anchor table (HBM)
         ]
         out_specs = pl.BlockSpec(
             (1, 2, sub, 128),
@@ -701,29 +746,27 @@ def _pallas_ft_accumulate(
                     carry, tab_tiles]
         if with_psqt:
             in_specs += [
-                pl.BlockSpec(memory_space=pltpu.ANY),  # PSQT columns (HBM)
+                pl.BlockSpec(memory_space=pltpu.VMEM),  # PSQT columns
                 pl.BlockSpec(memory_space=pltpu.VMEM),  # PSQT carry-in
-                pl.BlockSpec(memory_space=pltpu.ANY),  # anchor-PSQT table
+                pl.BlockSpec(memory_space=pltpu.VMEM),  # anchor-PSQT table
             ]
+            # One canonical PSQT row per position; the whole chunk's
+            # block stays resident in VMEM and is written back once.
             out_specs = [
                 out_specs,
                 pl.BlockSpec(
-                    (1, 2, n_buckets),
-                    lambda b, idx_ref, flags_ref, aid_ref: (b, 0, 0),
+                    (chunk, _LANES),
+                    lambda b, idx_ref, flags_ref, aid_ref: (0, 0),
                 ),
             ]
             out_shape = [
                 out_shape,
-                jax.ShapeDtypeStruct((chunk, 2, n_buckets), jnp.int32),
+                jax.ShapeDtypeStruct((chunk, _LANES), jnp.int32),
             ]
-            scratch += [
-                pltpu.VMEM((2, 2 * n_active, n_buckets), jnp.int32),
-                pltpu.SemaphoreType.DMA((2, 2 * n_active)),
-                pltpu.VMEM((2, n_buckets), jnp.int32),  # running PSQT anchor
-                pltpu.VMEM((2, 2, n_buckets), jnp.int32),  # persistent rows
-                pltpu.SemaphoreType.DMA((2,)),
-            ]
-            operands += [pq_rows, pcarry, ptab]
+            scratch.append(
+                pltpu.VMEM((1, _LANES), jnp.int32)  # running PSQT anchor
+            )
+            operands += [pq_tab, pcarry, ptab]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # indices + flags + anchor row ids
             grid=(chunk,),
@@ -737,6 +780,7 @@ def _pallas_ft_accumulate(
             out_shape=out_shape,
             grid_spec=grid_spec,
             interpret=interpret,
+            name="ft_gather",
         )(*operands)
 
     idx = indices.astype(jnp.int32)
@@ -748,20 +792,15 @@ def _pallas_ft_accumulate(
         anchor_ids = jnp.zeros((batch,), jnp.int32)
     else:
         anchor_ids = anchor_ids.astype(jnp.int32)
-    carry = jnp.zeros((2, sub, 128), jnp.int32)
-    pcarry = jnp.zeros((2, n_buckets), jnp.int32) if with_psqt else None
-    outs = []
-    pouts = []
-    for start in range(0, batch, _CHUNK):
-        idx_c = idx[start : start + _CHUNK]
-        fl_c = flags[start : start + _CHUNK]
-        aid_c = anchor_ids[start : start + _CHUNK]
+    def step(carries, chunk_args):
+        """One pallas_call over one chunk; threads the anchor carry."""
+        carry, pcarry = carries
+        idx_c, fl_c, aid_c = chunk_args
         out = run_chunk(idx_c, fl_c, aid_c, carry, pcarry)
+        pout = None
         if with_psqt:
             out, pout = out
-            pouts.append(pout)
-        outs.append(out)
-        if anchored and start + _CHUNK < batch:
+        if anchored:
             # Next chunk's carry-in: the accumulator of the last ANCHOR
             # entry so far — full (bit 0 clear) or persistent-resolved
             # (bit 2) — matching the in-kernel running-anchor rule.
@@ -777,15 +816,49 @@ def _pallas_ft_accumulate(
             if with_psqt:
                 pcarry = jnp.where(
                     has_anchor,
-                    jnp.take(pouts[-1], last_anchor, axis=0),
+                    jax.lax.dynamic_slice_in_dim(pout, last_anchor, 1),
                     pcarry,
                 )
+        return (carry, pcarry), (out, pout)
+
+    # Whole chunks ride ONE lax.scan — the kernel is traced, lowered and
+    # compiled once however many chunks the batch spans (an unrolled
+    # Python loop paid the kernel's multi-second trace once per chunk: 8x
+    # for a 4096-entry fused dispatch) — plus at most one ragged tail.
+    carries = (
+        jnp.zeros((2, sub, 128), jnp.int32),
+        jnp.zeros((1, _LANES), jnp.int32) if with_psqt else None,
+    )
+    n_full, tail = divmod(batch, _CHUNK)
+    outs, pouts = [], []
+    if n_full:
+        whole = n_full * _CHUNK
+        chunked = tuple(
+            a[:whole].reshape(n_full, _CHUNK, *a.shape[1:])
+            for a in (idx, flags, anchor_ids)
+        )
+        if n_full == 1:
+            carries, (out, pout) = step(carries, tuple(a[0] for a in chunked))
+        else:
+            carries, (out, pout) = jax.lax.scan(step, carries, chunked)
+            out = out.reshape(whole, *out.shape[2:])
+            if with_psqt:
+                pout = pout.reshape(whole, _LANES)
+        outs.append(out)
+        pouts.append(pout)
+    if tail:
+        _, (out, pout) = step(
+            carries, tuple(a[batch - tail :] for a in (idx, flags, anchor_ids))
+        )
+        outs.append(out)
+        pouts.append(pout)
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
     acc = out.reshape(batch, persp, l1)
     if not with_psqt:
         return acc
     pout = pouts[0] if len(pouts) == 1 else jnp.concatenate(pouts, axis=0)
-    return acc, pout
+    # The first 16 lanes of a canonical row are the [2, 8] accumulator.
+    return acc, pout[:, :16].reshape(batch, 2, 8)
 
 
 def ft_accumulate(

@@ -258,10 +258,7 @@ class ShardedEvaluator:
 
         from fishnet_tpu.nnue.jax_eval import evaluate_batch
 
-        try:
-            from jax import shard_map as _shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map as _shard_map
+        from jax import shard_map as _shard_map
 
         self.mesh = mesh if mesh is not None else make_mesh()
         self.n_devices = self.mesh.devices.size
@@ -412,10 +409,7 @@ class ShardedSegmentedEvaluator:
             evaluate_packed_anchored_segmented,
         )
 
-        try:
-            from jax import shard_map as _shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map as _shard_map
+        from jax import shard_map as _shard_map
 
         if mesh is None:
             devs = devices if devices is not None else jax.devices()

@@ -465,6 +465,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = parser.parse_args(argv)
 
     faults.install_from_env()
+    from fishnet_tpu.utils import compile_cache
+
+    compile_cache.configure()  # before the first jit
     nnue_params = None
     if args.nnue_file:
         import jax

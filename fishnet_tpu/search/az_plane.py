@@ -198,6 +198,9 @@ class AzDispatchPlane(CoalesceBackend):
         import jax
         import jax.numpy as jnp
 
+        from fishnet_tpu.utils import compile_cache
+
+        compile_cache.configure()
         self.cfg = cfg
         self._cap = int(cfg.batch_capacity)
         self._buckets = _bucket_ladder(self._cap)
@@ -683,7 +686,40 @@ class AzDispatchPlane(CoalesceBackend):
             skipped = self._skipped_dispatches
             pad = self._pad_rows
             spec = self._spec_rows
-        return [
+            rows = self._rows_dispatched
+        rungs = [
+            gauge_family(
+                "fishnet_az_shard_ladder_rung",
+                "Per-shard AZ degradation-ladder rung index "
+                "(0=fused, 1=solo, 2=chunk).",
+                rung, labels={"shard": str(shard)},
+            )
+            for shard, rung in enumerate(self._shard_rungs)
+        ]
+        return rungs + [
+            gauge_family(
+                "fishnet_az_plane_info",
+                "Static AZ dispatch-plane configuration (value is "
+                "always 1).",
+                1,
+                labels={
+                    "platform": self._devices[0].platform,
+                    "device_kind": self._devices[0].device_kind,
+                    "shards": str(self._n_shards),
+                },
+            ),
+            counter_family(
+                "fishnet_az_dispatches_total",
+                "Device dispatches issued by the AZ plane (a fused "
+                "dispatch counts once).",
+                self._coalescer.dispatches,
+            ),
+            counter_family(
+                "fishnet_az_rows_dispatched_total",
+                "Leaf positions shipped to the device by the AZ plane "
+                "(padding and speculative rows excluded).",
+                rows,
+            ),
             counter_family(
                 "fishnet_eval_cache_hits_total",
                 "Eval-cache hits by scope.",

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from fishnet_tpu.models.az import AzConfig, az_forward, init_az_params
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train.trainer import _constrain
+from fishnet_tpu.utils import compile_cache
 
 Batch = Dict[str, jax.Array]
 # keys: planes float32 [B,8,8,19]; policy_target float32 [B,4672]
@@ -79,6 +80,7 @@ class AzTrainer:
         self.mesh = mesh
         self.value_weight = value_weight
         self.optimizer = optimizer or optax.adamw(learning_rate, weight_decay=1e-4)
+        compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
 

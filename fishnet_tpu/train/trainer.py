@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import model as model_lib
 from fishnet_tpu.train.model import NNUE2SCORE, NetConfig, Params
+from fishnet_tpu.utils import compile_cache
 
 SIGMOID_SCALE = 410.0  # cp -> expected-score squash
 
@@ -87,6 +88,7 @@ class Trainer:
         self.mesh = mesh
         self.wdl_lambda = wdl_lambda
         self.optimizer = optimizer or optax.adam(learning_rate)
+        compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
 

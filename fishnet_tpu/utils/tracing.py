@@ -9,7 +9,7 @@ Python value and can never observe a traced array's contents.
 This replaces the deprecated ``isinstance(x, jax.core.Tracer)`` pattern
 (flagged by R3): ``jax.core.Tracer`` is slated for removal from the
 public namespace, while ``jax.core.is_concrete`` is the supported
-concreteness predicate on the pinned JAX line (0.4.3x).
+concreteness predicate of the installed JAX (0.9.0).
 """
 
 from __future__ import annotations
@@ -32,16 +32,4 @@ def is_concrete(x) -> bool:
         return True
     import jax
 
-    checker = getattr(jax.core, "is_concrete", None)
-    if checker is not None:
-        try:
-            return bool(checker(x))
-        except TypeError:
-            return True  # not a jax value at all
-    # Fallback for jax versions without is_concrete: tracers refuse
-    # conversion to a host array.
-    try:
-        np.asarray(x)
-    except Exception:
-        return False
-    return True
+    return bool(jax.core.is_concrete(x))

@@ -569,7 +569,7 @@ def test_anchor_placement_off_is_bit_identical(baseline_smoke, monkeypatch):
 
 class _SlowValues:
     """Wraps a dispatched array; materializing costs an extra sleep,
-    standing in for wire transport on a tunneled link."""
+    standing in for wire transport on a slow link."""
 
     def __init__(self, arr, delay):
         self._arr = arr

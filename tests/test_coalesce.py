@@ -243,7 +243,7 @@ def test_fit_dispatch_cost_clamps_noise():
 @pytest.mark.parametrize(
     "fixed,marginal,slots,n_groups,expected",
     [
-        # Tunnel probe, low occupancy: fixed dominates -> fuse wide
+        # High-latency-link probe, low occupancy: fixed dominates -> fuse wide
         # (floored to a power of two).
         (99.0, 18.7, 800, 8, 4),
         (99.0, 18.7, 100, 8, 8),

@@ -227,6 +227,38 @@ async def test_acquired_during_shutdown_abandons_through_ledger():
         accounting.clear()
 
 
+async def test_acquire_cancelled_in_the_turn_it_was_fulfilled_is_handed_back():
+    """The api actor fulfils a still-pending acquire future (so its
+    callback-dropped path does not fire) and the awaiting stream is
+    cancelled before it resumes: the batch must be abandoned + aborted,
+    not left acquired-and-forgotten (soak phase C lost one this way)."""
+    from fishnet_tpu.net import api as api_mod
+    from fishnet_tpu.protocol.types import Acquired
+
+    led = accounting.install()
+    try:
+        queue: "asyncio.Queue" = asyncio.Queue()
+        stub = api_mod.ApiStub(_queue=queue, endpoint="http://unused")
+        task = asyncio.ensure_future(stub.acquire(False))
+        msg = await queue.get()
+        await asyncio.sleep(0)  # the stream now awaits its future
+        led.record_acquired("work_id")  # what _parse_acquired does
+        msg.future.set_result(
+            Acquired.accepted(AcquireResponseBody.from_json(ANALYSIS_ACQUIRE))
+        )
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+        rec = led.record("work_id")
+        assert rec.terminal == "abandoned"
+        assert rec.reason == "shutdown_cancelled"
+        abort = queue.get_nowait()
+        assert (abort.kind, abort.batch_id) == ("abort", "work_id")
+        led.assert_clean()
+    finally:
+        accounting.clear()
+
+
 # ---------------------------------------------------------------------------
 # Requeue cap + deadline flush under concurrent tenants
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ SearchService and prints the traffic ratios the perf work targets
 suspensions per search. CPU JAX makes the absolute nps meaningless,
 but the RATIOS are a pure function of the search + emission logic, so
 this is the fast feedback loop for wire/prefetch changes without the
-device tunnel.
+device link.
 
 Usage: python tools/traffic_probe.py [--nodes 4000] [--batches 4]
 """
@@ -33,7 +33,7 @@ def main() -> None:
                     help="use the material-correlated net (default)")
     ap.add_argument("--random-net", dest="material", action="store_false")
     ap.add_argument("--pin-budget", type=int, default=-1,
-                    help="pin the speculation budget (mirrors the tunnel's "
+                    help="pin the speculation budget (mirrors a slow link's "
                     "operating point, where AIMD settles near 6)")
     args = ap.parse_args()
 
