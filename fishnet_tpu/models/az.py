@@ -90,24 +90,31 @@ def az_forward(params: Params, planes: jax.Array, cfg: AzConfig = AzConfig()):
     Compute runs in bfloat16; logits/value are returned in float32.
     """
     x = planes.astype(jnp.bfloat16)
-    x = jax.nn.relu(_conv2d(x, params["stem_w"], params["stem_b"]))
+    # Scope names are a contract (doc/observability.md "Training and
+    # compilation"): the benchmark's phase metrics join on them.
+    with jax.named_scope("stem"):
+        x = jax.nn.relu(_conv2d(x, params["stem_w"], params["stem_b"]))
     for i in range(cfg.blocks):
-        h = jax.nn.relu(_conv2d(x, params[f"res{i}_w1"], params[f"res{i}_b1"]))
-        h = _conv2d(h, params[f"res{i}_w2"], params[f"res{i}_b2"])
-        x = jax.nn.relu(x + h)
+        with jax.named_scope(f"block{i:02d}"):
+            h = jax.nn.relu(_conv2d(x, params[f"res{i}_w1"], params[f"res{i}_b1"]))
+            h = _conv2d(h, params[f"res{i}_w2"], params[f"res{i}_b2"])
+            x = jax.nn.relu(x + h)
 
-    pol = _conv2d(x, params["policy_w"], params["policy_b"])
-    policy_logits = pol.reshape(pol.shape[0], -1).astype(jnp.float32)
+    with jax.named_scope("policy_head"):
+        pol = _conv2d(x, params["policy_w"], params["policy_b"])
+        policy_logits = pol.reshape(pol.shape[0], -1).astype(jnp.float32)
     # NHWC reshape order = square-major within plane-minor; reorder to the
     # square*73+plane indexing of az_encoding.move_to_index.
     # pol[b, r, f, p] -> index (r*8+f)*73 + p: reshape already yields
     # b, (r*8+f)*planes + p, which is exactly that. (No permute needed.)
 
-    v = jax.nn.relu(_conv2d(x, params["value_w"], params["value_b"]))
-    v = v.reshape(v.shape[0], -1)
-    v = jax.nn.relu(v @ params["value_fc1_w"].astype(v.dtype) + params["value_fc1_b"].astype(v.dtype))
-    v = jnp.tanh(v @ params["value_fc2_w"].astype(v.dtype) + params["value_fc2_b"].astype(v.dtype))
-    return policy_logits, v[:, 0].astype(jnp.float32)
+    with jax.named_scope("value_head"):
+        v = jax.nn.relu(_conv2d(x, params["value_w"], params["value_b"]))
+        v = v.reshape(v.shape[0], -1)
+        v = jax.nn.relu(v @ params["value_fc1_w"].astype(v.dtype) + params["value_fc1_b"].astype(v.dtype))
+        v = jnp.tanh(v @ params["value_fc2_w"].astype(v.dtype) + params["value_fc2_b"].astype(v.dtype))
+        value = v[:, 0].astype(jnp.float32)
+    return policy_logits, value
 
 
 def value_to_centipawns(v: float) -> int:

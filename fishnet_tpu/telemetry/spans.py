@@ -39,11 +39,21 @@ named machinery actually runs):
 * ``drain``       — the process entered graceful drain: stop acquiring,
   flush in-flight, abort the rest upstream (resilience/drain.py;
   fields: reason, deadline_s)
+* ``train_init``  — one ``Trainer.init`` / ``AzTrainer.init``: the
+  init program traced, lowered, compiled or loaded, and run
+  (train/startup.py; fields: trainer, compile_s, cache_load_s,
+  trace_lower_s, cache_misses)
+* ``train_first_step`` — the first ``.step`` of a trainer instance:
+  trace + lower + compile or cache load + dispatch of the step program
+  (train/startup.py; same fields)
 
 Recording is OFF by default: every instrumentation site is gated on
 ``fishnet_tpu.telemetry.enabled()``, so with telemetry disabled the
 device-dispatch critical path pays one attribute read per step and the
-rings stay empty. When enabled, ``record()`` is one ``time.monotonic()``
+rings stay empty. The one exception is the trainers' two start-up
+stages above: two spans per trainer per process, over before anything
+could enable telemetry, recorded always (a step after the first pays
+one attribute test for them). When enabled, ``record()`` is one ``time.monotonic()``
 call plus a slot store into a preallocated per-thread ring — no lock,
 single writer per ring.
 
@@ -86,7 +96,7 @@ STAGES = (
 EVENT_STAGES = (
     "recover", "coalesce", "dispatch_issue", "dispatch_wait",
     "mcts_collect", "queue_wait", "submit", "admit", "cache_probe",
-    "drain", "control",
+    "drain", "control", "train_init", "train_first_step",
 )
 
 #: Span-dump header format. /2 added the additive causal-trace fields

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, az_forward, init_az_params
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from fishnet_tpu.train import startup
 from fishnet_tpu.train.trainer import _constrain
 from fishnet_tpu.utils import compile_cache
 
@@ -83,6 +84,7 @@ class AzTrainer:
         compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
+        self._first_step_pending = True
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -93,14 +95,18 @@ class AzTrainer:
         return AzTrainState(params, opt_state, jnp.zeros((), jnp.int32))
 
     def _loss(self, params, batch: Batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        logits, value = az_forward(params, batch["planes"], self.cfg)
-        target = batch["policy_target"]
-        # Masked cross-entropy: zero-probability targets (illegal moves)
-        # contribute nothing; log-softmax over the full policy space.
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        policy_loss = -jnp.mean(jnp.sum(target * logp, axis=-1))
-        value_loss = jnp.mean((value - batch["value_target"]) ** 2)
-        loss = policy_loss + self.value_weight * value_loss
+        # forward / loss / optimizer: the scope contract both trainers
+        # share (doc/observability.md "Training and compilation").
+        with jax.named_scope("forward"):
+            logits, value = az_forward(params, batch["planes"], self.cfg)
+        with jax.named_scope("loss"):
+            target = batch["policy_target"]
+            # Masked cross-entropy: zero-probability targets (illegal moves)
+            # contribute nothing; log-softmax over the full policy space.
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            policy_loss = -jnp.mean(jnp.sum(target * logp, axis=-1))
+            value_loss = jnp.mean((value - batch["value_target"]) ** 2)
+            loss = policy_loss + self.value_weight * value_loss
         return loss, {
             "loss": loss,
             "policy_loss": policy_loss,
@@ -110,20 +116,29 @@ class AzTrainer:
     def _step(self, state: AzTrainState, batch: Batch):
         batch = _constrain(batch, az_batch_specs(), self.mesh)
         grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch)
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         params = _constrain_params(params, self.mesh)
         return AzTrainState(params, opt_state, state.step + 1), metrics
 
     # -- public api -------------------------------------------------------
 
     def init(self, seed: int = 0) -> AzTrainState:
-        return self._init_jit(jax.random.PRNGKey(seed))
+        with startup.init_span("az"):
+            return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: AzTrainState, batch: Batch):
+        if self._first_step_pending:
+            return self._first_step(state, batch)
         return self._step_jit(state, batch)
+
+    def _first_step(self, state: AzTrainState, batch: Batch):
+        self._first_step_pending = False
+        with startup.first_step_span("az"):
+            return self.step(state, batch)
 
     def export(self, state: AzTrainState, path: str) -> None:
         """Save params as the .npz checkpoint --az-net-file consumes."""

@@ -101,36 +101,43 @@ def forward(
     mask = (indices < cfg.num_features)[..., None].astype(jnp.float32)
     safe = jnp.minimum(indices, cfg.num_features - 1)
 
-    rows = jnp.take(params["ft_w"], safe, axis=0) * mask  # [B, 2, A, L1]
-    acc = params["ft_b"] + jnp.sum(rows, axis=2)  # [B, 2, L1]
-    psqt_rows = jnp.take(params["ft_psqt"], safe, axis=0) * mask
-    psqt = jnp.sum(psqt_rows, axis=2)  # [B, 2, buckets]
+    # Scope names are a contract (doc/observability.md "Training and
+    # compilation"): the benchmark's phase metrics join on them.
+    with jax.named_scope("ft_gather"):
+        rows = jnp.take(params["ft_w"], safe, axis=0) * mask  # [B, 2, A, L1]
+        acc = params["ft_b"] + jnp.sum(rows, axis=2)  # [B, 2, L1]
+    with jax.named_scope("ft_psqt"):
+        psqt_rows = jnp.take(params["ft_psqt"], safe, axis=0) * mask
+        psqt = jnp.sum(psqt_rows, axis=2)  # [B, 2, buckets]
 
-    c = jnp.clip(acc, 0.0, 1.0)
-    pair = c[..., : cfg.l1_half] * c[..., cfg.l1_half :] * (127.0 / 128.0)
-    x = pair.reshape(pair.shape[0], cfg.l1)  # [B, L1], stm half first
+    with jax.named_scope("pairwise"):
+        c = jnp.clip(acc, 0.0, 1.0)
+        pair = c[..., : cfg.l1_half] * c[..., cfg.l1_half :] * (127.0 / 128.0)
+        x = pair.reshape(pair.shape[0], cfg.l1)  # [B, L1], stm half first
 
-    y_all = (
-        jnp.einsum("bi,koi->bko", x, params["l1_w"]) + params["l1_b"][None]
-    )  # [B, buckets, L2+1]
-    y = jnp.take_along_axis(y_all, buckets[:, None, None], axis=1)[:, 0]
+    with jax.named_scope("stacks"):
+        y_all = (
+            jnp.einsum("bi,koi->bko", x, params["l1_w"]) + params["l1_b"][None]
+        )  # [B, buckets, L2+1]
+        y = jnp.take_along_axis(y_all, buckets[:, None, None], axis=1)[:, 0]
 
-    skip = y[:, cfg.l2]
-    h = y[:, : cfg.l2]
-    sq = jnp.minimum(h * h * (127.0 / 128.0), 1.0)
-    ca = jnp.clip(h, 0.0, 1.0)
-    act = jnp.concatenate([sq, ca], axis=1)  # [B, 2*L2]
+        skip = y[:, cfg.l2]
+        h = y[:, : cfg.l2]
+        sq = jnp.minimum(h * h * (127.0 / 128.0), 1.0)
+        ca = jnp.clip(h, 0.0, 1.0)
+        act = jnp.concatenate([sq, ca], axis=1)  # [B, 2*L2]
 
-    z_all = jnp.einsum("bi,koi->bko", act, params["l2_w"]) + params["l2_b"][None]
-    z = jnp.clip(jnp.take_along_axis(z_all, buckets[:, None, None], axis=1)[:, 0], 0.0, 1.0)
+        z_all = jnp.einsum("bi,koi->bko", act, params["l2_w"]) + params["l2_b"][None]
+        z = jnp.clip(jnp.take_along_axis(z_all, buckets[:, None, None], axis=1)[:, 0], 0.0, 1.0)
 
-    v_all = jnp.einsum("bi,koi->bko", z, params["out_w"]) + params["out_b"][None]
-    v = jnp.take_along_axis(v_all, buckets[:, None, None], axis=1)[:, 0, 0]
+        v_all = jnp.einsum("bi,koi->bko", z, params["out_w"]) + params["out_b"][None]
+        v = jnp.take_along_axis(v_all, buckets[:, None, None], axis=1)[:, 0, 0]
 
-    p_sel = jnp.take_along_axis(
-        psqt, jnp.repeat(buckets[:, None, None], 2, axis=1), axis=2
-    )[..., 0]  # [B, 2]
-    material = (p_sel[:, 0] - p_sel[:, 1]) * 0.5
+    with jax.named_scope("material"):
+        p_sel = jnp.take_along_axis(
+            psqt, jnp.repeat(buckets[:, None, None], 2, axis=1), axis=2
+        )[..., 0]  # [B, 2]
+        material = (p_sel[:, 0] - p_sel[:, 1]) * 0.5
     return v + skip + material
 
 

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import model as model_lib
+from fishnet_tpu.train import startup
 from fishnet_tpu.train.model import NNUE2SCORE, NetConfig, Params
 from fishnet_tpu.utils import compile_cache
 
@@ -91,6 +92,7 @@ class Trainer:
         compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
+        self._first_step_pending = True
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -101,14 +103,18 @@ class Trainer:
         return TrainState(params, opt_state, jnp.zeros((), jnp.int32))
 
     def _loss(self, params: Params, batch: Batch) -> Tuple[jax.Array, jax.Array]:
-        pred_cp = (
-            model_lib.forward(params, batch["indices"], batch["buckets"], self.cfg)
-            * NNUE2SCORE
-        )
-        q = jax.nn.sigmoid(pred_cp / SIGMOID_SCALE)
-        t_score = jax.nn.sigmoid(batch["score_cp"] / SIGMOID_SCALE)
-        t = self.wdl_lambda * t_score + (1.0 - self.wdl_lambda) * batch["outcome"]
-        loss = jnp.mean(jnp.square(q - t))
+        # forward / loss / optimizer: the scope contract both trainers
+        # share (doc/observability.md "Training and compilation").
+        with jax.named_scope("forward"):
+            pred_cp = (
+                model_lib.forward(params, batch["indices"], batch["buckets"], self.cfg)
+                * NNUE2SCORE
+            )
+        with jax.named_scope("loss"):
+            q = jax.nn.sigmoid(pred_cp / SIGMOID_SCALE)
+            t_score = jax.nn.sigmoid(batch["score_cp"] / SIGMOID_SCALE)
+            t = self.wdl_lambda * t_score + (1.0 - self.wdl_lambda) * batch["outcome"]
+            loss = jnp.mean(jnp.square(q - t))
         return loss, pred_cp
 
     def _step(self, state: TrainState, batch: Batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
@@ -116,9 +122,10 @@ class Trainer:
         params = _constrain(state.params, param_specs(), self.mesh)
         (loss, pred_cp), grads = jax.value_and_grad(self._loss, has_aux=True)(params, batch)
         grads = _constrain(grads, param_specs(), self.mesh)
-        updates, opt_state = self.optimizer.update(grads, state.opt_state, params)
-        params = optax.apply_updates(params, updates)
-        params = model_lib.clip_params(params)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.optimizer.update(grads, state.opt_state, params)
+            params = optax.apply_updates(params, updates)
+            params = model_lib.clip_params(params)
         params = _constrain(params, param_specs(), self.mesh)
         metrics = {
             "loss": loss,
@@ -130,16 +137,24 @@ class Trainer:
     # -- public API -------------------------------------------------------
 
     def init(self, seed: int = 0) -> TrainState:
-        if self.mesh is not None:
-            with self.mesh:
-                return self._init_jit(jax.random.PRNGKey(seed))
-        return self._init_jit(jax.random.PRNGKey(seed))
+        with startup.init_span("nnue"):
+            if self.mesh is not None:
+                with self.mesh:
+                    return self._init_jit(jax.random.PRNGKey(seed))
+            return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: TrainState, batch: Batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
+        if self._first_step_pending:
+            return self._first_step(state, batch)
         if self.mesh is not None:
             with self.mesh:
                 return self._step_jit(state, batch)
         return self._step_jit(state, batch)
+
+    def _first_step(self, state: TrainState, batch: Batch):
+        self._first_step_pending = False
+        with startup.first_step_span("nnue"):
+            return self.step(state, batch)
 
     def export(self, state: TrainState):
         """Quantize trained params into serving weights."""
