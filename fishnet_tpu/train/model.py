@@ -27,6 +27,7 @@ can use tiny nets; quantization export requires the full spec shapes.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
@@ -53,10 +54,25 @@ class NetConfig:
     l2: int = spec.L2
     l3: int = spec.L3
     num_buckets: int = spec.NUM_PSQT_BUCKETS
+    # The feature table is ``king_buckets`` blocks of equal size, and all
+    # active indices of one (position, perspective) pair lie in one block
+    # (HalfKAv2_hm: index = king_bucket * 704 + plane * 64 + square). A
+    # net fed arbitrary indices says 1: one block spanning the table.
+    king_buckets: int = spec.NUM_KING_BUCKETS
+
+    def __post_init__(self) -> None:
+        if self.num_features % self.king_buckets:
+            raise ValueError(
+                f"num_features {self.num_features} is not {self.king_buckets} equal blocks"
+            )
 
     @property
     def l1_half(self) -> int:
         return self.l1 // 2
+
+    @property
+    def block_rows(self) -> int:
+        return self.num_features // self.king_buckets
 
     def is_full_spec(self) -> bool:
         return (
@@ -91,24 +107,104 @@ def init_params(rng: jax.Array, cfg: NetConfig = NetConfig()) -> Params:
     }
 
 
+# Pairs per tile of the table gradient's grouped matmul (its contraction).
+GRAD_TILE = 256
+
+
+def _pair_blocks(cfg: NetConfig, indices: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Per (position, perspective) pair its block of the table, int32
+    [..., 1], and each slot's row inside that block, int32 [..., A]:
+    -1 for padding and for an active index outside the pair's block."""
+    valid = indices < cfg.num_features
+    block = jnp.where(valid, indices // cfg.block_rows, 0)
+    pair_block = jnp.max(block, axis=-1, keepdims=True)
+    local = jnp.where(valid & (block == pair_block), indices - pair_block * cfg.block_rows, -1)
+    return pair_block, local
+
+
+def ft_block_misses(cfg: NetConfig, indices: jax.Array) -> jax.Array:
+    """Active entries whose index lies outside their pair's block: the
+    table gradient drops them. 0 on every batch that keeps NetConfig's
+    ``king_buckets`` contract (``Board.nnue_features`` does)."""
+    _, local = _pair_blocks(cfg, indices)
+    return jnp.sum((indices < cfg.num_features) & (local < 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def accumulate(cfg: NetConfig, table: jax.Array, indices: jax.Array) -> jax.Array:
+    """Sum of the active rows of ``table`` [num_features, N] per pair:
+    ``indices`` int32 [B, 2, A] -> [B, 2, N]. The gradient with respect
+    to ``table`` is ``_table_grad``, not the transpose of these lines."""
+    mask = (indices < cfg.num_features)[..., None].astype(table.dtype)
+    safe = jnp.minimum(indices, cfg.num_features - 1)
+    rows = jnp.take(table, safe, axis=0) * mask  # [B, 2, A, N]
+    return jnp.sum(rows, axis=2)
+
+
+def _accumulate_fwd(cfg, table, indices):
+    return accumulate(cfg, table, indices), indices
+
+
+def _table_grad(cfg: NetConfig, indices: jax.Array, g: jax.Array):
+    """d table = M^T @ g, M the [pairs, num_features] matrix that counts
+    each pair's active rows. M is zero outside the pair's block, so with
+    the pairs ordered by block it is one [block_rows, tile] @ [tile, N]
+    product per tile of pairs, each tile inside one block (groups are
+    padded to whole tiles), and a sum of the tiles of each block: 1/32 of
+    the dense product's operations for the published feature set, and no
+    scatter. The counts are exact in float32 and ``HIGHEST`` keeps ``g``
+    whole, so the result is the scatter-add's up to the order of sums."""
+    k, r = cfg.king_buckets, cfg.block_rows
+    pair_block, local = _pair_blocks(cfg, indices)
+    local = local.reshape(-1, local.shape[-1])  # [P, A]
+    pair_block = pair_block.reshape(-1)  # [P]
+    g = g.reshape(local.shape[0], -1)  # [P, N]
+    pairs = local.shape[0]
+    tile = min(GRAD_TILE, pairs)
+    tiles = (pairs + k * (tile - 1)) // tile  # the most the padded groups can take
+
+    order = jnp.argsort(pair_block)
+    counts = jnp.sum(pair_block[:, None] == jnp.arange(k), axis=0, dtype=jnp.int32)  # [k]
+    group_tiles = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(group_tiles)
+    # tiles no group needs go to the last block: all their slots are past its count
+    tile_block = jnp.minimum(jnp.searchsorted(tile_end, jnp.arange(tiles), side="right"), k - 1)
+    # rank of each slot among its block's pairs; slots past the count are dead
+    rank = (jnp.arange(tiles) - (tile_end - group_tiles)[tile_block])[:, None] * tile + jnp.arange(tile)
+    live = rank < counts[tile_block][:, None]
+    src = order[jnp.where(live, (jnp.cumsum(counts) - counts)[tile_block][:, None] + rank, 0)]
+
+    rows = jnp.where(live[..., None], local[src], -1)  # [tiles, tile, A]
+    counted = jnp.sum(rows[..., None] == jnp.arange(r), axis=2, dtype=g.dtype)  # [tiles, tile, r]
+    partial = jnp.einsum(
+        "tjr,tjn->trn", counted, g[src], precision=jax.lax.Precision.HIGHEST
+    )  # [tiles, r, N]
+    of_block = (tile_block == jnp.arange(k)[:, None]).astype(g.dtype)  # [k, tiles]
+    grad = jnp.einsum("kt,trn->krn", of_block, partial, precision=jax.lax.Precision.HIGHEST)
+    return grad.reshape(cfg.num_features, -1)
+
+
+def _accumulate_bwd(cfg, indices, g):
+    return _table_grad(cfg, indices, g), None
+
+
+accumulate.defvjp(_accumulate_fwd, _accumulate_bwd)
+
+
 def forward(
     params: Params, indices: jax.Array, buckets: jax.Array, cfg: NetConfig = NetConfig()
 ) -> jax.Array:
     """Float forward. ``indices`` int32 [B, 2, A] (stm perspective first),
-    padded with any value >= cfg.num_features; ``buckets`` int32 [B].
+    padded with any value >= cfg.num_features, each pair's active ones
+    inside one of ``cfg.king_buckets`` blocks; ``buckets`` int32 [B].
     Returns float32 [B] in network-output units (multiply by NNUE2SCORE
     for centipawns)."""
-    mask = (indices < cfg.num_features)[..., None].astype(jnp.float32)
-    safe = jnp.minimum(indices, cfg.num_features - 1)
-
     # Scope names are a contract (doc/observability.md "Training and
     # compilation"): the benchmark's phase metrics join on them.
     with jax.named_scope("ft_gather"):
-        rows = jnp.take(params["ft_w"], safe, axis=0) * mask  # [B, 2, A, L1]
-        acc = params["ft_b"] + jnp.sum(rows, axis=2)  # [B, 2, L1]
+        acc = params["ft_b"] + accumulate(cfg, params["ft_w"], indices)  # [B, 2, L1]
     with jax.named_scope("ft_psqt"):
-        psqt_rows = jnp.take(params["ft_psqt"], safe, axis=0) * mask
-        psqt = jnp.sum(psqt_rows, axis=2)  # [B, 2, buckets]
+        psqt = accumulate(cfg, params["ft_psqt"], indices)  # [B, 2, buckets]
 
     with jax.named_scope("pairwise"):
         c = jnp.clip(acc, 0.0, 1.0)

@@ -75,7 +75,15 @@ def _constrain(tree, specs, mesh: Optional[Mesh]):
 
 
 class Trainer:
-    """Owns optimizer + jitted step. ``mesh=None`` runs single-device."""
+    """Owns optimizer + jitted step. ``mesh=None`` runs single-device.
+
+    Index contract: all active indices of one (position, perspective)
+    pair lie in one of ``cfg.king_buckets`` equal blocks of the feature
+    table (``Board.nnue_features`` output does, for the published 32; a
+    net fed arbitrary indices declares 1). The table gradient is a matmul
+    per block (``model._table_grad``) and drops an entry outside its
+    pair's block; the step's ``ft_block_misses`` metric counts them and
+    reads 0 on every batch that keeps the contract."""
 
     def __init__(
         self,
@@ -131,6 +139,7 @@ class Trainer:
             "loss": loss,
             "pred_cp_mean": jnp.mean(pred_cp),
             "pred_cp_abs": jnp.mean(jnp.abs(pred_cp)),
+            "ft_block_misses": model_lib.ft_block_misses(self.cfg, batch["indices"]),
         }
         return TrainState(params, opt_state, state.step + 1), metrics
 

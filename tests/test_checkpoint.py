@@ -11,7 +11,7 @@ from fishnet_tpu.models.az import AzConfig
 from fishnet_tpu.train import AzTrainer, NetConfig, Trainer
 from fishnet_tpu.train.checkpoint import restore_checkpoint, save_checkpoint
 
-TINY_NNUE = NetConfig(num_features=512, max_active=8, l1=64, l2=15, l3=32)
+TINY_NNUE = NetConfig(num_features=512, max_active=8, l1=64, l2=15, l3=32, king_buckets=1)
 TINY_AZ = AzConfig(channels=16, blocks=2, value_hidden=16)
 
 

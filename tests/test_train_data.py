@@ -46,3 +46,4 @@ async def test_label_and_train():
     batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
     state, metrics = trainer.step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
+    assert int(metrics["ft_block_misses"]) == 0  # the encoder keeps NetConfig's block contract

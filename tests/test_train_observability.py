@@ -25,6 +25,8 @@ B = 4
 TRACE, LOWER, BACKEND, LOAD = compile_cache.PHASE_OF_EVENT
 HIT, MISS = compile_cache.CACHE_OF_EVENT
 
+NNUE = NetConfig(num_features=64, max_active=4, l1=16, l2=4, l3=4, num_buckets=2, king_buckets=4)
+
 MODEL_SCOPES = {
     "nnue": ("ft_gather", "ft_psqt", "pairwise", "stacks", "material"),
     "az": ("stem", "block00", "block01", "policy_head", "value_head"),
@@ -34,10 +36,13 @@ MODEL_SCOPES = {
 def make(kind):
     """A toy trainer of ``kind`` and one batch for it."""
     if kind == "nnue":
-        trainer = Trainer(NetConfig(num_features=64, max_active=4, l1=16, l2=4, l3=4, num_buckets=2))
+        trainer = Trainer(NNUE)
         rng = np.random.default_rng(0)
+        rows = rng.integers(0, NNUE.block_rows + 4, (B, 2, 4))  # past the block: padding
         batch = {
-            "indices": rng.integers(0, 70, (B, 2, 4)).astype(np.int32),  # >= 64 is padding
+            "indices": np.where(
+                rows < NNUE.block_rows, rng.integers(0, 4, (B, 2, 1)) * NNUE.block_rows + rows, 64
+            ).astype(np.int32),
             "buckets": rng.integers(0, 2, (B,)).astype(np.int32),
             "score_cp": np.zeros((B,), np.float32),
             "outcome": np.full((B,), 0.5, np.float32),
@@ -115,6 +120,26 @@ def test_no_heavy_instruction_is_unscoped(scoped):
             named += 1
             assert scopes.phase_of(op_name.group(1))[0] != "unscoped", line[:300]
     assert named >= 3 and nameless <= named // 5
+
+
+def test_table_gradient_is_named_and_holds_no_slot_temporary():
+    """The NNUE table gradient (``model._table_grad``) runs under the
+    scopes of the two tables it serves, in the backward pass, and only
+    the forward pass still holds a [batch, 2, max_active, l1] array."""
+    text = step_text("nnue")
+    names = [m.group(1) for m in map(scopes._OP_NAME.search, text.splitlines()) if m]
+    own = [n for n in names if re.search(r"tjr,tjn->trn|kt,trn->krn|argsort|searchsorted", n)]
+    assert {tag for n in own for tag in re.findall(r"tjr,tjn->trn|kt,trn->krn|argsort", n)} == {
+        "tjr,tjn->trn", "kt,trn->krn", "argsort"}
+    for name in own:
+        assert scopes.phase_of(name)[0] == "backward", name
+        assert re.search(r"/transpose\(jvp\(forward\)\)/(ft_gather|ft_psqt)/", name), name
+    slots = f"[{B},2,{NNUE.max_active},{NNUE.l1}]"
+    holders = [line for line in text.splitlines() if slots in line]
+    assert holders  # the forward's gather output
+    for line in holders:
+        name = scopes._OP_NAME.search(line)
+        assert name and "jvp(forward)/ft_gather" in name.group(1) and "transpose(" not in name.group(1), line[:300]
 
 
 def test_scopes_are_metadata_only(scoped, monkeypatch):
