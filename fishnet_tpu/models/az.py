@@ -21,13 +21,22 @@ TPU shaping choices:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES, POLICY_SIZE
+from fishnet_tpu.models.heads import conv2d as _conv2d
+from fishnet_tpu.models.heads import policy_value_heads
+from fishnet_tpu.models.trunk import (
+    TrunkConfig,
+    init_trunk_params,
+    trunk_checkpoint,
+    trunk_config_from_params,
+    trunk_forward_counted,
+)
 
 Params = Dict[str, jax.Array]
 
@@ -44,7 +53,13 @@ class AzConfig:
         return 64 * self.policy_planes
 
 
-def init_az_params(rng: jax.Array, cfg: AzConfig = AzConfig()) -> Params:
+#: A network behind ``az_forward``: the conv tower or the sparse-expert trunk.
+NetConfig = Union[AzConfig, TrunkConfig]
+
+
+def init_az_params(rng: jax.Array, cfg: NetConfig = AzConfig()) -> Params:
+    if isinstance(cfg, TrunkConfig):
+        return init_trunk_params(rng, cfg)
     c = cfg.channels
     keys = jax.random.split(rng, 4 + 2 * cfg.blocks)
 
@@ -73,22 +88,26 @@ def init_az_params(rng: jax.Array, cfg: AzConfig = AzConfig()) -> Params:
     return params
 
 
-def _conv2d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    out = jax.lax.conv_general_dilated(
-        x,
-        w.astype(x.dtype),
-        window_strides=(1, 1),
-        padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    return out + b.astype(x.dtype)
-
-
-def az_forward(params: Params, planes: jax.Array, cfg: AzConfig = AzConfig()):
+def az_forward(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):
     """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]).
 
-    Compute runs in bfloat16; logits/value are returned in float32.
+    The configuration's type selects the network: an ``AzConfig`` the
+    conv tower below, a ``TrunkConfig`` the sparse-expert trunk
+    (``models/trunk.py``). Compute runs in bfloat16; logits/value are
+    returned in float32.
     """
+    return az_forward_counted(params, planes, cfg)[:2]
+
+
+def az_forward_counted(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):
+    """``az_forward`` and the network's counters for the training step's
+    metrics: none for the tower, the routing counters for the trunk."""
+    if isinstance(cfg, TrunkConfig):
+        return trunk_forward_counted(params, planes, cfg)
+    return (*policy_value_heads(params, _tower(params, planes, cfg)), {})
+
+
+def _tower(params: Params, planes: jax.Array, cfg: AzConfig) -> jax.Array:
     x = planes.astype(jnp.bfloat16)
     # Scope names are a contract (doc/observability.md "Training and
     # compilation"): the benchmark's phase metrics join on them.
@@ -99,22 +118,14 @@ def az_forward(params: Params, planes: jax.Array, cfg: AzConfig = AzConfig()):
             h = jax.nn.relu(_conv2d(x, params[f"res{i}_w1"], params[f"res{i}_b1"]))
             h = _conv2d(h, params[f"res{i}_w2"], params[f"res{i}_b2"])
             x = jax.nn.relu(x + h)
+    return x
 
-    with jax.named_scope("policy_head"):
-        pol = _conv2d(x, params["policy_w"], params["policy_b"])
-        policy_logits = pol.reshape(pol.shape[0], -1).astype(jnp.float32)
-    # NHWC reshape order = square-major within plane-minor; reorder to the
-    # square*73+plane indexing of az_encoding.move_to_index.
-    # pol[b, r, f, p] -> index (r*8+f)*73 + p: reshape already yields
-    # b, (r*8+f)*planes + p, which is exactly that. (No permute needed.)
 
-    with jax.named_scope("value_head"):
-        v = jax.nn.relu(_conv2d(x, params["value_w"], params["value_b"]))
-        v = v.reshape(v.shape[0], -1)
-        v = jax.nn.relu(v @ params["value_fc1_w"].astype(v.dtype) + params["value_fc1_b"].astype(v.dtype))
-        v = jnp.tanh(v @ params["value_fc2_w"].astype(v.dtype) + params["value_fc2_b"].astype(v.dtype))
-        value = v[:, 0].astype(jnp.float32)
-    return policy_logits, value
+def az_checkpoint(params: Params, cfg: NetConfig) -> Dict[str, np.ndarray]:
+    """The arrays of the ``.npz`` that --az-net-file takes."""
+    if isinstance(cfg, TrunkConfig):
+        return trunk_checkpoint(params, cfg)
+    return {k: np.asarray(v) for k, v in params.items()}
 
 
 def value_to_centipawns(v: float) -> int:
@@ -124,14 +135,17 @@ def value_to_centipawns(v: float) -> int:
     return int(round(111.7 * np.tan(1.5620688421 * v)))
 
 
-def az_config_from_params(params: Params) -> AzConfig:
+def az_config_from_params(params: Params) -> NetConfig:
     """Recover the architecture a checkpoint was trained with.
 
     Every AzConfig field is determined by parameter shapes, so `.npz`
     checkpoints need no architecture metadata; loading a net trained with
     a non-default config (--az-net-file) reconstructs the right config
-    instead of crashing shape-mismatched inside the jitted forward.
+    instead of crashing shape-mismatched inside the jitted forward. A
+    trunk checkpoint is told from a tower's by its router.
     """
+    if "router_w" in params:
+        return trunk_config_from_params(params)
     required = ("stem_b", "policy_b", "value_fc1_b")
     missing = [k for k in required if k not in params]
     if missing:

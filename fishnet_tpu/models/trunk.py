@@ -1,0 +1,296 @@
+"""Sparse-expert transformer trunk over the 64 squares of a board.
+
+The second network family behind ``az_forward``: where ``models/az.py``
+runs a convolution tower over the 8x8x19 planes, this runs a
+bidirectional transformer over 64 tokens (one a square, 19 features
+each) whose feed-forward is a routed mixture of experts, and ends in the
+tower's own policy and value heads. The block is the published one of
+LLaDA-MoE-7B-A1B (inclusionAI, config.json: hidden 2048, 16 heads x 128,
+qk-norm, RoPE theta 50000, 64 experts top-8, softmax router, expert
+width 1024, SiLU, RMSNorm eps 1e-5), a mask predictor with no causal
+mask, so running it over a board removes nothing.
+
+Layer equations (``n = RMSNorm(x; g, eps)``, statistics in float32)::
+
+    tokens   t = planes.reshape(B, 64, 19);  x = t @ W_in + b_in
+    attention q, k, v = n1 @ W_q, n1 @ W_k, n1 @ W_v   (heads x head_dim)
+             q, k <- RMSNorm over head_dim (one gain each), then RoPE
+             (theta, rotate-half, all of head_dim) on the square index
+             h = x + concat(softmax(q k^T / sqrt(head_dim)) v) @ W_o   (no mask)
+    router   p = softmax(n2 @ W_r) over the experts, float32; the
+             experts_per_token largest p are the weights w_j, NOT renormalised
+    experts  E_e(u) = (silu(u @ W_g[e]) * (u @ W_u[e])) @ W_d[e]
+             x' = h + sum_j w_j E_{e_j}(n2)        (dropless: no capacity)
+    out      RMSNorm(x'; g_f) -> [B, 8, 8, hidden] -> the heads of models/az.py
+
+Mechanism: the (token, slot) pairs are sorted by expert (stable), each
+expert's rows form one group of a grouped matrix product (megablox
+``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
+the CPU), the rows are put back in token order and each token's slots
+summed under their weights. Matrix products run in
+bfloat16 with float32 accumulation over float32 parameters, as the
+tower's; norms, both softmaxes, the router and the combine weights are
+float32.
+
+Parameters are one flat dict (the ``.npz`` checkpoint format), the
+layers stacked on a leading axis: ``router_w [L, hidden, experts]``.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+from fishnet_tpu.models.az_encoding import INPUT_PLANES
+from fishnet_tpu.models.heads import policy_value_heads
+
+Params = Dict[str, jax.Array]
+
+SQUARES = 64
+_INIT_STD = 0.02
+
+
+@dataclass(frozen=True)
+class TrunkConfig:
+    hidden: int = 2048
+    heads: int = 16
+    head_dim: int = 128
+    layers: int = 1
+    experts: int = 64
+    experts_per_token: int = 8
+    expert_width: int = 1024
+    rope_theta: float = 50000.0
+    rms_eps: float = 1e-5
+    value_hidden: int = 256
+    policy_planes: int = 73
+
+
+def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every tensor of a trunk checkpoint by name."""
+    n, h, e, w = cfg.layers, cfg.hidden, cfg.experts, cfg.expert_width
+    inner = cfg.heads * cfg.head_dim
+    return {
+        "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
+        "attn_norm": (n, h), "wq": (n, h, inner), "wk": (n, h, inner), "wv": (n, h, inner),
+        "q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim), "wo": (n, inner, h),
+        "moe_norm": (n, h), "router_w": (n, h, e),
+        "experts_gate": (n, e, h, w), "experts_up": (n, e, h, w), "experts_down": (n, e, w, h),
+        "final_norm": (h,),
+        "policy_w": (1, 1, h, cfg.policy_planes), "policy_b": (cfg.policy_planes,),
+        "value_w": (1, 1, h, 4), "value_b": (4,),
+        "value_fc1_w": (4 * SQUARES, cfg.value_hidden), "value_fc1_b": (cfg.value_hidden,),
+        "value_fc2_w": (cfg.value_hidden, 1), "value_fc2_b": (1,),
+    }
+
+
+def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Params:
+    """Normal(0, 0.02) matrices, unit norm gains, zero biases; the value
+    head's last layer starts at zero, as the tower's."""
+    shapes = trunk_param_shapes(cfg)
+    keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
+    params: Params = {}
+    for name, shape in shapes.items():
+        if name.endswith("_norm"):
+            params[name] = jnp.ones(shape, jnp.float32)
+        elif name.endswith("_b") or name == "value_fc2_w":
+            params[name] = jnp.zeros(shape, jnp.float32)
+        else:
+            params[name] = jax.random.normal(keys[name], shape, jnp.float32) * _INIT_STD
+    return params
+
+
+def _rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def _matmul(x: jax.Array, w: jax.Array) -> jax.Array:
+    """bfloat16 operands, float32 accumulation and result."""
+    return jnp.dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+
+
+def _rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half RoPE over all of the last axis; position = square index.
+    ``x`` is [B, 64, heads, head_dim], float32."""
+    half = x.shape[-1] // 2
+    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+    angle = np.arange(SQUARES, dtype=np.float64)[:, None] * inv_freq[None, :]
+    cos = jnp.asarray(np.concatenate([np.cos(angle)] * 2, axis=-1), jnp.float32)[None, :, None, :]
+    sin = jnp.asarray(np.concatenate([np.sin(angle)] * 2, axis=-1), jnp.float32)[None, :, None, :]
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + rotated * sin
+
+
+def _attention(x: jax.Array, p: Params, cfg: TrunkConfig) -> jax.Array:
+    """[B, 64, hidden] float32 -> the attention branch's output, same shape."""
+    b = x.shape[0]
+    n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    split = lambda y: y.reshape(b, SQUARES, cfg.heads, cfg.head_dim)
+    q, k, v = (split(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
+    q = _rope(_rms_norm(q, p["q_norm"], cfg.rms_eps), cfg.rope_theta)
+    k = _rope(_rms_norm(k, p["k_norm"], cfg.rms_eps), cfg.rope_theta)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32) / np.sqrt(cfg.head_dim)
+    probs = jax.nn.softmax(scores, axis=-1)
+    mixed = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    return _matmul(mixed.reshape(b, SQUARES, cfg.heads * cfg.head_dim), p["wo"])
+
+
+@jax.custom_vjp
+def _take_rows(x: jax.Array, index: jax.Array, back: jax.Array) -> jax.Array:
+    """``x[index]`` where ``index`` takes every row of ``x`` equally often
+    and ``back`` lists, row after row of ``x``, where its copies went.
+    Dispatch (each token to its k sorted slots) and its undoing (a
+    permutation) are both this, so the gradient is a gather by ``back``
+    and a sum over each row's copies, never a scatter-add (24.8 ms against
+    8.9 at the published sizes, PERF.md section 5)."""
+    return x[index]
+
+
+def _take_rows_fwd(x, index, back):
+    return x[index], (back, x.shape[0])
+
+
+def _take_rows_bwd(res, g):
+    back, n = res
+    return g[back].reshape(n, -1, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+#: Largest tile of the grouped product (rows, contraction, columns): the
+#: fastest of those tried on a v5e at [262144, 2048] x [64, 2048, 1024]
+#: (PERF.md section 5); the next size up does not fit the kernel's VMEM.
+_TILE = (512, 1024, 1024)
+
+
+def _interpret() -> bool:
+    """The grouped product is one Pallas kernel everywhere: compiled by
+    Mosaic on a TPU, run by the Pallas interpreter elsewhere (the CPU of
+    the tests), never another path."""
+    return jax.default_backend() != "tpu"
+
+
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``rows[i] @ weights[g(i)]`` where the rows come in runs of
+    ``group_sizes`` (int32; their sum is the number of rows; a size may
+    be 0). bfloat16 operands and result, float32 accumulation: megablox
+    ``gmm``, whose gradients are ``gmm`` on the transposed weights and
+    ``tgmm`` (one product a group, summed over the group's rows). The
+    number of rows has to be a multiple of 8; it is 64 x experts_per_token
+    x positions here."""
+    m, k = rows.shape
+    tiling = (math.gcd(m, _TILE[0]), min(k, _TILE[1]), min(weights.shape[2], _TILE[2]))
+    return megablox.gmm(rows.astype(jnp.bfloat16), weights.astype(jnp.bfloat16), group_sizes,
+                        jnp.bfloat16, tiling, None, None, False, _interpret())
+
+
+def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """[N, hidden] float32 normed tokens -> the routed experts' weighted
+    sum [N, hidden] float32, and the layer's routing counters."""
+    n, k = n2.shape[0], cfg.experts_per_token
+    with jax.named_scope(f"{layer}.router"):
+        logits = jnp.dot(n2, p["router_w"], precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weight, expert = jax.lax.top_k(probs, k)  # [N, k] each; weights not renormalised
+    with jax.named_scope(f"{layer}.dispatch"):
+        slot_expert = expert.reshape(n * k)
+        order = jnp.argsort(slot_expert, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(slot_expert[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
+        rows = _take_rows(n2.astype(jnp.bfloat16), order // k, inverse)
+    with jax.named_scope(f"{layer}.experts"):
+        gate = grouped_matmul(rows, p["experts_gate"], group_sizes)
+        up = grouped_matmul(rows, p["experts_up"], group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up, p["experts_down"], group_sizes)
+    with jax.named_scope(f"{layer}.combine"):
+        per_slot = _take_rows(out, inverse, order).reshape(n, k, cfg.hidden)
+        mixed = jnp.einsum("nk,nkh->nh", weight, per_slot.astype(jnp.float32))
+    load = group_sizes.astype(jnp.float32)
+    entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
+    return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy}
+
+
+def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
+    """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]), float32."""
+    return trunk_forward_counted(params, planes, cfg)[:2]
+
+
+def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
+    """``trunk_forward`` and the routing counters of the step's metrics:
+    the most and the fewest slots any expert of any layer received
+    (``expert_load_max``, ``expert_load_min``) and the router's mean
+    entropy in nats (``router_entropy``)."""
+    b = planes.shape[0]
+    # Scope names are a contract (doc/observability.md "Training and compilation").
+    with jax.named_scope("embed"):
+        x = _matmul(planes.reshape(b, SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
+    counters = []
+    for i in range(cfg.layers):
+        layer = {name: params[name][i] for name in
+                 ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "moe_norm", "router_w",
+                  "experts_gate", "experts_up", "experts_down")}
+        # One scope a part, the layer in its name: the benchmark's scope
+        # table keeps two levels of a path (phase, then this).
+        name = f"layer{i:02d}"
+        with jax.named_scope(f"{name}.attention"):
+            x = x + _attention(x, layer, cfg)
+        with jax.named_scope(f"{name}.router"):
+            n2 = _rms_norm(x, layer["moe_norm"], cfg.rms_eps).reshape(b * SQUARES, cfg.hidden)
+        mixed, layer_counters = _experts(n2, layer, cfg, name)
+        with jax.named_scope(f"{name}.combine"):
+            x = x + mixed.reshape(b, SQUARES, cfg.hidden)
+        counters.append(layer_counters)
+    with jax.named_scope("final_norm"):
+        x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
+    features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
+    return (*policy_value_heads(params, features), {
+        "expert_load_max": jnp.max(jnp.stack([c["expert_load_max"] for c in counters])),
+        "expert_load_min": jnp.min(jnp.stack([c["expert_load_min"] for c in counters])),
+        "router_entropy": jnp.mean(jnp.stack([c["router_entropy"] for c in counters])),
+    })
+
+
+#: What a trunk checkpoint carries beside its tensors: the three fields
+#: of ``TrunkConfig`` that no shape determines, as float64[3].
+HPARAMS = "trunk_hparams"
+
+
+def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
+    """The arrays of a trunk ``.npz``: the tensors and ``trunk_hparams`` =
+    (experts_per_token, rope_theta, rms_eps)."""
+    arrays = {k: np.asarray(v) for k, v in params.items()}
+    arrays[HPARAMS] = np.asarray([cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps], np.float64)
+    return arrays
+
+
+def trunk_config_from_params(params: Params) -> TrunkConfig:
+    """The ``TrunkConfig`` of a checkpoint, from its shapes and its
+    ``trunk_hparams``; a ValueError names what does not fit."""
+    required = ("router_w", "experts_gate", "q_norm", "value_fc1_b", "policy_b", HPARAMS)
+    missing = [k for k in required if k not in params]
+    if missing:
+        raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
+    layers, hidden, experts = np.shape(params["router_w"])
+    head_dim = int(np.shape(params["q_norm"])[1])
+    top_k, theta, eps = (float(v) for v in np.asarray(params[HPARAMS]).reshape(3))
+    cfg = TrunkConfig(
+        hidden=int(hidden), heads=int(np.shape(params["wq"])[2]) // head_dim, head_dim=head_dim, layers=int(layers),
+        experts=int(experts), experts_per_token=int(round(top_k)), expert_width=int(np.shape(params["experts_gate"])[3]),
+        rope_theta=theta, rms_eps=eps,
+        value_hidden=int(np.shape(params["value_fc1_b"])[0]), policy_planes=int(np.shape(params["policy_b"])[0]),
+    )
+    expected = {**trunk_param_shapes(cfg), HPARAMS: (3,)}
+    got = {k: tuple(np.shape(v)) for k, v in params.items()}
+    if expected != got:
+        diff = set(expected) ^ set(got) or {k for k in expected if expected[k] != got[k]}
+        raise ValueError(f"trunk checkpoint does not match any {cfg}: mismatched keys {sorted(diff)}")
+    return cfg

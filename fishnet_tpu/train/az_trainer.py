@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fishnet_tpu.models.az import AzConfig, az_forward, init_az_params
+from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_params
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup
 from fishnet_tpu.train.trainer import _constrain
@@ -47,7 +47,8 @@ class AzTrainState(NamedTuple):
 
 def az_param_spec(name: str, value: jax.Array) -> P:
     """Shard conv kernels' output-channel dim over ``model``; replicate
-    biases and the small heads."""
+    biases, the small heads and every tensor of the sparse-expert trunk
+    (its experts go over chips with ROADMAP R7)."""
     if name.endswith(("_w1", "_w2")) or name == "stem_w":
         return P(None, None, None, MODEL_AXIS)
     return P()
@@ -71,7 +72,7 @@ class AzTrainer:
 
     def __init__(
         self,
-        cfg: AzConfig = AzConfig(),
+        cfg: NetConfig = AzConfig(),
         mesh: Optional[Mesh] = None,
         learning_rate: float = 2e-3,
         value_weight: float = 1.0,
@@ -98,7 +99,7 @@ class AzTrainer:
         # forward / loss / optimizer: the scope contract both trainers
         # share (doc/observability.md "Training and compilation").
         with jax.named_scope("forward"):
-            logits, value = az_forward(params, batch["planes"], self.cfg)
+            logits, value, counters = az_forward_counted(params, batch["planes"], self.cfg)
         with jax.named_scope("loss"):
             target = batch["policy_target"]
             # Masked cross-entropy: zero-probability targets (illegal moves)
@@ -111,6 +112,7 @@ class AzTrainer:
             "loss": loss,
             "policy_loss": policy_loss,
             "value_loss": value_loss,
+            **counters,  # the trunk's expert_load_max, expert_load_min, router_entropy
         }
 
     def _step(self, state: AzTrainState, batch: Batch):
@@ -144,4 +146,4 @@ class AzTrainer:
         """Save params as the .npz checkpoint --az-net-file consumes."""
         import numpy as np
 
-        np.savez(path, **{k: np.asarray(v) for k, v in state.params.items()})
+        np.savez(path, **az_checkpoint(state.params, self.cfg))

@@ -10,6 +10,7 @@ import pytest
 
 from fishnet_tpu.chess.board import Board
 from fishnet_tpu.models.az import AzConfig, az_forward, init_az_params, value_to_centipawns
+from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.models.az_encoding import (
     INPUT_PLANES,
     POLICY_SIZE,
@@ -20,6 +21,11 @@ from fishnet_tpu.search.mcts import MctsConfig, MctsPool
 
 STARTPOS = "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1"
 TINY = AzConfig(channels=16, blocks=2, value_hidden=16)
+# The second network behind az_forward (models/trunk.py), at a size the
+# CPU searches in seconds: same planes in, same heads out.
+TINY_TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=1, experts=4,
+                         experts_per_token=2, expert_width=16, value_hidden=16)
+NETS = {"tower": TINY, "trunk": TINY_TRUNK}
 
 
 # -- move encoding ---------------------------------------------------------
@@ -105,10 +111,11 @@ def test_value_to_centipawns_monotone():
 # -- MCTS ------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def pool():
-    params = init_az_params(jax.random.PRNGKey(1), TINY)
-    return MctsPool(params, MctsConfig(batch_capacity=64, az=TINY))
+@pytest.fixture(scope="module", params=sorted(NETS))
+def pool(request):
+    net = NETS[request.param]
+    params = init_az_params(jax.random.PRNGKey(1), net)
+    return MctsPool(params, MctsConfig(batch_capacity=64, az=net))
 
 
 def run_pool(pool, sids):

@@ -8,10 +8,14 @@ import jax
 import jax.numpy as jnp
 
 from fishnet_tpu.models.az import AzConfig
+from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.models.az_encoding import INPUT_PLANES, POLICY_SIZE
 from fishnet_tpu.train import AzTrainer
 
 TINY = AzConfig(channels=16, blocks=2, value_hidden=16)
+# The sparse-expert trunk through the same trainer (models/trunk.py).
+TINY_TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=1, experts=4,
+                         experts_per_token=2, expert_width=16, value_hidden=16)
 
 
 def make_batch(rng, batch):
@@ -44,7 +48,8 @@ def test_az_training_overfits_small_batch():
     assert int(state.step) == 30
 
 
-def test_az_training_sharded_mesh():
+@pytest.mark.parametrize("net", ["tower", "trunk"])
+def test_az_training_sharded_mesh(net):
     from fishnet_tpu.parallel.mesh import make_mesh
 
     devices = jax.devices()
@@ -52,7 +57,7 @@ def test_az_training_sharded_mesh():
         pytest.skip("needs 8 virtual devices")
     mesh = make_mesh(devices[:8])
     data, model = mesh.devices.shape
-    cfg = AzConfig(channels=8 * model, blocks=2, value_hidden=16)
+    cfg = AzConfig(channels=8 * model, blocks=2, value_hidden=16) if net == "tower" else TINY_TRUNK
     trainer = AzTrainer(cfg=cfg, mesh=mesh)
     state = trainer.init(seed=1)
     batch = make_batch(np.random.default_rng(1), 8 * data)
@@ -61,20 +66,26 @@ def test_az_training_sharded_mesh():
     assert int(state.step) == 1
 
 
-def test_az_export_roundtrip_into_engine(tmp_path):
-    trainer = AzTrainer(cfg=TINY)
+@pytest.mark.parametrize("net", [TINY, TINY_TRUNK], ids=["tower", "trunk"])
+def test_az_export_roundtrip_into_engine(tmp_path, net):
+    from fishnet_tpu.models.az import az_config_from_params
+
+    trainer = AzTrainer(cfg=net)
     state = trainer.init(seed=2)
     path = tmp_path / "az.npz"
     trainer.export(state, str(path))
 
+    # As --az-net-file loads it (__main__.py): the arrays of the file and
+    # the architecture recovered from them.
     loaded = np.load(path)
     params = {k: jnp.asarray(loaded[k]) for k in loaded.files}
-    assert set(params) == set(state.params)
+    assert set(state.params) <= set(params) <= set(state.params) | {"trunk_hparams"}
+    assert az_config_from_params({k: loaded[k] for k in loaded.files}) == net
 
     # The exported checkpoint must drive the MCTS pool directly.
     from fishnet_tpu.search.mcts import MctsConfig, MctsPool
 
-    pool = MctsPool(params, MctsConfig(batch_capacity=64, az=TINY))
+    pool = MctsPool(params, MctsConfig(batch_capacity=64, az=net))
     sid = pool.submit(
         "6k1/5ppp/8/8/8/8/5PPP/3R2K1 w - - 0 1", [], visits=200
     )

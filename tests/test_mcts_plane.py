@@ -12,6 +12,7 @@ import jax
 from fishnet_tpu import telemetry
 from fishnet_tpu.chess.board import Board
 from fishnet_tpu.models.az import AzConfig, init_az_params
+from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.models.az_encoding import POLICY_SIZE
 from fishnet_tpu.search import eval_cache
 from fishnet_tpu.search.mcts import MctsConfig, MctsPool
@@ -20,6 +21,9 @@ from fishnet_tpu.telemetry.spans import RECORDER
 
 STARTPOS = "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1"
 TINY = AzConfig(channels=16, blocks=2, value_hidden=16)
+# The sparse-expert trunk behind the same az_forward (models/trunk.py).
+TINY_TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=1, experts=4,
+                         experts_per_token=2, expert_width=16, value_hidden=16)
 
 OPENINGS = [
     [], ["e2e4"], ["d2d4"], ["g1f3"],
@@ -74,9 +78,9 @@ def _run_workload(pool, visits=80, trees=8):
 # -- parity: legacy vs plane, every rung, escape hatch ----------------------
 
 
-def _parity_run(params, monkeypatch, force_rung=None, legacy=False):
+def _parity_run(params, monkeypatch, force_rung=None, legacy=False, net=TINY):
     eval_cache.reset_cache()
-    cfg = MctsConfig(batch_capacity=64, az=TINY)
+    cfg = MctsConfig(batch_capacity=64, az=net)
     plane = None
     if legacy:
         monkeypatch.setenv("FISHNET_NO_SHARED_AZ_PLANE", "1")
@@ -95,14 +99,20 @@ def _parity_run(params, monkeypatch, force_rung=None, legacy=False):
             plane.close()
 
 
-def test_plane_parity_all_rungs_and_hatch(params, monkeypatch):
+@pytest.mark.parametrize("net", [TINY, TINY_TRUNK], ids=["tower", "trunk"])
+def test_plane_parity_all_rungs_and_hatch(params, monkeypatch, net):
     """The escape hatch restores the legacy path, and the shared plane
     matches it bit-for-bit on every forced degradation rung — with the
-    AZ eval cache live (pre-wire hits interleave with dispatches)."""
-    legacy = _parity_run(params, monkeypatch, legacy=True)
+    AZ eval cache live (pre-wire hits interleave with dispatches). The
+    same holds for the trunk: MctsPool -> AzDispatchPlane -> az_forward
+    with no edit to either."""
+    if net is not TINY:
+        params = init_az_params(jax.random.PRNGKey(3), net)
+    legacy = _parity_run(params, monkeypatch, legacy=True, net=net)
     assert any(r[1] > 0 for r in legacy)
+    assert all(r[0] for r in legacy)  # a best move from every tree
     for rung in (None, 0, 1, 2):  # default ladder + each forced rung
-        assert _parity_run(params, monkeypatch, force_rung=rung) == legacy
+        assert _parity_run(params, monkeypatch, force_rung=rung, net=net) == legacy
 
 
 def test_az_prewire_warm_replay(params, monkeypatch):

@@ -1,0 +1,283 @@
+"""The sparse-expert square-token trunk (models/trunk.py) at a tiny size
+on the CPU: hidden 64, 4 heads x 16, 8 experts top-2, width 32, 2
+layers, batch 8. The grouped product runs as the same Pallas kernel as
+on the chip, in interpret mode.
+
+The plain reference below is written from the layer equations in
+float32 with every expert applied to every token; the program rounds the
+operands of its matrix products to bfloat16, so the two differ by the
+rounding of 8-bit mantissas carried through two layers (~1% of the
+logits' norm, a few % of a gradient tensor's; the readings are beside
+each tolerance), and a rounding that swaps a token's second and third
+expert moves that token's output by more than any rounding of a product
+does (8 positions hold few tokens to average that out). Each tolerance
+is shown tight enough by three wrong references (no combine weights,
+renormalised top-k, causal attention), which have to miss it by more
+than 1.5x.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fishnet_tpu.models import trunk
+from fishnet_tpu.models.az import az_checkpoint, az_config_from_params, az_forward, init_az_params
+from fishnet_tpu.models.trunk import TrunkConfig, trunk_forward
+from fishnet_tpu.train.az_trainer import AzTrainer
+
+TINY = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=2, experts=8, experts_per_token=2,
+                   expert_width=32, value_hidden=32)
+BATCH = 8
+
+
+def conditioned_params(seed: int, cfg: TrunkConfig = TINY):
+    """Matrices normal(0, 0.9^2 / fan_in), gains and biases off their
+    special points, a peaked router (logits spread ~3), so that a
+    bfloat16 rounding that swaps a token's second and third expert swaps
+    two small weights (benchmark/reference/moe_trunk.py says the same)."""
+    rng = np.random.default_rng(seed)
+    params = {}
+    for name, shape in trunk.trunk_param_shapes(cfg).items():
+        if name.endswith("_norm"):
+            value = 1.0 + 0.1 * rng.standard_normal(shape)
+        elif name.endswith("_b"):
+            value = 0.05 * rng.standard_normal(shape) + (0.5 if name == "value_fc2_b" else 0.0)
+        else:
+            fan_in = shape[-2]
+            value = rng.standard_normal(shape) * (3.0 if name == "router_w" else 0.9) / np.sqrt(fan_in)
+        params[name] = jnp.asarray(value, jnp.float32)
+    return params
+
+
+def batch_of(seed: int, n: int = BATCH):
+    rng = np.random.default_rng(seed)
+    planes = (rng.random((n, 8, 8, 19)) < 0.15).astype(np.float32)
+    planes[..., 17] = rng.random((n, 1, 1)) * 0.5  # the halfmove plane is a fraction
+    target = rng.gamma(0.3, size=(n, 4672)) * (rng.random((n, 4672)) < 0.01)
+    target[:, 0] += 1e-3
+    return {"planes": jnp.asarray(planes), "policy_target": jnp.asarray(target / target.sum(1, keepdims=True), jnp.float32),
+            "value_target": jnp.asarray(rng.uniform(-1, 1, n), jnp.float32)}
+
+
+def _norm(x, gain, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def _rope(x, theta):
+    half = x.shape[-1] // 2
+    angle = np.arange(64)[:, None] / theta ** (np.arange(half) / half)[None, :]
+    cos, sin = (jnp.asarray(np.concatenate([f(angle)] * 2, -1), jnp.float32)[None, :, None, :] for f in (np.cos, np.sin))
+    return x * cos + jnp.concatenate([-x[..., half:], x[..., :half]], -1) * sin
+
+
+def reference_forward(p, planes, cfg, wrong=""):
+    """The layer equations, float32, dense over the experts. ``wrong``
+    leaves one piece of the mathematics out, for the tolerance's test."""
+    b = planes.shape[0]
+    x = planes.reshape(b, 64, 19) @ p["embed_w"] + p["embed_b"]
+    for i in range(cfg.layers):
+        n1 = _norm(x, p["attn_norm"][i], cfg.rms_eps)
+        q, k, v = ((n1 @ p[w][i]).reshape(b, 64, cfg.heads, cfg.head_dim) for w in ("wq", "wk", "wv"))
+        q = _rope(_norm(q, p["q_norm"][i], cfg.rms_eps), cfg.rope_theta)
+        k = _rope(_norm(k, p["k_norm"][i], cfg.rms_eps), cfg.rope_theta)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(cfg.head_dim)
+        if wrong == "causal":
+            scores = jnp.where(np.tril(np.ones((64, 64), bool)), scores, -1e30)
+        mixed = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v).reshape(b, 64, -1)
+        x = x + mixed @ p["wo"][i]
+        n2 = _norm(x, p["moe_norm"][i], cfg.rms_eps)
+        route = jax.nn.softmax(n2 @ p["router_w"][i], -1)
+        kth = jnp.sort(route, -1)[..., -cfg.experts_per_token][..., None]
+        weights = jnp.where(route >= kth, route, 0.0)
+        if wrong == "renormalised":
+            weights = weights / weights.sum(-1, keepdims=True)
+        if wrong == "unweighted":
+            weights = (weights > 0).astype(jnp.float32)
+        for e in range(cfg.experts):
+            act = jax.nn.silu(n2 @ p["experts_gate"][i, e]) * (n2 @ p["experts_up"][i, e])
+            x = x + weights[..., e, None] * (act @ p["experts_down"][i, e])
+    x = _norm(x, p["final_norm"], cfg.rms_eps)
+    logits = (x @ p["policy_w"][0, 0] + p["policy_b"]).reshape(b, -1)
+    v = jax.nn.relu(x @ p["value_w"][0, 0] + p["value_b"]).reshape(b, -1)
+    v = jax.nn.relu(v @ p["value_fc1_w"] + p["value_fc1_b"])
+    return logits, jnp.tanh(v @ p["value_fc2_w"] + p["value_fc2_b"])[:, 0]
+
+
+def reference_loss(p, batch, cfg, wrong=""):
+    logits, value = reference_forward(p, batch["planes"], cfg, wrong)
+    policy = -jnp.mean(jnp.sum(batch["policy_target"] * jax.nn.log_softmax(logits, -1), -1))
+    return policy + jnp.mean((value - batch["value_target"]) ** 2)
+
+
+def rel(got, want):
+    return float(jnp.linalg.norm(jnp.asarray(got, jnp.float32) - want) / jnp.linalg.norm(want))
+
+
+@pytest.fixture(scope="module")
+def program():
+    trainer = AzTrainer(TINY)
+    forward = jax.jit(lambda p, x: trunk_forward(p, x, TINY))
+    grad = jax.jit(jax.grad(lambda p, b: trainer._loss(p, b)[0]))
+    return forward, grad
+
+
+# Readings over seeds 1-5 (CPU): logits 0.008-0.018 of their norm, value 0.004-0.009 absolute; the wrong
+# references read logits >= 0.088 (renormalised), >= 0.55 (unweighted), >= 0.45 (causal).
+LOGITS_TOL, VALUE_TOL = 0.03, 0.03
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_forward_matches_the_plain_reference(program, seed):
+    params, batch = conditioned_params(seed), batch_of(seed)
+    logits, value = program[0](params, batch["planes"])
+    assert logits.shape == (BATCH, 4672) and value.shape == (BATCH,) and logits.dtype == value.dtype == jnp.float32
+    want_logits, want_value = reference_forward(params, batch["planes"], TINY)
+    assert rel(logits, want_logits) < LOGITS_TOL, rel(logits, want_logits)
+    assert float(jnp.max(jnp.abs(value - want_value))) < VALUE_TOL
+    # az_forward routes a TrunkConfig to the same network
+    routed = jax.jit(lambda p, x: az_forward(p, x, TINY))(params, batch["planes"])
+    assert jnp.array_equal(routed[0], logits) and jnp.array_equal(routed[1], value)
+
+
+@pytest.mark.parametrize("wrong", ["unweighted", "renormalised", "causal"])
+def test_the_tolerance_catches_left_out_mathematics(program, wrong):
+    params, batch = conditioned_params(1), batch_of(1)
+    logits, _value = program[0](params, batch["planes"])
+    missed = rel(logits, reference_forward(params, batch["planes"], TINY, wrong)[0])
+    assert missed > 1.5 * LOGITS_TOL, (wrong, missed)
+
+
+# Readings over seeds 1-5: all tensors as one vector 0.032-0.077; the worst single tensor 0.14 (experts_up,
+# seed 1) but for the policy head's bias and the value head's first layers, whose gradients are cancelling
+# sums (<= 0.20). The wrong references read >= 0.22 (renormalised), >= 0.68 (unweighted), >= 0.64 (causal)
+# as one vector.
+GRAD_ALL_TOL, GRAD_TENSOR_TOL, GRAD_CANCELLING_TOL = 0.1, 0.25, 0.5
+CANCELLING = ("policy_b", "value_w", "value_b", "value_fc1_w", "value_fc1_b")
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_gradient_of_the_trainers_loss_matches_the_plain_reference(program, seed):
+    params, batch = conditioned_params(seed), batch_of(seed)
+    got = program[1](params, batch)
+    want = jax.grad(reference_loss)(params, batch, TINY)
+    assert set(got) == set(want)
+    diff = np.sqrt(sum(float(jnp.sum((got[k] - want[k]) ** 2)) for k in want))
+    assert diff / np.sqrt(sum(float(jnp.sum(want[k] ** 2)) for k in want)) < GRAD_ALL_TOL
+    for name in want:
+        assert float(jnp.linalg.norm(want[name])) > 0, name  # every tensor has a gradient to compare
+        assert rel(got[name], want[name]) < (GRAD_CANCELLING_TOL if name in CANCELLING else GRAD_TENSOR_TOL), name
+    wrong = jax.grad(reference_loss)(params, batch, TINY, "renormalised")
+    missed = np.sqrt(sum(float(jnp.sum((got[k] - wrong[k]) ** 2)) for k in want) / sum(float(jnp.sum(wrong[k] ** 2)) for k in want))
+    assert missed > 1.5 * GRAD_ALL_TOL, missed
+
+
+def _dense_experts(n2, layer, cfg):
+    route = jax.nn.softmax(n2 @ layer["router_w"], -1)
+    kth = jnp.sort(route, -1)[:, -cfg.experts_per_token][:, None]
+    weights = jnp.where(route >= kth, route, 0.0)
+    out = jnp.zeros_like(n2)
+    for e in range(cfg.experts):
+        act = jax.nn.silu(n2 @ layer["experts_gate"][e]) * (n2 @ layer["experts_up"][e])
+        out = out + weights[:, e, None] * (act @ layer["experts_down"][e])
+    return out, (weights > 0).sum(0)
+
+
+@pytest.mark.parametrize("top_k,favoured,expected_load", [
+    (1, [0], [512, 0, 0, 0, 0, 0, 0, 0]),        # every token to one expert: group sizes [N*k, 0, 0, ...]
+    (2, [2, 5], [0, 0, 512, 0, 0, 512, 0, 0]),   # two experts take everything, six get none
+    (2, [], None),                               # the router's own choice: uneven, some small
+])
+def test_dropless_routing_computes_every_slot(top_k, favoured, expected_load):
+    cfg = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=1, experts=8, experts_per_token=top_k, expert_width=32)
+    rng = np.random.default_rng(7)
+    layer = {k: v[0] for k, v in conditioned_params(7, cfg).items() if k in ("router_w", "experts_gate", "experts_up", "experts_down")}
+    n2 = jnp.asarray(np.abs(rng.standard_normal((512, 64))) + 0.1, jnp.float32)  # all positive: a column of ones is a bias
+    if favoured:
+        layer["router_w"] = layer["router_w"].at[:, jnp.asarray(favoured)].add(1.0)
+    got, counters = jax.jit(lambda n, l: trunk._experts(n, l, cfg, "layer00"))(n2, layer)
+    want, load = _dense_experts(n2, layer, cfg)
+    assert rel(got, want) < 0.02, rel(got, want)
+    assert int(load.sum()) == 512 * top_k
+    if expected_load is not None:
+        assert load.tolist() == expected_load
+    assert float(counters["expert_load_max"]) == int(load.max()) and float(counters["expert_load_min"]) == int(load.min())
+    assert 0.0 <= float(counters["router_entropy"]) <= np.log(8) + 1e-6
+
+
+@pytest.mark.parametrize("rows,sizes", [
+    (1024, [100, 0, 300, 5, 119, 200, 0, 300]),  # tile 512: groups that straddle tiles, empty groups
+    (1024, [1024, 0, 0, 0, 0, 0, 0, 0]),
+    (192, [3, 0, 60, 5, 19, 20, 0, 85]),         # tile gcd(192, 512) = 64
+    (64, [0, 0, 0, 0, 0, 0, 1, 63]),
+])
+def test_grouped_matmul_against_a_loop_over_experts(rows, sizes):
+    rng = np.random.default_rng(rows + sizes[0])
+    x = jnp.asarray(rng.standard_normal((rows, 64)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((8, 64, 32)), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+
+    def loop(x, w):
+        xb, wb = x.astype(jnp.bfloat16).astype(jnp.float32), w.astype(jnp.bfloat16).astype(jnp.float32)
+        return jnp.concatenate([xb[starts[e]:starts[e + 1]] @ wb[e] for e in range(8)])
+
+    got = jax.jit(trunk.grouped_matmul)(x, w, group_sizes)
+    assert got.shape == (rows, 32) and got.dtype == jnp.bfloat16
+    assert rel(got, loop(x, w)) < 4e-3  # the result's own rounding to bfloat16
+    cot = jnp.asarray(rng.standard_normal((rows, 32)), jnp.float32)
+    got_dx, got_dw = jax.jit(jax.grad(lambda x, w: jnp.sum(trunk.grouped_matmul(x, w, group_sizes).astype(jnp.float32) * cot), (0, 1)))(x, w)
+    want_dx, want_dw = jax.grad(lambda x, w: jnp.sum(loop(x, w) * cot), (0, 1))(x, w)
+    assert rel(got_dx, want_dx) < 6e-3 and rel(got_dw, want_dw) < 6e-3  # bfloat16 cotangent and results
+    assert not np.any(np.asarray(got_dw)[np.asarray(sizes) == 0])  # an expert with no rows has no gradient
+
+
+def test_trainer_overfits_a_small_batch():
+    trainer = AzTrainer(TINY, learning_rate=3e-3)
+    state, batch = trainer.init(0), batch_of(5)
+    losses = []
+    for _ in range(30):
+        state, metrics = trainer.step(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all() and losses[-1] < 0.5 * losses[0], (losses[0], losses[-1])
+    assert {"expert_load_max", "expert_load_min", "router_entropy"} <= set(metrics)
+    assert float(metrics["expert_load_max"]) >= BATCH * 64 * 2 / 8 >= float(metrics["expert_load_min"])
+
+
+def test_config_round_trips_a_trunk_checkpoint(tmp_path):
+    cfg = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=1, experts=4, experts_per_token=3, expert_width=16,
+                      rope_theta=1234.5, rms_eps=1e-6, value_hidden=8)
+    trainer = AzTrainer(cfg)
+    trainer.export(trainer.init(0), str(tmp_path / "trunk.npz"))
+    loaded = dict(np.load(tmp_path / "trunk.npz"))
+    assert az_config_from_params(loaded) == cfg
+    assert az_config_from_params(az_checkpoint(init_az_params(jax.random.PRNGKey(0), TINY), TINY)) == TINY
+    # the loaded dict, hyperparameters and all, is what the served forward takes
+    logits, value = jax.jit(lambda p, x: az_forward(p, x, cfg))(loaded, jnp.zeros((1, 8, 8, 19)))
+    assert logits.shape == (1, 4672) and value.shape == (1,)
+    with pytest.raises(ValueError, match="trunk_hparams"):  # a trunk's tensors without its hyperparameters
+        az_config_from_params({k: v for k, v in loaded.items() if k != "trunk_hparams"})
+    with pytest.raises(ValueError, match="mismatched"):
+        az_config_from_params({**loaded, "wq": loaded["wq"][:, :, :16]})
+    with pytest.raises(ValueError, match="not an AZ checkpoint"):
+        az_config_from_params({"ft_w": np.zeros((4, 4))})
+
+
+def test_the_rpc_hosts_az_backend_serves_a_trunk():
+    """rpc/host.py builds its forward from ``az_forward(p, x, cfg.az)``: a
+    ``TrunkConfig`` there serves the trunk, uint8 planes in, float16 logits out."""
+    from fishnet_tpu.rpc.host import _HostAzBackend
+    from fishnet_tpu.search.mcts import MctsConfig
+
+    params = init_az_params(jax.random.PRNGKey(0), TINY)
+    backend = _HostAzBackend(params, MctsConfig(batch_capacity=16, az=TINY))
+    planes = (np.random.default_rng(0).random((5, 8, 8, 19)) < 0.2).astype(np.uint8)
+    logits16, values = backend._run([planes[:2], planes[2:]])
+    assert logits16.dtype == np.float16 and logits16.shape[1] == 4672 and values.dtype == np.float32
+    decoded = jnp.asarray(planes, jnp.float32).at[..., 17].multiply(1.0 / 100.0)  # the wire's halfmove plane rides x100
+    want_logits, want_values = jax.jit(lambda p, x: az_forward(p, x, TINY))(params, decoded)
+    # rows are padded to the host's bucket; a position's own rows do not depend on the others
+    assert np.allclose(logits16[:5], np.asarray(want_logits, np.float16), atol=2e-3)
+    assert np.allclose(values[:5], want_values, atol=2e-3)

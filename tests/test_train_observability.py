@@ -14,6 +14,7 @@ import pytest
 from benchmark import scopes
 from fishnet_tpu import telemetry
 from fishnet_tpu.models.az import AzConfig
+from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.telemetry.registry import MetricsRegistry
 from fishnet_tpu.telemetry.spans import EVENT_STAGES, RECORDER
 from fishnet_tpu.train.az_trainer import AzTrainer
@@ -30,7 +31,11 @@ NNUE = NetConfig(num_features=64, max_active=4, l1=16, l2=4, l3=4, num_buckets=2
 MODEL_SCOPES = {
     "nnue": ("ft_gather", "ft_psqt", "pairwise", "stacks", "material"),
     "az": ("stem", "block00", "block01", "policy_head", "value_head"),
+    # the sparse-expert trunk behind the same AzTrainer (models/trunk.py): one scope a part, the layer in its name
+    "trunk": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
+              "layer01.attention", "layer01.experts", "final_norm", "policy_head", "value_head"),
 }
+TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 
 
 def make(kind):
@@ -48,7 +53,7 @@ def make(kind):
             "outcome": np.full((B,), 0.5, np.float32),
         }
     else:
-        trainer = AzTrainer(AzConfig(channels=8, blocks=2, value_hidden=8))
+        trainer = AzTrainer(TRUNK if kind == "trunk" else AzConfig(channels=8, blocks=2, value_hidden=8))
         batch = {
             "planes": np.zeros((B, 8, 8, 19), np.float32),
             "policy_target": np.full((B, 4672), 1 / 4672, np.float32),
@@ -83,7 +88,7 @@ def step_text(kind):
         return trainer._step_jit.lower(state, batch).compile().as_text()
 
 
-@pytest.fixture(scope="module", params=["nnue", "az"])
+@pytest.fixture(scope="module", params=["nnue", "az", "trunk"])
 def scoped(request):
     return request.param, step_text(request.param)
 

@@ -1,0 +1,55 @@
+"""The trunk's grouped product compiled for a described TPU v5e at the
+published widths: what Mosaic refuses (a tile that does not fit VMEM, a
+slice off the tiling) fails here, on the CPU, at no chip time. Nothing
+runs: a compile says nothing about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library, and every xdist worker imports every
+test file. Keep such tests in this one file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fishnet_tpu.models import trunk
+
+SLOTS, HIDDEN, WIDTH, EXPERTS = 262_144, 2048, 1024, 64  # moe_trunk_train_b512: 512 positions x 64 squares x top-8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as err:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {err}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_for_tpu(monkeypatch):
+    """The kernel itself, not the interpreter's loops; and no write to the
+    persistent cache, which a described device cannot read back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(trunk, "_interpret", lambda: False)
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("rows_in,rows_out", [(HIDDEN, WIDTH), (WIDTH, HIDDEN)], ids=["gate_up", "down"])
+def test_grouped_matmul_and_its_gradients_compile_at_published_widths(one_chip, compiled_for_tpu, rows_in, rows_out):
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((SLOTS, rows_in), jnp.bfloat16), sds((EXPERTS, rows_in, rows_out), jnp.float32), sds((EXPERTS,), jnp.int32))
+
+    def loss(rows, weights, group_sizes):
+        return jnp.sum(trunk.grouped_matmul(rows, weights, group_sizes).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # gmm forward, gmm on the transposed weights, tgmm
