@@ -27,7 +27,14 @@ Mechanism: the (token, slot) pairs are sorted by expert (stable), each
 expert's rows form one group of a grouped matrix product (megablox
 ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
 the CPU), the rows are put back in token order and each token's slots
-summed under their weights. Matrix products run in
+summed under their weights. The rows move through two more Pallas
+kernels (``ops/row_move.py``): wherever a row is addressed singly it
+lives as ``[rows, hidden // 128, 128]``, one contiguous 4 KiB tile at
+hidden 2048, and one DMA moves it; the sorted side that the grouped
+products read stays ``[slots, hidden]``. ``rows_out`` (tokens to sorted
+slots) and ``rows_back`` (sorted slots to token order) are each other's
+transpose, so ``_dispatch`` and ``_combine`` pair them as forward and
+gradient and nothing is ever scatter-added. Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
 tower's; norms, both softmaxes, the router and the combine weights are
 float32.
@@ -49,6 +56,7 @@ from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
+from fishnet_tpu.ops.row_move import row_view, rows_back, rows_out
 
 Params = Dict[str, jax.Array]
 
@@ -143,40 +151,84 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig) -> jax.Array:
     return _matmul(mixed.reshape(b, SQUARES, cfg.heads * cfg.head_dim), p["wo"])
 
 
+def _interpret() -> bool:
+    """The trunk's Pallas kernels (the grouped product and the two row
+    moves) are one path everywhere: compiled by Mosaic on a TPU, run by
+    the Pallas interpreter elsewhere (the CPU of the tests), never
+    another path."""
+    return jax.default_backend() != "tpu"
+
+
+def _slots_by_token(rows: jax.Array, k: int) -> jax.Array:
+    """``rows_back``'s result [N * k, sub, lanes] as [N, k, sub, lanes],
+    for the sums over a token's k. The barrier keeps XLA from moving the
+    float32 convert (or the cotangent's broadcast) to the kernel's side
+    of this reshape, where no fusion reaches it and it is written out
+    whole, 2 GiB at the published sizes (PERF.md section 6, PR 27)."""
+    return jax.lax.optimization_barrier(rows.reshape(-1, k, *rows.shape[1:]))
+
+
 @jax.custom_vjp
-def _take_rows(x: jax.Array, index: jax.Array, back: jax.Array) -> jax.Array:
-    """``x[index]`` where ``index`` takes every row of ``x`` equally often
-    and ``back`` lists, row after row of ``x``, where its copies went.
-    Dispatch (each token to its k sorted slots) and its undoing (a
-    permutation) are both this, so the gradient is a gather by ``back``
-    and a sum over each row's copies, never a scatter-add (24.8 ms against
-    8.9 at the published sizes, PERF.md section 5)."""
-    return x[index]
+def _dispatch(tokens: jax.Array, order: jax.Array) -> jax.Array:
+    """Each token's row to its k slots, the slots sorted by expert:
+    ``tokens[order // k]``, [N, hidden] bfloat16 -> [N * k, hidden].
+    ``order`` [N * k] lists the (token, slot) pairs in sorted order. The
+    gradient brings every slot's row back to its place (a permutation:
+    ``rows_back``) and sums each token's k, never a scatter-add (24.8 ms
+    against 8.9 for the gather at the published sizes, PERF.md section 5)."""
+    k = order.shape[0] // tokens.shape[0]
+    return rows_out(row_view(tokens), order // k, interpret=_interpret())
 
 
-def _take_rows_fwd(x, index, back):
-    return x[index], (back, x.shape[0])
+def _dispatch_fwd(tokens, order):
+    return _dispatch(tokens, order), order.reshape(tokens.shape[0], -1)
 
 
-def _take_rows_bwd(res, g):
-    back, n = res
-    return g[back].reshape(n, -1, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
+def _dispatch_bwd(order, g):
+    n, k = order.shape
+    per_slot = _slots_by_token(rows_back(g, order.reshape(n * k), interpret=_interpret()), k)
+    return per_slot.sum(axis=1).reshape(n, -1), None
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out: jax.Array, weight: jax.Array, order: jax.Array) -> jax.Array:
+    """The experts' sorted rows ``out`` [N * k, hidden] bfloat16 back in
+    token order (``rows_back``) and each token's k summed under
+    ``weight`` [N, k], float32 weights and sum: [N, hidden] float32. The
+    rows in token order stay in the row view, ``[N, k, hidden // 128,
+    128]``, which is also the residual of the weights' gradient. The
+    gradient to ``out`` is the dispatch again with a scale: each slot's
+    token's cotangent times the slot's weight in float32, then rounded
+    to bfloat16 (``rows_out``)."""
+    return _combine_fwd(out, weight, order)[0]
+
+
+def _combine_fwd(out, weight, order):
+    n, k = weight.shape
+    per_slot = _slots_by_token(rows_back(out, order, interpret=_interpret()), k)
+    mixed = jnp.sum(weight[:, :, None, None] * per_slot.astype(jnp.float32), axis=1)
+    return mixed.reshape(n, -1), (per_slot, weight, order)
+
+
+def _combine_bwd(res, g):
+    per_slot, weight, order = res
+    n, k = weight.shape
+    g = row_view(g)
+    d_weight = jnp.sum(g[:, None] * per_slot.astype(jnp.float32), axis=(2, 3))
+    d_out = rows_out(g, order // k, weight.reshape(n * k)[order], dtype=per_slot.dtype, interpret=_interpret())
+    return d_out, d_weight, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 #: Largest tile of the grouped product (rows, contraction, columns): the
 #: fastest of those tried on a v5e at [262144, 2048] x [64, 2048, 1024]
 #: (PERF.md section 5); the next size up does not fit the kernel's VMEM.
 _TILE = (512, 1024, 1024)
-
-
-def _interpret() -> bool:
-    """The grouped product is one Pallas kernel everywhere: compiled by
-    Mosaic on a TPU, run by the Pallas interpreter elsewhere (the CPU of
-    the tests), never another path."""
-    return jax.default_backend() != "tpu"
 
 
 def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
@@ -204,16 +256,14 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
     with jax.named_scope(f"{layer}.dispatch"):
         slot_expert = expert.reshape(n * k)
         order = jnp.argsort(slot_expert, stable=True)
-        inverse = jnp.argsort(order)
         group_sizes = jnp.sum(slot_expert[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
-        rows = _take_rows(n2.astype(jnp.bfloat16), order // k, inverse)
+        rows = _dispatch(n2.astype(jnp.bfloat16), order)
     with jax.named_scope(f"{layer}.experts"):
         gate = grouped_matmul(rows, p["experts_gate"], group_sizes)
         up = grouped_matmul(rows, p["experts_up"], group_sizes)
         out = grouped_matmul(jax.nn.silu(gate) * up, p["experts_down"], group_sizes)
     with jax.named_scope(f"{layer}.combine"):
-        per_slot = _take_rows(out, inverse, order).reshape(n, k, cfg.hidden)
-        mixed = jnp.einsum("nk,nkh->nh", weight, per_slot.astype(jnp.float32))
+        mixed = _combine(out, weight, order)
     load = group_sizes.astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
     return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy}

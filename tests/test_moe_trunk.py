@@ -234,6 +234,80 @@ def test_grouped_matmul_against_a_loop_over_experts(rows, sizes):
     assert not np.any(np.asarray(got_dw)[np.asarray(sizes) == 0])  # an expert with no rows has no gradient
 
 
+#: tokens, top-k, hidden, which experts the slots go to. Slots = tokens x k
+#: against the row tile of 512: 64, 192 and 640 are not multiples of it
+#: (tiles of 64, 64 and 128), 1024 and 1536 are.
+MOVE_CASES = [
+    (64, 1, 64, "random"),
+    (512, 2, 64, "one_expert"),
+    (96, 2, 256, "some_empty"),
+    (128, 8, 256, "random"),
+    (320, 2, 256, "one_expert"),
+    (192, 8, 64, "some_empty"),
+    (24, 8, 2048, "random"),
+    (64, 1, 2048, "some_empty"),
+]
+MOVE_IDS = [f"n{n}-k{k}-h{h}-{routing}" for n, k, h, routing in MOVE_CASES]
+
+
+def _sorted_order(rng, slots: int, routing: str):
+    """``order`` as ``_experts`` makes it: the stable sort of each slot's expert."""
+    experts = {"random": rng.integers(0, 8, slots), "one_expert": np.full(slots, 3),
+               "some_empty": rng.choice([1, 4, 6], slots)}[routing]
+    return jnp.argsort(jnp.asarray(experts, jnp.int32), stable=True)
+
+
+def _small_integers(rng, shape):
+    """Values whose sums of eight are exact in bfloat16, so that the
+    order of a sum cannot show."""
+    return jnp.asarray(rng.integers(-8, 9, shape), jnp.float32)
+
+
+@pytest.mark.parametrize("tokens,top_k,hidden,routing", MOVE_CASES, ids=MOVE_IDS)
+def test_dispatch_is_plain_indexing_and_its_gradient(tokens, top_k, hidden, routing):
+    """``rows_out`` without a scale, and ``rows_back`` plus the sum over a
+    token's slots as its gradient, against ``x[index]`` and the
+    scatter-add that is its autodiff."""
+    rng = np.random.default_rng(tokens + top_k + hidden)
+    order = _sorted_order(rng, tokens * top_k, routing)
+    x = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.bfloat16)
+    cot = _small_integers(rng, (tokens * top_k, hidden))
+    plain = lambda x: x[order // top_k]
+    got = jax.jit(trunk._dispatch)(x, order)
+    assert got.dtype == jnp.bfloat16 and np.array_equal(np.asarray(got, np.float32), np.asarray(plain(x), np.float32))  # bit for bit
+    loss = lambda move: lambda x: jnp.sum(move(x.astype(jnp.bfloat16)).astype(jnp.float32) * cot)
+    got_dx = jax.jit(jax.grad(loss(lambda x: trunk._dispatch(x, order))))(x.astype(jnp.float32))
+    want_dx = jax.grad(loss(plain))(x.astype(jnp.float32))
+    assert np.array_equal(np.asarray(got_dx), np.asarray(want_dx))
+
+
+@pytest.mark.parametrize("tokens,top_k,hidden,routing", MOVE_CASES, ids=MOVE_IDS)
+def test_combine_is_plain_indexing_a_weighted_sum_and_their_gradient(tokens, top_k, hidden, routing):
+    """``rows_back`` and the float32 weighted sum, with ``rows_out`` under
+    the per-slot scale as the gradient to the rows, against ``jnp.take``
+    by the inverse permutation, the same sum, and their autodiff."""
+    rng = np.random.default_rng(tokens * top_k + hidden)
+    order = _sorted_order(rng, tokens * top_k, routing)
+    inverse = jnp.argsort(order)
+    out = jnp.asarray(rng.standard_normal((tokens * top_k, hidden)), jnp.bfloat16)
+    weight = jnp.asarray(rng.random((tokens, top_k)) + 0.1, jnp.float32)
+    cot = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+
+    def plain(out, weight):
+        per_slot = jnp.take(out, inverse, axis=0).reshape(tokens, top_k, hidden)
+        return jnp.einsum("nk,nkh->nh", weight, per_slot.astype(jnp.float32))
+
+    got = jax.jit(lambda o, w: trunk._combine(o, w, order))(out, weight)
+    assert got.dtype == jnp.float32 and np.allclose(got, plain(out, weight), rtol=1e-6, atol=1e-6)
+    if top_k == 1:  # one slot a token: the move alone, bit for bit
+        assert np.array_equal(np.asarray(got), np.asarray(out[inverse].astype(jnp.float32) * weight))
+    loss = lambda f: lambda o, w: jnp.sum(f(o.astype(jnp.bfloat16), w) * cot)
+    got_do, got_dw = jax.jit(jax.grad(loss(lambda o, w: trunk._combine(o, w, order)), (0, 1)))(out.astype(jnp.float32), weight)
+    want_do, want_dw = jax.grad(loss(plain), (0, 1))(out.astype(jnp.float32), weight)
+    assert np.array_equal(np.asarray(got_do), np.asarray(want_do))  # a permutation: one term a row, rounded once after the scale
+    assert np.allclose(got_dw, want_dw, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(want_dw))))  # float32 sums in another order
+
+
 def test_trainer_overfits_a_small_batch():
     trainer = AzTrainer(TINY, learning_rate=3e-3)
     state, batch = trainer.init(0), batch_of(5)

@@ -53,3 +53,43 @@ def test_grouped_matmul_and_its_gradients_compile_at_published_widths(one_chip, 
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 3  # gmm forward, gmm on the transposed weights, tgmm
+
+
+TOKENS, TOP_K = 32_768, 8  # SLOTS = TOKENS * TOP_K
+
+
+def test_row_moves_and_their_gradients_compile_at_published_widths(one_chip, compiled_for_tpu):
+    """Both kernels, forward and as each other's gradient: the one-row DMA
+    on the row view, the VMEM reshape, the index blocks in SMEM."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(tokens, out, weight, order):
+        rows = trunk._dispatch(tokens, order)  # rows_out; its gradient rows_back
+        mixed = trunk._combine(out, weight, order)  # rows_back; its gradient rows_out with the scale
+        return jnp.sum(rows.astype(jnp.float32)) + jnp.sum(mixed)
+
+    args = (sds((TOKENS, HIDDEN), jnp.bfloat16), sds((SLOTS, HIDDEN), jnp.bfloat16),
+            sds((TOKENS, TOP_K), jnp.float32), sds((SLOTS,), jnp.int32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+    assert text.count("moe_rows_out") >= 2 and text.count("moe_rows_back") >= 2
+
+
+def test_experts_step_at_published_widths_gathers_no_slot_rows(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of ``_experts`` as the cell runs it: no XLA
+    gather produces the 1 GiB ``bf16[262144,2048]`` any more."""
+    import re
+
+    cfg = trunk.TrunkConfig()
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {"router_w": sds((HIDDEN, EXPERTS), jnp.float32),
+             "experts_gate": sds((EXPERTS, HIDDEN, WIDTH), jnp.float32),
+             "experts_up": sds((EXPERTS, HIDDEN, WIDTH), jnp.float32),
+             "experts_down": sds((EXPERTS, WIDTH, HIDDEN), jnp.float32)}
+
+    def loss(n2, p):
+        return jnp.sum(trunk._experts(n2, p, cfg, "layer00")[0])
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((TOKENS, HIDDEN), jnp.float32), layer).compile().as_text()
+    slot_gathers = [line for line in text.splitlines() if re.search(r"= bf16\[262144,2048\]\S* gather\(", line)]
+    assert not slot_gathers, slot_gathers[:2]
+    assert text.count("moe_rows_out") >= 2 and text.count("moe_rows_back") >= 2
