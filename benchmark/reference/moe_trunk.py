@@ -52,22 +52,41 @@ _LAYER_TENSORS = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "moe_
 
 def init_params(seed: int, model: Dict[str, Any]) -> Dict[str, np.ndarray]:
     """Float32 parameters from the seed, conditioned so that every tensor
-    has a gradient worth comparing.
+    has a gradient worth comparing: each a sum of terms that add, never
+    what the bfloat16 program's rounding leaves of a sum that cancels.
 
     Matrices are normal(0, 0.9^2 / fan_in): 0.02 at the published hidden
     size, and activations of the same scale at any width. Norm gains are
     1 + 0.1 normal and biases 0.05 normal, so that none is a special
-    point. The value head's two dense layers are of ONE sign (|normal|,
-    the last layer's and its bias's sign drawn once a seed), scaled so that
-    the hidden units sit near 1 and the tanh's argument at 0.6 to 1.0. The
-    pool's value targets are nearly all 0 (playouts cut at 120 plies are
-    draws), so every position pulls the value the same way, and with signed
-    layers the gradient of the head's first convolution is a sum over 64
-    squares x hidden units of terms of random sign: its norm swings 20-fold
-    from seed to seed, and where it is small the bfloat16 head's rounding
-    is half of it (value_w 0.46, value_b 0.66 in one seed of 21 on the
-    chip). With layers of one sign the terms add and the comparison is of
-    the gradient, not of what is left of a cancelling sum.
+    point.
+
+    The value head is pinned, because what it sees is nearly the same
+    vector on every square of every position: most squares are empty and
+    share one embedding, attention averages near-equal tokens, and at the
+    published widths 91-95% of the squared norm of the final-normed
+    features is their mean over squares and positions (PERF.md section 6,
+    PR 29). ``x . value_w[:, c]`` is therefore one offset a plane, drawn
+    once a seed, plus a variation several times smaller. With ``value_w``
+    at the other matrices' scale the offsets read normal(0, 0.9^2) and the
+    variation 0.07-0.5: a plane is alive on every square, dead on every
+    square, or straddles the relu's corner, by the seed. Where none is
+    alive ``value_fc1_w``'s gradient is what one barely-alive unit of 8192
+    leaves (0.43 on the chip, seed 2700800011); where one straddles the
+    corner the bfloat16 program's rounding flips its units' masks, that
+    plane's gradients are off by 4-9%, and because a third of the trunk's
+    gradient comes through the head every tensor reads 3x its usual
+    (seed 2700034567: 0.016 overall). So: ``value_w`` is normal at
+    0.2 / sqrt(hidden) (offsets of 0.2, variation 0.02-0.06) under a bias
+    of 1 + 0.05 normal: every plane is alive on every square, 4 sigma and
+    more from the corner, and a unit reads about 1. The two dense layers
+    are of ONE sign (|normal|, the last layer's and its bias's sign drawn
+    once a seed), scaled so that the 256 hidden units sit near 1 and the
+    tanh's argument at 0.5 to 1.1. The pool's value targets are nearly
+    all 0 (playouts cut at 120 plies are draws; 3-4% are decisive, 0-10
+    of the 128 positions compared), so every position pulls the value the
+    same way, and with layers of one sign the terms of every gradient of
+    the head add.
+
     The router's matrix is 3.3 times larger, so that its logits spread by
     about 3: a trained router is peaked, and with the logits of a fresh
     one (spread 0.9) the eighth and ninth expert of a token weigh the
@@ -75,7 +94,10 @@ def init_params(seed: int, model: Dict[str, Any]) -> Dict[str, np.ndarray]:
     and each swap replaces an eighth of that token's output: the
     comparison would then measure how many near-ties a seed has. With the
     peaked router a swap exchanges two experts of weight ~0.02 of the
-    first's; every expert is still some token's first."""
+    first's; every expert is still some token's first.
+
+    The draws keep their order and count, so a seed's other tensors are
+    what they were before the head was pinned."""
     rng = np.random.default_rng([int(seed), 0x6D6F65])
     h, e, w = model["hidden_size"], model["num_experts"], model["expert_intermediate_size"]
     layers, inner = model["num_hidden_layers"], model["num_attention_heads"] * model["head_dim"]
@@ -103,9 +125,9 @@ def init_params(seed: int, model: Dict[str, Any]) -> Dict[str, np.ndarray]:
         "experts_down": matrix(layers, e, w, h, fan_in=w),
         "final_norm": gain(h),
         "policy_w": matrix(1, 1, h, model["policy_planes"], fan_in=h), "policy_b": bias(model["policy_planes"]),
-        "value_w": matrix(1, 1, h, 4, fan_in=h), "value_b": bias(4),
-        # relu(value conv) averages 0.36 a unit: 256 of them times |normal| (mean 0.8) / 74 is a hidden unit near 1
-        "value_fc1_w": np.abs(matrix(4 * SQUARES, hidden, fan_in=1, scale=1.0 / 74.0)), "value_fc1_b": bias(hidden),
+        "value_w": matrix(1, 1, h, 4, fan_in=h, scale=0.2), "value_b": np.float32(1.0) + bias(4),
+        # relu(value conv) is about 1 a unit: 256 of them times |normal| (mean 0.8) / 205 is a hidden unit near 1
+        "value_fc1_w": np.abs(matrix(4 * SQUARES, hidden, fan_in=1, scale=1.0 / 205.0)), "value_fc1_b": bias(hidden),
         "value_fc2_w": sign * np.abs(matrix(hidden, 1, fan_in=1, scale=0.375 / hidden)),
         "value_fc2_b": (sign * rng.uniform(0.3, 0.7, 1)).astype(np.float32),
     }
@@ -125,12 +147,19 @@ def _rope(x: jax.Array, theta: float) -> jax.Array:
     return x * cos + jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1) * sin
 
 
-def forward(params: Params, planes: jax.Array, model: Dict[str, Any], cast: Cast, grad_cast: Cast):
-    heads, head_dim, eps = model["num_attention_heads"], model["head_dim"], model["rms_norm_eps"]
-    top_k, b = model["num_experts_per_tok"], planes.shape[0]
-
+def _product(cast: Cast, grad_cast: Cast):
+    """Every contraction of the model: operands and incoming gradient in the precision, the result float32."""
     def product(subscripts: str, left: jax.Array, right: jax.Array) -> jax.Array:
         return grad_cast(jnp.einsum(subscripts, cast(left), cast(right))).astype(jnp.float32)
+
+    return product
+
+
+def features(params: Params, planes: jax.Array, model: Dict[str, Any], cast: Cast, grad_cast: Cast) -> jax.Array:
+    """The final-normed trunk output [B, 8, 8, hidden]: what both heads read."""
+    heads, head_dim, eps = model["num_attention_heads"], model["head_dim"], model["rms_norm_eps"]
+    top_k, b = model["num_experts_per_tok"], planes.shape[0]
+    product = _product(cast, grad_cast)
 
     x = product("bsp,ph->bsh", planes.reshape(b, SQUARES, -1), params["embed_w"]) + params["embed_b"]
     for i in range(model["num_hidden_layers"]):
@@ -157,7 +186,13 @@ def forward(params: Params, planes: jax.Array, model: Dict[str, Any], cast: Cast
                                  (p["experts_gate"], p["experts_up"], p["experts_down"], weights.T))
         x = x + routed.reshape(b, SQUARES, -1)
 
-    x = _rms_norm(x, params["final_norm"], eps).reshape(b, 8, 8, -1)
+    return _rms_norm(x, params["final_norm"], eps).reshape(b, 8, 8, -1)
+
+
+def forward(params: Params, planes: jax.Array, model: Dict[str, Any], cast: Cast, grad_cast: Cast):
+    x, b = features(params, planes, model, cast, grad_cast), planes.shape[0]
+    product = _product(cast, grad_cast)
+
     policy = product("brfh,hp->brfp", x, params["policy_w"][0, 0]) + params["policy_b"]
     logits = policy.reshape(b, -1)  # (square, plane) order
     v = jax.nn.relu(product("brfh,hc->brfc", x, params["value_w"][0, 0]) + params["value_b"]).reshape(b, -1)
