@@ -4,7 +4,7 @@
 
 PYTHON ?= python
 
-.PHONY: analysis analysis-fixtures sanitize-smoke sanitize test tier1 metrics-smoke soak-smoke overload-smoke coalesce-smoke async-smoke trace-smoke multichip-smoke cache-smoke cluster-smoke fleet-cache-smoke rpc-smoke control-smoke fleet-obs-smoke mcts-smoke profile-smoke regress-smoke depth-smoke
+.PHONY: analysis analysis-fixtures sanitize-smoke sanitize test tier1 metrics-smoke soak-smoke overload-smoke coalesce-smoke async-smoke trace-smoke multichip-smoke cache-smoke cluster-smoke fleet-cache-smoke rpc-smoke control-smoke fleet-obs-smoke mcts-smoke profile-smoke depth-smoke
 
 # Project-invariant static checker (R1-R9); exit 0 = clean tree. The
 # JSON artifact feeds the CI annotation step (build.yml "analysis").
@@ -34,8 +34,8 @@ soak-smoke:
 # Overload-serving contract (doc/resilience.md "Admission control and
 # load shedding", ≤60 s): the multi-tenant lane scheduler + shed
 # policy units, shutdown/requeue/deadline accounting under concurrent
-# tenants, the /healthz serving state, and a small saturation bench
-# run — analysis sheds at the watermark, best-move p99 holds, the
+# tenants, the /healthz serving state, and a small saturation run —
+# analysis sheds at the watermark, best-move p99 holds, the
 # queue stays bounded, and the ledger is exactly-once throughout.
 overload-smoke:
 	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_overload.py -q
@@ -89,8 +89,8 @@ cache-smoke:
 # forced degradation rung with the AZ eval cache live, the
 # FISHNET_NO_SHARED_AZ_PLANE escape hatch, pre-wire AZ eval reuse
 # across a pool respawn, and the preallocated step-buffer guard. The
-# full file — tree semantics, self-play parity, telemetry families,
-# bench schema — runs in tier-1.
+# full file — tree semantics, self-play parity, telemetry families —
+# runs in tier-1.
 mcts-smoke:
 	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_mcts_plane.py -q \
 		-k "parity_all_rungs or prewire or preallocated"
@@ -121,7 +121,7 @@ fleet-cache-smoke:
 # FISHNET_NO_BOUNDS / FISHNET_NO_SPECULATION escape hatches
 # byte-for-byte, speculative pad-row fill with unchanged MCTS results,
 # the controller's speculation pin/unpin rule, and the host linger
-# window fusing staggered cross-process waves (SPLIT_r01 pathology).
+# window fusing staggered cross-process waves.
 depth-smoke:
 	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_bounds_plane.py -q
 
@@ -165,15 +165,6 @@ fleet-obs-smoke:
 # measured dispatch wall on a real multi-tenant coalesced run.
 profile-smoke:
 	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_profiler.py -q
-
-# Perf-regression sentinel (doc/observability.md "Regression
-# sentinel", ≤15 s): the checked-in BENCH/MULTICHIP/CLUSTER/MCTS
-# artifacts must judge clean (exit 0, >=10 tracked series), a doctored
-# artifact must gate (exit 1), and the judging rules are pinned.
-regress-smoke:
-	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_regress.py -q
-	env JAX_PLATFORMS=cpu $(PYTHON) -m fishnet_tpu.telemetry.regress \
-		--root . --no-write
 
 # Causal-tracing contract (doc/observability.md "Causal tracing",
 # ≤60 s): a gated mock-server run must yield complete span trees (zero

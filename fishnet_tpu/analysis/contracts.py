@@ -1,8 +1,8 @@
 """R7 + R8: the two contract lints — telemetry vs doc, knobs vs registry.
 
 **R7 telemetry contract.** ``doc/observability.md`` is not prose: the
-fleet aggregator sums families by NAME, ``telemetry/regress.py`` keys
-its baselines by NAME, and dashboards join on LABELS. A family emitted
+fleet aggregator sums families by NAME, the SLO engine selects its
+series by NAME, and dashboards join on LABELS. A family emitted
 but not documented silently vanishes from all three; a documented row
 whose emitter was deleted leaves dashboards graphing flatlines. R7
 diffs the two worlds both ways and checks label sets (code labels must
@@ -322,7 +322,7 @@ class TelemetryContractRule:
                     message=(
                         f"metric family `{em.name}` is emitted here but "
                         f"has no row in {contract.path.name} — the fleet "
-                        "aggregator, the regression baseline, and every "
+                        "aggregator, the SLO engine, and every "
                         "dashboard are blind to it"
                     ),
                     suggestion=(
@@ -371,8 +371,8 @@ class TelemetryContractRule:
                         f"span stage `{em.name}` is recorded here but "
                         f"missing from the stage tables in "
                         f"{contract.path.name} — stage names are a "
-                        "stable contract (bench.py and the span tooling "
-                        "key on them)"
+                        "stable contract (the critical-path and span "
+                        "tooling key on them)"
                     ),
                     suggestion="add a Stage/Recorded in/Covers row",
                 ))
@@ -398,7 +398,7 @@ _ENV_NAME_RE = re.compile(r"^FISHNET_[A-Z0-9_]+$")
 _INI_KEY_RE = re.compile(r"^[A-Z][A-Za-z0-9]+$")
 _ENV_CALLS = ("environ.get", "environ.setdefault", "environ.pop", "getenv")
 #: modules whose argparse / ini surface is the PRODUCT contract (aux
-#: tools like telemetry/regress.py own their flags).
+#: tools like telemetry/fleet.py own their flags).
 _CLI_SCOPE = ("fishnet_tpu.configure",)
 
 
@@ -591,9 +591,10 @@ class EscapeHatchRule:
                         return i
                 return 1
 
-            # Top-level scripts (bench.py, soak drivers) read knobs
-            # too but sit outside the analyzed package — a cheap text
-            # probe keeps their knobs from reading as dead.
+            # Top-level scripts (chip_smoke.py, __graft_entry__.py,
+            # tools/*.py) read knobs too but sit outside the analyzed
+            # package — a cheap text probe keeps their knobs from
+            # reading as dead.
             script_text = "".join(
                 p.read_text(encoding="utf-8", errors="replace")
                 for pattern in ("*.py", "tools/*.py")

@@ -66,8 +66,6 @@ KNOBS: Tuple[Knob, ...] = (
          "doc/disaggregation.md", "tests/test_bounds_plane.py"),
     Knob("FISHNET_HOST_MATERIAL", "env", "unset (fused-PSQT wire path)",
          "doc/wire-format.md"),
-    Knob("FISHNET_METRICS_PORT", "env", "unset (exporter off)",
-         "doc/observability.md"),
     Knob("FISHNET_MOCK_ENGINE_DELAY", "env", "0 (seconds; test hook)",
          "doc/install.md"),
     Knob("FISHNET_NO_ASYNC", "env", "unset (async pipeline on)",
@@ -143,12 +141,8 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("--batch-deadline", "cli", "unset (no deadline flushes)",
          "doc/resilience.md", "tests/test_configure.py"),
     Knob("--conf", "cli", "fishnet.ini next to the module", "README.md"),
-    Knob("--control", "cli", "off (bench.py / fleet console mode flag)",
-         "doc/control-plane.md", "tests/test_control.py"),
     Knob("--cores", "cli", "auto (n-1)", "README.md",
          "tests/test_configure.py"),
-    Knob("--depth", "cli", "off (bench.py mode flag)",
-         "doc/eval-cache.md", "tests/test_bounds_plane.py"),
     Knob("--drain-deadline", "cli", "10s", "doc/resilience.md",
          "tests/test_cluster.py"),
     Knob("--endpoint", "cli", "https://lichess.org/fishnet",
@@ -157,8 +151,6 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("--engine-exe", "cli", "bundled binary", "doc/install.md"),
     Knob("--fault-plan", "cli", "unset", "doc/resilience.md",
          "tests/test_configure.py"),
-    Knob("--fleet-cache", "cli", "off (bench.py mode flag)",
-         "doc/eval-cache.md", "tests/test_position_tier.py"),
     Knob("--key", "cli", "unset (dialog asks)", "README.md",
          "tests/test_configure.py"),
     Knob("--key-file", "cli", "unset", "doc/install.md",
@@ -187,8 +179,6 @@ KNOBS: Tuple[Knob, ...] = (
          "doc/observability.md"),
     Knob("--spans-journal", "cli", "unset (ring dumps only)",
          "doc/observability.md"),
-    Knob("--split", "cli", "off (bench.py mode flag)",
-         "doc/disaggregation.md", "tests/test_rpc.py"),
     Knob("--stats-file", "cli", "platform data dir", "doc/install.md",
          "tests/test_configure.py"),
     Knob("--system-backlog", "cli", "0s", "doc/install.md"),
@@ -200,9 +190,6 @@ KNOBS: Tuple[Knob, ...] = (
          "tests/test_configure.py"),
     Knob("--verbose", "cli", "off", "doc/install.md",
          "tests/test_configure.py"),
-    # -- supervisor spec fields (cluster/supervisor.py ProcSpec) -----------
-    Knob("role=", "cli", "monolith (frontend|evaluator split the plane)",
-         "doc/disaggregation.md", "tests/test_rpc.py"),
     # -- fishnet.ini keys (mirror of _INI_FIELDS in configure.py) ----------
     Knob("Endpoint", "ini", "https://lichess.org/fishnet",
          "doc/install.md", "tests/test_configure.py"),

@@ -169,7 +169,7 @@ class Client:
     # priority lanes, DRR fairness, and admission control.
     # FISHNET_NO_MULTITENANT=1 forces the single-stream path.
     tenants: int = 1
-    # Admission/shedding policy override (tests, bench); None builds
+    # Admission/shedding policy override (tests); None builds
     # the default watermark policy in the front end.
     shed_policy: Optional[object] = None
     # ServiceSupervisor whose ladder rung scales shed capacity.

@@ -28,7 +28,8 @@ doc/disaggregation.md) script with the same one-string-per-proc
 grammar: give the evaluator spec ``rpc.detach:nth=N:error`` and its
 host drops one frontend link mid-flight on its Nth service sweep — the
 frontend reattaches and resubmits, exactly-once audited like every
-other fault here (exercised by ``bench.py --split``).
+other fault here (the site itself: tests/test_rpc.py
+``test_rpc_detach_fault_site``; no test runs a whole split fleet).
 """
 
 from __future__ import annotations

@@ -44,7 +44,7 @@ the process caches' striping discipline.
 Ownership: every slot records the writer's pid, so a hit splits into
 ``scope="local"`` (this process wrote it — a snapshot-restored or
 re-probed entry) vs ``scope="fleet"`` (another process paid the eval),
-which is exactly the cross-process reuse the fleet bench gates on.
+which is exactly the cross-process reuse the tier exists for.
 
 Attach is graceful: a missing/unwritable path, a foreign magic, a
 version or geometry mismatch all fall back to tier-off (the process
@@ -768,7 +768,7 @@ def get_tier() -> Optional[PositionTier]:
 
 
 def reset_tier() -> None:
-    """Detach and forget the process tier (tests / bench phase resets).
+    """Detach and forget the process tier (tests).
     Counters survive — they are process-lifetime totals."""
     global _tier, _tier_resolved
     with _tier_lock:

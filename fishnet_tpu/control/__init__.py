@@ -28,8 +28,10 @@ House gating: ``FISHNET_NO_CONTROL=1`` is the escape hatch — a
 constructed controller stops deciding, every actuator refuses to move,
 and ``revert()`` restores each subsystem's static default
 byte-for-byte. The controller only ever moves SCHEDULING knobs, never
-numerics, so analyses stay bit-identical with it on (``bench.py
---control`` pins this).
+numerics, so analyses stay bit-identical with it on (each knob's own
+parity tests pin that, tests/test_coalesce.py and
+tests/test_async_dispatch.py among them; no test compares analyses with
+the controller running).
 """
 
 from __future__ import annotations

@@ -126,7 +126,9 @@ class AzMctsService:
     def pool_counters(self) -> Dict:
         """Tree- and dispatch-side stats (visits, collisions, batch
         fill, subtree-reuse hits, plane dispatch/prewire counters) —
-        the ops surface bench.py --mcts and the console read."""
+        ``MctsPool.counters()``, which tests/test_mcts_plane.py reads
+        from the pool; nothing in the tree calls this method (ROADMAP
+        D8)."""
         return self.pool.counters()
 
     def close(self) -> None:

@@ -81,7 +81,7 @@ class ShardRouter:
     ordered by (occupancy EMA, assigned-group count, keep-current,
     shard id) — which is a no-op while the mesh is balanced (ties
     prefer the current home) but moves a waking group off a hot shard
-    onto an idle one, the MULTICHIP_r06 failure mode (8-shard
+    onto an idle one, the failure mode a round-6 run showed (8-shard
     dispatches [253,240,0,0,8,34,35,20] under pure round-robin).
     ``FISHNET_SHARD_PLACEMENT=rr`` restores the static assignment.
 

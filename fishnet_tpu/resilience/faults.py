@@ -99,7 +99,7 @@ _INJECTED = _telemetry.REGISTRY.counter(
 )
 
 #: Environment variable carrying the plan for processes not started via
-#: the CLI (bench, soak workers).
+#: the CLI (soak workers).
 PLAN_ENV = "FISHNET_FAULT_PLAN"
 
 

@@ -202,7 +202,9 @@ class RemoteBackend(SearchService):
     a plain SearchService, and analyses are bit-identical to a
     monolith's because the host replays the exact dense microbatch
     through the same ``evaluate_batch`` graph (the host-material rung's
-    parity contract; gated by bench.py --split)."""
+    parity contract; tests/test_rpc.py
+    ``test_two_process_split_bit_identical_analyses``, a ``slow``
+    test)."""
 
     def __init__(self, *args, rpc_dir: Optional[str] = None,
                  **kwargs) -> None:
@@ -239,7 +241,7 @@ class RemoteAzPlane:
     ``_PlaneEvaluator`` adapter, so handing this to a pool routes every
     leaf microbatch through the evaluator host — where microbatches
     from ALL frontends fuse into shared bucket dispatches (the
-    cross-process fill win bench.py --split gates). ``params`` is
+    cross-process fill win, which no test measures). ``params`` is
     optional and only salts the client-side pre-wire
     :class:`~fishnet_tpu.search.eval_cache.AzEvalCache` probe; the wire
     payload is the exact uint8 planes / fp16 logits the local plane
@@ -254,8 +256,8 @@ class RemoteAzPlane:
 
         self.cfg = cfg
         self._policy_size = POLICY_SIZE
-        # Link names are per-frontend: same-process planes (bench fill
-        # probe, tests) must pass distinct ``link_name``s or the second
+        # Link names are per-frontend: same-process planes (tests)
+        # must pass distinct ``link_name``s or the second
         # attach bumps the frontend epoch and fences the first plane's
         # in-flight submits as stale.
         self._client = _RpcClient(
@@ -353,7 +355,7 @@ class RemoteAzPlane:
 
     def counters(self) -> Dict[str, float]:
         """Client-side view (host-side fill rides the rpc_* metric
-        families; bench.py --split reads those)."""
+        families)."""
         with self._stats_lock:
             return {
                 "prewire_hits": self._prewire_hits,

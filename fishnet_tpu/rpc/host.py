@@ -8,7 +8,7 @@ from DIFFERENT PROCESSES fuse into the same segmented device dispatches.
 Cross-process batch fill is the direct payoff: three frontends each
 trickling 60%-full MCTS leaf batches become one evaluator dispatching
 near-full buckets (``fishnet_rpc_fused_rows_total`` over
-``fishnet_rpc_fused_slots_total``; gated by bench.py --split).
+``fishnet_rpc_fused_slots_total``; no test measures the fill).
 
 Parity: NNUE records carry the exact padded dense arrays the
 external-evaluator seam emits, replayed through the same
@@ -226,9 +226,8 @@ class EvaluatorHost:
     into the family coalescers, fans results back by link. One sweep
     thread owns every host-side ring word (the single-writer contract).
 
-    ``sweep()`` is public and synchronous so in-process tests (and the
-    split bench's parity probe) can drive the host deterministically
-    without the polling thread."""
+    ``sweep()`` is public and synchronous so in-process tests can drive
+    the host deterministically without the polling thread."""
 
     def __init__(
         self,
@@ -343,7 +342,7 @@ class EvaluatorHost:
         if not work:
             return 0
         if self._linger_s > 0.0 and len(self._links) > 1:
-            # Cross-process fusion pathology (SPLIT_r01): K frontends'
+            # Cross-process fusion pathology: K frontends'
             # waves land microseconds apart, so each sweep used to
             # catch ONE wave and pay its own pow2 bucket — 3×40-row
             # waves dispatched as three 64-slot buckets (192 slots)

@@ -306,8 +306,8 @@ class EvalCache:
         return st
 
     def clear(self) -> None:
-        """Drop all entries (stats and generation survive) — the bench's
-        cold-run reset."""
+        """Drop all entries (stats and generation survive): a cold-run
+        reset."""
         for s in range(self._n_stripes):
             with self._locks[s]:
                 self._stripes[s].clear()
@@ -783,7 +783,7 @@ def get_cache() -> Optional[EvalCache]:
 
 def reset_cache() -> None:
     """Tear down the process caches — BOTH families; a cold start is a
-    cold start (tests / bench cold runs). The registered collectors
+    cold start (tests). The registered collectors
     self-unregister on their next scrape."""
     global _global_cache, _global_az_cache, _global_bounds_cache
     with _global_lock:

@@ -1007,7 +1007,8 @@ class MctsPool:
             return sum(0 if s.done else 1 for s in self._searches.values())
 
     def counters(self) -> Dict:
-        """Tree- and dispatch-side stats for bench.py --mcts."""
+        """Tree- and dispatch-side stats (tests/test_mcts_plane.py reads
+        them; ``AzMctsEngine.pool_counters`` hands them on)."""
         out: Dict = {
             "visits": self._visits,
             "collisions": self._collisions,

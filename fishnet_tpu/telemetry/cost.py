@@ -29,7 +29,7 @@ Gate discipline: ``enabled()`` is one module-attribute read; when off,
 the driver computes no owner tables and the dispatch path takes no
 timestamps beyond what telemetry already takes. ``enable()`` is called
 by :func:`fishnet_tpu.telemetry.profiler.start` callers or directly by
-bench/tests; it registers the collector on first use.
+tests; it registers the collector on first use.
 
 Attribution is recorded ONCE per physical dispatch — the sync path
 records inline in ``_DispatchCoalescer._execute``; the async pipeline

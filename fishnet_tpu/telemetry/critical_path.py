@@ -6,7 +6,7 @@ Input is the flat span list the flight recorder produces
 under a trace context — ``trace_id``/``span_id``/``parent_id`` plus
 optional ``links`` (the fan-in convention, telemetry/tracing.py).
 
-Three consumers:
+Four consumers:
 
 * :func:`group_traces` / :func:`orphan_spans` — span-tree
   reconstruction and the completeness check (a healthy gated run has
@@ -19,8 +19,11 @@ Three consumers:
   each instant of a trace's wall window is charged to exactly one named
   component by a priority interval sweep, so the components sum to the
   window (residual = ``other``). ``report`` aggregates step traces
-  (root stage ``pack``) into the ``critical_path`` dict ``bench.py``
-  emits; ``batch_report`` does the per-request (acquire→submit) view.
+  (root stage ``pack``) into one ``critical_path`` dict;
+  ``batch_report`` does the per-request (acquire→submit) view.
+* :func:`dispatch_overlap` — how long one and two async dispatches
+  were in flight, from the ``dispatch_issue``/``dispatch_wait`` pairs
+  alone (needs no trace context).
 
 Attribution semantics, highest priority first:
 
@@ -48,7 +51,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
-#: Component names in the order bench.py reports them. ``reassignment``
+#: Component names in the order :func:`report` gives them. ``reassignment``
 #: is fleet-only: the dead time between a process dying with a unit in
 #: flight and another process re-acquiring it (telemetry/stitch.py
 #: synthesizes the span); single-process traces never contain it.
@@ -246,9 +249,10 @@ def report(
 ) -> dict:
     """Aggregate attribution over STEP traces (one per group eval
     microbatch): mean per-component milliseconds of steady-state
-    per-batch wall time — the ``critical_path`` dict in bench.py's
-    summary. ``skip_warmup`` drops the earliest 20% of traces (max 5):
-    first-dispatch compiles and probe traffic are not steady state."""
+    per-batch wall time, keyed ``<component>_ms`` (``device_compute``
+    as ``compute_ms``). ``skip_warmup`` drops the earliest 20% of traces
+    (max 5): first-dispatch compiles and probe traffic are not steady
+    state."""
     traces = [
         sp for sp in group_traces(spans).values() if _is_step_trace(sp)
     ]
@@ -309,3 +313,44 @@ def batch_report(spans: List[dict]) -> dict:
     for key in ("queue_wait_ms", "schedule_ms", "submit_ms", "wall_ms"):
         out[key] = round(out[key], 3)
     return out
+
+
+def dispatch_overlap(spans: List[dict]) -> dict:
+    """Proof of dispatch overlap from recorded spans alone, independent
+    of the service's live ``fishnet_dispatch_overlap_ratio`` gauge: pair
+    each ``dispatch_issue`` span (pack worker: staging through JAX
+    submission) with the ``dispatch_wait`` span (decode worker: blocked
+    materializing) of the same ``seq``; [issue.t, wait end] is that
+    dispatch's in-flight interval. Sweeping the intervals gives busy
+    (>= 1 in flight) and dual (>= 2) occupancy; dual / busy is the
+    overlap ratio. An issue with no wait, and a wait that ends before
+    its issue starts, are not dispatches in flight and are left out."""
+    issues, waits = {}, {}
+    for s in spans:
+        if s["stage"] == "dispatch_issue":
+            issues[s["seq"]] = s
+        elif s["stage"] == "dispatch_wait":
+            waits[s["seq"]] = s
+    edges = []
+    for seq, issue in issues.items():
+        wait = waits.get(seq)
+        if wait is None or _end(wait) <= issue["t"]:
+            continue
+        edges.append((issue["t"], 1))
+        edges.append((_end(wait), -1))
+    edges.sort()
+    busy = dual = 0.0
+    level, last_t = 0, 0.0
+    for t, step in edges:
+        if level > 0:
+            busy += t - last_t
+        if level > 1:
+            dual += t - last_t
+        level += step
+        last_t = t
+    return {
+        "dispatches_paired": len(edges) // 2,
+        "busy_s": round(busy, 3),
+        "dual_s": round(dual, 3),
+        "overlap_ratio": round(dual / busy, 4) if busy > 0 else 0.0,
+    }

@@ -2,7 +2,7 @@
 stdlib ``http.server`` thread.
 
 Opt-in: nothing starts unless ``--metrics-port`` (or the ``MetricsPort``
-ini key) is set, or bench exports ``FISHNET_METRICS_PORT``. The server
+ini key) is set. The server
 thread is independent of the asyncio event loop (R1: no blocking calls
 ride the loop) and mutates no state the serving path reads (R4: scrapes
 are read-only; the registry's scrape lock serializes them against

@@ -1,6 +1,6 @@
 """Continuous low-overhead sampling profiler + live stage-duration
 histograms (the profiling half of the observability plane; the other
-halves are telemetry/cost.py and telemetry/regress.py).
+half is telemetry/cost.py).
 
 Two signals, both answering "where do the milliseconds go?":
 
@@ -16,14 +16,13 @@ Two signals, both answering "where do the milliseconds go?":
 * **Stage durations.** A spans.STAGE_OBSERVER hook feeds every
   recorded span's duration into ``fishnet_stage_duration_seconds
   {stage}`` — pack/transport/compute/decode p99s become live series a
-  scrape (or the fleet aggregator) can watch continuously, instead of
-  bench-time-only attributions.
+  scrape (or the fleet aggregator) can watch continuously.
 
 Gate discipline (doc/observability.md): everything here is OFF by
 default. ``enabled()`` is one module-attribute read; the spans hook is
 one module-attribute read inside ``record()`` (itself already gated on
 ``telemetry.enabled()``). ``FISHNET_PROFILE=1`` arms the plane at
-``start_exporter`` time; tests and bench call :func:`start` directly.
+``start_exporter`` time; tests call :func:`start` directly.
 The sampler's own cost is self-accounted (``self_seconds``) so its
 overhead bound is a measured number, not a promise —
 tests/test_profiler.py gates it under 3% of wall.
@@ -128,7 +127,7 @@ class SamplingProfiler:
     """The sampling daemon + folded-stack aggregate.
 
     The sampler thread is the SINGLE writer of ``_stacks`` under
-    ``_lock``; readers (``/profile``, bench, the fleet console) take
+    ``_lock``; readers (``/profile``, the fleet console) take
     the same lock for a snapshot — sampling is ~Hz, so the lock is
     never hot. ``self_seconds`` accumulates the sampler's own walk
     time: its duty cycle (``self_seconds / wall``) IS the measured
@@ -202,7 +201,7 @@ class SamplingProfiler:
     def top_stacks(self, k: int = 10) -> List[dict]:
         """The k hottest folded stacks by sample count (= self+child
         time at the fold granularity), with each stack's share of all
-        samples — what bench summaries and the fleet console embed."""
+        samples — what the fleet console embeds."""
         with self._lock:
             items = sorted(
                 self._stacks.items(), key=lambda kv: -kv[1]

@@ -304,14 +304,14 @@ def _format_bound(b: float) -> str:
 
 
 #: The quantiles every histogram summary reports. A stable contract:
-#: bench.py, the fleet console, and the SLO engine all read these keys
+#: the fleet console and the SLO engine both read these keys
 #: instead of re-deriving percentiles their own way.
 SUMMARY_QUANTILES: Tuple[float, ...] = (0.5, 0.9, 0.99)
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
     """Nearest-rank percentile over RAW samples (q in [0, 100]); None
-    on no samples. The one definition bench.py and the fleet tooling
+    on no samples. The one definition the fleet tooling and the tests
     share — keep percentile math in one place."""
     if not values:
         return None
@@ -508,7 +508,7 @@ class MetricsRegistry:
         """JSON snapshot of the same families (the debug endpoint).
         Histogram families carry a ``quantiles`` summary (p50/p90/p99
         per label set, interpolated from the buckets) so consumers —
-        bench.py, the fleet console, any dashboard — read percentiles
+        the fleet console, any dashboard — read percentiles
         from one derivation instead of re-deriving from raw buckets."""
         metrics = {}
         for fam in self.collect():
@@ -564,5 +564,5 @@ def gauge_family(name: str, help: str, value: float, labels=None) -> MetricFamil
 
 
 #: Process-wide default registry; everything in-tree registers here so
-#: one exporter serves the whole process (client, bench, tests alike).
+#: one exporter serves the whole process (client and tests alike).
 REGISTRY = MetricsRegistry()

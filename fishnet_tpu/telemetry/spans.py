@@ -26,7 +26,8 @@ named machinery actually runs):
 * ``dispatch_wait``  — async decode worker blocked materializing that
   dispatch's values (fields: seq, width). [dispatch_issue.t,
   dispatch_wait.t + dur] brackets one dispatch's in-flight interval;
-  bench.py's overlap-ratio report is computed from these pairs.
+  ``critical_path.dispatch_overlap`` computes the overlap ratio from
+  these pairs.
 * ``mcts_collect`` — one MctsPool step's tree-side leaf collection:
   every live PUCT search's selection walks, run before the pooled
   microbatch rides the shared AZ dispatch plane (search/mcts.py;

@@ -35,8 +35,8 @@ aggregator scrapes into one coherent span set:
 The stitched output feeds three consumers: the fleet Perfetto export
 (``trace_export.chrome_trace`` renders one track group per process),
 the fleet critical-path report below (per-component attribution summing
-to wall, including the ``reassignment`` component), and bench.py's
-``fleet_observability`` summary section.
+to wall, including the ``reassignment`` component), and the fleet
+aggregator's ``/fleet`` document (telemetry/fleet.py).
 """
 
 from __future__ import annotations
@@ -328,8 +328,8 @@ def fleet_report(stitched_spans: List[dict]) -> dict:
     """Aggregate :func:`attribute_fleet_trace` over every stitched
     batch trace: mean per-component milliseconds (keys ``<comp>_ms``),
     overall coverage (attributed wall over total wall), and per-proc
-    attributed milliseconds summed across traces — the fleet-level
-    ``critical_path`` dict bench.py emits."""
+    attributed milliseconds summed across traces — the ``critical_path``
+    dict of the aggregator's ``/fleet`` document."""
     traces: Dict[str, List[dict]] = {}
     for s in stitched_spans:
         tid = s.get("trace_id")

@@ -150,7 +150,7 @@ class FakeLichess:
     #: Saturating load generator: keep at least this many unacquired
     #: system-queue analysis jobs in the queue at every acquire — the
     #: queue never drains, which is what "4x saturating load" means for
-    #: the overload bench. 0 disables (default: finite queue as before).
+    #: tests/test_overload.py. 0 disables (default: finite queue as before).
     auto_refill: int = 0
     #: With auto_refill active, every Nth synthesized job is a best-move
     #: job so the latency lane sees traffic during saturation. 0 = never.
@@ -170,7 +170,7 @@ class FakeLichess:
     #: runs several servers against one shared ledger: each server's
     #: counter restarts at 0, so identical prefixes would collide.
     work_id_prefix: str = "wk"
-    #: Cross-process exactly-once audit (cluster tests, bench --cluster).
+    #: Cross-process exactly-once audit (cluster tests).
     #: Always recorded — it is pure bookkeeping on existing handlers.
     fleet: FleetLedger = field(default_factory=FleetLedger)
     #: Server-side reassignment timeout (seconds): an acquired job not

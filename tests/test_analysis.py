@@ -7,6 +7,7 @@ any reintroduced R1-R9 violation fails CI here, not in review).
 from __future__ import annotations
 
 import json
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -393,6 +394,50 @@ def test_r8_registry_pointers_are_live():
 def test_r8_real_tree_contract_holds():
     findings = check_paths([PACKAGE], [EscapeHatchRule()])
     assert findings == [], "\n" + "\n".join(f.render() for f in findings)
+
+
+def test_r8_rows_a_deleted_root_script_kept_alive_read_as_dead():
+    """The text probe over root scripts keeps a row alive only while a
+    script that mentions the knob EXISTS: these six were kept by the
+    text of a root script the tree no longer has, and by nothing the
+    rule counts as a usage."""
+    rule = EscapeHatchRule()
+    gone = (
+        Knob("FISHNET_METRICS_PORT", "env", "unset", "doc/install.md"),
+        Knob("--control", "cli", "off", "doc/install.md"),
+        Knob("--depth", "cli", "off", "doc/install.md"),
+        Knob("--fleet-cache", "cli", "off", "doc/install.md"),
+        Knob("--split", "cli", "off", "doc/install.md"),
+        Knob("role=", "cli", "monolith", "doc/install.md"),
+    )
+    assert not {k.name for k in gone} & {k.name for k in KNOBS}
+    rule._knobs += gone
+    findings = check_paths([PACKAGE], [rule])
+    dead = re.compile(r"knob `(.+)` has no usage left in the tree")
+    assert sorted(dead.search(f.message).group(1) for f in findings) == (
+        sorted(k.name for k in gone)
+    ), [f.render() for f in findings]
+
+
+def test_one_record_of_measurements():
+    """PERF_LEDGER.jsonl (the driver's) and PERF.md are the record: no
+    per-round result file at the root, and no second benchmark script
+    imported from the package or its tests, so a second yardstick
+    cannot grow back one import at a time. Read from the tree on disk,
+    which is also what an unpacked archive has."""
+    records = [
+        p.name for p in REPO.iterdir()
+        if re.fullmatch(r"[A-Z_]+_r[0-9]+\.json", p.name)
+    ]
+    assert records == []
+    imports_bench = re.compile(r"^\s*(import bench\b|from bench\b)", re.M)
+    offenders = [
+        str(p.relative_to(REPO))
+        for top in (REPO / "tests", PACKAGE)
+        for p in sorted(top.rglob("*.py"))
+        if imports_bench.search(p.read_text(encoding="utf-8"))
+    ]
+    assert offenders == []
 
 
 # -- R9 -------------------------------------------------------------------

@@ -584,8 +584,9 @@ def test_overlap_smoke(monkeypatch):
     """With materialization slowed to transport-like latencies, the
     double buffer must actually overlap dispatches: overlap_ratio > 0
     live (counters + gauge inputs) and via the span flight recorder
-    (bench.py's overlap report)."""
+    (critical_path.dispatch_overlap)."""
     from fishnet_tpu import telemetry
+    from fishnet_tpu.telemetry.critical_path import dispatch_overlap
     from fishnet_tpu.telemetry.spans import RECORDER
 
     monkeypatch.setenv("FISHNET_COALESCE_WIDTH", "2")
@@ -617,9 +618,7 @@ def test_overlap_smoke(monkeypatch):
         stages = RECORDER.stages_seen()
         assert "dispatch_issue" in stages and "dispatch_wait" in stages
 
-        from bench import overlap_report_from_spans
-
-        report = overlap_report_from_spans()
+        report = dispatch_overlap(RECORDER.spans())
         assert report["dispatches_paired"] > 0
         assert report["overlap_ratio"] > 0
     finally:

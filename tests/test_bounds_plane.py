@@ -7,7 +7,7 @@ harvest/seed round-trips, the FISHNET_NO_BOUNDS / FISHNET_NO_SPECULATION
 escape hatches, speculative pad-row evals riding AZ dispatch padding
 without perturbing results, the speculation-budget control-plane rule,
 and the host linger window that fuses staggered cross-process waves
-into one pow2 bucket (the SPLIT_r01 3x40 -> 192-slot pathology)."""
+into one pow2 bucket (the 3x40 -> 192-slot pathology)."""
 
 import asyncio
 import sys
@@ -329,7 +329,7 @@ def test_service_bounds_harvest_then_seed(monkeypatch):
 def test_service_bounds_hatch_is_inert(monkeypatch):
     """FISHNET_NO_BOUNDS=1 (the conftest default): no bounds cache, no
     seed/harvest calls, and fresh-service runs stay deterministic —
-    the byte-for-byte arm the bench parity gate compares against."""
+    the byte-for-byte arm every parity test compares against."""
     assert eval_cache.bounds_disabled()
     assert eval_cache.get_bounds_cache() is None
     weights = NnueWeights.random(seed=3)
@@ -485,15 +485,15 @@ def test_speculation_controller_pin_unpin():
     assert plane.speculation_budget() == 8
 
 
-# -- host linger: cross-process pow2 fusion (SPLIT_r01) ----------------------
+# -- host linger: cross-process pow2 fusion ---------------------------------
 
 
 def test_host_linger_fuses_staggered_waves(tmp_path):
     """Three frontends' 40-row waves landing WITHIN one linger window
     (``linger_s`` here; FISHNET_HOST_LINGER_MS / --linger-ms in
     production) must dispatch as one fused 128-slot bucket (120 rows +
-    8 pads), not three 64-slot buckets (192 slots) — the SPLIT_r01
-    pow2 pathology."""
+    8 pads), not three 64-slot buckets (192 slots) — the pow2
+    pathology."""
     from fishnet_tpu.nnue.jax_eval import params_from_weights
     from fishnet_tpu.rpc.host import EvaluatorHost
 

@@ -68,10 +68,6 @@ def test_percentile_shared_definition():
     # Nearest-rank over (n-1)-scaled index: see registry.percentile.
     assert percentile(vals, 50) == 51
     assert percentile(vals, 99) == 99
-    # bench.py delegates to this definition.
-    import bench
-
-    assert bench._percentile([1.0, 2.0, 3.0], 50) == 2.0
 
 
 def test_quantile_from_buckets_interpolates_and_clamps():

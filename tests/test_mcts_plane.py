@@ -1,8 +1,7 @@
 """Shared-plane batched MCTS (ISSUE 14): plane-vs-legacy bit parity on
 every degradation rung, pre-wire AZ eval reuse, the preallocated step
 buffer, collision/terminal/multipv tree semantics, self-play parity
-plane-on vs plane-off, the tree-side telemetry families, and the
---mcts bench schema."""
+plane-on vs plane-off, and the tree-side telemetry families."""
 
 import numpy as np
 import pytest
@@ -319,19 +318,3 @@ def test_mcts_telemetry_families_and_collect_span():
     finally:
         telemetry.disable()
 
-
-# -- bench schema -----------------------------------------------------------
-
-
-def test_bench_mcts_summary_schema():
-    import bench
-
-    phase = {k: 0 for k in bench.SUMMARY_SCHEMA["mcts.phase"]}
-    summary = {k: 0 for k in bench.SUMMARY_SCHEMA["mcts"]}
-    summary["mode"] = "mcts"
-    for ph in ("baseline", "cold", "warm", "respawn"):
-        summary[ph] = dict(phase)
-    bench.validate_summary(summary)  # complete: must not raise
-    del summary["warm"]["collision_rate"]
-    with pytest.raises(ValueError):
-        bench.validate_summary(summary)

@@ -1,5 +1,5 @@
 """Pallas kernel parity tests (interpreter mode on CPU; the same kernel
-is exercised on real TPU hardware by bench/verify runs)."""
+is exercised on real TPU hardware by chip_smoke.py, Phase A)."""
 
 import numpy as np
 import pytest
@@ -732,7 +732,7 @@ def test_persistent_codes_without_table_raise_eagerly():
 def test_persistent_codes_without_table_poison_under_trace():
     """Traced misuse cannot raise: the structural guard must poison the
     affected entries (loudly constant) instead of returning plausible
-    unresolved partials — ADVICE r5 / ISSUE satellite."""
+    unresolved partials (the round-5 review's finding)."""
     import jax
 
     from fishnet_tpu.nnue import spec as _spec

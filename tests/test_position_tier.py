@@ -3,8 +3,8 @@ units (NNUE int32 + AZ fp16 round-trips, owner scoping, fingerprint
 isolation), the graceful attach-fallback ladder, torn-slot safety under
 real multi-process writers, SIGKILL-while-writing recovery (slot
 reclaim), and the two-process cross-process-hit smoke that ``make
-fleet-cache-smoke`` gates on. The full 3-process supervisor fleet with
-a mid-replay SIGKILL runs in ``bench.py --fleet-cache``."""
+fleet-cache-smoke`` gates on. No test runs the full 3-process
+supervisor fleet with a mid-replay SIGKILL (ROADMAP D14)."""
 
 import asyncio
 import json

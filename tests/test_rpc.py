@@ -8,8 +8,8 @@ supervisor's ``role=`` specs flip it per process), role federation
 across scraped frontend/evaluator processes (FISHNET_RPC_DIR wiring),
 and the two-process real smoke ``make rpc-smoke`` builds on: a
 subprocess evaluator host serving a frontend ``RemoteBackend`` with
-analyses bit-identical to a monolith. The full 3-frontend fleet with
-SIGKILLs runs in ``bench.py --split``."""
+analyses bit-identical to a monolith. No test runs the full
+3-frontend fleet with SIGKILLs (ROADMAP D14)."""
 
 import os
 import subprocess
@@ -217,7 +217,8 @@ def test_evaluator_restart_resubmits_inflight_ticket(tmp_path):
 def test_rpc_detach_fault_site(tmp_path):
     """faults grammar ``rpc.detach``: the host drops one live link on
     the matched sweep (reason="fault", file kept) and re-attaches it on
-    the next — the deterministic chaos hook bench.py --split scripts."""
+    the next — the deterministic chaos hook a split fleet's fault
+    plan scripts."""
     front = rings.create_frontend_link(str(tmp_path), name="fa.ring")
     host = EvaluatorHost(rpc_dir=str(tmp_path))
     before = rings.stats()
@@ -355,9 +356,8 @@ def test_two_process_split_bit_identical_analyses(tmp_path, monkeypatch):
     """THE split-plane assertion: a frontend RemoteBackend served by a
     REAL subprocess evaluator host (different pid, own device context)
     must produce bit-identical analyses to an in-process monolith over
-    the same weights. (The in-process twin of this parity — plus the
-    3-frontend fused-fill and SIGKILL ledger gates — runs in bench.py
-    --split.)"""
+    the same weights. (No test runs the 3-frontend fused-fill and
+    SIGKILL ledger gates: ROADMAP D14.)"""
     from fishnet_tpu.nnue.weights import NnueWeights
     from fishnet_tpu.search.service import SearchService
 

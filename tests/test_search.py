@@ -346,7 +346,7 @@ def test_material_correlation_probe():
     """nnue_material_correlated (cpp/src/nnue.cpp) gates the SEE
     heuristics whose premise is a material-tracking eval: it must accept
     a material net and reject a random one (random nets drive the test
-    and bench suites; pruning their searches by material logic was
+    suites; pruning their searches by material logic was
     measured to inflate the tree ~35%)."""
     import ctypes
     import tempfile
@@ -371,7 +371,7 @@ def test_material_correlation_probe():
                 lib.fc_nnue_free(net)
 
     assert probe(material_net())
-    assert not probe(NnueWeights.random(seed=7))  # the bench net
+    assert not probe(NnueWeights.random(seed=7))  # the random net
     assert not probe(NnueWeights.random(seed=21))  # the parity-suite net
 
 

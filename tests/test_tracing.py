@@ -6,7 +6,7 @@ and end-to-end through gated smokes, sync (FISHNET_NO_ASYNC=1) and
 async — plus the critical-path analyzer (span-tree reconstruction,
 orphan detection, wall-time attribution summing to the window), the
 Chrome/Perfetto exporter with cross-thread flow arrows, and the
-bench.py summary-schema contract. `make trace-smoke` runs this file."""
+critical-path report's key contract. `make trace-smoke` runs this file."""
 
 import json
 import os
@@ -276,6 +276,69 @@ def test_critical_path_report_aggregates_step_traces():
     # Empty input: zeroed shape, never a crash.
     empty = cp.report([])
     assert empty["traces"] == 0 and empty["wall_ms"] == 0.0
+
+
+@pytest.mark.parametrize(
+    "recorded",
+    [lambda: SpanRecorder().spans(), _synthetic_step_trace],
+    ids=["empty_recorder", "one_trace"],
+)
+def test_critical_path_report_key_contract(recorded):
+    """One key a component (``device_compute`` reports as
+    ``compute_ms``) plus the three totals, whatever was recorded, so
+    a reader may index the dict without ``.get``."""
+    spans = recorded()
+    rep = cp.report(spans, fixed_transport_ms=5.0, skip_warmup=False)
+    per_component = {
+        "compute_ms" if c == "device_compute" else f"{c}_ms"
+        for c in cp.COMPONENTS
+    }
+    assert set(rep) == per_component | {"wall_ms", "coverage", "traces"}
+    assert rep["traces"] == (1 if spans else 0)
+
+
+def _dispatch(seq, issued, done, wait_from=None):
+    """One dispatch's span pair: issued at ``issued``, materialized at
+    ``done`` (the wait span starts at ``wait_from``, default half-way)."""
+    wait_from = (issued + done) / 2 if wait_from is None else wait_from
+    return [
+        {"stage": "dispatch_issue", "t": issued, "dur_ms": 1.0, "seq": seq},
+        {"stage": "dispatch_wait", "t": wait_from,
+         "dur_ms": (done - wait_from) * 1e3, "seq": seq},
+    ]
+
+
+@pytest.mark.parametrize(
+    "spans, paired, busy_s, dual_s, ratio",
+    [
+        # Two dispatches one after the other: never two in flight.
+        (_dispatch(0, 10.0, 10.1) + _dispatch(1, 10.2, 10.3),
+         2, 0.2, 0.0, 0.0),
+        # Two coincident dispatches: two in flight the whole time.
+        (_dispatch(0, 10.0, 10.4) + _dispatch(1, 10.0, 10.4),
+         2, 0.4, 0.4, 1.0),
+        # The second issued half-way through the first.
+        (_dispatch(0, 10.0, 10.2) + _dispatch(1, 10.1, 10.3),
+         2, 0.3, 0.1, 0.3333),
+        # An issue whose wait was never recorded (ring overwrite, a
+        # crash between the two) is not a dispatch interval.
+        (_dispatch(0, 10.0, 10.2) + _dispatch(1, 10.1, 10.3)[:1],
+         1, 0.2, 0.0, 0.0),
+        # A wait that ends before its issue starts (a reused seq after
+        # a service rebuild) is dropped, not swept as negative time.
+        (_dispatch(0, 10.0, 10.2) + _dispatch(1, 10.1, 9.9, wait_from=9.8),
+         1, 0.2, 0.0, 0.0),
+    ],
+    ids=["disjoint", "coincident", "half", "unpaired_issue", "wait_before_issue"],
+)
+def test_dispatch_overlap_from_spans(spans, paired, busy_s, dual_s, ratio):
+    other = [{"stage": "pack", "t": 10.0, "dur_ms": 500.0}]  # ignored
+    assert cp.dispatch_overlap(spans + other) == {
+        "dispatches_paired": paired,
+        "busy_s": busy_s,
+        "dual_s": dual_s,
+        "overlap_ratio": ratio,
+    }
 
 
 def test_critical_path_batch_report():
@@ -557,44 +620,9 @@ def test_trace_smoke_decode_queue_counter(monkeypatch):
     assert counters["decode_queue"] == 0
 
 
-# -- bench summary schema -----------------------------------------------------
+# -- critical-path report over the recorder ----------------------------------
 
 
-def _fake_summary():
-    from bench import SUMMARY_SCHEMA
-
-    s = {k: 0 for k in SUMMARY_SCHEMA["top"]}
-    s["traffic"] = {
-        "overlap": {k: 0 for k in SUMMARY_SCHEMA["traffic.overlap"]}
-    }
-    s["critical_path"] = {k: 0 for k in SUMMARY_SCHEMA["critical_path"]}
-    return s
-
-
-def test_bench_summary_schema_export():
-    """The single stdout JSON line's schema is a pinned contract: both
-    the overlap report and the critical-path attribution ride it, and
-    emit_summary refuses a summary missing any promised key."""
-    from bench import validate_summary
-
-    validate_summary(_fake_summary())
-    for missing in ("critical_path", "dispatch_overlap_ratio"):
-        broken = _fake_summary()
-        del broken[missing]
-        with pytest.raises(ValueError, match=missing):
-            validate_summary(broken)
-    nested = _fake_summary()
-    del nested["critical_path"]["compute_ms"]
-    with pytest.raises(ValueError, match="critical_path.compute_ms"):
-        validate_summary(nested)
-    overlap_broken = _fake_summary()
-    del overlap_broken["traffic"]["overlap"]["overlap_ratio"]
-    with pytest.raises(ValueError, match="overlap_ratio"):
-        validate_summary(overlap_broken)
-
-
-def test_bench_critical_path_report_fn(tel_enabled):
-    from bench import critical_path_report_from_spans
-
-    rep = critical_path_report_from_spans(fixed_transport_ms=5.0)
+def test_critical_path_report_over_recorder(tel_enabled):
+    rep = cp.report(RECORDER.spans(), fixed_transport_ms=5.0)
     assert set(rep) >= {"wall_ms", "coverage", "traces", "compute_ms"}
