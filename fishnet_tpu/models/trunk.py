@@ -23,8 +23,17 @@ Layer equations (``n = RMSNorm(x; g, eps)``, statistics in float32)::
              x' = h + sum_j w_j E_{e_j}(n2)        (dropless: no capacity)
     out      RMSNorm(x'; g_f) -> [B, 8, 8, hidden] -> the heads of models/az.py
 
-Mechanism: the (token, slot) pairs are sorted by expert (stable), each
-expert's rows form one group of a grouped matrix product (megablox
+Mechanism, attention: the four projections are XLA's; everything
+between them (qk-norm, RoPE, the 64 x 64 scores, softmax, mix) is one
+Pallas kernel pair (``ops/board_attention.py``: ``board_attention`` and
+its gradient ``board_attention_grad``) that takes q, k, v as the
+projections write them, ``[B, 64, heads * head_dim]``, and works on one
+head of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
+view and no scores reach HBM, and the gradient recomputes the softmax
+from the same inputs.
+
+Mechanism, experts: the (token, slot) pairs are sorted by expert
+(stable), each expert's rows form one group of a grouped matrix product (megablox
 ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
 the CPU), the rows are put back in token order and each token's slots
 summed under their weights. The rows move through two more Pallas
@@ -52,15 +61,16 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
+from fishnet_tpu.ops.board_attention import SQUARES, board_attention
 from fishnet_tpu.ops.row_move import row_view, rows_back, rows_out
 
 Params = Dict[str, jax.Array]
 
-SQUARES = 64
 _INIT_STD = 0.02
 
 
@@ -123,37 +133,33 @@ def _matmul(x: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
 
 
-def _rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half RoPE over all of the last axis; position = square index.
-    ``x`` is [B, 64, heads, head_dim], float32."""
-    half = x.shape[-1] // 2
-    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
-    angle = np.arange(SQUARES, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.concatenate([np.cos(angle)] * 2, axis=-1), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.concatenate([np.sin(angle)] * 2, axis=-1), jnp.float32)[None, :, None, :]
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos + rotated * sin
+def _row_major(x: jax.Array) -> jax.Array:
+    """``x`` and its cotangent pinned to the row-major layout. The
+    residual stream starts with it: left to itself XLA lays
+    ``[tokens, hidden]`` out tokens-minor or square-major (what the
+    embedding's 19-deep product and the heads' convolutions like), and
+    every projection next to a kernel, whose operands are row-major by
+    contract, then re-tiles its operand or its result inside the
+    product (4.2 ms a step at the published sizes, PERF.md section 6,
+    PR 32). One pin is enough: the layout follows the stream."""
+    return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
 def _attention(x: jax.Array, p: Params, cfg: TrunkConfig) -> jax.Array:
-    """[B, 64, hidden] float32 -> the attention branch's output, same shape."""
-    b = x.shape[0]
+    """[tokens, hidden] float32, 64 tokens a board -> the attention
+    branch's output, same shape. The projections are XLA's; everything
+    between them is ``board_attention``."""
     n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-    split = lambda y: y.reshape(b, SQUARES, cfg.heads, cfg.head_dim)
-    q, k, v = (split(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
-    q = _rope(_rms_norm(q, p["q_norm"], cfg.rms_eps), cfg.rope_theta)
-    k = _rope(_rms_norm(k, p["k_norm"], cfg.rms_eps), cfg.rope_theta)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32) / np.sqrt(cfg.head_dim)
-    probs = jax.nn.softmax(scores, axis=-1)
-    mixed = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-    return _matmul(mixed.reshape(b, SQUARES, cfg.heads * cfg.head_dim), p["wo"])
+    by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
+    q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
+    mixed = board_attention(q, k, v.astype(jnp.bfloat16), p["q_norm"], p["k_norm"],
+                            cfg.rope_theta, cfg.rms_eps, _interpret())
+    return _matmul(mixed.reshape(x.shape[0], -1), p["wo"])
 
 
 def _interpret() -> bool:
-    """The trunk's Pallas kernels (the grouped product and the two row
-    moves) are one path everywhere: compiled by Mosaic on a TPU, run by
+    """The trunk's Pallas kernels (the attention core, the grouped product
+    and the two row moves) are one path everywhere: compiled by Mosaic on a TPU, run by
     the Pallas interpreter elsewhere (the CPU of the tests), never
     another path."""
     return jax.default_backend() != "tpu"
@@ -282,7 +288,7 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     b = planes.shape[0]
     # Scope names are a contract (doc/observability.md "Training and compilation").
     with jax.named_scope("embed"):
-        x = _matmul(planes.reshape(b, SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
+        x = _row_major(_matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"])
     counters = []
     for i in range(cfg.layers):
         layer = {name: params[name][i] for name in
@@ -294,10 +300,10 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         with jax.named_scope(f"{name}.attention"):
             x = x + _attention(x, layer, cfg)
         with jax.named_scope(f"{name}.router"):
-            n2 = _rms_norm(x, layer["moe_norm"], cfg.rms_eps).reshape(b * SQUARES, cfg.hidden)
+            n2 = _rms_norm(x, layer["moe_norm"], cfg.rms_eps)
         mixed, layer_counters = _experts(n2, layer, cfg, name)
         with jax.named_scope(f"{name}.combine"):
-            x = x + mixed.reshape(b, SQUARES, cfg.hidden)
+            x = x + mixed
         counters.append(layer_counters)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)

@@ -1,0 +1,150 @@
+"""The attention core's two Pallas kernels (ops/board_attention.py) alone,
+under the Pallas interpreter, against the formula written out below in
+float32.
+
+Tolerances come from counting roundings, not from the readings. The
+kernels round to bfloat16 (relative error at most 2^-8 an element: half
+a unit in the last of 8 bits) where the configuration says products take
+bfloat16 operands: on the way to ``mixed`` that is q, k, the
+probabilities and the result, four roundings; on the way to a gradient
+of q, k or a gain it is q, k, the probabilities' cotangent, the scores'
+cotangent, the other operand of the product and the product's result,
+six (v's gradient passes four). Scores here are O(1) (normed rows, gains
+near 1), so the softmax does not amplify them, and independent roundings
+add in quadrature, so the relative L2 error stays under roundings x 2^-8
+with room (the readings, CPU: 0.0033-0.0037 forward, 0.0036-0.0061
+gradients). Three wrong formulas show that the tolerances would catch a
+missing piece: they miss by 7x to 57x (no scale 35-52x, no RoPE 31-57x,
+no gain 7-10x).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fishnet_tpu.ops.board_attention import board_attention, rope_tables
+
+THETA, EPS = 50000.0, 1e-5
+ROUNDING = 2.0 ** -8
+FORWARD_TOL, GRADIENT_TOL = 4 * ROUNDING, 6 * ROUNDING
+
+
+def rope(x, head_dim, inverse=False):
+    """Rotate-half RoPE of [.., 64, heads, head_dim], position = square."""
+    half = head_dim // 2
+    angle = np.arange(64)[:, None] / THETA ** (np.arange(half) / half)[None, :]
+    cos, sin = (jnp.asarray(np.concatenate([f(angle)] * 2, -1), jnp.float32)[:, None, :] for f in (np.cos, np.sin))
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    return x * cos + turned * (-sin if inverse else sin)
+
+
+def plain(q, k, v, g_q, g_k, wrong=""):
+    """The core from the layer equations, float32 throughout."""
+    boards, head_dim = q.shape[0], g_q.shape[0]
+    split = lambda y: y.astype(jnp.float32).reshape(boards, 64, -1, head_dim)
+    norm = lambda x, g: x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * (1.0 if wrong == "no_gain" else g)
+    turn = (lambda x: x) if wrong == "no_rope" else (lambda x: rope(x, head_dim))
+    q, k, v = turn(norm(split(q), g_q)), turn(norm(split(k), g_k)), split(v)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / (1.0 if wrong == "no_scale" else np.sqrt(head_dim))
+    mixed = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v, precision="highest")
+    return mixed.reshape(boards, 64, -1)
+
+
+def inputs(boards, heads, head_dim, seed=0):
+    rng = np.random.default_rng(seed)
+    shape = (boards, 64, heads * head_dim)
+    gain = lambda: jnp.asarray(1.0 + 0.1 * rng.standard_normal(head_dim), jnp.float32)
+    return (jnp.asarray(1.5 * rng.standard_normal(shape), jnp.float32), jnp.asarray(1.5 * rng.standard_normal(shape), jnp.float32),
+            jnp.asarray(rng.standard_normal(shape), jnp.bfloat16), gain(), gain(),
+            jnp.asarray(rng.standard_normal(shape), jnp.bfloat16))  # the last: the cotangent of ``mixed``
+
+
+def kernel(q, k, v, g_q, g_k):
+    return board_attention(q, k, v, g_q, g_k, THETA, EPS, True)
+
+
+def value_and_gradients(f, q, k, v, g_q, g_k, cotangent):
+    out, pull = jax.vjp(f, q, k, v, g_q, g_k)
+    return (out, *pull(cotangent.astype(out.dtype)))
+
+
+def rel(got, want):
+    got, want = jnp.asarray(got, jnp.float32), jnp.asarray(want, jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+# (heads, head_dim, boards): 32 boards are two grid steps of the block's 16; 5 and 6 are batches
+# the block does not divide (1 and 2 boards a step); head_dim 128 is the published lane block.
+CASES = [(2, 16, 32), (2, 16, 5), (4, 16, 6), (2, 128, 4)]
+OUTPUTS = ["mixed", "d_q", "d_k", "d_v", "d_q_norm", "d_k_norm"]
+
+
+@functools.lru_cache(maxsize=None)
+def both(heads, head_dim, boards):
+    args = inputs(boards, heads, head_dim)
+    return (jax.jit(functools.partial(value_and_gradients, kernel))(*args),
+            jax.jit(functools.partial(value_and_gradients, plain))(*args))
+
+
+@pytest.mark.parametrize("output", OUTPUTS)
+@pytest.mark.parametrize("heads,head_dim,boards", CASES)
+def test_kernels_match_the_plain_formula(heads, head_dim, boards, output):
+    got, want = (side[OUTPUTS.index(output)] for side in both(heads, head_dim, boards))
+    assert got.shape == want.shape
+    assert got.dtype == (jnp.float32 if output in ("d_q", "d_k", "d_q_norm", "d_k_norm") else jnp.bfloat16)
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL)
+
+
+@pytest.mark.parametrize("wrong", ["no_scale", "no_rope", "no_gain"])
+def test_the_tolerances_catch_a_missing_piece(wrong):
+    """Each wrong formula misses at least one forward or gradient
+    tolerance by more than 1.5x."""
+    args = inputs(6, 2, 16)
+    got = jax.jit(functools.partial(value_and_gradients, kernel))(*args)
+    want = jax.jit(functools.partial(value_and_gradients, functools.partial(plain, wrong=wrong)))(*args)
+    misses = [rel(g, w) / (FORWARD_TOL if i == 0 else GRADIENT_TOL) for i, (g, w) in enumerate(zip(got, want))]
+    assert max(misses) > 1.5, misses
+
+
+def test_a_board_of_zero_queries_mixes_the_mean_of_the_values():
+    """All scores 0: the softmax is uniform, 1/64 exactly, and ``mixed``
+    is every head's mean value over the squares, rounded once."""
+    q, k, v, g_q, g_k, cotangent = inputs(3, 2, 16, seed=1)
+    q = q.at[1].set(0.0)
+    out, d_q, *_ = jax.jit(functools.partial(value_and_gradients, kernel))(q, k, v, g_q, g_k, cotangent)
+    mean = jnp.mean(v[1].astype(jnp.float32), axis=0, keepdims=True)
+    assert np.allclose(out[1].astype(jnp.float32), jnp.broadcast_to(mean, (64, 32)), rtol=ROUNDING, atol=1e-6)
+    assert bool(jnp.all(jnp.isfinite(d_q)))  # rsqrt(eps) is large, not infinite
+    assert rel(out[0], plain(q, k, v, g_q, g_k)[0]) < FORWARD_TOL  # the boards beside it are untouched
+
+
+def test_a_dominant_key_hands_every_query_its_value():
+    """Queries and one key that all point the same way after RoPE (built
+    by rotating a unit vector back to each square), gains 3: that key's
+    score is 9 sqrt(head_dim) = 36 for every query, the others' are
+    ~9 N(0, 1), so ``mixed`` is that key's value row on every square."""
+    heads, head_dim, key_square = 2, 16, 37
+    q, k, v, _, _, cotangent = inputs(2, heads, head_dim, seed=2)
+    rng = np.random.default_rng(3)
+    direction = jnp.asarray(rng.standard_normal((1, heads, head_dim)), jnp.float32)
+    aligned = rope(jnp.broadcast_to(direction, (64, heads, head_dim)), head_dim, inverse=True).reshape(64, -1)
+    q = q.at[0].set(aligned)
+    k = k.at[0, key_square].set(aligned[key_square])
+    gains = jnp.full((head_dim,), 3.0, jnp.float32)
+    out = jax.jit(kernel)(q, k, v, gains, gains)
+    want = jnp.broadcast_to(v[0, key_square].astype(jnp.float32), (64, heads * head_dim))
+    assert np.allclose(out[0].astype(jnp.float32), want, rtol=ROUNDING, atol=1e-3)
+    assert rel(out, plain(q, k, v, gains, gains)) < FORWARD_TOL
+
+
+def test_rope_tables_fold_the_sign_of_rotate_half_into_the_sine():
+    cos, sin = rope_tables(THETA, 16)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((64, 1, 16)), jnp.float32)
+    want = rope(x, 16)[:, 0]
+    assert cos.dtype == sin.dtype == np.float32 and cos.shape == sin.shape == (64, 16)
+    assert np.allclose(x[:, 0] * cos + jnp.roll(x[:, 0], 8, axis=-1) * sin, want, atol=1e-6)

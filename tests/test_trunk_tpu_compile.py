@@ -1,5 +1,5 @@
-"""The trunk's grouped product compiled for a described TPU v5e at the
-published widths: what Mosaic refuses (a tile that does not fit VMEM, a
+"""The trunk's Pallas kernels (grouped product, row moves, attention core)
+compiled for a described TPU v5e at the published widths: what Mosaic refuses (a tile that does not fit VMEM, a
 slice off the tiling) fails here, on the CPU, at no chip time. Nothing
 runs: a compile says nothing about results or times.
 
@@ -93,3 +93,30 @@ def test_experts_step_at_published_widths_gathers_no_slot_rows(one_chip, compile
     slot_gathers = [line for line in text.splitlines() if re.search(r"= bf16\[262144,2048\]\S* gather\(", line)]
     assert not slot_gathers, slot_gathers[:2]
     assert text.count("moe_rows_out") >= 2 and text.count("moe_rows_back") >= 2
+
+
+BOARDS = 512  # moe_trunk_train_b512
+
+
+def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of ``_attention`` as the cell runs it, 16 heads x
+    128 over 512 boards' tokens: the core is the two Pallas kernels, the
+    64 x 64 scores never exist as an array, and no copy changes
+    ``[512, 64, 2048]`` into a ``[.., 16, 128]`` view."""
+    import re
+
+    cfg = trunk.TrunkConfig()
+    inner = cfg.heads * cfg.head_dim
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {"attn_norm": sds((HIDDEN,), jnp.float32), "q_norm": sds((cfg.head_dim,), jnp.float32), "k_norm": sds((cfg.head_dim,), jnp.float32),
+             "wq": sds((HIDDEN, inner), jnp.float32), "wk": sds((HIDDEN, inner), jnp.float32),
+             "wv": sds((HIDDEN, inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
+
+    def loss(x, p):
+        return jnp.sum(trunk._attention(x, p, cfg))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "board_attention" in text and "board_attention_grad" in text
+    per_head = [line for line in text.splitlines() if re.search(r"\[512,16,64,64\]|\[512,64,16,128\]|\[512,16,64,128\]", line)]
+    assert not per_head, per_head[:2]
