@@ -33,6 +33,7 @@ from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.models.trunk import (
     TrunkConfig,
     init_trunk_params,
+    trunk_buffer_shapes,
     trunk_checkpoint,
     trunk_config_from_params,
     trunk_forward_counted,
@@ -86,6 +87,13 @@ def init_az_params(rng: jax.Array, cfg: NetConfig = AzConfig()) -> Params:
         params[f"res{i}_w2"] = conv(keys[5 + 2 * i], c, c)
         params[f"res{i}_b2"] = jnp.zeros((c,), jnp.float32)
     return params
+
+
+def init_az_buffers(cfg: NetConfig = AzConfig()) -> Params:
+    """What a training state holds beside the parameters, outside the
+    optimizer: a balancing trunk's zero ``expert_bias``, else nothing."""
+    shapes = trunk_buffer_shapes(cfg) if isinstance(cfg, TrunkConfig) else {}
+    return {name: jnp.zeros(shape, jnp.float32) for name, shape in shapes.items()}
 
 
 def az_forward(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):

@@ -4,13 +4,16 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. The block is the published one of
-LLaDA-MoE-7B-A1B (inclusionAI, config.json: hidden 2048, 16 heads x 128,
-qk-norm, RoPE theta 50000, 64 experts top-8, softmax router, expert
-width 1024, SiLU, RMSNorm eps 1e-5), a mask predictor with no causal
-mask, so running it over a board removes nothing.
+tower's own policy and value heads. ``TrunkConfig`` describes two
+published blocks as one code path at different values; neither has a
+causal mask, and a board is far shorter than either's window, so running
+them over a board removes nothing.
 
-Layer equations (``n = RMSNorm(x; g, eps)``, statistics in float32)::
+The first block is LLaDA-MoE-7B-A1B's (inclusionAI, config.json: hidden
+2048, 16 heads x 128, qk-norm, RoPE theta 50000, 64 experts top-8,
+softmax router, expert width 1024, SiLU, RMSNorm eps 1e-5): every layer
+the same. Layer equations (``n = RMSNorm(x; g, eps)``, statistics in
+float32)::
 
     tokens   t = planes.reshape(B, 64, 19);  x = t @ W_in + b_in
     attention q, k, v = n1 @ W_q, n1 @ W_k, n1 @ W_v   (heads x head_dim)
@@ -23,12 +26,48 @@ Layer equations (``n = RMSNorm(x; g, eps)``, statistics in float32)::
              x' = h + sum_j w_j E_{e_j}(n2)        (dropless: no capacity)
     out      RMSNorm(x'; g_f) -> [B, 8, 8, hidden] -> the heads of models/az.py
 
-Mechanism, attention: the four projections are XLA's; everything
-between them (qk-norm, RoPE, the 64 x 64 scores, softmax, mix) is one
-Pallas kernel pair (``ops/board_attention.py``: ``board_attention`` and
-its gradient ``board_attention_grad``) that takes q, k, v as the
-projections write them, ``[B, 64, heads * head_dim]``, and works on one
-head of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
+The second block is Trinity-Mini's (arcee-ai, config.json, ``model_type``
+afmoe: hidden 2048, 32 query heads over 4 key-value heads of 128, leading
+dense layers of width 6144, then 128 routed experts of width 1024, top-8,
+sigmoid scores, ``route_norm``, ``route_scale`` 2.826, one shared expert,
+three sliding-window layers to one full-attention layer); what its
+config.json does not say is the public afmoe modelling code's, listed
+under ``assumed`` in ``benchmark/configs/trinity-mini-trunk-train.json``.
+``N`` is RMSNorm, ``n`` the normed input, T tokens, 64 a board::
+
+    embed     x = (t W_in + b_in) * embed_scale                 (sqrt(hidden): mup_enabled)
+    layer     a = x + N_post_attn( Attn( N_in(x) ) )
+              y = a + N_post_mlp( FFN( N_pre_mlp(a) ) )         (four norms a layer)
+    Attn      q = n W_q [heads x head_dim];  k = n W_k, v = n W_v [kv_heads x head_dim];  g = n W_gate [heads x head_dim]
+              q, k <- RMSNorm over head_dim, one gain each
+              sliding layers: RoPE on the square index; full layers (``nope_layers``): none
+              query head h attends key-value head h // (heads // kv_heads), within a board, scores / sqrt(head_dim)
+              sliding layers mask |i - j| < sliding_window: all true at 64 tokens, so no mask is applied
+              and a window under 64 is refused; softmax in float32
+              out = ( (P v) * sigmoid(g) ) W_o
+    FFN dense (silu(n W_g) * (n W_u)) W_d, width dense_width     (the leading ``dense_layers``)
+    FFN MoE   s = sigmoid(n W_r) over all the experts, float32
+              chosen = top-k of (s + b), b = expert_bias: a buffer, no gradient through b or the choice
+              w_j = route_scale * s[e_j] / (sum_j s[e_j] + 1e-20)        (over all k chosen, held or not)
+              out = Shared(n) + sum over chosen e_j HELD HERE of w_j E_{e_j}(n);  Shared, E_e: SiLU-gated
+    balance   after a step, a routed layer's c_e = slots routed to expert e (all of them, held or not):
+              d = balance_rate * sign(mean(c) - c);  b <- b + d - mean(d)      (``train/az_trainer.py``)
+    out       N_final(y) -> the heads
+
+``held_experts = (first, count)`` tells the expert layer which experts
+it holds, as one chip of an expert-parallel deployment does: it routes
+over all ``experts``, computes the part of the result that its own give
+for the tokens routed to them, and leaves the rest out; the shares of
+all chips and the shared expert once add up to the whole layer
+(``tests/test_moe_trunk.py``). Nothing stands in for the absent chips.
+
+Mechanism, attention: the projections are XLA's; everything between
+them (qk-norm, RoPE, the 64 x 64 scores, softmax, mix) is one Pallas
+kernel pair (``ops/board_attention.py``: ``board_attention`` and its
+gradient ``board_attention_grad``) that takes q, k, v as the projections
+write them, ``[B, 64, heads * head_dim]`` and ``[B, 64, kv_heads *
+head_dim]``, and works on one key-value head and its group of query
+heads of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
 view and no scores reach HBM, and the gradient recomputes the softmax
 from the same inputs.
 
@@ -36,7 +75,12 @@ Mechanism, experts: the (token, slot) pairs are sorted by expert
 (stable), each expert's rows form one group of a grouped matrix product (megablox
 ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
 the CPU), the rows are put back in token order and each token's slots
-summed under their weights. The rows move through two more Pallas
+summed under their weights. A share's weights are ``[count, hidden,
+width]`` and ``gmm`` is told the first group it holds (its
+``group_offset``): it visits the held groups' rows alone and writes
+zeros for the others', forward and in both gradients, so a slot of an
+absent expert adds nothing and every held expert is dropless. The rows
+move through two more Pallas
 kernels (``ops/row_move.py``): wherever a row is addressed singly it
 lives as ``[rows, hidden // 128, 128]``, one contiguous 4 KiB tile at
 hidden 2048, and one DMA moves it; the sorted side that the grouped
@@ -45,18 +89,24 @@ slots) and ``rows_back`` (sorted slots to token order) are each other's
 transpose, so ``_dispatch`` and ``_combine`` pair them as forward and
 gradient and nothing is ever scatter-added. Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
-tower's; norms, both softmaxes, the router and the combine weights are
-float32.
+tower's; norms, the softmaxes, the router's scores, choice and combine
+weights and both sigmoid gates are float32.
 
 Parameters are one flat dict (the ``.npz`` checkpoint format), the
-layers stacked on a leading axis: ``router_w [L, hidden, experts]``.
+layers of a kind stacked on a leading axis: ``wq [layers, ..]``,
+``dense_gate [dense_layers, ..]``, ``router_w [routed layers, hidden,
+experts]``, ``experts_gate [routed layers, held, hidden, width]``.
+``expert_bias [routed layers, experts]`` is a buffer beside them: the
+forward reads it from the same dict when it is there, and no gradient
+reaches it.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,24 +137,79 @@ class TrunkConfig:
     rms_eps: float = 1e-5
     value_hidden: int = 256
     policy_planes: int = 73
+    # What the second block adds (module docstring); the defaults are the first block's.
+    kv_heads: Optional[int] = None  # None: one a query head
+    nope_layers: Tuple[int, ...] = ()  # the full-attention layers: no RoPE
+    sliding_window: Optional[int] = None  # of the other layers; masks nothing on a board
+    gated_attention: bool = False
+    post_norms: bool = False
+    embed_scale: float = 1.0
+    dense_layers: int = 0  # leading layers whose feed-forward is dense
+    dense_width: int = 0
+    shared_width: int = 0  # 0: no shared expert
+    router_score: str = "softmax"  # or "sigmoid"
+    route_norm: bool = False
+    route_scale: float = 1.0
+    held_experts: Optional[Tuple[int, int]] = None  # (first, count); None: all of them
+    balance_rate: float = 0.0  # over 0: the routed layers choose on score + expert_bias
+    recompute_experts: bool = False  # training keeps nothing of slot size for the backward pass (``_routed_recomputed``)
+
+    def __post_init__(self) -> None:
+        first, count = self.held
+        wrong = {
+            f"sliding_window {self.sliding_window} is under the {SQUARES} tokens of a board and the program applies no mask":
+                self.sliding_window is not None and self.sliding_window < SQUARES,
+            f"{self.heads} query heads do not divide over {self.kv_heads} key-value heads": self.heads % (self.kv_heads or self.heads),
+            f"router_score {self.router_score!r} is neither softmax nor sigmoid": self.router_score not in ("softmax", "sigmoid"),
+            f"held_experts {self.held_experts} is not a range of the {self.experts} experts":
+                not (0 <= first and 1 <= count and first + count <= self.experts),
+            f"{self.dense_layers} dense layers of {self.layers} leave no routed layer, or have no width":
+                not 0 <= self.dense_layers < self.layers or (self.dense_layers > 0) != (self.dense_width > 0),
+            f"nope_layers {self.nope_layers} are not layers": any(not 0 <= i < self.layers for i in self.nope_layers),
+        }
+        if any(wrong.values()):
+            raise ValueError("; ".join(k for k, v in wrong.items() if v))
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts whose weights are here."""
+        return self.held_experts or (0, self.experts)
+
+    @property
+    def routed_layers(self) -> int:
+        return self.layers - self.dense_layers
 
 
 def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
-    """Every tensor of a trunk checkpoint by name."""
-    n, h, e, w = cfg.layers, cfg.hidden, cfg.experts, cfg.expert_width
-    inner = cfg.heads * cfg.head_dim
-    return {
+    """Every trained tensor of a trunk checkpoint by name."""
+    n, r, h, w = cfg.layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
+    inner, kv_inner, held = cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim, cfg.held[1]
+    shapes = {
         "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
-        "attn_norm": (n, h), "wq": (n, h, inner), "wk": (n, h, inner), "wv": (n, h, inner),
+        "attn_norm": (n, h), "wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner),
         "q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim), "wo": (n, inner, h),
-        "moe_norm": (n, h), "router_w": (n, h, e),
-        "experts_gate": (n, e, h, w), "experts_up": (n, e, h, w), "experts_down": (n, e, w, h),
+        "moe_norm": (n, h), "router_w": (r, h, cfg.experts),
+        "experts_gate": (r, held, h, w), "experts_up": (r, held, h, w), "experts_down": (r, held, w, h),
         "final_norm": (h,),
         "policy_w": (1, 1, h, cfg.policy_planes), "policy_b": (cfg.policy_planes,),
         "value_w": (1, 1, h, 4), "value_b": (4,),
         "value_fc1_w": (4 * SQUARES, cfg.value_hidden), "value_fc1_b": (cfg.value_hidden,),
         "value_fc2_w": (cfg.value_hidden, 1), "value_fc2_b": (1,),
     }
+    if cfg.gated_attention:
+        shapes["wgate"] = (n, h, inner)
+    if cfg.post_norms:
+        shapes.update(post_attn_norm=(n, h), post_mlp_norm=(n, h))
+    for kind, count, width in (("dense", cfg.dense_layers, cfg.dense_width), ("shared", r, cfg.shared_width)):
+        if width:
+            shapes.update({f"{kind}_gate": (count, h, width), f"{kind}_up": (count, h, width), f"{kind}_down": (count, width, h)})
+    return shapes
+
+
+def trunk_buffer_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
+    """What a training state holds beside the trained tensors, outside
+    the optimizer: ``expert_bias`` where the routed layers balance."""
+    return {"expert_bias": (cfg.routed_layers, cfg.experts)} if cfg.balance_rate else {}
 
 
 def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Params:
@@ -145,7 +250,13 @@ def _row_major(x: jax.Array) -> jax.Array:
     return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
-def _attention(x: jax.Array, p: Params, cfg: TrunkConfig) -> jax.Array:
+def _gated_ffn(n: jax.Array, p: Params, kind: str) -> jax.Array:
+    """``(silu(n W_g) * (n W_u)) W_d`` on every token: the dense layer's
+    feed-forward (``kind`` "dense") and the shared expert ("shared")."""
+    return _matmul(jax.nn.silu(_matmul(n, p[f"{kind}_gate"])) * _matmul(n, p[f"{kind}_up"]), p[f"{kind}_down"])
+
+
+def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True) -> jax.Array:
     """[tokens, hidden] float32, 64 tokens a board -> the attention
     branch's output, same shape. The projections are XLA's; everything
     between them is ``board_attention``."""
@@ -153,8 +264,12 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig) -> jax.Array:
     by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
     q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
     mixed = board_attention(q, k, v.astype(jnp.bfloat16), p["q_norm"], p["k_norm"],
-                            cfg.rope_theta, cfg.rms_eps, _interpret())
-    return _matmul(mixed.reshape(x.shape[0], -1), p["wo"])
+                            cfg.rope_theta if rope else None, cfg.rms_eps, _interpret())
+    mixed = mixed.reshape(x.shape[0], -1)
+    if cfg.gated_attention:
+        mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
+    out = _matmul(mixed, p["wo"])
+    return _rms_norm(out, p["post_attn_norm"], cfg.rms_eps) if cfg.post_norms else out
 
 
 def _interpret() -> bool:
@@ -237,42 +352,121 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 _TILE = (512, 1024, 1024)
 
 
-def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, first: Optional[int] = None) -> jax.Array:
     """``rows[i] @ weights[g(i)]`` where the rows come in runs of
     ``group_sizes`` (int32; their sum is the number of rows; a size may
     be 0). bfloat16 operands and result, float32 accumulation: megablox
     ``gmm``, whose gradients are ``gmm`` on the transposed weights and
     ``tgmm`` (one product a group, summed over the group's rows). The
     number of rows has to be a multiple of 8; it is 64 x experts_per_token
-    x positions here."""
+    x positions here. With ``first``, ``weights`` are those of the groups
+    ``first .. first + len(weights)`` alone (``gmm``'s ``group_offset``:
+    a share of sharded experts): the other groups' rows are not visited
+    and come out zero, as do their cotangents."""
     m, k = rows.shape
     tiling = (math.gcd(m, _TILE[0]), min(k, _TILE[1]), min(weights.shape[2], _TILE[2]))
+    offset = None if first is None else jnp.asarray(first, jnp.int32)
     return megablox.gmm(rows.astype(jnp.bfloat16), weights.astype(jnp.bfloat16), group_sizes,
-                        jnp.bfloat16, tiling, None, None, False, _interpret())
+                        jnp.bfloat16, tiling, offset, None, False, _interpret())
+
+
+def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router in float32: each token's ``experts_per_token`` experts
+    [N, k], their combine weights [N, k], and the scores as a
+    distribution over the experts [N, experts] (for the entropy)."""
+    logits = jnp.dot(n2, p["router_w"], precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "softmax":
+        score = probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        score = jax.nn.sigmoid(logits)
+        probs = score / jnp.sum(score, axis=-1, keepdims=True)
+    if "expert_bias" in p:  # the choice is on score + bias, the weights on the score; neither has a gradient through the bias
+        _, expert = jax.lax.top_k(score + jax.lax.stop_gradient(p["expert_bias"]), cfg.experts_per_token)
+        weight = jnp.take_along_axis(score, expert, axis=-1)
+    else:
+        weight, expert = jax.lax.top_k(score, cfg.experts_per_token)
+    if cfg.route_norm:  # over all the chosen, held here or not: the shares of all chips add up
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    if cfg.route_scale != 1.0:
+        weight = weight * cfg.route_scale
+    return expert, weight, probs
+
+
+def _expert_ffn(rows: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array, group_sizes: jax.Array,
+                first: Optional[int]) -> jax.Array:
+    """The three grouped products of the held experts on the sorted rows."""
+    gate = grouped_matmul(rows, gate_w, group_sizes, first)
+    up = grouped_matmul(rows, up_w, group_sizes, first)
+    return grouped_matmul(jax.nn.silu(gate) * up, down_w, group_sizes, first)
+
+
+def _routed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first: Optional[int], layer: str) -> jax.Array:
+    """Dispatch, the held experts and combine: [N, hidden] float32 normed
+    tokens -> the weighted sum of each token's held slots, same shape."""
+    with jax.named_scope(f"{layer}.dispatch"):
+        rows = _dispatch(n2.astype(jnp.bfloat16), order)
+    with jax.named_scope(f"{layer}.experts"):
+        out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, first)
+    with jax.named_scope(f"{layer}.combine"):
+        return _combine(out, weight, order)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _routed_recomputed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first: Optional[int], layer: str) -> jax.Array:
+    """``_routed`` that keeps nothing of slot size for its gradient: the
+    sorted rows, the products' intermediates and the rows in token order
+    (1.75 GiB a layer at 131,072 slots of hidden 2048) are made again in
+    the backward pass from the tokens, which the router keeps anyway.
+    Written out by parts, each under its own scope and never nested in a
+    layer's, because the benchmark's scope table reads two levels of a
+    path and ``jax.checkpoint`` would put its own two first."""
+    return _routed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first, layer)
+
+
+def _routed_recomputed_fwd(n2, weight, order, group_sizes, gate_w, up_w, down_w, first, layer):
+    args = (n2, weight, order, group_sizes, gate_w, up_w, down_w)
+    return _routed(*args, first, layer), args
+
+
+def _routed_recomputed_bwd(first, layer, args, g):
+    n2, weight, order, group_sizes, gate_w, up_w, down_w = args
+    # Tied to the cotangent's arrival, as jax.checkpoint ties its own: nothing else keeps XLA from
+    # making every layer's rows again at once, as soon as the forward pass has the tokens.
+    n2, g = jax.lax.optimization_barrier((n2, g))
+    with jax.named_scope(f"{layer}.dispatch"):
+        rows, pull_rows = jax.vjp(lambda t: _dispatch(t.astype(jnp.bfloat16), order), n2)
+    with jax.named_scope(f"{layer}.experts"):
+        out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, first), rows, gate_w, up_w, down_w)
+    with jax.named_scope(f"{layer}.combine"):
+        d_out, d_weight = jax.vjp(lambda o, w: _combine(o, w, order), out, weight)[1](g)
+    with jax.named_scope(f"{layer}.experts"):
+        d_rows, *d_weights = pull_ffn(d_out)
+    with jax.named_scope(f"{layer}.dispatch"):
+        (d_n2,) = pull_rows(d_rows)
+    return (d_n2, d_weight, None, None, *d_weights)
+
+
+_routed_recomputed.defvjp(_routed_recomputed_fwd, _routed_recomputed_bwd)
 
 
 def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """[N, hidden] float32 normed tokens -> the routed experts' weighted
-    sum [N, hidden] float32, and the layer's routing counters."""
+    """[N, hidden] float32 normed tokens -> the held routed experts'
+    weighted sum [N, hidden] float32, and the layer's routing counters.
+    Enters its own scopes: call it under none of a layer's."""
     n, k = n2.shape[0], cfg.experts_per_token
     with jax.named_scope(f"{layer}.router"):
-        logits = jnp.dot(n2, p["router_w"], precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weight, expert = jax.lax.top_k(probs, k)  # [N, k] each; weights not renormalised
+        expert, weight, probs = _route(n2, p, cfg)
     with jax.named_scope(f"{layer}.dispatch"):
         slot_expert = expert.reshape(n * k)
         order = jnp.argsort(slot_expert, stable=True)
         group_sizes = jnp.sum(slot_expert[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
-        rows = _dispatch(n2.astype(jnp.bfloat16), order)
-    with jax.named_scope(f"{layer}.experts"):
-        gate = grouped_matmul(rows, p["experts_gate"], group_sizes)
-        up = grouped_matmul(rows, p["experts_up"], group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up, p["experts_down"], group_sizes)
-    with jax.named_scope(f"{layer}.combine"):
-        mixed = _combine(out, weight, order)
+    routed = _routed_recomputed if cfg.recompute_experts else _routed
+    mixed = routed(n2, weight, order, group_sizes, p["experts_gate"], p["experts_up"], p["experts_down"],
+                   cfg.held_experts[0] if cfg.held_experts else None, layer)
     load = group_sizes.astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
-    return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy}
+    return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy,
+                   "expert_slots": load}
 
 
 def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
@@ -280,71 +474,133 @@ def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkCon
     return trunk_forward_counted(params, planes, cfg)[:2]
 
 
+_EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "moe_norm", "wgate", "post_attn_norm", "post_mlp_norm")
+_ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias")
+_DENSE = ("dense_gate", "dense_up", "dense_down")
+
+
 def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     """``trunk_forward`` and the routing counters of the step's metrics:
-    the most and the fewest slots any expert of any layer received
-    (``expert_load_max``, ``expert_load_min``) and the router's mean
-    entropy in nats (``router_entropy``)."""
+    the most and the fewest slots any expert of any routed layer received
+    (``expert_load_max``, ``expert_load_min``), the router's mean entropy
+    in nats (``router_entropy``: of the softmax, or of the sigmoid scores
+    over their sum) and every routed layer's slots an expert
+    (``expert_slots`` [routed layers, experts], what the balance update
+    reads); for a share, the slots that fell on the held experts, summed
+    over the layers (``held_slots``); with an ``expert_bias`` among
+    ``params``, its largest magnitude (``expert_bias_abs_max``)."""
     b = planes.shape[0]
     # Scope names are a contract (doc/observability.md "Training and compilation").
     with jax.named_scope("embed"):
-        x = _row_major(_matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"])
+        x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
+        x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
     counters = []
     for i in range(cfg.layers):
-        layer = {name: params[name][i] for name in
-                 ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "moe_norm", "router_w",
-                  "experts_gate", "experts_up", "experts_down")}
+        # Layers of a kind are stacked: a routed layer's tensors are indexed from the first routed layer.
+        routed = i - cfg.dense_layers
+        own, index = (_ROUTED, routed) if routed >= 0 else (_DENSE, i)
+        layer = {name: params[name][i] for name in _EVERY_LAYER if name in params}
+        layer.update({name: params[name][index] for name in own if name in params})
+        post = (lambda y: _rms_norm(y, layer["post_mlp_norm"], cfg.rms_eps)) if cfg.post_norms else (lambda y: y)
         # One scope a part, the layer in its name: the benchmark's scope
         # table keeps two levels of a path (phase, then this).
         name = f"layer{i:02d}"
         with jax.named_scope(f"{name}.attention"):
-            x = x + _attention(x, layer, cfg)
+            x = x + _attention(x, layer, cfg, rope=i not in cfg.nope_layers)
+        if routed < 0:
+            with jax.named_scope(f"{name}.dense"):
+                x = x + post(_gated_ffn(_rms_norm(x, layer["moe_norm"], cfg.rms_eps), layer, "dense"))
+            continue
         with jax.named_scope(f"{name}.router"):
             n2 = _rms_norm(x, layer["moe_norm"], cfg.rms_eps)
         mixed, layer_counters = _experts(n2, layer, cfg, name)
+        if cfg.shared_width:
+            with jax.named_scope(f"{name}.shared"):
+                mixed = mixed + _gated_ffn(n2, layer, "shared")
         with jax.named_scope(f"{name}.combine"):
-            x = x + mixed
+            x = x + post(mixed)
         counters.append(layer_counters)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
+    slots = jnp.stack([c["expert_slots"] for c in counters])
+    first, count = cfg.held
     return (*policy_value_heads(params, features), {
         "expert_load_max": jnp.max(jnp.stack([c["expert_load_max"] for c in counters])),
         "expert_load_min": jnp.min(jnp.stack([c["expert_load_min"] for c in counters])),
         "router_entropy": jnp.mean(jnp.stack([c["router_entropy"] for c in counters])),
+        "expert_slots": slots,
+        **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
+        **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
     })
 
 
-#: What a trunk checkpoint carries beside its tensors: the three fields
-#: of ``TrunkConfig`` that no shape determines, as float64[3].
+def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
+    """``expert_bias`` [routed layers, experts] after a step whose routed
+    layers sent ``slots`` rows to each expert: up by ``rate`` for an
+    expert under its layer's mean load, down for one over it, the
+    layer's mean change taken out (the block's auxiliary-loss-free
+    balancing)."""
+    change = rate * jnp.sign(jnp.mean(slots, axis=-1, keepdims=True) - slots)
+    return bias + change - jnp.mean(change, axis=-1, keepdims=True)
+
+
+#: What a trunk checkpoint carries beside its tensors: the fields of
+#: ``TrunkConfig`` that no shape determines, as float64. A file of the
+#: first block alone has the first three; the rest default to it.
 HPARAMS = "trunk_hparams"
+_HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_scale", "balance_rate", "sliding_window",
+            "sigmoid", "route_norm", "first_held", "nope_mask")
 
 
 def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
-    """The arrays of a trunk ``.npz``: the tensors and ``trunk_hparams`` =
-    (experts_per_token, rope_theta, rms_eps)."""
+    """The arrays of a trunk ``.npz``: the tensors (``expert_bias`` among
+    them where the state has one) and ``trunk_hparams``: experts_per_token,
+    rope_theta, rms_eps, then embed_scale, route_scale, balance_rate,
+    sliding_window (0: none), sigmoid scores (0 or 1), route_norm, the
+    first held expert (-1: all are held) and the layers without RoPE as
+    a bit mask. ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
-    arrays[HPARAMS] = np.asarray([cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps], np.float64)
+    arrays[HPARAMS] = np.asarray([
+        cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
+        cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
+        cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers)], np.float64)
     return arrays
 
 
 def trunk_config_from_params(params: Params) -> TrunkConfig:
     """The ``TrunkConfig`` of a checkpoint, from its shapes and its
     ``trunk_hparams``; a ValueError names what does not fit."""
-    required = ("router_w", "experts_gate", "q_norm", "value_fc1_b", "policy_b", HPARAMS)
+    required = ("router_w", "experts_gate", "q_norm", "attn_norm", "value_fc1_b", "policy_b", HPARAMS)
     missing = [k for k in required if k not in params]
     if missing:
         raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
-    layers, hidden, experts = np.shape(params["router_w"])
-    head_dim = int(np.shape(params["q_norm"])[1])
-    top_k, theta, eps = (float(v) for v in np.asarray(params[HPARAMS]).reshape(3))
-    cfg = TrunkConfig(
-        hidden=int(hidden), heads=int(np.shape(params["wq"])[2]) // head_dim, head_dim=head_dim, layers=int(layers),
-        experts=int(experts), experts_per_token=int(round(top_k)), expert_width=int(np.shape(params["experts_gate"])[3]),
-        rope_theta=theta, rms_eps=eps,
-        value_hidden=int(np.shape(params["value_fc1_b"])[0]), policy_planes=int(np.shape(params["policy_b"])[0]),
-    )
-    expected = {**trunk_param_shapes(cfg), HPARAMS: (3,)}
+    given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
+    defaults = trunk_checkpoint({}, TrunkConfig())[HPARAMS]
+    if not 3 <= len(given) <= len(_HPARAMS):
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS)}")
+    hp = dict(zip(_HPARAMS, [*given, *defaults[len(given):]]))
+    shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
+    (routed, hidden, experts), layers, head_dim = shape("router_w"), shape("attn_norm")[0], shape("q_norm")[1]
+    width_of = lambda name: shape(name)[2] if name in params else 0
+    try:
+        cfg = TrunkConfig(
+            hidden=hidden, heads=shape("wq")[2] // head_dim, head_dim=head_dim, layers=layers,
+            experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_gate")[3],
+            rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
+            value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
+            kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim,
+            nope_layers=tuple(i for i in range(layers) if int(hp["nope_mask"]) >> i & 1),
+            sliding_window=int(hp["sliding_window"]) or None,
+            gated_attention="wgate" in params, post_norms="post_attn_norm" in params, embed_scale=hp["embed_scale"],
+            dense_layers=layers - routed, dense_width=width_of("dense_gate"), shared_width=width_of("shared_gate"),
+            router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
+            held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_gate")[1]),
+            balance_rate=hp["balance_rate"],
+        )
+    except ValueError as err:
+        raise ValueError(f"trunk checkpoint: mismatched shapes: {err}") from err
+    expected = {**trunk_param_shapes(cfg), **trunk_buffer_shapes(cfg), HPARAMS: (len(given),)}
     got = {k: tuple(np.shape(v)) for k, v in params.items()}
     if expected != got:
         diff = set(expected) ^ set(got) or {k for k in expected if expected[k] != got[k]}

@@ -4,20 +4,27 @@ the output projection, for the 64 squares of a board and one head at a
 time, without leaving VMEM.
 
 ``board_attention(q, k, v, g_q, g_k)`` with q, k (float32) and v
-(bfloat16) as the projections write them, ``[boards, 64, heads *
-head_dim]``, gives ``mixed`` in the same shape, bfloat16. Per board and
-head::
+(bfloat16) as the projections write them, q ``[boards, 64, heads *
+head_dim]`` and k, v ``[boards, 64, kv_heads * head_dim]``, gives
+``mixed`` in q's shape, bfloat16. ``heads`` is a multiple of
+``kv_heads``: query head h attends key-value head ``h // (heads //
+kv_heads)`` (grouped-query attention; one to one where the two counts
+are equal). Per board and query head::
 
     q, k <- RMSNorm over head_dim (float32 statistics, gains g_q, g_k),
-            then RoPE (rotate-half, position = square index), rounded
-            to bfloat16
+            then RoPE (rotate-half, position = square index) unless
+            ``theta`` is None, rounded to bfloat16
     s     = q k^T / sqrt(head_dim)        float32 accumulation
     p     = softmax(s)                    float32
     mixed = p (bfloat16) @ v              float32 accumulation
 
-A grid step's block is ``(boards, 64, head_dim)`` at lane offset ``head
-* head_dim`` of the arrays as they are: no ``[.., heads, head_dim]``
-view ever exists outside VMEM, and the 64 x 64 scores never reach HBM.
+A grid step's block is one key-value head of a few boards, ``(boards,
+64, head_dim)`` of k and v at lane offset ``kv_head * head_dim`` of the
+arrays as they are, and the group of query heads that attend it,
+``(boards, 64, group * head_dim)`` of q: k is normed and rotated once a
+group, and the gradient's dk, dv sum over the group in VMEM. No ``[..,
+heads, head_dim]`` view ever exists outside VMEM, and the 64 x 64
+scores never reach HBM.
 Inside, the scores stand ``[key, query]``, the keys down the sublanes,
 so that the softmax's sums run over whole vregs: both kernels are bound
 by the unit that moves data across lanes (the norms' sums, RoPE's
@@ -38,7 +45,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,11 +57,12 @@ __all__ = ["SQUARES", "board_attention", "rope_tables"]
 
 SQUARES = 64
 
-#: Boards a grid step, and boards unrolled in one loop body of the forward
-#: and of the gradient kernel: the fastest of 4-64 boards and 1-16 unrolled
-#: on a v5e at [512, 64, 16 x 128] (PERF.md section 5). The blocks of a
-#: step, double-buffered, take 3 MiB (forward) and 5.5 MiB (gradient) of
-#: VMEM; 64 boards would pass the 16 MiB a kernel gets by default.
+#: (Board, query head) pairs a grid step, and pairs unrolled in one loop
+#: body of the forward and of the gradient kernel: the fastest of 4-64 and
+#: 1-16 on a v5e at [512, 64, 16 x 128] (PERF.md section 5). A group of g
+#: query heads takes 1/g of the boards, so that the blocks of a step,
+#: double-buffered, stay at 3 MiB (forward) and 5.5 MiB (gradient) of
+#: VMEM; 64 would pass the 16 MiB a kernel gets by default.
 _BOARDS = 16
 _UNROLL = 4
 _UNROLL_GRAD = 8
@@ -117,16 +125,26 @@ def _each_board(boards: int, body, carry, unroll: int):
     return jax.lax.fori_loop(0, boards // unroll, step, carry)
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, unroll: int):
+def _head(b, g: int, head_dim: int, group: int):
+    """The index of board ``b``, head ``g`` in a block ``[boards, 64, group * head_dim]``."""
+    return b if group == 1 else (b, slice(None), slice(g * head_dim, (g + 1) * head_dim))
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int):
     cos, sin = cos_ref[...], sin_ref[...]
     gq, gk = gq_ref[...], gk_ref[...]
+    head_dim = k_ref.shape[-1]
+    turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
 
     def board(b, carry):
-        qb = _rope(_unit(q_ref[b], eps)[0] * gq, cos, sin).astype(jnp.bfloat16)
-        kb = _rope(_unit(k_ref[b], eps)[0] * gk, cos, sin).astype(jnp.bfloat16)
-        p = _softmax(_scores(kb, qb)).astype(jnp.bfloat16)
-        mixed = jax.lax.dot_general(p, v_ref[b], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        out_ref[b] = mixed.astype(out_ref.dtype)
+        group = q_ref.shape[-1] // head_dim
+        for g in range(group):
+            qb = turn(_unit(q_ref[_head(b, g, head_dim, group)], eps)[0] * gq).astype(jnp.bfloat16)
+            if g == 0:  # after the first query head's, as PR 32's one-head kernel had it
+                kb = turn(_unit(k_ref[b], eps)[0] * gk).astype(jnp.bfloat16)
+            p = _softmax(_scores(kb, qb)).astype(jnp.bfloat16)
+            mixed = jax.lax.dot_general(p, v_ref[b], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            out_ref[_head(b, g, head_dim, group)] = mixed.astype(out_ref.dtype)
         return carry
 
     _each_board(q_ref.shape[0], board, 0, unroll)
@@ -137,38 +155,58 @@ def _rounded(x: jax.Array) -> jax.Array:
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
-def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: jax.Array, cos: jax.Array, sin: jax.Array):
+def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: jax.Array, cos: jax.Array, sin: jax.Array, rope: bool):
     """The cotangent of a normed and rotated ``[64, head_dim]`` back to
     its raw input, and the summand of the gain's gradient."""
-    d_normed = d_rot * cos + _turned(d_rot * sin)
+    d_normed = d_rot * cos + _turned(d_rot * sin) if rope else d_rot
     d_unit = d_normed * gain
     d_x = r * (d_unit - unit * jnp.mean(d_unit * unit, axis=-1, keepdims=True))
     return d_x, d_normed * unit
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_ref,
-                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, unroll: int):
+                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, rope: bool, unroll: int):
     cos, sin = cos_ref[...], sin_ref[...]
     gq, gk = gq_ref[...], gk_ref[...]
     bf16, f32 = jnp.bfloat16, jnp.float32
-    scale = np.float32(1.0 / math.sqrt(q_ref.shape[-1]))
+    head_dim = k_ref.shape[-1]
+    scale = np.float32(1.0 / math.sqrt(head_dim))
+    turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
 
     def board(b, carry):
-        uq, rq = _unit(q_ref[b], eps)
-        uk, rk = _unit(k_ref[b], eps)
-        qb = _rope(uq * gq, cos, sin).astype(bf16)
-        kb = _rope(uk * gk, cos, sin).astype(bf16)
-        vb, do = v_ref[b], do_ref[b]
-        p = _softmax(_scores(kb, qb))
-        dv_ref[b] = jnp.dot(p.astype(bf16), do, preferred_element_type=f32).astype(dv_ref.dtype)
-        dp = _rounded(jax.lax.dot_general(vb, do, (((1,), (1,)), ((), ())), preferred_element_type=f32))
-        ds = (p * (dp - jnp.sum(dp * p, axis=0, keepdims=True)) * scale).astype(bf16)
-        dq_rot = _rounded(jax.lax.dot_general(ds, kb, (((0,), (0,)), ((), ())), preferred_element_type=f32))
-        dk_rot = _rounded(jnp.dot(ds, qb, preferred_element_type=f32))
-        dq, dgq = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin)
-        dk, dgk = _unrope_unnorm(dk_rot, uk, rk, gk, cos, sin)
-        dq_ref[b], dk_ref[b] = dq, dk
-        return carry[0] + dgq, carry[1] + dgk
+        # In the order PR 32's one-head kernel emitted its operations (a group of one IS that kernel):
+        # dv leaves as soon as the last head's part is in, dq and dk are taken back through RoPE and
+        # the norm side by side. Mosaic schedules what it is given: the same work emitted with dv and
+        # dk held to the end read 0.5 ms a step slower at [512, 64, 16 x 128] (PERF.md section 6, PR 33).
+        dgq, dgk = carry
+        group = q_ref.shape[-1] // head_dim
+        for g in range(group):
+            uq, rq = _unit(q_ref[_head(b, g, head_dim, group)], eps)
+            if g == 0:
+                uk, rk = _unit(k_ref[b], eps)
+            qb = turn(uq * gq).astype(bf16)
+            if g == 0:
+                kb = turn(uk * gk).astype(bf16)
+                vb = v_ref[b]
+            do = do_ref[_head(b, g, head_dim, group)]
+            p = _softmax(_scores(kb, qb))
+            dv_g = jnp.dot(p.astype(bf16), do, preferred_element_type=f32)
+            dv = dv_g if g == 0 else dv + dv_g  # float32 sums over the group's query heads, rounded once
+            if g == group - 1:
+                dv_ref[b] = dv.astype(dv_ref.dtype)
+            dp = _rounded(jax.lax.dot_general(vb, do, (((1,), (1,)), ((), ())), preferred_element_type=f32))
+            ds = (p * (dp - jnp.sum(dp * p, axis=0, keepdims=True)) * scale).astype(bf16)
+            dq_rot = _rounded(jax.lax.dot_general(ds, kb, (((0,), (0,)), ((), ())), preferred_element_type=f32))
+            dk_g = jnp.dot(ds, qb, preferred_element_type=f32)
+            dk_rot = dk_g if g == 0 else dk_rot + dk_g
+            dq, dgq_g = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope)
+            dgq = dgq + dgq_g
+            if g == group - 1:
+                dk, dgk_b = _unrope_unnorm(_rounded(dk_rot), uk, rk, gk, cos, sin, rope)
+                dq_ref[_head(b, g, head_dim, group)], dk_ref[b] = dq, dk
+            else:
+                dq_ref[_head(b, g, head_dim, group)] = dq
+        return dgq, dgk + dgk_b
 
     zero = jnp.zeros(cos.shape, f32)
     dgq, dgk = _each_board(q_ref.shape[0], board, (zero, zero), unroll)
@@ -176,44 +214,51 @@ def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_r
     dgk_ref[0] = jnp.sum(dgk, axis=0, keepdims=True)
 
 
-def _blocks(boards: int, heads: int, head_dim: int):
-    """The grid (blocks of boards, heads) and the BlockSpecs of a ``[boards,
-    64, heads * head_dim]`` operand, a gain or table ``[.., head_dim]``
-    and a step's partial sum in ``[steps, 1, heads * head_dim]``."""
-    tb = math.gcd(boards, _BOARDS)
+def _blocks(boards: int, heads: int, kv_heads: int, head_dim: int):
+    """The grid (blocks of boards, key-value heads) and the BlockSpecs of
+    a key-value head of ``[boards, 64, kv_heads * head_dim]``, of its
+    group of query heads in ``[boards, 64, heads * head_dim]``, of a gain
+    or table ``[.., head_dim]`` and of a step's partial sum in ``[steps,
+    1, kv_heads * head_dim]``."""
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not divide over {kv_heads} key-value heads")
+    group = heads // kv_heads
+    tb = math.gcd(boards, max(1, _BOARDS // group))
     per_head = pl.BlockSpec((tb, SQUARES, head_dim), lambda i, h: (i, 0, h))
+    per_group = pl.BlockSpec((tb, SQUARES, group * head_dim), lambda i, h: (i, 0, h))
     whole = lambda rows: pl.BlockSpec((rows, head_dim), lambda i, h: (0, 0))
     partial = pl.BlockSpec((1, 1, head_dim), lambda i, h: (i, 0, h))
-    return (boards // tb, heads), per_head, whole, partial
+    return (boards // tb, kv_heads), group, per_head, per_group, whole, partial
 
 
-def _operands(g_q, g_k, theta: float):
+def _operands(g_q, g_k, theta: Optional[float]):
     head_dim = g_q.shape[-1]
-    cos, sin = rope_tables(theta, head_dim)
+    cos, sin = rope_tables(theta or 1.0, head_dim)  # not read without RoPE
     gain = lambda g: g.astype(jnp.float32).reshape(1, head_dim)
     return gain(g_q), gain(g_k), jnp.asarray(cos), jnp.asarray(sin)
 
 
-def _unroll(interpret: bool, unroll: int) -> int:
-    """Unrolling is for Mosaic's scheduler; the interpreter pays for every
-    emitted operation and gains nothing."""
-    return 1 if interpret else unroll
+def _unroll(interpret: bool, unroll: int, group: int) -> int:
+    """Boards to a loop body. Unrolling is for Mosaic's scheduler; the
+    interpreter pays for every emitted operation and gains nothing."""
+    return 1 if interpret else max(1, unroll // group)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: jax.Array, g_k: jax.Array,
-                    theta: float, eps: float, interpret: bool = False) -> jax.Array:
-    """The attention core (module docstring): q, k float32 and v bfloat16
-    ``[boards, 64, heads * head_dim]``, gains ``[head_dim]`` -> bfloat16
-    of the same shape. ``heads`` follows from the shapes."""
+                    theta: Optional[float], eps: float, interpret: bool = False) -> jax.Array:
+    """The attention core (module docstring): q float32 ``[boards, 64,
+    heads * head_dim]``, k float32 and v bfloat16 ``[boards, 64, kv_heads
+    * head_dim]``, gains ``[head_dim]`` -> bfloat16 of q's shape. Both
+    head counts follow from the shapes; ``theta`` None is no RoPE."""
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
-    grid, per_head, whole, _ = _blocks(boards, inner // head_dim, head_dim)
+    grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL)),
+        functools.partial(_forward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL, group)),
         grid=grid,
-        in_specs=[per_head, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES)],
-        out_specs=per_head,
+        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES)],
+        out_specs=per_group,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.bfloat16),
         compiler_params=_PARAMS,
         name="board_attention",
@@ -229,21 +274,21 @@ def _board_attention_bwd(theta, eps, interpret, residuals, d_mixed):
     q, k, v, g_q, g_k = residuals
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
-    heads = inner // head_dim
-    grid, per_head, whole, partial = _blocks(boards, heads, head_dim)
-    sums = jax.ShapeDtypeStruct((grid[0], 1, inner), jnp.float32)
+    kv_heads = k.shape[-1] // head_dim
+    grid, group, per_head, per_group, whole, partial = _blocks(boards, inner // head_dim, kv_heads, head_dim)
+    sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
     dq, dk, dv, dgq, dgk = pl.pallas_call(
-        functools.partial(_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD)),
+        functools.partial(_backward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL_GRAD, group)),
         grid=grid,
-        in_specs=[per_head, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES), per_head],
-        out_specs=[per_head, per_head, per_head, partial, partial],
+        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES), per_group],
+        out_specs=[per_group, per_head, per_head, partial, partial],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype), sums, sums],
         compiler_params=_PARAMS,
         name="board_attention_grad",
         interpret=interpret,
     )(q, k, v, *_operands(g_q, g_k, theta), d_mixed)
-    total = lambda s, g: s.reshape(-1, heads, head_dim).sum(axis=(0, 1)).astype(g.dtype)
+    total = lambda s, g: s.reshape(-1, kv_heads, head_dim).sum(axis=(0, 1)).astype(g.dtype)
     return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
 
 

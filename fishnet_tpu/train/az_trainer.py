@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_params
+from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
+from fishnet_tpu.models.trunk import balanced_bias
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup
 from fishnet_tpu.train.trainer import _constrain
@@ -43,6 +44,10 @@ class AzTrainState(NamedTuple):
     params: Dict[str, jax.Array]
     opt_state: optax.OptState
     step: jax.Array
+    #: What the network reads beside its parameters and the optimizer
+    #: never sees (no moments, no weight decay): a balancing trunk's
+    #: ``expert_bias``, which the step moves by its own routing counts.
+    buffers: Dict[str, jax.Array] = {}
 
 
 def az_param_spec(name: str, value: jax.Array) -> P:
@@ -93,13 +98,13 @@ class AzTrainer:
         params = init_az_params(rng, self.cfg)
         params = _constrain_params(params, self.mesh)
         opt_state = self.optimizer.init(params)
-        return AzTrainState(params, opt_state, jnp.zeros((), jnp.int32))
+        return AzTrainState(params, opt_state, jnp.zeros((), jnp.int32), init_az_buffers(self.cfg))
 
-    def _loss(self, params, batch: Batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def _loss(self, params, batch: Batch, buffers: Dict[str, jax.Array] = {}) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         # forward / loss / optimizer: the scope contract both trainers
         # share (doc/observability.md "Training and compilation").
         with jax.named_scope("forward"):
-            logits, value, counters = az_forward_counted(params, batch["planes"], self.cfg)
+            logits, value, counters = az_forward_counted({**params, **buffers}, batch["planes"], self.cfg)
         with jax.named_scope("loss"):
             target = batch["policy_target"]
             # Masked cross-entropy: zero-probability targets (illegal moves)
@@ -112,19 +117,23 @@ class AzTrainer:
             "loss": loss,
             "policy_loss": policy_loss,
             "value_loss": value_loss,
-            **counters,  # the trunk's expert_load_max, expert_load_min, router_entropy
+            **counters,  # the trunk's routing counters (models/trunk.py trunk_forward_counted)
         }
 
     def _step(self, state: AzTrainState, batch: Batch):
         batch = _constrain(batch, az_batch_specs(), self.mesh)
-        grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch)
+        grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch, state.buffers)
+        slots = metrics.pop("expert_slots", None)  # [routed layers, experts], not a scalar of the step's metrics
         with jax.named_scope("optimizer"):
             updates, opt_state = self.optimizer.update(
                 grads, state.opt_state, state.params
             )
             params = optax.apply_updates(state.params, updates)
+            buffers = state.buffers
+            if "expert_bias" in buffers:
+                buffers = {**buffers, "expert_bias": balanced_bias(buffers["expert_bias"], slots, self.cfg.balance_rate)}
         params = _constrain_params(params, self.mesh)
-        return AzTrainState(params, opt_state, state.step + 1), metrics
+        return AzTrainState(params, opt_state, state.step + 1, buffers), metrics
 
     # -- public api -------------------------------------------------------
 
@@ -146,4 +155,4 @@ class AzTrainer:
         """Save params as the .npz checkpoint --az-net-file consumes."""
         import numpy as np
 
-        np.savez(path, **az_checkpoint(state.params, self.cfg))
+        np.savez(path, **az_checkpoint({**state.params, **state.buffers}, self.cfg))

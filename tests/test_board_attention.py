@@ -43,29 +43,32 @@ def rope(x, head_dim, inverse=False):
     return x * cos + turned * (-sin if inverse else sin)
 
 
-def plain(q, k, v, g_q, g_k, wrong=""):
-    """The core from the layer equations, float32 throughout."""
+def plain(q, k, v, g_q, g_k, wrong="", theta=THETA):
+    """The core from the layer equations, float32 throughout. Fewer
+    key-value heads than query heads: each is repeated for the query
+    heads of its group (head h attends key-value head h // group)."""
     boards, head_dim = q.shape[0], g_q.shape[0]
     split = lambda y: y.astype(jnp.float32).reshape(boards, 64, -1, head_dim)
     norm = lambda x, g: x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * (1.0 if wrong == "no_gain" else g)
-    turn = (lambda x: x) if wrong == "no_rope" else (lambda x: rope(x, head_dim))
+    turn = (lambda x: x) if wrong == "no_rope" or theta is None else (lambda x: rope(x, head_dim))
     q, k, v = turn(norm(split(q), g_q)), turn(norm(split(k), g_k)), split(v)
+    k, v = (jnp.repeat(y, q.shape[2] // y.shape[2], axis=2) for y in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / (1.0 if wrong == "no_scale" else np.sqrt(head_dim))
     mixed = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v, precision="highest")
     return mixed.reshape(boards, 64, -1)
 
 
-def inputs(boards, heads, head_dim, seed=0):
+def inputs(boards, heads, head_dim, seed=0, kv_heads=None):
     rng = np.random.default_rng(seed)
-    shape = (boards, 64, heads * head_dim)
+    shape, kv_shape = (boards, 64, heads * head_dim), (boards, 64, (kv_heads or heads) * head_dim)
     gain = lambda: jnp.asarray(1.0 + 0.1 * rng.standard_normal(head_dim), jnp.float32)
-    return (jnp.asarray(1.5 * rng.standard_normal(shape), jnp.float32), jnp.asarray(1.5 * rng.standard_normal(shape), jnp.float32),
-            jnp.asarray(rng.standard_normal(shape), jnp.bfloat16), gain(), gain(),
+    return (jnp.asarray(1.5 * rng.standard_normal(shape), jnp.float32), jnp.asarray(1.5 * rng.standard_normal(kv_shape), jnp.float32),
+            jnp.asarray(rng.standard_normal(kv_shape), jnp.bfloat16), gain(), gain(),
             jnp.asarray(rng.standard_normal(shape), jnp.bfloat16))  # the last: the cotangent of ``mixed``
 
 
-def kernel(q, k, v, g_q, g_k):
-    return board_attention(q, k, v, g_q, g_k, THETA, EPS, True)
+def kernel(q, k, v, g_q, g_k, theta=THETA):
+    return board_attention(q, k, v, g_q, g_k, theta, EPS, True)
 
 
 def value_and_gradients(f, q, k, v, g_q, g_k, cotangent):
@@ -98,6 +101,43 @@ def test_kernels_match_the_plain_formula(heads, head_dim, boards, output):
     assert got.shape == want.shape
     assert got.dtype == (jnp.float32 if output in ("d_q", "d_k", "d_q_norm", "d_k_norm") else jnp.bfloat16)
     assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL)
+
+
+# (heads, key-value heads, head_dim, boards, theta): the second block's 8 query heads a key-value head at the
+# published lane block (2 boards a grid step); groups of 2 and 4 with batches the block does and does not
+# divide; None is a layer without RoPE, with and without a group. dk, dv sum over a group's query heads: the
+# tolerance stays, because the sum is float32 and rounded once.
+GROUPED_CASES = [(4, 2, 16, 12, THETA), (8, 2, 16, 5, THETA), (8, 1, 128, 2, THETA), (4, 2, 16, 6, None), (2, 2, 16, 8, None)]
+
+
+@functools.lru_cache(maxsize=None)
+def both_grouped(heads, kv_heads, head_dim, boards, theta):
+    args = inputs(boards, heads, head_dim, seed=5, kv_heads=kv_heads)
+    return (jax.jit(functools.partial(value_and_gradients, functools.partial(kernel, theta=theta)))(*args),
+            jax.jit(functools.partial(value_and_gradients, functools.partial(plain, theta=theta)))(*args))
+
+
+@pytest.mark.parametrize("output", OUTPUTS)
+@pytest.mark.parametrize("heads,kv_heads,head_dim,boards,theta", GROUPED_CASES)
+def test_grouped_heads_and_layers_without_rope_match_the_plain_formula(heads, kv_heads, head_dim, boards, theta, output):
+    got, want = (side[OUTPUTS.index(output)] for side in both_grouped(heads, kv_heads, head_dim, boards, theta))
+    assert got.shape == want.shape
+    if not output.endswith("_norm"):
+        assert got.shape[-1] == (kv_heads if output in ("d_k", "d_v") else heads) * head_dim
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL)
+
+
+def test_the_tolerances_catch_a_wrong_group_or_a_rotation_left_in():
+    """Query head h attends key-value head h // group, not h % kv_heads;
+    a layer without RoPE rotates nothing."""
+    args = inputs(6, 4, 16, seed=6, kv_heads=2)
+    got = jax.jit(functools.partial(value_and_gradients, kernel))(*args)
+    interleaved = lambda q, k, v, g_q, g_k: plain(q, jnp.tile(k, 2), jnp.tile(v, 2), g_q, g_k)  # head h -> h % 2
+    assert rel(got[0], jax.jit(interleaved)(*args[:5])) > 1.5 * FORWARD_TOL
+    unrotated = jax.jit(functools.partial(value_and_gradients, functools.partial(kernel, theta=None)))(*args)
+    assert rel(unrotated[0], got[0]) > 1.5 * FORWARD_TOL
+    with pytest.raises(ValueError, match="do not divide"):
+        board_attention(args[0], jnp.tile(args[1], 2)[..., :48], jnp.tile(args[2], 2)[..., :48], args[3], args[4], THETA, EPS, True)
 
 
 @pytest.mark.parametrize("wrong", ["no_scale", "no_rope", "no_gain"])

@@ -120,3 +120,54 @@ def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_ch
     assert "board_attention" in text and "board_attention_grad" in text
     per_head = [line for line in text.splitlines() if re.search(r"\[512,16,64,64\]|\[512,64,16,128\]|\[512,16,64,128\]", line)]
     assert not per_head, per_head[:2]
+
+
+# -- the second block at the shapes of afmoe_trunk_train_b256: 256 boards, 32 query heads over 4 key-value heads, -----
+# -- 131,072 slots a routed layer of which 8 of 128 experts are held -------------------------------------------------
+
+AFMOE = trunk.TrunkConfig(heads=32, kv_heads=4, experts=128, held_experts=(0, 8), gated_attention=True, post_norms=True,
+                          router_score="sigmoid", route_norm=True, route_scale=2.826, shared_width=1024, balance_rate=0.001,
+                          rope_theta=10000.0)
+AFMOE_BOARDS = 256
+
+
+@pytest.mark.parametrize("rope", [True, False], ids=["rope", "nope"])
+def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled_for_tpu, rope):
+    """``value_and_grad`` of ``_attention`` with 8 query heads a key-value
+    head, the output gate and the post-norm, with and without RoPE: still
+    the two kernels, a grid step's blocks inside the 16 MiB a kernel gets."""
+    cfg, inner, kv_inner = AFMOE, 32 * 128, 4 * 128
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {"attn_norm": sds((HIDDEN,), jnp.float32), "post_attn_norm": sds((HIDDEN,), jnp.float32),
+             "q_norm": sds((cfg.head_dim,), jnp.float32), "k_norm": sds((cfg.head_dim,), jnp.float32),
+             "wq": sds((HIDDEN, inner), jnp.float32), "wgate": sds((HIDDEN, inner), jnp.float32),
+             "wk": sds((HIDDEN, kv_inner), jnp.float32), "wv": sds((HIDDEN, kv_inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
+
+    def loss(x, p):
+        return jnp.sum(trunk._attention(x, p, cfg, rope=rope))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "board_attention" in text and "board_attention_grad" in text
+
+
+@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
+def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_for_tpu, recompute):
+    """``value_and_grad`` of ``_experts`` holding 8 of 128 experts over
+    131,072 slots: ``gmm`` and ``tgmm`` with a ``group_offset`` and weights
+    ``[8, 2048, 1024]``; with ``recompute_experts`` the forward's kernels
+    are there a second time, in the backward pass."""
+    import dataclasses
+
+    cfg = dataclasses.replace(AFMOE, recompute_experts=recompute)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {"router_w": sds((HIDDEN, 128), jnp.float32), "expert_bias": sds((128,), jnp.float32),
+             "experts_gate": sds((8, HIDDEN, WIDTH), jnp.float32), "experts_up": sds((8, HIDDEN, WIDTH), jnp.float32),
+             "experts_down": sds((8, WIDTH, HIDDEN), jnp.float32)}
+
+    def loss(n2, p):
+        return jnp.sum(trunk._experts(n2, p, cfg, "layer01")[0])
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == (9 + 4) + (3 + 2 if recompute else 0), kernels  # nine products and four moves; three and two of them made again
