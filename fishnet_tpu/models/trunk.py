@@ -77,7 +77,8 @@ Mechanism, experts: the (token, slot) pairs are sorted by expert
 the CPU), the rows are put back in token order and each token's slots
 summed under their weights. A share's weights are ``[count, hidden,
 width]`` and ``gmm`` is told the first group it holds (its
-``group_offset``): it visits the held groups' rows alone and writes
+``group_offset``; 0, because a share's sort puts its own groups first,
+see below): it visits the held groups' rows alone and writes
 zeros for the others', forward and in both gradients, so a slot of an
 absent expert adds nothing and every held expert is dropless. The rows
 move through two more Pallas
@@ -87,7 +88,20 @@ hidden 2048, and one DMA moves it; the sorted side that the grouped
 products read stays ``[slots, hidden]``. ``rows_out`` (tokens to sorted
 slots) and ``rows_back`` (sorted slots to token order) are each other's
 transpose, so ``_dispatch`` and ``_combine`` pair them as forward and
-gradient and nothing is ever scatter-added. Matrix products run in
+gradient and nothing is ever scatter-added. A share moves the rows of
+its own experts alone: it sorts on ``(expert - first) mod experts``, so
+they are rows ``[0, held)`` whatever ``first`` is, and hands ``held``
+(the layer's own count, on the device) to every move as its extent. The
+buffers keep their dropless shapes (``[N * k, hidden]`` sorted, ``[N,
+k, hidden // 128, 128]`` in token order: any routing fits, all slots
+held included); only the work follows the count. What a tail holds:
+past ``held`` (rounded up to a block) the sorted buffers that the moves
+write, and the places of absent experts' slots in the token-order view,
+are uninitialised and may be NaN; ``gmm`` still writes zeros for the
+absent groups' rows of ITS results. Who may read a tail: ``gmm`` and
+``tgmm`` (they visit held groups only and select by the group's rows),
+and the three sums over a token's k, through ``_held_alone``'s select
+on the slot's mask, never through a product. Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
 tower's; norms, the softmaxes, the router's scores, choice and combine
 weights and both sigmoid gates are float32.
@@ -106,7 +120,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -117,7 +131,7 @@ from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
-from fishnet_tpu.ops.row_move import row_view, rows_back, rows_out
+from fishnet_tpu.ops.row_move import row_view, rows_back, rows_covered, rows_out
 
 Params = Dict[str, jax.Array]
 
@@ -289,33 +303,62 @@ def _slots_by_token(rows: jax.Array, k: int) -> jax.Array:
     return jax.lax.optimization_barrier(rows.reshape(-1, k, *rows.shape[1:]))
 
 
+class Held(NamedTuple):
+    """What a share's moves and sums know of its routing (``_experts``
+    makes it; ``None`` where every expert is held). The held experts'
+    rows are the first ``extent`` of the sorted order; ``mask`` [N, k]
+    says which of a token's slots they are; ``scale`` [N * k] are the
+    combine weights in sorted order, which the share's sort carries
+    along (the gather that makes them otherwise, 1.1 ms over 131,072
+    slots, would be the longest operation left in the combine's
+    gradient, for the 1/16 of them that is read)."""
+    extent: jax.Array  # int32 scalar
+    mask: jax.Array  # bool [N, k]
+    scale: jax.Array  # float32 [N * k], no gradient
+
+
+def _extent(held: Optional[Held]) -> Optional[jax.Array]:
+    return None if held is None else held.extent
+
+
+def _held_alone(x: jax.Array, held: Optional[Held]) -> jax.Array:
+    """``x`` [N, k, ...] with zeros where a slot's expert is absent. A
+    select, never a product: those places of the token-order view were
+    not written (``rows_back`` under an extent) and may hold NaN."""
+    return x if held is None else jnp.where(held.mask.reshape(held.mask.shape + (1,) * (x.ndim - 2)), x, 0)
+
+
 @jax.custom_vjp
-def _dispatch(tokens: jax.Array, order: jax.Array) -> jax.Array:
+def _dispatch(tokens: jax.Array, order: jax.Array, held: Optional[Held] = None) -> jax.Array:
     """Each token's row to its k slots, the slots sorted by expert:
     ``tokens[order // k]``, [N, hidden] bfloat16 -> [N * k, hidden].
     ``order`` [N * k] lists the (token, slot) pairs in sorted order. The
     gradient brings every slot's row back to its place (a permutation:
     ``rows_back``) and sums each token's k, never a scatter-add (24.8 ms
-    against 8.9 for the gather at the published sizes, PERF.md section 5)."""
+    against 8.9 for the gather at the published sizes, PERF.md section 5).
+    With ``held`` the rows past its extent are not moved, either way:
+    the result's tail is uninitialised, and the gradient sums a token's
+    held slots alone."""
     k = order.shape[0] // tokens.shape[0]
-    return rows_out(row_view(tokens), order // k, interpret=_interpret())
+    return rows_out(row_view(tokens), order // k, extent=_extent(held), interpret=_interpret())
 
 
-def _dispatch_fwd(tokens, order):
-    return _dispatch(tokens, order), order.reshape(tokens.shape[0], -1)
+def _dispatch_fwd(tokens, order, held):
+    return _dispatch(tokens, order, held), (order.reshape(tokens.shape[0], -1), held)
 
 
-def _dispatch_bwd(order, g):
+def _dispatch_bwd(res, g):
+    order, held = res
     n, k = order.shape
-    per_slot = _slots_by_token(rows_back(g, order.reshape(n * k), interpret=_interpret()), k)
-    return per_slot.sum(axis=1).reshape(n, -1), None
+    per_slot = _slots_by_token(rows_back(g, order.reshape(n * k), extent=_extent(held), interpret=_interpret()), k)
+    return _held_alone(per_slot, held).sum(axis=1).reshape(n, -1), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(out: jax.Array, weight: jax.Array, order: jax.Array) -> jax.Array:
+def _combine(out: jax.Array, weight: jax.Array, order: jax.Array, held: Optional[Held] = None) -> jax.Array:
     """The experts' sorted rows ``out`` [N * k, hidden] bfloat16 back in
     token order (``rows_back``) and each token's k summed under
     ``weight`` [N, k], float32 weights and sum: [N, hidden] float32. The
@@ -323,24 +366,29 @@ def _combine(out: jax.Array, weight: jax.Array, order: jax.Array) -> jax.Array:
     128]``, which is also the residual of the weights' gradient. The
     gradient to ``out`` is the dispatch again with a scale: each slot's
     token's cotangent times the slot's weight in float32, then rounded
-    to bfloat16 (``rows_out``)."""
-    return _combine_fwd(out, weight, order)[0]
+    to bfloat16 (``rows_out``). With ``held`` the rows of ``out`` past
+    its extent are not read and those of its gradient not written; the
+    sums take a token's held slots alone, and an absent slot's weight
+    has no gradient."""
+    return _combine_fwd(out, weight, order, held)[0]
 
 
-def _combine_fwd(out, weight, order):
+def _combine_fwd(out, weight, order, held):
     n, k = weight.shape
-    per_slot = _slots_by_token(rows_back(out, order, interpret=_interpret()), k)
-    mixed = jnp.sum(weight[:, :, None, None] * per_slot.astype(jnp.float32), axis=1)
-    return mixed.reshape(n, -1), (per_slot, weight, order)
+    per_slot = _slots_by_token(rows_back(out, order, extent=_extent(held), interpret=_interpret()), k)
+    mixed = jnp.sum(weight[:, :, None, None] * _held_alone(per_slot, held).astype(jnp.float32), axis=1)
+    return mixed.reshape(n, -1), (per_slot, weight, order, held)
 
 
 def _combine_bwd(res, g):
-    per_slot, weight, order = res
+    per_slot, weight, order, held = res
     n, k = weight.shape
     g = row_view(g)
-    d_weight = jnp.sum(g[:, None] * per_slot.astype(jnp.float32), axis=(2, 3))
-    d_out = rows_out(g, order // k, weight.reshape(n * k)[order], dtype=per_slot.dtype, interpret=_interpret())
-    return d_out, d_weight, None
+    # A slot's sum is over its own row alone, so the select may follow it (inside the reduce it costs 0.8 ms a layer, PERF.md section 6, PR 34).
+    d_weight = _held_alone(jnp.sum(g[:, None] * per_slot.astype(jnp.float32), axis=(2, 3)), held)
+    scale = weight.reshape(n * k)[order] if held is None else held.scale
+    d_out = rows_out(g, order // k, scale, extent=_extent(held), dtype=per_slot.dtype, interpret=_interpret())
+    return d_out, d_weight, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -400,50 +448,58 @@ def _expert_ffn(rows: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax
     return grouped_matmul(jax.nn.silu(gate) * up, down_w, group_sizes, first)
 
 
-def _routed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first: Optional[int], layer: str) -> jax.Array:
+def _first_group(held: Optional[Held]) -> Optional[int]:
+    """``gmm``'s ``group_offset``: a share's groups come first in its
+    sorted order (``_experts``), so its first held group is group 0."""
+    return None if held is None else 0
+
+
+def _routed(n2, weight, order, group_sizes, held: Optional[Held], gate_w, up_w, down_w, layer: str) -> jax.Array:
     """Dispatch, the held experts and combine: [N, hidden] float32 normed
     tokens -> the weighted sum of each token's held slots, same shape."""
     with jax.named_scope(f"{layer}.dispatch"):
-        rows = _dispatch(n2.astype(jnp.bfloat16), order)
+        rows = _dispatch(n2.astype(jnp.bfloat16), order, held)
     with jax.named_scope(f"{layer}.experts"):
-        out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, first)
+        out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, _first_group(held))
     with jax.named_scope(f"{layer}.combine"):
-        return _combine(out, weight, order)
+        return _combine(out, weight, order, held)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _routed_recomputed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first: Optional[int], layer: str) -> jax.Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _routed_recomputed(n2, weight, order, group_sizes, held: Optional[Held], gate_w, up_w, down_w, layer: str) -> jax.Array:
     """``_routed`` that keeps nothing of slot size for its gradient: the
     sorted rows, the products' intermediates and the rows in token order
-    (1.75 GiB a layer at 131,072 slots of hidden 2048) are made again in
+    (1.75 GiB a layer at 131,072 slots of hidden 2048, whatever the
+    extent of the moves: the buffers keep the dropless worst case's
+    shape) are made again in
     the backward pass from the tokens, which the router keeps anyway.
     Written out by parts, each under its own scope and never nested in a
     layer's, because the benchmark's scope table reads two levels of a
     path and ``jax.checkpoint`` would put its own two first."""
-    return _routed(n2, weight, order, group_sizes, gate_w, up_w, down_w, first, layer)
+    return _routed(n2, weight, order, group_sizes, held, gate_w, up_w, down_w, layer)
 
 
-def _routed_recomputed_fwd(n2, weight, order, group_sizes, gate_w, up_w, down_w, first, layer):
-    args = (n2, weight, order, group_sizes, gate_w, up_w, down_w)
-    return _routed(*args, first, layer), args
+def _routed_recomputed_fwd(n2, weight, order, group_sizes, held, gate_w, up_w, down_w, layer):
+    args = (n2, weight, order, group_sizes, held, gate_w, up_w, down_w)
+    return _routed(*args, layer), args
 
 
-def _routed_recomputed_bwd(first, layer, args, g):
-    n2, weight, order, group_sizes, gate_w, up_w, down_w = args
+def _routed_recomputed_bwd(layer, args, g):
+    n2, weight, order, group_sizes, held, gate_w, up_w, down_w = args
     # Tied to the cotangent's arrival, as jax.checkpoint ties its own: nothing else keeps XLA from
     # making every layer's rows again at once, as soon as the forward pass has the tokens.
     n2, g = jax.lax.optimization_barrier((n2, g))
     with jax.named_scope(f"{layer}.dispatch"):
-        rows, pull_rows = jax.vjp(lambda t: _dispatch(t.astype(jnp.bfloat16), order), n2)
+        rows, pull_rows = jax.vjp(lambda t: _dispatch(t.astype(jnp.bfloat16), order, held), n2)
     with jax.named_scope(f"{layer}.experts"):
-        out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, first), rows, gate_w, up_w, down_w)
+        out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, _first_group(held)), rows, gate_w, up_w, down_w)
     with jax.named_scope(f"{layer}.combine"):
-        d_out, d_weight = jax.vjp(lambda o, w: _combine(o, w, order), out, weight)[1](g)
+        d_out, d_weight = jax.vjp(lambda o, w: _combine(o, w, order, held), out, weight)[1](g)
     with jax.named_scope(f"{layer}.experts"):
         d_rows, *d_weights = pull_ffn(d_out)
     with jax.named_scope(f"{layer}.dispatch"):
         (d_n2,) = pull_rows(d_rows)
-    return (d_n2, d_weight, None, None, *d_weights)
+    return (d_n2, d_weight, None, None, None, *d_weights)
 
 
 _routed_recomputed.defvjp(_routed_recomputed_fwd, _routed_recomputed_bwd)
@@ -452,21 +508,33 @@ _routed_recomputed.defvjp(_routed_recomputed_fwd, _routed_recomputed_bwd)
 def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """[N, hidden] float32 normed tokens -> the held routed experts'
     weighted sum [N, hidden] float32, and the layer's routing counters.
-    Enters its own scopes: call it under none of a layer's."""
+    Enters its own scopes: call it under none of a layer's.
+
+    A share sorts its slots on ``(expert - first) mod experts``, so the
+    held experts' rows are always rows ``[0, held)`` of the sorted order,
+    their groups the first ``count``; that count of rows, the layer's
+    own, on the device, is the extent of every move (``Held``). The
+    counters keep the experts' own order."""
     n, k = n2.shape[0], cfg.experts_per_token
+    first, count = cfg.held
     with jax.named_scope(f"{layer}.router"):
         expert, weight, probs = _route(n2, p, cfg)
     with jax.named_scope(f"{layer}.dispatch"):
-        slot_expert = expert.reshape(n * k)
-        order = jnp.argsort(slot_expert, stable=True)
-        group_sizes = jnp.sum(slot_expert[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
+        group = expert.reshape(n * k)
+        if cfg.held_experts:
+            group = (group - first) % cfg.experts
+            _, order, scale = jax.lax.sort((group, jnp.arange(n * k, dtype=jnp.int32), jax.lax.stop_gradient(weight).reshape(n * k)),
+                                           num_keys=1, is_stable=True)
+        else:
+            order = jnp.argsort(group, stable=True)
+        group_sizes = jnp.sum(group[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
+        held = Held(jnp.sum(group_sizes[:count]), group.reshape(n, k) < count, scale) if cfg.held_experts else None
     routed = _routed_recomputed if cfg.recompute_experts else _routed
-    mixed = routed(n2, weight, order, group_sizes, p["experts_gate"], p["experts_up"], p["experts_down"],
-                   cfg.held_experts[0] if cfg.held_experts else None, layer)
-    load = group_sizes.astype(jnp.float32)
+    mixed = routed(n2, weight, order, group_sizes, held, p["experts_gate"], p["experts_up"], p["experts_down"], layer)
+    load = (jnp.roll(group_sizes, first) if cfg.held_experts else group_sizes).astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
     return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy,
-                   "expert_slots": load}
+                   "expert_slots": load, "moved_rows": jnp.asarray(rows_covered(n * k, _extent(held)), jnp.float32)}
 
 
 def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
@@ -486,7 +554,10 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     in nats (``router_entropy``: of the softmax, or of the sigmoid scores
     over their sum) and every routed layer's slots an expert
     (``expert_slots`` [routed layers, experts], what the balance update
-    reads); for a share, the slots that fell on the held experts, summed
+    reads); the rows each of a routed layer's moves covers, summed over
+    the layers (``moved_rows``: every slot where all experts are held; a
+    share's held count a layer, rounded up to whole blocks of the move);
+    for a share, the slots that fell on the held experts, summed
     over the layers (``held_slots``); with an ``expert_bias`` among
     ``params``, its largest magnitude (``expert_bias_abs_max``)."""
     b = planes.shape[0]
@@ -530,6 +601,7 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         "expert_load_min": jnp.min(jnp.stack([c["expert_load_min"] for c in counters])),
         "router_entropy": jnp.mean(jnp.stack([c["router_entropy"] for c in counters])),
         "expert_slots": slots,
+        "moved_rows": jnp.sum(jnp.stack([c["moved_rows"] for c in counters])),
         **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
         **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
     })

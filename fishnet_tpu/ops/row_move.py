@@ -21,6 +21,24 @@ Each is the other's transpose. The next block's DMAs are in flight while
 this block is reshaped (two buffers, one DMA semaphore each, one wait
 for a buffer's bytes). Off the TPU both run under the Pallas
 interpreter, as the FT gather (``ops/ft_gather.py``) does.
+
+The extent of a move. Both take an optional ``extent``, an int32 scalar
+on the device: only rows ``[0, extent)`` of the slot side matter to the
+caller (a share of the experts: the rows of the experts it holds, which
+its sort puts first). The grid then has ``cdiv(extent, tm)`` (+ 1)
+steps, a traced bound as megablox ``gmm``'s, and the blocks past them
+are neither fetched, reshaped nor written back. Shapes do not change,
+so what lies past the last moved block is UNINITIALISED, not zero:
+the tail rows of ``rows_out``'s result, and in ``rows_back``'s result
+every place ``index[i]`` of a row ``i`` that was not moved (there, "every
+row is written exactly once" holds only without an extent). Who may
+read them: the grouped products, which visit the held groups' rows
+alone and mask a straddling tile by ``select``; and the sums over a
+token's slots in ``models/trunk.py``, which select by the slot's mask
+and never multiply, because what is there may be NaN (the interpreter
+fills it with NaN, which is what the tests lean on). The block that
+straddles ``extent`` moves whole. Without ``extent`` the grid is the
+static one and the kernels are what they were.
 """
 
 from __future__ import annotations
@@ -33,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["row_view", "rows_out", "rows_back"]
+__all__ = ["row_view", "rows_out", "rows_back", "rows_covered"]
 
 #: Rows a grid step, and DMA starts unrolled in one loop body: the fastest
 #: of 128-1024 rows and 1-64 starts on a v5e at 262,144 rows of 4 KiB
@@ -66,6 +84,20 @@ def _tile(rows: int, most: int = _TM) -> int:
     """The most rows a grid step, dividing ``rows`` as the grouped
     product's tile does."""
     return math.gcd(rows, most)
+
+
+def _blocks(rows: int, tm: int, extent: Optional[jax.Array]):
+    """The blocks of ``tm`` rows a move covers: all of them, or, as a
+    traced grid bound, those that hold rows ``[0, extent)``."""
+    return rows // tm if extent is None else pl.cdiv(jnp.asarray(extent, jnp.int32), tm)
+
+
+def rows_covered(rows: int, extent: Optional[jax.Array]):
+    """The rows a move of ``rows`` rows really covers under ``extent``:
+    whole blocks of the row tile (the scaled ``rows_out`` moves in
+    smaller blocks and covers up to ``_TM - _TM_SCALED`` rows fewer)."""
+    tm = _tile(rows)
+    return _blocks(rows, tm, extent) * tm
 
 
 #: A one-dimensional int32 operand is tiled by 1024 on the TPU, so the
@@ -146,11 +178,14 @@ def _rows_out_kernel(idx_ref, src_ref, *rest, scaled: bool, at, unroll: int):
 
 
 def rows_out(src: jax.Array, index: jax.Array, scale: Optional[jax.Array] = None, *,
-             dtype=None, interpret: bool = False) -> jax.Array:
+             extent: Optional[jax.Array] = None, dtype=None, interpret: bool = False) -> jax.Array:
     """``src[index]`` for ``src`` in the row view ``[n, sub, lanes]``,
     as ``[len(index), sub * lanes]`` of ``dtype`` (default: ``src``'s).
     With ``scale`` (float32, one a row of the result) each row is
-    multiplied in float32 before it is rounded to ``dtype``."""
+    multiplied in float32 before it is rounded to ``dtype``. With
+    ``extent`` rows ``[0, extent)`` of the result alone are promised:
+    the blocks past them are never written and hold whatever the buffer
+    held."""
     n, sub, lanes = src.shape
     m = index.shape[0]
     tm = _tile(m, _TM if scale is None else _TM_SCALED)
@@ -164,7 +199,7 @@ def rows_out(src: jax.Array, index: jax.Array, scale: Optional[jax.Array] = None
         operands.append(scale.astype(jnp.float32).reshape(m, 1))
     return pl.pallas_call(
         lambda *refs: _rows_out_kernel(*refs, scaled=scale is not None, at=at, unroll=_unroll(interpret)),
-        grid=(m // tm + 1,),
+        grid=(_blocks(m, tm, extent) + 1,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, sub * lanes), before),
         out_shape=jax.ShapeDtypeStruct((m, sub * lanes), dtype),
@@ -197,18 +232,24 @@ def _rows_back_kernel(idx_ref, rows_ref, out_ref, buf, sem, *, at, unroll: int):
             _wait(buf, sem, 1 - slot)
 
 
-def rows_back(rows: jax.Array, index: jax.Array, *, interpret: bool = False) -> jax.Array:
+def rows_back(rows: jax.Array, index: jax.Array, *, extent: Optional[jax.Array] = None,
+              interpret: bool = False) -> jax.Array:
     """``out[index[i]] = rows[i]`` for a permutation ``index``: the
-    result in the row view ``[len(index), sub, lanes]``. Every row of
-    the result is written exactly once, so nothing is added and nothing
-    needs zeroing."""
+    result in the row view ``[len(index), sub, lanes]``. Without
+    ``extent`` every row of the result is written exactly once, so
+    nothing is added and nothing needs zeroing. With it only rows ``[0,
+    extent)`` of ``rows`` are promised to arrive (whole blocks do): every
+    other place of the result, ``index[i]`` for ``i`` past the last
+    moved block, is never written and holds whatever the buffer held.
+    The reader has to know which places those are and select, never
+    multiply: what is there may be NaN."""
     m = rows.shape[0]
     shape = row_view(rows).shape
     tm = _tile(m)
     index, spec, at = _index_blocks(index, tm)
     return pl.pallas_call(
         lambda *refs: _rows_back_kernel(*refs, at=at, unroll=_unroll(interpret)),
-        grid=(m // tm,),
+        grid=(_blocks(m, tm, extent),),
         in_specs=[spec, pl.BlockSpec((tm, rows.shape[1]), lambda i: (i, 0))],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(shape, rows.dtype),

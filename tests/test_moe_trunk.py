@@ -234,27 +234,53 @@ def test_grouped_matmul_against_a_loop_over_experts(rows, sizes):
     assert not np.any(np.asarray(got_dw)[np.asarray(sizes) == 0])  # an expert with no rows has no gradient
 
 
-#: tokens, top-k, hidden, which experts the slots go to. Slots = tokens x k
-#: against the row tile of 512: 64, 192 and 640 are not multiples of it
-#: (tiles of 64, 64 and 128), 1024 and 1536 are.
+#: tokens, top-k, hidden, which experts the slots go to, and for a share
+#: (first held expert, held slots): the extent of its moves. Slots =
+#: tokens x k against the row tile of 512: 64, 192 and 640 are not
+#: multiples of it (tiles of 64, 64 and 128), 1024 and 1536 are. The
+#: shares' extents: none, one row, one under and one over a block's edge,
+#: all rows; one share whose experts are not the first.
 MOVE_CASES = [
-    (64, 1, 64, "random"),
-    (512, 2, 64, "one_expert"),
-    (96, 2, 256, "some_empty"),
-    (128, 8, 256, "random"),
-    (320, 2, 256, "one_expert"),
-    (192, 8, 64, "some_empty"),
-    (24, 8, 2048, "random"),
-    (64, 1, 2048, "some_empty"),
+    (64, 1, 64, "random", None),
+    (512, 2, 64, "one_expert", None),
+    (96, 2, 256, "some_empty", None),
+    (128, 8, 256, "random", None),
+    (320, 2, 256, "one_expert", None),
+    (192, 8, 64, "some_empty", None),
+    (24, 8, 2048, "random", None),
+    (64, 1, 2048, "some_empty", None),
+    (192, 8, 64, "share", (0, 0)),
+    (192, 8, 64, "share", (0, 1)),
+    (192, 8, 64, "share", (0, 511)),
+    (192, 8, 64, "share", (0, 513)),
+    (192, 8, 64, "share", (0, 1536)),
+    (192, 8, 64, "share", (5, 700)),
+    (96, 2, 256, "share", (2, 70)),
 ]
-MOVE_IDS = [f"n{n}-k{k}-h{h}-{routing}" for n, k, h, routing in MOVE_CASES]
+MOVE_IDS = [f"n{n}-k{k}-h{h}-{routing}" + (f"-first{share[0]}-held{share[1]}" if share else "") for n, k, h, routing, share in MOVE_CASES]
+HELD_COUNT = 3  # of the 8 experts of a share's move cases
 
 
-def _sorted_order(rng, slots: int, routing: str):
-    """``order`` as ``_experts`` makes it: the stable sort of each slot's expert."""
-    experts = {"random": rng.integers(0, 8, slots), "one_expert": np.full(slots, 3),
-               "some_empty": rng.choice([1, 4, 6], slots)}[routing]
-    return jnp.argsort(jnp.asarray(experts, jnp.int32), stable=True)
+def _sorted_order(rng, tokens: int, top_k: int, routing: str, share=None, weight=None):
+    """``order`` as ``_experts`` makes it: the stable sort of each slot's
+    expert; for a share, of ``(expert - first) mod 8``, with ``Held``:
+    the count of slots on its ``HELD_COUNT`` experts, the slots' mask and
+    ``weight`` [tokens, top_k] in sorted order."""
+    slots = tokens * top_k
+    if share is None:
+        experts = {"random": rng.integers(0, 8, slots), "one_expert": np.full(slots, 3),
+                   "some_empty": rng.choice([1, 4, 6], slots)}[routing]
+        return jnp.argsort(jnp.asarray(experts, jnp.int32), stable=True), None
+    first, held_slots = share
+    held = (first + rng.integers(0, HELD_COUNT, slots)) % 8
+    absent = (first + rng.integers(HELD_COUNT, 8, slots)) % 8
+    experts = np.where(rng.permutation(slots) < held_slots, held, absent)
+    group = jnp.asarray((experts - first) % 8, jnp.int32)
+    mask = (group < HELD_COUNT).reshape(tokens, top_k)
+    assert int(mask.sum()) == held_slots
+    order = jnp.argsort(group, stable=True)
+    scale = jnp.zeros(slots) if weight is None else weight.reshape(slots)[order]
+    return order, trunk.Held(jnp.asarray(held_slots, jnp.int32), mask, scale)
 
 
 def _small_integers(rng, shape):
@@ -263,49 +289,78 @@ def _small_integers(rng, shape):
     return jnp.asarray(rng.integers(-8, 9, shape), jnp.float32)
 
 
-@pytest.mark.parametrize("tokens,top_k,hidden,routing", MOVE_CASES, ids=MOVE_IDS)
-def test_dispatch_is_plain_indexing_and_its_gradient(tokens, top_k, hidden, routing):
+def _poisoned(x, held):
+    """``x`` [slots, hidden] with NaN in every row past a share's extent:
+    what a sorted buffer may hold there."""
+    return x if held is None else jnp.where(jnp.arange(x.shape[0])[:, None] < held.extent, x, jnp.nan)
+
+
+def _covered(slots: int, held, most: int = 512) -> int:
+    """The rows a move covers: whole blocks of the row tile."""
+    tile = np.gcd(slots, most)
+    return slots if held is None else -(-int(held.extent) // tile) * tile
+
+
+@pytest.mark.parametrize("tokens,top_k,hidden,routing,share", MOVE_CASES, ids=MOVE_IDS)
+def test_dispatch_is_plain_indexing_and_its_gradient(tokens, top_k, hidden, routing, share):
     """``rows_out`` without a scale, and ``rows_back`` plus the sum over a
     token's slots as its gradient, against ``x[index]`` and the
-    scatter-add that is its autodiff."""
+    scatter-add that is its autodiff. A share's moves stop at its extent:
+    rows past the last moved block stay what the buffer held (NaN under
+    the interpreter), and the gradient reads no cotangent past the extent
+    (they are NaN here)."""
     rng = np.random.default_rng(tokens + top_k + hidden)
-    order = _sorted_order(rng, tokens * top_k, routing)
+    slots = tokens * top_k
+    order, held = _sorted_order(rng, tokens, top_k, routing, share)
+    extent = slots if held is None else int(held.extent)
     x = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.bfloat16)
-    cot = _small_integers(rng, (tokens * top_k, hidden))
+    cot = _small_integers(rng, (slots, hidden))
     plain = lambda x: x[order // top_k]
-    got = jax.jit(trunk._dispatch)(x, order)
-    assert got.dtype == jnp.bfloat16 and np.array_equal(np.asarray(got, np.float32), np.asarray(plain(x), np.float32))  # bit for bit
-    loss = lambda move: lambda x: jnp.sum(move(x.astype(jnp.bfloat16)).astype(jnp.float32) * cot)
-    got_dx = jax.jit(jax.grad(loss(lambda x: trunk._dispatch(x, order))))(x.astype(jnp.float32))
-    want_dx = jax.grad(loss(plain))(x.astype(jnp.float32))
+    got = jax.jit(lambda x: trunk._dispatch(x, order, held))(x)
+    assert got.dtype == jnp.bfloat16 and got.shape == (slots, hidden)
+    assert np.array_equal(np.asarray(got, np.float32)[:extent], np.asarray(plain(x), np.float32)[:extent])  # bit for bit
+    assert np.all(np.isnan(np.asarray(got, np.float32)[max(_covered(slots, held), np.gcd(slots, 512)):]))  # never written (one block always is)
+    move = lambda x: trunk._dispatch(x.astype(jnp.bfloat16), order, held).astype(jnp.float32)
+    got_dx = jax.jit(lambda x, c: jax.vjp(move, x)[1](c)[0])(x.astype(jnp.float32), _poisoned(cot, held))
+    want_dx = jax.vjp(lambda x: plain(x.astype(jnp.bfloat16)).astype(jnp.float32), x.astype(jnp.float32))[1](
+        jnp.where(jnp.arange(slots)[:, None] < extent, cot, 0.0))[0]
     assert np.array_equal(np.asarray(got_dx), np.asarray(want_dx))
 
 
-@pytest.mark.parametrize("tokens,top_k,hidden,routing", MOVE_CASES, ids=MOVE_IDS)
-def test_combine_is_plain_indexing_a_weighted_sum_and_their_gradient(tokens, top_k, hidden, routing):
+@pytest.mark.parametrize("tokens,top_k,hidden,routing,share", MOVE_CASES, ids=MOVE_IDS)
+def test_combine_is_plain_indexing_a_weighted_sum_and_their_gradient(tokens, top_k, hidden, routing, share):
     """``rows_back`` and the float32 weighted sum, with ``rows_out`` under
     the per-slot scale as the gradient to the rows, against ``jnp.take``
-    by the inverse permutation, the same sum, and their autodiff."""
+    by the inverse permutation, the same sum, and their autodiff. A share
+    reads no row past its extent (they are NaN here), sums a token's held
+    slots alone, and leaves the rows' gradient past the last moved block
+    unwritten."""
     rng = np.random.default_rng(tokens * top_k + hidden)
-    order = _sorted_order(rng, tokens * top_k, routing)
-    inverse = jnp.argsort(order)
-    out = jnp.asarray(rng.standard_normal((tokens * top_k, hidden)), jnp.bfloat16)
+    slots = tokens * top_k
     weight = jnp.asarray(rng.random((tokens, top_k)) + 0.1, jnp.float32)
+    order, held = _sorted_order(rng, tokens, top_k, routing, share, weight)
+    extent = slots if held is None else int(held.extent)
+    inverse = jnp.argsort(order)
+    out = jnp.asarray(rng.standard_normal((slots, hidden)), jnp.bfloat16)
     cot = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
 
     def plain(out, weight):
+        out = jnp.where(jnp.arange(slots)[:, None] < extent, out, 0)  # an absent expert's rows add nothing
         per_slot = jnp.take(out, inverse, axis=0).reshape(tokens, top_k, hidden)
         return jnp.einsum("nk,nkh->nh", weight, per_slot.astype(jnp.float32))
 
-    got = jax.jit(lambda o, w: trunk._combine(o, w, order))(out, weight)
+    combine = lambda o, w: trunk._combine(_poisoned(o, held), w, order, held)
+    got = jax.jit(combine)(out, weight)
     assert got.dtype == jnp.float32 and np.allclose(got, plain(out, weight), rtol=1e-6, atol=1e-6)
     if top_k == 1:  # one slot a token: the move alone, bit for bit
         assert np.array_equal(np.asarray(got), np.asarray(out[inverse].astype(jnp.float32) * weight))
     loss = lambda f: lambda o, w: jnp.sum(f(o.astype(jnp.bfloat16), w) * cot)
-    got_do, got_dw = jax.jit(jax.grad(loss(lambda o, w: trunk._combine(o, w, order)), (0, 1)))(out.astype(jnp.float32), weight)
+    got_do, got_dw = jax.jit(jax.grad(loss(combine), (0, 1)))(out.astype(jnp.float32), weight)
     want_do, want_dw = jax.grad(loss(plain), (0, 1))(out.astype(jnp.float32), weight)
-    assert np.array_equal(np.asarray(got_do), np.asarray(want_do))  # a permutation: one term a row, rounded once after the scale
-    assert np.allclose(got_dw, want_dw, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(want_dw))))  # float32 sums in another order
+    assert np.array_equal(np.asarray(got_do)[:extent], np.asarray(want_do)[:extent])  # a permutation: one term a row, rounded once after the scale
+    assert np.all(np.isfinite(got_dw)) and np.allclose(got_dw, want_dw, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(want_dw))))  # float32 sums in another order
+    if held is not None:
+        assert not np.any(np.asarray(got_dw)[~np.asarray(held.mask)])  # an absent slot's weight has no gradient
 
 
 def test_trainer_overfits_a_small_batch():
@@ -609,3 +664,68 @@ def test_recomputing_the_routed_branch_changes_no_number():
     import re
     names = set(re.findall(r'op_name="jit\(_step\)/(transpose\(jvp\(forward\)\)/layer\d+\.\w+)/', text))
     assert {f"transpose(jvp(forward))/layer0{i}.{part}" for i in (1, 2) for part in ("dispatch", "experts", "combine")} <= names, names
+
+
+def _afmoe_loss_and_grads(cfg, params, batch):
+    trainer = AzTrainer(cfg)
+    trained = {k: v for k, v in params.items() if k != "expert_bias"}
+    return jax.jit(jax.value_and_grad(lambda q: trainer._loss(q, batch, {"expert_bias": params["expert_bias"]}), has_aux=True))(trained)
+
+
+@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
+def test_the_extent_of_a_shares_moves_changes_no_number(monkeypatch, recompute):
+    """A share (experts 4-11 of 16: not the first) moves the rows of its
+    extent alone and leaves NaN in every tail (the interpreter's
+    uninitialised memory); moved in full, the same tails hold the zeros
+    ``gmm`` writes for absent experts. The loss and every gradient are
+    the same to the bit: nothing reads a tail but through a select."""
+    cfg = TrunkConfig(**{**AFMOE.__dict__, "recompute_experts": recompute})
+    params, batch = afmoe_params(6), batch_of(6)
+    (loss, aux), grads = _afmoe_loss_and_grads(cfg, params, batch)
+    assert 0 < float(aux["held_slots"]) < 2 * BATCH * 64 * 4 and float(aux["moved_rows"]) < 2 * BATCH * 64 * 4  # a share indeed
+    monkeypatch.setattr(trunk, "_extent", lambda held: None)  # the oracle: every move in full
+    (full_loss, _), full_grads = _afmoe_loss_and_grads(cfg, params, batch)
+    assert np.isfinite(float(loss)) and float(loss) == float(full_loss)
+    for name, want in full_grads.items():
+        assert np.array_equal(np.asarray(grads[name]), np.asarray(want)), name
+
+
+@pytest.mark.parametrize("bias,held_share", [(10.0, 1.0), (-10.0, 0.0)], ids=["every_slot_held", "no_slot_held"])
+def test_a_share_is_dropless_at_both_ends(bias, held_share):
+    """A choice pushed wholly onto the held experts (8 of 16 held, top-4),
+    and wholly off them: the moves cover every row, or one block of a
+    kernel that has nothing to move, and the step's loss and gradients
+    are the plain reference's, all finite. Nothing is capped either way."""
+    params, batch = afmoe_params(7), batch_of(7)
+    push = jnp.zeros((AFMOE.routed_layers, AFMOE.experts)).at[:, 4:12].set(bias)  # sigmoid scores lie in (0, 1)
+    params["expert_bias"] = push
+    (loss, aux), got = _afmoe_loss_and_grads(AFMOE, params, batch)
+    slots = AFMOE.routed_layers * BATCH * 64 * AFMOE.experts_per_token
+    assert float(aux["held_slots"]) == held_share * slots and float(aux["moved_rows"]) == held_share * slots
+    want_loss, want = jax.value_and_grad(afmoe_reference_loss)(params, batch, AFMOE)
+    assert np.isfinite(float(loss)) and abs(float(loss) - float(want_loss)) < 0.01 * abs(float(want_loss)), (float(loss), float(want_loss))
+    want.pop("expert_bias")
+    total = lambda a, b: np.sqrt(sum(float(jnp.sum((a[k] - b[k]) ** 2)) for k in b) / sum(float(jnp.sum(b[k] ** 2)) for k in b))
+    assert total(got, want) < GRAD_ALL_TOL, total(got, want)
+    for name in want:
+        assert np.all(np.isfinite(got[name])), name
+        if float(jnp.linalg.norm(want[name])) == 0:  # no slot held: the experts and the router reach no loss
+            assert name in ("experts_gate", "experts_up", "experts_down", "router_w") and held_share == 0 and not np.any(np.asarray(got[name])), name
+        else:
+            assert rel(got[name], want[name]) < (GRAD_CANCELLING_TOL if name in CANCELLING else GRAD_TENSOR_TOL), name
+
+
+def test_moved_rows_against_a_hand_count():
+    """``moved_rows``: what the row moves of a step's routed layers cover.
+    Where every expert is held, every slot of every layer; for a share,
+    each layer's held count rounded up to the moves' block (512 rows at
+    2,048 slots a layer)."""
+    _, _, counters = jax.jit(lambda p, x: trunk.trunk_forward_counted(p, x, TINY))(conditioned_params(1), batch_of(1)["planes"])
+    assert float(counters["moved_rows"]) == TINY.layers * BATCH * 64 * TINY.experts_per_token and "held_slots" not in counters
+    _, _, counters = jax.jit(lambda p, x: trunk.trunk_forward_counted(p, x, AFMOE))(afmoe_params(1), batch_of(1)["planes"])
+    held = np.asarray(counters["expert_slots"])[:, 4:12].sum(axis=1)  # a layer
+    assert held.sum() == float(counters["held_slots"]) and np.all(held % 512 != 0)  # the rounding shows
+    assert float(counters["moved_rows"]) == sum(-(-int(h) // 512) * 512 for h in held)
+    trainer = AzTrainer(AFMOE)
+    _, metrics = trainer.step(trainer.init(1), batch_of(1))
+    assert 0 < float(metrics["moved_rows"]) <= 2 * BATCH * 64 * 4 and float(metrics["moved_rows"]) % 512 == 0  # in the step's metrics

@@ -151,13 +151,24 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
     assert "board_attention" in text and "board_attention_grad" in text
 
 
+#: Temporaries of the program below as the parent of PR 34 compiled it (every move over all 131,072 slots), bytes.
+#: The extent adds one ``pred[16384,8]`` mask a layer, tiled (8, 128): 2 MiB.
+PARENT_TEMPORARIES = {False: 2_016_916_480, True: 2_930_790_400}
+MASK_BYTES = 16_384 * 128
+
+
 @pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
 def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_for_tpu, recompute):
     """``value_and_grad`` of ``_experts`` holding 8 of 128 experts over
     131,072 slots: ``gmm`` and ``tgmm`` with a ``group_offset`` and weights
     ``[8, 2048, 1024]``; with ``recompute_experts`` the forward's kernels
-    are there a second time, in the backward pass."""
+    are there a second time, in the backward pass. The moves take the
+    held count as their grid's bound (Mosaic compiles a traced grid), the
+    buffers keep their shape, so the temporaries are the parent's; and no
+    ``cond`` or ``while`` wraps a layer's scope: every ``layerNN.<part>``
+    is the second level of its path, where ``benchmark/scopes.py`` reads it."""
     import dataclasses
+    import re
 
     cfg = dataclasses.replace(AFMOE, recompute_experts=recompute)
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -166,8 +177,17 @@ def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_
              "experts_down": sds((8, WIDTH, HIDDEN), jnp.float32)}
 
     def loss(n2, p):
-        return jnp.sum(trunk._experts(n2, p, cfg, "layer01")[0])
+        with jax.named_scope("forward"):  # the phase a trainer's step puts first
+            return jnp.sum(trunk._experts(n2, p, cfg, "layer01")[0])
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile()
+    text = compiled.as_text()
     kernels = text.count('custom_call_target="tpu_custom_call"')
     assert kernels == (9 + 4) + (3 + 2 if recompute else 0), kernels  # nine products and four moves; three and two of them made again
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= PARENT_TEMPORARIES[recompute] + MASK_BYTES, temporaries
+    names = {name for joined in re.findall(r'op_name="([^"]*)"', text) for name in joined.split(";") if re.search(r"layer\d+\.", name)}
+    second_level = re.compile(r"jit\(loss\)/(?:jvp\(forward\)|transpose\(jvp\(forward\)\))/layer01\.(?:router|dispatch|experts|combine)(?:/|$)")
+    assert len(names) > 100 and not [name for name in names if not second_level.match(name)]
+    assert {f"{phase}/layer01.{part}" for phase in ("jvp(forward)", "transpose(jvp(forward))") for part in ("router", "dispatch", "experts", "combine")} == {
+        re.match(r"jit\(loss\)/([^/]*/[^/]*)", name).group(1) for name in names}
