@@ -4,10 +4,10 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes two
-published blocks as one code path at different values; neither has a
-causal mask, and a board is far shorter than either's window, so running
-them over a board removes nothing.
+tower's own policy and value heads. ``TrunkConfig`` describes three
+published blocks as one code path at different values; none has a
+causal mask here, and a board is far shorter than any's window, so
+running them over a board removes nothing.
 
 The first block is LLaDA-MoE-7B-A1B's (inclusionAI, config.json: hidden
 2048, 16 heads x 128, qk-norm, RoPE theta 50000, 64 experts top-8,
@@ -54,6 +54,47 @@ under ``assumed`` in ``benchmark/configs/trinity-mini-trunk-train.json``.
               d = balance_rate * sign(mean(c) - c);  b <- b + d - mean(d)      (``train/az_trainer.py``)
     out       N_final(y) -> the heads
 
+The third block is Kanana-2-30B-A3B's (kakaocorp, config.json,
+``model_type`` deepseek_v3 with ``q_lora_rank`` null: hidden 2048, 32
+heads, keys and values through a 512-wide latent, a score of 128 NoPE +
+64 RoPE columns over values of 128, a leading dense layer of 6144, then
+128 routed experts of width 768, top-6, sigmoid scores, two shared
+experts, ``routed_scaling_factor`` 2.448, RMSNorm eps 1e-6, RoPE theta
+1e6 on interleaved pairs); what its config.json does not say is the
+public DeepSeek-V3 modelling code's, listed under ``assumed`` in
+``benchmark/configs/kanana-2-trunk-train.json``. H heads::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer     a = x + Attn(N_in(x));   y = a + FFN(N_post_attn(a))      (two norms a layer, no post-norms)
+    Attn      q      = n W_q                    [H x (nope + rope)];  q_nope, q_pe a head
+              ckv    = n W_kva                  [rank + rope];  c = N_kv(ckv[:rank]; gain kv_norm),  k_pe = ckv[rank:]  (ONE for all heads)
+              kv     = c W_kvb                  [H x (nope + value)];  k_nope, v a head
+              q_pe, k_pe <- RoPE(theta, position = square index, interleaved pairs (2i, 2i + 1), all rope columns)
+              s_h    = (q_nope_h k_nope_h^T + q_pe_h k_pe^T) / sqrt(nope + rope)      within a board, no mask, no qk-norm
+              out    = concat_h( softmax(s_h) v_h ) W_o                               [H x value -> hidden]
+    FFN       as the second block's: a leading dense layer; sigmoid scores, the choice on score + expert_bias, weights
+              renormalised over all the chosen and scaled; Shared: ONE SiLU-gated feed-forward of width
+              n_shared_experts x moe_intermediate_size beside the held experts; the same balance rule
+    out       N_final(y) -> the heads
+
+The latent is EXPANDED (k_nope and v are made from c for every head),
+not absorbed into the query: absorbing trades the ``rank -> H x (nope +
+value)`` product for scores and a mix over ``rank + rope`` = 576 columns
+a head in place of 192 and 128, which pays where one query meets a long
+cache of latents (decoding), and costs 3x-4.5x the core's products and
+bytes where 64 queries meet 64 keys and every key is made once and used
+once a head (a training step over a board).
+The program keeps the columns of this block's projections in its own
+order, which is a layout and not a departure: ``wq`` every head's NoPE
+columns, then every head's RoPE columns, each head's interleaved pairs
+taken apart into the halves that rotate-half turns
+(``ops.board_attention.latent_column_order``; ``wkv_a``'s RoPE columns
+likewise: a score is unchanged when q and k are permuted alike);
+``wkv_b`` every head's key columns, then every head's value columns. A
+checkpoint of the published per-head order is brought in by that
+permutation (``benchmark/families/mla_trunk.py`` does it for the
+reference's parameters and takes the gradients back).
+
 ``held_experts = (first, count)`` tells the expert layer which experts
 it holds, as one chip of an expert-parallel deployment does: it routes
 over all ``experts``, computes the part of the result that its own give
@@ -64,7 +105,9 @@ all chips and the shared expert once add up to the whole layer
 Mechanism, attention: the projections are XLA's; everything between
 them (qk-norm, RoPE, the 64 x 64 scores, softmax, mix) is one Pallas
 kernel pair (``ops/board_attention.py``: ``board_attention`` and its
-gradient ``board_attention_grad``) that takes q, k, v as the projections
+gradient ``board_attention_grad``; told the norm or its absence, the
+extent of RoPE, and whether the rotated key is one a key-value head or
+one for all heads) that takes q, k, v as the projections
 write them, ``[B, 64, heads * head_dim]`` and ``[B, 64, kv_heads *
 head_dim]``, and works on one key-value head and its group of query
 heads of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
@@ -167,10 +210,24 @@ class TrunkConfig:
     held_experts: Optional[Tuple[int, int]] = None  # (first, count); None: all of them
     balance_rate: float = 0.0  # over 0: the routed layers choose on score + expert_bias
     recompute_experts: bool = False  # training keeps nothing of slot size for the backward pass (``_routed_recomputed``)
+    # What the third block adds: keys and values through a latent (named after the published keys). None: the two blocks
+    # above, and the three widths below are not read. With it ``head_dim`` is not read: a head's score is ``qk_nope_head_dim
+    # + qk_rope_head_dim`` wide, RoPE on the second part alone, its value ``v_head_dim``; there is no qk-norm.
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self) -> None:
         first, count = self.held
+        latent = self.kv_lora_rank is not None
         wrong = {
+            f"a latent of {self.kv_lora_rank} wants qk_nope_head_dim, qk_rope_head_dim and v_head_dim, got "
+            f"{(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)}":
+                latent and min(self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) < 1,
+            f"qk_rope_head_dim {self.qk_rope_head_dim} is odd: RoPE turns pairs": latent and self.qk_rope_head_dim % 2,
+            "latent attention has one key and value a query head from the latent (no kv_heads), no output gate and RoPE on every "
+            "layer (no nope_layers)": latent and (self.kv_heads is not None or self.gated_attention or self.nope_layers),
             f"sliding_window {self.sliding_window} is under the {SQUARES} tokens of a board and the program applies no mask":
                 self.sliding_window is not None and self.sliding_window < SQUARES,
             f"{self.heads} query heads do not divide over {self.kv_heads} key-value heads": self.heads % (self.kv_heads or self.heads),
@@ -198,10 +255,16 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     """Every trained tensor of a trunk checkpoint by name."""
     n, r, h, w = cfg.layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
     inner, kv_inner, held = cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim, cfg.held[1]
+    if cfg.kv_lora_rank is None:
+        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner),
+                     "q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim), "wo": (n, inner, h)}
+    else:  # columns in the order ``_attention`` reads them
+        rank, nope, rope, value = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        attention = {"wq": (n, h, cfg.heads * (nope + rope)), "wkv_a": (n, h, rank + rope), "kv_norm": (n, rank),
+                     "wkv_b": (n, rank, cfg.heads * (nope + value)), "wo": (n, cfg.heads * value, h)}
     shapes = {
         "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
-        "attn_norm": (n, h), "wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner),
-        "q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim), "wo": (n, inner, h),
+        "attn_norm": (n, h), **attention,
         "moe_norm": (n, h), "router_w": (r, h, cfg.experts),
         "experts_gate": (r, held, h, w), "experts_up": (r, held, h, w), "experts_down": (r, held, w, h),
         "final_norm": (h,),
@@ -235,7 +298,7 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     for name, shape in shapes.items():
         if name.endswith("_norm"):
             params[name] = jnp.ones(shape, jnp.float32)
-        elif name.endswith("_b") or name == "value_fc2_w":
+        elif (name.endswith("_b") and len(shape) == 1) or name == "value_fc2_w":  # a bias is a vector: ``wkv_b`` is a matrix
             params[name] = jnp.zeros(shape, jnp.float32)
         else:
             params[name] = jax.random.normal(keys[name], shape, jnp.float32) * _INIT_STD
@@ -270,20 +333,42 @@ def _gated_ffn(n: jax.Array, p: Params, kind: str) -> jax.Array:
     return _matmul(jax.nn.silu(_matmul(n, p[f"{kind}_gate"])) * _matmul(n, p[f"{kind}_up"]), p[f"{kind}_down"])
 
 
-def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True) -> jax.Array:
+def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, layer: str = "layer00") -> Tuple[jax.Array, Optional[jax.Array]]:
     """[tokens, hidden] float32, 64 tokens a board -> the attention
-    branch's output, same shape. The projections are XLA's; everything
-    between them is ``board_attention``."""
-    n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    branch's output, same shape, and the latent's root mean square (None
+    without a latent). The projections are XLA's; everything between
+    them is ``board_attention``. Enters its own scopes (call it under
+    none of a layer's): ``<layer>.attention``, and for the way from the
+    normed stream through the latent to the keys and values
+    ``<layer>.latent`` beside it, so that the two add up to the branch."""
     by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
-    q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
-    mixed = board_attention(q, k, v.astype(jnp.bfloat16), p["q_norm"], p["k_norm"],
-                            cfg.rope_theta if rope else None, cfg.rms_eps, _interpret())
-    mixed = mixed.reshape(x.shape[0], -1)
-    if cfg.gated_attention:
-        mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
-    out = _matmul(mixed, p["wo"])
-    return _rms_norm(out, p["post_attn_norm"], cfg.rms_eps) if cfg.post_norms else out
+    with jax.named_scope(f"{layer}.attention"):
+        n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    if cfg.kv_lora_rank is not None:
+        # Columns (module docstring): wq every head's NoPE part, then every head's RoPE part; wkv_a the latent, then the
+        # RoPE key; wkv_b every head's key, then every head's value. Split on the weights' side, where a slice costs
+        # nothing (it joins the bfloat16 cast): the kernels' operands are then the products' results as they are.
+        split, rank = cfg.heads * cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        with jax.named_scope(f"{layer}.attention"):
+            q, q_pe = _matmul(n1, p["wq"][:, :split]), _matmul(n1, p["wq"][:, split:])
+        with jax.named_scope(f"{layer}.latent"):
+            ckv, k_pe = _matmul(n1, p["wkv_a"][:, :rank]), _matmul(n1, p["wkv_a"][:, rank:])
+            latent_rms = jnp.sqrt(jnp.mean(jax.lax.stop_gradient(ckv) ** 2))
+            c = _rms_norm(ckv, p["kv_norm"], cfg.rms_eps)
+            k, v = _matmul(c, p["wkv_b"][:, :split]), _matmul(c, p["wkv_b"][:, split:]).astype(jnp.bfloat16)
+        with jax.named_scope(f"{layer}.attention"):
+            mixed = board_attention(by_board(q), by_board(k), by_board(v), None, None, cfg.rope_theta, cfg.rms_eps, _interpret(),
+                                    q_pe=by_board(q_pe), k_pe=by_board(k_pe))
+            return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), latent_rms
+    with jax.named_scope(f"{layer}.attention"):
+        q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
+        mixed = board_attention(q, k, v.astype(jnp.bfloat16), p["q_norm"], p["k_norm"],
+                                cfg.rope_theta if rope else None, cfg.rms_eps, _interpret())
+        mixed = mixed.reshape(x.shape[0], -1)
+        if cfg.gated_attention:
+            mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
+        out = _matmul(mixed, p["wo"])
+        return (_rms_norm(out, p["post_attn_norm"], cfg.rms_eps) if cfg.post_norms else out), None
 
 
 def _interpret() -> bool:
@@ -542,7 +627,8 @@ def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkCon
     return trunk_forward_counted(params, planes, cfg)[:2]
 
 
-_EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "moe_norm", "wgate", "post_attn_norm", "post_mlp_norm")
+_EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wkv_a", "kv_norm", "wkv_b", "wo", "moe_norm", "wgate", "post_attn_norm",
+                "post_mlp_norm")
 _ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias")
 _DENSE = ("dense_gate", "dense_up", "dense_down")
 
@@ -559,13 +645,16 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     share's held count a layer, rounded up to whole blocks of the move);
     for a share, the slots that fell on the held experts, summed
     over the layers (``held_slots``); with an ``expert_bias`` among
-    ``params``, its largest magnitude (``expert_bias_abs_max``)."""
+    ``params``, its largest magnitude (``expert_bias_abs_max``); with a
+    latent, the root mean square of the key-value latent before its norm,
+    over the tokens, mean over the layers (``latent_rms``: a latent that
+    collapses or blows up shows here before the loss does)."""
     b = planes.shape[0]
     # Scope names are a contract (doc/observability.md "Training and compilation").
     with jax.named_scope("embed"):
         x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
         x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
-    counters = []
+    counters, latent = [], []
     for i in range(cfg.layers):
         # Layers of a kind are stacked: a routed layer's tensors are indexed from the first routed layer.
         routed = i - cfg.dense_layers
@@ -576,8 +665,10 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         # One scope a part, the layer in its name: the benchmark's scope
         # table keeps two levels of a path (phase, then this).
         name = f"layer{i:02d}"
+        branch, latent_rms = _attention(x, layer, cfg, rope=i not in cfg.nope_layers, layer=name)
         with jax.named_scope(f"{name}.attention"):
-            x = x + _attention(x, layer, cfg, rope=i not in cfg.nope_layers)
+            x = x + branch
+        latent.append(latent_rms)
         if routed < 0:
             with jax.named_scope(f"{name}.dense"):
                 x = x + post(_gated_ffn(_rms_norm(x, layer["moe_norm"], cfg.rms_eps), layer, "dense"))
@@ -604,6 +695,7 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         "moved_rows": jnp.sum(jnp.stack([c["moved_rows"] for c in counters])),
         **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
         **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
+        **({"latent_rms": jnp.mean(jnp.stack(latent))} if cfg.kv_lora_rank is not None else {}),
     })
 
 
@@ -643,7 +735,7 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
 def trunk_config_from_params(params: Params) -> TrunkConfig:
     """The ``TrunkConfig`` of a checkpoint, from its shapes and its
     ``trunk_hparams``; a ValueError names what does not fit."""
-    required = ("router_w", "experts_gate", "q_norm", "attn_norm", "value_fc1_b", "policy_b", HPARAMS)
+    required = ("router_w", "experts_gate", "wq", "wo", "attn_norm", "value_fc1_b", "policy_b", HPARAMS)
     missing = [k for k in required if k not in params]
     if missing:
         raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
@@ -653,15 +745,33 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS)}")
     hp = dict(zip(_HPARAMS, [*given, *defaults[len(given):]]))
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
-    (routed, hidden, experts), layers, head_dim = shape("router_w"), shape("attn_norm")[0], shape("q_norm")[1]
+    (routed, hidden, experts), layers = shape("router_w"), shape("attn_norm")[0]
     width_of = lambda name: shape(name)[2] if name in params else 0
+    if "kv_norm" in params:  # the latent form: its four widths and the head count from five shapes
+        missing = [k for k in ("wkv_a", "wkv_b") if k not in params]
+        if missing:
+            raise ValueError(f"trunk checkpoint: a latent (kv_norm) without {missing}")
+        rank, score, key_value, value = shape("kv_norm")[1], shape("wq")[2], shape("wkv_b")[2], shape("wo")[1]
+        rope = shape("wkv_a")[2] - rank
+        heads = (score - key_value + value) // rope if rope > 0 else 0  # heads x (nope + rope) - heads x (nope + value) + heads x value
+        if heads < 1:
+            raise ValueError(f"trunk checkpoint: mismatched shapes: wkv_a {shape('wkv_a')} leaves no RoPE key beside a latent of {rank}, "
+                             f"or wq {shape('wq')}, wkv_b {shape('wkv_b')} and wo {shape('wo')} no head")
+        attention = dict(heads=heads, kv_lora_rank=rank, qk_rope_head_dim=rope, qk_nope_head_dim=(key_value - value) // heads,
+                         v_head_dim=value // heads)
+    else:
+        missing = [k for k in ("q_norm", "wk") if k not in params]
+        if missing:
+            raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
+        head_dim = shape("q_norm")[1]
+        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim,
+                         kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
     try:
         cfg = TrunkConfig(
-            hidden=hidden, heads=shape("wq")[2] // head_dim, head_dim=head_dim, layers=layers,
+            hidden=hidden, layers=layers, **attention,
             experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_gate")[3],
             rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
             value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
-            kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim,
             nope_layers=tuple(i for i in range(layers) if int(hp["nope_mask"]) >> i & 1),
             sliding_window=int(hp["sliding_window"]) or None,
             gated_attention="wgate" in params, post_norms="post_attn_norm" in params, embed_scale=hp["embed_scale"],

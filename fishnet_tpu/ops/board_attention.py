@@ -38,6 +38,10 @@ bfloat16 values are bfloat16, the four products take bfloat16 operands
 and accumulate in float32. The gains' gradients leave it as one partial
 sum a grid step, summed outside over ``[steps, heads, head_dim]``.
 
+The same pair has a second, latent form (the end of this file): no norm,
+RoPE on a trailing part of the score width, one rotated key for all
+heads. ``board_attention`` is told which, and does not guess.
+
 Off the TPU both kernels run under the Pallas interpreter.
 """
 
@@ -53,7 +57,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SQUARES", "board_attention", "rope_tables"]
+__all__ = ["SQUARES", "board_attention", "latent_column_order", "rope_tables"]
 
 SQUARES = 64
 
@@ -244,13 +248,34 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
     return 1 if interpret else max(1, unroll // group)
 
 
+def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
+                    theta: Optional[float], eps: float, interpret: bool = False,
+                    q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None) -> jax.Array:
+    """The attention core (module docstring). What it is told, and does
+    not guess: the norm (the gains ``[head_dim]``, or None for none), the
+    extent of RoPE (``theta`` None: none of the score width; no ``k_pe``:
+    all of it; with ``q_pe`` and ``k_pe``: those trailing columns alone)
+    and whether the rotated part of k is one a key-value head (it is part
+    of ``k``) or one for all heads (``k_pe`` ``[boards, 64, rope]``).
+
+    Normed form: q float32 ``[boards, 64, heads * head_dim]``, k float32
+    and v bfloat16 ``[boards, 64, kv_heads * head_dim]`` -> bfloat16 of
+    q's shape; both head counts follow from the shapes. Latent form: q
+    and k float32 ``[boards, 64, heads * nope]``, ``q_pe`` float32
+    ``[boards, 64, heads * rope]``, v bfloat16 ``[boards, 64, heads *
+    value]`` -> bfloat16 of v's shape. The combinations the kernels do
+    not compute are refused."""
+    latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
+    if all(latent) and theta is not None:
+        return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta, interpret)
+    if any(latent):
+        raise ValueError("board_attention computes qk-norm with RoPE over all or none of head_dim, or no norm with RoPE "
+                         "over trailing columns q_pe and one k_pe for all heads; not a mixture of the two")
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: jax.Array, g_k: jax.Array,
-                    theta: Optional[float], eps: float, interpret: bool = False) -> jax.Array:
-    """The attention core (module docstring): q float32 ``[boards, 64,
-    heads * head_dim]``, k float32 and v bfloat16 ``[boards, 64, kv_heads
-    * head_dim]``, gains ``[head_dim]`` -> bfloat16 of q's shape. Both
-    head counts follow from the shapes; ``theta`` None is no RoPE."""
+def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool):
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
@@ -267,7 +292,7 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: jax.Array, g_
 
 
 def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret):
-    return board_attention(q, k, v, g_q, g_k, theta, eps, interpret), (q, k, v, g_q, g_k)
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret), (q, k, v, g_q, g_k)
 
 
 def _board_attention_bwd(theta, eps, interpret, residuals, d_mixed):
@@ -292,4 +317,213 @@ def _board_attention_bwd(theta, eps, interpret, residuals, d_mixed):
     return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
 
 
-board_attention.defvjp(_board_attention_fwd, _board_attention_bwd)
+_normed_attention.defvjp(_board_attention_fwd, _board_attention_bwd)
+
+
+# -- the latent form --------------------------------------------------------------------------------------------
+#
+# ``board_attention(q, k, v, None, None, theta, eps, interpret, k_pe)``: what the caller tells it is that there
+# is no norm (the gains are None), that RoPE covers a trailing part of the score width (the width of ``k_pe``),
+# and that the rotated part of k is one for all heads (``k_pe`` has no head axis). A head's score is then
+#
+#     s_h = (q_nope_h k_nope_h^T + rope(q_pe_h) rope(k_pe)^T) / sqrt(nope + rope)
+#
+# with q ``[boards, 64, heads * nope + heads * rope]`` laid out as every head's NoPE columns, then every head's
+# RoPE columns (``latent_column_order``), k ``[boards, 64, heads * nope]`` and v ``[boards, 64, heads * value]``
+# one a head, ``k_pe`` ``[boards, 64, rope]``. The RoPE columns of 128 // rope heads fill one 128-lane tile:
+# the tile is rotated once (two lane rotations: rotate-half inside every ``rope`` lanes) and each head's part
+# is the tile under a table that is zero off the head's lanes, so that the 128-wide product with ``k_pe``
+# repeated across the tile contracts over that head's lanes alone. Nothing narrower than a vreg is ever
+# sliced, and no copy of ``k_pe`` a head exists outside VMEM. In the gradient ``dk_pe`` sums over a grid
+# step's heads in registers and over the steps of a board in a VMEM scratch, float32, rounded once.
+
+#: Heads a grid step of the latent form; a step takes ``_BOARDS // _LATENT_HEADS`` boards.
+_LATENT_HEADS = 8
+_LANES = 128
+
+_LATENT_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def latent_column_order(heads: int, nope: int, rope: int) -> np.ndarray:
+    """Where the latent form wants each column of a published per-head
+    ``[heads x (nope + rope)]`` query projection: ``w[:, order]`` has
+    every head's NoPE columns first, then every head's RoPE columns,
+    each head's interleaved pairs (2i, 2i + 1) taken apart into the two
+    halves that rotate-half turns (a score is unchanged when q and k are
+    permuted alike). With ``heads`` 1 and ``nope`` 0 it is the order of
+    the RoPE key's columns."""
+    per_head = np.arange(heads)[:, None] * (nope + rope)
+    halves = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    return np.concatenate([(per_head + np.arange(nope)[None, :]).reshape(-1), (per_head + nope + halves[None, :]).reshape(-1)])
+
+
+def _latent_tables(theta: float, rope: int) -> np.ndarray:
+    """``[1 + per, 3, 64, 128]`` float32, ``per`` = 128 // rope heads a
+    tile: cos, and the sine split by which lane rotation brings the
+    partner (``_rotated``), for the whole tile (index 0: ``k_pe``
+    repeated) and zero off each head's lanes (1 + j: head j of a tile)."""
+    per, half = _LANES // rope, rope // 2
+    cos, sin = rope_tables(theta, rope)  # [64, rope], the sine signed: minus on the first half
+    cos, sin = np.tile(cos, per), np.tile(sin, per)
+    first = (np.arange(_LANES) % rope) < half
+    whole = np.stack([cos, np.where(first, 0.0, sin), np.where(first, sin, 0.0)])  # partner at lane - half, at lane + half
+    own = [(np.arange(_LANES) // rope) == j for j in range(per)]
+    return np.stack([whole] + [np.where(mask, whole, 0.0) for mask in own]).astype(np.float32)
+
+
+def _rotated(x: jax.Array, table: jax.Array, half: int) -> jax.Array:
+    """Rotate-half RoPE inside every ``2 * half`` lanes of a 128-lane tile."""
+    return x * table[0] + pltpu.roll(x, half, axis=1) * table[1] + pltpu.roll(x, _LANES - half, axis=1) * table[2]
+
+
+def _unrotated(d: jax.Array, table: jax.Array, half: int) -> jax.Array:
+    """The transpose of ``_rotated``."""
+    return d * table[0] + pltpu.roll(d * table[1], _LANES - half, axis=1) + pltpu.roll(d * table[2], half, axis=1)
+
+
+def _lanes(h: int, width: int) -> slice:
+    return slice(h * width, (h + 1) * width)
+
+
+def _latent_sizes(qr_ref, kn_ref, v_ref, rope: int):
+    """Heads a tile, half the RoPE width, tiles a step, a head's NoPE and value widths, the scores' scale."""
+    per, tiles = _LANES // rope, qr_ref.shape[-1] // _LANES
+    nope, width = kn_ref.shape[-1] // (tiles * per), v_ref.shape[-1] // (tiles * per)
+    return per, rope // 2, tiles, nope, width, np.float32(1.0 / math.sqrt(nope + rope))
+
+
+def _latent_scores(kn, qn, kr, qr, scale):
+    both = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(kn, qn, both, preferred_element_type=jnp.float32)
+    return (s + jax.lax.dot_general(kr, qr, both, preferred_element_type=jnp.float32)) * scale
+
+
+def _latent_forward_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, tab_ref, out_ref, *, rope: int, unroll: int):
+    bf16 = jnp.bfloat16
+    per, half, tiles, nope, width, scale = _latent_sizes(qr_ref, kn_ref, v_ref, rope)
+
+    def board(b, carry):
+        kr = _rotated(jnp.concatenate([kr_ref[b]] * per, axis=-1), tab_ref[0], half).astype(bf16)
+        for t in range(tiles):
+            x = qr_ref[b, :, _lanes(t, _LANES)]
+            back, ahead = pltpu.roll(x, half, axis=1), pltpu.roll(x, _LANES - half, axis=1)
+            for j in range(per):
+                h, table = t * per + j, tab_ref[1 + j]
+                qr = (x * table[0] + back * table[1] + ahead * table[2]).astype(bf16)
+                qn, kn = qn_ref[b, :, _lanes(h, nope)].astype(bf16), kn_ref[b, :, _lanes(h, nope)].astype(bf16)
+                p = _softmax(_latent_scores(kn, qn, kr, qr, scale)).astype(bf16)
+                mixed = jax.lax.dot_general(p, v_ref[b, :, _lanes(h, width)], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                out_ref[b, :, _lanes(h, width)] = mixed.astype(out_ref.dtype)
+        return carry
+
+    _each_board(qn_ref.shape[0], board, 0, unroll)
+
+
+def _latent_backward_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, tab_ref, do_ref,
+                            dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref, sum_ref, *, rope: int, unroll: int):
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    per, half, tiles, nope, width, scale = _latent_sizes(qr_ref, kn_ref, v_ref, rope)
+    step, last = pl.program_id(1), pl.num_programs(1) - 1
+
+    def board(b, carry):
+        kr = _rotated(jnp.concatenate([kr_ref[b]] * per, axis=-1), tab_ref[0], half).astype(bf16)
+        dkr = jnp.zeros((SQUARES, _LANES), f32)
+        for t in range(tiles):
+            x = qr_ref[b, :, _lanes(t, _LANES)]
+            back, ahead = pltpu.roll(x, half, axis=1), pltpu.roll(x, _LANES - half, axis=1)
+            by_cos = by_back = by_ahead = jnp.zeros((SQUARES, _LANES), f32)
+            for j in range(per):
+                h, table = t * per + j, tab_ref[1 + j]
+                qr = (x * table[0] + back * table[1] + ahead * table[2]).astype(bf16)
+                qn, kn = qn_ref[b, :, _lanes(h, nope)].astype(bf16), kn_ref[b, :, _lanes(h, nope)].astype(bf16)
+                vb, do = v_ref[b, :, _lanes(h, width)], do_ref[b, :, _lanes(h, width)]
+                p = _softmax(_latent_scores(kn, qn, kr, qr, scale))
+                dv_ref[b, :, _lanes(h, width)] = jnp.dot(p.astype(bf16), do, preferred_element_type=f32).astype(dv_ref.dtype)
+                dp = _rounded(jax.lax.dot_general(vb, do, (((1,), (1,)), ((), ())), preferred_element_type=f32))
+                ds = (p * (dp - jnp.sum(dp * p, axis=0, keepdims=True)) * scale).astype(bf16)
+                by_query = (((0,), (0,)), ((), ()))
+                dqn_ref[b, :, _lanes(h, nope)] = _rounded(jax.lax.dot_general(ds, kn, by_query, preferred_element_type=f32))
+                dkn_ref[b, :, _lanes(h, nope)] = _rounded(jnp.dot(ds, qn, preferred_element_type=f32))
+                dqr = _rounded(jax.lax.dot_general(ds, kr, by_query, preferred_element_type=f32))  # every head's lanes hold this head's
+                by_cos, by_back, by_ahead = by_cos + dqr * table[0], by_back + dqr * table[1], by_ahead + dqr * table[2]
+                dkr = dkr + jnp.dot(ds, qr, preferred_element_type=f32)  # this head's lanes alone: qr is zero off them
+            dqr_ref[b, :, _lanes(t, _LANES)] = by_cos + pltpu.roll(by_back, _LANES - half, axis=1) + pltpu.roll(by_ahead, half, axis=1)
+
+        @pl.when(step == 0)
+        def _():
+            sum_ref[b] = dkr
+
+        @pl.when(step > 0)
+        def _():
+            sum_ref[b] = sum_ref[b] + dkr
+
+        @pl.when(step == last)
+        def _():
+            total = sum_ref[b]
+            for j in range(1, per):  # the tile's heads, folded: afterwards every ``rope`` lanes hold the sum over all heads
+                total = total + pltpu.roll(sum_ref[b], j * rope, axis=1)
+            dkr_ref[b] = _unrotated(_rounded(total), tab_ref[0], half)[:, :rope]
+
+        return carry
+
+    _each_board(qn_ref.shape[0], board, 0, unroll)
+
+
+def _latent_blocks(q, q_pe, k, k_pe, v):
+    """The grid (blocks of boards, blocks of heads) and the BlockSpecs of
+    a step's NoPE columns of q or k, RoPE columns of q, values, ``k_pe``
+    and the tables."""
+    boards, rope = q.shape[0], k_pe.shape[-1]
+    per = _LANES // rope if rope and rope % 2 == 0 and _LANES % rope == 0 else 0
+    heads = q_pe.shape[-1] // rope if per else 0
+    if not heads or heads % per or q_pe.shape[-1] != heads * rope or q.shape != k.shape or q.shape[-1] % heads or v.shape[-1] % heads:
+        raise ValueError(f"latent attention: q {q.shape} + {q_pe.shape}, k {k.shape} + {k_pe.shape} and v {v.shape} are not heads x nope + "
+                         "heads x rope, heads x nope + rope and heads x value with whole 128-lane tiles of RoPE columns")
+    hg = math.gcd(heads, max(per, _LATENT_HEADS))
+    tb = math.gcd(boards, max(1, _BOARDS // hg))
+    spec = lambda lanes: pl.BlockSpec((tb, SQUARES, lanes), lambda i, h: (i, 0, h))
+    return ((boards // tb, heads // hg), tb, hg,
+            dict(nope=spec(hg * q.shape[-1] // heads), rope=spec(hg * rope), value=spec(hg * v.shape[-1] // heads),
+                 key=pl.BlockSpec((tb, SQUARES, rope), lambda i, h: (i, 0, 0)),
+                 tables=pl.BlockSpec((1 + per, 3, SQUARES, _LANES), lambda i, h: (0, 0, 0, 0))))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _latent_attention(q, q_pe, k, k_pe, v, theta: float, interpret: bool):
+    grid, _, hg, specs = _latent_blocks(q, q_pe, k, k_pe, v)
+    rope = k_pe.shape[-1]
+    return pl.pallas_call(
+        functools.partial(_latent_forward_kernel, rope=rope, unroll=_unroll(interpret, _UNROLL, hg)),
+        grid=grid,
+        in_specs=[specs["nope"], specs["rope"], specs["nope"], specs["key"], specs["value"], specs["tables"]],
+        out_specs=specs["value"],
+        out_shape=jax.ShapeDtypeStruct(v.shape, jnp.bfloat16),
+        compiler_params=_LATENT_PARAMS,
+        name="board_attention",
+        interpret=interpret,
+    )(q, q_pe, k, k_pe, v, jnp.asarray(_latent_tables(theta, rope)))
+
+
+def _latent_attention_fwd(q, q_pe, k, k_pe, v, theta, interpret):
+    return _latent_attention(q, q_pe, k, k_pe, v, theta, interpret), (q, q_pe, k, k_pe, v)
+
+
+def _latent_attention_bwd(theta, interpret, residuals, d_mixed):
+    q, q_pe, k, k_pe, v = residuals
+    grid, tb, hg, specs = _latent_blocks(q, q_pe, k, k_pe, v)
+    rope = k_pe.shape[-1]
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return tuple(pl.pallas_call(
+        functools.partial(_latent_backward_kernel, rope=rope, unroll=_unroll(interpret, _UNROLL_GRAD, hg)),
+        grid=grid,
+        in_specs=[specs["nope"], specs["rope"], specs["nope"], specs["key"], specs["value"], specs["tables"], specs["value"]],
+        out_specs=[specs["nope"], specs["rope"], specs["nope"], specs["key"], specs["value"]],
+        out_shape=[like(q), like(q_pe), like(k), like(k_pe), like(v)],
+        scratch_shapes=[pltpu.VMEM((tb, SQUARES, _LANES), jnp.float32)],
+        compiler_params=_LATENT_PARAMS,
+        name="board_attention_grad",
+        interpret=interpret,
+    )(q, q_pe, k, k_pe, v, jnp.asarray(_latent_tables(theta, rope)), d_mixed))
+
+
+_latent_attention.defvjp(_latent_attention_fwd, _latent_attention_bwd)

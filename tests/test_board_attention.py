@@ -188,3 +188,117 @@ def test_rope_tables_fold_the_sign_of_rotate_half_into_the_sine():
     want = rope(x, 16)[:, 0]
     assert cos.dtype == sin.dtype == np.float32 and cos.shape == sin.shape == (64, 16)
     assert np.allclose(x[:, 0] * cos + jnp.roll(x[:, 0], 8, axis=-1) * sin, want, atol=1e-6)
+
+
+# -- the latent form: no norm, a NoPE and a RoPE part of every head's score, one RoPE key for all heads ---------------
+#
+# The plain formula is the published one, literally: per-head columns [nope | rope], RoPE on interleaved pairs
+# (2i, 2i + 1). The kernels take every head's NoPE columns, then every head's RoPE columns with the pairs taken
+# apart (``latent_column_order``): the tests permute the inputs in and the gradients back, so the map is held to the
+# published order. Roundings as above (no norm: the same count or fewer), so the tolerances stay.
+
+from fishnet_tpu.ops.board_attention import latent_column_order  # noqa: E402
+
+LATENT_THETA = 1e6
+
+
+def rope_pairs(x, theta=LATENT_THETA):
+    """RoPE on interleaved pairs of [boards, 64, .., rope], position = square."""
+    r = x.shape[-1]
+    angle = np.arange(64)[:, None] / theta ** (np.arange(0, r, 2) / r)[None, :]
+    shape = (64,) + (1,) * (x.ndim - 3) + (r // 2,)
+    cos, sin = jnp.asarray(np.cos(angle), jnp.float32).reshape(shape), jnp.asarray(np.sin(angle), jnp.float32).reshape(shape)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1).reshape(x.shape)
+
+
+def plain_latent(q, k, k_pe, v, heads, wrong=""):
+    """q [boards, 64, heads x (nope + rope)] in the published per-head order, k [boards, 64, heads x nope], k_pe
+    [boards, 64, rope] one for all heads, v [boards, 64, heads x value]; float32 throughout."""
+    boards, rope_dim = q.shape[0], k_pe.shape[-1]
+    split = lambda y: y.astype(jnp.float32).reshape(boards, 64, heads, -1)
+    q, k, v = split(q), split(k), split(v)
+    nope = k.shape[-1]
+    q_nope, q_pe, k_pe = q[..., :nope], q[..., nope:], jnp.broadcast_to(k_pe[:, :, None, :], (boards, 64, heads, rope_dim))
+    if wrong == "key_per_head":  # each head reads a RoPE key of its own (here: another square's)
+        k_pe = jnp.stack([jnp.roll(k_pe[:, :, h], h, axis=1) for h in range(heads)], axis=2)
+    apart = latent_column_order(1, 0, rope_dim)  # x[..., apart] has the pairs' first elements, then their second
+    halves = lambda x: rope_pairs(x[..., np.argsort(apart)])[..., apart]  # column i turned with column i + rope / 2
+    turn = halves if wrong == "rotate_half" else rope_pairs  # rotate-half where pairs were published, with no permutation
+    if wrong == "rope_on_nope":
+        q_nope, k = rope_pairs(q_nope, THETA), rope_pairs(k, THETA)
+    q_pe, k_pe = turn(q_pe), turn(k_pe)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q_nope, k, precision="highest") + jnp.einsum("bqhd,bkhd->bhqk", q_pe, k_pe, precision="highest")
+    scores = scores / np.sqrt(nope if wrong == "scale_of_nope" else nope + rope_dim)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v, precision="highest").reshape(boards, 64, -1)
+
+
+def latent_kernel(q, k, k_pe, v, heads):
+    """The kernel pair behind the published order: columns permuted in, so that ``jax.vjp`` permutes the gradients back."""
+    rope_dim = k_pe.shape[-1]
+    nope = k.shape[-1] // heads
+    q = q[..., latent_column_order(heads, nope, rope_dim)]
+    return board_attention(q[..., :heads * nope], k, v, None, None, LATENT_THETA, EPS, True,
+                           q_pe=q[..., heads * nope:], k_pe=k_pe[..., latent_column_order(1, 0, rope_dim)])
+
+
+def latent_inputs(heads, nope, rope_dim, value, boards, seed=7):
+    rng = np.random.default_rng(seed)
+    normal = lambda width, dtype=jnp.float32, scale=1.0: jnp.asarray(scale * rng.standard_normal((boards, 64, width)), dtype)
+    return (normal(heads * (nope + rope_dim)), normal(heads * nope), normal(rope_dim), normal(heads * value, jnp.bfloat16),
+            normal(heads * value, jnp.bfloat16))  # the last: the cotangent of ``mixed``
+
+
+# (heads, nope, rope, value, boards): RoPE parts of 64 (two heads a 128-lane tile, the published width), 32 (four) and
+# 128 (one); 16 heads are two grid steps of 8, so dk_pe sums over the steps of a board too; 5 boards are 5 steps of 1.
+LATENT_CASES = [(2, 16, 64, 16, 4), (4, 32, 64, 16, 5), (16, 16, 64, 8, 2), (4, 16, 32, 16, 3), (2, 16, 128, 32, 2)]
+LATENT_OUTPUTS = ["mixed", "d_q", "d_k", "d_k_pe", "d_v"]
+
+
+@functools.lru_cache(maxsize=None)
+def both_latent(heads, nope, rope_dim, value, boards, wrong=""):
+    *args, cotangent = latent_inputs(heads, nope, rope_dim, value, boards)
+
+    def value_and_gradients(f):
+        out, pull = jax.vjp(lambda *a: f(*a, heads), *args)
+        return (out, *pull(cotangent.astype(out.dtype)))
+
+    return jax.jit(lambda: value_and_gradients(latent_kernel))(), jax.jit(lambda: value_and_gradients(functools.partial(plain_latent, wrong=wrong)))()
+
+
+@pytest.mark.parametrize("output", LATENT_OUTPUTS)
+@pytest.mark.parametrize("heads,nope,rope_dim,value,boards", LATENT_CASES)
+def test_the_latent_form_matches_the_published_formula(heads, nope, rope_dim, value, boards, output):
+    got, want = (side[LATENT_OUTPUTS.index(output)] for side in both_latent(heads, nope, rope_dim, value, boards))
+    assert got.shape == want.shape
+    assert got.dtype == (jnp.bfloat16 if output in ("mixed", "d_v") else jnp.float32)
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL)
+
+
+@pytest.mark.parametrize("wrong", ["rope_on_nope", "scale_of_nope", "key_per_head", "rotate_half"])
+def test_the_tolerances_catch_a_wrong_latent_formula(wrong):
+    """RoPE on the NoPE part too, a scale of 1 / sqrt(nope), a RoPE key
+    taken per head, rotate-half on the published column order: each
+    misses at least one tolerance by more than 1.5x."""
+    got, want = both_latent(4, 32, 64, 16, 5, wrong)
+    misses = [rel(g, w) / (FORWARD_TOL if i == 0 else GRADIENT_TOL) for i, (g, w) in enumerate(zip(got, want))]
+    assert max(misses) > 1.5, misses
+
+
+def test_the_latent_column_order_takes_every_published_column_once():
+    order = latent_column_order(3, 4, 6)
+    assert sorted(order) == list(range(30))
+    assert list(order[:12]) == [0, 1, 2, 3, 10, 11, 12, 13, 20, 21, 22, 23]  # every head's NoPE columns first
+    assert list(order[12:18]) == [4, 6, 8, 5, 7, 9]  # head 0's pairs (2i, 2i + 1) as the two halves rotate-half turns
+    assert list(latent_column_order(1, 0, 4)) == [0, 2, 1, 3]
+
+
+def test_a_mixture_of_the_two_forms_is_refused():
+    q, k, k_pe, v, _ = latent_inputs(2, 16, 64, 16, 2)
+    gain = jnp.ones((16,), jnp.float32)
+    with pytest.raises(ValueError, match="not a mixture"):
+        board_attention(q[..., :32], k, v, gain, gain, LATENT_THETA, EPS, True, k_pe=k_pe)
+    with pytest.raises(ValueError, match="not a mixture"):
+        board_attention(q[..., :32], k, v, None, None, None, EPS, True, q_pe=q[..., 32:160], k_pe=k_pe)
+    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+        board_attention(q[..., :32], k, v, None, None, LATENT_THETA, EPS, True, q_pe=q[..., 32:96], k_pe=k_pe)  # one head's RoPE columns for two

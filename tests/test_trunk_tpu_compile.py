@@ -113,7 +113,7 @@ def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_ch
              "wv": sds((HIDDEN, inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
 
     def loss(x, p):
-        return jnp.sum(trunk._attention(x, p, cfg))
+        return jnp.sum(trunk._attention(x, p, cfg)[0])
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -144,7 +144,7 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
              "wk": sds((HIDDEN, kv_inner), jnp.float32), "wv": sds((HIDDEN, kv_inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
 
     def loss(x, p):
-        return jnp.sum(trunk._attention(x, p, cfg, rope=rope))
+        return jnp.sum(trunk._attention(x, p, cfg, rope=rope)[0])
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -191,3 +191,66 @@ def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_
     assert len(names) > 100 and not [name for name in names if not second_level.match(name)]
     assert {f"{phase}/layer01.{part}" for phase in ("jvp(forward)", "transpose(jvp(forward))") for part in ("router", "dispatch", "experts", "combine")} == {
         re.match(r"jit\(loss\)/([^/]*/[^/]*)", name).group(1) for name in names}
+
+
+# -- the third block at the shapes of mla_trunk_train_b256: 256 boards, 32 heads of 128 NoPE + 64 RoPE score columns over --------
+# -- 128-wide values through a 512-wide latent, 98,304 slots a routed layer of which 8 of 128 experts are held ----------------
+
+KANANA = trunk.TrunkConfig(heads=32, layers=5, experts=128, experts_per_token=6, expert_width=768, rope_theta=1e6, rms_eps=1e-6,
+                           dense_layers=1, dense_width=6144, shared_width=1536, router_score="sigmoid", route_norm=True, route_scale=2.448,
+                           held_experts=(0, 8), balance_rate=0.001, recompute_experts=True, kv_lora_rank=512, qk_nope_head_dim=128,
+                           qk_rope_head_dim=64, v_head_dim=128)
+KANANA_BOARDS = 256
+HBM_GIB = 15.75  # what a v5e chip shows
+
+
+def test_latent_attention_compiles_at_published_widths_and_keeps_no_scores_or_copies_of_the_rope_key(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of ``_attention`` with a latent: Mosaic lowers the
+    kernel pair at ``[256, 64, 32 x (128 + 64)]`` (a 64-wide RoPE part is
+    half a vreg's lanes: two heads a 128-lane tile); the core is still the
+    two kernels, each reads the ONE RoPE key ``f32[256,64,64]``, and no
+    scores, no per-head view and no key with the RoPE part copied to every
+    head exists as an array."""
+    import re
+
+    cfg = KANANA
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {name: sds(shape[1:], jnp.float32) for name, shape in trunk.trunk_param_shapes(cfg).items()
+             if name in ("attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo")}
+    assert {k: v.shape for k, v in layer.items()} == {"attn_norm": (2048,), "wq": (2048, 6144), "wkv_a": (2048, 576), "kv_norm": (512,),
+                                                      "wkv_b": (512, 8192), "wo": (4096, 2048)}
+
+    def loss(x, p):
+        with jax.named_scope("forward"):  # the phase a trainer's step puts first
+            return jnp.sum(trunk._attention(x, p, cfg)[0])
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((KANANA_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_attention_grad" in line for line in kernels) == 1
+    assert all("operand_layout_constraints={" in line and "f32[256,64,64]{2,1,0}" in line for line in kernels)
+    wide = r"\[256,32,64,64\]|\[256,64,32,(?:64|128|192|256)\]|\[256,32,64,(?:128|192|256)\]|\[16384,32,(?:64|128|192|256)\]|\[256,64,6144\]\S* concatenate"
+    assert not [line for line in text.splitlines() if re.search(wide, line)][:2]
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):  # the latent's scope beside the attention's, second level of a path
+        assert f"jit(loss)/{phase}/layer00.latent/" in text and f"jit(loss)/{phase}/layer00.attention/" in text
+
+
+def test_the_whole_step_of_the_third_block_fits_one_chip(one_chip, compiled_for_tpu):
+    """``AzTrainer._step`` on the cut configuration of ``mla_trunk_train_b256`` (one dense and four routed layers,
+    8 of 128 experts held, batch 256, ``recompute_experts``): temporaries and arguments together stay under the
+    chip's memory with nothing of attention remade, and every layer's core is the kernel pair."""
+    import optax
+
+    from fishnet_tpu.train.az_trainer import AzTrainer
+
+    trainer = AzTrainer(KANANA, optimizer=optax.adamw(optax.linear_schedule(0.0, 3e-4, 100_000), weight_decay=1e-4))
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    state = jax.tree.map(sds, jax.eval_shape(trainer._init, jax.random.PRNGKey(0)))
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 359_558_222
+    batch = {"planes": jax.ShapeDtypeStruct((KANANA_BOARDS, 8, 8, 19), jnp.float32, sharding=one_chip),
+             "policy_target": jax.ShapeDtypeStruct((KANANA_BOARDS, 4672), jnp.float32, sharding=one_chip),
+             "value_target": jax.ShapeDtypeStruct((KANANA_BOARDS,), jnp.float32, sharding=one_chip)}
+    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(state, batch).compile()
+    stats = compiled.memory_analysis()
+    assert (stats.temp_size_in_bytes + stats.argument_size_in_bytes) / 2**30 < HBM_GIB, stats
+    text = compiled.as_text()
+    assert len([line for line in text.splitlines() if "tpu_custom_call" in line and "board_attention" in line]) == 10  # five layers, forward and gradient
