@@ -118,12 +118,17 @@ Mechanism, experts: the (token, slot) pairs are sorted by expert
 (stable), each expert's rows form one group of a grouped matrix product (megablox
 ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
 the CPU), the rows are put back in token order and each token's slots
-summed under their weights. A share's weights are ``[count, hidden,
-width]`` and ``gmm`` is told the first group it holds (its
-``group_offset``; 0, because a share's sort puts its own groups first,
-see below): it visits the held groups' rows alone and writes
-zeros for the others', forward and in both gradients, so a slot of an
-absent expert adds nothing and every held expert is dropless. The rows
+summed under their weights. An expert is two grouped products round one
+elementwise kernel: gate and up are ONE product on the two weights
+joined along their columns, ``[slots, hidden] x [held, hidden, 2 *
+width]``; ``silu(gate) * up`` is ``ops/expert_gate.py``'s kernel pair
+(``expert_gate``, ``expert_gate_grad``); the down product follows. A
+share's weights are ``[count, hidden, width]`` and the products are
+given the held groups' sizes alone, the first ``count`` of its sorted
+order (see below): megablox visits the tiles of the groups it is given
+and nothing else, forward and in both gradients, so a slot of an absent
+expert costs nothing and adds nothing, and every held expert is
+dropless. The rows
 move through two more Pallas
 kernels (``ops/row_move.py``): wherever a row is addressed singly it
 lives as ``[rows, hidden // 128, 128]``, one contiguous 4 KiB tile at
@@ -134,20 +139,25 @@ transpose, so ``_dispatch`` and ``_combine`` pair them as forward and
 gradient and nothing is ever scatter-added. A share moves the rows of
 its own experts alone: it sorts on ``(expert - first) mod experts``, so
 they are rows ``[0, held)`` whatever ``first`` is, and hands ``held``
-(the layer's own count, on the device) to every move as its extent. The
+(the layer's own count, on the device) to every move and to the gate
+kernels as their extent. The
 buffers keep their dropless shapes (``[N * k, hidden]`` sorted, ``[N,
 k, hidden // 128, 128]`` in token order: any routing fits, all slots
-held included); only the work follows the count. What a tail holds:
-past ``held`` (rounded up to a block) the sorted buffers that the moves
-write, and the places of absent experts' slots in the token-order view,
-are uninitialised and may be NaN; ``gmm`` still writes zeros for the
-absent groups' rows of ITS results. Who may read a tail: ``gmm`` and
+held included); only the work follows the count: on a share nothing
+between the router and the sums over a token's k passes over a row past
+``held``. What a tail holds:
+past ``held`` (rounded up to a block) every sorted buffer, the moves',
+the products' and the gate kernels' results and their cotangents alike,
+and the places of absent experts' slots in the token-order view, are
+uninitialised and may be NaN. Who may read a tail: ``gmm`` and
 ``tgmm`` (they visit held groups only and select by the group's rows),
+the moves and the gate kernels (they stop at the extent's block),
 and the three sums over a token's k, through ``_held_alone``'s select
 on the slot's mask, never through a product. Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
 tower's; norms, the softmaxes, the router's scores, choice and combine
-weights and both sigmoid gates are float32.
+weights, both sigmoid gates and the experts' gated activation are
+float32.
 
 Parameters are one flat dict (the ``.npz`` checkpoint format), the
 layers of a kind stacked on a leading axis: ``wq [layers, ..]``,
@@ -169,11 +179,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
-from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tgmm as megablox_tgmm
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
+from fishnet_tpu.ops.expert_gate import gated_activation
 from fishnet_tpu.ops.row_move import row_view, rows_back, rows_covered, rows_out
 
 Params = Dict[str, jax.Array]
@@ -372,8 +383,8 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
 
 
 def _interpret() -> bool:
-    """The trunk's Pallas kernels (the attention core, the grouped product
-    and the two row moves) are one path everywhere: compiled by Mosaic on a TPU, run by
+    """The trunk's Pallas kernels (the attention core, the grouped product,
+    the experts' gate pair and the two row moves) are one path everywhere: compiled by Mosaic on a TPU, run by
     the Pallas interpreter elsewhere (the CPU of the tests), never
     another path."""
     return jax.default_backend() != "tpu"
@@ -389,9 +400,12 @@ def _slots_by_token(rows: jax.Array, k: int) -> jax.Array:
 
 
 class Held(NamedTuple):
-    """What a share's moves and sums know of its routing (``_experts``
-    makes it; ``None`` where every expert is held). The held experts'
-    rows are the first ``extent`` of the sorted order; ``mask`` [N, k]
+    """What a share's moves, gate kernels and sums know of its routing
+    (``_experts`` makes it; ``None`` where every expert is held). The held
+    experts' rows are the first ``extent`` of the sorted order, and no
+    kernel between dispatch and combine covers a block past it (the
+    grouped products know it as the sum of the held groups' sizes);
+    ``mask`` [N, k]
     says which of a token's slots they are; ``scale`` [N * k] are the
     combine weights in sorted order, which the share's sort carries
     along (the gather that makes them otherwise, 1.1 ms over 131,072
@@ -485,22 +499,60 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 _TILE = (512, 1024, 1024)
 
 
-def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, first: Optional[int] = None) -> jax.Array:
+def _tile(width: int, most: int) -> int:
+    """The largest tile up to ``most`` that divides ``width``, in whole
+    128-lane tiles where the width has them: a tile that does not divide
+    is computed whole and half empty (1,536 columns: 768, not 1,024)."""
+    step = 128 if width % 128 == 0 else 1
+    return max(t for t in range(step, min(width, most) + 1, step) if width % t == 0)
+
+
+@jax.custom_vjp
+def _gmm(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """megablox ``gmm`` on bfloat16 operands, each of its three products
+    (this one, and in the gradient ``gmm`` on the transposed weights and
+    ``tgmm``) at the tiles of its own shape: megablox's own gradient rule
+    gives all three the forward's, whose contraction tile meets the
+    columns when the weights are transposed."""
+    return _gmm_fwd(rows, weights, group_sizes)[0]
+
+
+def _tiling(rows: int, contraction: int, columns: int) -> Tuple[int, int, int]:
+    return math.gcd(rows, _TILE[0]), _tile(contraction, _TILE[1]), _tile(columns, _TILE[2])
+
+
+def _gmm_fwd(rows, weights, group_sizes):
+    (m, k), n = rows.shape, weights.shape[2]
+    return megablox_gmm(rows, weights, group_sizes, jnp.bfloat16, _tiling(m, k, n), interpret=_interpret()), (rows, weights, group_sizes)
+
+
+def _gmm_bwd(res, g):
+    rows, weights, group_sizes = res
+    (m, k), n = rows.shape, weights.shape[2]
+    d_rows = megablox_gmm(g, weights, group_sizes, rows.dtype, _tiling(m, n, k), transpose_rhs=True, interpret=_interpret())
+    d_weights = megablox_tgmm(rows.swapaxes(0, 1), g, group_sizes, weights.dtype, _tiling(m, k, n), interpret=_interpret())
+    return d_rows, d_weights, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Array:
     """``rows[i] @ weights[g(i)]`` where the rows come in runs of
-    ``group_sizes`` (int32; their sum is the number of rows; a size may
-    be 0). bfloat16 operands and result, float32 accumulation: megablox
-    ``gmm``, whose gradients are ``gmm`` on the transposed weights and
-    ``tgmm`` (one product a group, summed over the group's rows). The
-    number of rows has to be a multiple of 8; it is 64 x experts_per_token
-    x positions here. With ``first``, ``weights`` are those of the groups
-    ``first .. first + len(weights)`` alone (``gmm``'s ``group_offset``:
-    a share of sharded experts): the other groups' rows are not visited
-    and come out zero, as do their cotangents."""
-    m, k = rows.shape
-    tiling = (math.gcd(m, _TILE[0]), min(k, _TILE[1]), min(weights.shape[2], _TILE[2]))
-    offset = None if first is None else jnp.asarray(first, jnp.int32)
-    return megablox.gmm(rows.astype(jnp.bfloat16), weights.astype(jnp.bfloat16), group_sizes,
-                        jnp.bfloat16, tiling, offset, None, False, _interpret())
+    ``group_sizes`` (int32, one a group of ``weights``; a size may be 0).
+    bfloat16 operands and result, float32 accumulation: megablox ``gmm``,
+    whose gradients are ``gmm`` on the transposed weights and ``tgmm``
+    (one product a group, summed over the group's rows). The number of
+    rows has to be a multiple of 8; it is 64 x experts_per_token x
+    positions here. The sizes may add up to fewer rows than there are (a
+    share of sharded experts: the held groups' sizes, whose rows its sort
+    puts first): the kernels visit the tiles of the groups they are given
+    and nothing else, so the rows past the sum are not read, and those of
+    the result and of the cotangent to ``rows`` are UNINITIALISED, not
+    zero, as the tails the row moves leave (``ops/row_move.py``, "The
+    extent of a move"); the weights' gradient sums a group's own rows
+    alone and is whole."""
+    return _gmm(rows.astype(jnp.bfloat16), weights.astype(jnp.bfloat16), group_sizes)
 
 
 def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -526,17 +578,20 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
 
 
 def _expert_ffn(rows: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array, group_sizes: jax.Array,
-                first: Optional[int]) -> jax.Array:
-    """The three grouped products of the held experts on the sorted rows."""
-    gate = grouped_matmul(rows, gate_w, group_sizes, first)
-    up = grouped_matmul(rows, up_w, group_sizes, first)
-    return grouped_matmul(jax.nn.silu(gate) * up, down_w, group_sizes, first)
-
-
-def _first_group(held: Optional[Held]) -> Optional[int]:
-    """``gmm``'s ``group_offset``: a share's groups come first in its
-    sorted order (``_experts``), so its first held group is group 0."""
-    return None if held is None else 0
+                extent: Optional[jax.Array]) -> jax.Array:
+    """The held experts on the sorted rows, two grouped products round
+    the gated activation: gate and up are ONE product on the two weights
+    joined along their columns (in the bfloat16 cast the step makes
+    anyway), so the rows are read once, and their gradient is one product
+    over the joined width, summed in float32 inside the kernel, in place
+    of two bfloat16 results and their sum. ``silu(gate) * up`` between
+    them is a kernel (``ops/expert_gate.py``) that stops at ``extent`` as
+    the moves do: with an extent nothing here passes over a row past it,
+    and the tail of every intermediate, of the result and of the
+    cotangent to ``rows`` is uninitialised."""
+    gate_up = jnp.concatenate([gate_w.astype(jnp.bfloat16), up_w.astype(jnp.bfloat16)], axis=-1)
+    hidden = gated_activation(grouped_matmul(rows, gate_up, group_sizes), extent, _interpret())
+    return grouped_matmul(hidden, down_w, group_sizes)
 
 
 def _routed(n2, weight, order, group_sizes, held: Optional[Held], gate_w, up_w, down_w, layer: str) -> jax.Array:
@@ -545,7 +600,7 @@ def _routed(n2, weight, order, group_sizes, held: Optional[Held], gate_w, up_w, 
     with jax.named_scope(f"{layer}.dispatch"):
         rows = _dispatch(n2.astype(jnp.bfloat16), order, held)
     with jax.named_scope(f"{layer}.experts"):
-        out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, _first_group(held))
+        out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, _extent(held))
     with jax.named_scope(f"{layer}.combine"):
         return _combine(out, weight, order, held)
 
@@ -577,7 +632,7 @@ def _routed_recomputed_bwd(layer, args, g):
     with jax.named_scope(f"{layer}.dispatch"):
         rows, pull_rows = jax.vjp(lambda t: _dispatch(t.astype(jnp.bfloat16), order, held), n2)
     with jax.named_scope(f"{layer}.experts"):
-        out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, _first_group(held)), rows, gate_w, up_w, down_w)
+        out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, _extent(held)), rows, gate_w, up_w, down_w)
     with jax.named_scope(f"{layer}.combine"):
         d_out, d_weight = jax.vjp(lambda o, w: _combine(o, w, order, held), out, weight)[1](g)
     with jax.named_scope(f"{layer}.experts"):
@@ -615,7 +670,8 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
         group_sizes = jnp.sum(group[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
         held = Held(jnp.sum(group_sizes[:count]), group.reshape(n, k) < count, scale) if cfg.held_experts else None
     routed = _routed_recomputed if cfg.recompute_experts else _routed
-    mixed = routed(n2, weight, order, group_sizes, held, p["experts_gate"], p["experts_up"], p["experts_down"], layer)
+    # The products get the held groups' sizes alone (a share's come first): they then visit no row past the extent.
+    mixed = routed(n2, weight, order, group_sizes[:count], held, p["experts_gate"], p["experts_up"], p["experts_down"], layer)
     load = (jnp.roll(group_sizes, first) if cfg.held_experts else group_sizes).astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
     return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy,

@@ -1,0 +1,110 @@
+"""Pallas TPU kernels for the routed experts' gated activation,
+``silu(gate) * up``, and its gradient, on the sorted rows between the
+grouped products of the sparse-expert trunk (``models/trunk.py``).
+
+The gate and the up product are ONE grouped product there, so a row of
+the operand ``gu`` ``[slots, 2 * width]`` holds its gate in the first
+``width`` columns and its up in the rest, bfloat16:
+
+``expert_gate``       ``h = silu(gu[:, :width]) * gu[:, width:]``, ``[slots, width]``.
+``expert_gate_grad``  ``(gu, d_h) -> d_gu`` ``[slots, 2 * width]``: the gate's
+                      and the up's cotangents side by side, as the
+                      transposed grouped product reads them.
+
+Both work on whole rows in blocks, in float32, and round once to
+bfloat16, as XLA's fusion of the same expression does. They are
+memory-bound passes, and a kernel only for what XLA cannot be told: the
+extent. With ``extent`` (``ops/row_move.py``, "The extent of a move": an
+int32 scalar on the device, a share's held rows) the grid covers
+``rows_covered(slots, extent)`` rows, the blocks the moves cover, and
+the blocks past them are neither fetched nor written: the tail of either
+result is UNINITIALISED, as the tails of the moves' and of the grouped
+products' results are. Without it the grid is the static one. Off the
+TPU both run under the Pallas interpreter.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fishnet_tpu.ops.row_move import rows_covered
+
+__all__ = ["gated_activation"]
+
+#: Rows a grid step: the moves' row tile, so that its blocks are theirs.
+#: On a v5e 128, 256 and 512 read within 0.05 ms of each other under an
+#: extent and 512 the least at the static grid (PERF.md section 6, PR 36).
+_TM = 512
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20)
+
+
+def _halves(gu_ref, width: int):
+    gate, up = gu_ref[:, :width].astype(jnp.float32), gu_ref[:, width:].astype(jnp.float32)
+    return gate, up, jax.nn.sigmoid(gate)
+
+
+def _gate_kernel(gu_ref, out_ref):
+    gate, up, s = _halves(gu_ref, out_ref.shape[1])
+    out_ref[...] = (gate * s * up).astype(out_ref.dtype)
+
+
+def _gate_grad_kernel(gu_ref, d_ref, out_ref):
+    width = d_ref.shape[1]
+    gate, up, s = _halves(gu_ref, width)
+    d = d_ref[...].astype(jnp.float32)
+    silu = gate * s
+    out_ref[:, :width] = (d * up * (s + silu * (1.0 - s))).astype(out_ref.dtype)
+    out_ref[:, width:] = (d * silu).astype(out_ref.dtype)
+
+
+def _call(kernel, name: str, out_width: int, extent: Optional[jax.Array], interpret: bool, *operands: jax.Array,
+          in_place: bool = False) -> jax.Array:
+    """``kernel`` over row blocks of ``operands`` ``[slots, .]``, all of
+    them or those the moves cover under ``extent``. ``in_place``: the
+    result takes the first operand's buffer (a block is read before it
+    is written back)."""
+    slots = operands[0].shape[0]
+    tm = math.gcd(slots, _TM)
+    block = lambda width: pl.BlockSpec((tm, width), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(rows_covered(slots, extent) // tm,),
+        in_specs=[block(x.shape[1]) for x in operands],
+        out_specs=block(out_width),
+        out_shape=jax.ShapeDtypeStruct((slots, out_width), operands[0].dtype),
+        input_output_aliases={0: 0} if in_place else {},
+        compiler_params=_PARAMS,
+        name=name,
+        interpret=interpret,
+    )(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def gated_activation(gu: jax.Array, extent: Optional[jax.Array] = None, interpret: bool = False) -> jax.Array:
+    """``silu(gu[:, :width]) * gu[:, width:]`` for ``gu`` ``[slots, 2 *
+    width]``: ``[slots, width]`` of ``gu``'s dtype, float32 arithmetic.
+    With ``extent`` rows ``[0, extent)`` alone are promised (whole blocks
+    are computed), of the result and of the gradient to ``gu``: the rest
+    is never written and holds whatever the buffer held."""
+    return _call(_gate_kernel, "expert_gate", gu.shape[1] // 2, extent, interpret, gu)
+
+
+def _gated_fwd(gu, extent, interpret):
+    return gated_activation(gu, extent, interpret), (gu, extent)
+
+
+def _gated_bwd(interpret, res, d_h):
+    gu, extent = res
+    # ``gu`` is this rule's alone and dead after it: its cotangent, the largest array of the experts' backward pass, takes its place.
+    return _call(_gate_grad_kernel, "expert_gate_grad", gu.shape[1], extent, interpret, gu, d_h.astype(gu.dtype), in_place=True), None
+
+
+gated_activation.defvjp(_gated_fwd, _gated_bwd)

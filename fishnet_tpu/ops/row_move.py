@@ -31,9 +31,14 @@ are neither fetched, reshaped nor written back. Shapes do not change,
 so what lies past the last moved block is UNINITIALISED, not zero:
 the tail rows of ``rows_out``'s result, and in ``rows_back``'s result
 every place ``index[i]`` of a row ``i`` that was not moved (there, "every
-row is written exactly once" holds only without an extent). Who may
+row is written exactly once" holds only without an extent). The
+grouped products leave the same tails: given the held groups' sizes
+alone they write no row past the extent, of a result or of a cotangent
+(``models/trunk.py grouped_matmul``). Who may
 read them: the grouped products, which visit the held groups' rows
-alone and mask a straddling tile by ``select``; and the sums over a
+alone and mask a straddling tile by ``select``; the gated activation
+between them (``ops/expert_gate.py``), whose grid stops at the same
+block as a move's (``rows_covered``); and the sums over a
 token's slots in ``models/trunk.py``, which select by the slot's mask
 and never multiply, because what is there may be NaN (the interpreter
 fills it with NaN, which is what the tests lean on). The block that
