@@ -123,14 +123,6 @@ class AzMctsService:
         with self._lock:
             return self._visit_rate
 
-    def pool_counters(self) -> Dict:
-        """Tree- and dispatch-side stats (visits, collisions, batch
-        fill, subtree-reuse hits, plane dispatch/prewire counters) —
-        ``MctsPool.counters()``, which tests/test_mcts_plane.py reads
-        from the pool; nothing in the tree calls this method (ROADMAP
-        D8)."""
-        return self.pool.counters()
-
     def close(self) -> None:
         with self._lock:
             self._stopping = True

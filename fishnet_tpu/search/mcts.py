@@ -1008,7 +1008,7 @@ class MctsPool:
 
     def counters(self) -> Dict:
         """Tree- and dispatch-side stats (tests/test_mcts_plane.py reads
-        them; ``AzMctsEngine.pool_counters`` hands them on)."""
+        them)."""
         out: Dict = {
             "visits": self._visits,
             "collisions": self._collisions,

@@ -30,7 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
 from fishnet_tpu.models.trunk import balanced_bias
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
-from fishnet_tpu.train import startup
+from fishnet_tpu.train import startup, step_metrics
 from fishnet_tpu.train.trainer import _constrain
 from fishnet_tpu.utils import compile_cache
 
@@ -90,7 +90,7 @@ class AzTrainer:
         compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
-        self._first_step_pending = True
+        self._record = step_metrics.STEPS.attach("az")
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -142,14 +142,7 @@ class AzTrainer:
             return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: AzTrainState, batch: Batch):
-        if self._first_step_pending:
-            return self._first_step(state, batch)
-        return self._step_jit(state, batch)
-
-    def _first_step(self, state: AzTrainState, batch: Batch):
-        self._first_step_pending = False
-        with startup.first_step_span("az"):
-            return self.step(state, batch)
+        return self._record.run(self._step_jit, state, batch)
 
     def export(self, state: AzTrainState, path: str) -> None:
         """Save params as the .npz checkpoint --az-net-file consumes."""

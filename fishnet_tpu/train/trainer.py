@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import model as model_lib
-from fishnet_tpu.train import startup
+from fishnet_tpu.train import startup, step_metrics
 from fishnet_tpu.train.model import NNUE2SCORE, NetConfig, Params
 from fishnet_tpu.utils import compile_cache
 
@@ -100,7 +100,7 @@ class Trainer:
         compile_cache.configure()  # before the first jit
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
-        self._first_step_pending = True
+        self._record = step_metrics.STEPS.attach("nnue")
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -153,17 +153,13 @@ class Trainer:
             return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: TrainState, batch: Batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
-        if self._first_step_pending:
-            return self._first_step(state, batch)
+        return self._record.run(self._dispatch, state, batch)
+
+    def _dispatch(self, state: TrainState, batch: Batch):
         if self.mesh is not None:
             with self.mesh:
                 return self._step_jit(state, batch)
         return self._step_jit(state, batch)
-
-    def _first_step(self, state: TrainState, batch: Batch):
-        self._first_step_pending = False
-        with startup.first_step_span("nnue"):
-            return self.step(state, batch)
 
     def export(self, state: TrainState):
         """Quantize trained params into serving weights."""

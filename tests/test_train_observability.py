@@ -4,6 +4,7 @@ recorder that ``compile_cache.configure()`` installs, and the trainers'
 two start-up spans. CPU, toy widths."""
 
 import contextlib
+import os
 import re
 import time
 
@@ -17,6 +18,7 @@ from fishnet_tpu.models.az import AzConfig
 from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.telemetry.registry import MetricsRegistry
 from fishnet_tpu.telemetry.spans import EVENT_STAGES, RECORDER
+from fishnet_tpu.train import step_metrics
 from fishnet_tpu.train.az_trainer import AzTrainer
 from fishnet_tpu.train.model import NetConfig
 from fishnet_tpu.train.trainer import Trainer
@@ -36,6 +38,11 @@ MODEL_SCOPES = {
               "layer01.attention", "layer01.experts", "final_norm", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
+# a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
+SHARE = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8,
+                    dense_layers=1, dense_width=32, router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
+LATENT = TrunkConfig(**{**SHARE.__dict__, "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16})
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT}
 
 
 def make(kind):
@@ -53,7 +60,7 @@ def make(kind):
             "outcome": np.full((B,), 0.5, np.float32),
         }
     else:
-        trainer = AzTrainer(TRUNK if kind == "trunk" else AzConfig(channels=8, blocks=2, value_hidden=8))
+        trainer = AzTrainer(TRUNKS.get(kind) or AzConfig(channels=8, blocks=2, value_hidden=8))
         batch = {
             "planes": np.zeros((B, 8, 8, 19), np.float32),
             "policy_target": np.full((B, 4672), 1 / 4672, np.float32),
@@ -261,3 +268,227 @@ def test_startup_spans_once_a_trainer_with_telemetry_disabled(kind):
     other, _ = make(kind)
     other.step(other.init(2), batch)
     assert [s["stage"] for s in mine()].count("train_first_step") == 2  # once a trainer INSTANCE
+
+
+# -- C. the step recorder ----------------------------------------------------------
+
+LOSSES = {"nnue": {"loss", "pred_cp_mean", "pred_cp_abs"}, "az": {"loss", "policy_loss", "value_loss"}}
+ROUTING = {"expert_load_max", "expert_load_min", "router_entropy", "moved_rows"}
+STEP_KEYS = {
+    "nnue": LOSSES["nnue"] | {"ft_block_misses"},
+    "az": LOSSES["az"],
+    "trunk": LOSSES["az"] | ROUTING,
+    "share": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max"},
+    "latent": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms"},
+}
+
+
+@pytest.fixture()
+def steps(monkeypatch):
+    """The process's step recorder, empty, on a registry of its own."""
+    registry = MetricsRegistry()
+    recorder = step_metrics.StepRecorder(registry)
+    monkeypatch.setattr(step_metrics, "STEPS", recorder)
+    return recorder, registry
+
+
+def series(registry, name):
+    return {tuple(sorted(s.labels.items())): s.value for fam in registry.collect() if fam.name == name for s in fam.samples}
+
+
+@pytest.mark.parametrize("kind", ["nnue", "az"])
+def test_ring_holds_the_last_steps_and_no_more(kind, steps):
+    trainer, batch = make(kind)
+    state = trainer.init(0)
+    record = trainer._record
+    assert record.read() == step_metrics.Reading(f"{kind}-0", 0, []) and record.read().steps == 0
+    losses = []
+    for _ in range(step_metrics.RING_STEPS + 3):
+        state, metrics = trainer.step(state, batch)
+        losses.append(metrics["loss"])
+    reading = record.read()
+    assert (reading.trainer, reading.steps, reading.first) == (f"{kind}-0", step_metrics.RING_STEPS + 3, 3)
+    assert len(reading.metrics) == step_metrics.RING_STEPS == len(record._ring)
+    assert [step["loss"] for step in reading.metrics] == [float(x) for x in losses[3:]]  # oldest first, the first three gone
+    assert all(set(step) == STEP_KEYS[kind] and all(type(v) is float for v in step.values()) for step in reading.metrics)
+    newest = record.read(last=2)
+    assert (newest.first, newest.steps, newest.metrics) == (reading.steps - 2, reading.steps, reading.metrics[-2:])
+
+
+@pytest.mark.parametrize("kind", ["nnue", "trunk"])
+def test_step_transfers_nothing_and_takes_no_shared_lock(kind, steps, monkeypatch):
+    """After its first call ``.step`` stores what the program returned and
+    fetches nothing, and it goes on while another thread holds every lock
+    a reader or a new trainer takes."""
+    import threading
+
+    recorder, registry = steps
+    trainer, batch = make(kind)
+    batch = jax.device_put(batch)
+    state, _ = trainer.step(trainer.init(0), batch)
+
+    def refuse(*_a, **_kw):
+        raise AssertionError("a host transfer on the step's path")
+
+    returned, failures = [], []
+
+    def three_steps(state):
+        try:
+            with jax.transfer_guard_device_to_host("disallow_explicit"):
+                for _ in range(3):
+                    state, metrics = trainer.step(state, batch)
+                    returned.append(metrics)
+        except BaseException as err:  # handed to the test's thread, which raises it
+            failures.append(err)
+
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "device_get", refuse)
+        patched.setattr(jax, "block_until_ready", refuse)
+        worker = threading.Thread(target=three_steps, args=(state,))
+        with recorder._lock, registry._scrape_lock, registry._lock:
+            worker.start()
+            worker.join(timeout=120)
+            assert not worker.is_alive(), "a step waited for a lock a reader holds"
+    assert not failures, failures
+    held = list(trainer._record._ring)
+    assert [step for step, _metrics in held] == [0, 1, 2, 3]
+    for (_step, kept), metrics in zip(held[1:], returned):
+        assert kept is metrics  # the dict the step program returned, as it is
+        assert all(isinstance(value, jax.Array) for value in kept.values())
+    assert trainer._record.read().metrics[-1].keys() == STEP_KEYS[kind]
+
+
+def test_first_stepped_trainer_with_others_made_round_it(steps):
+    recorder, _registry = steps
+    idle_before, _ = make("az")
+    cell, batch = make("nnue")
+    assert recorder.first_stepped() is None
+    state, _ = cell.step(cell.init(0), batch)
+    later, later_batch = make("az")
+    later.step(later.init(0), later_batch)
+    later.step(later.init(1), later_batch)
+    idle_after, _ = make("nnue")
+    assert [(r.trainer, r.steps) for r in recorder.records()] == [("az-0", 0), ("nnue-1", 1), ("az-2", 2), ("nnue-3", 0)]
+    assert recorder.first_stepped() is cell._record
+    assert idle_before._record.read().metrics == [] == idle_after._record.read().metrics
+
+
+def test_a_collected_trainer_takes_its_ring(steps):
+    import gc
+    import weakref
+
+    recorder, registry = steps
+    gone, batch = make("nnue")
+    kept, _ = make("nnue")
+    state, metrics = gone.step(gone.init(0), batch)
+    kept.step(kept.init(0), batch)
+    ring = weakref.ref(gone._record)
+    assert recorder.first_stepped() is gone._record
+    assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-0", "nnue-1"}
+    del gone, state, metrics
+    gc.collect()  # the trainer and its jitted methods are a cycle
+    assert ring() is None
+    assert [r.trainer for r in recorder.records()] == ["nnue-1"] and recorder.first_stepped() is kept._record
+    assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-1"}
+
+
+@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent"])
+def test_collector_serves_each_scalar_of_the_latest_step(kind, steps):
+    """``fishnet_train_step{trainer,key}`` for exactly the keys the kind's
+    step returns, and ``fishnet_train_steps_total{trainer}``: every counter
+    doc/observability.md documents for a step is a series."""
+    recorder, registry = steps
+    trainer, batch = make(kind)
+    label = ("trainer", trainer._record.trainer)
+    assert series(registry, "fishnet_train_step") == {} and series(registry, "fishnet_train_steps_total") == {(label,): 0.0}
+    state = trainer.init(0)
+    for _ in range(2):
+        state, metrics = trainer.step(state, batch)
+    assert series(registry, "fishnet_train_steps_total") == {(label,): 2.0}
+    served = series(registry, "fishnet_train_step")
+    assert {dict(key)["key"] for key in served} == STEP_KEYS[kind] == set(metrics)
+    assert served == {(("key", key), label): float(value) for key, value in metrics.items()}  # the latest step's
+    text = registry.render_prometheus()
+    assert f'fishnet_train_step{{key="loss",trainer="{label[1]}"}} ' in text and "# TYPE fishnet_train_steps_total counter" in text
+
+
+def test_an_index_outside_its_block_shows_through_the_series(steps):
+    _recorder, registry = steps
+    trainer, batch = make("nnue")
+    misses = lambda: series(registry, "fishnet_train_step")[(("key", "ft_block_misses"), ("trainer", "nnue-0"))]
+    state, _ = trainer.step(trainer.init(0), batch)
+    assert misses() == 0.0
+    stray = dict(batch, indices=batch["indices"].copy())
+    block = stray["indices"][0, 0, 0] // NNUE.block_rows
+    stray["indices"][0, 0, 1] = ((block + 1) % 4) * NNUE.block_rows  # an active index in another block than its pair's
+    stray["indices"][2, 1, 3] = stray["indices"][0, 0, 1] if stray["indices"][2, 1, 0] // NNUE.block_rows != (block + 1) % 4 else block * NNUE.block_rows
+    state, _ = trainer.step(state, stray)
+    assert misses() >= 1.0
+    state, _ = trainer.step(state, batch)
+    assert misses() == 0.0  # a gauge of the latest step, not a sum
+    assert [step["ft_block_misses"] > 0 for step in trainer._record.read().metrics] == [False, True, False]
+
+
+@pytest.mark.parametrize("kind", ["nnue", "az"])
+def test_the_recorded_step_runs_the_bare_step_program(kind, steps):
+    """Host-only: what ``.step`` hands to the compiler through the recorder
+    (the first call inside its span) is the program a bare
+    ``jax.jit(trainer._step)`` lowers, and it is traced once."""
+    trainer, batch = make(kind)
+    jitted, lowered = trainer._step_jit, []
+
+    def spy(state, batch):
+        lowered.append(jitted.lower(state, batch))
+        return jitted(state, batch)
+
+    trainer._step_jit = spy
+    state = trainer.init(0)
+    for _ in range(2):
+        state, _metrics = trainer.step(state, batch)
+    bare, _ = make(kind)
+    with persistent_cache(None):
+        through = [scopes.without_metadata(low.compile().as_text()) for low in lowered]
+        parent_form = jax.jit(bare._step, donate_argnums=(0,)).lower(jax.eval_shape(bare._init, jax.random.PRNGKey(0)), batch)
+        assert through[0] == through[1] == scopes.without_metadata(parent_form.compile().as_text())
+    assert jitted._cache_size() == 1
+
+
+def test_a_reader_beside_the_stepping_thread_sees_whole_steps():
+    """One thread stores steps as fast as it can while readers copy the
+    ring: every reading is a run of consecutive steps, newest last, never
+    more than the ring holds, and each step's values belong together."""
+    import sys
+    import threading
+
+    record = step_metrics.StepRecord("az", 0)
+    stop, failures = threading.Event(), []
+
+    def read_until_stopped():
+        try:
+            while not stop.is_set():
+                reading = record.read()
+                numbers = [step["n"] for step in reading.metrics]
+                assert len(numbers) <= step_metrics.RING_STEPS
+                assert numbers == list(range(reading.first, reading.first + len(numbers)))
+                assert reading.steps == reading.first + len(numbers) <= record.steps + 1  # the count follows the store
+                assert all(step["twice"] == 2 * step["n"] for step in reading.metrics)
+        except BaseException as err:
+            failures.append(err)
+
+    readers = [threading.Thread(target=read_until_stopped) for _ in range(2 * (os.cpu_count() or 4))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for reader in readers:
+            reader.start()
+        deadline = time.monotonic() + 2.0
+        while time.monotonic() < deadline:
+            n = record.steps
+            record.run(lambda state, batch: (state, {"n": n, "twice": 2 * n}), None, None)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        for reader in readers:
+            reader.join(timeout=60)
+    assert not failures, failures[:1]
+    assert not any(reader.is_alive() for reader in readers) and record.steps > step_metrics.RING_STEPS
