@@ -144,16 +144,22 @@ kernels as their extent. The
 buffers keep their dropless shapes (``[N * k, hidden]`` sorted, ``[N,
 k, hidden // 128, 128]`` in token order: any routing fits, all slots
 held included); only the work follows the count: on a share nothing
-between the router and the sums over a token's k passes over a row past
-``held``. What a tail holds:
+from the router's choice to the sums over a token's k costs by the slot
+count. The chosen scores are a one-hot select, not a gather; the sums
+over a token's k are a third kernel (``rows_sum``) that fetches the held
+places of the token-order view, one DMA a row, and passes over nothing;
+the combine weights' gradient is made on the sorted side, a row product
+inside the move that gathers the cotangent, and a sort brings it to
+token order (``_combine``). What a tail holds:
 past ``held`` (rounded up to a block) every sorted buffer, the moves',
 the products' and the gate kernels' results and their cotangents alike,
 and the places of absent experts' slots in the token-order view, are
 uninitialised and may be NaN. Who may read a tail: ``gmm`` and
 ``tgmm`` (they visit held groups only and select by the group's rows),
-the moves and the gate kernels (they stop at the extent's block),
-and the three sums over a token's k, through ``_held_alone``'s select
-on the slot's mask, never through a product. Matrix products run in
+the moves and the gate kernels (they stop at the extent's block), and
+the select on the slot's mask that follows the weights' gradient's sort,
+never a product; the view's unwritten places are read by nobody
+(``ops/row_move.py``, "The extent of a move"). Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
 tower's; norms, the softmaxes, the router's scores, choice and combine
 weights, both sigmoid gates and the experts' gated activation are
@@ -185,7 +191,7 @@ from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
 from fishnet_tpu.ops.expert_gate import gated_activation
-from fishnet_tpu.ops.row_move import row_view, rows_back, rows_covered, rows_out
+from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
 
@@ -384,7 +390,7 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
 
 def _interpret() -> bool:
     """The trunk's Pallas kernels (the attention core, the grouped product,
-    the experts' gate pair and the two row moves) are one path everywhere: compiled by Mosaic on a TPU, run by
+    the experts' gate pair, the two row moves and a share's sum over a token's slots) are one path everywhere: compiled by Mosaic on a TPU, run by
     the Pallas interpreter elsewhere (the CPU of the tests), never
     another path."""
     return jax.default_backend() != "tpu"
@@ -392,7 +398,8 @@ def _interpret() -> bool:
 
 def _slots_by_token(rows: jax.Array, k: int) -> jax.Array:
     """``rows_back``'s result [N * k, sub, lanes] as [N, k, sub, lanes],
-    for the sums over a token's k. The barrier keeps XLA from moving the
+    for the sums over a token's k where every slot is held. The barrier
+    keeps XLA from moving the
     float32 convert (or the cotangent's broadcast) to the kernel's side
     of this reshape, where no fusion reaches it and it is written out
     whole, 2 GiB at the published sizes (PERF.md section 6, PR 27)."""
@@ -401,7 +408,7 @@ def _slots_by_token(rows: jax.Array, k: int) -> jax.Array:
 
 class Held(NamedTuple):
     """What a share's moves, gate kernels and sums know of its routing
-    (``_experts`` makes it; ``None`` where every expert is held). The held
+    (``_held`` makes it; ``None`` where every expert is held). The held
     experts' rows are the first ``extent`` of the sorted order, and no
     kernel between dispatch and combine covers a block past it (the
     grouped products know it as the sum of the held groups' sizes);
@@ -410,21 +417,33 @@ class Held(NamedTuple):
     combine weights in sorted order, which the share's sort carries
     along (the gather that makes them otherwise, 1.1 ms over 131,072
     slots, would be the longest operation left in the combine's
-    gradient, for the 1/16 of them that is read)."""
+    gradient, for the 1/16 of them that is read); ``places`` and
+    ``counts`` list the held slots' places in the token-order view, tile
+    of tokens by tile (``ops/row_move.py held_places``): the sums over a
+    token's slots read those rows of the view and no other."""
     extent: jax.Array  # int32 scalar
     mask: jax.Array  # bool [N, k]
     scale: jax.Array  # float32 [N * k], no gradient
+    places: jax.Array  # int32, a run a tile of tokens
+    counts: jax.Array  # int32 [tiles of tokens]
+
+
+def _held(extent: jax.Array, mask: jax.Array, scale: jax.Array) -> Held:
+    return Held(extent, mask, scale, *held_places(mask))
 
 
 def _extent(held: Optional[Held]) -> Optional[jax.Array]:
     return None if held is None else held.extent
 
 
-def _held_alone(x: jax.Array, held: Optional[Held]) -> jax.Array:
-    """``x`` [N, k, ...] with zeros where a slot's expert is absent. A
-    select, never a product: those places of the token-order view were
-    not written (``rows_back`` under an extent) and may hold NaN."""
-    return x if held is None else jnp.where(held.mask.reshape(held.mask.shape + (1,) * (x.ndim - 2)), x, 0)
+def _held_slots_sum(rows: jax.Array, order: jax.Array, held: Held, weight: Optional[jax.Array], dtype) -> jax.Array:
+    """A share's sorted ``rows`` [N * k, hidden] summed at their tokens
+    (under ``weight`` [N, k]), [N, hidden] of ``dtype``: the held rows go
+    back to their places in the token-order view (``rows_back`` to its
+    extent) and ``rows_sum`` fetches those places alone, one DMA a row;
+    nothing passes over the view."""
+    view = rows_back(rows, order, extent=held.extent, interpret=_interpret())
+    return rows_sum(view, held.places, held.counts, weight, k=held.mask.shape[1], dtype=dtype, interpret=_interpret())
 
 
 @jax.custom_vjp
@@ -437,7 +456,7 @@ def _dispatch(tokens: jax.Array, order: jax.Array, held: Optional[Held] = None) 
     against 8.9 for the gather at the published sizes, PERF.md section 5).
     With ``held`` the rows past its extent are not moved, either way:
     the result's tail is uninitialised, and the gradient sums a token's
-    held slots alone."""
+    held slots alone (``_held_slots_sum``)."""
     k = order.shape[0] // tokens.shape[0]
     return rows_out(row_view(tokens), order // k, extent=_extent(held), interpret=_interpret())
 
@@ -449,8 +468,10 @@ def _dispatch_fwd(tokens, order, held):
 def _dispatch_bwd(res, g):
     order, held = res
     n, k = order.shape
-    per_slot = _slots_by_token(rows_back(g, order.reshape(n * k), extent=_extent(held), interpret=_interpret()), k)
-    return _held_alone(per_slot, held).sum(axis=1).reshape(n, -1), None, None
+    if held is not None:
+        return _held_slots_sum(g, order.reshape(n * k), held, None, g.dtype), None, None
+    per_slot = _slots_by_token(rows_back(g, order.reshape(n * k), interpret=_interpret()), k)
+    return per_slot.sum(axis=1).reshape(n, -1), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -461,32 +482,51 @@ def _combine(out: jax.Array, weight: jax.Array, order: jax.Array, held: Optional
     """The experts' sorted rows ``out`` [N * k, hidden] bfloat16 back in
     token order (``rows_back``) and each token's k summed under
     ``weight`` [N, k], float32 weights and sum: [N, hidden] float32. The
-    rows in token order stay in the row view, ``[N, k, hidden // 128,
-    128]``, which is also the residual of the weights' gradient. The
     gradient to ``out`` is the dispatch again with a scale: each slot's
     token's cotangent times the slot's weight in float32, then rounded
-    to bfloat16 (``rows_out``). With ``held`` the rows of ``out`` past
-    its extent are not read and those of its gradient not written; the
-    sums take a token's held slots alone, and an absent slot's weight
-    has no gradient."""
+    to bfloat16 (``rows_out``). Where every slot is held the rows in
+    token order stay in the row view, ``[N, k, hidden // 128, 128]``,
+    which is also the residual of the weights' gradient. With ``held``
+    the rows of ``out`` past its extent are not read and those of its
+    gradient not written; the
+    sum takes a token's held slots alone (``_held_slots_sum``); the
+    residual is ``out`` itself, and the weights' gradient is made on the
+    sorted side: the move that gathers a slot's token's cotangent also
+    multiplies it with the slot's row of ``out`` and sums, in float32,
+    and a sort keyed on ``order`` (a permutation) brings the sums to
+    token order; an absent slot's weight has no gradient (a select: what
+    lies past the extent may be NaN)."""
     return _combine_fwd(out, weight, order, held)[0]
 
 
+def _combine_kept(out, weight, order, held):
+    """What the combine's gradient needs of its forward, without the sum
+    (``_routed_recomputed`` makes it again and has no use for the sum): a
+    share's sorted rows themselves; where every slot is held the rows in
+    token order."""
+    if held is not None:
+        return out, weight, order, held
+    return _slots_by_token(rows_back(out, order, interpret=_interpret()), weight.shape[1]), weight, order, held
+
+
 def _combine_fwd(out, weight, order, held):
-    n, k = weight.shape
-    per_slot = _slots_by_token(rows_back(out, order, extent=_extent(held), interpret=_interpret()), k)
-    mixed = jnp.sum(weight[:, :, None, None] * _held_alone(per_slot, held).astype(jnp.float32), axis=1)
-    return mixed.reshape(n, -1), (per_slot, weight, order, held)
+    kept = _combine_kept(out, weight, order, held)
+    if held is not None:
+        return _held_slots_sum(out, order, held, weight, jnp.float32), kept
+    mixed = jnp.sum(weight[:, :, None, None] * kept[0].astype(jnp.float32), axis=1)
+    return mixed.reshape(weight.shape[0], -1), kept
 
 
 def _combine_bwd(res, g):
-    per_slot, weight, order, held = res
+    rows, weight, order, held = res
     n, k = weight.shape
     g = row_view(g)
-    # A slot's sum is over its own row alone, so the select may follow it (inside the reduce it costs 0.8 ms a layer, PERF.md section 6, PR 34).
-    d_weight = _held_alone(jnp.sum(g[:, None] * per_slot.astype(jnp.float32), axis=(2, 3)), held)
-    scale = weight.reshape(n * k)[order] if held is None else held.scale
-    d_out = rows_out(g, order // k, scale, extent=_extent(held), dtype=per_slot.dtype, interpret=_interpret())
+    if held is not None:
+        d_out, dots = rows_out_dot(g, order // k, held.scale, rows, extent=held.extent, interpret=_interpret())
+        _, d_weight = jax.lax.sort((order, dots), num_keys=1)
+        return d_out, jnp.where(held.mask, d_weight.reshape(n, k), 0.0), None, None
+    d_weight = jnp.sum(g[:, None] * rows.astype(jnp.float32), axis=(2, 3))
+    d_out = rows_out(g, order // k, weight.reshape(n * k)[order], dtype=rows.dtype, interpret=_interpret())
     return d_out, d_weight, None, None
 
 
@@ -567,7 +607,11 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
         probs = score / jnp.sum(score, axis=-1, keepdims=True)
     if "expert_bias" in p:  # the choice is on score + bias, the weights on the score; neither has a gradient through the bias
         _, expert = jax.lax.top_k(score + jax.lax.stop_gradient(p["expert_bias"]), cfg.experts_per_token)
-        weight = jnp.take_along_axis(score, expert, axis=-1)
+        # take_along_axis(score, expert) as a select: a token's experts are distinct, so each sum is one score and zeros, and so is its
+        # gradient's. XLA's gather of tokens x k scalars and the scatter that is its gradient cost 2.48 ms a layer at 131,072 slots, the
+        # select and the two sums 0.52 (PERF.md section 6, PR 38).
+        chosen = expert[:, :, None] == jnp.arange(score.shape[-1], dtype=expert.dtype)
+        weight = jnp.sum(jnp.where(chosen, score[:, None, :], 0.0), axis=-1)
     else:
         weight, expert = jax.lax.top_k(score, cfg.experts_per_token)
     if cfg.route_norm:  # over all the chosen, held here or not: the shares of all chips add up
@@ -634,7 +678,7 @@ def _routed_recomputed_bwd(layer, args, g):
     with jax.named_scope(f"{layer}.experts"):
         out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, _extent(held)), rows, gate_w, up_w, down_w)
     with jax.named_scope(f"{layer}.combine"):
-        d_out, d_weight = jax.vjp(lambda o, w: _combine(o, w, order, held), out, weight)[1](g)
+        d_out, d_weight, _, _ = _combine_bwd(_combine_kept(out, weight, order, held), g)
     with jax.named_scope(f"{layer}.experts"):
         d_rows, *d_weights = pull_ffn(d_out)
     with jax.named_scope(f"{layer}.dispatch"):
@@ -668,7 +712,7 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
         else:
             order = jnp.argsort(group, stable=True)
         group_sizes = jnp.sum(group[:, None] == jnp.arange(cfg.experts)[None, :], axis=0, dtype=jnp.int32)
-        held = Held(jnp.sum(group_sizes[:count]), group.reshape(n, k) < count, scale) if cfg.held_experts else None
+        held = _held(jnp.sum(group_sizes[:count]), group.reshape(n, k) < count, scale) if cfg.held_experts else None
     routed = _routed_recomputed if cfg.recompute_experts else _routed
     # The products get the held groups' sizes alone (a share's come first): they then visit no row past the extent.
     mixed = routed(n2, weight, order, group_sizes[:count], held, p["experts_gate"], p["experts_up"], p["experts_down"], layer)

@@ -356,8 +356,12 @@ def test_a_shares_experts_against_a_loop_over_the_held_experts(sizes):
 #: (first held expert, held slots): the extent of its moves. Slots =
 #: tokens x k against the row tile of 512: 64, 192 and 640 are not
 #: multiples of it (tiles of 64, 64 and 128), 1024 and 1536 are. The
-#: shares' extents: none, one row, one under and one over a block's edge,
-#: all rows; one share whose experts are not the first.
+#: shares' extents: none, one row, one under, on and one over a block's
+#: edge, all rows; one share whose experts are not the first; and what the
+#: sums over a token's held slots meet (192 tokens are three tiles of 64,
+#: 256 two of 128, whose 768 slots at top-6 fill no whole index block):
+#: a whole tile of tokens with no held slot, a token with all its slots
+#: held.
 MOVE_CASES = [
     (64, 1, 64, "random", None),
     (512, 2, 64, "one_expert", None),
@@ -374,6 +378,10 @@ MOVE_CASES = [
     (192, 8, 64, "share", (0, 1536)),
     (192, 8, 64, "share", (5, 700)),
     (96, 2, 256, "share", (2, 70)),
+    (192, 8, 64, "share", (0, 512)),
+    (192, 8, 64, "share_but_one_tile", (0, 300)),
+    (192, 8, 64, "share_with_a_whole_token", (3, 40)),
+    (256, 6, 256, "share_but_one_tile", (0, 90)),
 ]
 MOVE_IDS = [f"n{n}-k{k}-h{h}-{routing}" + (f"-first{share[0]}-held{share[1]}" if share else "") for n, k, h, routing, share in MOVE_CASES]
 HELD_COUNT = 3  # of the 8 experts of a share's move cases
@@ -392,13 +400,23 @@ def _sorted_order(rng, tokens: int, top_k: int, routing: str, share=None, weight
     first, held_slots = share
     held = (first + rng.integers(0, HELD_COUNT, slots)) % 8
     absent = (first + rng.integers(HELD_COUNT, 8, slots)) % 8
-    experts = np.where(rng.permutation(slots) < held_slots, held, absent)
+    rank = rng.permutation(slots).reshape(tokens, top_k)  # the held slots are those of the lowest ranks
+    tile = np.gcd(tokens, 128)  # the tokens a grid step of ``rows_sum``
+    if routing == "share_but_one_tile":  # the second tile of tokens holds nothing
+        rank[tile:2 * tile] = slots
+        rank = np.argsort(np.argsort(rank.reshape(slots), kind="stable"), kind="stable")
+    if routing == "share_with_a_whole_token":
+        rank[5] = -1
+        rank = np.argsort(np.argsort(rank.reshape(slots), kind="stable"), kind="stable")
+    experts = np.where(rank.reshape(slots) < held_slots, held, absent)
     group = jnp.asarray((experts - first) % 8, jnp.int32)
     mask = (group < HELD_COUNT).reshape(tokens, top_k)
     assert int(mask.sum()) == held_slots
+    assert routing != "share_but_one_tile" or (tokens >= 2 * tile and not mask[tile:2 * tile].any())
+    assert routing != "share_with_a_whole_token" or mask[5].all()
     order = jnp.argsort(group, stable=True)
     scale = jnp.zeros(slots) if weight is None else weight.reshape(slots)[order]
-    return order, trunk.Held(jnp.asarray(held_slots, jnp.int32), mask, scale)
+    return order, trunk._held(jnp.asarray(held_slots, jnp.int32), mask, scale)
 
 
 def _small_integers(rng, shape):
@@ -479,6 +497,75 @@ def test_combine_is_plain_indexing_a_weighted_sum_and_their_gradient(tokens, top
     assert np.all(np.isfinite(got_dw)) and np.allclose(got_dw, want_dw, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(want_dw))))  # float32 sums in another order
     if held is not None:
         assert not np.any(np.asarray(got_dw)[~np.asarray(held.mask)])  # an absent slot's weight has no gradient
+
+
+@pytest.mark.parametrize("tokens,top_k,hidden,routing,share", [case for case in MOVE_CASES if case[4] in ((0, 1), (0, 513), (5, 700), (0, 90))],
+                         ids=lambda value: str(value).replace(" ", ""))
+def test_the_sums_loop_bodies_of_eight_rows_select_their_padding_away(monkeypatch, tokens, top_k, hidden, routing, share):
+    """On the TPU ``rows_sum`` runs its loops eight rows to a body, and
+    the rows that fill a tile's last body are its first held row again,
+    selected away; the interpreter runs one row to a body and never meets
+    them. Here it is made to: both sums of a share (the combine's under
+    the weights, the dispatch's gradient without) at eight rows a body,
+    at counts a tile of 1, 7 and under, and no multiple of eight, against
+    the masked sums; NaN everywhere a share may not read."""
+    from fishnet_tpu.ops import row_move
+
+    monkeypatch.setattr(row_move, "_unroll", lambda interpret, most=1: most)
+    rng = np.random.default_rng(tokens + hidden)
+    slots = tokens * top_k
+    weight = jnp.asarray(rng.random((tokens, top_k)) + 0.1, jnp.float32)
+    order, held = _sorted_order(rng, tokens, top_k, routing, share, weight)
+    assert np.any(np.asarray(held.counts) % 8)
+    rows = _poisoned(jnp.asarray(rng.integers(-8, 9, (slots, hidden)), jnp.bfloat16), held)
+    per_slot = jnp.where(held.mask[:, :, None], jnp.take(rows, jnp.argsort(order), axis=0).reshape(tokens, top_k, hidden), 0).astype(jnp.float32)
+    mixed = trunk._held_slots_sum(rows, order, held, weight, jnp.float32)
+    assert np.allclose(mixed, jnp.einsum("nk,nkh->nh", weight, per_slot), rtol=1e-6, atol=1e-6)
+    summed = trunk._held_slots_sum(rows, order, held, None, jnp.bfloat16)
+    assert summed.dtype == jnp.bfloat16 and np.array_equal(np.asarray(summed, np.float32), np.asarray(per_slot.sum(axis=1)))  # small integers: exact
+
+
+@pytest.mark.parametrize("top_k,score,norm,scale", [(8, "sigmoid", True, 2.826), (6, "sigmoid", True, 2.448), (2, "softmax", False, 1.0)],
+                         ids=["afmoe", "deepseek_v3", "softmax"])
+def test_the_routers_chosen_scores_are_take_along_axis_bit_for_bit(top_k, score, norm, scale):
+    """With an ``expert_bias`` the choice is on ``score + bias`` and the
+    weights are the chosen experts' scores: a one-hot select and a sum
+    over the experts, where it was ``take_along_axis`` and its scatter
+    gradient. A token's experts are distinct, so every sum is one score
+    and zeros: weights and both gradients equal the gather's bit for bit,
+    also where ``score + bias`` ties (expert 9 is expert 3 again, and the
+    inputs are coarse: whole tokens repeat). Operation by operation: a
+    compiler that fuses the sigmoid into the select may round it another
+    way than into the gather, and that is its own business."""
+    rng = np.random.default_rng(top_k)
+    tokens, hidden, experts = 96, 32, 16
+    cfg = trunk.TrunkConfig(hidden=hidden, heads=2, head_dim=16, experts=experts, experts_per_token=top_k, router_score=score,
+                            route_norm=norm, route_scale=scale)
+    n2 = jnp.asarray(rng.integers(-2, 3, (tokens, hidden)), jnp.float32)
+    n2 = n2.at[48:].set(n2[:48])
+    router_w = jnp.asarray(rng.integers(-4, 5, (hidden, experts)) / 16, jnp.float32)
+    router_w = router_w.at[:, 9].set(router_w[:, 3])
+    bias = jnp.asarray(rng.integers(-1, 2, experts) / 8, jnp.float32).at[9].set(0.0).at[3].set(0.0)
+    cot = jnp.asarray(rng.standard_normal((tokens, top_k)), jnp.float32)
+
+    def gathered(n2, router_w):
+        logits = jnp.dot(n2, router_w, precision=jax.lax.Precision.HIGHEST)
+        chosen_from = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
+        _, expert = jax.lax.top_k(chosen_from + bias, top_k)
+        weight = jnp.take_along_axis(chosen_from, expert, axis=-1)
+        if norm:
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+        return expert, weight * scale if scale != 1.0 else weight
+
+    route = lambda n2, router_w: trunk._route(n2, {"router_w": router_w, "expert_bias": bias}, cfg)[:2]
+    (expert, weight), (want_expert, want_weight) = route(n2, router_w), gathered(n2, router_w)
+    assert np.array_equal(expert, want_expert) and np.array_equal(np.asarray(weight), np.asarray(want_weight))
+    tied = np.asarray(expert == 3).any(axis=1) & np.asarray(expert == 9).any(axis=1)
+    assert tied.any() and np.array_equal(np.asarray(expert[:48]), np.asarray(expert[48:]))  # the ties are met
+    got = jax.grad(lambda *a: jnp.sum(route(*a)[1] * cot), (0, 1))(n2, router_w)
+    want = jax.grad(lambda *a: jnp.sum(gathered(*a)[1] * cot), (0, 1))(n2, router_w)
+    for g, w in zip(got, want):
+        assert np.any(np.asarray(w)) and np.array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_trainer_overfits_a_small_batch():

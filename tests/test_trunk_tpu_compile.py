@@ -116,6 +116,42 @@ def test_row_moves_and_their_gradients_compile_at_published_widths(one_chip, com
     assert text.count("moe_rows_out") >= 2 and text.count("moe_rows_back") >= 2
 
 
+def test_step_text_equal_tells_a_moved_kernel_from_a_changed_one(one_chip, compiled_for_tpu, tmp_path, capsys, monkeypatch):
+    """``tools/step_text_equal.py`` on a two-kernel program: traced from
+    another line the text differs and the tool finds the programs equal
+    (a Mosaic module keeps the lines of its trace's call stack); with
+    another row tile it does not. It parses the compiled text with
+    ``jax._src``'s MLIR bindings: this is where an upgrade that breaks it
+    shows."""
+    import importlib.util
+    import math
+    from pathlib import Path
+
+    from fishnet_tpu.ops import row_move
+
+    spec = importlib.util.spec_from_file_location("step_text_equal", Path(__file__).resolve().parent.parent / "tools" / "step_text_equal.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    args = (jax.ShapeDtypeStruct((2048, HIDDEN), jnp.bfloat16, sharding=one_chip), jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip))
+
+    def moves(rows, index):
+        return row_move.rows_back(row_move.rows_out(row_move.row_view(rows), index), index)
+
+    def again(f):  # a function of its own every time (``jit`` keeps what it traced), named alike (a module is named after its function)
+        g = lambda rows, index: f(rows, index)
+        g.__name__ = "moves"
+        return g
+
+    def text(f, name):
+        (tmp_path / name).write_text(jax.jit(f).lower(*args).compile().as_text())
+        return str(tmp_path / name)
+
+    here, there = text(again(moves), "here"), text(again(again(moves)), "there")  # the same program, traced through one more frame
+    assert tool.main([here, there]) == 0 and "text without metadata: differs" in capsys.readouterr().out
+    monkeypatch.setattr(row_move, "_tile", lambda rows, most=256: math.gcd(rows, most))  # ``rows_back`` takes 512 rows a grid step otherwise
+    assert tool.main([here, text(again(moves), "other")]) == 1 and "['moe_rows_back.1']" in capsys.readouterr().out
+
+
 def test_experts_step_at_published_widths_gathers_no_slot_rows(one_chip, compiled_for_tpu):
     """``value_and_grad`` of ``_experts`` as the cell runs it: no XLA
     gather produces the 1 GiB ``bf16[262144,2048]`` any more."""
@@ -193,13 +229,18 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
     assert "board_attention" in text and "board_attention_grad" in text
 
 
-#: Temporaries of the program below as the parent of PR 36 compiled it (the products with a ``group_offset``, XLA's passes
-#: over all 131,072 rows between them), bytes. What the cells run is the recomputed form, which may not pass it. The kept
-#: form, which no cell runs at these shapes (the step does not fit without the recomputation), holds one ``[131072, 1024]``
-#: array more at its peak since gate and up are one ``[131072, 2048]``: 3 x 512 + 2 x 256 MiB of slot-sized arrays against
-#: 2 x 512 + 3 x 256.
-PARENT_TEMPORARIES = {False: 2_018_981_376, True: 2_662_708_224}
-KEPT_ROOM = 192 << 20
+#: Temporaries of the program below as the parent of PR 38 compiled it, bytes: neither form may pass them by more than the
+#: lists of a share's held places (``ROOM``). PR 38 reads 2,481,530,880 (kept: the parent's and 137,216 bytes) and 2,078,909,952
+#: (recomputed: 133 MB under, the combine's gradient keeps the experts' rows, writes their gradient over them and makes no
+#: token-order view again). The loss squares the layer's result, so that the cotangent waits for the forward pass as it does
+#: under any layer above this one. Until PR 38 the loss was the plain sum, whose cotangent is a constant: XLA then ran the
+#: combine's gradient BEFORE the forward's experts (the parent's schedule: its ``moe_rows_out`` at 367, the forward's first
+#: ``gmm`` at 378, its last at 433) and the bytes read were those of an interleaving no step can have, 2,219,951,616 for the
+#: kept form against the 2,481,393,664 of the same tree here. PR 38's gradient reads the experts' rows, so that interleaving
+#: went and the old program read 3,525,360,128, the forward's view held across the gradient's move: a reading of the test
+#: program, not of the layer (PERF.md section 6, PR 38).
+PARENT_TEMPORARIES = {False: 2_481_393_664, True: 2_211_950_080}
+ROOM = 1 << 20
 
 
 @pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
@@ -210,10 +251,12 @@ def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_
     them; with ``recompute_experts`` the forward's kernels
     are there a second time, in the backward pass. The moves take the
     held count as their grid's bound (Mosaic compiles a traced grid), the
+    sums over a token's slots loop over a tile's count of held slots, the
     buffers keep their shape; and no
     ``cond`` or ``while`` wraps a layer's scope: every ``layerNN.<part>``
     is the second level of its path, where ``benchmark/scopes.py`` reads it."""
     import dataclasses
+    import math
     import re
 
     cfg = dataclasses.replace(AFMOE, recompute_experts=recompute)
@@ -224,14 +267,26 @@ def test_a_share_of_the_experts_compiles_at_published_widths(one_chip, compiled_
 
     def loss(n2, p):
         with jax.named_scope("forward"):  # the phase a trainer's step puts first
-            return jnp.sum(trunk._experts(n2, p, cfg, "layer01")[0])
+            return jnp.sum(jnp.square(trunk._experts(n2, p, cfg, "layer01")[0]))  # the cotangent is the result's, not a constant
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile()
     text = compiled.as_text()
-    kernels = text.count('custom_call_target="tpu_custom_call"')
-    assert kernels == (6 + 2 + 4) + (2 + 1 + 2 if recompute else 0), kernels  # six products, the gate pair, four moves; two, one and two made again
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    # Six products, the gate pair and six kernels of ``ops/row_move.py``: rows out; rows back and their sum at the tokens; rows
+    # out under the scale with the weights' products; rows back and their sum. Made again: two products, the gate and the
+    # dispatch's rows out (the combine's gradient needs nothing of its forward but the experts' rows).
+    assert len(kernels) == (6 + 2 + 6) + (2 + 1 + 1 if recompute else 0), len(kernels)
+    own = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1) for line in kernels]
+    assert [sum(name in kernel for kernel in own) for name in ("moe_rows_out", "moe_rows_back", "moe_rows_sum")] == [3 if recompute else 2, 2, 2], own
+    # On a share nothing under the router, the dispatch or the combine costs by the slot count (PR 38): no XLA gather or scatter
+    # of a slot's worth of elements (the chosen scores are a select, the weights' gradient comes through a sort), and nothing
+    # but a kernel touches the token-order view.
+    routing = [line for line in text.splitlines() if re.search(r"layer01\.(?:router|dispatch|combine)", line)]
+    elements = lambda line: math.prod(int(n) for n in re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1).split(",") if n)
+    assert len(routing) > 100 and not [line for line in routing if re.search(r" (?:gather|scatter)\(", line) and elements(line) >= 131_072][:2]
+    assert not [line for line in text.splitlines() if re.search(r"\[16384,8,16,128\]|\[131072,16,128\]", line) and line not in kernels][:2]
     temporaries = compiled.memory_analysis().temp_size_in_bytes
-    assert temporaries <= PARENT_TEMPORARIES[recompute] + (0 if recompute else KEPT_ROOM), temporaries
+    assert temporaries <= PARENT_TEMPORARIES[recompute] + ROOM, temporaries
     names = {name for joined in re.findall(r'op_name="([^"]*)"', text) for name in joined.split(";") if re.search(r"layer\d+\.", name)}
     second_level = re.compile(r"jit\(loss\)/(?:jvp\(forward\)|transpose\(jvp\(forward\)\))/layer01\.(?:router|dispatch|experts|combine)(?:/|$)")
     assert len(names) > 100 and not [name for name in names if not second_level.match(name)]
