@@ -4,9 +4,9 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes three
-published blocks as one code path at different values; none has a
-causal mask here, and a board is far shorter than any's window, so
+tower's own policy and value heads. ``TrunkConfig`` describes four
+published blocks as one code path at different values; no attention has
+a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
 
 The first block is LLaDA-MoE-7B-A1B's (inclusionAI, config.json: hidden
@@ -95,6 +95,53 @@ checkpoint of the published per-head order is brought in by that
 permutation (``benchmark/families/mla_trunk.py`` does it for the
 reference's parameters and takes the gradients back).
 
+The fourth block is Nemotron-Labs-TwoTower-30B-A3B's (nvidia,
+config.json, ``model_type`` nemotron_h; the ONE tower that file declares:
+hidden 2688, 52 layers by ``hybrid_override_pattern``, each ONE sublayer
+under ONE norm: 23 Mamba-2 mixers of 64 heads x 64 with a state of 128 in
+8 groups and a convolution of 4, 6 attention layers of 32 query heads
+over 2 key-value heads of 128, 23 routed feed-forwards of 128 ungated
+squared-ReLU experts of width 1856, top-6, sigmoid scores, one shared
+expert of width 3712, ``routed_scaling_factor`` 2.5, RMSNorm eps 1e-5);
+what its config.json does not say is Mamba-2's published mixer, listed
+under ``assumed`` in ``benchmark/configs/nemotron-twotower-trunk-train.json``
+(a second tower, its conditioning and block diffusion are named there as
+LEFT OUT: no key defines them). ``TrunkConfig.pattern`` is the published
+string, cut::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer i   x <- x + Mixer_kind(i)( N_i(x) )                          kind(i) = pattern[i]: M, E or *; one norm, ``layer_norm[i]``
+    M         [z | xBC | dt] = n W_inproj                               ``mamba_in`` [hidden, 2 x inner + 2 x groups x state + heads], inner = heads x P
+              xBC <- silu( conv(xBC) ),  conv(u)[t] = b + sum_k w[:, k] u[t - (taps - 1) + k]     depthwise along a board's squares,
+                                                                        nothing before square 0;  xBC = [x | B | C]
+              D_t = softplus(dt_t + dt_bias) [heads];  a = -exp(A_log) [heads]
+              head h (P columns of x), group g = h // (heads // groups) (``state`` columns of B and of C):
+                S_t = exp(D_t a) S_{t-1} + D_t x_t B_t^T                S [P, state], zero before square 0 of every board
+                y_t = S_t C_t + D_skip[h] x_t
+              64 tokens are ONE chunk (the published chunk is 128), so the recurrence is exactly its dual form, which is computed:
+                y_i = sum_{j <= i} exp(c_i - c_j) (C_i . B_j) D_j x_j + D_skip x_i,   c = cumsum(D a) along the board
+              y <- N_grouped( y * silu(z); gain ``mamba_norm`` [inner], ``groups`` groups )
+              out = y W_outproj                                         ``mamba_out`` [inner, hidden]
+    *         the second block's attention without qk-norm, gate or post-norm: RoPE on every such layer, 16 query heads a key-value head
+    E         the third block's router (sigmoid, the choice on score + expert_bias, weights renormalised over all k and scaled);
+              E_e(u) = relu(u W_up[e])^2 W_down[e]: TWO products, no gate (``gated_ffn`` False); Shared the same at ``shared_width``
+    out       N_final(x) -> the heads
+
+Mechanism, the mixer: the projections, the convolution (four shifted
+multiply-adds), softplus and the gated grouped norm are XLA's under
+``layerNN.mamba``; the scan's core is one Pallas kernel pair
+(``ops/board_scan.py``: ``board_scan``, ``board_scan_grad``) under
+``layerNN.scan`` beside it, one B/C group and its heads of a few boards a
+grid step, the decay from a cumulative sum made in the kernel, nothing
+``[64, 64]`` or ``[.., heads, P]`` in HBM; ``mamba_in`` is split on the
+weights' side, so the kernels' operands are the convolution's results as
+they are. Mechanism, widths no tile divides (``_whole_lanes``,
+``_whole_rows``: ONE rule, zeros inside the step, never a parameter): an
+expert width of 1,856 = 14.5 lane tiles is padded to 1,920 on the
+weights' side; a moved row of 2,688 = 21 x 128 is not whole (8, 128)
+tiles, so the tokens go to dispatch as rows of 3,072 and the combine's
+sum is cut back. ``_tile`` hands Mosaic no tile that is not whole lanes.
+
 ``held_experts = (first, count)`` tells the expert layer which experts
 it holds, as one chip of an expert-parallel deployment does: it routes
 over all ``experts``, computes the part of the result that its own give
@@ -166,7 +213,9 @@ weights, both sigmoid gates and the experts' gated activation are
 float32.
 
 Parameters are one flat dict (the ``.npz`` checkpoint format), the
-layers of a kind stacked on a leading axis: ``wq [layers, ..]``,
+layers of a kind stacked on a leading axis (with a pattern: ``mamba_*``,
+``conv_*``, ``dt_bias``, ``A_log``, ``D_skip`` over the M layers, the
+attention's over the ``*`` layers, ``layer_norm [layers, hidden]``): ``wq [layers, ..]``,
 ``dense_gate [dense_layers, ..]``, ``router_w [routed layers, hidden,
 experts]``, ``experts_gate [routed layers, held, hidden, width]``.
 ``expert_bias [routed layers, experts]`` is a buffer beside them: the
@@ -190,12 +239,15 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tg
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
-from fishnet_tpu.ops.expert_gate import gated_activation
+from fishnet_tpu.ops.board_scan import board_scan
+from fishnet_tpu.ops.expert_gate import gated_activation, squared_relu
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
 
 _INIT_STD = 0.02
+#: Mamba-2's ``time_step_min``, ``time_step_max`` and ``time_step_floor``: where a fresh mixer's steps lie (``init_trunk_params``).
+_TIME_STEP_MIN, _TIME_STEP_MAX, _TIME_STEP_FLOOR = 0.001, 0.1, 1e-4
 
 
 @dataclass(frozen=True)
@@ -234,11 +286,35 @@ class TrunkConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # What the fourth block adds (module docstring). ``pattern``: a layer is ONE sublayer under ONE norm, of the kind its character
+    # names (``M`` a Mamba-2 mixer, ``E`` a routed feed-forward, ``*`` attention); ``layers`` is then its length. None: the three
+    # blocks above, every layer attention then a feed-forward, and the mixer's four sizes are not read.
+    pattern: Optional[str] = None
+    qk_norm: bool = True
+    gated_ffn: bool = True  # False: relu(u W_up)^2 W_down, two products an expert, routed and shared alike
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 0  # B and C are one a group of mamba_heads // mamba_groups heads
+    state_size: int = 0
+    conv_kernel: int = 4
 
     def __post_init__(self) -> None:
         first, count = self.held
         latent = self.kv_lora_rank is not None
+        pattern = self.pattern or ""
+        if pattern and self.layers in (1, len(pattern)):
+            object.__setattr__(self, "layers", len(pattern))
         wrong = {
+            f"pattern {self.pattern!r} is not a string of M (Mamba-2 mixer), E (routed feed-forward) and * (attention) with an E in it":
+                self.pattern is not None and (not pattern or set(pattern) - set("ME*") or "E" not in pattern),
+            f"pattern {self.pattern!r} names {len(pattern)} layers, not {self.layers}": bool(pattern) and self.layers != len(pattern),
+            "a pattern's layers have one sublayer and one norm: no dense_layers (no E is dense), nope_layers, post_norms, gated "
+            "attention or latent": bool(pattern) and bool(self.dense_layers or self.nope_layers or self.post_norms or self.gated_attention
+                                                          or latent),
+            f"an M layer wants mamba_heads, mamba_head_dim, mamba_groups, state_size and conv_kernel over 0 and whole groups, got "
+            f"{(self.mamba_heads, self.mamba_head_dim, self.mamba_groups, self.state_size, self.conv_kernel)}":
+                "M" in pattern and (min(self.mamba_heads, self.mamba_head_dim, self.mamba_groups, self.state_size, self.conv_kernel) < 1
+                                    or self.mamba_heads % self.mamba_groups != 0),
             f"a latent of {self.kv_lora_rank} wants qk_nope_head_dim, qk_rope_head_dim and v_head_dim, got "
             f"{(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)}":
                 latent and min(self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) < 1,
@@ -265,24 +341,35 @@ class TrunkConfig:
 
     @property
     def routed_layers(self) -> int:
-        return self.layers - self.dense_layers
+        return self.pattern.count("E") if self.pattern else self.layers - self.dense_layers
+
+    @property
+    def attention_layers(self) -> int:
+        return self.pattern.count("*") if self.pattern else self.layers
 
 
 def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     """Every trained tensor of a trunk checkpoint by name."""
-    n, r, h, w = cfg.layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
+    n, r, h, w = cfg.attention_layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
     inner, kv_inner, held = cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim, cfg.held[1]
     if cfg.kv_lora_rank is None:
-        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner),
-                     "q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim), "wo": (n, inner, h)}
+        norms = {"q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim)} if cfg.qk_norm else {}
+        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner), **norms, "wo": (n, inner, h)}
     else:  # columns in the order ``_attention`` reads them
         rank, nope, rope, value = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         attention = {"wq": (n, h, cfg.heads * (nope + rope)), "wkv_a": (n, h, rank + rope), "kv_norm": (n, rank),
                      "wkv_b": (n, rank, cfg.heads * (nope + value)), "wo": (n, cfg.heads * value, h)}
+    if cfg.pattern:  # one norm a layer; the tensors of a kind stacked over the layers of that kind, none where the pattern has none
+        m, mixer, state = cfg.pattern.count("M"), cfg.mamba_heads * cfg.mamba_head_dim, cfg.mamba_groups * cfg.state_size
+        mamba = {"mamba_in": (m, h, 2 * mixer + 2 * state + cfg.mamba_heads), "conv_w": (m, mixer + 2 * state, cfg.conv_kernel),
+                 "conv_b": (m, mixer + 2 * state), "dt_bias": (m, cfg.mamba_heads), "A_log": (m, cfg.mamba_heads),
+                 "D_skip": (m, cfg.mamba_heads), "mamba_norm": (m, mixer), "mamba_out": (m, mixer, h)}
+        layers = {"layer_norm": (cfg.layers, h), **(mamba if m else {}), **(attention if n else {})}
+    else:
+        layers = {"attn_norm": (n, h), **attention, "moe_norm": (n, h)}
     shapes = {
         "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
-        "attn_norm": (n, h), **attention,
-        "moe_norm": (n, h), "router_w": (r, h, cfg.experts),
+        **layers, "router_w": (r, h, cfg.experts),
         "experts_gate": (r, held, h, w), "experts_up": (r, held, h, w), "experts_down": (r, held, w, h),
         "final_norm": (h,),
         "policy_w": (1, 1, h, cfg.policy_planes), "policy_b": (cfg.policy_planes,),
@@ -297,6 +384,8 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     for kind, count, width in (("dense", cfg.dense_layers, cfg.dense_width), ("shared", r, cfg.shared_width)):
         if width:
             shapes.update({f"{kind}_gate": (count, h, width), f"{kind}_up": (count, h, width), f"{kind}_down": (count, width, h)})
+    if not cfg.gated_ffn:
+        shapes = {name: shape for name, shape in shapes.items() if not name.endswith("_gate")}
     return shapes
 
 
@@ -308,14 +397,28 @@ def trunk_buffer_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
 
 def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Params:
     """Normal(0, 0.02) matrices, unit norm gains, zero biases; the value
-    head's last layer starts at zero, as the tower's."""
+    head's last layer starts at zero, as the tower's. A Mamba-2 mixer's
+    own tensors start as Mamba-2's: the decay rates ``exp(A_log)``
+    uniform in [1, 16], the steps ``softplus(dt_bias)`` log-uniform in
+    [0.001, 0.1] and at least 0.0001 (``dt_bias`` their inverse
+    softplus), the direct term ``D_skip`` 1, the convolution uniform
+    within 1 / sqrt(its taps) under a zero bias."""
     shapes = trunk_param_shapes(cfg)
     keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
+    uniform = lambda name, low, high: jax.random.uniform(keys[name], shapes[name], jnp.float32, low, high)
     params: Params = {}
     for name, shape in shapes.items():
-        if name.endswith("_norm"):
+        if name.endswith("_norm") or name == "D_skip":
             params[name] = jnp.ones(shape, jnp.float32)
-        elif (name.endswith("_b") and len(shape) == 1) or name == "value_fc2_w":  # a bias is a vector: ``wkv_b`` is a matrix
+        elif name == "A_log":
+            params[name] = jnp.log(uniform(name, 1.0, 16.0))
+        elif name == "dt_bias":
+            step = jnp.maximum(jnp.exp(uniform(name, math.log(_TIME_STEP_MIN), math.log(_TIME_STEP_MAX))), _TIME_STEP_FLOOR)
+            params[name] = step + jnp.log(-jnp.expm1(-step))
+        elif name == "conv_w":
+            params[name] = uniform(name, -1.0, 1.0) / math.sqrt(shape[-1])
+        # a bias is a vector (``wkv_b`` is a matrix), or the convolution's, a vector a mixer
+        elif (name.endswith("_b") and len(shape) == 1) or name in ("value_fc2_w", "conv_b"):
             params[name] = jnp.zeros(shape, jnp.float32)
         else:
             params[name] = jax.random.normal(keys[name], shape, jnp.float32) * _INIT_STD
@@ -350,6 +453,55 @@ def _gated_ffn(n: jax.Array, p: Params, kind: str) -> jax.Array:
     return _matmul(jax.nn.silu(_matmul(n, p[f"{kind}_gate"])) * _matmul(n, p[f"{kind}_up"]), p[f"{kind}_down"])
 
 
+def _ffn(n: jax.Array, p: Params, kind: str, gated: bool) -> jax.Array:
+    """The feed-forward every token passes, gated as the three blocks'
+    or the fourth block's ``relu(n W_u)^2 W_d``."""
+    return _gated_ffn(n, p, kind) if gated else _matmul(jnp.square(jax.nn.relu(_matmul(n, p[f"{kind}_up"]))), p[f"{kind}_down"])
+
+
+def _board_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """A depthwise causal convolution along the squares of a board:
+    ``y[t] = b + sum_k w[:, k] x[t - (taps - 1) + k]`` for ``x`` [boards,
+    64, channels] float32 and ``w`` [channels, taps], nothing before
+    square 0 (``torch.nn.Conv1d(groups=channels, padding=taps - 1)`` cut
+    to the board, as the published mixer applies it)."""
+    taps = w.shape[-1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return b + sum(padded[:, k:k + SQUARES] * w[:, k] for k in range(taps))
+
+
+def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """[tokens, hidden] float32, 64 tokens a board -> a Mamba-2 mixer's
+    output, same shape, and its two counters (the mean step; the
+    smallest of the heads' decays across a board, mean over boards). The
+    projections, the convolution, softplus and the gated grouped norm are
+    XLA's under ``<layer>.mamba``; the scan's core is ``board_scan``
+    under ``<layer>.scan`` beside it, never inside (call this under none
+    of a layer's scopes). ``mamba_in``'s columns are the published
+    ``[z | x | B | C | dt]``, split on the weights' side as the latent
+    projections are."""
+    heads, groups = cfg.mamba_heads, cfg.mamba_groups
+    inner, state = heads * cfg.mamba_head_dim, groups * cfg.state_size
+    by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
+    with jax.named_scope(f"{layer}.mamba"):
+        n = _rms_norm(x, p["layer_norm"], cfg.rms_eps)
+        w = p["mamba_in"]
+        z, xbc, dt = _matmul(n, w[:, :inner]), _matmul(n, w[:, inner:2 * inner + 2 * state]), _matmul(n, w[:, 2 * inner + 2 * state:])
+        xbc = jax.nn.silu(_board_conv(by_board(xbc), p["conv_w"], p["conv_b"]))
+        xs, bs, cs = (xbc[..., lo:hi].astype(jnp.bfloat16) for lo, hi in ((0, inner), (inner, inner + state), (inner + state, inner + 2 * state)))
+        step = jax.nn.softplus(by_board(dt) + p["dt_bias"])  # time_step_limit (0, inf) clips nothing
+        rate = -jnp.exp(p["A_log"])
+        counted, decay = jax.lax.stop_gradient((step, rate))
+        across = jnp.exp(jnp.sum(counted[:, 1:] * decay, axis=1))  # exp(c_63 - c_0) [boards, heads]
+        counters = {"ssm_dt_mean": jnp.mean(counted), "ssm_decay_min": jnp.min(jnp.mean(across, axis=0))}
+    with jax.named_scope(f"{layer}.scan"):
+        y = board_scan(xs, bs, cs, step, rate, p["D_skip"], groups, _interpret())
+    with jax.named_scope(f"{layer}.mamba"):
+        y = y.reshape(x.shape[0], inner).astype(jnp.float32) * jax.nn.silu(z)
+        y = _rms_norm(y.reshape(-1, groups, inner // groups), p["mamba_norm"].reshape(groups, -1), cfg.rms_eps)
+        return _matmul(y.reshape(-1, inner), p["mamba_out"]), counters
+
+
 def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, layer: str = "layer00") -> Tuple[jax.Array, Optional[jax.Array]]:
     """[tokens, hidden] float32, 64 tokens a board -> the attention
     branch's output, same shape, and the latent's root mean square (None
@@ -379,8 +531,9 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
             return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), latent_rms
     with jax.named_scope(f"{layer}.attention"):
         q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
-        mixed = board_attention(q, k, v.astype(jnp.bfloat16), p["q_norm"], p["k_norm"],
-                                cfg.rope_theta if rope else None, cfg.rms_eps, _interpret())
+        gains = dict(g_q=p["q_norm"], g_k=p["k_norm"]) if cfg.qk_norm else dict(g_q=None, g_k=None, head_dim=cfg.head_dim)
+        mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if rope else None, eps=cfg.rms_eps, interpret=_interpret(),
+                                **gains)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
             mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
@@ -539,12 +692,57 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 _TILE = (512, 1024, 1024)
 
 
+#: A lane tile, and the columns of a moved row that is whole (8, 128) tiles.
+_LANES, _ROW_TILE = 128, 1024
+
+
 def _tile(width: int, most: int) -> int:
     """The largest tile up to ``most`` that divides ``width``, in whole
     128-lane tiles where the width has them: a tile that does not divide
-    is computed whole and half empty (1,536 columns: 768, not 1,024)."""
-    step = 128 if width % 128 == 0 else 1
+    is computed whole and half empty (1,536 columns: 768, not 1,024). A
+    width that is not whole lanes is the tests' tiny nets' under the
+    interpreter; Mosaic is never handed one (``_expert_ffn`` pads an
+    expert's width by ``_whole_lanes`` before it gets here)."""
+    if width % _LANES and not _interpret():
+        raise ValueError(f"a grouped product's dimension of {width} is not whole {_LANES}-lane tiles: pad it on the weights' side "
+                         "(_whole_lanes, _padded) before the product")
+    step = _LANES if width % _LANES == 0 else 1
     return max(t for t in range(step, min(width, most) + 1, step) if width % t == 0)
+
+
+def _padded(x: jax.Array, axis: int, size: int) -> jax.Array:
+    """``x`` with zeros along ``axis`` up to ``size`` (``x`` itself where it has that size already)."""
+    if x.shape[axis] == size:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - x.shape[axis])
+    return jnp.pad(x, pad)
+
+
+def _whole_lanes(width: int) -> int:
+    """THE rule for a dimension of the routed path that Mosaic's tiles do
+    not divide, both of its cases: what a width is padded to, with zeros,
+    inside the step. An expert's width that is not a multiple of 128
+    lanes (1,856 = 14.5 x 128) goes to the next multiple (1,920): up's
+    columns and down's rows. ``relu(0)^2 = 0`` (and ``silu(0) x 0``) meets
+    down's zero rows, so no number of the result changes. A width under
+    128 is left as it is: a tiny net under the interpreter, which
+    ``_tile`` lets through there and nowhere else."""
+    return width if width < _LANES else -(-width // _LANES) * _LANES
+
+
+def _whole_rows(hidden: int) -> int:
+    """The rule's other case: a moved row. ``ops/row_move.py`` addresses
+    a row as ``[hidden // 128, 128]``, and a DMA moves whole (8, 128)
+    tiles: a hidden whose 128-lane pieces are not a multiple of 8 (2,688
+    = 21 x 128) moves as the next such row (24 x 128 = 3,072), the
+    tokens padded before the dispatch, up's rows and down's columns
+    beside them, the combine's sum cut back to ``hidden``. A hidden of
+    1,024 or less is left as it is (the tests' nets). Either padding is
+    made from the parameters' and the tokens' casts, is no parameter, has
+    no gradient (a pad's transpose is a slice) and is in no checkpoint
+    and in no comparison with a reference."""
+    return hidden if hidden <= _ROW_TILE else -(-hidden // _ROW_TILE) * _ROW_TILE
 
 
 @jax.custom_vjp
@@ -621,7 +819,7 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
     return expert, weight, probs
 
 
-def _expert_ffn(rows: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array, group_sizes: jax.Array,
+def _expert_ffn(rows: jax.Array, gate_w: Optional[jax.Array], up_w: jax.Array, down_w: jax.Array, group_sizes: jax.Array,
                 extent: Optional[jax.Array]) -> jax.Array:
     """The held experts on the sorted rows, two grouped products round
     the gated activation: gate and up are ONE product on the two weights
@@ -632,21 +830,40 @@ def _expert_ffn(rows: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax
     them is a kernel (``ops/expert_gate.py``) that stops at ``extent`` as
     the moves do: with an extent nothing here passes over a row past it,
     and the tail of every intermediate, of the result and of the
-    cotangent to ``rows`` is uninitialised."""
-    gate_up = jnp.concatenate([gate_w.astype(jnp.bfloat16), up_w.astype(jnp.bfloat16)], axis=-1)
+    cotangent to ``rows`` is uninitialised. Without ``gate_w`` an expert
+    is ``relu(u W_up)^2 W_down``: the up product alone, then
+    ``expert_gate.py``'s second form, ``squared_relu``, a kernel on the
+    same grid under the same extent (an XLA elementwise would pass over
+    the tail), then the down product. The weights are padded to the
+    rows' width (``_whole_rows``: the rows come padded) and to whole
+    lanes of their own (``_whole_lanes``)."""
+    moved, lanes = rows.shape[1], _whole_lanes(up_w.shape[2])
+    into = lambda w: _padded(_padded(w.astype(jnp.bfloat16), 1, moved), 2, lanes)
+    down_w = _padded(_padded(down_w, 1, lanes), 2, moved)
+    if gate_w is None:
+        hidden = squared_relu(grouped_matmul(rows, into(up_w), group_sizes), extent, _interpret())
+        return grouped_matmul(hidden, down_w, group_sizes)
+    gate_up = jnp.concatenate([into(gate_w), into(up_w)], axis=-1)
     hidden = gated_activation(grouped_matmul(rows, gate_up, group_sizes), extent, _interpret())
     return grouped_matmul(hidden, down_w, group_sizes)
 
 
 def _routed(n2, weight, order, group_sizes, held: Optional[Held], gate_w, up_w, down_w, layer: str) -> jax.Array:
     """Dispatch, the held experts and combine: [N, hidden] float32 normed
-    tokens -> the weighted sum of each token's held slots, same shape."""
+    tokens -> the weighted sum of each token's held slots, same shape
+    (between the two every row is ``_whole_rows(hidden)`` wide)."""
     with jax.named_scope(f"{layer}.dispatch"):
-        rows = _dispatch(n2.astype(jnp.bfloat16), order, held)
+        rows = _dispatch(_moved(n2), order, held)
     with jax.named_scope(f"{layer}.experts"):
         out = _expert_ffn(rows, gate_w, up_w, down_w, group_sizes, _extent(held))
     with jax.named_scope(f"{layer}.combine"):
-        return _combine(out, weight, order, held)
+        mixed = _combine(out, weight, order, held)
+        return mixed if mixed.shape == n2.shape else mixed[:, :n2.shape[1]]
+
+
+def _moved(tokens: jax.Array) -> jax.Array:
+    """The normed tokens as the moves take them: bfloat16, a row whole tiles."""
+    return _padded(tokens.astype(jnp.bfloat16), 1, _whole_rows(tokens.shape[1]))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
@@ -674,11 +891,11 @@ def _routed_recomputed_bwd(layer, args, g):
     # making every layer's rows again at once, as soon as the forward pass has the tokens.
     n2, g = jax.lax.optimization_barrier((n2, g))
     with jax.named_scope(f"{layer}.dispatch"):
-        rows, pull_rows = jax.vjp(lambda t: _dispatch(t.astype(jnp.bfloat16), order, held), n2)
+        rows, pull_rows = jax.vjp(lambda t: _dispatch(_moved(t), order, held), n2)
     with jax.named_scope(f"{layer}.experts"):
         out, pull_ffn = jax.vjp(lambda *a: _expert_ffn(*a, group_sizes, _extent(held)), rows, gate_w, up_w, down_w)
     with jax.named_scope(f"{layer}.combine"):
-        d_out, d_weight, _, _ = _combine_bwd(_combine_kept(out, weight, order, held), g)
+        d_out, d_weight, _, _ = _combine_bwd(_combine_kept(out, weight, order, held), _padded(g, 1, _whole_rows(g.shape[1])))
     with jax.named_scope(f"{layer}.experts"):
         d_rows, *d_weights = pull_ffn(d_out)
     with jax.named_scope(f"{layer}.dispatch"):
@@ -715,7 +932,7 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
         held = _held(jnp.sum(group_sizes[:count]), group.reshape(n, k) < count, scale) if cfg.held_experts else None
     routed = _routed_recomputed if cfg.recompute_experts else _routed
     # The products get the held groups' sizes alone (a share's come first): they then visit no row past the extent.
-    mixed = routed(n2, weight, order, group_sizes[:count], held, p["experts_gate"], p["experts_up"], p["experts_down"], layer)
+    mixed = routed(n2, weight, order, group_sizes[:count], held, p.get("experts_gate"), p["experts_up"], p["experts_down"], layer)
     load = (jnp.roll(group_sizes, first) if cfg.held_experts else group_sizes).astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
     return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy,
@@ -731,29 +948,29 @@ _EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wkv_a", "kv_
                 "post_mlp_norm")
 _ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias")
 _DENSE = ("dense_gate", "dense_up", "dense_down")
+#: A pattern's tensors by the kind of layer that owns them (``layer_norm`` is every layer's).
+_BY_KIND = {"M": ("mamba_in", "conv_w", "conv_b", "dt_bias", "A_log", "D_skip", "mamba_norm", "mamba_out"),
+            "*": ("wq", "wk", "wv", "q_norm", "k_norm", "wo"), "E": _ROUTED}
 
 
-def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
-    """``trunk_forward`` and the routing counters of the step's metrics:
-    the most and the fewest slots any expert of any routed layer received
-    (``expert_load_max``, ``expert_load_min``), the router's mean entropy
-    in nats (``router_entropy``: of the softmax, or of the sigmoid scores
-    over their sum) and every routed layer's slots an expert
-    (``expert_slots`` [routed layers, experts], what the balance update
-    reads); the rows each of a routed layer's moves covers, summed over
-    the layers (``moved_rows``: every slot where all experts are held; a
-    share's held count a layer, rounded up to whole blocks of the move);
-    for a share, the slots that fell on the held experts, summed
-    over the layers (``held_slots``); with an ``expert_bias`` among
-    ``params``, its largest magnitude (``expert_bias_abs_max``); with a
-    latent, the root mean square of the key-value latent before its norm,
-    over the tokens, mean over the layers (``latent_rms``: a latent that
-    collapses or blows up shows here before the loss does)."""
-    b = planes.shape[0]
-    # Scope names are a contract (doc/observability.md "Training and compilation").
-    with jax.named_scope("embed"):
-        x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
-        x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
+def _routed_layer(x: jax.Array, norm: jax.Array, layer: Params, cfg: TrunkConfig, name: str, post) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """A routed feed-forward added to the stream (the norm under the
+    router's scope, the held experts, the shared expert, the residual
+    under the combine's), and the layer's routing counters."""
+    with jax.named_scope(f"{name}.router"):
+        n2 = _rms_norm(x, norm, cfg.rms_eps)
+    mixed, layer_counters = _experts(n2, layer, cfg, name)
+    if cfg.shared_width:
+        with jax.named_scope(f"{name}.shared"):
+            mixed = mixed + _ffn(n2, layer, "shared", cfg.gated_ffn)
+    with jax.named_scope(f"{name}.combine"):
+        return x + post(mixed), layer_counters
+
+
+def _block_layers(params: Params, x: jax.Array, cfg: TrunkConfig):
+    """The first three blocks' layers: attention, then a dense or a
+    routed feed-forward. Returns the stream, the routed layers' counters
+    and each layer's latent's root mean square (None without a latent)."""
     counters, latent = [], []
     for i in range(cfg.layers):
         # Layers of a kind are stacked: a routed layer's tensors are indexed from the first routed layer.
@@ -773,15 +990,66 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
             with jax.named_scope(f"{name}.dense"):
                 x = x + post(_gated_ffn(_rms_norm(x, layer["moe_norm"], cfg.rms_eps), layer, "dense"))
             continue
-        with jax.named_scope(f"{name}.router"):
-            n2 = _rms_norm(x, layer["moe_norm"], cfg.rms_eps)
-        mixed, layer_counters = _experts(n2, layer, cfg, name)
-        if cfg.shared_width:
-            with jax.named_scope(f"{name}.shared"):
-                mixed = mixed + _gated_ffn(n2, layer, "shared")
-        with jax.named_scope(f"{name}.combine"):
-            x = x + post(mixed)
+        x, layer_counters = _routed_layer(x, layer["moe_norm"], layer, cfg, name, post)
         counters.append(layer_counters)
+    return x, counters, latent
+
+
+def _pattern_layers(params: Params, x: jax.Array, cfg: TrunkConfig):
+    """The fourth block's layers: ONE sublayer under ONE norm each, of
+    the kind the pattern names, its tensors indexed from the first layer
+    of its kind. Returns the stream, the routed layers' counters and the
+    mixers'."""
+    counters, mixers, seen = [], [], dict.fromkeys(_BY_KIND, 0)
+    for i, kind in enumerate(cfg.pattern):
+        layer = {name: params[name][seen[kind]] for name in _BY_KIND[kind] if name in params}
+        seen[kind] += 1
+        name, norm = f"layer{i:02d}", params["layer_norm"][i]
+        if kind == "E":
+            x, layer_counters = _routed_layer(x, norm, layer, cfg, name, lambda y: y)
+            counters.append(layer_counters)
+            continue
+        if kind == "M":
+            branch, mixer_counters = _mamba(x, {**layer, "layer_norm": norm}, cfg, name)
+            mixers.append(mixer_counters)
+        else:
+            branch, _ = _attention(x, {**layer, "attn_norm": norm}, cfg, layer=name)
+        with jax.named_scope(f"{name}.{'mamba' if kind == 'M' else 'attention'}"):
+            x = x + branch
+    return x, counters, mixers
+
+
+def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
+    """``trunk_forward`` and the routing counters of the step's metrics:
+    the most and the fewest slots any expert of any routed layer received
+    (``expert_load_max``, ``expert_load_min``), the router's mean entropy
+    in nats (``router_entropy``: of the softmax, or of the sigmoid scores
+    over their sum) and every routed layer's slots an expert
+    (``expert_slots`` [routed layers, experts], what the balance update
+    reads); the rows each of a routed layer's moves covers, summed over
+    the layers (``moved_rows``: every slot where all experts are held; a
+    share's held count a layer, rounded up to whole blocks of the move);
+    for a share, the slots that fell on the held experts, summed
+    over the layers (``held_slots``); with an ``expert_bias`` among
+    ``params``, its largest magnitude (``expert_bias_abs_max``); with a
+    latent, the root mean square of the key-value latent before its norm,
+    over the tokens, mean over the layers (``latent_rms``: a latent that
+    collapses or blows up shows here before the loss does); with Mamba-2
+    mixers, the mean step ``D_t`` after its softplus, over tokens, heads
+    and mixers (``ssm_dt_mean``), and the smallest decay across a board
+    ``exp(c_63 - c_0)`` of any head of any mixer, mean over the boards
+    (``ssm_decay_min``: a head that forgets a board within it shows here
+    before the loss does)."""
+    b = planes.shape[0]
+    # Scope names are a contract (doc/observability.md "Training and compilation").
+    with jax.named_scope("embed"):
+        x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
+        x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
+    latent, mixers = [], []
+    if cfg.pattern:
+        x, counters, mixers = _pattern_layers(params, x, cfg)
+    else:
+        x, counters, latent = _block_layers(params, x, cfg)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
@@ -796,6 +1064,8 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
         **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
         **({"latent_rms": jnp.mean(jnp.stack(latent))} if cfg.kv_lora_rank is not None else {}),
+        **({"ssm_dt_mean": jnp.mean(jnp.stack([c["ssm_dt_mean"] for c in mixers])),
+            "ssm_decay_min": jnp.min(jnp.stack([c["ssm_decay_min"] for c in mixers]))} if mixers else {}),
     })
 
 
@@ -814,7 +1084,9 @@ def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
 #: first block alone has the first three; the rest default to it.
 HPARAMS = "trunk_hparams"
 _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_scale", "balance_rate", "sliding_window",
-            "sigmoid", "route_norm", "first_held", "nope_mask")
+            "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups")
+#: A pattern's checkpoint carries the pattern itself, its characters as bytes.
+PATTERN = "trunk_pattern"
 
 
 def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
@@ -822,21 +1094,28 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     them where the state has one) and ``trunk_hparams``: experts_per_token,
     rope_theta, rms_eps, then embed_scale, route_scale, balance_rate,
     sliding_window (0: none), sigmoid scores (0 or 1), route_norm, the
-    first held expert (-1: all are held) and the layers without RoPE as
-    a bit mask. ``recompute_experts`` is the trainer's and in no file."""
+    first held expert (-1: all are held), the layers without RoPE as a
+    bit mask, and what no shape of the fourth block gives: head_dim
+    (there are no qk-norm gains to read it from) and mamba_groups. A
+    pattern's file carries the pattern too (``trunk_pattern``, its
+    characters as bytes). ``recompute_experts`` is the trainer's and in
+    no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
     arrays[HPARAMS] = np.asarray([
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
         cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
-        cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers)], np.float64)
+        cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
+        cfg.head_dim, cfg.mamba_groups], np.float64)
+    if cfg.pattern:
+        arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
     return arrays
 
 
 def trunk_config_from_params(params: Params) -> TrunkConfig:
     """The ``TrunkConfig`` of a checkpoint, from its shapes and its
     ``trunk_hparams``; a ValueError names what does not fit."""
-    required = ("router_w", "experts_gate", "wq", "wo", "attn_norm", "value_fc1_b", "policy_b", HPARAMS)
-    missing = [k for k in required if k not in params]
+    required = (("router_w", "experts_up", "layer_norm") if PATTERN in params else ("router_w", "experts_gate", "wq", "wo", "attn_norm"))
+    missing = [k for k in (*required, "value_fc1_b", "policy_b", HPARAMS) if k not in params]
     if missing:
         raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
     given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
@@ -845,8 +1124,10 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS)}")
     hp = dict(zip(_HPARAMS, [*given, *defaults[len(given):]]))
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
-    (routed, hidden, experts), layers = shape("router_w"), shape("attn_norm")[0]
     width_of = lambda name: shape(name)[2] if name in params else 0
+    if PATTERN in params:
+        return _checked(params, len(given), lambda: _pattern_config(params, hp, shape, width_of))
+    (routed, hidden, experts), layers = shape("router_w"), shape("attn_norm")[0]
     if "kv_norm" in params:  # the latent form: its four widths and the head count from five shapes
         missing = [k for k in ("wkv_a", "wkv_b") if k not in params]
         if missing:
@@ -866,8 +1147,7 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         head_dim = shape("q_norm")[1]
         attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim,
                          kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
-    try:
-        cfg = TrunkConfig(
+    return _checked(params, len(given), lambda: TrunkConfig(
             hidden=hidden, layers=layers, **attention,
             experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_gate")[3],
             rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
@@ -879,10 +1159,46 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
             router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
             held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_gate")[1]),
             balance_rate=hp["balance_rate"],
-        )
+        ))
+
+
+def _pattern_config(params: Params, hp: Dict[str, float], shape, width_of) -> TrunkConfig:
+    """The fourth block's ``TrunkConfig``: the pattern from the file, the
+    sizes of each kind of layer from that kind's shapes."""
+    pattern = bytes(np.asarray(params[PATTERN], np.uint8)).decode("ascii")
+    own = {"M": ("mamba_norm", "dt_bias", "conv_w"), "*": ("wq", "wk", "wo")}
+    missing = [k for kind, names in own.items() if kind in pattern for k in names if k not in params]
+    if missing:
+        raise ValueError(f"trunk checkpoint: a pattern {pattern!r} without {missing}")
+    attention, mamba = {}, {}
+    if "*" in pattern:
+        head_dim = shape("q_norm")[1] if "q_norm" in params else int(hp["head_dim"])
+        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, qk_norm="q_norm" in params,
+                         kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
+    if "M" in pattern:
+        heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
+        mamba = dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
+                     state_size=(channels - inner) // (2 * groups) if groups else 0)
+    _, hidden, experts = shape("router_w")
+    return TrunkConfig(
+        hidden=hidden, pattern=pattern, **attention, **mamba,
+        experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_up")[3],
+        rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"], value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
+        sliding_window=int(hp["sliding_window"]) or None, embed_scale=hp["embed_scale"],
+        gated_ffn="experts_gate" in params, shared_width=width_of("shared_up"),
+        router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
+        held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_up")[1]),
+        balance_rate=hp["balance_rate"],
+    )
+
+
+def _checked(params: Params, given: int, make) -> TrunkConfig:
+    """``make()``'s configuration if the file is exactly what it describes."""
+    try:
+        cfg = make()
     except ValueError as err:
         raise ValueError(f"trunk checkpoint: mismatched shapes: {err}") from err
-    expected = {**trunk_param_shapes(cfg), **trunk_buffer_shapes(cfg), HPARAMS: (len(given),)}
+    expected = {**trunk_param_shapes(cfg), **trunk_buffer_shapes(cfg), HPARAMS: (given,), **({PATTERN: (cfg.layers,)} if cfg.pattern else {})}
     got = {k: tuple(np.shape(v)) for k, v in params.items()}
     if expected != got:
         diff = set(expected) ^ set(got) or {k for k in expected if expected[k] != got[k]}

@@ -134,18 +134,19 @@ def _head(b, g: int, head_dim: int, group: int):
     return b if group == 1 else (b, slice(None), slice(g * head_dim, (g + 1) * head_dim))
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int):
+def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True):
     cos, sin = cos_ref[...], sin_ref[...]
     gq, gk = gq_ref[...], gk_ref[...]
     head_dim = k_ref.shape[-1]
     turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
+    normed = (lambda x, gain: _unit(x, eps)[0] * gain) if norm else (lambda x, gain: x)
 
     def board(b, carry):
         group = q_ref.shape[-1] // head_dim
         for g in range(group):
-            qb = turn(_unit(q_ref[_head(b, g, head_dim, group)], eps)[0] * gq).astype(jnp.bfloat16)
+            qb = turn(normed(q_ref[_head(b, g, head_dim, group)], gq)).astype(jnp.bfloat16)
             if g == 0:  # after the first query head's, as PR 32's one-head kernel had it
-                kb = turn(_unit(k_ref[b], eps)[0] * gk).astype(jnp.bfloat16)
+                kb = turn(normed(k_ref[b], gk)).astype(jnp.bfloat16)
             p = _softmax(_scores(kb, qb)).astype(jnp.bfloat16)
             mixed = jax.lax.dot_general(p, v_ref[b], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
             out_ref[_head(b, g, head_dim, group)] = mixed.astype(out_ref.dtype)
@@ -161,21 +162,26 @@ def _rounded(x: jax.Array) -> jax.Array:
 
 def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: jax.Array, cos: jax.Array, sin: jax.Array, rope: bool):
     """The cotangent of a normed and rotated ``[64, head_dim]`` back to
-    its raw input, and the summand of the gain's gradient."""
+    its raw input, and the summand of the gain's gradient (``r`` None:
+    there was no norm, and the gain, which was not read, has none)."""
     d_normed = d_rot * cos + _turned(d_rot * sin) if rope else d_rot
+    if r is None:
+        return d_normed, jnp.zeros_like(d_normed)
     d_unit = d_normed * gain
     d_x = r * (d_unit - unit * jnp.mean(d_unit * unit, axis=-1, keepdims=True))
     return d_x, d_normed * unit
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_ref,
-                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, rope: bool, unroll: int):
+                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True):
     cos, sin = cos_ref[...], sin_ref[...]
     gq, gk = gq_ref[...], gk_ref[...]
     bf16, f32 = jnp.bfloat16, jnp.float32
     head_dim = k_ref.shape[-1]
     scale = np.float32(1.0 / math.sqrt(head_dim))
     turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
+    unit = (lambda x: _unit(x, eps)) if norm else (lambda x: (x, None))
+    gained = (lambda u, gain: u * gain) if norm else (lambda u, gain: u)
 
     def board(b, carry):
         # In the order PR 32's one-head kernel emitted its operations (a group of one IS that kernel):
@@ -185,12 +191,12 @@ def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_r
         dgq, dgk = carry
         group = q_ref.shape[-1] // head_dim
         for g in range(group):
-            uq, rq = _unit(q_ref[_head(b, g, head_dim, group)], eps)
+            uq, rq = unit(q_ref[_head(b, g, head_dim, group)])
             if g == 0:
-                uk, rk = _unit(k_ref[b], eps)
-            qb = turn(uq * gq).astype(bf16)
+                uk, rk = unit(k_ref[b])
+            qb = turn(gained(uq, gq)).astype(bf16)
             if g == 0:
-                kb = turn(uk * gk).astype(bf16)
+                kb = turn(gained(uk, gk)).astype(bf16)
                 vb = v_ref[b]
             do = do_ref[_head(b, g, head_dim, group)]
             p = _softmax(_scores(kb, qb))
@@ -250,9 +256,10 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
 
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
                     theta: Optional[float], eps: float, interpret: bool = False,
-                    q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None) -> jax.Array:
+                    q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None, head_dim: Optional[int] = None) -> jax.Array:
     """The attention core (module docstring). What it is told, and does
-    not guess: the norm (the gains ``[head_dim]``, or None for none), the
+    not guess: the norm (the gains ``[head_dim]``, or None for none, and
+    then ``head_dim`` itself unless the form is the latent one), the
     extent of RoPE (``theta`` None: none of the score width; no ``k_pe``:
     all of it; with ``q_pe`` and ``k_pe``: those trailing columns alone)
     and whether the rotated part of k is one a key-value head (it is part
@@ -268,19 +275,23 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
     if all(latent) and theta is not None:
         return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta, interpret)
-    if any(latent):
-        raise ValueError("board_attention computes qk-norm with RoPE over all or none of head_dim, or no norm with RoPE "
-                         "over trailing columns q_pe and one k_pe for all heads; not a mixture of the two")
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret)
+    if latent == (True, True, False, False) and head_dim is not None:  # the grouped form without its norm: the gains are not read
+        ones = jnp.ones((head_dim,), jnp.float32)
+        return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False)
+    if any(latent) or head_dim is not None:
+        raise ValueError("board_attention computes qk-norm (or, told head_dim in the gains' place, no norm) with RoPE over all or "
+                         "none of head_dim, or no norm with RoPE over trailing columns q_pe and one k_pe for all heads; not a "
+                         "mixture of these")
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool, norm: bool = True):
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL, group)),
+        functools.partial(_forward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL, group), norm=norm),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES)],
         out_specs=per_group,
@@ -291,11 +302,11 @@ def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, int
     )(q, k, v, *_operands(g_q, g_k, theta))
 
 
-def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret):
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret), (q, k, v, g_q, g_k)
+def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret, norm=True):
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, norm), (q, k, v, g_q, g_k)
 
 
-def _board_attention_bwd(theta, eps, interpret, residuals, d_mixed):
+def _board_attention_bwd(theta, eps, interpret, norm, residuals, d_mixed):
     q, k, v, g_q, g_k = residuals
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
@@ -303,7 +314,7 @@ def _board_attention_bwd(theta, eps, interpret, residuals, d_mixed):
     grid, group, per_head, per_group, whole, partial = _blocks(boards, inner // head_dim, kv_heads, head_dim)
     sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
     dq, dk, dv, dgq, dgk = pl.pallas_call(
-        functools.partial(_backward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL_GRAD, group)),
+        functools.partial(_backward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL_GRAD, group), norm=norm),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES), per_group],
         out_specs=[per_group, per_head, per_head, partial, partial],

@@ -11,7 +11,11 @@ the operand ``gu`` ``[slots, 2 * width]`` holds its gate in the first
                       and the up's cotangents side by side, as the
                       transposed grouped product reads them.
 
-Both work on whole rows in blocks, in float32, and round once to
+A second form is the ungated expert's, whose up product stands alone
+(``[slots, width]``): ``squared_relu`` is ``relu(u)^2`` and its gradient
+``2 relu(u) d_h``, the same two kernels' names, blocks and extent.
+
+All work on whole rows in blocks, in float32, and round once to
 bfloat16, as XLA's fusion of the same expression does. They are
 memory-bound passes, and a kernel only for what XLA cannot be told: the
 extent. With ``extent`` (``ops/row_move.py``, "The extent of a move": an
@@ -36,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fishnet_tpu.ops.row_move import rows_covered
 
-__all__ = ["gated_activation"]
+__all__ = ["gated_activation", "squared_relu"]
 
 #: Rows a grid step: the moves' row tile, so that its blocks are theirs.
 #: On a v5e 128, 256 and 512 read within 0.05 ms of each other under an
@@ -63,6 +67,15 @@ def _gate_grad_kernel(gu_ref, d_ref, out_ref):
     silu = gate * s
     out_ref[:, :width] = (d * up * (s + silu * (1.0 - s))).astype(out_ref.dtype)
     out_ref[:, width:] = (d * silu).astype(out_ref.dtype)
+
+
+def _relu2_kernel(u_ref, out_ref):
+    r = jnp.maximum(u_ref[...].astype(jnp.float32), 0.0)
+    out_ref[...] = (r * r).astype(out_ref.dtype)
+
+
+def _relu2_grad_kernel(u_ref, d_ref, out_ref):
+    out_ref[...] = (2.0 * jnp.maximum(u_ref[...].astype(jnp.float32), 0.0) * d_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 def _call(kernel, name: str, out_width: int, extent: Optional[jax.Array], interpret: bool, *operands: jax.Array,
@@ -108,3 +121,23 @@ def _gated_bwd(interpret, res, d_h):
 
 
 gated_activation.defvjp(_gated_fwd, _gated_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def squared_relu(u: jax.Array, extent: Optional[jax.Array] = None, interpret: bool = False) -> jax.Array:
+    """``relu(u)^2`` for ``u`` ``[slots, width]``, the ungated expert's
+    activation: ``u``'s shape and dtype, float32 arithmetic, and
+    ``gated_activation``'s contract under ``extent``."""
+    return _call(_relu2_kernel, "expert_gate", u.shape[1], extent, interpret, u)
+
+
+def _relu2_fwd(u, extent, interpret):
+    return squared_relu(u, extent, interpret), (u, extent)
+
+
+def _relu2_bwd(interpret, res, d_h):
+    u, extent = res
+    return _call(_relu2_grad_kernel, "expert_gate_grad", u.shape[1], extent, interpret, u, d_h.astype(u.dtype), in_place=True), None
+
+
+squared_relu.defvjp(_relu2_fwd, _relu2_bwd)

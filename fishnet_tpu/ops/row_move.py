@@ -5,8 +5,14 @@ and the combine of the sparse-expert trunk (``models/trunk.py``).
 A row of ``hidden`` bfloat16 in a ``[rows, hidden]`` array is not
 contiguous on the TPU: sixteen rows share each ``(16, 128)`` tile, so
 XLA's row gather moves ``hidden / 128`` pieces of 256 bytes a row. Seen
-as ``[rows, hidden // 128, 128]`` one row is whole tiles (one 4 KiB tile
-at hidden 2048), and a DMA moves it in one piece. The kernels address
+as ``[rows, hidden // 128, 128]`` one row is whole tiles where ``hidden
+// 128`` is a multiple of 8 (16 x 128 bfloat16 at hidden 2048: one 4 KiB
+tile; in float32 two), and a DMA moves it in one piece. A hidden whose
+128-lane pieces are not a multiple of 8 (2,688 = 21 x 128) is not whole
+tiles and Mosaic refuses to slice it a row at a time: the caller pads
+such rows to the next multiple before they get here (``models/trunk.py
+_whole_rows``: 24 x 128 = 3,072, 6 KiB in bfloat16), so every statement
+below about ``hidden`` is one about the padded row. The kernels address
 single rows only in that view, on the token side; the slot side, which
 the grouped products read, stays ``[slots, hidden]`` and is read or
 written in contiguous blocks of ``tm`` rows. The change of view happens
@@ -73,7 +79,8 @@ __all__ = ["row_view", "rows_out", "rows_out_dot", "rows_back", "rows_covered", 
 
 #: Rows a grid step, and DMA starts unrolled in one loop body: the fastest
 #: of 128-1024 rows and 1-64 starts on a v5e at 262,144 rows of 4 KiB
-#: (PERF.md section 5). The scaled move reads float32 rows (8 KiB) and
+#: (hidden 2048, bfloat16; PERF.md section 5). The scaled move reads
+#: float32 rows (8 KiB there, ``hidden // 128`` half-KiB pieces anywhere) and
 #: is fastest at 128.
 _TM = 512
 _TM_SCALED = 128
@@ -82,8 +89,9 @@ _UNROLL = 32
 
 def row_view(x: jax.Array) -> jax.Array:
     """``[rows, hidden]`` as ``[rows, hidden // 128, 128]``: every row
-    whole tiles. A hidden under 128 is one short row (the tests' tiny
-    nets under the interpreter; Mosaic would pad it)."""
+    whole tiles where ``hidden // 128`` is a multiple of 8 (the caller
+    sees to that: module docstring). A hidden under 128 is one short row
+    (the tests' tiny nets under the interpreter; Mosaic would pad it)."""
     rows, hidden = x.shape
     if hidden < 128:
         return x.reshape(rows, 1, hidden)

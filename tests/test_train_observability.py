@@ -36,13 +36,20 @@ MODEL_SCOPES = {
     # the sparse-expert trunk behind the same AzTrainer (models/trunk.py): one scope a part, the layer in its name
     "trunk": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
               "layer01.attention", "layer01.experts", "final_norm", "policy_head", "value_head"),
+    # its fourth block: one sublayer a layer, the scan's core beside the mixer's scope and never inside it
+    "pattern": ("embed", "layer00.mamba", "layer00.scan", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine",
+                "layer01.shared", "layer02.attention", "final_norm", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
 SHARE = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8,
                     dense_layers=1, dense_width=32, router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
 LATENT = TrunkConfig(**{**SHARE.__dict__, "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16})
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT}
+# the fourth block: a layer pattern, Mamba-2 mixers, ungated experts, a share held and balanced
+PATTERN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, qk_norm=False, pattern="ME*", experts=8, experts_per_token=2, expert_width=16,
+                      gated_ffn=False, shared_width=8, value_hidden=8, mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=8,
+                      router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN}
 
 
 def make(kind):
@@ -95,7 +102,7 @@ def step_text(kind):
         return trainer._step_jit.lower(state, batch).compile().as_text()
 
 
-@pytest.fixture(scope="module", params=["nnue", "az", "trunk"])
+@pytest.fixture(scope="module", params=["nnue", "az", "trunk", "pattern"])
 def scoped(request):
     return request.param, step_text(request.param)
 
@@ -280,6 +287,7 @@ STEP_KEYS = {
     "trunk": LOSSES["az"] | ROUTING,
     "share": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max"},
     "latent": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms"},
+    "pattern": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "ssm_dt_mean", "ssm_decay_min"},
 }
 
 
@@ -392,7 +400,7 @@ def test_a_collected_trainer_takes_its_ring(steps):
     assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-1"}
 
 
-@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent"])
+@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern"])
 def test_collector_serves_each_scalar_of_the_latest_step(kind, steps):
     """``fishnet_train_step{trainer,key}`` for exactly the keys the kind's
     step returns, and ``fishnet_train_steps_total{trainer}``: every counter

@@ -375,3 +375,73 @@ def test_the_whole_step_of_a_share_trunk_fits_one_chip(one_chip, compiled_for_tp
     assert len([line for line in text.splitlines() if "tpu_custom_call" in line and "board_attention" in line]) == 10  # five layers, forward and gradient
     slots = boards * trunk.SQUARES * trainer.cfg.experts_per_token
     assert not _xla_passes_over_slots(text, slots)
+
+
+# -- the fourth block (nemotron_h) at its published widths: hidden 2688 = 21 x 128, expert width 1856 = 14.5 x 128 ----------------
+
+SSM_BOARDS = 128  # ssm_trunk_train_b128
+
+
+def test_the_scan_kernel_pair_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``board_scan`` and ``board_scan_grad`` on a batch of the cell: 64
+    heads x 64 in 8 groups, a state of 128, the decay's float32 products
+    at ``highest``."""
+    from fishnet_tpu.ops.board_scan import board_scan
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((SSM_BOARDS, 64, 4096), jnp.bfloat16), sds((SSM_BOARDS, 64, 1024), jnp.bfloat16), sds((SSM_BOARDS, 64, 1024), jnp.bfloat16),
+            sds((SSM_BOARDS, 64, 64), jnp.float32), sds((64,), jnp.float32), sds((64,), jnp.float32))
+    loss = lambda *a: jnp.sum(jnp.square(board_scan(*a, 8, False).astype(jnp.float32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(*args).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_scan_grad" in line.split(" = ")[0] for line in kernels) == 1, [line.split(" = ")[0] for line in kernels]
+
+
+def test_the_ungated_experts_compile_at_widths_no_lane_tile_divides(one_chip, compiled_for_tpu):
+    """A share's ungated experts at hidden 2,688 and width 1,856, as
+    ``_routed`` hands them to Mosaic: rows of 3,072 (whole tiles of a
+    moved row), weights padded to them and to 1,920 lanes: two grouped
+    products and the squared ReLU forward, four products and its
+    gradient backward, under a traced extent; the padding is XLA's and
+    no pass of it is over ``[slots, .]``."""
+    slots, held, hidden, width = SSM_BOARDS * 64 * 6, 8, 2688, 1856
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((slots, trunk._whole_rows(hidden)), jnp.bfloat16), sds((held, hidden, width), jnp.float32), sds((held, width, hidden), jnp.float32),
+            sds((held,), jnp.int32), sds((), jnp.int32))
+
+    def loss(rows, up_w, down_w, group_sizes, extent):
+        with jax.named_scope("layer01.experts"):
+            out = trunk._expert_ffn(rows, None, up_w, down_w, group_sizes, extent)
+        return jnp.sum(jnp.where(jnp.arange(slots)[:, None] < extent, out.astype(jnp.float32), 0.0))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 8, len(kernels)  # forward 2 products + relu^2; backward 2 gmm + 2 tgmm + its gradient
+    assert [line for line in kernels if f"bf16[{slots},1920]" in line and f"bf16[{held},3072,1920]" in line]  # the padded up product
+    assert not _xla_passes_over_slots(text, slots)
+
+
+def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """The whole step of ``ssm_trunk_train_b128``: the scan pair a mixer,
+    the attention pair at 16 query heads a key-value head without its
+    norm, the moves at a row of 3,072, the products at 1,920 lanes."""
+    import optax
+
+    from fishnet_tpu.train.az_trainer import AzTrainer
+
+    cfg = trunk.TrunkConfig(hidden=2688, heads=32, kv_heads=2, head_dim=128, qk_norm=False, pattern="MEMEM*E", experts=128, experts_per_token=6,
+                            expert_width=1856, gated_ffn=False, shared_width=3712, rope_theta=1e4, rms_eps=1e-5, mamba_heads=64, mamba_head_dim=64,
+                            mamba_groups=8, state_size=128, router_score="sigmoid", route_norm=True, route_scale=2.5, held_experts=(0, 8),
+                            balance_rate=0.001, recompute_experts=True)
+    trainer = AzTrainer(cfg, optimizer=optax.adamw(optax.linear_schedule(0.0, 3e-4, 100_000), weight_decay=1e-4))
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == 440_339_214  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((SSM_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((SSM_BOARDS, 4672)), "value_target": jnp.zeros((SSM_BOARDS,))}
+    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("board_scan_grad" in n for n in names) == 3 and sum("board_scan" in n for n in names) == 6, names
+    assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.5  # 4.92 + 7.24 GiB when this was written
