@@ -161,6 +161,18 @@ heads of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
 view and no scores reach HBM, and the gradient recomputes the softmax
 from the same inputs.
 
+Mechanism, the feed-forward every token passes (the leading dense layer,
+the shared expert; ``_gated_ffn``): three XLA products round ``silu(gate)
+* up``. Where the gate's weight ``[hidden, width]`` is small XLA fuses
+the activation and its gradient into the products and is left to (the
+shared experts of the cells); past ``_FUSED_GATE_BYTES`` (the dense
+layer of width 6,144) gate and up are ONE product
+on the joined weights, the activation and its gradient are the experts'
+kernel pair below, made once as bfloat16 arrays, and every product's
+operand is a plain bfloat16 array (``_gated_products``, one gradient rule
+round the whole). One algorithm, its form taken from what the function
+sees in its input: never from the layer's kind or the family.
+
 Mechanism, experts: the (token, slot) pairs are sorted by expert
 (stable), each expert's rows form one group of a grouped matrix product (megablox
 ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
@@ -240,7 +252,7 @@ from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
 from fishnet_tpu.ops.board_scan import board_scan
-from fishnet_tpu.ops.expert_gate import gated_activation, squared_relu
+from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
@@ -447,10 +459,91 @@ def _row_major(x: jax.Array) -> jax.Array:
     return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
+#: Bytes of a gated feed-forward's gate (or up) weight in float32, ``[hidden, width]``: its gradient's size, up to which
+#: XLA's own fusion of ``silu x up`` and of its gradient into the products is kept. Read on a v5e inside the two share
+#: cells' steps, 16,384 tokens (PERF.md section 6, PR 42): at 8 and 12 MiB (the shared experts, width 1,024 and 1,536)
+#: XLA's form is 0.30 and 0.42 ms a layer faster than the kernel form, at 48 MiB (the dense layer, width 6,144) 15.9 and
+#: 10.1 ms slower; nothing between was read, and 16 MiB is the VMEM an XLA fusion gets there. By the weight and not by the
+#: tokens, so that a net takes one form at every batch: the benchmark's ``correct`` compares 64 boards at a time.
+_FUSED_GATE_BYTES = 16 << 20
+
+
 def _gated_ffn(n: jax.Array, p: Params, kind: str) -> jax.Array:
     """``(silu(n W_g) * (n W_u)) W_d`` on every token: the dense layer's
-    feed-forward (``kind`` "dense") and the shared expert ("shared")."""
-    return _matmul(jax.nn.silu(_matmul(n, p[f"{kind}_gate"])) * _matmul(n, p[f"{kind}_up"]), p[f"{kind}_down"])
+    feed-forward (``kind`` "dense") and the shared expert ("shared"). One
+    algorithm in two forms by the size of its weights: XLA's fusion of
+    the plain formula, or ``_gated_products``."""
+    gate_w, up_w, down_w = p[f"{kind}_gate"], p[f"{kind}_up"], p[f"{kind}_down"]
+    if gate_w.size * 4 > _FUSED_GATE_BYTES:
+        return _gated_products(n, gate_w, up_w, down_w)
+    return _matmul(jax.nn.silu(_matmul(n, gate_w)) * _matmul(n, up_w), down_w)
+
+
+def _contract(x: jax.Array, y: jax.Array, x_axis: int, y_axis: int) -> jax.Array:
+    """``_matmul`` over any one axis of each (a gradient's transposed products): bfloat16 operands as they are, float32 accumulation and result."""
+    return jax.lax.dot_general(x, y, (((x_axis,), (y_axis,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _joined(gate_w: jax.Array, up_w: jax.Array) -> jax.Array:
+    """``[W_g | W_u]`` in the bfloat16 cast the step makes anyway, as ``_expert_ffn`` joins the experts'."""
+    return jnp.concatenate([gate_w.astype(jnp.bfloat16), up_w.astype(jnp.bfloat16)], axis=1)
+
+
+@jax.custom_vjp
+def _gated_products(n: jax.Array, gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array) -> jax.Array:
+    """The gated feed-forward as three products forward and three
+    backward, every operand a bfloat16 ARRAY, the activation and its
+    gradient made between them by the experts' kernel pair
+    (``ops/expert_gate.py``)::
+
+        gu = n [W_g | W_u]   float32 [tokens, 2 x width], ONE product; kept for the gradient
+        h  = expert_gate(gu)           bfloat16, float32 arithmetic, rounded once
+        out = h W_d                    float32
+        d_h  = d_out W_d^T             bfloat16 (the cotangent of a bfloat16 operand)
+        d_gu, h = expert_gate_grad(gu, d_h)   bfloat16 [tokens, 2 x width], written once, and ``h`` again
+        d W_d = h^T d_out;  d [W_g | W_u] = n^T d_gu (its halves are the two gradients);  d_n = d_gu [W_g | W_u]^T
+
+    Left to autodiff XLA makes no array of the activation's gradient: it
+    fuses ``exp``, ``divide`` and eight multiplies over float32 ``gate``
+    and ``up`` as a producer into each gradient product's operand, two to
+    three times a step, with AdamW behind the same products, and at width
+    6,144 those products ran at 1.9x to 3.5x their time at the bfloat16
+    peak (PERF.md section 6, PR 42). One rule round the whole
+    feed-forward because a ``custom_vjp`` has to return a float32
+    cotangent for a float32 ``gu``, and ``d_gu`` is bfloat16: what each
+    consuming product rounds it to anyway. The parameters, the checkpoint
+    and the optimizer keep ``gate`` and ``up`` apart.
+
+    What is kept for the gradient is the bfloat16 tokens and joined
+    weights and ``gu``, not ``h``: the gradient kernel has ``h`` in
+    registers and writes it again. The three ``optimization_barrier``s
+    hold an array out of the products that read it: the tokens' cast
+    (with the norm's two multiplies) and the weights' join, the
+    cotangent's cast (with a post-norm's gradient behind it), and ``gu``
+    itself, which a step compiled against the chip's memory otherwise
+    remakes in the backward pass, 4.6 ms for its 805 MB, where remaking
+    three attention products frees as much for less."""
+    return _gated_products_fwd(n, gate_w, up_w, down_w)[0]
+
+
+def _gated_products_fwd(n, gate_w, up_w, down_w):
+    n, joined = jax.lax.optimization_barrier((n.astype(jnp.bfloat16), _joined(gate_w, up_w)))
+    gu = _matmul(n, joined)
+    h = expert_gate(gu, None, _interpret())
+    return _matmul(h, down_w), (n, gu, joined, down_w)
+
+
+def _gated_products_bwd(res, d_out):
+    n, gu, joined, down_w = res
+    d_out = jax.lax.optimization_barrier(d_out.astype(jnp.bfloat16))  # once, for its two products
+    d_h = _contract(d_out, down_w.astype(jnp.bfloat16), 1, 1).astype(jnp.bfloat16)
+    gu, d_h = jax.lax.optimization_barrier((gu, d_h))
+    d_gu, h = expert_gate_grad(gu, d_h, None, _interpret(), with_h=True)
+    d_gate_w, d_up_w = jnp.split(_contract(n, d_gu, 0, 0), 2, axis=1)
+    return _contract(d_gu, joined, 1, 1), d_gate_w, d_up_w, _contract(h, d_out, 0, 0)
+
+
+_gated_products.defvjp(_gated_products_fwd, _gated_products_bwd)
 
 
 def _ffn(n: jax.Array, p: Params, kind: str, gated: bool) -> jax.Array:
@@ -543,7 +636,7 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
 
 def _interpret() -> bool:
     """The trunk's Pallas kernels (the attention core, the grouped product,
-    the experts' gate pair, the two row moves and a share's sum over a token's slots) are one path everywhere: compiled by Mosaic on a TPU, run by
+    the gate pair, the two row moves and a share's sum over a token's slots) are one path everywhere: compiled by Mosaic on a TPU, run by
     the Pallas interpreter elsewhere (the CPU of the tests), never
     another path."""
     return jax.default_backend() != "tpu"

@@ -264,11 +264,16 @@ def test_a_shares_ungated_experts_against_a_loop_over_the_held_experts(hidden, w
 
 #: sha256 of ``jax.jit(AzTrainer(cfg)._step).lower(state, batch).as_text()`` (no debug locations) at this file's tiny sizes, read
 #: on PR 41's parent (792ac6d) with this jax: the three accepted blocks' step programs. A PR that means to change one of them
-#: reads its own parent the same way and says so; one that does not has changed a program it did not mean to.
+#: reads its own parent the same way and says so; one that does not has changed a program it did not mean to. PR 42 read
+#: all four on ITS parent (3c5f3ae): the three are what they were, and the fourth block's is pinned beside them. PR 42's
+#: kernel form of the gated feed-forward is taken by size (``trunk._FUSED_GATE_BYTES``), and every tiny net here is under
+#: the rule, so ``afmoe`` and ``mla`` hold too: the kernel form is held by ``test_moe_trunk.py`` on both sides of the rule
+#: and by ``test_trunk_tpu_compile.py`` at the dense layer's published size.
 PARENT_STEP_SHA256 = {
     "llada": "60f5865d293d8516a7b2474ae17766489aca839166a5b0790d0a185cee8b0c77",
     "afmoe": "3bb78678b125d3e25ffcd3ae70ee260376b77e41d93b3becf876fd6c40f16f35",
     "mla": "0fedb499d5d0ceb1b7924dbb2f29537fddb8a67cbebdbcd6d1a68efeda7719e2",
+    "hybrid": "b47f763534f7ae51ffedf16d7bdd2fa2051129eae5215351464c2dc0ceec763a",
 }
 
 
@@ -276,7 +281,7 @@ PARENT_STEP_SHA256 = {
 def test_an_accepted_blocks_lowered_step_is_the_parents_op_for_op(block):
     import hashlib
 
-    trainer = AzTrainer({"llada": TINY, "afmoe": AFMOE, "mla": MLA}[block])
+    trainer = AzTrainer({"llada": TINY, "afmoe": AFMOE, "mla": MLA, "hybrid": HYBRID}[block])
     state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
     text = jax.jit(trainer._step).lower(state, jax.eval_shape(lambda: batch_of(1))).as_text()
     assert "loc(" not in text

@@ -264,12 +264,14 @@ def test_grouped_matmul_at_joined_columns_the_largest_tile_does_not_divide():
     assert rel(got_dx, want_dx) < 6e-3 and rel(got_dw, want_dw) < 6e-3 and not np.any(np.asarray(got_dw)[1])
 
 
-def _one_bfloat16_apart(got, want) -> bool:
+def _one_bfloat16_apart(got, want, floor: float = 1e-30) -> bool:
     """Equal to the rounding: within one unit of the last of bfloat16's 8
     bits (the kernel and XLA may round a float32 that differs in ITS last
-    bit to neighbouring bfloat16 values)."""
+    bit to neighbouring bfloat16 values), or within ``floor`` (where the
+    value is a difference that cancels, the two formulas' last float32
+    bits are more than a bfloat16 unit of the result)."""
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return bool(np.all(np.abs(got - want) <= 2.0 ** -7 * np.abs(want) + 1e-30))
+    return bool(np.all(np.abs(got - want) <= 2.0 ** -7 * np.abs(want) + floor))
 
 
 #: slots, width, extent (None: no extent, the static grid). 1,536 slots are three blocks of the moves' 512; 192 is tiled by 64.
@@ -304,6 +306,123 @@ def test_the_gated_activation_kernel_and_its_gradient(slots, width, extent):
         g = np.asarray(g, np.float32)
         assert np.all(np.isfinite(g[:covered])) and np.all(np.isnan(g[covered:]))  # the rows it covers, and no other
         assert _one_bfloat16_apart(g[:covered], np.asarray(w.astype(jnp.bfloat16), np.float32)[:covered])
+
+
+#: slots, width of a float32 ``gu`` ``[slots, 2 x width]``, the rows a grid step of ``expert_gate`` takes: 512 (the moves'
+#: tile) while its block stays within 8 MiB, halved past it (a row of 2 x 2,560 float32 columns is 20 KiB: 256 rows), and a
+#: divisor of the slots.
+WIDE_GATE_CASES = [(256, 64, 256), (1536, 128, 512), (192, 32, 64), (512, 2560, 256)]
+
+
+@pytest.mark.parametrize("slots,width,tile", WIDE_GATE_CASES)
+def test_the_gate_kernels_take_a_float32_product_and_round_once_to_bfloat16(slots, width, tile):
+    """``expert_gate`` and ``expert_gate_grad`` as the dense layer calls
+    them: ``gu`` float32 as the product leaves it, ``h`` and ``d_gu``
+    bfloat16, against the float32 formula and ``jax.vjp`` of it rounded
+    once; the gradient kernel's second result is ``h`` itself; the row
+    tile follows the operands' row bytes."""
+    from fishnet_tpu.ops import expert_gate as gate
+
+    assert gate._row_tile(slots, 2 * width * 4) == tile
+    rng = np.random.default_rng(slots + width)
+    gu = jnp.asarray(3 * rng.standard_normal((slots, 2 * width)), jnp.float32)
+    d_h = jnp.asarray(rng.standard_normal((slots, width)), jnp.bfloat16)
+    got = gate.expert_gate(gu, None, True)
+    got_d, again = gate.expert_gate_grad(gu, d_h, None, True, with_h=True)
+    assert np.array_equal(np.asarray(again, np.float32), np.asarray(got, np.float32))  # ``h`` again from the gradient kernel, bit for bit
+    assert np.array_equal(np.asarray(gate.expert_gate_grad(gu, d_h, None, True), np.float32), np.asarray(got_d, np.float32))
+    want, pull = jax.vjp(lambda gu: jax.nn.silu(gu[:, :width]) * gu[:, width:], gu)
+    (want_d,) = pull(d_h.astype(jnp.float32))
+    assert got.shape == (slots, width) and got_d.shape == gu.shape and got.dtype == got_d.dtype == jnp.bfloat16
+    # silu's derivative crosses zero near gate = -1.28: of 2.6 M elements a few land within 1e-6 of it, where the kernel's
+    # ``s + silu (1 - s)`` and autodiff's form differ in the float32 bits that are left (readings of 1e-7 to 8e-6 apart by 4-7%)
+    assert _one_bfloat16_apart(got, want.astype(jnp.bfloat16)) and _one_bfloat16_apart(got_d, want_d.astype(jnp.bfloat16), floor=1e-6)
+
+
+def test_the_gate_kernels_row_tile_at_the_cells_shapes():
+    """The experts' blocks are what they were (``_TM`` rows of bfloat16);
+    the dense layer's float32 ``[16384, 2 x 6144]`` takes 128 rows, the
+    shared experts' ``[16384, 2 x 1024]`` and ``[.., 2 x 1536]`` 512."""
+    from fishnet_tpu.ops.expert_gate import _row_tile
+
+    assert [_row_tile(slots, row) for slots, row in ((262_144, 2048 * 2), (131_072, 2048 * 2), (98_304, 1536 * 2), (49_152, 1920 * 2))] == [512] * 4
+    assert [_row_tile(16_384, 2 * width * 4) for width in (1024, 1536, 6144)] == [512, 512, 128]
+    assert _row_tile(16_384, 2 * 6144 * 4 + 6144 * 2) == 128  # the gradient's: ``gu`` and the bfloat16 cotangent
+
+
+def _plain_gated_ffn(n, gate_w, up_w, down_w):
+    """What ``_gated_ffn`` was until PR 42: three ``_matmul``s round ``silu x up``, the gradient autodiff's."""
+    return trunk._matmul(jax.nn.silu(trunk._matmul(n, gate_w)) * trunk._matmul(n, up_w), down_w)
+
+
+def _gated_ffn_case(tokens, hidden, width):
+    rng = np.random.default_rng(tokens + width)
+    n = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    weights = [jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[0]), jnp.float32) for shape in ((hidden, width), (hidden, width), (width, hidden))]
+    return n, weights, jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+
+
+#: tokens, hidden, width: the tiny nets' dense layer and shared expert, and a width whose ``gu`` takes more than one grid step.
+GATED_FFN_CASES = [(512, 64, 96), (512, 64, 32), (1024, 128, 384)]
+
+
+@pytest.mark.parametrize("tokens,hidden,width", GATED_FFN_CASES)
+@pytest.mark.parametrize("form", ["kernels", "fused"])
+def test_the_gated_feed_forward_and_its_four_gradients_match_the_plain_formula(monkeypatch, form, tokens, hidden, width):
+    """``_gated_ffn`` on either side of its size rule (the gate's float32
+    weight ``[hidden, width]`` against ``_FUSED_GATE_BYTES``: over it
+    one joined product, the kernel pair and one gradient rule round the
+    whole; up to it XLA's fusion of the plain formula) against the
+    three plain products and autodiff, to the tolerance the experts'
+    joined product is held to: the result, the gradient to the normed
+    tokens and to each of the three weights."""
+    n, (gate_w, up_w, down_w), cot = _gated_ffn_case(tokens, hidden, width)
+    weight = hidden * width * 4
+    monkeypatch.setattr(trunk, "_FUSED_GATE_BYTES", weight - 1 if form == "kernels" else weight)
+    calls = []
+    monkeypatch.setattr(trunk, "expert_gate", lambda *a, kernel=trunk.expert_gate: calls.append(a[0].shape) or kernel(*a))
+    params = {"dense_gate": gate_w, "dense_up": up_w, "dense_down": down_w}
+    got, pull = jax.vjp(lambda n, p: trunk._gated_ffn(n, p, "dense"), n, params)
+    got_dn, got_dp = pull(cot)
+    assert calls == ([(tokens, 2 * width)] if form == "kernels" else [])
+    want, plain_pull = jax.vjp(_plain_gated_ffn, n, gate_w, up_w, down_w)
+    want_dn, *want_dw = plain_pull(cot)
+    assert got.shape == want.shape and got.dtype == got_dn.dtype == jnp.float32
+    assert rel(got, want) < 6e-3 and rel(got_dn, want_dn) < 1e-2, (rel(got, want), rel(got_dn, want_dn))
+    for name, w in zip(("dense_gate", "dense_up", "dense_down"), want_dw):
+        assert got_dp[name].shape == w.shape and got_dp[name].dtype == jnp.float32 and rel(got_dp[name], w) < 1e-2, (name, rel(got_dp[name], w))
+
+
+def test_the_size_rule_leaves_the_shared_experts_fused_and_takes_the_dense_layer():
+    """At the two share cells' widths, hidden 2,048: the shared experts'
+    gate weights are 8 and 12 MiB of float32 (XLA's fusion read 0.30 and
+    0.42 ms a layer faster there on the chip), the dense layer's 48 MiB
+    (the kernel form 10-16 ms faster); every tiny net of the tests is
+    under the rule, and no count of tokens moves a net across it."""
+    weight = lambda hidden, width: hidden * width * 4
+    assert weight(2048, 1024) <= trunk._FUSED_GATE_BYTES and weight(2048, 1536) <= trunk._FUSED_GATE_BYTES < weight(2048, 6144)
+    assert max(weight(cfg.hidden, max(cfg.dense_width, cfg.shared_width)) for cfg in (AFMOE, MLA)) <= trunk._FUSED_GATE_BYTES
+
+
+@pytest.mark.parametrize("swapped", [False, True], ids=["as_joined", "halves_swapped"])
+def test_the_gate_and_up_gradients_are_the_two_halves_of_the_joined_products(monkeypatch, swapped):
+    """``d [W_g | W_u] = n^T d_gu`` is one product; ``dense_gate`` gets its
+    first ``width`` columns and ``dense_up`` the rest. With the weights
+    joined the other way round (``[W_u | W_g]``: the kernels then gate
+    with the up product) the forward result and both gradients are another
+    function's, and the comparison that holds the program shows it."""
+    n, (gate_w, up_w, down_w), cot = _gated_ffn_case(512, 64, 96)
+    if swapped:
+        monkeypatch.setattr(trunk, "_joined", lambda gate, up, joined=trunk._joined: joined(up, gate))
+    _, pull = jax.vjp(trunk._gated_products, n, gate_w, up_w, down_w)
+    _, got_gate, got_up, _ = pull(cot)
+    _, plain_pull = jax.vjp(_plain_gated_ffn, n, gate_w, up_w, down_w)
+    _, want_gate, want_up, _ = plain_pull(cot)
+    assert rel(want_up, want_gate) > 0.5  # the two are different tensors: a swap cannot hide
+    if swapped:
+        assert rel(got_gate, want_gate) > 0.5 and rel(got_up, want_up) > 0.5
+    else:
+        assert rel(got_gate, want_gate) < 1e-2 and rel(got_up, want_up) < 1e-2 and rel(got_up, want_gate) > 0.5
 
 
 #: the held experts' sizes (3 of them; the slots past their sum are other experts'), 1,024 slots in two tiles of 512:

@@ -97,6 +97,63 @@ def test_the_joined_product_and_the_gate_kernels_compile_at_published_widths(one
     assert not _xla_passes_over_slots(text, slots)
 
 
+def _opcodes_fused_with_a_product(text: str):
+    """For each fusion of a compiled module's entry computation that holds a ``convolution`` (its own or a nested fusion's,
+    which is how the TPU compiler puts a producer into a product's operand): its name and every opcode it holds."""
+    import re
+
+    computations, current = {}, None
+    for line in text.splitlines():
+        header = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line) if not line.startswith(" ") else None
+        if header:
+            current = computations.setdefault(header.group(1), [])
+        elif current is not None and " = " in line:
+            current.append(line)
+
+    def held(computation):
+        opcodes = set()
+        for line in computations.get(computation, ()):
+            opcode = re.search(r" = .+? ([a-z][\w\-]*)\(", line)
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            opcodes |= held(called.group(1)) if opcode and opcode.group(1) == "fusion" and called else {opcode.group(1)} if opcode else set()
+        return opcodes
+
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    fusions = {re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line).group(1): held(re.search(r"calls=%?([\w.\-]+)", line).group(1))
+               for line in computations[entry] if re.search(r" fusion\(.*calls=", line)}
+    return {name: opcodes for name, opcodes in fusions.items() if "convolution" in opcodes}
+
+
+DENSE_WIDTH = 6144  # the leading dense layer of afmoe_trunk_train_b256 and mla_trunk_train_b256, on 256 x 64 tokens
+
+
+def test_the_dense_layers_products_hold_no_activation_gradient(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of the dense layer as a step runs it (norm,
+    ``_gated_ffn``, residual) at ``[16384, 2048] x 6144``: the kernel pair
+    fits VMEM at the row tile its float32 rows take, the six products
+    (gate and up joined forward, in the weights' gradient and in the
+    input's) are all there are, and none holds an ``exponential`` or a
+    ``divide``: until PR 42 XLA made no array of the activation's
+    gradient and fused its whole chain into each gradient product's
+    operand (nine products, five of them behind an ``exp`` and a
+    ``divide`` an operand element)."""
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    layer = {"moe_norm": sds((HIDDEN,)), "dense_gate": sds((HIDDEN, DENSE_WIDTH)), "dense_up": sds((HIDDEN, DENSE_WIDTH)),
+             "dense_down": sds((DENSE_WIDTH, HIDDEN))}
+
+    def loss(x, p):
+        with jax.named_scope("layer00.dense"):
+            return jnp.sum(jnp.square(x + trunk._gated_ffn(trunk._rms_norm(x, p["moe_norm"], 1e-5), p, "dense")))  # a cotangent that waits for the result
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN)), layer).compile().as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("expert_gate_grad" in name for name in kernels) == 1 and all("expert_gate" in name for name in kernels), kernels
+    assert f"f32[16384,{2 * DENSE_WIDTH}]" in text and f"bf16[16384,{2 * DENSE_WIDTH}]" in text  # ``gu`` float32 from product to kernel, ``d_gu`` bfloat16
+    products = _opcodes_fused_with_a_product(text)
+    assert len(products) == 6, sorted(products)
+    assert not {name: sorted(opcodes & {"exponential", "divide"}) for name, opcodes in products.items() if opcodes & {"exponential", "divide"}}
+
+
 TOKENS, TOP_K = 32_768, 8  # SLOTS = TOKENS * TOP_K
 
 
