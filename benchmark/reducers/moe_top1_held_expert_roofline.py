@@ -1,0 +1,28 @@
+"""The held routed experts' share of their roofline at ONE expert a
+token: the least time the chip could take for the three grouped
+products' forward and both gradients on the rows the held experts
+receive under even routing (roofline/moe_top1_held_experts.py, from
+shapes alone: the expectation of the program's ``held_slots`` counter, 8
+of 16 experts held, every layer routed; no padding rows, no absent
+experts' rows, nothing made again) over ``moe_experts_ms``, the device
+time of everything under the ``layerNN.experts`` scopes, the backward
+pass's remade forward products included. A sibling of
+``moe_held_expert_roofline``, which answers for the second trunk's
+family alone. None where the program has no such scope or the
+configuration is not of the block with the mix and the top-1 router."""
+
+
+def reduce(ctx):
+    config = ctx["config"]
+    if config["family"] != "cca_trunk":
+        return None
+    experts_ms = ctx["registry"].module("reducers", "moe_experts_ms").reduce(ctx)
+    if not experts_ms:
+        return None
+    roofline = ctx["registry"].module("roofline", "moe_top1_held_experts")
+    least = roofline.least_seconds(config["model"], ctx["batch"], ctx["registry"].peaks(ctx["device_kind"]))
+    print(f"moe_top1_held_expert_roofline: {least['bound']}-bound, least {1e3 * least['least_s']:.3f} ms "
+          f"(compute {1e3 * least['compute_s']:.3f}, memory {1e3 * least['memory_s']:.3f}) for "
+          f"{roofline.held_slots(config['model'], ctx['batch']):.0f} expected held slots a layer "
+          f"over {experts_ms:.3f} ms under the experts scopes a step")
+    return 100.0 * 1e3 * least["least_s"] / experts_ms

@@ -150,9 +150,10 @@ def az_config_from_params(params: Params) -> NetConfig:
     checkpoints need no architecture metadata; loading a net trained with
     a non-default config (--az-net-file) reconstructs the right config
     instead of crashing shape-mismatched inside the jitted forward. A
-    trunk checkpoint is told from a tower's by its router.
+    trunk checkpoint is told from a tower's by its router (one product,
+    or the fifth block's MLP).
     """
-    if "router_w" in params:
+    if "router_w" in params or "router_down" in params:
         return trunk_config_from_params(params)
     required = ("stem_b", "policy_b", "value_fc1_b")
     missing = [k for k in required if k not in params]

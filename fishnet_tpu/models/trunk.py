@@ -4,7 +4,7 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes four
+tower's own policy and value heads. ``TrunkConfig`` describes five
 published blocks as one code path at different values; no attention has
 a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
@@ -142,6 +142,62 @@ weights' side; a moved row of 2,688 = 21 x 128 is not whole (8, 128)
 tiles, so the tokens go to dispatch as rows of 3,072 and the combine's
 sum is cut back. ``_tile`` hands Mosaic no tile that is not whole lanes.
 
+The fifth block is ZAYA1-8B's (Zyphra, config.json, ``model_type`` zaya:
+hidden 2048, 40 layers all ``hybrid``, 8 query heads over 2 key-value
+heads of 128, ``cca_time0`` 2 and ``cca_time1`` 2, ``partial_rotary_factor``
+0.5 at theta 5e6, 16 experts of width 2048, ONE a token, behind a router
+that is an MLP of ``router_hidden_size`` 256; RMSNorm eps 1e-5, no
+biases in attention). Compressed convolutional attention: queries live
+in ``heads x head_dim`` = 1024 columns (hidden / 2), keys and values in
+``kv_heads x head_dim`` = 256 (hidden / 8), and ALL of attention runs
+there. What its config.json does not say is the family's two papers'
+(arXiv:2510.04476, arXiv:2511.17127), listed under ``assumed`` in
+``benchmark/configs/zaya1-trunk-train.json`` each with its basis (the
+router state's mixing across layers, learned residual scales and a
+sibling's mixture-of-depths are named there as LEFT OUT: no key sizes
+them). ``TrunkConfig.cca`` is the two kernel sizes; ``t - 1`` is the
+previous square of the same board, zero before square 0::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer     a = x + Attn(N_a(x));   y = a + MoE(N_m(a))               (two norms a layer; no dense layer, no shared expert)
+    Attn      q~ = n W_q [heads x head_dim];  k~ = n W_k [kv_heads x head_dim]
+              v  = [ n_t W_v1 | n_{t-1} W_v2 ]                          a key-value head's first half from the token, its second half from
+                                                                        the previous square (the value shift; ``wv1``, ``wv2`` [hidden, kv_heads x head_dim / 2])
+              c  = conv1( conv0( [q~ | k~] ) )                          over the heads + kv_heads groups of head_dim columns side by side:
+                   conv0 depthwise, ``cca[0]`` taps along the squares, causal, a bias (``conv0_w`` [columns, taps]: the last tap the token's own)
+                   conv1 a head at a time, ``cca[1]`` taps, each a [head_dim, head_dim] matrix of its head, causal, a bias (``conv1_w``
+                   [heads + kv_heads, taps, head_dim in, head_dim out])
+              m_q[h] = (q~[h] + k~[h // group]) / 2;   m_k[g] = (mean over the group's heads of q~ + k~[g]) / 2       (the q-k mean)
+              q = c_q + m_q;   k = c_k + m_k
+              q[h] <- q[h] / rms(q[h]);   k[g] <- temp[g] * k[g] / rms(k[g])     a norm WITHOUT a gain over head_dim, float32, and the key's
+                                                                        learned temperature a key-value head (``temp`` [kv_heads], 1 at first)
+              RoPE (theta, rotate-half) on the FIRST ``rotary_dim`` columns of every head, the rest pass
+              head h attends key-value head h // group within a board, no mask, scores / sqrt(head_dim), softmax float32
+              out = concat_h(P v) W_o                                   ``wo`` [heads x head_dim, hidden]: narrower in than out
+    MoE       r = n W_rd + b_rd [router_hidden];  h = gelu(r W_r1 + b_r1);  h = gelu(h W_r2 + b_r2)     (gelu by erf; float32 at ``highest``)
+              s = softmax(h W_r3) over all the experts;   e = argmax(s + b), b = expert_bias: no gradient through b or the choice
+              out = s[e] * E_e(n) if e is HELD HERE, else 0             the chosen expert's probability is the combine weight (a renormalised
+                                                                        single weight would be 1 and leave the router no gradient); E_e SiLU-gated
+    balance   the second block's rule;   out   N_final(y) -> the heads
+
+Mechanism, the fifth block: the projections are two joined products,
+``[W_q | W_k]`` and ``[W_v1 | W_v2]`` (one read of the normed stream
+each). The value shift is a move of the second product's ROWS, not of
+its input: ``n_{t-1} W`` is row ``t - 1`` of ``n W``, so nothing shifted
+is ever multiplied (``_shifted_values``; the benchmark's reference
+shifts the input, so the two do not share a derivation). Everything
+between ``[q~ | k~]`` and the core is one Pallas kernel pair
+(``ops/cca_mix.py``: ``cca_mix``, ``cca_mix_grad``) under ``layerNN.cca``
+beside ``layerNN.attention``: a few boards' ``[64, columns]`` a grid
+step, a shift along the squares a rotation of sublanes and a select, so
+what conv0 and conv1 see before a board's first square is zero and never
+the board before; ``[q~ | k~]`` is read once and q and k written once.
+The norm, the temperature and RoPE stay inside ``board_attention``,
+which is told ``g_q`` None beside a gain a key-value head and
+``rotary_dim``. At one expert a token a "sum over a token's slots" is a
+select on the slot's mask: ``_held`` makes no lists of places and
+``_held_slots_sum`` calls no ``rows_sum`` kernel.
+
 ``held_experts = (first, count)`` tells the expert layer which experts
 it holds, as one chip of an expert-parallel deployment does: it routes
 over all ``experts``, computes the part of the result that its own give
@@ -252,6 +308,7 @@ from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
 from fishnet_tpu.ops.board_scan import board_scan
+from fishnet_tpu.ops.cca_mix import cca_mix
 from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
@@ -309,11 +366,19 @@ class TrunkConfig:
     mamba_groups: int = 0  # B and C are one a group of mamba_heads // mamba_groups heads
     state_size: int = 0
     conv_kernel: int = 4
+    # What the fifth block adds (module docstring). ``cca``: the kernel sizes of the two convolutions over queries and keys, and with
+    # them the whole compressed form (the value shift, the q-k mean, ``qk_norm`` as the norm without a gain under a key temperature);
+    # None: the four blocks above. ``rotary_dim``: RoPE on the first columns of a head (None: all of it). ``router_hidden``: the
+    # width of the router's MLP (0: the one product ``n W_r``).
+    cca: Optional[Tuple[int, int]] = None
+    rotary_dim: Optional[int] = None
+    router_hidden: int = 0
 
     def __post_init__(self) -> None:
         first, count = self.held
         latent = self.kv_lora_rank is not None
         pattern = self.pattern or ""
+        cca = self.cca is not None
         if pattern and self.layers in (1, len(pattern)):
             object.__setattr__(self, "layers", len(pattern))
         wrong = {
@@ -342,6 +407,16 @@ class TrunkConfig:
             f"{self.dense_layers} dense layers of {self.layers} leave no routed layer, or have no width":
                 not 0 <= self.dense_layers < self.layers or (self.dense_layers > 0) != (self.dense_width > 0),
             f"nope_layers {self.nope_layers} are not layers": any(not 0 <= i < self.layers for i in self.nope_layers),
+            f"cca {self.cca} is not two kernel sizes of 1 to {SQUARES}": cca and not (len(self.cca) == 2 and all(1 <= t <= SQUARES for t in self.cca)),
+            "compressed convolutional attention wants kv_heads (its keys and values live in kv_heads x head_dim columns) and an even "
+            "head_dim (half of a value head is the previous square's)": cca and (self.kv_heads is None or self.head_dim % 2 != 0),
+            "compressed convolutional attention norms queries and keys without a gain under a key temperature (qk_norm), and has no "
+            "latent, no pattern, no output gate, no post-norms and RoPE on every layer (no nope_layers)":
+                cca and (not self.qk_norm or latent or bool(pattern) or self.gated_attention or self.post_norms or bool(self.nope_layers)),
+            f"rotary_dim {self.rotary_dim} is not an even part of a head of {self.head_dim}, or stands beside a latent (whose RoPE "
+            "columns are qk_rope_head_dim)": self.rotary_dim is not None and (latent or self.rotary_dim % 2 != 0
+                                                                                  or not 0 < self.rotary_dim <= self.head_dim),
+            f"router_hidden {self.router_hidden} is under 0": self.router_hidden < 0,
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -364,7 +439,13 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     """Every trained tensor of a trunk checkpoint by name."""
     n, r, h, w = cfg.attention_layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
     inner, kv_inner, held = cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim, cfg.held[1]
-    if cfg.kv_lora_rank is None:
+    if cfg.cca is not None:  # queries in ``inner`` columns, keys and values in ``kv_inner``; a value head's halves from two projections
+        mixed, groups = inner + kv_inner, cfg.heads + cfg.kv_heads
+        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv1": (n, h, kv_inner // 2), "wv2": (n, h, kv_inner // 2),
+                     "conv0_w": (n, mixed, cfg.cca[0]), "conv0_b": (n, mixed),
+                     "conv1_w": (n, groups, cfg.cca[1], cfg.head_dim, cfg.head_dim), "conv1_b": (n, mixed),
+                     "temp": (n, cfg.kv_heads), "wo": (n, inner, h)}
+    elif cfg.kv_lora_rank is None:
         norms = {"q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim)} if cfg.qk_norm else {}
         attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner), **norms, "wo": (n, inner, h)}
     else:  # columns in the order ``_attention`` reads them
@@ -379,9 +460,13 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
         layers = {"layer_norm": (cfg.layers, h), **(mamba if m else {}), **(attention if n else {})}
     else:
         layers = {"attn_norm": (n, h), **attention, "moe_norm": (n, h)}
+    rh = cfg.router_hidden
+    router = {"router_w": (r, h, cfg.experts)} if not rh else {
+        "router_down": (r, h, rh), "router_down_b": (r, rh), "router_w1": (r, rh, rh), "router_w1_b": (r, rh),
+        "router_w2": (r, rh, rh), "router_w2_b": (r, rh), "router_w3": (r, rh, cfg.experts)}
     shapes = {
         "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
-        **layers, "router_w": (r, h, cfg.experts),
+        **layers, **router,
         "experts_gate": (r, held, h, w), "experts_up": (r, held, h, w), "experts_down": (r, held, w, h),
         "final_norm": (h,),
         "policy_w": (1, 1, h, cfg.policy_planes), "policy_b": (cfg.policy_planes,),
@@ -401,6 +486,12 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     return shapes
 
 
+#: The router MLP's matrices of fan-in ``router_hidden``: initialised by it, not at ``_INIT_STD``.
+_ROUTER_HIDDEN = ("router_w1", "router_w2", "router_w3")
+#: The fifth block's biases: a vector a layer, stacked (``init_trunk_params`` knows a bias by being a vector, and these are not).
+_STACKED_BIASES = ("conv0_b", "conv1_b", "router_down_b", "router_w1_b", "router_w2_b")
+
+
 def trunk_buffer_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     """What a training state holds beside the trained tensors, outside
     the optimizer: ``expert_bias`` where the routed layers balance."""
@@ -414,14 +505,32 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     uniform in [1, 16], the steps ``softplus(dt_bias)`` log-uniform in
     [0.001, 0.1] and at least 0.0001 (``dt_bias`` their inverse
     softplus), the direct term ``D_skip`` 1, the convolution uniform
-    within 1 / sqrt(its taps) under a zero bias."""
+    within 1 / sqrt(its taps) under a zero bias. The fifth block's mix
+    starts as a pass: ``conv0_w`` 1 at the token's own tap and 0 at the
+    earlier ones, ``conv1_w`` the identity at the token's own tap, both
+    biases and the router MLP's zero, the key temperature ``temp`` 1. The
+    router MLP's matrices behind its down-projection (``router_w1``,
+    ``router_w2``, ``router_w3``) are normal(0, 1 / fan-in): at 0.02 each
+    of the three 256-wide layers shrinks what it is given to a sixth, a
+    fresh router's logits have a spread of 0.011 where a one-product
+    router's at 0.02 over 2048 columns have 0.9, every token's scores lie
+    within 0.0002 of each other, and the balance rule's 0.001 a step then
+    moves ALL of a layer's tokens from expert to expert every step
+    (PERF.md section 6, PR 43: a whole layer's tokens on one expert, the
+    held rows of a step anywhere between an eighth and seven eighths)."""
     shapes = trunk_param_shapes(cfg)
     keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
     uniform = lambda name, low, high: jax.random.uniform(keys[name], shapes[name], jnp.float32, low, high)
     params: Params = {}
     for name, shape in shapes.items():
-        if name.endswith("_norm") or name == "D_skip":
+        if name.endswith("_norm") or name in ("D_skip", "temp"):
             params[name] = jnp.ones(shape, jnp.float32)
+        elif name == "conv0_w":  # [layers, columns, taps]: the last tap is the token's own
+            params[name] = jnp.zeros(shape, jnp.float32).at[..., -1].set(1.0)
+        elif name == "conv1_w":  # [layers, heads, taps, in, out]
+            params[name] = jnp.zeros(shape, jnp.float32).at[:, :, -1].set(jnp.eye(shape[-1], dtype=jnp.float32))
+        elif name in _ROUTER_HIDDEN:  # [layers, router_hidden, .]: by their fan-in (the docstring above says why)
+            params[name] = jax.random.normal(keys[name], shape, jnp.float32) / math.sqrt(shape[-2])
         elif name == "A_log":
             params[name] = jnp.log(uniform(name, 1.0, 16.0))
         elif name == "dt_bias":
@@ -430,7 +539,7 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
         elif name == "conv_w":
             params[name] = uniform(name, -1.0, 1.0) / math.sqrt(shape[-1])
         # a bias is a vector (``wkv_b`` is a matrix), or the convolution's, a vector a mixer
-        elif (name.endswith("_b") and len(shape) == 1) or name in ("value_fc2_w", "conv_b"):
+        elif (name.endswith("_b") and len(shape) == 1) or name in ("value_fc2_w", "conv_b", *_STACKED_BIASES):
             params[name] = jnp.zeros(shape, jnp.float32)
         else:
             params[name] = jax.random.normal(keys[name], shape, jnp.float32) * _INIT_STD
@@ -595,17 +704,68 @@ def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.A
         return _matmul(y.reshape(-1, inner), p["mamba_out"]), counters
 
 
-def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, layer: str = "layer00") -> Tuple[jax.Array, Optional[jax.Array]]:
+def _cca_mix(x: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The fifth block's mix of queries and keys, between their
+    projections and the core, one Pallas kernel pair
+    (``ops/cca_mix.py``: ``cca_mix``, ``cca_mix_grad``): ``x`` [boards,
+    64, (heads + kv_heads) x head_dim] float32, ``[q~ | k~]`` as the
+    joined product writes them, -> q [boards, 64, heads x head_dim], k
+    [boards, 64, kv_heads x head_dim], float32, and the mean square of
+    what the convolutions changed, ``c - x``, over that of ``x`` (no
+    gradient)::
+
+        a = conv0(x)     depthwise along the squares (the fourth block's ``_board_conv``, here inside the kernel), float32
+        c = conv1(a)     a head at a time (heads + kv_heads groups of head_dim columns): c[t, g] = b1[g] + sum_k a[t - (taps - 1) + k, g] W1[g, k],
+                         each tap a [head_dim, head_dim] matrix, bfloat16 operands, float32 accumulation; nothing before square 0
+        q[h] = c_q[h] + (x_q[h] + x_k[h // group]) / 2;   k[g] = c_k[g] + (mean over the group's heads of x_q + x_k[g]) / 2
+    """
+    q, k, sums = cca_mix(x, p["conv0_w"], p["conv0_b"], p["conv1_w"], p["conv1_b"], cfg.heads, cfg.kv_heads, _interpret())
+    return q, k, sums[0] / sums[1]
+
+
+def _shifted_values(v12: jax.Array, kv_heads: int) -> jax.Array:
+    """``[v1 | v2]`` [boards, 64, kv_heads x head_dim] float32 as the
+    joined value product writes it (``wv1``'s columns, then ``wv2``'s,
+    each key-value head's half of a head in turn) -> the values the core
+    reads, bfloat16: a head's first half its own ``v1``, its second half
+    the PREVIOUS square's ``v2``, zero at square 0. The shift is a move
+    of the product's rows, not of its input: ``n_{t-1} W`` is row ``t -
+    1`` of ``n W`` (a per-token projection commutes with it), so the
+    normed stream is read once and never copied shifted."""
+    boards, _, width = v12.shape
+    half = width // (2 * kv_heads)
+    v1, v2 = v12[..., :width // 2], jnp.pad(v12[:, :-1, width // 2:], ((0, 0), (1, 0), (0, 0)))
+    halves = [y.reshape(boards, SQUARES, kv_heads, half) for y in (v1, v2)]
+    return jnp.concatenate(halves, axis=-1).reshape(boards, SQUARES, width).astype(jnp.bfloat16)
+
+
+def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, layer: str = "layer00"):
     """[tokens, hidden] float32, 64 tokens a board -> the attention
     branch's output, same shape, and the latent's root mean square (None
-    without a latent). The projections are XLA's; everything between
+    without a latent; with the fifth block's mix its two counters by
+    name). The projections are XLA's; everything between
     them is ``board_attention``. Enters its own scopes (call it under
     none of a layer's): ``<layer>.attention``, and for the way from the
     normed stream through the latent to the keys and values
-    ``<layer>.latent`` beside it, so that the two add up to the branch."""
+    ``<layer>.latent`` beside it, so that the two add up to the branch;
+    likewise ``<layer>.cca`` for the fifth block's mix of queries and
+    keys and the move of its values."""
     by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
     with jax.named_scope(f"{layer}.attention"):
         n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    if cfg.cca is not None:
+        # Two joined products, each one read of the normed stream: [W_q | W_k], whose result the mix takes as it is, and [W_v1 | W_v2].
+        with jax.named_scope(f"{layer}.attention"):
+            qk = by_board(_matmul(n1, jnp.concatenate([p["wq"], p["wk"]], axis=1)))
+            v12 = by_board(_matmul(n1, jnp.concatenate([p["wv1"], p["wv2"]], axis=1)))
+        with jax.named_scope(f"{layer}.cca"):
+            q, k, changed = _cca_mix(qk, p, cfg)
+            v = _shifted_values(v12, cfg.kv_heads)
+        with jax.named_scope(f"{layer}.attention"):
+            temp = jnp.broadcast_to(p["temp"][:, None], (cfg.kv_heads, cfg.head_dim))  # a gain a key-value head, the same on its columns
+            mixed = board_attention(q, k, v, None, temp, cfg.rope_theta, cfg.rms_eps, _interpret(), rotary_dim=cfg.rotary_dim)
+            counters = {"cca_conv_share": jnp.sqrt(changed), "cca_temp_max": jnp.max(jax.lax.stop_gradient(p["temp"]))}
+            return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), counters
     if cfg.kv_lora_rank is not None:
         # Columns (module docstring): wq every head's NoPE part, then every head's RoPE part; wkv_a the latent, then the
         # RoPE key; wkv_b every head's key, then every head's value. Split on the weights' side, where a slice costs
@@ -626,7 +786,7 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
         q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
         gains = dict(g_q=p["q_norm"], g_k=p["k_norm"]) if cfg.qk_norm else dict(g_q=None, g_k=None, head_dim=cfg.head_dim)
         mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if rope else None, eps=cfg.rms_eps, interpret=_interpret(),
-                                **gains)
+                                rotary_dim=cfg.rotary_dim, **gains)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
             mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
@@ -675,7 +835,8 @@ class Held(NamedTuple):
 
 
 def _held(extent: jax.Array, mask: jax.Array, scale: jax.Array) -> Held:
-    return Held(extent, mask, scale, *held_places(mask))
+    """At one slot a token there is no sum over a token's slots, and no lists of their places are made (``_held_slots_sum``)."""
+    return Held(extent, mask, scale, *(held_places(mask) if mask.shape[1] > 1 else (None, None)))
 
 
 def _extent(held: Optional[Held]) -> Optional[jax.Array]:
@@ -687,8 +848,16 @@ def _held_slots_sum(rows: jax.Array, order: jax.Array, held: Held, weight: Optio
     (under ``weight`` [N, k]), [N, hidden] of ``dtype``: the held rows go
     back to their places in the token-order view (``rows_back`` to its
     extent) and ``rows_sum`` fetches those places alone, one DMA a row;
-    nothing passes over the view."""
+    nothing passes over the view. At ONE slot a token (top-1) the view
+    is the result where the token's slot is held and nothing where it is
+    not: a select on the slot's mask (what the view holds at an absent
+    slot's place is uninitialised and is selected away, never
+    multiplied), which XLA fuses into whatever reads the sum; no kernel
+    is called to make a copy, and ``_held`` makes no lists."""
     view = rows_back(rows, order, extent=held.extent, interpret=_interpret())
+    if held.mask.shape[1] == 1:
+        own = view.astype(jnp.float32) if weight is None else view.astype(jnp.float32) * weight[:, :, None]
+        return jnp.where(held.mask[:, :, None], own, 0.0).astype(dtype).reshape(view.shape[0], -1)
     return rows_sum(view, held.places, held.counts, weight, k=held.mask.shape[1], dtype=dtype, interpret=_interpret())
 
 
@@ -849,7 +1018,10 @@ def _gmm(rows: jax.Array, weights: jax.Array, group_sizes: jax.Array) -> jax.Arr
 
 
 def _tiling(rows: int, contraction: int, columns: int) -> Tuple[int, int, int]:
-    return math.gcd(rows, _TILE[0]), _tile(contraction, _TILE[1]), _tile(columns, _TILE[2])
+    tiles = math.gcd(rows, _TILE[0]), _tile(contraction, _TILE[1]), _tile(columns, _TILE[2])
+    # 4,096 joined columns of a 2048-wide expert take four tiles of 1,024, 1,536 two of 768: never a tile that is computed half empty
+    assert contraction % tiles[1] == 0 and columns % tiles[2] == 0, (contraction, columns, tiles)
+    return tiles
 
 
 def _gmm_fwd(rows, weights, group_sizes):
@@ -890,7 +1062,14 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
     """The router in float32: each token's ``experts_per_token`` experts
     [N, k], their combine weights [N, k], and the scores as a
     distribution over the experts [N, experts] (for the entropy)."""
-    logits = jnp.dot(n2, p["router_w"], precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_hidden:  # the fifth block's: a down-projection, two GELU layers (erf), then the experts' logits, biases but on the last
+        highest = jax.lax.Precision.HIGHEST
+        r = jnp.dot(n2, p["router_down"], precision=highest) + p["router_down_b"]
+        for name in ("router_w1", "router_w2"):
+            r = jax.nn.gelu(jnp.dot(r, p[name], precision=highest) + p[f"{name}_b"], approximate=False)
+        logits = jnp.dot(r, p["router_w3"], precision=highest)
+    else:
+        logits = jnp.dot(n2, p["router_w"], precision=jax.lax.Precision.HIGHEST)
     if cfg.router_score == "softmax":
         score = probs = jax.nn.softmax(logits, axis=-1)
     else:
@@ -898,8 +1077,8 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
         probs = score / jnp.sum(score, axis=-1, keepdims=True)
     if "expert_bias" in p:  # the choice is on score + bias, the weights on the score; neither has a gradient through the bias
         _, expert = jax.lax.top_k(score + jax.lax.stop_gradient(p["expert_bias"]), cfg.experts_per_token)
-        # take_along_axis(score, expert) as a select: a token's experts are distinct, so each sum is one score and zeros, and so is its
-        # gradient's. XLA's gather of tokens x k scalars and the scatter that is its gradient cost 2.48 ms a layer at 131,072 slots, the
+        # take_along_axis(score, expert) as a select: a token's experts are distinct (at top-1 there is one), so each of its k sums
+        # is one score and zeros, and so is its gradient's. XLA's gather of tokens x k scalars and the scatter that is its gradient cost 2.48 ms a layer at 131,072 slots, the
         # select and the two sums 0.52 (PERF.md section 6, PR 38).
         chosen = expert[:, :, None] == jnp.arange(score.shape[-1], dtype=expert.dtype)
         weight = jnp.sum(jnp.where(chosen, score[:, None, :], 0.0), axis=-1)
@@ -1028,8 +1207,9 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
     mixed = routed(n2, weight, order, group_sizes[:count], held, p.get("experts_gate"), p["experts_up"], p["experts_down"], layer)
     load = (jnp.roll(group_sizes, first) if cfg.held_experts else group_sizes).astype(jnp.float32)
     entropy = -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1))
+    top1 = {"route_top1_weight": jnp.mean(jax.lax.stop_gradient(weight))} if k == 1 else {}  # the chosen expert's score: the router's only gradient
     return mixed, {"expert_load_max": jnp.max(load), "expert_load_min": jnp.min(load), "router_entropy": entropy,
-                   "expert_slots": load, "moved_rows": jnp.asarray(rows_covered(n * k, _extent(held)), jnp.float32)}
+                   "expert_slots": load, "moved_rows": jnp.asarray(rows_covered(n * k, _extent(held)), jnp.float32), **top1}
 
 
 def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
@@ -1038,8 +1218,9 @@ def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkCon
 
 
 _EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wkv_a", "kv_norm", "wkv_b", "wo", "moe_norm", "wgate", "post_attn_norm",
-                "post_mlp_norm")
-_ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias")
+                "post_mlp_norm", "wv1", "wv2", "conv0_w", "conv0_b", "conv1_w", "conv1_b", "temp")
+_ROUTER_MLP = ("router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3")
+_ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias", *_ROUTER_MLP)
 _DENSE = ("dense_gate", "dense_up", "dense_down")
 #: A pattern's tensors by the kind of layer that owns them (``layer_norm`` is every layer's).
 _BY_KIND = {"M": ("mamba_in", "conv_w", "conv_b", "dt_bias", "A_log", "D_skip", "mamba_norm", "mamba_out"),
@@ -1063,7 +1244,8 @@ def _routed_layer(x: jax.Array, norm: jax.Array, layer: Params, cfg: TrunkConfig
 def _block_layers(params: Params, x: jax.Array, cfg: TrunkConfig):
     """The first three blocks' layers: attention, then a dense or a
     routed feed-forward. Returns the stream, the routed layers' counters
-    and each layer's latent's root mean square (None without a latent)."""
+    and each layer's latent's root mean square (None without a latent;
+    the fifth block's mix's counters in its place)."""
     counters, latent = [], []
     for i in range(cfg.layers):
         # Layers of a kind are stacked: a routed layer's tensors are indexed from the first routed layer.
@@ -1132,7 +1314,16 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     and mixers (``ssm_dt_mean``), and the smallest decay across a board
     ``exp(c_63 - c_0)`` of any head of any mixer, mean over the boards
     (``ssm_decay_min``: a head that forgets a board within it shows here
-    before the loss does)."""
+    before the loss does); with the fifth block's mix, the root mean
+    square of what the two convolutions changed, ``c - [q~ | k~]``, over
+    that of ``[q~ | k~]``, mean over the layers (``cca_conv_share``: 0.0017,
+    the rounding of conv1's bfloat16 operand, while the convolutions still
+    pass their input, so a mixing path that is dead or has taken over
+    shows before the loss does) and the largest
+    key temperature of any head of any layer (``cca_temp_max``); at one
+    expert a token, the mean combine weight, the chosen expert's score,
+    over tokens and layers (``route_top1_weight``: at 1.0 the router has
+    no gradient left, at 1 / experts it has not chosen)."""
     b = planes.shape[0]
     # Scope names are a contract (doc/observability.md "Training and compilation").
     with jax.named_scope("embed"):
@@ -1157,6 +1348,9 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
         **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
         **({"latent_rms": jnp.mean(jnp.stack(latent))} if cfg.kv_lora_rank is not None else {}),
+        **({"cca_conv_share": jnp.mean(jnp.stack([c["cca_conv_share"] for c in latent])),
+            "cca_temp_max": jnp.max(jnp.stack([c["cca_temp_max"] for c in latent]))} if cfg.cca is not None else {}),
+        **({"route_top1_weight": jnp.mean(jnp.stack([c["route_top1_weight"] for c in counters]))} if cfg.experts_per_token == 1 else {}),
         **({"ssm_dt_mean": jnp.mean(jnp.stack([c["ssm_dt_mean"] for c in mixers])),
             "ssm_decay_min": jnp.min(jnp.stack([c["ssm_decay_min"] for c in mixers]))} if mixers else {}),
     })
@@ -1177,7 +1371,7 @@ def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
 #: first block alone has the first three; the rest default to it.
 HPARAMS = "trunk_hparams"
 _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_scale", "balance_rate", "sliding_window",
-            "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups")
+            "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim")
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
 
@@ -1189,7 +1383,9 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     sliding_window (0: none), sigmoid scores (0 or 1), route_norm, the
     first held expert (-1: all are held), the layers without RoPE as a
     bit mask, and what no shape of the fourth block gives: head_dim
-    (there are no qk-norm gains to read it from) and mamba_groups. A
+    (there are no qk-norm gains to read it from) and mamba_groups, and
+    of the fifth block's rotary_dim (0: RoPE on all of a head; its kernel
+    sizes, head width and the router MLP's width are shapes). A
     pattern's file carries the pattern too (``trunk_pattern``, its
     characters as bytes). ``recompute_experts`` is the trainer's and in
     no file."""
@@ -1198,7 +1394,7 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
         cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
         cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
-        cfg.head_dim, cfg.mamba_groups], np.float64)
+        cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0], np.float64)
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
     return arrays
@@ -1207,7 +1403,8 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
 def trunk_config_from_params(params: Params) -> TrunkConfig:
     """The ``TrunkConfig`` of a checkpoint, from its shapes and its
     ``trunk_hparams``; a ValueError names what does not fit."""
-    required = (("router_w", "experts_up", "layer_norm") if PATTERN in params else ("router_w", "experts_gate", "wq", "wo", "attn_norm"))
+    router = "router_w3" if "router_down" in params else "router_w"  # the MLP router's last matrix: its columns are the experts
+    required = ((router, "experts_up", "layer_norm") if PATTERN in params else (router, "experts_gate", "wq", "wo", "attn_norm"))
     missing = [k for k in (*required, "value_fc1_b", "policy_b", HPARAMS) if k not in params]
     if missing:
         raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
@@ -1220,8 +1417,15 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     width_of = lambda name: shape(name)[2] if name in params else 0
     if PATTERN in params:
         return _checked(params, len(given), lambda: _pattern_config(params, hp, shape, width_of))
-    (routed, hidden, experts), layers = shape("router_w"), shape("attn_norm")[0]
-    if "kv_norm" in params:  # the latent form: its four widths and the head count from five shapes
+    (routed, _, experts), (layers, hidden) = shape(router), shape("attn_norm")
+    if "conv0_w" in params:  # the fifth block's form: the head width from conv1's taps, the kernel sizes from both
+        missing = [k for k in ("conv1_w", "wk", "wv1", "wv2", "temp") if k not in params]
+        if missing:
+            raise ValueError(f"trunk checkpoint: compressed convolutional attention (conv0_w) without {missing}")
+        head_dim = shape("conv1_w")[-1]
+        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, kv_heads=shape("wk")[2] // head_dim,
+                         cca=(shape("conv0_w")[2], shape("conv1_w")[2]))
+    elif "kv_norm" in params:  # the latent form: its four widths and the head count from five shapes
         missing = [k for k in ("wkv_a", "wkv_b") if k not in params]
         if missing:
             raise ValueError(f"trunk checkpoint: a latent (kv_norm) without {missing}")
@@ -1251,7 +1455,8 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
             dense_layers=layers - routed, dense_width=width_of("dense_gate"), shared_width=width_of("shared_gate"),
             router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
             held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_gate")[1]),
-            balance_rate=hp["balance_rate"],
+            balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
+            router_hidden=shape("router_down")[2] if "router_down" in params else 0,
         ))
 
 
@@ -1272,9 +1477,10 @@ def _pattern_config(params: Params, hp: Dict[str, float], shape, width_of) -> Tr
         heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
         mamba = dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
                      state_size=(channels - inner) // (2 * groups) if groups else 0)
-    _, hidden, experts = shape("router_w")
+    hidden, experts = shape("layer_norm")[1], shape("router_w3" if "router_down" in params else "router_w")[2]
     return TrunkConfig(
-        hidden=hidden, pattern=pattern, **attention, **mamba,
+        hidden=hidden, pattern=pattern, **attention, **mamba, rotary_dim=int(hp["rotary_dim"]) or None,
+        router_hidden=shape("router_down")[2] if "router_down" in params else 0,
         experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_up")[3],
         rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"], value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
         sliding_window=int(hp["sliding_window"]) or None, embed_scale=hp["embed_scale"],

@@ -38,6 +38,21 @@ bfloat16 values are bfloat16, the four products take bfloat16 operands
 and accumulate in float32. The gains' gradients leave it as one partial
 sum a grid step, summed outside over ``[steps, heads, head_dim]``.
 
+The normed form is told three things more, each None or absent for the
+blocks that do not have it (their programs are then what they were):
+``g_k`` as ``[kv_heads, head_dim]`` is a gain a KEY-VALUE HEAD (a grid
+step reads its own head's ``head_dim`` lanes of the gains laid side by
+side, and its gradient comes back in that shape): the fifth block's key
+temperature, the same number on a head's columns; ``g_q`` None beside a
+``g_k`` norms both and gives the query no gain (an RMSNorm without one);
+``rotary_dim`` rotates the FIRST ``rotary_dim`` columns of every head,
+rotate-half inside them, and passes the rest: the tables are then
+``head_dim`` wide, cos 1 and sin 0 on the passing lanes, and the sine is
+split by which of two lane rotations brings a column's partner
+(``part_rope_tables``), since a rotation of a whole 128-lane head by half
+the ROTATED width wraps. A group of 4 query heads a key-value head takes
+4 boards a grid step (16 board-head pairs, as every group does).
+
 The same pair has a second, latent form (the end of this file): no norm,
 RoPE on a trailing part of the score width, one rotated key for all
 heads. ``board_attention`` is told which, and does not guess.
@@ -57,7 +72,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SQUARES", "board_attention", "latent_column_order", "rope_tables"]
+__all__ = ["SQUARES", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables"]
 
 SQUARES = 64
 
@@ -102,6 +117,36 @@ def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return x * cos + _turned(x) * sin
 
 
+def part_rope_tables(theta: float, head_dim: int, rotary_dim: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The tables of rotate-half RoPE on the FIRST ``rotary_dim`` columns
+    of a head (the rest pass): cos ``[64, head_dim]`` (1 on the passing
+    columns) and the signed sine ``[2 * 64, head_dim]`` split by which
+    lane rotation brings a column's partner (``_part_rope``): rows ``[0,
+    64)`` for the partner at ``lane - rotary_dim // 2`` (the second half
+    of the rotated columns), rows ``[64, 128)`` for the partner at ``lane
+    + rotary_dim // 2`` (their first half), zero elsewhere."""
+    if rotary_dim % 2 or not 0 < rotary_dim <= head_dim:
+        raise ValueError(f"rotary_dim {rotary_dim} is not an even part of a head of {head_dim}: RoPE turns pairs")
+    cos, sin = rope_tables(theta, rotary_dim)
+    passing = np.zeros((SQUARES, head_dim - rotary_dim), np.float32)
+    first = np.arange(rotary_dim) < rotary_dim // 2
+    whole = lambda part: np.concatenate([part, passing], axis=-1)
+    return np.concatenate([cos, passing + 1.0], axis=-1), np.concatenate(
+        [whole(np.where(first, 0.0, sin)), whole(np.where(first, sin, 0.0))]).astype(np.float32)
+
+
+def _part_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, half: int) -> jax.Array:
+    """Rotate-half RoPE inside the first ``2 * half`` lanes (``part_rope_tables``)."""
+    lanes = x.shape[-1]
+    return x * cos + pltpu.roll(x, half, axis=x.ndim - 1) * sin[:SQUARES] + pltpu.roll(x, lanes - half, axis=x.ndim - 1) * sin[SQUARES:]
+
+
+def _part_unrope(d: jax.Array, cos: jax.Array, sin: jax.Array, half: int) -> jax.Array:
+    """The transpose of ``_part_rope``."""
+    lanes = d.shape[-1]
+    return d * cos + pltpu.roll(d * sin[:SQUARES], lanes - half, axis=d.ndim - 1) + pltpu.roll(d * sin[SQUARES:], half, axis=d.ndim - 1)
+
+
 def _scores(kb: jax.Array, qb: jax.Array) -> jax.Array:
     """``[key, query]``: every sum of the softmax and of its gradient
     then runs down the sublanes, and one product in each kernel pays for
@@ -134,12 +179,13 @@ def _head(b, g: int, head_dim: int, group: int):
     return b if group == 1 else (b, slice(None), slice(g * head_dim, (g + 1) * head_dim))
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True):
+def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True,
+                    half: Optional[int] = None, q_gain: bool = True):
     cos, sin = cos_ref[...], sin_ref[...]
-    gq, gk = gq_ref[...], gk_ref[...]
+    gq, gk = gq_ref[...] if q_gain else None, gk_ref[...]
     head_dim = k_ref.shape[-1]
-    turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
-    normed = (lambda x, gain: _unit(x, eps)[0] * gain) if norm else (lambda x, gain: x)
+    turn = (lambda x: _rope(x, cos, sin) if half is None else _part_rope(x, cos, sin, half)) if rope else (lambda x: x)
+    normed = (lambda x, gain: _unit(x, eps)[0] if gain is None else _unit(x, eps)[0] * gain) if norm else (lambda x, gain: x)
 
     def board(b, carry):
         group = q_ref.shape[-1] // head_dim
@@ -160,28 +206,35 @@ def _rounded(x: jax.Array) -> jax.Array:
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
-def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: jax.Array, cos: jax.Array, sin: jax.Array, rope: bool):
+def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: Optional[jax.Array], cos: jax.Array, sin: jax.Array, rope: bool,
+                   half: Optional[int] = None):
     """The cotangent of a normed and rotated ``[64, head_dim]`` back to
     its raw input, and the summand of the gain's gradient (``r`` None:
-    there was no norm, and the gain, which was not read, has none)."""
-    d_normed = d_rot * cos + _turned(d_rot * sin) if rope else d_rot
+    there was no norm, and the gain, which was not read, has none;
+    ``gain`` None: a norm without a gain). ``half``: RoPE on the first
+    ``2 * half`` columns alone."""
+    if half is not None and rope:
+        d_normed = _part_unrope(d_rot, cos, sin, half)
+    else:
+        d_normed = d_rot * cos + _turned(d_rot * sin) if rope else d_rot
     if r is None:
         return d_normed, jnp.zeros_like(d_normed)
-    d_unit = d_normed * gain
+    d_unit = d_normed if gain is None else d_normed * gain
     d_x = r * (d_unit - unit * jnp.mean(d_unit * unit, axis=-1, keepdims=True))
-    return d_x, d_normed * unit
+    return d_x, jnp.zeros_like(d_normed) if gain is None else d_normed * unit
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_ref,
-                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True):
+                     dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True,
+                     half: Optional[int] = None, q_gain: bool = True):
     cos, sin = cos_ref[...], sin_ref[...]
-    gq, gk = gq_ref[...], gk_ref[...]
+    gq, gk = gq_ref[...] if q_gain else None, gk_ref[...]
     bf16, f32 = jnp.bfloat16, jnp.float32
     head_dim = k_ref.shape[-1]
     scale = np.float32(1.0 / math.sqrt(head_dim))
-    turn = (lambda x: _rope(x, cos, sin)) if rope else (lambda x: x)
+    turn = (lambda x: _rope(x, cos, sin) if half is None else _part_rope(x, cos, sin, half)) if rope else (lambda x: x)
     unit = (lambda x: _unit(x, eps)) if norm else (lambda x: (x, None))
-    gained = (lambda u, gain: u * gain) if norm else (lambda u, gain: u)
+    gained = (lambda u, gain: u if gain is None else u * gain) if norm else (lambda u, gain: u)
 
     def board(b, carry):
         # In the order PR 32's one-head kernel emitted its operations (a group of one IS that kernel):
@@ -209,16 +262,16 @@ def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_r
             dq_rot = _rounded(jax.lax.dot_general(ds, kb, (((0,), (0,)), ((), ())), preferred_element_type=f32))
             dk_g = jnp.dot(ds, qb, preferred_element_type=f32)
             dk_rot = dk_g if g == 0 else dk_rot + dk_g
-            dq, dgq_g = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope)
+            dq, dgq_g = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope, half)
             dgq = dgq + dgq_g
             if g == group - 1:
-                dk, dgk_b = _unrope_unnorm(_rounded(dk_rot), uk, rk, gk, cos, sin, rope)
+                dk, dgk_b = _unrope_unnorm(_rounded(dk_rot), uk, rk, gk, cos, sin, rope, half)
                 dq_ref[_head(b, g, head_dim, group)], dk_ref[b] = dq, dk
             else:
                 dq_ref[_head(b, g, head_dim, group)] = dq
         return dgq, dgk + dgk_b
 
-    zero = jnp.zeros(cos.shape, f32)
+    zero = jnp.zeros((SQUARES, head_dim), f32)
     dgq, dgk = _each_board(q_ref.shape[0], board, (zero, zero), unroll)
     dgq_ref[0] = jnp.sum(dgq, axis=0, keepdims=True)
     dgk_ref[0] = jnp.sum(dgk, axis=0, keepdims=True)
@@ -241,10 +294,19 @@ def _blocks(boards: int, heads: int, kv_heads: int, head_dim: int):
     return (boards // tb, kv_heads), group, per_head, per_group, whole, partial
 
 
-def _operands(g_q, g_k, theta: Optional[float]):
+def _gain_spec(g_k: jax.Array, whole):
+    """A gain's block: the one ``[1, head_dim]`` vector, or a key-value
+    head's own ``head_dim`` lanes of ``[1, kv_heads * head_dim]``."""
+    return whole(1) if g_k.ndim == 1 else pl.BlockSpec((1, g_k.shape[-1]), lambda i, h: (0, h))
+
+
+def _operands(g_q, g_k, theta: Optional[float], rotary_dim: Optional[int] = None):
     head_dim = g_q.shape[-1]
-    cos, sin = rope_tables(theta or 1.0, head_dim)  # not read without RoPE
-    gain = lambda g: g.astype(jnp.float32).reshape(1, head_dim)
+    if rotary_dim is None:
+        cos, sin = rope_tables(theta or 1.0, head_dim)  # not read without RoPE
+    else:
+        cos, sin = part_rope_tables(theta or 1.0, head_dim, rotary_dim)
+    gain = lambda g: g.astype(jnp.float32).reshape(1, -1)  # a gain a key-value head: its heads side by side along the lanes
     return gain(g_q), gain(g_k), jnp.asarray(cos), jnp.asarray(sin)
 
 
@@ -256,12 +318,18 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
 
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
                     theta: Optional[float], eps: float, interpret: bool = False,
-                    q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None, head_dim: Optional[int] = None) -> jax.Array:
+                    q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None, head_dim: Optional[int] = None,
+                    rotary_dim: Optional[int] = None) -> jax.Array:
     """The attention core (module docstring). What it is told, and does
     not guess: the norm (the gains ``[head_dim]``, or None for none, and
-    then ``head_dim`` itself unless the form is the latent one), the
+    then ``head_dim`` itself unless the form is the latent one; ``g_q``
+    None beside a ``g_k``: both are normed and the query takes no gain;
+    ``g_k`` ``[kv_heads, head_dim]``: a gain a key-value head, whose
+    gradient comes back in that shape), the
     extent of RoPE (``theta`` None: none of the score width; no ``k_pe``:
-    all of it; with ``q_pe`` and ``k_pe``: those trailing columns alone)
+    all of it, or with ``rotary_dim`` the first ``rotary_dim`` columns of
+    every head, rotate-half inside them; with ``q_pe`` and ``k_pe``: those
+    trailing columns alone)
     and whether the rotated part of k is one a key-value head (it is part
     of ``k``) or one for all heads (``k_pe`` ``[boards, 64, rope]``).
 
@@ -273,58 +341,71 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     value]`` -> bfloat16 of v's shape. The combinations the kernels do
     not compute are refused."""
     latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
-    if all(latent) and theta is not None:
+    if all(latent) and theta is not None and rotary_dim is None:
         return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta, interpret)
     if latent == (True, True, False, False) and head_dim is not None:  # the grouped form without its norm: the gains are not read
         ones = jnp.ones((head_dim,), jnp.float32)
-        return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False)
+        return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False, rotary_dim)
+    if latent == (True, False, False, False) and head_dim is None:  # both normed, the query without a gain (which is not read)
+        return _normed_attention(q, k, v, jnp.ones((g_k.shape[-1],), jnp.float32), g_k, theta, eps, interpret, True, rotary_dim, False)
     if any(latent) or head_dim is not None:
-        raise ValueError("board_attention computes qk-norm (or, told head_dim in the gains' place, no norm) with RoPE over all or "
-                         "none of head_dim, or no norm with RoPE over trailing columns q_pe and one k_pe for all heads; not a "
-                         "mixture of these")
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, True)
+        raise ValueError("board_attention computes qk-norm (or, told head_dim in the gains' place, no norm; or the query's norm without a "
+                         "gain) with RoPE over all, none or the first rotary_dim columns of head_dim, or no norm with RoPE over trailing "
+                         "columns q_pe and one k_pe for all heads; not a mixture of these")
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, True, rotary_dim)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool, norm: bool = True):
+def _form(theta: Optional[float], rotary_dim: Optional[int], q_gain: bool) -> dict:
+    """What the kernels are told beside the norm: nothing for the forms
+    they had before a head could be rotated in part or a query normed
+    without a gain (their programs are what they were)."""
+    part = {} if rotary_dim is None else {"half": rotary_dim // 2}
+    return {"rope": theta is not None, **part, **({} if q_gain else {"q_gain": False})}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool, norm: bool = True,
+                      rotary_dim: Optional[int] = None, q_gain: bool = True):
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
+    tables = SQUARES if rotary_dim is None else 2 * SQUARES
     return pl.pallas_call(
-        functools.partial(_forward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL, group), norm=norm),
+        functools.partial(_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
-        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES)],
+        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables)],
         out_specs=per_group,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.bfloat16),
         compiler_params=_PARAMS,
         name="board_attention",
         interpret=interpret,
-    )(q, k, v, *_operands(g_q, g_k, theta))
+    )(q, k, v, *_operands(g_q, g_k, theta, rotary_dim))
 
 
-def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret, norm=True):
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, norm), (q, k, v, g_q, g_k)
+def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret, norm=True, rotary_dim=None, q_gain=True):
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, norm, rotary_dim, q_gain), (q, k, v, g_q, g_k)
 
 
-def _board_attention_bwd(theta, eps, interpret, norm, residuals, d_mixed):
+def _board_attention_bwd(theta, eps, interpret, norm, rotary_dim, q_gain, residuals, d_mixed):
     q, k, v, g_q, g_k = residuals
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     kv_heads = k.shape[-1] // head_dim
     grid, group, per_head, per_group, whole, partial = _blocks(boards, inner // head_dim, kv_heads, head_dim)
     sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
+    tables = SQUARES if rotary_dim is None else 2 * SQUARES
     dq, dk, dv, dgq, dgk = pl.pallas_call(
-        functools.partial(_backward_kernel, eps=eps, rope=theta is not None, unroll=_unroll(interpret, _UNROLL_GRAD, group), norm=norm),
+        functools.partial(_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
-        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(SQUARES), whole(SQUARES), per_group],
+        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables), per_group],
         out_specs=[per_group, per_head, per_head, partial, partial],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype), sums, sums],
         compiler_params=_PARAMS,
         name="board_attention_grad",
         interpret=interpret,
-    )(q, k, v, *_operands(g_q, g_k, theta), d_mixed)
-    total = lambda s, g: s.reshape(-1, kv_heads, head_dim).sum(axis=(0, 1)).astype(g.dtype)
+    )(q, k, v, *_operands(g_q, g_k, theta, rotary_dim), d_mixed)
+    total = lambda s, g: s.reshape(-1, kv_heads, head_dim).sum(axis=(0, 1) if g.ndim == 1 else 0).astype(g.dtype)
     return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
 
 

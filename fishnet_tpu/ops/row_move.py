@@ -398,7 +398,11 @@ def rows_sum(src: jax.Array, places: jax.Array, counts: jax.Array, weight: Optio
     ``[N, sub * lanes]`` of ``dtype``, float32 weights, products and sum,
     a token's slots in ascending order, rounded once. One DMA a held row
     and nothing else is read of ``src``: a token with no held slot is
-    zero. Every other place of ``src`` may hold anything, NaN too. A
+    zero. Every other place of ``src`` may hold anything, NaN too. With
+    8 or 6 slots a token this is a sum of up to that many rows; at ONE
+    slot a token it would be a copy of the row or a zero, and
+    ``trunk._held_slots_sum`` does not call it then (a select on the
+    slot's mask, fused into its reader; no lists of places are made). A
     ``jit`` of its own: the layers of one shape share one trace and one
     lowering of the kernel (a start-up pays for each in Python)."""
     slots, sub, lanes = src.shape

@@ -302,3 +302,80 @@ def test_a_mixture_of_the_two_forms_is_refused():
         board_attention(q[..., :32], k, v, None, None, None, EPS, True, q_pe=q[..., 32:160], k_pe=k_pe)
     with pytest.raises(ValueError, match="whole 128-lane tiles"):
         board_attention(q[..., :32], k, v, None, None, LATENT_THETA, EPS, True, q_pe=q[..., 32:96], k_pe=k_pe)  # one head's RoPE columns for two
+
+
+# -- the fifth block's form: a norm without a query gain, a gain a key-value head, RoPE on the first columns of a head --------
+
+# (heads, key-value heads, head_dim, rotary_dim, boards): the published 8 over 2 of 128 with 64 rotated (4 query heads a
+# key-value head: 4 boards a grid step) on a batch the block divides and one it does not; a tiny group of 4; all of a
+# head rotated through the partial tables; a quarter of it.
+PART_CASES = [(8, 2, 128, 64, 4), (8, 2, 16, 8, 6), (4, 1, 16, 8, 5), (4, 2, 16, 16, 3), (2, 2, 16, 4, 8)]
+PART_OUTPUTS = ["mixed", "d_q", "d_k", "d_v", "d_gain"]
+PART_THETA = 5_000_000.0
+
+
+def plain_part(q, k, v, gain, head_dim, rotary_dim, wrong=""):
+    """The fifth block's core, float32: q and k normed without a gain, k under ``gain`` [kv_heads, head_dim], rotate-half
+    RoPE inside the FIRST ``rotary_dim`` columns of every head, the rest passing."""
+    boards = q.shape[0]
+    split = lambda y: y.astype(jnp.float32).reshape(boards, 64, -1, head_dim)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS)
+    half = rotary_dim // 2
+    angle = np.arange(64)[:, None] / PART_THETA ** (np.arange(half) / half)[None, :]
+    cos, sin = (jnp.asarray(np.concatenate([f(angle)] * 2, -1), jnp.float32)[:, None, :] for f in (np.cos, np.sin))
+
+    def turn(x):
+        part = x[..., -rotary_dim:] if wrong == "last_columns" else x[..., :rotary_dim]
+        part = part * cos + jnp.concatenate([-part[..., half:], part[..., :half]], -1) * sin
+        return jnp.concatenate([x[..., :-rotary_dim], part] if wrong == "last_columns" else [part, x[..., rotary_dim:]], -1)
+
+    q, v = turn(unit(split(q))), split(v)
+    k = turn(unit(split(k)) * (gain[::-1] if wrong == "gains_exchanged" else gain)[None, None])
+    k, v = (jnp.repeat(y, q.shape[2] // y.shape[2], axis=2) for y in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / np.sqrt(head_dim)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v, precision="highest").reshape(boards, 64, -1)
+
+
+@functools.lru_cache(maxsize=None)
+def both_part(heads, kv_heads, head_dim, rotary_dim, boards, wrong=""):
+    q, k, v, _, _, cotangent = inputs(boards, heads, head_dim, seed=11, kv_heads=kv_heads)
+    gain = jnp.asarray(1.0 + 0.3 * np.random.default_rng(12).standard_normal((kv_heads, head_dim)), jnp.float32)
+
+    def sides(f):
+        out, pull = jax.vjp(f, q, k, v, gain)
+        return (out, *pull(cotangent.astype(out.dtype)))
+
+    return (jax.jit(lambda: sides(lambda q, k, v, g: board_attention(q, k, v, None, g, PART_THETA, EPS, True, rotary_dim=rotary_dim)))(),
+            jax.jit(lambda: sides(lambda q, k, v, g: plain_part(q, k, v, g, head_dim, rotary_dim, wrong)))())
+
+
+@pytest.mark.parametrize("output", PART_OUTPUTS)
+@pytest.mark.parametrize("heads,kv_heads,head_dim,rotary_dim,boards", PART_CASES)
+def test_a_gain_a_key_value_head_and_rope_on_part_of_a_head_match_the_plain_formula(heads, kv_heads, head_dim, rotary_dim, boards, output):
+    got, want = (side[PART_OUTPUTS.index(output)] for side in both_part(heads, kv_heads, head_dim, rotary_dim, boards))
+    assert got.shape == want.shape and (output != "d_gain" or got.shape == (kv_heads, head_dim))
+    assert got.dtype == (jnp.bfloat16 if output in ("mixed", "d_v") else jnp.float32)
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL)
+
+
+@pytest.mark.parametrize("wrong", ["last_columns", "gains_exchanged"])
+def test_the_tolerances_catch_rope_on_the_wrong_columns_and_a_gain_on_the_wrong_head(wrong):
+    got, want = both_part(8, 2, 16, 8, 6, wrong)
+    assert rel(got[0], want[0]) > 3 * FORWARD_TOL and rel(got[1], want[1]) > 3 * GRADIENT_TOL
+
+
+def test_rope_on_all_of_a_head_is_the_same_through_either_table():
+    q, k, v, _, g_k, _ = inputs(4, 4, 16, seed=13, kv_heads=2)
+    gain = jnp.broadcast_to(g_k, (2, 16))
+    whole = board_attention(q, k, v, None, gain, PART_THETA, EPS, True)
+    part = board_attention(q, k, v, None, gain, PART_THETA, EPS, True, rotary_dim=16)
+    assert rel(part, whole) < 1e-6
+
+
+def test_a_part_that_is_odd_or_wider_than_a_head_and_a_part_of_a_latent_are_refused():
+    q, k, v, g_q, g_k, _ = inputs(2, 2, 16)
+    for rotary_dim in (5, 18, 0):
+        with pytest.raises(ValueError, match="even part of a head"):
+            board_attention(q, k, v, g_q, g_k, THETA, EPS, True, rotary_dim=rotary_dim)
+    with pytest.raises(ValueError, match="not a mixture"):
+        board_attention(q, k, v, None, None, THETA, EPS, True, q_pe=q, k_pe=k[..., :16], rotary_dim=8)

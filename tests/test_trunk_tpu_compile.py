@@ -502,3 +502,81 @@ def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_
     assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.5  # 4.92 + 7.24 GiB when this was written
+
+
+# -- the fifth block (zaya) at its published widths: 8 query heads over 2 key-value heads of 128 in a 1024 / 256 latent, -----------
+# -- 1,280 mixed columns, a 256-wide router MLP choosing one of 16 experts of width 2048, 8 of them held -------------------------
+
+CCA_BOARDS = 512  # cca_trunk_train_b512
+CCA = trunk.TrunkConfig(hidden=2048, heads=8, kv_heads=2, head_dim=128, layers=4, cca=(2, 2), rotary_dim=64, router_hidden=256, experts=16,
+                        experts_per_token=1, expert_width=2048, rope_theta=5e6, rms_eps=1e-5, held_experts=(0, 8), balance_rate=0.0001,
+                        recompute_experts=True)
+
+
+def test_the_mix_kernel_pair_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``cca_mix`` and ``cca_mix_grad`` on a batch of the cell: 1,280
+    columns, two taps each, conv1's ten heads of ``[128, 128]`` matrices
+    and their float32 sums resident across the grid's steps."""
+    from fishnet_tpu.ops.cca_mix import cca_mix
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((CCA_BOARDS, 64, 1280)), sds((1280, 2)), sds((1280,)), sds((10, 2, 128, 128)), sds((1280,)))
+
+    def loss(*a):
+        q, k, sums = cca_mix(*a, 8, 2, False)
+        return jnp.sum(jnp.square(q)) + jnp.sum(jnp.square(k)) + jnp.sum(sums)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("cca_mix_grad" in name for name in kernels) == 1, kernels
+
+
+def test_the_core_with_a_gain_a_key_value_head_and_half_a_head_rotated_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of the fifth block's ``_attention``: the two
+    joined projections, the mix pair, and ``board_attention`` /
+    ``board_attention_grad`` told a norm without a query gain, a gain a
+    key-value head and RoPE on 64 of 128 columns, 4 query heads a
+    key-value head: four kernels, no scores kept, no per-head copy."""
+    import re
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {name: sds(shape[1:]) for name, shape in trunk.trunk_param_shapes(CCA).items() if name in trunk._EVERY_LAYER and name != "moe_norm"}
+    assert layer["wo"].shape == (1024, 2048) and layer["temp"].shape == (2,) and layer["conv1_w"].shape == (10, 2, 128, 128)
+
+    def loss(x, p):
+        return jnp.sum(jnp.square(trunk._attention(x, p, CCA)[0]))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((CCA_BOARDS * trunk.SQUARES, HIDDEN)), layer).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 4 and sum("board_attention" in name for name in kernels) == 2 and sum("cca_mix" in name for name in kernels) == 2, kernels
+    per_head = [line for line in text.splitlines() if re.search(r"\[512,8,64,64\]|\[512,64,8,128\]|\[512,8,64,128\]", line)]
+    assert not per_head, per_head[:2]
+
+
+def test_the_fifth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+    """The whole step of ``cca_trunk_train_b512`` from its configuration
+    file: the mix pair and the attention pair a layer, the experts'
+    joined product at 4,096 columns on ~2,048 rows a held expert, the
+    router MLP, top-1 without ``moe_rows_sum`` (a token's one slot is a
+    select), nothing remade to fit."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "zaya1-trunk-train.json").read_text())
+    trainer = importlib.import_module("benchmark.families.cca_trunk").make_trainer(config)
+    assert trainer.cfg == CCA and config["train"]["batch"] == CCA_BOARDS
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == 427_880_022  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((CCA_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((CCA_BOARDS, 4672)), "value_target": jnp.zeros((CCA_BOARDS,))}
+    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("cca_mix_grad" in n for n in names) == 4 and sum("cca_mix" in n for n in names) == 8, names
+    assert sum("board_attention_grad" in n for n in names) == 4 and sum("board_attention" in n for n in names) == 8
+    assert not [n for n in names if "moe_rows_sum" in n] and sum("moe_rows_back" in n for n in names) >= 4
+    assert not _xla_passes_over_slots(text, CCA_BOARDS * trunk.SQUARES)  # at one slot a token the slots are the tokens: still the kernels alone
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.0  # 4.79 + 7.46 GiB when this was written
+    assert ".remat" not in text
