@@ -127,15 +127,25 @@ string, cut::
               E_e(u) = relu(u W_up[e])^2 W_down[e]: TWO products, no gate (``gated_ffn`` False); Shared the same at ``shared_width``
     out       N_final(x) -> the heads
 
-Mechanism, the mixer: the projections, the convolution (four shifted
-multiply-adds), softplus and the gated grouped norm are XLA's under
+Mechanism, the mixer: the projections and softplus are XLA's under
 ``layerNN.mamba``; the scan's core is one Pallas kernel pair
 (``ops/board_scan.py``: ``board_scan``, ``board_scan_grad``) under
 ``layerNN.scan`` beside it, one B/C group and its heads of a few boards a
 grid step, the decay from a cumulative sum made in the kernel, nothing
-``[64, 64]`` or ``[.., heads, P]`` in HBM; ``mamba_in`` is split on the
-weights' side, so the kernels' operands are the convolution's results as
-they are. Mechanism, widths no tile divides (``_whole_lanes``,
+``[64, 64]`` or ``[.., heads, P]`` in HBM. The two float32 chains
+between the projections and the scan are two more pairs under
+``layerNN.mamba`` (``ops/mamba_mix.py``), each read once and written
+once, a few boards' rows a grid step: ``mamba_conv`` /
+``mamba_conv_grad``, the convolution (four multiply-adds, a shift a
+rotation of sublanes and a select, so nothing crosses a board) with its
+silu, whose results ARE the scan's three bfloat16 operands (no ``[..,
+x + B + C]`` array that is then sliced; the gradient makes the
+convolution again from the kept product result); and
+``mamba_gate_norm`` / ``mamba_gate_norm_grad``, ``y * silu(z)`` under the
+grouped norm (a group's mean square summed inside the kernel: no
+``[tokens, groups, width]`` view), written once in the bfloat16 the
+out-projection reads. ``mamba_in`` is split on the weights' side, so the
+kernels' operands are the products' results as they are. Mechanism, widths no tile divides (``_whole_lanes``,
 ``_whole_rows``: ONE rule, zeros inside the step, never a parameter): an
 expert width of 1,856 = 14.5 lane tiles is padded to 1,920 on the
 weights' side; a moved row of 2,688 = 21 x 128 is not whole (8, 128)
@@ -310,6 +320,7 @@ from fishnet_tpu.ops.board_attention import SQUARES, board_attention
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
 from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
+from fishnet_tpu.ops.mamba_mix import mamba_conv, mamba_gate_norm
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
@@ -661,27 +672,19 @@ def _ffn(n: jax.Array, p: Params, kind: str, gated: bool) -> jax.Array:
     return _gated_ffn(n, p, kind) if gated else _matmul(jnp.square(jax.nn.relu(_matmul(n, p[f"{kind}_up"]))), p[f"{kind}_down"])
 
 
-def _board_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """A depthwise causal convolution along the squares of a board:
-    ``y[t] = b + sum_k w[:, k] x[t - (taps - 1) + k]`` for ``x`` [boards,
-    64, channels] float32 and ``w`` [channels, taps], nothing before
-    square 0 (``torch.nn.Conv1d(groups=channels, padding=taps - 1)`` cut
-    to the board, as the published mixer applies it)."""
-    taps = w.shape[-1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return b + sum(padded[:, k:k + SQUARES] * w[:, k] for k in range(taps))
-
-
 def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """[tokens, hidden] float32, 64 tokens a board -> a Mamba-2 mixer's
     output, same shape, and its two counters (the mean step; the
     smallest of the heads' decays across a board, mean over boards). The
-    projections, the convolution, softplus and the gated grouped norm are
-    XLA's under ``<layer>.mamba``; the scan's core is ``board_scan``
-    under ``<layer>.scan`` beside it, never inside (call this under none
-    of a layer's scopes). ``mamba_in``'s columns are the published
-    ``[z | x | B | C | dt]``, split on the weights' side as the latent
-    projections are."""
+    projections and softplus are XLA's under ``<layer>.mamba``, and so
+    are the two kernel pairs between them and the scan
+    (``ops/mamba_mix.py``: the convolution with its silu, which writes the
+    scan's three bfloat16 operands; the gate with its grouped norm, which
+    writes what the out-projection reads); the scan's core is
+    ``board_scan`` under ``<layer>.scan`` beside it, never inside (call
+    this under none of a layer's scopes). ``mamba_in``'s columns are the
+    published ``[z | x | B | C | dt]``, split on the weights' side as the
+    latent projections are."""
     heads, groups = cfg.mamba_heads, cfg.mamba_groups
     inner, state = heads * cfg.mamba_head_dim, groups * cfg.state_size
     by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
@@ -689,8 +692,7 @@ def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.A
         n = _rms_norm(x, p["layer_norm"], cfg.rms_eps)
         w = p["mamba_in"]
         z, xbc, dt = _matmul(n, w[:, :inner]), _matmul(n, w[:, inner:2 * inner + 2 * state]), _matmul(n, w[:, 2 * inner + 2 * state:])
-        xbc = jax.nn.silu(_board_conv(by_board(xbc), p["conv_w"], p["conv_b"]))
-        xs, bs, cs = (xbc[..., lo:hi].astype(jnp.bfloat16) for lo, hi in ((0, inner), (inner, inner + state), (inner + state, inner + 2 * state)))
+        xs, bs, cs = mamba_conv(by_board(xbc), p["conv_w"], p["conv_b"], (inner, state, state), _interpret())
         step = jax.nn.softplus(by_board(dt) + p["dt_bias"])  # time_step_limit (0, inf) clips nothing
         rate = -jnp.exp(p["A_log"])
         counted, decay = jax.lax.stop_gradient((step, rate))
@@ -699,9 +701,8 @@ def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.A
     with jax.named_scope(f"{layer}.scan"):
         y = board_scan(xs, bs, cs, step, rate, p["D_skip"], groups, _interpret())
     with jax.named_scope(f"{layer}.mamba"):
-        y = y.reshape(x.shape[0], inner).astype(jnp.float32) * jax.nn.silu(z)
-        y = _rms_norm(y.reshape(-1, groups, inner // groups), p["mamba_norm"].reshape(groups, -1), cfg.rms_eps)
-        return _matmul(y.reshape(-1, inner), p["mamba_out"]), counters
+        y = mamba_gate_norm(y.reshape(x.shape[0], inner), z, p["mamba_norm"], groups, cfg.rms_eps, _interpret())
+        return _matmul(y, p["mamba_out"]), counters
 
 
 def _cca_mix(x: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -714,7 +715,7 @@ def _cca_mix(x: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.
     what the convolutions changed, ``c - x``, over that of ``x`` (no
     gradient)::
 
-        a = conv0(x)     depthwise along the squares (the fourth block's ``_board_conv``, here inside the kernel), float32
+        a = conv0(x)     depthwise along the squares (as the fourth block's ``mamba_conv``), float32
         c = conv1(a)     a head at a time (heads + kv_heads groups of head_dim columns): c[t, g] = b1[g] + sum_k a[t - (taps - 1) + k, g] W1[g, k],
                          each tap a [head_dim, head_dim] matrix, bfloat16 operands, float32 accumulation; nothing before square 0
         q[h] = c_q[h] + (x_q[h] + x_k[h // group]) / 2;   k[g] = c_k[g] + (mean over the group's heads of x_q + x_k[g]) / 2
@@ -796,9 +797,12 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, lay
 
 def _interpret() -> bool:
     """The trunk's Pallas kernels (the attention core, the grouped product,
-    the gate pair, the two row moves and a share's sum over a token's slots) are one path everywhere: compiled by Mosaic on a TPU, run by
-    the Pallas interpreter elsewhere (the CPU of the tests), never
-    another path."""
+    the gate pair, the two row moves and a share's sum over a token's
+    slots; the fourth block's scan pair and its mixer's convolution and
+    gate-norm pairs, ``ops/board_scan.py`` and ``ops/mamba_mix.py``; the
+    fifth block's mix pair, ``ops/cca_mix.py``) are one path everywhere:
+    compiled by Mosaic on a TPU, run by the Pallas interpreter elsewhere
+    (the CPU of the tests), never another path."""
     return jax.default_backend() != "tpu"
 
 

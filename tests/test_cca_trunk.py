@@ -310,3 +310,19 @@ def test_the_counters_read_the_references_mix_temperature_and_chosen_score():
     assert 0.4 < float(counters["route_top1_weight"]) < 0.99
     slots = np.asarray(cca_reference.expert_slots(params, batch["planes"], CCA_MODEL))
     assert abs(float(counters["held_slots"]) - slots[:, 4:12].sum()) <= 2 and float(np.sum(counters["expert_slots"])) == 2 * BATCH * 64
+
+
+#: sha256 of the fifth block's tiny lowered step program, as ``tests/test_hybrid_trunk.py PARENT_STEP_SHA256`` holds the four
+#: older blocks': read on PR 44's parent (3160177) and on PR 44's tree with this jax, and the same on both (PR 44 changed the
+#: fourth block's mixer, which no ``cca`` layer runs). A PR that means to change it reads its own parent the same way.
+CCA_STEP_SHA256 = "a835ede221ce0bab9447436bfb275c77f53ad24369a01935cc2fdcf61d9fb41a"
+
+
+def test_the_fifth_blocks_lowered_step_is_the_parents_op_for_op():
+    import hashlib
+
+    trainer = AzTrainer(CCA)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    text = jax.jit(trainer._step).lower(state, jax.eval_shape(lambda: board_batch(1))).as_text()
+    assert "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == CCA_STEP_SHA256

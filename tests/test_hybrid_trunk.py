@@ -86,8 +86,8 @@ def _wrong_hybrid(monkeypatch, wrong):
         scan = trunk.board_scan
         monkeypatch.setattr(trunk, "board_scan", lambda x, b, c, step, a, *rest: scan(x, b, c, step, 1.5 * a, *rest))
     elif wrong == "taps_reversed":
-        conv = trunk._board_conv
-        monkeypatch.setattr(trunk, "_board_conv", lambda x, w, b: conv(x, w[:, ::-1], b))
+        conv = trunk.mamba_conv  # what ``_mamba`` calls since PR 44 (``ops/mamba_mix.py``)
+        monkeypatch.setattr(trunk, "mamba_conv", lambda u, w, b, *rest: conv(u, w[:, ::-1], b, *rest))
     elif wrong == "no_direct_term":
         scan = trunk.board_scan
         monkeypatch.setattr(trunk, "board_scan", lambda x, b, c, step, a, skip, *rest: scan(x, b, c, step, a, 0.0 * skip, *rest))
@@ -268,12 +268,14 @@ def test_a_shares_ungated_experts_against_a_loop_over_the_held_experts(hidden, w
 #: all four on ITS parent (3c5f3ae): the three are what they were, and the fourth block's is pinned beside them. PR 42's
 #: kernel form of the gated feed-forward is taken by size (``trunk._FUSED_GATE_BYTES``), and every tiny net here is under
 #: the rule, so ``afmoe`` and ``mla`` hold too: the kernel form is held by ``test_moe_trunk.py`` on both sides of the rule
-#: and by ``test_trunk_tpu_compile.py`` at the dense layer's published size.
+#: and by ``test_trunk_tpu_compile.py`` at the dense layer's published size. PR 44 MEANT to change ``hybrid`` (the mixer's
+#: convolution with its silu and its gate with the grouped norm became two kernel pairs, ``ops/mamba_mix.py``): its pin was
+#: read anew on PR 44's tree (the parent 3160177 read b47f7635...763a); the three others passed unedited.
 PARENT_STEP_SHA256 = {
     "llada": "60f5865d293d8516a7b2474ae17766489aca839166a5b0790d0a185cee8b0c77",
     "afmoe": "3bb78678b125d3e25ffcd3ae70ee260376b77e41d93b3becf876fd6c40f16f35",
     "mla": "0fedb499d5d0ceb1b7924dbb2f29537fddb8a67cbebdbcd6d1a68efeda7719e2",
-    "hybrid": "b47f763534f7ae51ffedf16d7bdd2fa2051129eae5215351464c2dc0ceec763a",
+    "hybrid": "3fa4c14a61f2e5625e227cbc27f8da0358f6120b0c9c8837d6f423315ff94f71",
 }
 
 

@@ -454,6 +454,67 @@ def test_the_scan_kernel_pair_compiles_at_published_widths(one_chip, compiled_fo
     assert len(kernels) == 2 and sum("board_scan_grad" in line.split(" = ")[0] for line in kernels) == 1, [line.split(" = ")[0] for line in kernels]
 
 
+def test_the_mixers_two_kernel_pairs_compile_at_published_widths(one_chip, compiled_for_tpu):
+    """``mamba_conv`` / ``mamba_conv_grad`` on 128 boards of 4,096 + 1,024 +
+    1,024 columns under four taps, and ``mamba_gate_norm`` /
+    ``mamba_gate_norm_grad`` on their 8,192 tokens of 4,096 columns in 8
+    groups: the blocks fit VMEM twice buffered beside the resident
+    gradients of the taps, the bias and the gain."""
+    from fishnet_tpu.ops.mamba_mix import mamba_conv, mamba_gate_norm
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    conv_loss = lambda *a: sum(jnp.sum(jnp.square(out.astype(jnp.float32))) for out in mamba_conv(*a, (4096, 1024, 1024), False))
+    norm_loss = lambda *a: jnp.sum(jnp.square(mamba_gate_norm(*a, 8, 1e-5, False).astype(jnp.float32)))
+    for loss, args, name in ((conv_loss, (sds((SSM_BOARDS, 64, 6144)), sds((6144, 4)), sds((6144,))), "mamba_conv"),
+                             (norm_loss, (sds((SSM_BOARDS * 64, 4096), jnp.bfloat16), sds((SSM_BOARDS * 64, 4096)), sds((4096,))), "mamba_gate_norm")):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+        kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(kernels) == 2 and sum(f"{name}_grad" in kernel for kernel in kernels) == 1 and all(name in kernel for kernel in kernels), kernels
+
+
+def _xla_passes_over_a_mixers_chains(text: str):
+    """The instructions of a compiled module's entry computation under a ``layerNN.mamba`` scope whose result is ``[128, 64,
+    6144]`` (or its ``[8192, 6144]`` view) or ``[8192, 8, 512]`` (or a relayout of it) and that XLA itself computes: neither
+    a kernel, nor a view of a kernel's or a product's result, nor a product (a fusion that holds a ``convolution``)."""
+    import re
+
+    products = _opcodes_fused_with_a_product(text)
+    shaped = re.compile(r"= \S*\[(?:128,64,6144|8192,6144|8192,8,512|1024,8,8,512)\]")
+    entry = text[re.search(r"^ENTRY ", text, re.M).start():]
+    return [line for line in entry.splitlines() if re.search(r"layer\d+\.mamba", line) and shaped.search(line)
+            and not re.search(r" (custom-call|get-tuple-element|bitcast)\(", line) and re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1) not in products]
+
+
+def test_a_mixer_at_published_widths_is_its_products_the_scan_and_two_kernel_pairs(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of ``_mamba`` as a step runs it (one norm, the
+    residual) on 128 boards at hidden 2,688: exactly six kernels, and
+    between the products and the scan no XLA pass whose result is as large
+    as ``[128, 64, 6144]`` or ``[8192, 8, 512]``: until PR 44 the
+    convolution, its silu, the cut into x, B, C, the gate and the grouped
+    norm were a dozen such passes a mixer, two relayout copies among them."""
+    import re
+
+    cfg = trunk.TrunkConfig(hidden=2688, heads=32, kv_heads=2, head_dim=128, qk_norm=False, pattern="MEMEM*E", experts=128, experts_per_token=6,
+                            expert_width=1856, gated_ffn=False, shared_width=3712, rope_theta=1e4, rms_eps=1e-5, mamba_heads=64, mamba_head_dim=64,
+                            mamba_groups=8, state_size=128, router_score="sigmoid", route_norm=True, route_scale=2.5, held_experts=(0, 8),
+                            balance_rate=0.001, recompute_experts=True)
+    shapes = {name: shape[1:] for name, shape in trunk.trunk_param_shapes(cfg).items()
+              if name.startswith(("mamba_", "conv_")) or name in ("dt_bias", "A_log", "D_skip", "layer_norm")}
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(x, p):
+        return jnp.sum(jnp.square(x + trunk._mamba(x, p, cfg, "layer00")[0]))  # a cotangent that waits for the result
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((SSM_BOARDS * 64, 2688)), {name: sds(shape) for name, shape in shapes.items()}).compile().as_text()
+    kernels = sorted(line.split(" = ")[0].strip().lstrip("%").split(".")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == ["board_scan", "board_scan_grad", "mamba_conv", "mamba_conv_grad", "mamba_gate_norm", "mamba_gate_norm_grad"], kernels
+    assert f"f32[{SSM_BOARDS * 64},6144]" in text or f"f32[{SSM_BOARDS},64,6144]" in text  # the x B C product's result is there to be looked for
+    assert not _xla_passes_over_a_mixers_chains(text), _xla_passes_over_a_mixers_chains(text)[:3]
+    # the cotangents of the x B C and z products' results leave their kernels bfloat16 and reach the transposed products as they are
+    grads = {name: line for line in text.splitlines() for name in ("mamba_conv_grad", "mamba_gate_norm_grad") if re.match(rf"\s*%{name}[.\d]* = ", line)}
+    assert "= (bf16[8192,6144]" in grads["mamba_conv_grad"] and "= (bf16[8192,4096]{1,0:T(8,128)(2,1)}, bf16[8192,4096]" in grads["mamba_gate_norm_grad"], grads
+
+
 def test_the_ungated_experts_compile_at_widths_no_lane_tile_divides(one_chip, compiled_for_tpu):
     """A share's ungated experts at hidden 2,688 and width 1,856, as
     ``_routed`` hands them to Mosaic: rows of 3,072 (whole tiles of a
@@ -479,8 +540,8 @@ def test_the_ungated_experts_compile_at_widths_no_lane_tile_divides(one_chip, co
 
 
 def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_for_tpu):
-    """The whole step of ``ssm_trunk_train_b128``: the scan pair a mixer,
-    the attention pair at 16 query heads a key-value head without its
+    """The whole step of ``ssm_trunk_train_b128``: the scan pair and the
+    convolution and gate-norm pairs a mixer, the attention pair at 16 query heads a key-value head without its
     norm, the moves at a row of 3,072, the products at 1,920 lanes."""
     import optax
 
@@ -499,6 +560,8 @@ def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_
     text = compiled.as_text()
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("board_scan_grad" in n for n in names) == 3 and sum("board_scan" in n for n in names) == 6, names
+    for pair in ("mamba_conv", "mamba_gate_norm"):  # since PR 44: a mixer's two float32 chains, forward and gradient
+        assert sum(f"{pair}_grad" in n for n in names) == 3 and sum(pair in n for n in names) == 6, (pair, names)
     assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.5  # 4.92 + 7.24 GiB when this was written
