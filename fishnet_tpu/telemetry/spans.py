@@ -43,10 +43,12 @@ named machinery actually runs):
 * ``train_init``  — one ``Trainer.init`` / ``AzTrainer.init``: the
   init program traced, lowered, compiled or loaded, and run
   (train/startup.py; fields: trainer, compile_s, cache_load_s,
-  trace_lower_s, cache_misses)
+  trace_lower_s, cache_misses; ``AzTrainer``'s also layout_held_leaves,
+  layout_held_bytes: the state's leaves the client holds off row-major,
+  whose update its step runs in the client's layout)
 * ``train_first_step`` — the first ``.step`` of a trainer instance:
   trace + lower + compile or cache load + dispatch of the step program
-  (train/startup.py; same fields)
+  (train/startup.py; the first five fields)
 
 Recording is OFF by default: every instrumentation site is gated on
 ``fishnet_tpu.telemetry.enabled()``, so with telemetry disabled the

@@ -20,12 +20,13 @@ framework produce the very nets its engines serve.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.experimental.layout import Layout, with_layout_constraint
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
 from fishnet_tpu.models.trunk import balanced_bias
@@ -59,6 +60,47 @@ def az_param_spec(name: str, value: jax.Array) -> P:
     return P()
 
 
+#: The leaves a Pallas kernel reads: the operands of the trunk's grouped products (``models/trunk.py _expert_ffn``).
+_KERNEL_OPERANDS = ("experts_gate", "experts_up", "experts_down")
+
+
+def _client_default(device: jax.Device, dtype: Any, shape: Tuple[int, ...]) -> Layout:
+    """The layout ``device``'s client gives an array of this shape left to itself."""
+    return Layout.from_pjrt_layout(device.client.get_default_layout(dtype, shape, device))
+
+
+def held_layouts(params: Dict[str, Any], mesh: Optional[Mesh], device: jax.Device) -> Dict[str, Layout]:
+    """The layout ``device``'s client holds each kernel operand of
+    ``params`` (arrays or their shapes) in, where that is not row-major:
+    the leaves the step's update has to follow.
+
+    A kernel's operands are row-major by contract (``models/trunk.py
+    _row_major`` says the same of the residual stream), so the leaf's
+    gradient arrives row-major and XLA, left to itself, runs AdamW on it
+    row-major: where the client stores the leaf otherwise (a TPU holds
+    ``f32[.., 2688, 1856]`` with 2,688 on the lanes, because 1,856 is
+    14.5 lane tiles) the weight and its two moments are then copied whole
+    on the way into the step and, donated, on the way out of it, every
+    step, under no scope. Every other leaf is XLA's own products' and
+    they already run in whatever the client chose. On the CPU, and
+    wherever the minor width is whole lanes, row-major is the default and
+    nothing is listed.
+
+    The state itself stays in the client's default at every program's
+    boundary: an executable whose arguments or results have another
+    layout does not survive the persistent compile cache on jax 0.9.0
+    (PERF.md section 6, PR 45)."""
+    held = {}
+    for name in _KERNEL_OPERANDS:
+        if name in params:
+            leaf = params[name]
+            shape = leaf.shape if mesh is None else NamedSharding(mesh, az_param_spec(name, leaf)).shard_shape(leaf.shape)
+            default = _client_default(device, leaf.dtype, shape).major_to_minor
+            if default != tuple(range(leaf.ndim)):
+                held[name] = Layout(major_to_minor=default)
+    return held
+
+
 def az_batch_specs() -> Dict[str, P]:
     return {
         "planes": P(DATA_AXIS),
@@ -88,9 +130,20 @@ class AzTrainer:
         self.value_weight = value_weight
         self.optimizer = optimizer or optax.adamw(learning_rate, weight_decay=1e-4)
         compile_cache.configure()  # before the first jit
+        self._hold_on(jax.devices()[0] if mesh is None else mesh.devices.flat[0])
         self._init_jit = jax.jit(self._init)
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
         self._record = step_metrics.STEPS.attach("az")
+
+    def _hold_on(self, device: jax.Device) -> None:
+        """Ask ``device``'s client (the mesh's first where there is a
+        mesh) how it holds the state this trainer makes: what ``_step``
+        follows, and what ``init``'s span reports of it (the leaves of
+        those names, moments included, and their bytes)."""
+        state = jax.eval_shape(self._init, jax.random.PRNGKey(0))
+        self._held = held_layouts(state.params, self.mesh, device)
+        followed = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0] if getattr(path[-1], "key", None) in self._held]
+        self._held_fields = {"layout_held_leaves": len(followed), "layout_held_bytes": sum(leaf.size * leaf.dtype.itemsize for leaf in followed)}
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -125,6 +178,9 @@ class AzTrainer:
         grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch, state.buffers)
         slots = metrics.pop("expert_slots", None)  # [routed layers, experts], not a scalar of the step's metrics
         with jax.named_scope("optimizer"):
+            # the update of a leaf the client holds off row-major runs in that layout, and neither the weight nor a
+            # moment is relaid on the way in or out (held_layouts)
+            grads = {k: with_layout_constraint(g, self._held[k]) if k in self._held else g for k, g in grads.items()}
             updates, opt_state = self.optimizer.update(
                 grads, state.opt_state, state.params
             )
@@ -138,7 +194,7 @@ class AzTrainer:
     # -- public api -------------------------------------------------------
 
     def init(self, seed: int = 0) -> AzTrainState:
-        with startup.init_span("az"):
+        with startup.init_span("az", **self._held_fields):
             return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: AzTrainState, batch: Batch):

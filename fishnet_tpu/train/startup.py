@@ -32,11 +32,13 @@ def _annotated(stage: str) -> Iterator[float]:
 
 
 @contextmanager
-def init_span(trainer: str) -> Iterator[None]:
-    """Round ``Trainer.init`` / ``AzTrainer.init``; ``trainer`` is ``nnue`` or ``az``."""
+def init_span(trainer: str, **fields: int) -> Iterator[None]:
+    """Round ``Trainer.init`` / ``AzTrainer.init``; ``trainer`` is ``nnue`` or ``az``,
+    ``fields`` what the trainer knows of the state it makes (``AzTrainer``: the leaves
+    the client holds off row-major, and their bytes)."""
     with _annotated("train_init") as started:
         yield
-    RECORDER.record("train_init", started, trainer=trainer, **compile_cache.configure_recorder().totals_since(started))
+    RECORDER.record("train_init", started, trainer=trainer, **fields, **compile_cache.configure_recorder().totals_since(started))
 
 
 @contextmanager

@@ -403,6 +403,57 @@ STEP_CELLS = {"afmoe_trunk": ("trinity-mini-trunk-train", 401_913_678, 10_794_56
               "mla_trunk": ("kanana-2-trunk-train", 359_558_222, 10_884_026_880, 416 << 20)}
 
 
+def _copies_of_state_arguments(text: str, floor: int = 1 << 20):
+    """The ``copy`` instructions of a compiled step that relayout an argument of the state (``%state_params__...``,
+    ``%state_opt_state_...``: same element type in and out) into a result of more than ``floor`` bytes: a leaf the client
+    holds in another layout than the step computes it in, copied whole on the way in every step and, donated, on the way
+    out (PERF.md section 7: the instruction names are the ledger's ``breakdown`` names). Below the floor are the scalars
+    and biases XLA moves to scoped memory; a copy to another type is a cast the step needs, read in the held layout."""
+    import math
+    import re
+
+    sizes = {"f32": 4, "bf16": 2, "s32": 4}
+    types = dict(re.findall(r"%(state_[\w.]+) = (\w+)\[[\d,]*\]\S* parameter\(", text))
+    found = []
+    for line in text.splitlines():
+        match = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* copy\(%(state_[\w.]+)\)", line)
+        if match and match.group(1) == types[match.group(3)] and sizes[match.group(1)] * math.prod(int(n) for n in match.group(2).split(",") if n) > floor:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _held_as_the_trainer_holds_it(trainer, one_chip):
+    """``trainer`` told that its state lives on the described chip: its own jit, what its step follows asked of that
+    chip's client."""
+    (device,) = one_chip.device_set
+    trainer._hold_on(device)
+    return trainer
+
+
+#: Every cell that ``AzTrainer`` steps, with the leaves the v5e client holds off row-major and the trainer's step follows, and
+#: the bytes of each with its two moments: Nemotron's 1,856 is 14.5 lane tiles, so ``experts_up``; every other width is whole lanes.
+HELD = {"az": ("az-256x19-train", (), 0), "moe_trunk": ("lladamoe-trunk-train", (), 0), "afmoe_trunk": ("trinity-mini-trunk-train", (), 0),
+        "mla_trunk": ("kanana-2-trunk-train", (), 0), "hybrid_trunk": ("nemotron-twotower-trunk-train", ("experts_up",), 3 * 4 * 3 * 8 * 2688 * 1856),
+        "cca_trunk": ("zaya1-trunk-train", (), 0)}
+
+
+@pytest.mark.parametrize("family", HELD)
+def test_the_leaves_a_cell_holds_off_row_major(one_chip, family):
+    """No compile: the rule (``az_trainer.held_layouts``) asked of the described chip's client, for each cell's own
+    trainer. A cell that holds nothing traces the ``_step`` it always did."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    from jax.experimental.layout import Layout
+
+    name, leaves, held_bytes = HELD[family]
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / f"{name}.json").read_text())
+    trainer = _held_as_the_trainer_holds_it(importlib.import_module(f"benchmark.families.{family}").make_trainer(config), one_chip)
+    assert trainer._held == {leaf: Layout(major_to_minor=(0, 1, 3, 2)) for leaf in leaves}
+    assert trainer._held_fields == {"layout_held_leaves": 3 * len(leaves), "layout_held_bytes": held_bytes}
+
+
 @pytest.mark.parametrize("family", STEP_CELLS)
 def test_the_whole_step_of_a_share_trunk_fits_one_chip(one_chip, compiled_for_tpu, family):
     """``AzTrainer._step`` on the cut configurations of ``afmoe_trunk_train_b256`` and ``mla_trunk_train_b256`` (one dense and
@@ -424,11 +475,12 @@ def test_the_whole_step_of_a_share_trunk_fits_one_chip(one_chip, compiled_for_tp
     batch = {"planes": jax.ShapeDtypeStruct((boards, 8, 8, 19), jnp.float32, sharding=one_chip),
              "policy_target": jax.ShapeDtypeStruct((boards, 4672), jnp.float32, sharding=one_chip),
              "value_target": jax.ShapeDtypeStruct((boards,), jnp.float32, sharding=one_chip)}
-    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(state, batch).compile()
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(state, batch).compile()
     stats = compiled.memory_analysis()
     assert (stats.temp_size_in_bytes + stats.argument_size_in_bytes) / 2**30 < HBM_GIB, stats
     assert stats.temp_size_in_bytes <= parent_temporaries + room, stats.temp_size_in_bytes
     text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)  # whole-lane widths: the client's default is the step's layout
     assert len([line for line in text.splitlines() if "tpu_custom_call" in line and "board_attention" in line]) == 10  # five layers, forward and gradient
     slots = boards * trunk.SQUARES * trainer.cfg.experts_per_token
     assert not _xla_passes_over_slots(text, slots)
@@ -540,9 +592,14 @@ def test_the_ungated_experts_compile_at_widths_no_lane_tile_divides(one_chip, co
 
 
 def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_for_tpu):
-    """The whole step of ``ssm_trunk_train_b128``: the scan pair and the
+    """The whole step of ``ssm_trunk_train_b128`` as the trainer compiles it: the scan pair and the
     convolution and gate-norm pairs a mixer, the attention pair at 16 query heads a key-value head without its
-    norm, the moves at a row of 3,072, the products at 1,920 lanes."""
+    norm, the moves at a row of 3,072, the products at 1,920 lanes; and since PR 45 the update of ``experts_up``
+    runs in the layout the client holds it and its two moments in (``{2,3,1,0}``: 2,688 on the lanes), so that no
+    state argument is relaid (until then six transposing copies of ``f32[3,8,2688,1856]``, 479 MB each, every step:
+    three in, three out of the donated state) and the forward's bfloat16 cast reads the argument itself."""
+    import re
+
     import optax
 
     from fishnet_tpu.train.az_trainer import AzTrainer
@@ -556,8 +613,16 @@ def test_the_fourth_blocks_step_compiles_at_published_widths(one_chip, compiled_
     state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
     assert sum(v.size for v in state.params.values()) == 440_339_214  # the configuration file's reckoning
     batch = {"planes": jnp.zeros((SSM_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((SSM_BOARDS, 4672)), "value_target": jnp.zeros((SSM_BOARDS,))}
-    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(on_chip(state), on_chip(batch)).compile()
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
     text = compiled.as_text()
+    assert list(trainer._held) == ["experts_up"] and not _copies_of_state_arguments(text), _copies_of_state_arguments(text)
+    assert not re.search(r"= f32\[3,8,2688,1856\]\S* copy\(", text)
+    header = text.splitlines()[0]
+    layouts = re.findall(r"f32\[3,8,2688,1856\]\{([\d,]+)", header[header.index("entry_computation_layout="):])
+    assert layouts == 6 * ["2,3,1,0"], layouts  # the weight and its two moments, arguments and results: the client's one layout
+    adamw = [line for line in text.splitlines() if re.match(r"\s*%[\w.]+ = \(f32\[3,8,2688,1856\]\{2,3,1,0\S*, f32\[3,8,2688,1856\]\{2,3,1,0\S*, f32\[3,8,2688,1856\]\{2,3,1,0", line)]
+    assert len(adamw) == 1 and " fusion(" in adamw[0], adamw  # the update itself runs in that layout
+    assert header.count("-alias)") == len(jax.tree.leaves(state)), header.count("-alias)")  # donation still covers every state leaf
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("board_scan_grad" in n for n in names) == 3 and sum("board_scan" in n for n in names) == 6, names
     for pair in ("mamba_conv", "mamba_gate_norm"):  # since PR 44: a mixer's two float32 chains, forward and gradient
@@ -633,8 +698,9 @@ def test_the_fifth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
     assert sum(v.size for v in state.params.values()) == 427_880_022  # the configuration file's reckoning
     batch = {"planes": jnp.zeros((CCA_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((CCA_BOARDS, 4672)), "value_target": jnp.zeros((CCA_BOARDS,))}
-    compiled = jax.jit(trainer._step, donate_argnums=(0,)).lower(on_chip(state), on_chip(batch)).compile()
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
     text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)  # whole-lane widths: the client's default is the step's layout
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("cca_mix_grad" in n for n in names) == 4 and sum("cca_mix" in n for n in names) == 8, names
     assert sum("board_attention_grad" in n for n in names) == 4 and sum("board_attention" in n for n in names) == 8
