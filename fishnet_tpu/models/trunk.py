@@ -9,6 +9,18 @@ published blocks as one code path at different values; no attention has
 a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
 
+The program reads a trunk as a LIST OF SUBLAYERS (``trunk_plan``, made once a configuration), each ``x <- x +
+[post-norm](kind(norm(x)))``, and runs ONE loop over it (``trunk_forward_counted``). A kind is one function ``(x, p, cfg,
+sublayer) -> (branch, counters by name)`` (a row of ``_KINDS``) and owns one row of ``_OWNS``, the table of stacked tensors
+by kind that ``trunk_param_shapes``, the loop's slices (``sublayer_params``) and the checkpoint reader go by. Four kinds mix
+tokens: ``attention`` (``_attention``), ``latent`` (``_latent_attention``: deepseek_v3's), ``cca`` (``_cca_attention``:
+zaya's), ``mamba`` (``_mamba``); two are feed-forwards: ``dense`` (``_dense_layer``), ``routed`` (``_routed_layer``). A
+layer of the first block is attention then routed, under ``attn_norm[i]`` and ``moe_norm[i]``; of the second, attention then
+dense or routed, a post-norm each; of the third, latent then dense or routed; of the fifth, cca then routed; a layer of the
+fourth is ONE of mamba, routed and attention, as ``pattern`` says, under ``layer_norm[i]``. A new token mixer is one
+function, one row in each of the two tables, its shapes (``_kind_shapes``), its fields of ``TrunkConfig`` with their
+refusal, and one helper of the checkpoint reader (``_SIZES``): nothing inside another kind's function.
+
 The first block is LLaDA-MoE-7B-A1B's (inclusionAI, config.json: hidden
 2048, 16 heads x 128, qk-norm, RoPE theta 50000, 64 experts top-8,
 softmax router, expert width 1024, SiLU, RMSNorm eps 1e-5): every layer
@@ -134,23 +146,18 @@ Mechanism, the mixer: the projections and softplus are XLA's under
 grid step, the decay from a cumulative sum made in the kernel, nothing
 ``[64, 64]`` or ``[.., heads, P]`` in HBM. The two float32 chains
 between the projections and the scan are two more pairs under
-``layerNN.mamba`` (``ops/mamba_mix.py``), each read once and written
-once, a few boards' rows a grid step: ``mamba_conv`` /
-``mamba_conv_grad``, the convolution (four multiply-adds, a shift a
-rotation of sublanes and a select, so nothing crosses a board) with its
-silu, whose results ARE the scan's three bfloat16 operands (no ``[..,
-x + B + C]`` array that is then sliced; the gradient makes the
-convolution again from the kept product result); and
-``mamba_gate_norm`` / ``mamba_gate_norm_grad``, ``y * silu(z)`` under the
-grouped norm (a group's mean square summed inside the kernel: no
-``[tokens, groups, width]`` view), written once in the bfloat16 the
-out-projection reads. ``mamba_in`` is split on the weights' side, so the
-kernels' operands are the products' results as they are. Mechanism, widths no tile divides (``_whole_lanes``,
-``_whole_rows``: ONE rule, zeros inside the step, never a parameter): an
-expert width of 1,856 = 14.5 lane tiles is padded to 1,920 on the
-weights' side; a moved row of 2,688 = 21 x 128 is not whole (8, 128)
-tiles, so the tokens go to dispatch as rows of 3,072 and the combine's
-sum is cut back. ``_tile`` hands Mosaic no tile that is not whole lanes.
+``layerNN.mamba`` (``ops/mamba_mix.py``, which says how), each read once
+and written once: the convolution with its silu, whose results ARE the
+scan's three bfloat16 operands, and ``y * silu(z)`` under the grouped
+norm, written once in the bfloat16 the out-projection reads.
+``mamba_in`` is split on the weights' side, so the kernels' operands are
+the products' results as they are.
+
+Mechanism, widths no tile divides (``_whole_lanes``, ``_whole_rows``:
+ONE rule, zeros inside the step, never a parameter): an expert width of
+1,856 = 14.5 lane tiles is padded to 1,920 on the weights' side; a moved
+row of 2,688 = 21 x 128 goes to dispatch as 3,072 and the combine's sum
+is cut back. ``_tile`` hands Mosaic no tile that is not whole lanes.
 
 The fifth block is ZAYA1-8B's (Zyphra, config.json, ``model_type`` zaya:
 hidden 2048, 40 layers all ``hybrid``, 8 query heads over 2 key-value
@@ -198,112 +205,92 @@ is ever multiplied (``_shifted_values``; the benchmark's reference
 shifts the input, so the two do not share a derivation). Everything
 between ``[q~ | k~]`` and the core is one Pallas kernel pair
 (``ops/cca_mix.py``: ``cca_mix``, ``cca_mix_grad``) under ``layerNN.cca``
-beside ``layerNN.attention``: a few boards' ``[64, columns]`` a grid
-step, a shift along the squares a rotation of sublanes and a select, so
-what conv0 and conv1 see before a board's first square is zero and never
-the board before; ``[q~ | k~]`` is read once and q and k written once.
-The norm, the temperature and RoPE stay inside ``board_attention``,
-which is told ``g_q`` None beside a gain a key-value head and
-``rotary_dim``. At one expert a token a "sum over a token's slots" is a
-select on the slot's mask: ``_held`` makes no lists of places and
-``_held_slots_sum`` calls no ``rows_sum`` kernel.
+beside ``layerNN.attention``: ``[q~ | k~]`` is read once, q and k written
+once, and nothing crosses a board. The norm, the temperature and RoPE
+stay inside ``board_attention``, which is told ``g_q`` None beside a
+gain a key-value head and ``rotary_dim``. At one expert a token a "sum
+over a token's slots" is a select on the slot's mask (``_held_slots_sum``).
 
 ``held_experts = (first, count)`` tells the expert layer which experts
 it holds, as one chip of an expert-parallel deployment does: it routes
 over all ``experts``, computes the part of the result that its own give
 for the tokens routed to them, and leaves the rest out; the shares of
 all chips and the shared expert once add up to the whole layer
-(``tests/test_moe_trunk.py``). Nothing stands in for the absent chips.
+(``tests/test_afmoe_trunk.py``). Nothing stands in for the absent chips.
 
 Mechanism, attention: the projections are XLA's; everything between
 them (qk-norm, RoPE, the 64 x 64 scores, softmax, mix) is one Pallas
 kernel pair (``ops/board_attention.py``: ``board_attention`` and its
 gradient ``board_attention_grad``; told the norm or its absence, the
 extent of RoPE, and whether the rotated key is one a key-value head or
-one for all heads) that takes q, k, v as the projections
-write them, ``[B, 64, heads * head_dim]`` and ``[B, 64, kv_heads *
-head_dim]``, and works on one key-value head and its group of query
-heads of a few boards at a time in VMEM: no ``[.., heads, head_dim]``
-view and no scores reach HBM, and the gradient recomputes the softmax
-from the same inputs.
+one for all heads) that takes q, k, v as the projections write them and
+works on one key-value head and its group of query heads of a few boards
+at a time in VMEM: no ``[.., heads, head_dim]`` view and no scores reach
+HBM, and the gradient recomputes the softmax from the same inputs.
 
 Mechanism, the feed-forward every token passes (the leading dense layer,
 the shared expert; ``_gated_ffn``): three XLA products round ``silu(gate)
-* up``. Where the gate's weight ``[hidden, width]`` is small XLA fuses
-the activation and its gradient into the products and is left to (the
-shared experts of the cells); past ``_FUSED_GATE_BYTES`` (the dense
-layer of width 6,144) gate and up are ONE product
-on the joined weights, the activation and its gradient are the experts'
-kernel pair below, made once as bfloat16 arrays, and every product's
-operand is a plain bfloat16 array (``_gated_products``, one gradient rule
-round the whole). One algorithm, its form taken from what the function
-sees in its input: never from the layer's kind or the family.
+* up`` or, past ``_FUSED_GATE_BYTES`` of gate weight (the dense layer of
+width 6,144), ONE product on the joined weights, the experts' kernel
+pair for the activation and its gradient, and plain bfloat16 operands
+(``_gated_products``, which says why). One algorithm, its form taken
+from what the function sees in its input: never from the layer's kind
+or the family.
 
 Mechanism, experts: the (token, slot) pairs are sorted by expert
-(stable), each expert's rows form one group of a grouped matrix product (megablox
-``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas interpreter on
-the CPU), the rows are put back in token order and each token's slots
-summed under their weights. An expert is two grouped products round one
-elementwise kernel: gate and up are ONE product on the two weights
-joined along their columns, ``[slots, hidden] x [held, hidden, 2 *
-width]``; ``silu(gate) * up`` is ``ops/expert_gate.py``'s kernel pair
-(``expert_gate``, ``expert_gate_grad``); the down product follows. A
-share's weights are ``[count, hidden, width]`` and the products are
-given the held groups' sizes alone, the first ``count`` of its sorted
-order (see below): megablox visits the tiles of the groups it is given
-and nothing else, forward and in both gradients, so a slot of an absent
-expert costs nothing and adds nothing, and every held expert is
-dropless. The rows
-move through two more Pallas
-kernels (``ops/row_move.py``): wherever a row is addressed singly it
-lives as ``[rows, hidden // 128, 128]``, one contiguous 4 KiB tile at
-hidden 2048, and one DMA moves it; the sorted side that the grouped
-products read stays ``[slots, hidden]``. ``rows_out`` (tokens to sorted
-slots) and ``rows_back`` (sorted slots to token order) are each other's
+(stable), each expert's rows form one group of a grouped matrix product
+(megablox ``gmm``, a Pallas kernel: Mosaic on the TPU, the Pallas
+interpreter on the CPU), the rows are put back in token order and each
+token's slots summed under their weights. An expert is two grouped
+products round one elementwise kernel: gate and up are ONE product on
+the two weights joined along their columns, ``[slots, hidden] x [held,
+hidden, 2 * width]``; ``silu(gate) * up`` is ``ops/expert_gate.py``'s
+kernel pair (``expert_gate``, ``expert_gate_grad``); the down product
+follows. A share's weights are ``[count, hidden, width]`` and the
+products are given the held groups' sizes alone, the first ``count`` of
+its sorted order (see below): megablox visits the tiles of the groups it
+is given and nothing else, forward and in both gradients, so a slot of
+an absent expert costs nothing and adds nothing, and every held expert
+is dropless. The rows move through two more Pallas kernels
+(``ops/row_move.py``): wherever a row is addressed singly it lives as
+``[rows, hidden // 128, 128]``, one contiguous 4 KiB tile at hidden
+2048, and one DMA moves it; the sorted side that the grouped products
+read stays ``[slots, hidden]``. ``rows_out`` (tokens to sorted slots)
+and ``rows_back`` (sorted slots to token order) are each other's
 transpose, so ``_dispatch`` and ``_combine`` pair them as forward and
 gradient and nothing is ever scatter-added. A share moves the rows of
 its own experts alone: it sorts on ``(expert - first) mod experts``, so
 they are rows ``[0, held)`` whatever ``first`` is, and hands ``held``
 (the layer's own count, on the device) to every move and to the gate
-kernels as their extent. The
-buffers keep their dropless shapes (``[N * k, hidden]`` sorted, ``[N,
-k, hidden // 128, 128]`` in token order: any routing fits, all slots
-held included); only the work follows the count: on a share nothing
-from the router's choice to the sums over a token's k costs by the slot
-count. The chosen scores are a one-hot select, not a gather; the sums
-over a token's k are a third kernel (``rows_sum``) that fetches the held
-places of the token-order view, one DMA a row, and passes over nothing;
-the combine weights' gradient is made on the sorted side, a row product
-inside the move that gathers the cotangent, and a sort brings it to
-token order (``_combine``). What a tail holds:
-past ``held`` (rounded up to a block) every sorted buffer, the moves',
-the products' and the gate kernels' results and their cotangents alike,
-and the places of absent experts' slots in the token-order view, are
-uninitialised and may be NaN. Who may read a tail: ``gmm`` and
-``tgmm`` (they visit held groups only and select by the group's rows),
-the moves and the gate kernels (they stop at the extent's block), and
-the select on the slot's mask that follows the weights' gradient's sort,
-never a product; the view's unwritten places are read by nobody
-(``ops/row_move.py``, "The extent of a move"). Matrix products run in
+kernels as their extent. The buffers keep their dropless shapes (``[N *
+k, hidden]`` sorted, ``[N, k, hidden // 128, 128]`` in token order: any
+routing fits, all slots held included); only the work follows the count:
+on a share nothing from the router's choice to the sums over a token's k
+costs by the slot count. The chosen scores are a one-hot select, not a
+gather; the sums over a token's k are a third kernel (``rows_sum``) that
+fetches the held places of the token-order view, one DMA a row, and
+passes over nothing; the combine weights' gradient is made on the sorted
+side, a row product inside the move that gathers the cotangent, and a
+sort brings it to token order (``_combine``). Past ``held`` (rounded up
+to a block) every sorted buffer and its cotangent, and the places of
+absent experts' slots in the token-order view, are uninitialised and may
+be NaN: who may read such a tail, and never a product, is
+``ops/row_move.py``'s "The extent of a move". Matrix products run in
 bfloat16 with float32 accumulation over float32 parameters, as the
 tower's; norms, the softmaxes, the router's scores, choice and combine
 weights, both sigmoid gates and the experts' gated activation are
 float32.
 
-Parameters are one flat dict (the ``.npz`` checkpoint format), the
-layers of a kind stacked on a leading axis (with a pattern: ``mamba_*``,
-``conv_*``, ``dt_bias``, ``A_log``, ``D_skip`` over the M layers, the
-attention's over the ``*`` layers, ``layer_norm [layers, hidden]``): ``wq [layers, ..]``,
-``dense_gate [dense_layers, ..]``, ``router_w [routed layers, hidden,
-experts]``, ``experts_gate [routed layers, held, hidden, width]``.
-``expert_bias [routed layers, experts]`` is a buffer beside them: the
-forward reads it from the same dict when it is there, and no gradient
-reaches it.
+Parameters are one flat dict (the ``.npz`` checkpoint format), the sublayers of a kind stacked on a leading axis:
+``wq [attention layers, ..]``, ``dense_gate [dense_layers, ..]``, ``experts_gate [routed layers, held, hidden, width]``,
+a pattern's ``layer_norm [layers, hidden]``. ``expert_bias [routed layers, experts]`` is a buffer beside them: the
+forward reads it from the same dict when it is there, and no gradient reaches it.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from dataclasses import dataclass
 from typing import Dict, NamedTuple, Optional, Tuple
@@ -446,55 +433,114 @@ class TrunkConfig:
         return self.pattern.count("*") if self.pattern else self.layers
 
 
-def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
-    """Every trained tensor of a trunk checkpoint by name."""
-    n, r, h, w = cfg.attention_layers, cfg.routed_layers, cfg.hidden, cfg.expert_width
-    inner, kv_inner, held = cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim, cfg.held[1]
-    if cfg.cca is not None:  # queries in ``inner`` columns, keys and values in ``kv_inner``; a value head's halves from two projections
-        mixed, groups = inner + kv_inner, cfg.heads + cfg.kv_heads
-        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv1": (n, h, kv_inner // 2), "wv2": (n, h, kv_inner // 2),
-                     "conv0_w": (n, mixed, cfg.cca[0]), "conv0_b": (n, mixed),
-                     "conv1_w": (n, groups, cfg.cca[1], cfg.head_dim, cfg.head_dim), "conv1_b": (n, mixed),
-                     "temp": (n, cfg.kv_heads), "wo": (n, inner, h)}
-    elif cfg.kv_lora_rank is None:
+class Sublayer(NamedTuple):
+    """One entry of a trunk's plan: ``x <- x + [post-norm](kind(norm(x)))``."""
+    layer: str  # the prefix of its scopes, ``layerNN``
+    kind: str  # a row of ``_OWNS`` and of ``_KINDS``
+    index: int  # its row of its kind's stacked tensors: layers of a kind are indexed from the first of that kind
+    norm: str  # the norm it reads: the tensor's name ...
+    norm_index: int  # ... and its row there, which is its post-norm's too
+    rope: bool = False  # an attention's: RoPE on its queries and keys
+    post_norm: Optional[str] = None
+
+
+@functools.lru_cache(maxsize=None)
+def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
+    """The trunk's sublayers in order: one a character of a pattern, each
+    under ``layer_norm[i]``; else two a layer, a token mixer under
+    ``attn_norm[i]`` and a feed-forward under ``moe_norm[i]``, dense in
+    the leading ``dense_layers``. Everything between ``embed`` and
+    ``final_norm`` reads this and not the fields it is made from."""
+    if cfg.pattern:
+        kinds = [{"M": "mamba", "E": "routed", "*": "attention"}[kind] for kind in cfg.pattern]
+        return tuple(Sublayer(f"layer{i:02d}", kind, kinds[:i].count(kind), "layer_norm", i, rope=kind == "attention") for i, kind in enumerate(kinds))
+    mixer = "cca" if cfg.cca is not None else "latent" if cfg.kv_lora_rank is not None else "attention"
+    # the attention branch's post-norm is the first kind's alone (a latent beside ``post_norms`` holds ``post_attn_norm`` and never reads it)
+    after_mixer, after_ffn = ("post_attn_norm" if mixer == "attention" else None, "post_mlp_norm") if cfg.post_norms else (None, None)
+    plan = []
+    for i in range(cfg.layers):
+        ffn = ("dense", i) if i < cfg.dense_layers else ("routed", i - cfg.dense_layers)
+        plan += [Sublayer(f"layer{i:02d}", mixer, i, "attn_norm", i, i not in cfg.nope_layers, after_mixer),
+                 Sublayer(f"layer{i:02d}", *ffn, "moe_norm", i, post_norm=after_ffn)]
+    return tuple(plan)
+
+
+#: THE table of the stacked tensors a kind of sublayer owns (its norms are the plan's): ``trunk_param_shapes`` makes these and
+#: no other (checked there), the loop slices by it (``sublayer_params``; a row's order is its slices'), the checkpoint reader
+#: requires a mixer's from it (``_SIZES``). ``expert_bias`` is a buffer (``trunk_buffer_shapes``) and is sliced alike.
+_OWNS = {
+    "mamba": ("mamba_in", "conv_w", "conv_b", "dt_bias", "A_log", "D_skip", "mamba_norm", "mamba_out"),
+    "attention": ("wq", "wk", "wv", "q_norm", "k_norm", "wo", "wgate"),
+    "latent": ("wq", "wkv_a", "kv_norm", "wkv_b", "wo"),
+    "cca": ("wq", "wk", "wv1", "wv2", "conv0_w", "conv0_b", "conv1_w", "conv1_b", "temp", "wo"),
+    "dense": ("dense_gate", "dense_up", "dense_down"),
+    "routed": ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias",
+               "router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3"),
+}
+_MIXERS, _FEED_FORWARDS = ("mamba", "attention", "latent", "cca"), ("dense", "routed")
+#: What the second block added to the first's file comes after the heads, in this order: ``init_trunk_params`` deals the
+#: split of its rng out in the order of ``trunk_param_shapes``' keys, so the order is every seed's tensors.
+_LATE = ("wgate", "post_attn_norm", "post_mlp_norm", "dense_gate", "dense_up", "dense_down", "shared_gate", "shared_up", "shared_down")
+
+
+def _kind_shapes(cfg: TrunkConfig, kind: str, n: int) -> Dict[str, Tuple[int, ...]]:
+    """The tensors that ``n`` stacked sublayers of ``kind`` own, by name."""
+    h, inner, kv_inner = cfg.hidden, cfg.heads * cfg.head_dim, (cfg.kv_heads or cfg.heads) * cfg.head_dim
+    if kind == "attention":
         norms = {"q_norm": (n, cfg.head_dim), "k_norm": (n, cfg.head_dim)} if cfg.qk_norm else {}
-        attention = {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner), **norms, "wo": (n, inner, h)}
-    else:  # columns in the order ``_attention`` reads them
+        gate = {"wgate": (n, h, inner)} if cfg.gated_attention else {}
+        return {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv": (n, h, kv_inner), **norms, "wo": (n, inner, h), **gate}
+    if kind == "latent":  # columns in the order ``_latent_attention`` reads them
         rank, nope, rope, value = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        attention = {"wq": (n, h, cfg.heads * (nope + rope)), "wkv_a": (n, h, rank + rope), "kv_norm": (n, rank),
-                     "wkv_b": (n, rank, cfg.heads * (nope + value)), "wo": (n, cfg.heads * value, h)}
-    if cfg.pattern:  # one norm a layer; the tensors of a kind stacked over the layers of that kind, none where the pattern has none
-        m, mixer, state = cfg.pattern.count("M"), cfg.mamba_heads * cfg.mamba_head_dim, cfg.mamba_groups * cfg.state_size
-        mamba = {"mamba_in": (m, h, 2 * mixer + 2 * state + cfg.mamba_heads), "conv_w": (m, mixer + 2 * state, cfg.conv_kernel),
-                 "conv_b": (m, mixer + 2 * state), "dt_bias": (m, cfg.mamba_heads), "A_log": (m, cfg.mamba_heads),
-                 "D_skip": (m, cfg.mamba_heads), "mamba_norm": (m, mixer), "mamba_out": (m, mixer, h)}
-        layers = {"layer_norm": (cfg.layers, h), **(mamba if m else {}), **(attention if n else {})}
+        return {"wq": (n, h, cfg.heads * (nope + rope)), "wkv_a": (n, h, rank + rope), "kv_norm": (n, rank),
+                "wkv_b": (n, rank, cfg.heads * (nope + value)), "wo": (n, cfg.heads * value, h)}
+    if kind == "cca":  # queries in ``inner`` columns, keys and values in ``kv_inner``; a value head's halves from two projections
+        mixed, groups = inner + kv_inner, cfg.heads + cfg.kv_heads
+        return {"wq": (n, h, inner), "wk": (n, h, kv_inner), "wv1": (n, h, kv_inner // 2), "wv2": (n, h, kv_inner // 2),
+                "conv0_w": (n, mixed, cfg.cca[0]), "conv0_b": (n, mixed),
+                "conv1_w": (n, groups, cfg.cca[1], cfg.head_dim, cfg.head_dim), "conv1_b": (n, mixed),
+                "temp": (n, cfg.kv_heads), "wo": (n, inner, h)}
+    if kind == "mamba":
+        mixer, state = cfg.mamba_heads * cfg.mamba_head_dim, cfg.mamba_groups * cfg.state_size
+        return {"mamba_in": (n, h, 2 * mixer + 2 * state + cfg.mamba_heads), "conv_w": (n, mixer + 2 * state, cfg.conv_kernel),
+                "conv_b": (n, mixer + 2 * state), "dt_bias": (n, cfg.mamba_heads), "A_log": (n, cfg.mamba_heads),
+                "D_skip": (n, cfg.mamba_heads), "mamba_norm": (n, mixer), "mamba_out": (n, mixer, h)}
+    ffn = lambda name, width, *held: {f"{name}_gate": (n, *held, h, width), f"{name}_up": (n, *held, h, width), f"{name}_down": (n, *held, width, h)}
+    if kind == "dense":
+        shapes = ffn("dense", cfg.dense_width)
     else:
-        layers = {"attn_norm": (n, h), **attention, "moe_norm": (n, h)}
-    rh = cfg.router_hidden
-    router = {"router_w": (r, h, cfg.experts)} if not rh else {
-        "router_down": (r, h, rh), "router_down_b": (r, rh), "router_w1": (r, rh, rh), "router_w1_b": (r, rh),
-        "router_w2": (r, rh, rh), "router_w2_b": (r, rh), "router_w3": (r, rh, cfg.experts)}
-    shapes = {
-        "embed_w": (INPUT_PLANES, h), "embed_b": (h,),
-        **layers, **router,
-        "experts_gate": (r, held, h, w), "experts_up": (r, held, h, w), "experts_down": (r, held, w, h),
+        rh = cfg.router_hidden
+        router = {"router_w": (n, h, cfg.experts)} if not rh else {
+            "router_down": (n, h, rh), "router_down_b": (n, rh), "router_w1": (n, rh, rh), "router_w1_b": (n, rh),
+            "router_w2": (n, rh, rh), "router_w2_b": (n, rh), "router_w3": (n, rh, cfg.experts)}
+        shapes = {**router, **ffn("experts", cfg.expert_width, cfg.held[1]), **(ffn("shared", cfg.shared_width) if cfg.shared_width else {})}
+    return {name: shape for name, shape in shapes.items() if cfg.gated_ffn or not name.endswith("_gate")}
+
+
+def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every trained tensor of a trunk checkpoint by name: the plan's
+    norms and what each kind in it owns, the tensors of a kind stacked
+    over the sublayers of that kind, none where the plan has none. The
+    ORDER of the keys is part of the result (``_LATE``)."""
+    plan, h = trunk_plan(cfg), cfg.hidden
+    shapes = {"embed_w": (INPUT_PLANES, h), "embed_b": (h,)}
+    for kinds in (_MIXERS, _FEED_FORWARDS):  # the token mixers' norm and tensors, then the feed-forwards'
+        shapes.update({s.norm: (cfg.layers, h) for s in plan if s.kind in kinds and s.norm not in shapes})
+        for kind in kinds:
+            n = sum(s.kind == kind for s in plan)
+            own = _kind_shapes(cfg, kind, n) if n else {}
+            assert set(own) <= set(_OWNS[kind]), (kind, set(own) - set(_OWNS[kind]))
+            shapes.update(own)
+    if cfg.post_norms:
+        shapes.update(post_attn_norm=(cfg.layers, h), post_mlp_norm=(cfg.layers, h))
+    shapes.update({
         "final_norm": (h,),
         "policy_w": (1, 1, h, cfg.policy_planes), "policy_b": (cfg.policy_planes,),
         "value_w": (1, 1, h, 4), "value_b": (4,),
         "value_fc1_w": (4 * SQUARES, cfg.value_hidden), "value_fc1_b": (cfg.value_hidden,),
         "value_fc2_w": (cfg.value_hidden, 1), "value_fc2_b": (1,),
-    }
-    if cfg.gated_attention:
-        shapes["wgate"] = (n, h, inner)
-    if cfg.post_norms:
-        shapes.update(post_attn_norm=(n, h), post_mlp_norm=(n, h))
-    for kind, count, width in (("dense", cfg.dense_layers, cfg.dense_width), ("shared", r, cfg.shared_width)):
-        if width:
-            shapes.update({f"{kind}_gate": (count, h, width), f"{kind}_up": (count, h, width), f"{kind}_down": (count, width, h)})
-    if not cfg.gated_ffn:
-        shapes = {name: shape for name, shape in shapes.items() if not name.endswith("_gate")}
-    return shapes
+    })
+    return {**{name: shape for name, shape in shapes.items() if name not in _LATE}, **{name: shapes[name] for name in _LATE if name in shapes}}
 
 
 #: The router MLP's matrices of fan-in ``router_hidden``: initialised by it, not at ``_INIT_STD``.
@@ -522,13 +568,10 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     biases and the router MLP's zero, the key temperature ``temp`` 1. The
     router MLP's matrices behind its down-projection (``router_w1``,
     ``router_w2``, ``router_w3``) are normal(0, 1 / fan-in): at 0.02 each
-    of the three 256-wide layers shrinks what it is given to a sixth, a
-    fresh router's logits have a spread of 0.011 where a one-product
-    router's at 0.02 over 2048 columns have 0.9, every token's scores lie
-    within 0.0002 of each other, and the balance rule's 0.001 a step then
-    moves ALL of a layer's tokens from expert to expert every step
-    (PERF.md section 6, PR 43: a whole layer's tokens on one expert, the
-    held rows of a step anywhere between an eighth and seven eighths)."""
+    of the three 256-wide layers shrinks what it is given to a sixth, all
+    of a token's scores lie within 0.0002 of each other, and the balance
+    rule's 0.001 a step then moves ALL of a layer's tokens from expert to
+    expert every step (PERF.md section 6, PR 43)."""
     shapes = trunk_param_shapes(cfg)
     keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
     uniform = lambda name, low, high: jax.random.uniform(keys[name], shapes[name], jnp.float32, low, high)
@@ -672,28 +715,33 @@ def _ffn(n: jax.Array, p: Params, kind: str, gated: bool) -> jax.Array:
     return _gated_ffn(n, p, kind) if gated else _matmul(jnp.square(jax.nn.relu(_matmul(n, p[f"{kind}_up"]))), p[f"{kind}_down"])
 
 
-def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """[tokens, hidden] float32, 64 tokens a board -> a Mamba-2 mixer's
-    output, same shape, and its two counters (the mean step; the
-    smallest of the heads' decays across a board, mean over boards). The
-    projections and softplus are XLA's under ``<layer>.mamba``, and so
-    are the two kernel pairs between them and the scan
-    (``ops/mamba_mix.py``: the convolution with its silu, which writes the
-    scan's three bfloat16 operands; the gate with its grouped norm, which
-    writes what the out-projection reads); the scan's core is
-    ``board_scan`` under ``<layer>.scan`` beside it, never inside (call
-    this under none of a layer's scopes). ``mamba_in``'s columns are the
-    published ``[z | x | B | C | dt]``, split on the weights' side as the
-    latent projections are."""
-    heads, groups = cfg.mamba_heads, cfg.mamba_groups
+def _dense_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The dense kind: the gated feed-forward every token passes, under ``<layer>.dense``; it counts nothing."""
+    with jax.named_scope(f"{sublayer.layer}.dense"):
+        return _gated_ffn(_rms_norm(x, p[sublayer.norm], cfg.rms_eps), p, "dense"), {}
+
+
+def _by_board(y: jax.Array) -> jax.Array:
+    """``[tokens, columns]`` as the kernels take it, ``[boards, 64, columns]``."""
+    return y.reshape(-1, SQUARES, y.shape[-1])
+
+
+def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The mamba kind: [tokens, hidden] float32, 64 tokens a board -> a
+    Mamba-2 mixer's output, same shape, and its two counters. All of it
+    runs under ``<layer>.mamba`` but the scan's core (``board_scan``),
+    under ``<layer>.scan`` beside it, never inside (module docstring,
+    "Mechanism, the mixer"). ``mamba_in``'s columns are the published
+    ``[z | x | B | C | dt]``, split on the weights' side as the latent
+    projections are."""
+    heads, groups, layer = cfg.mamba_heads, cfg.mamba_groups, sublayer.layer
     inner, state = heads * cfg.mamba_head_dim, groups * cfg.state_size
-    by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
     with jax.named_scope(f"{layer}.mamba"):
-        n = _rms_norm(x, p["layer_norm"], cfg.rms_eps)
+        n = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
         w = p["mamba_in"]
         z, xbc, dt = _matmul(n, w[:, :inner]), _matmul(n, w[:, inner:2 * inner + 2 * state]), _matmul(n, w[:, 2 * inner + 2 * state:])
-        xs, bs, cs = mamba_conv(by_board(xbc), p["conv_w"], p["conv_b"], (inner, state, state), _interpret())
-        step = jax.nn.softplus(by_board(dt) + p["dt_bias"])  # time_step_limit (0, inf) clips nothing
+        xs, bs, cs = mamba_conv(_by_board(xbc), p["conv_w"], p["conv_b"], (inner, state, state), _interpret())
+        step = jax.nn.softplus(_by_board(dt) + p["dt_bias"])  # time_step_limit (0, inf) clips nothing
         rate = -jnp.exp(p["A_log"])
         counted, decay = jax.lax.stop_gradient((step, rate))
         across = jnp.exp(jnp.sum(counted[:, 1:] * decay, axis=1))  # exp(c_63 - c_0) [boards, heads]
@@ -703,25 +751,6 @@ def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[jax.A
     with jax.named_scope(f"{layer}.mamba"):
         y = mamba_gate_norm(y.reshape(x.shape[0], inner), z, p["mamba_norm"], groups, cfg.rms_eps, _interpret())
         return _matmul(y, p["mamba_out"]), counters
-
-
-def _cca_mix(x: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The fifth block's mix of queries and keys, between their
-    projections and the core, one Pallas kernel pair
-    (``ops/cca_mix.py``: ``cca_mix``, ``cca_mix_grad``): ``x`` [boards,
-    64, (heads + kv_heads) x head_dim] float32, ``[q~ | k~]`` as the
-    joined product writes them, -> q [boards, 64, heads x head_dim], k
-    [boards, 64, kv_heads x head_dim], float32, and the mean square of
-    what the convolutions changed, ``c - x``, over that of ``x`` (no
-    gradient)::
-
-        a = conv0(x)     depthwise along the squares (as the fourth block's ``mamba_conv``), float32
-        c = conv1(a)     a head at a time (heads + kv_heads groups of head_dim columns): c[t, g] = b1[g] + sum_k a[t - (taps - 1) + k, g] W1[g, k],
-                         each tap a [head_dim, head_dim] matrix, bfloat16 operands, float32 accumulation; nothing before square 0
-        q[h] = c_q[h] + (x_q[h] + x_k[h // group]) / 2;   k[g] = c_k[g] + (mean over the group's heads of x_q + x_k[g]) / 2
-    """
-    q, k, sums = cca_mix(x, p["conv0_w"], p["conv0_b"], p["conv1_w"], p["conv1_b"], cfg.heads, cfg.kv_heads, _interpret())
-    return q, k, sums[0] / sums[1]
 
 
 def _shifted_values(v12: jax.Array, kv_heads: int) -> jax.Array:
@@ -740,69 +769,78 @@ def _shifted_values(v12: jax.Array, kv_heads: int) -> jax.Array:
     return jnp.concatenate(halves, axis=-1).reshape(boards, SQUARES, width).astype(jnp.bfloat16)
 
 
-def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, rope: bool = True, layer: str = "layer00"):
-    """[tokens, hidden] float32, 64 tokens a board -> the attention
-    branch's output, same shape, and the latent's root mean square (None
-    without a latent; with the fifth block's mix its two counters by
-    name). The projections are XLA's; everything between
-    them is ``board_attention``. Enters its own scopes (call it under
-    none of a layer's): ``<layer>.attention``, and for the way from the
-    normed stream through the latent to the keys and values
-    ``<layer>.latent`` beside it, so that the two add up to the branch;
-    likewise ``<layer>.cca`` for the fifth block's mix of queries and
-    keys and the move of its values."""
-    by_board = lambda y: y.reshape(-1, SQUARES, y.shape[-1])
-    with jax.named_scope(f"{layer}.attention"):
-        n1 = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-    if cfg.cca is not None:
-        # Two joined products, each one read of the normed stream: [W_q | W_k], whose result the mix takes as it is, and [W_v1 | W_v2].
-        with jax.named_scope(f"{layer}.attention"):
-            qk = by_board(_matmul(n1, jnp.concatenate([p["wq"], p["wk"]], axis=1)))
-            v12 = by_board(_matmul(n1, jnp.concatenate([p["wv1"], p["wv2"]], axis=1)))
-        with jax.named_scope(f"{layer}.cca"):
-            q, k, changed = _cca_mix(qk, p, cfg)
-            v = _shifted_values(v12, cfg.kv_heads)
-        with jax.named_scope(f"{layer}.attention"):
-            temp = jnp.broadcast_to(p["temp"][:, None], (cfg.kv_heads, cfg.head_dim))  # a gain a key-value head, the same on its columns
-            mixed = board_attention(q, k, v, None, temp, cfg.rope_theta, cfg.rms_eps, _interpret(), rotary_dim=cfg.rotary_dim)
-            counters = {"cca_conv_share": jnp.sqrt(changed), "cca_temp_max": jnp.max(jax.lax.stop_gradient(p["temp"]))}
-            return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), counters
-    if cfg.kv_lora_rank is not None:
-        # Columns (module docstring): wq every head's NoPE part, then every head's RoPE part; wkv_a the latent, then the
-        # RoPE key; wkv_b every head's key, then every head's value. Split on the weights' side, where a slice costs
-        # nothing (it joins the bfloat16 cast): the kernels' operands are then the products' results as they are.
-        split, rank = cfg.heads * cfg.qk_nope_head_dim, cfg.kv_lora_rank
-        with jax.named_scope(f"{layer}.attention"):
-            q, q_pe = _matmul(n1, p["wq"][:, :split]), _matmul(n1, p["wq"][:, split:])
-        with jax.named_scope(f"{layer}.latent"):
-            ckv, k_pe = _matmul(n1, p["wkv_a"][:, :rank]), _matmul(n1, p["wkv_a"][:, rank:])
-            latent_rms = jnp.sqrt(jnp.mean(jax.lax.stop_gradient(ckv) ** 2))
-            c = _rms_norm(ckv, p["kv_norm"], cfg.rms_eps)
-            k, v = _matmul(c, p["wkv_b"][:, :split]), _matmul(c, p["wkv_b"][:, split:]).astype(jnp.bfloat16)
-        with jax.named_scope(f"{layer}.attention"):
-            mixed = board_attention(by_board(q), by_board(k), by_board(v), None, None, cfg.rope_theta, cfg.rms_eps, _interpret(),
-                                    q_pe=by_board(q_pe), k_pe=by_board(k_pe))
-            return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), latent_rms
-    with jax.named_scope(f"{layer}.attention"):
-        q, k, v = (by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
+def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The attention kind (the first, second and fourth blocks'):
+    [tokens, hidden] float32, 64 tokens a board -> the branch's output,
+    same shape, before its post-norm; it counts nothing. The projections
+    are XLA's; everything between them is ``board_attention``. As every
+    kind's function it enters its own scopes (call it under none of a
+    layer's): ``<layer>.attention``."""
+    with jax.named_scope(f"{sublayer.layer}.attention"):
+        n1 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+        q, k, v = (_by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
         gains = dict(g_q=p["q_norm"], g_k=p["k_norm"]) if cfg.qk_norm else dict(g_q=None, g_k=None, head_dim=cfg.head_dim)
-        mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if rope else None, eps=cfg.rms_eps, interpret=_interpret(),
-                                rotary_dim=cfg.rotary_dim, **gains)
+        mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if sublayer.rope else None, eps=cfg.rms_eps,
+                                interpret=_interpret(), rotary_dim=cfg.rotary_dim, **gains)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
             mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
-        out = _matmul(mixed, p["wo"])
-        return (_rms_norm(out, p["post_attn_norm"], cfg.rms_eps) if cfg.post_norms else out), None
+        return _matmul(mixed, p["wo"]), {}
+
+
+def _latent_attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The latent kind (the third block's), as ``_attention``, and the
+    latent's root mean square before its norm. The way from the normed
+    stream through the latent to the keys and values runs under
+    ``<layer>.latent`` beside ``<layer>.attention``, so that the two add
+    up to the branch. The columns (module docstring) are split on the
+    weights' side, where a slice costs nothing (it joins the bfloat16
+    cast): the kernels' operands are the products' results as they are."""
+    layer, split, rank = sublayer.layer, cfg.heads * cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope(f"{layer}.attention"):
+        n1 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+        q, q_pe = _matmul(n1, p["wq"][:, :split]), _matmul(n1, p["wq"][:, split:])
+    with jax.named_scope(f"{layer}.latent"):
+        ckv, k_pe = _matmul(n1, p["wkv_a"][:, :rank]), _matmul(n1, p["wkv_a"][:, rank:])
+        latent_rms = jnp.sqrt(jnp.mean(jax.lax.stop_gradient(ckv) ** 2))
+        c = _rms_norm(ckv, p["kv_norm"], cfg.rms_eps)
+        k, v = _matmul(c, p["wkv_b"][:, :split]), _matmul(c, p["wkv_b"][:, split:]).astype(jnp.bfloat16)
+    with jax.named_scope(f"{layer}.attention"):
+        mixed = board_attention(_by_board(q), _by_board(k), _by_board(v), None, None, cfg.rope_theta, cfg.rms_eps, _interpret(),
+                                q_pe=_by_board(q_pe), k_pe=_by_board(k_pe))
+        return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), {"latent_rms": latent_rms}
+
+
+def _cca_attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The cca kind (the fifth block's), as ``_attention``, and the mix's
+    two counters. Two joined products, each one read of the normed
+    stream: [W_q | W_k], whose result ``[boards, 64, (heads + kv_heads) x
+    head_dim]`` the mix takes as it is, and [W_v1 | W_v2]. The mix of
+    queries and keys (``ops/cca_mix.py``, one kernel pair: conv0
+    depthwise along the squares, conv1 a head at a time with bfloat16
+    operands, the q-k mean; it also sums the squares of what the
+    convolutions changed and of their input, no gradient) and the move of
+    the values run under ``<layer>.cca`` beside ``<layer>.attention``."""
+    layer = sublayer.layer
+    with jax.named_scope(f"{layer}.attention"):
+        n1 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+        qk = _by_board(_matmul(n1, jnp.concatenate([p["wq"], p["wk"]], axis=1)))
+        v12 = _by_board(_matmul(n1, jnp.concatenate([p["wv1"], p["wv2"]], axis=1)))
+    with jax.named_scope(f"{layer}.cca"):
+        q, k, sums = cca_mix(qk, p["conv0_w"], p["conv0_b"], p["conv1_w"], p["conv1_b"], cfg.heads, cfg.kv_heads, _interpret())
+        changed = sums[0] / sums[1]  # the mean square of what the convolutions changed over that of what they were given
+        v = _shifted_values(v12, cfg.kv_heads)
+    with jax.named_scope(f"{layer}.attention"):
+        temp = jnp.broadcast_to(p["temp"][:, None], (cfg.kv_heads, cfg.head_dim))  # a gain a key-value head, the same on its columns
+        mixed = board_attention(q, k, v, None, temp, cfg.rope_theta, cfg.rms_eps, _interpret(), rotary_dim=cfg.rotary_dim)
+        counters = {"cca_conv_share": jnp.sqrt(changed), "cca_temp_max": jnp.max(jax.lax.stop_gradient(p["temp"]))}
+        return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), counters
 
 
 def _interpret() -> bool:
-    """The trunk's Pallas kernels (the attention core, the grouped product,
-    the gate pair, the two row moves and a share's sum over a token's
-    slots; the fourth block's scan pair and its mixer's convolution and
-    gate-norm pairs, ``ops/board_scan.py`` and ``ops/mamba_mix.py``; the
-    fifth block's mix pair, ``ops/cca_mix.py``) are one path everywhere:
-    compiled by Mosaic on a TPU, run by the Pallas interpreter elsewhere
-    (the CPU of the tests), never another path."""
+    """The trunk's Pallas kernels are one path everywhere: compiled by
+    Mosaic on a TPU, run by the Pallas interpreter elsewhere (the CPU of
+    the tests), never another path."""
     return jax.default_backend() != "tpu"
 
 
@@ -1095,6 +1133,11 @@ def _route(n2: jax.Array, p: Params, cfg: TrunkConfig) -> Tuple[jax.Array, jax.A
     return expert, weight, probs
 
 
+#: The leaves a Pallas kernel reads as they are held: the operands of the grouped products (``_expert_ffn``). The trainer keeps
+#: their update in the layout the client holds them in (``train/az_trainer.py held_layouts``).
+KERNEL_OPERANDS = ("experts_gate", "experts_up", "experts_down")
+
+
 def _expert_ffn(rows: jax.Array, gate_w: Optional[jax.Array], up_w: jax.Array, down_w: jax.Array, group_sizes: jax.Array,
                 extent: Optional[jax.Array]) -> jax.Array:
     """The held experts on the sorted rows, two grouped products round
@@ -1221,143 +1264,109 @@ def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkCon
     return trunk_forward_counted(params, planes, cfg)[:2]
 
 
-_EVERY_LAYER = ("attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wkv_a", "kv_norm", "wkv_b", "wo", "moe_norm", "wgate", "post_attn_norm",
-                "post_mlp_norm", "wv1", "wv2", "conv0_w", "conv0_b", "conv1_w", "conv1_b", "temp")
-_ROUTER_MLP = ("router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3")
-_ROUTED = ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias", *_ROUTER_MLP)
-_DENSE = ("dense_gate", "dense_up", "dense_down")
-#: A pattern's tensors by the kind of layer that owns them (``layer_norm`` is every layer's).
-_BY_KIND = {"M": ("mamba_in", "conv_w", "conv_b", "dt_bias", "A_log", "D_skip", "mamba_norm", "mamba_out"),
-            "*": ("wq", "wk", "wv", "q_norm", "k_norm", "wo"), "E": _ROUTED}
-
-
-def _routed_layer(x: jax.Array, norm: jax.Array, layer: Params, cfg: TrunkConfig, name: str, post) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """A routed feed-forward added to the stream (the norm under the
-    router's scope, the held experts, the shared expert, the residual
-    under the combine's), and the layer's routing counters."""
+def _routed_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The routed kind: the norm under the router's scope, the held
+    experts, the shared expert beside them; the layer's routing
+    counters. Its residual is added under the combine's scope."""
+    name = sublayer.layer
     with jax.named_scope(f"{name}.router"):
-        n2 = _rms_norm(x, norm, cfg.rms_eps)
-    mixed, layer_counters = _experts(n2, layer, cfg, name)
+        n2 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+    mixed, counters = _experts(n2, p, cfg, name)
     if cfg.shared_width:
         with jax.named_scope(f"{name}.shared"):
-            mixed = mixed + _ffn(n2, layer, "shared", cfg.gated_ffn)
-    with jax.named_scope(f"{name}.combine"):
-        return x + post(mixed), layer_counters
+            mixed = mixed + _ffn(n2, p, "shared", cfg.gated_ffn)
+    return mixed, counters
 
 
-def _block_layers(params: Params, x: jax.Array, cfg: TrunkConfig):
-    """The first three blocks' layers: attention, then a dense or a
-    routed feed-forward. Returns the stream, the routed layers' counters
-    and each layer's latent's root mean square (None without a latent;
-    the fifth block's mix's counters in its place)."""
-    counters, latent = [], []
-    for i in range(cfg.layers):
-        # Layers of a kind are stacked: a routed layer's tensors are indexed from the first routed layer.
-        routed = i - cfg.dense_layers
-        own, index = (_ROUTED, routed) if routed >= 0 else (_DENSE, i)
-        layer = {name: params[name][i] for name in _EVERY_LAYER if name in params}
-        layer.update({name: params[name][index] for name in own if name in params})
-        post = (lambda y: _rms_norm(y, layer["post_mlp_norm"], cfg.rms_eps)) if cfg.post_norms else (lambda y: y)
-        # One scope a part, the layer in its name: the benchmark's scope
-        # table keeps two levels of a path (phase, then this).
-        name = f"layer{i:02d}"
-        branch, latent_rms = _attention(x, layer, cfg, rope=i not in cfg.nope_layers, layer=name)
-        with jax.named_scope(f"{name}.attention"):
-            x = x + branch
-        latent.append(latent_rms)
-        if routed < 0:
-            with jax.named_scope(f"{name}.dense"):
-                x = x + post(_gated_ffn(_rms_norm(x, layer["moe_norm"], cfg.rms_eps), layer, "dense"))
-            continue
-        x, layer_counters = _routed_layer(x, layer["moe_norm"], layer, cfg, name, post)
-        counters.append(layer_counters)
-    return x, counters, latent
+#: Kind of sublayer -> its function, ``(x, p, cfg, sublayer) -> (branch, counters by name)``, which opens its own scopes and is
+#: called under none, and the scope under which the loop adds the branch (through the sublayer's post-norm) to the stream.
+_KINDS = {"attention": (_attention, "attention"), "latent": (_latent_attention, "attention"), "cca": (_cca_attention, "attention"),
+          "mamba": (_mamba, "mamba"), "dense": (_dense_layer, "dense"), "routed": (_routed_layer, "combine")}
+
+#: The order in which ONE layer's slices are made: nothing but the lowered text depends on it, and the step pins hold that text
+#: (``tests/test_hybrid_trunk.py PARENT_STEP_SHA256``). It is the order in which the blocks came: a block layer's feed-forward
+#: norm after its mixer's ``wo`` and before what the second and the fifth block brought, a pattern's norm last.
+_SLICE_ORDER = tuple(dict.fromkeys((
+    "attn_norm", "wq", "wk", "wv", "q_norm", "k_norm", "wkv_a", "kv_norm", "wkv_b", "wo", "moe_norm", "wgate", "post_attn_norm", "post_mlp_norm",
+    *(name for names in _OWNS.values() for name in names), "layer_norm")))
 
 
-def _pattern_layers(params: Params, x: jax.Array, cfg: TrunkConfig):
-    """The fourth block's layers: ONE sublayer under ONE norm each, of
-    the kind the pattern names, its tensors indexed from the first layer
-    of its kind. Returns the stream, the routed layers' counters and the
-    mixers'."""
-    counters, mixers, seen = [], [], dict.fromkeys(_BY_KIND, 0)
-    for i, kind in enumerate(cfg.pattern):
-        layer = {name: params[name][seen[kind]] for name in _BY_KIND[kind] if name in params}
-        seen[kind] += 1
-        name, norm = f"layer{i:02d}", params["layer_norm"][i]
-        if kind == "E":
-            x, layer_counters = _routed_layer(x, norm, layer, cfg, name, lambda y: y)
-            counters.append(layer_counters)
-            continue
-        if kind == "M":
-            branch, mixer_counters = _mamba(x, {**layer, "layer_norm": norm}, cfg, name)
-            mixers.append(mixer_counters)
-        else:
-            branch, _ = _attention(x, {**layer, "attn_norm": norm}, cfg, layer=name)
-        with jax.named_scope(f"{name}.{'mamba' if kind == 'M' else 'attention'}"):
-            x = x + branch
-    return x, counters, mixers
+def _reads(sublayer: Sublayer) -> Dict[str, int]:
+    """Everything a sublayer reads, tensor -> row: its norms and what its kind owns."""
+    norms = {name: sublayer.norm_index for name in (sublayer.norm, sublayer.post_norm) if name}
+    return {**norms, **dict.fromkeys(_OWNS[sublayer.kind], sublayer.index)}
+
+
+def _sliced(params: Params, plan: Tuple[Sublayer, ...]):
+    """Each sublayer of ``plan`` with its own tensors out of the stacked
+    ``params``, by their names (those the checkpoint has); the slices of
+    one layer's sublayers are made together, when its first is reached,
+    in ``_SLICE_ORDER`` (no two sublayers of a layer read one tensor)."""
+    for _, layer in itertools.groupby(plan, key=lambda sublayer: sublayer.layer):
+        layer = tuple(layer)
+        rows = {name: row for sublayer in layer for name, row in _reads(sublayer).items() if name in params}
+        own = {name: params[name][rows[name]] for name in _SLICE_ORDER if name in rows}
+        yield from ((sublayer, {name: own[name] for name in _reads(sublayer) if name in own}) for sublayer in layer)
+
+
+def sublayer_params(params: Params, sublayer: Sublayer) -> Params:
+    """One sublayer's own tensors out of the stacked ``params``."""
+    return next(_sliced(params, (sublayer,)))[1]
 
 
 def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
-    """``trunk_forward`` and the routing counters of the step's metrics:
-    the most and the fewest slots any expert of any routed layer received
-    (``expert_load_max``, ``expert_load_min``), the router's mean entropy
-    in nats (``router_entropy``: of the softmax, or of the sigmoid scores
-    over their sum) and every routed layer's slots an expert
-    (``expert_slots`` [routed layers, experts], what the balance update
-    reads); the rows each of a routed layer's moves covers, summed over
-    the layers (``moved_rows``: every slot where all experts are held; a
-    share's held count a layer, rounded up to whole blocks of the move);
-    for a share, the slots that fell on the held experts, summed
-    over the layers (``held_slots``); with an ``expert_bias`` among
-    ``params``, its largest magnitude (``expert_bias_abs_max``); with a
-    latent, the root mean square of the key-value latent before its norm,
-    over the tokens, mean over the layers (``latent_rms``: a latent that
-    collapses or blows up shows here before the loss does); with Mamba-2
-    mixers, the mean step ``D_t`` after its softplus, over tokens, heads
-    and mixers (``ssm_dt_mean``), and the smallest decay across a board
-    ``exp(c_63 - c_0)`` of any head of any mixer, mean over the boards
-    (``ssm_decay_min``: a head that forgets a board within it shows here
-    before the loss does); with the fifth block's mix, the root mean
-    square of what the two convolutions changed, ``c - [q~ | k~]``, over
-    that of ``[q~ | k~]``, mean over the layers (``cca_conv_share``: 0.0017,
-    the rounding of conv1's bfloat16 operand, while the convolutions still
-    pass their input, so a mixing path that is dead or has taken over
-    shows before the loss does) and the largest
-    key temperature of any head of any layer (``cca_temp_max``); at one
-    expert a token, the mean combine weight, the chosen expert's score,
-    over tokens and layers (``route_top1_weight``: at 1.0 the router has
-    no gradient left, at 1 / experts it has not chosen)."""
+    """``trunk_forward`` and the counters of the step's metrics: what
+    the sublayers counted, folded over the sublayers as ``_FOLDS`` says
+    (which also says what each one is), and the whole trunk's own three."""
     b = planes.shape[0]
-    # Scope names are a contract (doc/observability.md "Training and compilation").
+    # Scope names are a contract (doc/observability.md "Training and compilation"): one scope a part, the layer in its name,
+    # because the benchmark's scope table keeps two levels of a path (phase, then this).
     with jax.named_scope("embed"):
         x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
         x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
-    latent, mixers = [], []
-    if cfg.pattern:
-        x, counters, mixers = _pattern_layers(params, x, cfg)
-    else:
-        x, counters, latent = _block_layers(params, x, cfg)
+    counters = []
+    for sublayer, p in _sliced(params, trunk_plan(cfg)):
+        run, scope = _KINDS[sublayer.kind]
+        branch, counted = run(x, p, cfg, sublayer)
+        with jax.named_scope(f"{sublayer.layer}.{scope}"):
+            x = x + (_rms_norm(branch, p[sublayer.post_norm], cfg.rms_eps) if sublayer.post_norm else branch)
+        counters.append(counted)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
-    slots = jnp.stack([c["expert_slots"] for c in counters])
-    first, count = cfg.held
-    return (*policy_value_heads(params, features), {
-        "expert_load_max": jnp.max(jnp.stack([c["expert_load_max"] for c in counters])),
-        "expert_load_min": jnp.min(jnp.stack([c["expert_load_min"] for c in counters])),
-        "router_entropy": jnp.mean(jnp.stack([c["router_entropy"] for c in counters])),
-        "expert_slots": slots,
-        "moved_rows": jnp.sum(jnp.stack([c["moved_rows"] for c in counters])),
-        **({"held_slots": jnp.sum(slots[:, first:first + count])} if cfg.held_experts else {}),
-        **({"expert_bias_abs_max": jnp.max(jnp.abs(params["expert_bias"]))} if "expert_bias" in params else {}),
-        **({"latent_rms": jnp.mean(jnp.stack(latent))} if cfg.kv_lora_rank is not None else {}),
-        **({"cca_conv_share": jnp.mean(jnp.stack([c["cca_conv_share"] for c in latent])),
-            "cca_temp_max": jnp.max(jnp.stack([c["cca_temp_max"] for c in latent]))} if cfg.cca is not None else {}),
-        **({"route_top1_weight": jnp.mean(jnp.stack([c["route_top1_weight"] for c in counters]))} if cfg.experts_per_token == 1 else {}),
-        **({"ssm_dt_mean": jnp.mean(jnp.stack([c["ssm_dt_mean"] for c in mixers])),
-            "ssm_decay_min": jnp.min(jnp.stack([c["ssm_decay_min"] for c in mixers]))} if mixers else {}),
-    })
+    slots = jnp.stack([c["expert_slots"] for c in counters if "expert_slots" in c])
+    heads = policy_value_heads(params, features)
+    folded = {}
+    for name, fold in _FOLDS.items():  # the whole trunk's own three are made where the result's key order has them
+        values = [c[name] for c in counters if name in c]
+        if fold and values:
+            folded[name] = fold(jnp.stack(values))
+        elif name == "expert_slots":
+            folded[name] = slots
+        elif name == "held_slots" and cfg.held_experts:
+            folded[name] = jnp.sum(slots[:, cfg.held[0]:sum(cfg.held)])
+        elif name == "expert_bias_abs_max" and "expert_bias" in params:
+            folded[name] = jnp.max(jnp.abs(params["expert_bias"]))
+    return (*heads, folded)
+
+
+#: The trunk's counters in the result's key order, and how each folds over the sublayers that return it (``None``: the trunk's own).
+_FOLDS = {
+    "expert_load_max": jnp.max,  # the most and ...
+    "expert_load_min": jnp.min,  # ... the fewest slots any expert of any routed layer received
+    "router_entropy": jnp.mean,  # nats: of the softmax, or of the sigmoid scores over their sum
+    "expert_slots": None,  # every routed layer's slots an expert, held or not, [routed layers, experts]: what the balance update reads
+    "moved_rows": jnp.sum,  # the rows each of a routed layer's moves covers: every slot, or a share's held count rounded up to whole blocks
+    "held_slots": None,  # a share's: the slots that fell on the held experts, summed over the layers
+    "expert_bias_abs_max": None,  # with an ``expert_bias`` among the params
+    "latent_rms": jnp.mean,  # the root mean square of the key-value latent before its norm: a latent that collapses or blows up
+    "cca_conv_share": jnp.mean,  # the root mean square of what the two convolutions changed, ``c - [q~ | k~]``, over that of ``[q~ | k~]``: 0.0017 (the
+    # rounding of conv1's bfloat16 operand) while they still pass their input; a mixing path that is dead or has taken over shows here
+    "cca_temp_max": jnp.max,  # the largest key temperature of any head of any layer
+    "route_top1_weight": jnp.mean,  # at one expert a token, the chosen expert's score: at 1.0 the router has no gradient left, at 1 / experts it has not chosen
+    "ssm_dt_mean": jnp.mean,  # the mean step ``D_t`` after its softplus, over tokens, heads and mixers
+    "ssm_decay_min": jnp.min,  # the smallest decay across a board, ``exp(c_63 - c_0)``, of any head of any mixer, mean over boards: a head that forgets a board
+}
 
 
 def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
@@ -1382,17 +1391,15 @@ PATTERN = "trunk_pattern"
 
 def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     """The arrays of a trunk ``.npz``: the tensors (``expert_bias`` among
-    them where the state has one) and ``trunk_hparams``: experts_per_token,
-    rope_theta, rms_eps, then embed_scale, route_scale, balance_rate,
-    sliding_window (0: none), sigmoid scores (0 or 1), route_norm, the
-    first held expert (-1: all are held), the layers without RoPE as a
-    bit mask, and what no shape of the fourth block gives: head_dim
-    (there are no qk-norm gains to read it from) and mamba_groups, and
-    of the fifth block's rotary_dim (0: RoPE on all of a head; its kernel
-    sizes, head width and the router MLP's width are shapes). A
-    pattern's file carries the pattern too (``trunk_pattern``, its
-    characters as bytes). ``recompute_experts`` is the trainer's and in
-    no file."""
+    them where the state has one) and ``trunk_hparams`` (``_HPARAMS``
+    names its values): sliding_window 0 for none, sigmoid scores 0 or 1,
+    the first held expert (-1: all are held), the layers without RoPE as
+    a bit mask, what no shape of the fourth block gives (head_dim: there
+    are no qk-norm gains to read it from; mamba_groups) and the fifth's
+    rotary_dim (0: RoPE on all of a head; its kernel sizes, head width
+    and router width are shapes). A pattern's file carries the pattern
+    too (``trunk_pattern``, its characters as bytes).
+    ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
     arrays[HPARAMS] = np.asarray([
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
@@ -1404,14 +1411,52 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     return arrays
 
 
+def _attention_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the head width from the qk-norm's gains, or the file's
+    head_dim = shape("q_norm")[1] if "q_norm" in params else int(hp["head_dim"])
+    return dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, qk_norm="q_norm" in params,
+                kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
+
+
+def _latent_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # its four widths and the head count from five shapes
+    rank, score, key_value, value = shape("kv_norm")[1], shape("wq")[2], shape("wkv_b")[2], shape("wo")[1]
+    rope = shape("wkv_a")[2] - rank
+    heads = (score - key_value + value) // rope if rope > 0 else 0  # heads x (nope + rope) - heads x (nope + value) + heads x value
+    if heads < 1:
+        raise ValueError(f"trunk checkpoint: mismatched shapes: wkv_a {shape('wkv_a')} leaves no RoPE key beside a latent of {rank}, "
+                         f"or wq {shape('wq')}, wkv_b {shape('wkv_b')} and wo {shape('wo')} no head")
+    return dict(heads=heads, kv_lora_rank=rank, qk_rope_head_dim=rope, qk_nope_head_dim=(key_value - value) // heads, v_head_dim=value // heads)
+
+
+def _cca_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the head width from conv1's taps, the kernel sizes from both
+    head_dim = shape("conv1_w")[-1]
+    return dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, kv_heads=shape("wk")[2] // head_dim, cca=(shape("conv0_w")[2], shape("conv1_w")[2]))
+
+
+def _mamba_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the groups from the file, the rest from three shapes
+    heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
+    return dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
+                state_size=(channels - inner) // (2 * groups) if groups else 0)
+
+
+#: Token mixer -> the tensors of its row of ``_OWNS`` that its sizes are read from, and the reader (above) of its fields of
+#: ``TrunkConfig`` from those tensors' shapes (``shape``) and the file's values (``hp``).
+_SIZES = {"attention": (("wq", "wk", "wo"), _attention_sizes), "latent": (("kv_norm", "wkv_a", "wkv_b"), _latent_sizes),
+          "cca": (("conv0_w", "conv1_w", "wk", "wv1", "wv2", "temp"), _cca_sizes), "mamba": (("mamba_norm", "dt_bias", "conv_w"), _mamba_sizes)}
+assert all(set(names) <= set(_OWNS[kind]) for kind, (names, _) in _SIZES.items())
+
+
 def trunk_config_from_params(params: Params) -> TrunkConfig:
-    """The ``TrunkConfig`` of a checkpoint, from its shapes and its
-    ``trunk_hparams``; a ValueError names what does not fit."""
+    """The ``TrunkConfig`` of a checkpoint, from its shapes, its
+    ``trunk_hparams`` and, a pattern's, its ``trunk_pattern``; a
+    ValueError names what does not fit."""
+    pattern = bytes(np.asarray(params[PATTERN], np.uint8)).decode("ascii") if PATTERN in params else None
     router = "router_w3" if "router_down" in params else "router_w"  # the MLP router's last matrix: its columns are the experts
-    required = ((router, "experts_up", "layer_norm") if PATTERN in params else (router, "experts_gate", "wq", "wo", "attn_norm"))
+    norm = "attn_norm" if pattern is None else "layer_norm"
+    required = (router, "experts_gate", "wq", "wo", norm, "experts_up") if pattern is None else (router, "experts_up", norm)
+    not_one = lambda missing: f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}..."
     missing = [k for k in (*required, "value_fc1_b", "policy_b", HPARAMS) if k not in params]
     if missing:
-        raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
+        raise ValueError(not_one(missing))
     given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
     defaults = trunk_checkpoint({}, TrunkConfig())[HPARAMS]
     if not 3 <= len(given) <= len(_HPARAMS):
@@ -1419,80 +1464,34 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     hp = dict(zip(_HPARAMS, [*given, *defaults[len(given):]]))
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
     width_of = lambda name: shape(name)[2] if name in params else 0
-    if PATTERN in params:
-        return _checked(params, len(given), lambda: _pattern_config(params, hp, shape, width_of))
-    (routed, _, experts), (layers, hidden) = shape(router), shape("attn_norm")
-    if "conv0_w" in params:  # the fifth block's form: the head width from conv1's taps, the kernel sizes from both
-        missing = [k for k in ("conv1_w", "wk", "wv1", "wv2", "temp") if k not in params]
-        if missing:
-            raise ValueError(f"trunk checkpoint: compressed convolutional attention (conv0_w) without {missing}")
-        head_dim = shape("conv1_w")[-1]
-        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, kv_heads=shape("wk")[2] // head_dim,
-                         cca=(shape("conv0_w")[2], shape("conv1_w")[2]))
-    elif "kv_norm" in params:  # the latent form: its four widths and the head count from five shapes
-        missing = [k for k in ("wkv_a", "wkv_b") if k not in params]
-        if missing:
-            raise ValueError(f"trunk checkpoint: a latent (kv_norm) without {missing}")
-        rank, score, key_value, value = shape("kv_norm")[1], shape("wq")[2], shape("wkv_b")[2], shape("wo")[1]
-        rope = shape("wkv_a")[2] - rank
-        heads = (score - key_value + value) // rope if rope > 0 else 0  # heads x (nope + rope) - heads x (nope + value) + heads x value
-        if heads < 1:
-            raise ValueError(f"trunk checkpoint: mismatched shapes: wkv_a {shape('wkv_a')} leaves no RoPE key beside a latent of {rank}, "
-                             f"or wq {shape('wq')}, wkv_b {shape('wkv_b')} and wo {shape('wo')} no head")
-        attention = dict(heads=heads, kv_lora_rank=rank, qk_rope_head_dim=rope, qk_nope_head_dim=(key_value - value) // heads,
-                         v_head_dim=value // heads)
+    if pattern is not None:  # the mixers its characters name
+        mixers, what = [kind for kind, mark in (("mamba", "M"), ("attention", "*")) if mark in pattern], f"a pattern {pattern!r}"
+    elif "conv0_w" in params:
+        mixers, what = ["cca"], "compressed convolutional attention (conv0_w)"
+    elif "kv_norm" in params:
+        mixers, what = ["latent"], "a latent (kv_norm)"
     else:
-        missing = [k for k in ("q_norm", "wk") if k not in params]
-        if missing:
-            raise ValueError(f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}...")
-        head_dim = shape("q_norm")[1]
-        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim,
-                         kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
-    return _checked(params, len(given), lambda: TrunkConfig(
-            hidden=hidden, layers=layers, **attention,
-            experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_gate")[3],
-            rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
-            value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
-            nope_layers=tuple(i for i in range(layers) if int(hp["nope_mask"]) >> i & 1),
-            sliding_window=int(hp["sliding_window"]) or None,
-            gated_attention="wgate" in params, post_norms="post_attn_norm" in params, embed_scale=hp["embed_scale"],
-            dense_layers=layers - routed, dense_width=width_of("dense_gate"), shared_width=width_of("shared_gate"),
-            router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
-            held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_gate")[1]),
-            balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
-            router_hidden=shape("router_down")[2] if "router_down" in params else 0,
-        ))
-
-
-def _pattern_config(params: Params, hp: Dict[str, float], shape, width_of) -> TrunkConfig:
-    """The fourth block's ``TrunkConfig``: the pattern from the file, the
-    sizes of each kind of layer from that kind's shapes."""
-    pattern = bytes(np.asarray(params[PATTERN], np.uint8)).decode("ascii")
-    own = {"M": ("mamba_norm", "dt_bias", "conv_w"), "*": ("wq", "wk", "wo")}
-    missing = [k for kind, names in own.items() if kind in pattern for k in names if k not in params]
+        mixers, what = ["attention"], None
+    missing = [k for kind in mixers for k in _SIZES[kind][0] if k not in params]
     if missing:
-        raise ValueError(f"trunk checkpoint: a pattern {pattern!r} without {missing}")
-    attention, mamba = {}, {}
-    if "*" in pattern:
-        head_dim = shape("q_norm")[1] if "q_norm" in params else int(hp["head_dim"])
-        attention = dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, qk_norm="q_norm" in params,
-                         kv_heads=None if shape("wk") == shape("wq") else shape("wk")[2] // head_dim)
-    if "M" in pattern:
-        heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
-        mamba = dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
-                     state_size=(channels - inner) // (2 * groups) if groups else 0)
-    hidden, experts = shape("layer_norm")[1], shape("router_w3" if "router_down" in params else "router_w")[2]
-    return TrunkConfig(
-        hidden=hidden, pattern=pattern, **attention, **mamba, rotary_dim=int(hp["rotary_dim"]) or None,
-        router_hidden=shape("router_down")[2] if "router_down" in params else 0,
+        raise ValueError(f"trunk checkpoint: {what} without {missing}" if what else not_one(missing))
+    sizes = {field: value for kind in mixers for field, value in _SIZES[kind][1](params, shape, hp).items()}
+    (routed, _, experts), (layers, hidden) = shape(router), shape(norm)
+    layout = dict(pattern=pattern) if pattern is not None else dict(layers=layers, dense_layers=layers - routed)
+    return _checked(params, len(given), lambda: TrunkConfig(
+        hidden=hidden, **layout, **sizes,
         experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_up")[3],
-        rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"], value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
-        sliding_window=int(hp["sliding_window"]) or None, embed_scale=hp["embed_scale"],
-        gated_ffn="experts_gate" in params, shared_width=width_of("shared_up"),
+        rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
+        value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
+        nope_layers=tuple(i for i in range(layers) if int(hp["nope_mask"]) >> i & 1),
+        sliding_window=int(hp["sliding_window"]) or None,
+        gated_attention="wgate" in params, post_norms="post_attn_norm" in params, embed_scale=hp["embed_scale"],
+        dense_width=width_of("dense_up"), shared_width=width_of("shared_up"), gated_ffn="experts_gate" in params,
         router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
         held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_up")[1]),
-        balance_rate=hp["balance_rate"],
-    )
+        balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
+        router_hidden=shape("router_down")[2] if "router_down" in params else 0,
+    ))
 
 
 def _checked(params: Params, given: int, make) -> TrunkConfig:

@@ -29,7 +29,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
-from fishnet_tpu.models.trunk import balanced_bias
+from fishnet_tpu.models.trunk import KERNEL_OPERANDS, balanced_bias
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup, step_metrics
 from fishnet_tpu.train.trainer import _constrain
@@ -60,10 +60,6 @@ def az_param_spec(name: str, value: jax.Array) -> P:
     return P()
 
 
-#: The leaves a Pallas kernel reads: the operands of the trunk's grouped products (``models/trunk.py _expert_ffn``).
-_KERNEL_OPERANDS = ("experts_gate", "experts_up", "experts_down")
-
-
 def _client_default(device: jax.Device, dtype: Any, shape: Tuple[int, ...]) -> Layout:
     """The layout ``device``'s client gives an array of this shape left to itself."""
     return Layout.from_pjrt_layout(device.client.get_default_layout(dtype, shape, device))
@@ -91,7 +87,7 @@ def held_layouts(params: Dict[str, Any], mesh: Optional[Mesh], device: jax.Devic
     layout does not survive the persistent compile cache on jax 0.9.0
     (PERF.md section 6, PR 45)."""
     held = {}
-    for name in _KERNEL_OPERANDS:
+    for name in KERNEL_OPERANDS:
         if name in params:
             leaf = params[name]
             shape = leaf.shape if mesh is None else NamedSharding(mesh, az_param_spec(name, leaf)).shard_shape(leaf.shape)

@@ -26,35 +26,18 @@ from fishnet_tpu.models import trunk
 from fishnet_tpu.models.az import az_checkpoint, az_config_from_params, az_forward, init_az_buffers, init_az_params
 from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.train.az_trainer import AzTrainer
-from test_moe_trunk import AFMOE, BATCH, CANCELLING, GRAD_CANCELLING_TOL, MLA, TINY, _all, rel  # noqa: E402
+from tools.step_text import HOW_TO_SEE_WHAT_MOVED, lowered_step_text
+from trunk_tiny import AFMOE, BATCH, CANCELLING, CCA, GRAD_CANCELLING_TOL, MLA, TINY, _all, board_batch, rel  # noqa: E402
 
 CCA_MODEL = {"hidden_size": 128, "num_hidden_layers": 2, "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 8, "cca_time0": 2,
              "cca_time1": 2, "rotary_dim": 4, "rope_theta": 5000000, "moe_intermediate_size": 32, "num_experts": 8, "num_routed_experts": 16,
              "first_held_expert": 4, "num_experts_per_tok": 1, "router_hidden_size": 32, "load_balance_coeff": 0.001, "rms_norm_eps": 1e-05,
              "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
 CCA_CONFIG = {"model": CCA_MODEL, "train": {"value_weight": 1.0}}
-CCA = TrunkConfig(hidden=128, heads=8, kv_heads=2, head_dim=8, layers=2, cca=(2, 2), rotary_dim=4, router_hidden=32, experts=16, experts_per_token=1,
-                  expert_width=32, rope_theta=5e6, rms_eps=1e-5, value_hidden=32, held_experts=(4, 8), balance_rate=0.001)
 
 
 def cca_params(seed: int, model=CCA_MODEL):
     return {k: jnp.asarray(v) for k, v in cca_reference.init_params(seed, model).items()}
-
-
-def board_batch(seed: int, n: int = BATCH):
-    """Boards as the encoder writes them: at most ONE piece plane a square (the reference's router chooses on it, with a
-    margin no rounding flips), castling planes a board, the halfmove fraction, the plane of ones."""
-    rng = np.random.default_rng(seed)
-    planes = np.zeros((n, 8, 8, 19), np.float32)
-    kind = rng.integers(-14, 12, (n, 8, 8))  # over half the squares empty
-    for piece in range(12):
-        planes[..., piece] = kind == piece
-    planes[..., 12:16] = rng.random((n, 1, 1, 4)) < 0.5
-    planes[..., 17] = rng.random((n, 1, 1)) * 0.5
-    planes[..., 18] = 1.0
-    policy = rng.random((n, 4672)).astype(np.float32) ** 8
-    return {"planes": jnp.asarray(planes), "policy_target": jnp.asarray(policy / policy.sum(-1, keepdims=True)),
-            "value_target": jnp.asarray(rng.uniform(-1, 1, n).astype(np.float32))}
 
 
 @pytest.fixture(scope="module")
@@ -158,10 +141,10 @@ def test_the_two_shares_of_eight_experts_add_up_to_the_uncut_references_layer():
     want = cca_reference.features(params, planes, model, same, same).reshape(256, 128)
 
     x = trunk._matmul(planes.reshape(256, 19), params["embed_w"]) + params["embed_b"]
-    attention = {name: params[name][0] for name in trunk._EVERY_LAYER if name in params}
-    x = x + trunk._attention(x, attention, whole)[0]  # every chip computes it alike: once
-    layer = {name: params[name][0] for name in trunk._ROUTED if name in params}
-    n2 = trunk._rms_norm(x, params["moe_norm"][0], whole.rms_eps)
+    attention, routed = trunk.trunk_plan(whole)
+    x = x + trunk._cca_attention(x, trunk.sublayer_params(params, attention), whole, attention)[0]  # every chip computes it alike: once
+    layer = trunk.sublayer_params(params, routed)
+    n2 = trunk._rms_norm(x, layer.pop("moe_norm"), whole.rms_eps)
 
     def share(first):
         cfg = dataclasses.replace(whole, held_experts=(first, 8))
@@ -321,8 +304,6 @@ CCA_STEP_SHA256 = "a835ede221ce0bab9447436bfb275c77f53ad24369a01935cc2fdcf61d9fb
 def test_the_fifth_blocks_lowered_step_is_the_parents_op_for_op():
     import hashlib
 
-    trainer = AzTrainer(CCA)
-    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
-    text = jax.jit(trainer._step).lower(state, jax.eval_shape(lambda: board_batch(1))).as_text()
+    text = lowered_step_text(CCA, board_batch(1))
     assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == CCA_STEP_SHA256
+    assert hashlib.sha256(text.encode()).hexdigest() == CCA_STEP_SHA256, HOW_TO_SEE_WHAT_MOVED.format(block="cca")

@@ -1,8 +1,8 @@
 """The fourth block of the square-token trunk (models/trunk.py with a
 ``TrunkConfig.pattern``: Nemotron-Labs-TwoTower's nemotron_h block) at a
-tiny size on the CPU, beside ``test_moe_trunk.py`` (whose batches,
-tolerances and three blocks it imports; a file of its own so that the two
-run on two workers): the program against the benchmark's own plain
+tiny size on the CPU (batches, tolerances and the blocks' tiny
+configurations are ``trunk_tiny.py``'s; a file a block, so that each runs on
+a worker of its own): the program against the benchmark's own plain
 reference, the shares against the uncut layer, the rule for widths no
 tile divides, what a pattern refuses, its checkpoint, and the three
 accepted blocks' step programs against their parent's."""
@@ -16,20 +16,9 @@ import pytest
 
 from fishnet_tpu.models import trunk
 from fishnet_tpu.models.az import az_checkpoint, az_config_from_params, az_forward, init_az_buffers, init_az_params
-from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.train.az_trainer import AzTrainer
-from test_moe_trunk import (  # noqa: E402
-    AFMOE,
-    BATCH,
-    CANCELLING,
-    GRAD_CANCELLING_TOL,
-    GRAD_TENSOR_TOL,
-    MLA,
-    TINY,
-    _all,
-    batch_of,
-    rel,
-)
+from tools.step_text import HOW_TO_SEE_WHAT_MOVED, lowered_step_text
+from trunk_tiny import BATCH, BLOCKS, CANCELLING, GRAD_CANCELLING_TOL, GRAD_TENSOR_TOL, HYBRID, _all, batch_of, rel  # noqa: E402
 
 # The plain reference is again the benchmark's own (benchmark/reference/hybrid_trunk.py: the sequential recurrence,
 # importing nothing of the program), at a tiny size with the published factors: hidden 21 x 4 (one short row for the
@@ -44,10 +33,6 @@ HYBRID_MODEL = {"hidden_size": 84, "pattern": "MEMEM*E", "num_hidden_layers": 7,
                 "first_held_expert": 4, "num_experts_per_tok": 3, "route_scale": 2.5, "load_balance_coeff": 0.001, "rope_theta": 10000,
                 "rms_norm_eps": 1e-05, "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
 HYBRID_CONFIG = {"model": HYBRID_MODEL, "train": {"value_weight": 1.0}}
-HYBRID = TrunkConfig(hidden=84, heads=4, kv_heads=2, head_dim=16, qk_norm=False, pattern="MEMEM*E", experts=16, experts_per_token=3,
-                     expert_width=232, gated_ffn=False, shared_width=58, rope_theta=1e4, rms_eps=1e-5, value_hidden=32,
-                     mamba_heads=4, mamba_head_dim=8, mamba_groups=2, state_size=16, router_score="sigmoid", route_norm=True,
-                     route_scale=2.5, held_experts=(4, 8), balance_rate=0.001)
 
 
 def hybrid_params(seed: int, model=HYBRID_MODEL):
@@ -135,10 +120,10 @@ def test_the_sixteen_shares_of_an_ungated_layer_add_up_to_the_uncut_reference():
     want = hybrid_reference.features(params, planes, model, same, same).reshape(128, 84)
 
     x = trunk._matmul(planes.reshape(128, 19), params["embed_w"]) + params["embed_b"]
-    mixer = {name: params[name][0] for name in trunk._BY_KIND["M"]}
-    x = x + trunk._mamba(x, {**mixer, "layer_norm": params["layer_norm"][0]}, whole, "layer00")[0]  # every chip computes it alike: once
-    layer = {name: params[name][0] for name in trunk._ROUTED if name in params}
-    n2 = trunk._rms_norm(x, params["layer_norm"][1], whole.rms_eps)
+    mixer, routed = trunk.trunk_plan(whole)
+    x = x + trunk._mamba(x, trunk.sublayer_params(params, mixer), whole, mixer)[0]  # every chip computes it alike: once
+    layer = trunk.sublayer_params(params, routed)
+    n2 = trunk._rms_norm(x, layer.pop("layer_norm"), whole.rms_eps)
 
     def share(first):
         cfg = dataclasses.replace(whole, held_experts=(first, 8))
@@ -283,8 +268,7 @@ PARENT_STEP_SHA256 = {
 def test_an_accepted_blocks_lowered_step_is_the_parents_op_for_op(block):
     import hashlib
 
-    trainer = AzTrainer({"llada": TINY, "afmoe": AFMOE, "mla": MLA, "hybrid": HYBRID}[block])
-    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
-    text = jax.jit(trainer._step).lower(state, jax.eval_shape(lambda: batch_of(1))).as_text()
+    cfg, batch = BLOCKS[block]
+    text = lowered_step_text(cfg, batch(1))
     assert "loc(" not in text
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP_SHA256[block]
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEP_SHA256[block], HOW_TO_SEE_WHAT_MOVED.format(block=block)

@@ -16,6 +16,7 @@ renormalised top-k, causal attention), which have to miss it by more
 than 1.5x.
 """
 
+
 from __future__ import annotations
 
 import jax
@@ -24,53 +25,26 @@ import numpy as np
 import pytest
 
 from fishnet_tpu.models import trunk
-from fishnet_tpu.models.az import az_checkpoint, az_config_from_params, az_forward, init_az_buffers, init_az_params
+from fishnet_tpu.models.az import az_checkpoint, az_config_from_params, az_forward, init_az_params
 from fishnet_tpu.models.trunk import TrunkConfig, trunk_forward
 from fishnet_tpu.train.az_trainer import AzTrainer
-
-TINY = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=2, experts=8, experts_per_token=2,
-                   expert_width=32, value_hidden=32)
-BATCH = 8
-
-
-def conditioned_params(seed: int, cfg: TrunkConfig = TINY):
-    """Matrices normal(0, 0.9^2 / fan_in), gains and biases off their
-    special points, a peaked router (logits spread ~3), so that a
-    bfloat16 rounding that swaps a token's second and third expert swaps
-    two small weights (benchmark/reference/moe_trunk.py says the same)."""
-    rng = np.random.default_rng(seed)
-    params = {}
-    for name, shape in trunk.trunk_param_shapes(cfg).items():
-        if name.endswith("_norm"):
-            value = 1.0 + 0.1 * rng.standard_normal(shape)
-        elif name.endswith("_b") and len(shape) == 1:
-            value = 0.05 * rng.standard_normal(shape) + (0.5 if name == "value_fc2_b" else 0.0)
-        else:
-            fan_in = shape[-2]
-            value = rng.standard_normal(shape) * (3.0 if name == "router_w" else 0.9) / np.sqrt(fan_in)
-        params[name] = jnp.asarray(value, jnp.float32)
-    return params
-
-
-def batch_of(seed: int, n: int = BATCH):
-    rng = np.random.default_rng(seed)
-    planes = (rng.random((n, 8, 8, 19)) < 0.15).astype(np.float32)
-    planes[..., 17] = rng.random((n, 1, 1)) * 0.5  # the halfmove plane is a fraction
-    target = rng.gamma(0.3, size=(n, 4672)) * (rng.random((n, 4672)) < 0.01)
-    target[:, 0] += 1e-3
-    return {"planes": jnp.asarray(planes), "policy_target": jnp.asarray(target / target.sum(1, keepdims=True), jnp.float32),
-            "value_target": jnp.asarray(rng.uniform(-1, 1, n), jnp.float32)}
-
-
-def _norm(x, gain, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
-
-
-def _rope(x, theta):
-    half = x.shape[-1] // 2
-    angle = np.arange(64)[:, None] / theta ** (np.arange(half) / half)[None, :]
-    cos, sin = (jnp.asarray(np.concatenate([f(angle)] * 2, -1), jnp.float32)[None, :, None, :] for f in (np.cos, np.sin))
-    return x * cos + jnp.concatenate([-x[..., half:], x[..., :half]], -1) * sin
+from trunk_tiny import (  # noqa: E402
+    AFMOE,
+    BATCH,
+    CANCELLING,
+    GRAD_ALL_TOL,
+    GRAD_CANCELLING_TOL,
+    GRAD_TENSOR_TOL,
+    LOGITS_TOL,
+    MLA,
+    TINY,
+    VALUE_TOL,
+    _norm,
+    _rope,
+    batch_of,
+    conditioned_params,
+    rel,
+)
 
 
 def reference_forward(p, planes, cfg, wrong=""):
@@ -112,21 +86,12 @@ def reference_loss(p, batch, cfg, wrong=""):
     return policy + jnp.mean((value - batch["value_target"]) ** 2)
 
 
-def rel(got, want):
-    return float(jnp.linalg.norm(jnp.asarray(got, jnp.float32) - want) / jnp.linalg.norm(want))
-
-
 @pytest.fixture(scope="module")
 def program():
     trainer = AzTrainer(TINY)
     forward = jax.jit(lambda p, x: trunk_forward(p, x, TINY))
     grad = jax.jit(jax.grad(lambda p, b: trainer._loss(p, b)[0]))
     return forward, grad
-
-
-# Readings over seeds 1-5 (CPU): logits 0.008-0.018 of their norm, value 0.004-0.009 absolute; the wrong
-# references read logits >= 0.088 (renormalised), >= 0.55 (unweighted), >= 0.45 (causal).
-LOGITS_TOL, VALUE_TOL = 0.03, 0.03
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -149,13 +114,6 @@ def test_the_tolerance_catches_left_out_mathematics(program, wrong):
     missed = rel(logits, reference_forward(params, batch["planes"], TINY, wrong)[0])
     assert missed > 1.5 * LOGITS_TOL, (wrong, missed)
 
-
-# Readings over seeds 1-5: all tensors as one vector 0.032-0.077; the worst single tensor 0.14 (experts_up,
-# seed 1) but for the policy head's bias and the value head's first layers, whose gradients are cancelling
-# sums (<= 0.20). The wrong references read >= 0.22 (renormalised), >= 0.68 (unweighted), >= 0.64 (causal)
-# as one vector.
-GRAD_ALL_TOL, GRAD_TENSOR_TOL, GRAD_CANCELLING_TOL = 0.1, 0.25, 0.5
-CANCELLING = ("policy_b", "value_w", "value_b", "value_fc1_w", "value_fc1_b")
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -734,492 +692,3 @@ def test_the_rpc_hosts_az_backend_serves_a_trunk():
     # rows are padded to the host's bucket; a position's own rows do not depend on the others
     assert np.allclose(logits16[:5], np.asarray(want_logits, np.float16), atol=2e-3)
     assert np.allclose(values[:5], want_values, atol=2e-3)
-
-
-# -- the second block (afmoe): a leading dense layer, grouped-query gated attention with RoPE and NoPE layers,
-# -- a shared expert beside sigmoid-routed experts of which a share is held, four norms a layer -------------------
-
-AFMOE = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=3, experts=16, experts_per_token=4, expert_width=32,
-                    rope_theta=10000.0, value_hidden=32, kv_heads=2, nope_layers=(2,), sliding_window=2048,
-                    gated_attention=True, post_norms=True, embed_scale=8.0, dense_layers=1, dense_width=96,
-                    shared_width=32, router_score="sigmoid", route_norm=True, route_scale=2.826,
-                    held_experts=(4, 8), balance_rate=0.001)
-
-
-def afmoe_params(seed: int, cfg: TrunkConfig = AFMOE):
-    """``conditioned_params`` and an ``expert_bias`` of a few balance
-    steps (multiples of the rate, each layer's mean zero)."""
-    params = conditioned_params(seed, cfg)
-    rng = np.random.default_rng(seed + 1000)
-    bias = cfg.balance_rate * rng.integers(-3, 4, (cfg.routed_layers, cfg.experts))
-    params["expert_bias"] = jnp.asarray(bias - bias.mean(-1, keepdims=True), jnp.float32)
-    return params
-
-
-def _gated(n, p, kind, i):
-    return (jax.nn.silu(n @ p[f"{kind}_gate"][i]) * (n @ p[f"{kind}_up"][i])) @ p[f"{kind}_down"][i]
-
-
-def afmoe_weights(n, router_w, bias, cfg, wrong=""):
-    """[.., experts] combine weights, zero off the chosen: sigmoid scores,
-    the choice on score + bias, renormalised over all the chosen, scaled."""
-    score = jax.nn.sigmoid(n @ router_w)
-    chosen = score + (0.0 if wrong == "no_bias" else bias)
-    kth = jnp.sort(chosen, -1)[..., -cfg.experts_per_token][..., None]
-    picked = jnp.where(chosen >= kth, score, 0.0)
-    if wrong != "not_renormalised":
-        picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
-    return picked * cfg.route_scale * (1.5 if wrong == "scale" else 1.0)
-
-
-def afmoe_reference_forward(p, planes, cfg, wrong=""):
-    """The second block's layer equations (models/trunk.py), float32,
-    every HELD expert applied to every token and masked by the choice.
-    ``wrong`` leaves one piece of the mathematics out."""
-    b, group = planes.shape[0], cfg.heads // cfg.kv_heads
-    first, count = cfg.held
-    x = (planes.reshape(b, 64, 19) @ p["embed_w"] + p["embed_b"]) * cfg.embed_scale
-    for i in range(cfg.layers):
-        n1 = _norm(x, p["attn_norm"][i], cfg.rms_eps)
-        q = _norm((n1 @ p["wq"][i]).reshape(b, 64, cfg.heads, cfg.head_dim), p["q_norm"][i], cfg.rms_eps)
-        k = _norm((n1 @ p["wk"][i]).reshape(b, 64, cfg.kv_heads, cfg.head_dim), p["k_norm"][i], cfg.rms_eps)
-        v = (n1 @ p["wv"][i]).reshape(b, 64, cfg.kv_heads, cfg.head_dim)
-        if (i not in cfg.nope_layers) != (wrong == "rope_swapped"):
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-        k, v = (jnp.repeat(y, group, axis=2) for y in (k, v))  # query head h attends key-value head h // group
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(cfg.head_dim)
-        near = np.abs(np.arange(64)[:, None] - np.arange(64)[None, :]) < cfg.sliding_window
-        if i not in cfg.nope_layers:
-            scores = jnp.where(near, scores, -1e30)  # the window, applied literally: all true on a board
-        mixed = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v).reshape(b, 64, -1)
-        if wrong != "no_gate":
-            mixed = mixed * jax.nn.sigmoid(n1 @ p["wgate"][i])
-        x = x + _norm(mixed @ p["wo"][i], p["post_attn_norm"][i], cfg.rms_eps)
-        n2 = _norm(x, p["moe_norm"][i], cfg.rms_eps)
-        r = i - cfg.dense_layers
-        if r < 0:
-            out = _gated(n2, p, "dense", i)
-        else:
-            weights = afmoe_weights(n2, p["router_w"][r], p["expert_bias"][r], cfg, wrong)
-            out = 0.0 if wrong == "no_shared" else _gated(n2, p, "shared", r)
-            for e in range(count):
-                act = jax.nn.silu(n2 @ p["experts_gate"][r, e]) * (n2 @ p["experts_up"][r, e])
-                out = out + weights[..., first + e, None] * (act @ p["experts_down"][r, e])
-        x = x + (out if wrong == "no_post_norm" else _norm(out, p["post_mlp_norm"][i], cfg.rms_eps))
-    x = _norm(x, p["final_norm"], cfg.rms_eps)
-    logits = (x @ p["policy_w"][0, 0] + p["policy_b"]).reshape(b, -1)
-    v = jax.nn.relu(x @ p["value_w"][0, 0] + p["value_b"]).reshape(b, -1)
-    v = jax.nn.relu(v @ p["value_fc1_w"] + p["value_fc1_b"])
-    return logits, jnp.tanh(v @ p["value_fc2_w"] + p["value_fc2_b"])[:, 0]
-
-
-def afmoe_reference_loss(p, batch, cfg, wrong=""):
-    logits, value = afmoe_reference_forward(p, batch["planes"], cfg, wrong)
-    policy = -jnp.mean(jnp.sum(batch["policy_target"] * jax.nn.log_softmax(logits, -1), -1))
-    return policy + jnp.mean((value - batch["value_target"]) ** 2)
-
-
-@pytest.fixture(scope="module")
-def afmoe_program():
-    trainer = AzTrainer(AFMOE)
-    forward = jax.jit(lambda p, x: trunk_forward(p, x, AFMOE))
-    split = lambda p: ({k: v for k, v in p.items() if k != "expert_bias"}, {"expert_bias": p["expert_bias"]})
-    grad = jax.jit(lambda p, b: jax.grad(lambda q: trainer._loss(q, b, split(p)[1])[0])(split(p)[0]))
-    return forward, grad
-
-
-AFMOE_WRONG = ["no_gate", "no_shared", "scale", "not_renormalised", "rope_swapped", "no_post_norm"]
-
-
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_afmoe_forward_matches_the_plain_reference(afmoe_program, seed):
-    params, batch = afmoe_params(seed), batch_of(seed)
-    logits, value = afmoe_program[0](params, batch["planes"])
-    want_logits, want_value = afmoe_reference_forward(params, batch["planes"], AFMOE)
-    print("afmoe forward", seed, rel(logits, want_logits), float(jnp.max(jnp.abs(value - want_value))))
-    assert rel(logits, want_logits) < LOGITS_TOL, rel(logits, want_logits)
-    assert float(jnp.max(jnp.abs(value - want_value))) < VALUE_TOL
-
-
-@pytest.mark.parametrize("wrong", AFMOE_WRONG)
-def test_the_tolerance_catches_left_out_afmoe_mathematics(afmoe_program, wrong):
-    params, batch = afmoe_params(1), batch_of(1)
-    logits, _value = afmoe_program[0](params, batch["planes"])
-    missed = rel(logits, afmoe_reference_forward(params, batch["planes"], AFMOE, wrong)[0])
-    print("afmoe wrong", wrong, missed)
-    assert missed > 1.5 * LOGITS_TOL, (wrong, missed)
-
-
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_afmoe_gradient_of_the_trainers_loss_matches_the_plain_reference(afmoe_program, seed):
-    params, batch = afmoe_params(seed), batch_of(seed)
-    got = afmoe_program[1](params, batch)
-    want = jax.grad(afmoe_reference_loss)(params, batch, AFMOE)
-    assert not np.any(np.asarray(want.pop("expert_bias")))  # no gradient through the bias or the choice
-    assert set(got) == set(want) == set(trunk.trunk_param_shapes(AFMOE))
-    total = lambda a, b: np.sqrt(sum(float(jnp.sum((a[k] - b[k]) ** 2)) for k in b) / sum(float(jnp.sum(b[k] ** 2)) for k in b))
-    print("afmoe grad", seed, total(got, want), {k: round(rel(got[k], want[k]), 4) for k in want})
-    assert total(got, want) < GRAD_ALL_TOL
-    for name in want:
-        assert float(jnp.linalg.norm(want[name])) > 0, name  # every tensor has a gradient to compare
-        assert rel(got[name], want[name]) < (GRAD_CANCELLING_TOL if name in CANCELLING else GRAD_TENSOR_TOL), name
-    for wrong in ("no_gate", "scale"):
-        assert total({**got, "expert_bias": 0.0 * params["expert_bias"]}, jax.grad(afmoe_reference_loss)(params, batch, AFMOE, wrong)) > 1.5 * GRAD_ALL_TOL, wrong
-
-
-@pytest.mark.parametrize("block", ["afmoe_4_shares_of_4", "mla_16_shares_of_8"])
-def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(block):
-    """16 experts in 4 shares of 4, as four chips of an expert-parallel
-    deployment hold them: each share routes over all 16, computes its own
-    experts' part for the tokens routed to them and leaves the rest out.
-    The four routed parts and the shared expert ONCE are the uncut
-    reference layer; a share alone is not; and every slot falls in
-    exactly one share (``held_slots`` of the four add up to all slots).
-    The third block's case (below, after its reference): 128 experts in 16
-    shares of 8, top-6, two shared experts, latent attention counted once."""
-    if block.startswith("mla"):
-        return _the_sixteen_shares_of_a_latent_layer_add_up()
-    whole = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=1, experts=16, experts_per_token=4, expert_width=32,
-                        shared_width=32, router_score="sigmoid", route_norm=True, route_scale=2.826, balance_rate=0.001)
-    rng = np.random.default_rng(11)
-    layer = {k: v[0] for k, v in afmoe_params(11, whole).items() if k in trunk._ROUTED}
-    n2 = jnp.asarray(rng.standard_normal((256, 64)), jnp.float32)
-    weights = afmoe_weights(n2, layer["router_w"], layer["expert_bias"], whole)
-    expert = lambda e: (jax.nn.silu(n2 @ layer["experts_gate"][e]) * (n2 @ layer["experts_up"][e])) @ layer["experts_down"][e]
-    want = _gated(n2, {k: v[None] for k, v in layer.items()}, "shared", 0) + sum(weights[:, e, None] * expert(e) for e in range(16))
-
-    def share(first):
-        cfg = TrunkConfig(**{**whole.__dict__, "held_experts": (first, 4)})
-        held = {k: (v[first:first + 4] if k.startswith("experts_") else v) for k, v in layer.items()}
-        mixed, counters = jax.jit(lambda n, l: trunk._experts(n, l, cfg, "layer00"))(n2, held)
-        return mixed, counters["expert_slots"]
-
-    parts = [share(first) for first in (0, 4, 8, 12)]
-    for first, (mixed, slots) in zip((0, 4, 8, 12), parts):
-        own = sum(weights[:, e, None] * expert(e) for e in range(first, first + 4))
-        assert rel(mixed, own) < 0.02, (first, rel(mixed, own))
-        assert np.array_equal(slots, np.asarray((weights > 0).sum(0)))  # every share counts all 16 experts' slots alike
-    total = sum(mixed for mixed, _ in parts) + trunk._gated_ffn(n2, layer, "shared")
-    assert rel(total, want) < 0.02, rel(total, want)
-    assert rel(parts[0][0] + trunk._gated_ffn(n2, layer, "shared"), want) > 0.3  # one share is not the layer
-    # the uncut program (all 16 held, no offset) is the same sum
-    uncut, _ = jax.jit(lambda n, l: trunk._experts(n, l, whole, "layer00"))(n2, layer)
-    assert rel(uncut, sum(mixed for mixed, _ in parts)) < 0.01
-    # the gradient to a share's weights comes from its own slots alone, and an absent expert's rows pass none back
-    cfg = TrunkConfig(**{**whole.__dict__, "held_experts": (4, 4)})
-    held = {k: (v[4:8] if k.startswith("experts_") else v) for k, v in layer.items()}
-    loss = lambda n, l: jnp.sum(trunk._experts(n, l, cfg, "layer00")[0] ** 2)
-    d_n2, d_layer = jax.jit(jax.grad(loss, (0, 1)))(n2, held)
-    plain = lambda n, l: jnp.sum(sum(afmoe_weights(n, l["router_w"], l["expert_bias"], whole)[:, 4 + e, None] * (
-        (jax.nn.silu(n @ l["experts_gate"][e]) * (n @ l["experts_up"][e])) @ l["experts_down"][e]) for e in range(4)) ** 2)
-    want_n2, want_layer = jax.grad(plain, (0, 1))(n2, held)
-    assert rel(d_n2, want_n2) < 0.05, rel(d_n2, want_n2)
-    for name in ("experts_gate", "experts_up", "experts_down", "router_w"):
-        assert rel(d_layer[name], want_layer[name]) < 0.05, (name, rel(d_layer[name], want_layer[name]))
-    assert not np.any(np.asarray(d_layer["expert_bias"]))
-
-
-def test_the_balance_update_against_a_hand_count():
-    """Four experts, mean load 10: the one over it goes down by the rate,
-    the two under it up, the one at it stays, and the layer's mean change
-    (+0.001 / 4) is taken out of all four."""
-    bias = jnp.asarray([[0.0, 0.002, -0.001, 0.0], [0.0, 0.0, 0.0, 0.0]], jnp.float32)
-    slots = jnp.asarray([[25.0, 3.0, 2.0, 10.0], [10.0, 10.0, 10.0, 10.0]], jnp.float32)
-    got = np.asarray(trunk.balanced_bias(bias, slots, 0.001))
-    assert np.allclose(got[0], [-0.001 - 0.00025, 0.003 - 0.00025, 0.0 - 0.00025, -0.00025], atol=1e-9)
-    assert np.allclose(got[1], 0.0)  # an even layer does not move
-    # the trainer applies it to the state's buffer from the step's own routing, and AdamW never sees the buffer
-    trainer = AzTrainer(AFMOE, learning_rate=1e-3)
-    state, batch = trainer.init(3), batch_of(3)
-    assert set(state.buffers) == {"expert_bias"} and "expert_bias" not in state.params
-    assert jax.tree_util.tree_structure(state.opt_state) == jax.tree_util.tree_structure(trainer.optimizer.init(state.params))
-    _, _, counters = jax.jit(lambda p, x: trunk.trunk_forward_counted(p, x, AFMOE))({**state.params, **state.buffers}, batch["planes"])
-    new, metrics = trainer.step(state, batch)  # donates ``state``
-    want = trunk.balanced_bias(jnp.zeros((2, 16)), counters["expert_slots"], 0.001)
-    assert np.allclose(new.buffers["expert_bias"], want, atol=1e-9) and float(jnp.max(jnp.abs(want))) > 0
-    assert "expert_slots" not in metrics and float(metrics["expert_bias_abs_max"]) == 0.0  # the bias the step's forward read
-    assert float(metrics["held_slots"]) == float(jnp.sum(counters["expert_slots"][:, 4:12]))
-    assert float(jnp.sum(counters["expert_slots"])) == 2 * BATCH * 64 * 4  # every slot of both routed layers is counted
-    _, metrics = trainer.step(new, batch)
-    assert 0.0 < float(metrics["expert_bias_abs_max"]) <= 0.002
-
-
-def test_a_window_shorter_than_a_board_is_refused():
-    with pytest.raises(ValueError, match="sliding_window 32 is under the 64 tokens"):
-        TrunkConfig(sliding_window=32)
-    assert TrunkConfig(sliding_window=64).sliding_window == 64  # masks nothing: |i - j| < 64 on a board
-    for wrong in (dict(heads=4, kv_heads=3), dict(router_score="tanh"), dict(held_experts=(60, 8)),
-                  dict(dense_layers=1), dict(layers=2, dense_layers=1), dict(nope_layers=(1,))):
-        with pytest.raises(ValueError):
-            TrunkConfig(**wrong)
-
-
-def test_afmoe_checkpoint_round_trips_and_the_first_blocks_files_still_load(tmp_path):
-    trainer = AzTrainer(AFMOE)
-    state, _ = trainer.step(trainer.init(0), batch_of(0))
-    trainer.export(state, str(tmp_path / "afmoe.npz"))
-    loaded = dict(np.load(tmp_path / "afmoe.npz"))
-    assert az_config_from_params(loaded) == AFMOE
-    assert np.array_equal(loaded["expert_bias"], state.buffers["expert_bias"]) and np.any(loaded["expert_bias"])
-    logits, value = jax.jit(lambda p, x: az_forward(p, x, AFMOE))(loaded, batch_of(0)["planes"])
-    assert logits.shape == (BATCH, 4672) and bool(jnp.all(jnp.isfinite(value)))
-    # a file written before the second block carries three hyperparameters; the rest default to the first block
-    old = az_checkpoint(init_az_params(jax.random.PRNGKey(0), TINY), TINY)
-    old["trunk_hparams"] = old["trunk_hparams"][:3]
-    assert az_config_from_params(old) == TINY
-    with pytest.raises(ValueError, match="mismatched"):
-        az_config_from_params({k: v for k, v in loaded.items() if k != "expert_bias"})
-
-
-def test_recomputing_the_routed_branch_changes_no_number():
-    """``recompute_experts`` keeps nothing of slot size for the backward
-    pass and makes it again there: the loss and every gradient are the
-    ones the kept intermediates give, bit for bit, and the backward
-    pass's operations keep their layer's scope names at the second level
-    of the path, where the benchmark's scope table reads them."""
-    again = TrunkConfig(**{**AFMOE.__dict__, "recompute_experts": True})
-    params, batch = afmoe_params(4), batch_of(4)
-    trained = {k: v for k, v in params.items() if k != "expert_bias"}
-    grads = {}
-    for cfg in (AFMOE, again):
-        trainer = AzTrainer(cfg)
-        grads[cfg] = jax.jit(jax.value_and_grad(lambda q, t=trainer: t._loss(q, batch, {"expert_bias": params["expert_bias"]})[0]))(trained)
-    assert float(grads[AFMOE][0]) == float(grads[again][0])
-    for name, want in grads[AFMOE][1].items():
-        assert np.array_equal(np.asarray(want), np.asarray(grads[again][1][name])), name
-    trainer = AzTrainer(again)
-    state = trainer.init(0)
-    text = trainer._step_jit.lower(state, batch).compile().as_text()
-    import re
-    names = set(re.findall(r'op_name="jit\(_step\)/(transpose\(jvp\(forward\)\)/layer\d+\.\w+)/', text))
-    assert {f"transpose(jvp(forward))/layer0{i}.{part}" for i in (1, 2) for part in ("dispatch", "experts", "combine")} <= names, names
-
-
-def _afmoe_loss_and_grads(cfg, params, batch):
-    trainer = AzTrainer(cfg)
-    trained = {k: v for k, v in params.items() if k != "expert_bias"}
-    return jax.jit(jax.value_and_grad(lambda q: trainer._loss(q, batch, {"expert_bias": params["expert_bias"]}), has_aux=True))(trained)
-
-
-@pytest.mark.parametrize("recompute", [False, True], ids=["kept", "recomputed"])
-def test_the_extent_of_a_shares_moves_changes_no_number(monkeypatch, recompute):
-    """A share (experts 4-11 of 16: not the first) moves the rows of its
-    extent alone and leaves NaN in every tail (the interpreter's
-    uninitialised memory); moved in full, the same tails hold the zeros
-    ``gmm`` writes for absent experts. The loss and every gradient are
-    the same to the bit: nothing reads a tail but through a select."""
-    cfg = TrunkConfig(**{**AFMOE.__dict__, "recompute_experts": recompute})
-    params, batch = afmoe_params(6), batch_of(6)
-    (loss, aux), grads = _afmoe_loss_and_grads(cfg, params, batch)
-    assert 0 < float(aux["held_slots"]) < 2 * BATCH * 64 * 4 and float(aux["moved_rows"]) < 2 * BATCH * 64 * 4  # a share indeed
-    monkeypatch.setattr(trunk, "_extent", lambda held: None)  # the oracle: every move in full
-    (full_loss, _), full_grads = _afmoe_loss_and_grads(cfg, params, batch)
-    assert np.isfinite(float(loss)) and float(loss) == float(full_loss)
-    for name, want in full_grads.items():
-        assert np.array_equal(np.asarray(grads[name]), np.asarray(want)), name
-
-
-@pytest.mark.parametrize("bias,held_share", [(10.0, 1.0), (-10.0, 0.0)], ids=["every_slot_held", "no_slot_held"])
-def test_a_share_is_dropless_at_both_ends(bias, held_share):
-    """A choice pushed wholly onto the held experts (8 of 16 held, top-4),
-    and wholly off them: the moves cover every row, or one block of a
-    kernel that has nothing to move, and the step's loss and gradients
-    are the plain reference's, all finite. Nothing is capped either way."""
-    # Seed 2: its choices stand clear of ties. Seed 7, used through PR 35, has two tokens whose fourth and fifth expert in the second
-    # routed layer are one rounding apart; PR 36's products round differently, the two flipped, and the gradients read 0.102 against 0.1.
-    params, batch = afmoe_params(2), batch_of(2)
-    push = jnp.zeros((AFMOE.routed_layers, AFMOE.experts)).at[:, 4:12].set(bias)  # sigmoid scores lie in (0, 1)
-    params["expert_bias"] = push
-    (loss, aux), got = _afmoe_loss_and_grads(AFMOE, params, batch)
-    slots = AFMOE.routed_layers * BATCH * 64 * AFMOE.experts_per_token
-    assert float(aux["held_slots"]) == held_share * slots and float(aux["moved_rows"]) == held_share * slots
-    want_loss, want = jax.value_and_grad(afmoe_reference_loss)(params, batch, AFMOE)
-    assert np.isfinite(float(loss)) and abs(float(loss) - float(want_loss)) < 0.01 * abs(float(want_loss)), (float(loss), float(want_loss))
-    want.pop("expert_bias")
-    total = lambda a, b: np.sqrt(sum(float(jnp.sum((a[k] - b[k]) ** 2)) for k in b) / sum(float(jnp.sum(b[k] ** 2)) for k in b))
-    assert total(got, want) < GRAD_ALL_TOL, total(got, want)
-    for name in want:
-        assert np.all(np.isfinite(got[name])), name
-        if float(jnp.linalg.norm(want[name])) == 0:  # no slot held: the experts and the router reach no loss
-            assert name in ("experts_gate", "experts_up", "experts_down", "router_w") and held_share == 0 and not np.any(np.asarray(got[name])), name
-        else:
-            assert rel(got[name], want[name]) < (GRAD_CANCELLING_TOL if name in CANCELLING else GRAD_TENSOR_TOL), name
-
-
-def test_moved_rows_against_a_hand_count():
-    """``moved_rows``: what the row moves of a step's routed layers cover.
-    Where every expert is held, every slot of every layer; for a share,
-    each layer's held count rounded up to the moves' block (512 rows at
-    2,048 slots a layer)."""
-    _, _, counters = jax.jit(lambda p, x: trunk.trunk_forward_counted(p, x, TINY))(conditioned_params(1), batch_of(1)["planes"])
-    assert float(counters["moved_rows"]) == TINY.layers * BATCH * 64 * TINY.experts_per_token and "held_slots" not in counters
-    _, _, counters = jax.jit(lambda p, x: trunk.trunk_forward_counted(p, x, AFMOE))(afmoe_params(1), batch_of(1)["planes"])
-    held = np.asarray(counters["expert_slots"])[:, 4:12].sum(axis=1)  # a layer
-    assert held.sum() == float(counters["held_slots"]) and np.all(held % 512 != 0)  # the rounding shows
-    assert float(counters["moved_rows"]) == sum(-(-int(h) // 512) * 512 for h in held)
-    trainer = AzTrainer(AFMOE)
-    _, metrics = trainer.step(trainer.init(1), batch_of(1))
-    assert 0 < float(metrics["moved_rows"]) <= 2 * BATCH * 64 * 4 and float(metrics["moved_rows"]) % 512 == 0  # in the step's metrics
-
-
-# -- the third block: latent attention (Kanana-2's deepseek_v3 block) ---------------------------------------------
-#
-# The plain reference here is the benchmark's own (benchmark/reference/mla_trunk.py: the published equations,
-# literally, in the published column order, importing nothing of the program), at a tiny size; the program reads
-# its parameters through benchmark/families/mla_trunk.py's permutation and hands its gradients back through it.
-
-from benchmark.families import mla_trunk as mla_family  # noqa: E402
-from benchmark.reference import mla_trunk as mla_reference  # noqa: E402
-
-MLA_MODEL = {"hidden_size": 64, "num_attention_heads": 4, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16,
-             "num_hidden_layers": 3, "num_dense_layers": 1, "intermediate_size": 96, "moe_intermediate_size": 32, "num_shared_experts": 2,
-             "num_experts": 8, "num_routed_experts": 16, "first_held_expert": 4, "num_experts_per_tok": 3, "route_scale": 2.448,
-             "load_balance_coeff": 0.001, "rope_theta": 1000000, "rms_norm_eps": 1e-06, "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
-MLA_CONFIG = {"model": MLA_MODEL, "train": {"value_weight": 1.0}}
-MLA = TrunkConfig(hidden=64, heads=4, layers=3, experts=16, experts_per_token=3, expert_width=32, rope_theta=1e6, rms_eps=1e-6,
-                  value_hidden=32, dense_layers=1, dense_width=96, shared_width=64, router_score="sigmoid", route_norm=True,
-                  route_scale=2.448, held_experts=(4, 8), balance_rate=0.001, kv_lora_rank=32, qk_nope_head_dim=16,
-                  qk_rope_head_dim=64, v_head_dim=16)
-
-
-def mla_params(seed: int):
-    return {k: jnp.asarray(v) for k, v in mla_reference.init_params(seed, MLA_MODEL).items()}
-
-
-@pytest.fixture(scope="module")
-def mla_program():
-    return mla_family.loss_and_grads(AzTrainer(MLA))
-
-
-# Readings over seeds 1-3 (CPU): all gradients as one vector 0.005-0.013 (the embedding at sqrt(hidden) and the peaked, centred
-# router of the reference's conditioning make this block's sums add); the wrong layers below read 0.115-0.54.
-MLA_GRAD_ALL_TOL = 0.04
-
-
-def _all(got, want):
-    return np.sqrt(sum(float(jnp.sum((got[k] - want[k]) ** 2)) for k in want) / sum(float(jnp.sum(want[k] ** 2)) for k in want))
-
-
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_mla_loss_and_every_gradient_match_the_benchmarks_reference(mla_program, seed):
-    params, batch = mla_params(seed), batch_of(seed)
-    loss, got = mla_program(params, batch)
-    want_loss, want = jax.value_and_grad(mla_reference.loss)(params, batch, MLA_CONFIG)
-    assert not np.any(np.asarray(want.pop("expert_bias"))) and not np.any(np.asarray(got.pop("expert_bias")))
-    assert set(got) == set(want) == set(trunk.trunk_param_shapes(MLA)) and {"wkv_a", "kv_norm", "wkv_b"} < set(want) and "q_norm" not in want
-    print("mla", seed, abs(float(loss) - float(want_loss)) / float(want_loss), _all(got, want), {k: round(rel(got[k], want[k]), 4) for k in want})
-    assert abs(float(loss) - float(want_loss)) < 0.01 * float(want_loss)
-    assert _all(got, want) < MLA_GRAD_ALL_TOL
-    for name in want:
-        assert got[name].shape == want[name].shape and float(jnp.linalg.norm(want[name])) > 0, name
-        assert rel(got[name], want[name]) < (GRAD_CANCELLING_TOL if name in CANCELLING else GRAD_TENSOR_TOL), name
-
-
-@pytest.mark.parametrize("wrong", ["no_latent_norm", "rotate_half_unpermuted", "keys_before_values_unpermuted", "scale_of_nope"])
-def test_the_tolerance_catches_a_wrong_latent_layer(mla_program, monkeypatch, wrong):
-    """A latent that is not normed; the program's rotate-half on columns
-    left in the published interleaved order; ``wkv_b`` left in the
-    published per-head order; a scale of 1 / sqrt(nope): each is further
-    from the reference than the tolerance on all gradients as one vector."""
-    params, batch = mla_params(1), batch_of(1)
-    if wrong == "no_latent_norm":
-        norm = mla_reference._rms_norm
-        monkeypatch.setattr(mla_reference, "_rms_norm", lambda x, g, eps: x * g if x.shape[-1] == MLA_MODEL["kv_lora_rank"] else norm(x, g, eps))
-    elif wrong == "scale_of_nope":
-        monkeypatch.setattr(mla_reference.np, "sqrt", lambda x: np.float64(x - 64) ** 0.5 if x == 80 else np.float64(x) ** 0.5)
-    else:
-        orders = mla_family.column_orders(MLA)
-        key = "wq" if wrong == "rotate_half_unpermuted" else "wkv_b"
-        monkeypatch.setattr(mla_family, "column_orders", lambda cfg: {**orders, key: np.arange(len(orders[key]))})
-    _, got = mla_family.loss_and_grads(AzTrainer(MLA))(params, batch)
-    want = jax.grad(mla_reference.loss)(params, batch, MLA_CONFIG)
-    print("mla wrong", wrong, _all(got, want))
-    assert _all(got, want) > 1.5 * MLA_GRAD_ALL_TOL, (wrong, _all(got, want))
-
-
-def test_the_programs_column_order_against_a_hand_count():
-    """2 heads, NoPE 2, RoPE 4, value 3, latent 5: ``program = published[..., order]``."""
-    cfg = TrunkConfig(heads=2, kv_lora_rank=5, qk_nope_head_dim=2, qk_rope_head_dim=4, v_head_dim=3)
-    orders = mla_family.column_orders(cfg)
-    # published wq: head 0 = [n0 n1 | r0 r1 r2 r3] at 0..5, head 1 at 6..11; the pairs (r0, r1), (r2, r3) taken apart: r0 r2 | r1 r3
-    assert list(orders["wq"]) == [0, 1, 6, 7, 2, 4, 3, 5, 8, 10, 9, 11]
-    assert list(orders["wkv_a"]) == [0, 1, 2, 3, 4, 5, 7, 6, 8]  # the latent as it is, then the RoPE key's pairs taken apart
-    assert list(orders["wkv_b"]) == [0, 1, 5, 6, 2, 3, 4, 7, 8, 9]  # published: head 0 = [k0 k1 | v0 v1 v2], head 1 the same at 5..9
-    params = {"wq": jnp.arange(12.0)[None], "wkv_a": jnp.arange(9.0)[None], "wkv_b": jnp.arange(10.0)[None], "wo": jnp.arange(6.0)[None]}
-    there = mla_family.to_program(cfg, params)
-    assert list(np.asarray(there["wq"][0])) == list(orders["wq"]) and np.array_equal(there["wo"], params["wo"])
-    back = mla_family.from_program(cfg, there)
-    assert all(np.array_equal(back[k], params[k]) for k in params)
-
-
-def test_mla_checkpoint_round_trips_and_the_older_blocks_files_still_load(tmp_path):
-    trainer = AzTrainer(MLA)
-    state, metrics = trainer.step(trainer.init(0), batch_of(0))
-    assert 0.0 < float(metrics["latent_rms"]) < 10.0 and "held_slots" in metrics
-    trainer.export(state, str(tmp_path / "mla.npz"))
-    loaded = dict(np.load(tmp_path / "mla.npz"))
-    assert az_config_from_params(loaded) == MLA  # heads and the latent's four widths from the shapes of wq, wkv_a, kv_norm, wkv_b, wo
-    assert {"wkv_a", "kv_norm", "wkv_b"} < set(loaded) and not {"q_norm", "k_norm", "wk", "wv"} & set(loaded)
-    assert bool(jnp.any(state.params["wkv_b"] != 0))  # a matrix, not a bias: initialised as one
-    logits, value = jax.jit(lambda p, x: az_forward(p, x, MLA))(loaded, batch_of(0)["planes"])
-    assert logits.shape == (BATCH, 4672) and bool(jnp.all(jnp.isfinite(value)))
-    for older in (TINY, AFMOE):
-        older_params = {**init_az_params(jax.random.PRNGKey(0), older), **init_az_buffers(older)}
-        assert az_config_from_params(az_checkpoint(older_params, older)) == older
-    with pytest.raises(ValueError, match="mismatched"):
-        az_config_from_params({**loaded, "wkv_a": loaded["wkv_a"][..., :32]})  # no RoPE key beside the latent
-    with pytest.raises(ValueError, match="missing"):
-        az_config_from_params({k: v for k, v in loaded.items() if k != "kv_norm"})  # neither a latent nor q_norm and wk
-
-
-def test_a_latent_refuses_what_the_code_does_not_compute():
-    latent = dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=64, v_head_dim=16)
-    assert TrunkConfig(heads=4, **latent).kv_lora_rank == 32
-    for wrong in (dict(kv_heads=2), dict(gated_attention=True), dict(layers=2, nope_layers=(1,)), dict(qk_rope_head_dim=63),
-                  dict(v_head_dim=0), dict(qk_nope_head_dim=0)):
-        with pytest.raises(ValueError):
-            TrunkConfig(heads=4, **{**latent, **wrong})
-
-
-def _the_sixteen_shares_of_a_latent_layer_add_up():
-    """One layer of the third block with all 128 experts, as the benchmark's
-    reference computes it uncut (every expert on every token, the published
-    column order), against the program's pieces put together as 16 chips
-    would: the latent attention and the two shared experts (one feed-forward
-    of twice the width) ONCE, and the routed parts of 16 shares of 8 experts,
-    each routing over all 128 with top-6 and weights renormalised over all
-    six chosen, held or not."""
-    import dataclasses
-
-    model = {**MLA_MODEL, "num_hidden_layers": 1, "num_dense_layers": 0, "num_experts": 128, "num_routed_experts": 128,
-             "first_held_expert": 0, "num_experts_per_tok": 6, "moe_intermediate_size": 16}
-    whole = dataclasses.replace(MLA, layers=1, dense_layers=0, dense_width=0, experts=128, experts_per_token=6, expert_width=16,
-                                shared_width=32, held_experts=None)
-    published = {k: jnp.asarray(v) for k, v in mla_reference.init_params(5, model).items()}
-    planes = batch_of(5, 2)["planes"]
-    same = lambda x: x
-    want = mla_reference.features(published, planes, model, same, same).reshape(128, 64)
-
-    params = mla_family.to_program(whole, published)
-    layer = {k: v[0] for k, v in params.items() if k in trunk._EVERY_LAYER + trunk._ROUTED}
-    x = trunk._matmul(planes.reshape(128, 19), params["embed_w"]) + params["embed_b"]
-    a = x + trunk._attention(x, layer, whole)[0]  # every chip computes it alike: counted once
-    n2 = trunk._rms_norm(a, layer["moe_norm"], whole.rms_eps)
-
-    def share(first):
-        cfg = dataclasses.replace(whole, held_experts=(first, 8))
-        held = {k: (v[first:first + 8] if k.startswith("experts_") else v) for k, v in layer.items() if k in trunk._ROUTED}
-        mixed, counters = jax.jit(lambda n, l: trunk._experts(n, l, cfg, "layer00"))(n2, held)
-        return mixed, counters["expert_slots"]
-
-    parts = [share(first) for first in range(0, 128, 8)]
-    final = lambda y: trunk._rms_norm(y, params["final_norm"], whole.rms_eps)
-    shared = trunk._gated_ffn(n2, layer, "shared")
-    total = final(a + shared + sum(mixed for mixed, _ in parts))
-    print("mla shares", rel(total, want), rel(final(a + shared + parts[0][0]), want), rel(final(a + sum(mixed for mixed, _ in parts)), want))
-    assert rel(total, want) < 0.02, rel(total, want)
-    assert rel(final(a + shared + parts[0][0]), want) > 5 * rel(total, want)  # one share is not the layer
-    assert rel(final(a + 2 * shared + sum(mixed for mixed, _ in parts)), want) > 5 * rel(total, want)  # nor the shared experts twice
-    slots = parts[0][1]
-    assert all(np.array_equal(s, slots) for _, s in parts)  # every share counts all 128 experts' slots alike
-    assert float(slots.sum()) == 128 * 6 and sum(float(s[first:first + 8].sum()) for first, (_, s) in zip(range(0, 128, 8), parts)) == 128 * 6

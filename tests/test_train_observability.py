@@ -39,17 +39,30 @@ MODEL_SCOPES = {
     # its fourth block: one sublayer a layer, the scan's core beside the mixer's scope and never inside it
     "pattern": ("embed", "layer00.mamba", "layer00.scan", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine",
                 "layer01.shared", "layer02.attention", "final_norm", "policy_head", "value_head"),
+    # the second block's feed-forwards: a leading dense layer, a shared expert beside the held share
+    "share": ("embed", "layer00.attention", "layer00.dense", "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts",
+              "layer01.combine", "layer01.shared", "final_norm", "policy_head", "value_head"),
+    # the third's: the way through the latent beside the attention's scope, in every layer
+    "latent": ("embed", "layer00.attention", "layer00.latent", "layer00.dense", "layer01.attention", "layer01.latent", "layer01.router",
+               "layer01.dispatch", "layer01.experts", "layer01.combine", "layer01.shared", "final_norm", "policy_head", "value_head"),
+    # the fifth's: the mix of queries and keys beside the attention's scope
+    "cca": ("embed", "layer00.attention", "layer00.cca", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
+            "layer01.attention", "layer01.cca", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm",
+            "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
 SHARE = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8,
-                    dense_layers=1, dense_width=32, router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
+                    dense_layers=1, dense_width=32, shared_width=16, router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
 LATENT = TrunkConfig(**{**SHARE.__dict__, "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16})
 # the fourth block: a layer pattern, Mamba-2 mixers, ungated experts, a share held and balanced
 PATTERN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, qk_norm=False, pattern="ME*", experts=8, experts_per_token=2, expert_width=16,
                       gated_ffn=False, shared_width=8, value_hidden=8, mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=8,
                       router_score="sigmoid", route_norm=True, held_experts=(2, 4), balance_rate=0.001)
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN}
+# the fifth block: compressed convolutional attention, an MLP router, one expert a token
+CCA = TrunkConfig(hidden=32, heads=4, kv_heads=2, head_dim=8, layers=2, cca=(2, 2), rotary_dim=4, router_hidden=8, experts=8, experts_per_token=1,
+                  expert_width=16, value_hidden=8, held_experts=(2, 4), balance_rate=0.001)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA}
 
 
 def make(kind):
@@ -120,6 +133,18 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert any("transpose(jvp(forward))/" + MODEL_SCOPES[kind][0] in name for name in names)
     phases = {scopes.phase_of(name)[0] for name in names}
     assert {"forward", "backward", "optimizer"} <= phases
+
+
+@pytest.mark.parametrize("kind", ["share", "latent", "cca"])
+def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
+    """The scopes of the trunk's own parts in the three configurations
+    the fixture above does not compile, as PR 46's PARENT (3036d35) named
+    them, no more and no fewer: the benchmark's reducers read these names,
+    and a lowered step's text (the step pins) carries none of them."""
+    names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))
+    held = {scope for name in names for part in scopes._parts(name) for scope in [scopes._unwrap(part)[1]]}
+    own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$", scope)}
+    assert own == set(MODEL_SCOPES[kind]) - {"policy_head", "value_head"} and {"policy_head", "value_head"} <= held
 
 
 def test_no_heavy_instruction_is_unscoped(scoped):

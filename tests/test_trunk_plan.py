@@ -1,0 +1,100 @@
+"""What a trunk's lowered step text does not hold (``tools/step_text.py``
+prints what the step pins hash: a step takes its parameters as arguments,
+so neither their order nor their first values are in it), and the plan
+the program reads a ``TrunkConfig`` as, for the five blocks' tiny nets
+(``trunk_tiny.py BLOCKS``)."""
+
+from __future__ import annotations
+
+import hashlib
+
+import jax
+import numpy as np
+import pytest
+
+from fishnet_tpu.models import trunk
+from fishnet_tpu.models.trunk import Sublayer
+from trunk_tiny import BLOCKS
+
+_HEADS = "final_norm policy_w policy_b value_w value_b value_fc1_w value_fc1_b value_fc2_w value_fc2_b"
+_FIRST = "attn_norm wq wk wv q_norm k_norm wo moe_norm router_w experts_gate experts_up experts_down"
+#: ``list(trunk_param_shapes(cfg))`` and the sha256 of ``init_trunk_params(PRNGKey(0), cfg)``'s bytes in that order, read on
+#: PR 46's PARENT (3036d35), before the shapes' maker was rewritten by kind. ``init_trunk_params`` deals the split of its rng
+#: out in the order of the keys: reorder them and every tensor of every seed starts elsewhere, a share cell's rate follows
+#: its seed's held rows, and no step pin would notice. A PR that means to move either reads its own parent the same way.
+KEYS_AND_INIT_SHA256 = {
+    "llada": (f"embed_w embed_b {_FIRST} {_HEADS}", "fabaae8f6bd6f0e472ef85b73eca49a2fd6d7dfb5675bb93b25646dc47f8036a"),
+    "afmoe": (f"embed_w embed_b {_FIRST} {_HEADS} wgate post_attn_norm post_mlp_norm dense_gate dense_up dense_down shared_gate shared_up shared_down",
+              "9e9d9defb3eec212fe622fbf639933a553dd4aa6d173a48867e413bee1161a56"),
+    "mla": (f"embed_w embed_b attn_norm wq wkv_a kv_norm wkv_b wo moe_norm router_w experts_gate experts_up experts_down {_HEADS} "
+            "dense_gate dense_up dense_down shared_gate shared_up shared_down", "6a1f37e25c06fc7b657eb970aff645cd6325b9056fa9931b0780860f244d92a2"),
+    "hybrid": ("embed_w embed_b layer_norm mamba_in conv_w conv_b dt_bias A_log D_skip mamba_norm mamba_out wq wk wv wo router_w experts_up "
+               f"experts_down {_HEADS} shared_up shared_down", "1f3777e015a1ca8428d85d740210e08593b56c941a2ea555f3b8a075da38342e"),
+    "cca": ("embed_w embed_b attn_norm wq wk wv1 wv2 conv0_w conv0_b conv1_w conv1_b temp wo moe_norm router_down router_down_b router_w1 "
+            f"router_w1_b router_w2 router_w2_b router_w3 experts_gate experts_up experts_down {_HEADS}",
+            "02d03546c382512b911e8fa0998f8970e7fc73249ccd4031c3ff805d34c088a3"),
+}
+
+
+@pytest.mark.parametrize("block", KEYS_AND_INIT_SHA256)
+def test_a_blocks_tensors_come_in_the_parents_order_and_start_where_the_parents_do(block):
+    keys, digest = KEYS_AND_INIT_SHA256[block]
+    cfg = BLOCKS[block][0]
+    assert list(trunk.trunk_param_shapes(cfg)) == keys.split()
+    params = trunk.init_trunk_params(jax.random.PRNGKey(0), cfg)
+    assert list(params) == keys.split() and {k: v.shape for k, v in params.items()} == trunk.trunk_param_shapes(cfg)
+    sha = hashlib.sha256()
+    for value in params.values():
+        sha.update(np.asarray(value).tobytes())
+    assert sha.hexdigest() == digest
+    # the table and the shapes agree: a kind's tensors are its row's, and nothing but norms, the embedding and the heads is no kind's
+    owned = {name for names in trunk._OWNS.values() for name in names}
+    plain = {"embed_w", "embed_b", *_HEADS.split()} | {norm for s in trunk.trunk_plan(cfg) for norm in (s.norm, s.post_norm) if norm}
+    assert set(keys.split()) <= owned | plain and not owned & plain
+
+
+def _block_plan(mixer, layers, dense=0, nope=(), post=False):
+    """Two sublayers a layer, written out: the mixer under ``attn_norm[i]``, the feed-forward under ``moe_norm[i]``."""
+    plan = []
+    for i in range(layers):
+        plan.append(Sublayer(f"layer{i:02d}", mixer, i, "attn_norm", i, i not in nope, "post_attn_norm" if post else None))
+        kind, index = ("dense", i) if i < dense else ("routed", i - dense)
+        plan.append(Sublayer(f"layer{i:02d}", kind, index, "moe_norm", i, False, "post_mlp_norm" if post else None))
+    return tuple(plan)
+
+
+#: The plan of each tiny block, by hand: (scope prefix, kind, row of its kind's tensors, norm, norm's row, RoPE, post-norm).
+PLANS = {
+    "llada": _block_plan("attention", 2),
+    "afmoe": (  # three layers, the first dense, the last without RoPE, a post-norm a sublayer
+        Sublayer("layer00", "attention", 0, "attn_norm", 0, True, "post_attn_norm"), Sublayer("layer00", "dense", 0, "moe_norm", 0, False, "post_mlp_norm"),
+        Sublayer("layer01", "attention", 1, "attn_norm", 1, True, "post_attn_norm"), Sublayer("layer01", "routed", 0, "moe_norm", 1, False, "post_mlp_norm"),
+        Sublayer("layer02", "attention", 2, "attn_norm", 2, False, "post_attn_norm"), Sublayer("layer02", "routed", 1, "moe_norm", 2, False, "post_mlp_norm")),
+    "mla": _block_plan("latent", 3, dense=1),
+    "hybrid": (  # MEMEM*E: one sublayer a character, each under layer_norm[i], indexed from the first of its kind
+        Sublayer("layer00", "mamba", 0, "layer_norm", 0), Sublayer("layer01", "routed", 0, "layer_norm", 1), Sublayer("layer02", "mamba", 1, "layer_norm", 2),
+        Sublayer("layer03", "routed", 1, "layer_norm", 3), Sublayer("layer04", "mamba", 2, "layer_norm", 4),
+        Sublayer("layer05", "attention", 0, "layer_norm", 5, True), Sublayer("layer06", "routed", 2, "layer_norm", 6)),
+    "cca": _block_plan("cca", 2),
+}
+
+
+@pytest.mark.parametrize("block", PLANS)
+def test_the_plan_of_a_tiny_block_is_what_it_should_be(block):
+    cfg = BLOCKS[block][0]
+    plan = trunk.trunk_plan(cfg)
+    assert plan == PLANS[block]
+    assert plan is trunk.trunk_plan(cfg)  # made once a configuration
+    assert _block_plan("attention", 3, dense=1, nope=(2,), post=True) == PLANS["afmoe"]  # the helper against the one written out whole
+    # the plan and the configuration's own counts agree, and every kind has its function, its row and, a mixer, its reader
+    kinds = [s.kind for s in plan]
+    assert kinds.count("routed") == cfg.routed_layers and sum(k in ("attention", "latent", "cca") for k in kinds) == cfg.attention_layers
+    assert set(trunk._KINDS) == set(trunk._OWNS) == {*trunk._MIXERS, *trunk._FEED_FORWARDS} and set(trunk._SIZES) == set(trunk._MIXERS)
+    # a sublayer's slice is its norms and its row of the table, out of the stacked tensors
+    params = {name: np.zeros(shape, np.float32) for name, shape in {**trunk.trunk_param_shapes(cfg), **trunk.trunk_buffer_shapes(cfg)}.items()}
+    for sublayer in plan:
+        own = trunk.sublayer_params(params, sublayer)
+        assert set(own) == ({sublayer.norm, sublayer.post_norm, *trunk._OWNS[sublayer.kind]} - {None}) & set(params)
+        assert all(value.shape == params[name].shape[1:] for name, value in own.items())
+    sliced = list(trunk._sliced(params, plan))
+    assert [s for s, _ in sliced] == list(plan) and all(set(p) == set(trunk.sublayer_params(params, s)) for s, p in sliced)

@@ -233,6 +233,13 @@ def test_experts_step_at_published_widths_gathers_no_slot_rows(one_chip, compile
 BOARDS = 512  # moe_trunk_train_b512
 
 
+def _sublayer_shapes(cfg, sublayer, sharding):
+    """A sublayer's own tensors as float32 shapes on ``sharding``: ``trunk.sublayer_params`` of the stacked shapes."""
+    stacked = {name: jax.ShapeDtypeStruct(shape, jnp.float32) for name, shape in trunk.trunk_param_shapes(cfg).items()}
+    sliced = jax.eval_shape(lambda params: trunk.sublayer_params(params, sublayer), stacked)
+    return {name: jax.ShapeDtypeStruct(leaf.shape, jnp.float32, sharding=sharding) for name, leaf in sliced.items()}
+
+
 def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_chip, compiled_for_tpu):
     """``value_and_grad`` of ``_attention`` as the cell runs it, 16 heads x
     128 over 512 boards' tokens: the core is the two Pallas kernels, the
@@ -248,7 +255,7 @@ def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_ch
              "wv": sds((HIDDEN, inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
 
     def loss(x, p):
-        return jnp.sum(trunk._attention(x, p, cfg)[0])
+        return jnp.sum(trunk._attention(x, p, cfg, trunk.trunk_plan(cfg)[0])[0])
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -278,8 +285,11 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
              "wq": sds((HIDDEN, inner), jnp.float32), "wgate": sds((HIDDEN, inner), jnp.float32),
              "wk": sds((HIDDEN, kv_inner), jnp.float32), "wv": sds((HIDDEN, kv_inner), jnp.float32), "wo": sds((inner, HIDDEN), jnp.float32)}
 
-    def loss(x, p):
-        return jnp.sum(trunk._attention(x, p, cfg, rope=rope)[0])
+    sublayer = trunk.trunk_plan(cfg)[0]._replace(rope=rope)
+    assert sublayer.kind == "attention" and sublayer.post_norm == "post_attn_norm"
+
+    def loss(x, p):  # the branch and its post-norm, as the trunk's loop adds them
+        return jnp.sum(trunk._rms_norm(trunk._attention(x, p, cfg, sublayer)[0], p[sublayer.post_norm], cfg.rms_eps))
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -373,14 +383,14 @@ def test_latent_attention_compiles_at_published_widths_and_keeps_no_scores_or_co
 
     cfg = KANANA
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    layer = {name: sds(shape[1:], jnp.float32) for name, shape in trunk.trunk_param_shapes(cfg).items()
-             if name in ("attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo")}
+    sublayer = trunk.trunk_plan(cfg)[0]
+    layer = _sublayer_shapes(cfg, sublayer, one_chip)
     assert {k: v.shape for k, v in layer.items()} == {"attn_norm": (2048,), "wq": (2048, 6144), "wkv_a": (2048, 576), "kv_norm": (512,),
                                                       "wkv_b": (512, 8192), "wo": (4096, 2048)}
 
     def loss(x, p):
         with jax.named_scope("forward"):  # the phase a trainer's step puts first
-            return jnp.sum(trunk._attention(x, p, cfg)[0])
+            return jnp.sum(trunk._latent_attention(x, p, cfg, sublayer)[0])
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((KANANA_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -550,14 +560,15 @@ def test_a_mixer_at_published_widths_is_its_products_the_scan_and_two_kernel_pai
                             expert_width=1856, gated_ffn=False, shared_width=3712, rope_theta=1e4, rms_eps=1e-5, mamba_heads=64, mamba_head_dim=64,
                             mamba_groups=8, state_size=128, router_score="sigmoid", route_norm=True, route_scale=2.5, held_experts=(0, 8),
                             balance_rate=0.001, recompute_experts=True)
-    shapes = {name: shape[1:] for name, shape in trunk.trunk_param_shapes(cfg).items()
-              if name.startswith(("mamba_", "conv_")) or name in ("dt_bias", "A_log", "D_skip", "layer_norm")}
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    mixer = trunk.trunk_plan(cfg)[0]
+    layer = _sublayer_shapes(cfg, mixer, one_chip)
+    assert mixer.kind == "mamba" and set(layer) == {"layer_norm", *trunk._OWNS["mamba"]}
 
     def loss(x, p):
-        return jnp.sum(jnp.square(x + trunk._mamba(x, p, cfg, "layer00")[0]))  # a cotangent that waits for the result
+        return jnp.sum(jnp.square(x + trunk._mamba(x, p, cfg, mixer)[0]))  # a cotangent that waits for the result
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((SSM_BOARDS * 64, 2688)), {name: sds(shape) for name, shape in shapes.items()}).compile().as_text()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((SSM_BOARDS * 64, 2688)), layer).compile().as_text()
     kernels = sorted(line.split(" = ")[0].strip().lstrip("%").split(".")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line)
     assert kernels == ["board_scan", "board_scan_grad", "mamba_conv", "mamba_conv_grad", "mamba_gate_norm", "mamba_gate_norm_grad"], kernels
     assert f"f32[{SSM_BOARDS * 64},6144]" in text or f"f32[{SSM_BOARDS},64,6144]" in text  # the x B C product's result is there to be looked for
@@ -668,11 +679,12 @@ def test_the_core_with_a_gain_a_key_value_head_and_half_a_head_rotated_compiles_
     import re
 
     sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    layer = {name: sds(shape[1:]) for name, shape in trunk.trunk_param_shapes(CCA).items() if name in trunk._EVERY_LAYER and name != "moe_norm"}
+    sublayer = trunk.trunk_plan(CCA)[0]
+    layer = _sublayer_shapes(CCA, sublayer, one_chip)
     assert layer["wo"].shape == (1024, 2048) and layer["temp"].shape == (2,) and layer["conv1_w"].shape == (10, 2, 128, 128)
 
     def loss(x, p):
-        return jnp.sum(jnp.square(trunk._attention(x, p, CCA)[0]))
+        return jnp.sum(jnp.square(trunk._cca_attention(x, p, CCA, sublayer)[0]))
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((CCA_BOARDS * trunk.SQUARES, HIDDEN)), layer).compile().as_text()
     kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
