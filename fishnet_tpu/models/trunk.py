@@ -4,7 +4,7 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes five
+tower's own policy and value heads. ``TrunkConfig`` describes six
 published blocks as one code path at different values; no attention has
 a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
@@ -12,11 +12,13 @@ running them over a board removes nothing.
 The program reads a trunk as a LIST OF SUBLAYERS (``trunk_plan``, made once a configuration), each ``x <- x +
 [post-norm](kind(norm(x)))``, and runs ONE loop over it (``trunk_forward_counted``). A kind is one function ``(x, p, cfg,
 sublayer) -> (branch, counters by name)`` (a row of ``_KINDS``) and owns one row of ``_OWNS``, the table of stacked tensors
-by kind that ``trunk_param_shapes``, the loop's slices (``sublayer_params``) and the checkpoint reader go by. Four kinds mix
+by kind that ``trunk_param_shapes``, the loop's slices (``sublayer_params``) and the checkpoint reader go by. Five kinds mix
 tokens: ``attention`` (``_attention``), ``latent`` (``_latent_attention``: deepseek_v3's), ``cca`` (``_cca_attention``:
-zaya's), ``mamba`` (``_mamba``); two are feed-forwards: ``dense`` (``_dense_layer``), ``routed`` (``_routed_layer``). A
+zaya's), ``mamba`` (``_mamba``), ``kda`` (``_kda``: kimi_linear's); two are feed-forwards: ``dense`` (``_dense_layer``),
+``routed`` (``_routed_layer``). A
 layer of the first block is attention then routed, under ``attn_norm[i]`` and ``moe_norm[i]``; of the second, attention then
-dense or routed, a post-norm each; of the third, latent then dense or routed; of the fifth, cca then routed; a layer of the
+dense or routed, a post-norm each; of the third, latent then dense or routed; of the fifth, cca then routed; of the sixth, kda
+or latent, as ``mixers[i]`` says, then dense or routed; a layer of the
 fourth is ONE of mamba, routed and attention, as ``pattern`` says, under ``layer_norm[i]``. A new token mixer is one
 function, one row in each of the two tables, its shapes (``_kind_shapes``), its fields of ``TrunkConfig`` with their
 refusal, and one helper of the checkpoint reader (``_SIZES``): nothing inside another kind's function.
@@ -211,6 +213,61 @@ stay inside ``board_attention``, which is told ``g_q`` None beside a
 gain a key-value head and ``rotary_dim``. At one expert a token a "sum
 over a token's slots" is a select on the slot's mask (``_held_slots_sum``).
 
+The sixth block is Kimi-Linear-48B-A3B's (moonshotai, config.json,
+``model_type`` kimi_linear: hidden 2304, 27 layers, on 20 of them a Kimi
+Delta Attention mixer of 32 heads x 128 behind a convolution of 4, on 7
+(every fourth) latent attention of 32 heads WITHOUT RoPE
+(``mla_use_nope``; ``kv_lora_rank`` 512, 128 + 64 score columns over
+128-wide values), a leading dense layer of 9216, then 256 routed experts
+of width 1024, top-8, sigmoid scores, one shared expert,
+``routed_scaling_factor`` 2.446, RMSNorm eps 1e-5); what its config.json
+does not say is the Kimi Linear report's (arXiv:2510.26692) and the
+public layer's, listed under ``assumed`` in
+``benchmark/configs/kimi-linear-trunk-train.json`` each with its basis.
+``TrunkConfig.mixers`` is each layer's mixer by kind; H heads HELD (below),
+d = ``kda_head_dim``, P = H d, a board's 64 squares in index order::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer i   a = x + Mixer_i(N_in(x));   y = a + FFN_i(N_post(a))      Mixer_i = mixers[i]; two norms a layer; FFN as the third block's
+    kda       q, k, v = silu(conv(n W_q)), silu(conv(n W_k)), silu(conv(n W_v))     ``kda_q``, ``kda_k``, ``kda_v`` [hidden, P]; conv depthwise
+                                                                        along the squares, ``conv_kernel`` taps, causal, no bias (``kda_conv``
+                                                                        [3 P, taps]: q's, k's, v's channels in turn, the last tap the token's own)
+              q_h <- q_h / sqrt(|q_h|^2 + 1e-6) * d^-1/2;  k_h <- k_h / sqrt(|k_h|^2 + 1e-6)
+              g = -exp(A_log[h]) * softplus((n W_fa) W_fb + dt_bias)    [T, H, d] float32: a log-decay a CHANNEL, alpha = exp(g); ``kda_fa``
+                                                                        [hidden, d], ``kda_fb`` [d, P], ``kda_dt_bias`` [P], ``kda_A_log`` [H]
+              beta = sigmoid(n W_b)                                     [T, H]; ``kda_beta`` [hidden, H]
+              head h of board b, S [d, d] zero before square 0:
+                S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T;   o_t = S_t^T q_t
+              64 squares are ONE chunk from a zero state, so the recurrence is exactly its chunk form, which is computed
+              (``ops/board_delta.py`` says how, and how no exponent in it is ever positive):
+                c = cumsum(g);  Mk[t, j] = sum_c k_t k_j exp(c_t - c_j) (j < t);  Mq[t, j] = sum_c q_t k_j exp(c_t - c_j) (j <= t)
+                U = (I + Diag(beta) Mk)^-1 (beta * V);   O = Mq U
+              out = ( N_d(o; gain ``kda_o_norm`` [d]) * sigmoid((n W_ga) W_gb) ) W_o      ``kda_ga`` [hidden, d], ``kda_gb`` [d, P], ``kda_out`` [P, hidden]
+    latent    the third block's, its 64 further score columns NOT rotated on the layers of ``nope_layers`` (the kernel pair under
+              tables that turn nothing: ``board_attention`` told ``rotary_dim`` 0)
+    out       N_final(y) -> the heads
+
+Mechanism, the sixth block: q, k and v are ONE product on the joined
+weights (one read of the normed stream) and ONE convolution with its silu,
+the fourth block's kernel pair told three widths and a zero bias, whose
+three bfloat16 results are the core's operands as they are; the l2 norms,
+the cumulative sum, the six-level cut of the decayed products, the
+triangular solve and ``Mq U`` are one Pallas kernel pair
+(``ops/board_delta.py``: ``board_delta``, ``board_delta_grad``) under
+``layerNN.delta``, one head of a few boards a grid step, nothing ``[64,
+64]`` and no state in HBM; the low-rank gates, softplus, beta, the gated
+head norm and the out-projection are XLA's under ``layerNN.kda`` beside it.
+
+**Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
+heads HELD here, as ``held_experts`` is the experts': both mixers are sums
+over heads (a KDA head's state, norm and gate are its own; the latent is
+made once and every head reads it), so a chip that shares a layer's heads
+computes its own heads' columns of what is made a head and their partial
+sum through their ROWS of ``kda_out`` / ``wo``; the shares of all chips add
+up to the branch (``tests/test_kda_trunk.py``). No exchange, no all-reduce,
+nothing stands in for the absent heads; ``kda_fa``, ``kda_ga``,
+``kda_o_norm`` and ``wkv_a`` with its norm are whole on every chip.
+
 ``held_experts = (first, count)`` tells the expert layer which experts
 it holds, as one chip of an expert-parallel deployment does: it routes
 over all ``experts``, computes the part of the result that its own give
@@ -304,6 +361,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tg
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention
+from fishnet_tpu.ops.board_delta import board_delta
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
 from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
@@ -371,12 +429,22 @@ class TrunkConfig:
     cca: Optional[Tuple[int, int]] = None
     rotary_dim: Optional[int] = None
     router_hidden: int = 0
+    # What the sixth block adds (module docstring). ``mixers``: each layer's token mixer by its kind, "kda" (Kimi Delta Attention:
+    # ``kda_heads`` heads of ``kda_head_dim``, a convolution of ``conv_kernel``) or "latent" (the third block's, at ``heads``;
+    # without RoPE where the layer is among ``nope_layers``), before a dense or routed feed-forward; None: ONE mixer for the
+    # whole trunk, told by the fields above. A head count is the heads HELD here (the mixer's ``wo`` has their rows).
+    mixers: Optional[Tuple[str, ...]] = None
+    kda_heads: int = 0
+    kda_head_dim: int = 0
 
     def __post_init__(self) -> None:
         first, count = self.held
         latent = self.kv_lora_rank is not None
         pattern = self.pattern or ""
         cca = self.cca is not None
+        mixers = tuple(self.mixers or ())
+        if mixers and self.layers in (1, len(mixers)):
+            object.__setattr__(self, "layers", len(mixers))
         if pattern and self.layers in (1, len(pattern)):
             object.__setattr__(self, "layers", len(pattern))
         wrong = {
@@ -394,8 +462,9 @@ class TrunkConfig:
             f"{(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)}":
                 latent and min(self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) < 1,
             f"qk_rope_head_dim {self.qk_rope_head_dim} is odd: RoPE turns pairs": latent and self.qk_rope_head_dim % 2,
-            "latent attention has one key and value a query head from the latent (no kv_heads), no output gate and RoPE on every "
-            "layer (no nope_layers)": latent and (self.kv_heads is not None or self.gated_attention or self.nope_layers),
+            "latent attention has one key and value a query head from the latent (no kv_heads), no output gate and, but as a layer's own "
+            "of mixers, RoPE on every layer (no nope_layers)":
+                latent and (self.kv_heads is not None or self.gated_attention or (bool(self.nope_layers) and not mixers)),
             f"sliding_window {self.sliding_window} is under the {SQUARES} tokens of a board and the program applies no mask":
                 self.sliding_window is not None and self.sliding_window < SQUARES,
             f"{self.heads} query heads do not divide over {self.kv_heads} key-value heads": self.heads % (self.kv_heads or self.heads),
@@ -415,6 +484,12 @@ class TrunkConfig:
             "columns are qk_rope_head_dim)": self.rotary_dim is not None and (latent or self.rotary_dim % 2 != 0
                                                                                   or not 0 < self.rotary_dim <= self.head_dim),
             f"router_hidden {self.router_hidden} is under 0": self.router_hidden < 0,
+            f"mixers {self.mixers} are not {self.layers} of kda and latent, or stand beside a pattern or cca (which tell the mixer themselves) "
+            "or post_norms": bool(mixers) and (len(mixers) != self.layers or bool(set(mixers) - {"kda", "latent"}) or bool(pattern) or cca
+                                               or self.post_norms),
+            "a latent among the mixers wants kv_lora_rank and its three widths": "latent" in mixers and not latent,
+            f"a kda mixer wants kda_heads, kda_head_dim and conv_kernel over 0, got {(self.kda_heads, self.kda_head_dim, self.conv_kernel)}":
+                "kda" in mixers and min(self.kda_heads, self.kda_head_dim, self.conv_kernel) < 1,
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -430,7 +505,7 @@ class TrunkConfig:
 
     @property
     def attention_layers(self) -> int:
-        return self.pattern.count("*") if self.pattern else self.layers
+        return self.pattern.count("*") if self.pattern else self.layers - (self.mixers or ()).count("kda")
 
 
 class Sublayer(NamedTuple):
@@ -448,19 +523,21 @@ class Sublayer(NamedTuple):
 def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
     """The trunk's sublayers in order: one a character of a pattern, each
     under ``layer_norm[i]``; else two a layer, a token mixer under
-    ``attn_norm[i]`` and a feed-forward under ``moe_norm[i]``, dense in
+    ``attn_norm[i]`` (the layer's own of ``mixers``, or the one mixer of
+    the whole trunk) and a feed-forward under ``moe_norm[i]``, dense in
     the leading ``dense_layers``. Everything between ``embed`` and
     ``final_norm`` reads this and not the fields it is made from."""
     if cfg.pattern:
         kinds = [{"M": "mamba", "E": "routed", "*": "attention"}[kind] for kind in cfg.pattern]
         return tuple(Sublayer(f"layer{i:02d}", kind, kinds[:i].count(kind), "layer_norm", i, rope=kind == "attention") for i, kind in enumerate(kinds))
     mixer = "cca" if cfg.cca is not None else "latent" if cfg.kv_lora_rank is not None else "attention"
+    mixers = cfg.mixers or (mixer,) * cfg.layers  # a layer's mixer is row ``index`` of its kind's tensors: its place among that kind's layers
     # the attention branch's post-norm is the first kind's alone (a latent beside ``post_norms`` holds ``post_attn_norm`` and never reads it)
     after_mixer, after_ffn = ("post_attn_norm" if mixer == "attention" else None, "post_mlp_norm") if cfg.post_norms else (None, None)
     plan = []
     for i in range(cfg.layers):
         ffn = ("dense", i) if i < cfg.dense_layers else ("routed", i - cfg.dense_layers)
-        plan += [Sublayer(f"layer{i:02d}", mixer, i, "attn_norm", i, i not in cfg.nope_layers, after_mixer),
+        plan += [Sublayer(f"layer{i:02d}", mixers[i], mixers[:i].count(mixers[i]), "attn_norm", i, i not in cfg.nope_layers, after_mixer),
                  Sublayer(f"layer{i:02d}", *ffn, "moe_norm", i, post_norm=after_ffn)]
     return tuple(plan)
 
@@ -473,11 +550,13 @@ _OWNS = {
     "attention": ("wq", "wk", "wv", "q_norm", "k_norm", "wo", "wgate"),
     "latent": ("wq", "wkv_a", "kv_norm", "wkv_b", "wo"),
     "cca": ("wq", "wk", "wv1", "wv2", "conv0_w", "conv0_b", "conv1_w", "conv1_b", "temp", "wo"),
+    "kda": ("kda_q", "kda_k", "kda_v", "kda_conv", "kda_fa", "kda_fb", "kda_dt_bias", "kda_A_log", "kda_beta", "kda_ga", "kda_gb", "kda_o_norm",
+            "kda_out"),
     "dense": ("dense_gate", "dense_up", "dense_down"),
     "routed": ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias",
                "router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3"),
 }
-_MIXERS, _FEED_FORWARDS = ("mamba", "attention", "latent", "cca"), ("dense", "routed")
+_MIXERS, _FEED_FORWARDS = ("mamba", "attention", "latent", "cca", "kda"), ("dense", "routed")
 #: What the second block added to the first's file comes after the heads, in this order: ``init_trunk_params`` deals the
 #: split of its rng out in the order of ``trunk_param_shapes``' keys, so the order is every seed's tensors.
 _LATE = ("wgate", "post_attn_norm", "post_mlp_norm", "dense_gate", "dense_up", "dense_down", "shared_gate", "shared_up", "shared_down")
@@ -500,6 +579,11 @@ def _kind_shapes(cfg: TrunkConfig, kind: str, n: int) -> Dict[str, Tuple[int, ..
                 "conv0_w": (n, mixed, cfg.cca[0]), "conv0_b": (n, mixed),
                 "conv1_w": (n, groups, cfg.cca[1], cfg.head_dim, cfg.head_dim), "conv1_b": (n, mixed),
                 "temp": (n, cfg.kv_heads), "wo": (n, inner, h)}
+    if kind == "kda":  # q, k, v and the gates in ``heads x d`` columns; the two gates through a rank of one head's width
+        d, p = cfg.kda_head_dim, cfg.kda_heads * cfg.kda_head_dim
+        return {"kda_q": (n, h, p), "kda_k": (n, h, p), "kda_v": (n, h, p), "kda_conv": (n, 3 * p, cfg.conv_kernel),
+                "kda_fa": (n, h, d), "kda_fb": (n, d, p), "kda_dt_bias": (n, p), "kda_A_log": (n, cfg.kda_heads), "kda_beta": (n, h, cfg.kda_heads),
+                "kda_ga": (n, h, d), "kda_gb": (n, d, p), "kda_o_norm": (n, d), "kda_out": (n, p, h)}
     if kind == "mamba":
         mixer, state = cfg.mamba_heads * cfg.mamba_head_dim, cfg.mamba_groups * cfg.state_size
         return {"mamba_in": (n, h, 2 * mixer + 2 * state + cfg.mamba_heads), "conv_w": (n, mixer + 2 * state, cfg.conv_kernel),
@@ -562,7 +646,9 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     uniform in [1, 16], the steps ``softplus(dt_bias)`` log-uniform in
     [0.001, 0.1] and at least 0.0001 (``dt_bias`` their inverse
     softplus), the direct term ``D_skip`` 1, the convolution uniform
-    within 1 / sqrt(its taps) under a zero bias. The fifth block's mix
+    within 1 / sqrt(its taps) under a zero bias; a KDA mixer's rates
+    ``exp(kda_A_log)``, steps ``softplus(kda_dt_bias)`` (a channel) and
+    taps ``kda_conv`` alike. The fifth block's mix
     starts as a pass: ``conv0_w`` 1 at the token's own tap and 0 at the
     earlier ones, ``conv1_w`` the identity at the token's own tap, both
     biases and the router MLP's zero, the key temperature ``temp`` 1. The
@@ -585,12 +671,12 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
             params[name] = jnp.zeros(shape, jnp.float32).at[:, :, -1].set(jnp.eye(shape[-1], dtype=jnp.float32))
         elif name in _ROUTER_HIDDEN:  # [layers, router_hidden, .]: by their fan-in (the docstring above says why)
             params[name] = jax.random.normal(keys[name], shape, jnp.float32) / math.sqrt(shape[-2])
-        elif name == "A_log":
+        elif name in ("A_log", "kda_A_log"):
             params[name] = jnp.log(uniform(name, 1.0, 16.0))
-        elif name == "dt_bias":
+        elif name in ("dt_bias", "kda_dt_bias"):
             step = jnp.maximum(jnp.exp(uniform(name, math.log(_TIME_STEP_MIN), math.log(_TIME_STEP_MAX))), _TIME_STEP_FLOOR)
             params[name] = step + jnp.log(-jnp.expm1(-step))
-        elif name == "conv_w":
+        elif name in ("conv_w", "kda_conv"):
             params[name] = uniform(name, -1.0, 1.0) / math.sqrt(shape[-1])
         # a bias is a vector (``wkv_b`` is a matrix), or the convolution's, a vector a mixer
         elif (name.endswith("_b") and len(shape) == 1) or name in ("value_fc2_w", "conv_b", *_STACKED_BIASES):
@@ -807,7 +893,7 @@ def _latent_attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Subla
         k, v = _matmul(c, p["wkv_b"][:, :split]), _matmul(c, p["wkv_b"][:, split:]).astype(jnp.bfloat16)
     with jax.named_scope(f"{layer}.attention"):
         mixed = board_attention(_by_board(q), _by_board(k), _by_board(v), None, None, cfg.rope_theta, cfg.rms_eps, _interpret(),
-                                q_pe=_by_board(q_pe), k_pe=_by_board(k_pe))
+                                q_pe=_by_board(q_pe), k_pe=_by_board(k_pe), rotary_dim=None if sublayer.rope else 0)
         return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), {"latent_rms": latent_rms}
 
 
@@ -835,6 +921,35 @@ def _cca_attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer
         mixed = board_attention(q, k, v, None, temp, cfg.rope_theta, cfg.rms_eps, _interpret(), rotary_dim=cfg.rotary_dim)
         counters = {"cca_conv_share": jnp.sqrt(changed), "cca_temp_max": jnp.max(jax.lax.stop_gradient(p["temp"]))}
         return _matmul(mixed.reshape(x.shape[0], -1), p["wo"]), counters
+
+
+def _kda(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The kda kind (the sixth block's): [tokens, hidden] float32, 64
+    tokens a board -> a Kimi Delta Attention mixer's output, same shape,
+    and its two counters. All of it runs under ``<layer>.kda`` but the
+    delta rule's core (``board_delta``, which also makes the l2 norms of
+    q and k), under ``<layer>.delta`` beside it, never inside (module
+    docstring, "Mechanism, the sixth block"). q, k and v are ONE product
+    on the joined weights and ONE convolution with its silu (the fourth
+    block's kernel pair, told three widths and a zero bias), whose three
+    bfloat16 results are the core's operands as they are."""
+    heads, d, layer, tokens = cfg.kda_heads, cfg.kda_head_dim, sublayer.layer, x.shape[0]
+    inner = heads * d
+    with jax.named_scope(f"{layer}.kda"):
+        n = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+        qkv = _by_board(_matmul(n, jnp.concatenate([p["kda_q"], p["kda_k"], p["kda_v"]], axis=1)))
+        q, k, v = mamba_conv(qkv, p["kda_conv"], jnp.zeros((3 * inner,), jnp.float32), (inner, inner, inner), _interpret())
+        step = jax.nn.softplus(_matmul(_matmul(n, p["kda_fa"]), p["kda_fb"]) + p["kda_dt_bias"])
+        g = step * jnp.repeat(-jnp.exp(p["kda_A_log"]), d)  # a log-decay a channel: alpha = exp(g)
+        beta = jax.nn.sigmoid(_matmul(n, p["kda_beta"]))
+        kept, written = jax.lax.stop_gradient((g, beta))
+        counters = {"kda_state_kept": jnp.mean(jnp.exp(kept)), "kda_beta": jnp.mean(written)}
+    with jax.named_scope(f"{layer}.delta"):
+        o = board_delta(q, k, v, _by_board(g), _by_board(beta), _interpret())
+    with jax.named_scope(f"{layer}.kda"):
+        gate = jax.nn.sigmoid(_matmul(_matmul(n, p["kda_ga"]), p["kda_gb"]))
+        normed = _rms_norm(o.reshape(tokens, heads, d), p["kda_o_norm"], cfg.rms_eps)  # a head's own norm, one gain for all heads
+        return _matmul(normed.reshape(tokens, inner) * gate, p["kda_out"]), counters
 
 
 def _interpret() -> bool:
@@ -1281,7 +1396,7 @@ def _routed_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer)
 #: Kind of sublayer -> its function, ``(x, p, cfg, sublayer) -> (branch, counters by name)``, which opens its own scopes and is
 #: called under none, and the scope under which the loop adds the branch (through the sublayer's post-norm) to the stream.
 _KINDS = {"attention": (_attention, "attention"), "latent": (_latent_attention, "attention"), "cca": (_cca_attention, "attention"),
-          "mamba": (_mamba, "mamba"), "dense": (_dense_layer, "dense"), "routed": (_routed_layer, "combine")}
+          "mamba": (_mamba, "mamba"), "kda": (_kda, "kda"), "dense": (_dense_layer, "dense"), "routed": (_routed_layer, "combine")}
 
 #: The order in which ONE layer's slices are made: nothing but the lowered text depends on it, and the step pins hold that text
 #: (``tests/test_hybrid_trunk.py PARENT_STEP_SHA256``). It is the order in which the blocks came: a block layer's feed-forward
@@ -1366,6 +1481,8 @@ _FOLDS = {
     "route_top1_weight": jnp.mean,  # at one expert a token, the chosen expert's score: at 1.0 the router has no gradient left, at 1 / experts it has not chosen
     "ssm_dt_mean": jnp.mean,  # the mean step ``D_t`` after its softplus, over tokens, heads and mixers
     "ssm_decay_min": jnp.min,  # the smallest decay across a board, ``exp(c_63 - c_0)``, of any head of any mixer, mean over boards: a head that forgets a board
+    "kda_state_kept": jnp.mean,  # the mean decay ``alpha = exp(g)`` a square, over tokens, heads, channels and KDA mixers: 1 a state that never forgets, 0 one that holds nothing
+    "kda_beta": jnp.mean,  # the mean ``beta``, the share of a square's value written over what the state held for its key: 0 a mixer that writes nothing
 }
 
 
@@ -1387,6 +1504,8 @@ _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_
             "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim")
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
+#: A checkpoint whose mixer is told by layer carries ``mixers``, each layer's kind as its place in ``_MIXERS``.
+MIXERS = "trunk_mixers"
 
 
 def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
@@ -1398,7 +1517,8 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     are no qk-norm gains to read it from; mamba_groups) and the fifth's
     rotary_dim (0: RoPE on all of a head; its kernel sizes, head width
     and router width are shapes). A pattern's file carries the pattern
-    too (``trunk_pattern``, its characters as bytes).
+    too (``trunk_pattern``, its characters as bytes), one whose mixer is
+    told by layer its ``mixers`` (``trunk_mixers``).
     ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
     arrays[HPARAMS] = np.asarray([
@@ -1408,6 +1528,8 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
         cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0], np.float64)
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
+    if cfg.mixers:
+        arrays[MIXERS] = np.asarray([_MIXERS.index(kind) for kind in cfg.mixers], np.uint8)
     return arrays
 
 
@@ -1432,6 +1554,10 @@ def _cca_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]
     return dict(heads=shape("wq")[2] // head_dim, head_dim=head_dim, kv_heads=shape("wk")[2] // head_dim, cca=(shape("conv0_w")[2], shape("conv1_w")[2]))
 
 
+def _kda_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the heads from the rates, a head's width from its norm's gain
+    return dict(kda_heads=shape("kda_A_log")[1], kda_head_dim=shape("kda_o_norm")[1], conv_kernel=shape("kda_conv")[2])
+
+
 def _mamba_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the groups from the file, the rest from three shapes
     heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
     return dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
@@ -1441,7 +1567,8 @@ def _mamba_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, objec
 #: Token mixer -> the tensors of its row of ``_OWNS`` that its sizes are read from, and the reader (above) of its fields of
 #: ``TrunkConfig`` from those tensors' shapes (``shape``) and the file's values (``hp``).
 _SIZES = {"attention": (("wq", "wk", "wo"), _attention_sizes), "latent": (("kv_norm", "wkv_a", "wkv_b"), _latent_sizes),
-          "cca": (("conv0_w", "conv1_w", "wk", "wv1", "wv2", "temp"), _cca_sizes), "mamba": (("mamba_norm", "dt_bias", "conv_w"), _mamba_sizes)}
+          "cca": (("conv0_w", "conv1_w", "wk", "wv1", "wv2", "temp"), _cca_sizes), "mamba": (("mamba_norm", "dt_bias", "conv_w"), _mamba_sizes),
+          "kda": (("kda_A_log", "kda_o_norm", "kda_conv"), _kda_sizes)}
 assert all(set(names) <= set(_OWNS[kind]) for kind, (names, _) in _SIZES.items())
 
 
@@ -1450,9 +1577,13 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     ``trunk_hparams`` and, a pattern's, its ``trunk_pattern``; a
     ValueError names what does not fit."""
     pattern = bytes(np.asarray(params[PATTERN], np.uint8)).decode("ascii") if PATTERN in params else None
+    places = [int(i) for i in np.asarray(params[MIXERS]).reshape(-1)] if MIXERS in params else None
+    if places is not None and any(not 0 <= i < len(_MIXERS) for i in places):
+        raise ValueError(f"trunk checkpoint: {MIXERS} {places} are not places in {_MIXERS}")
+    by_layer = None if places is None else tuple(_MIXERS[i] for i in places)
     router = "router_w3" if "router_down" in params else "router_w"  # the MLP router's last matrix: its columns are the experts
     norm = "attn_norm" if pattern is None else "layer_norm"
-    required = (router, "experts_gate", "wq", "wo", norm, "experts_up") if pattern is None else (router, "experts_up", norm)
+    required = (router, "experts_gate", "wq", "wo", norm, "experts_up") if pattern is None and by_layer is None else (router, "experts_up", norm)
     not_one = lambda missing: f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}..."
     missing = [k for k in (*required, "value_fc1_b", "policy_b", HPARAMS) if k not in params]
     if missing:
@@ -1466,6 +1597,8 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     width_of = lambda name: shape(name)[2] if name in params else 0
     if pattern is not None:  # the mixers its characters name
         mixers, what = [kind for kind, mark in (("mamba", "M"), ("attention", "*")) if mark in pattern], f"a pattern {pattern!r}"
+    elif by_layer is not None:  # the mixers its layers name
+        mixers, what = [kind for kind in _MIXERS if kind in by_layer], f"mixers {by_layer}"
     elif "conv0_w" in params:
         mixers, what = ["cca"], "compressed convolutional attention (conv0_w)"
     elif "kv_norm" in params:
@@ -1477,7 +1610,7 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         raise ValueError(f"trunk checkpoint: {what} without {missing}" if what else not_one(missing))
     sizes = {field: value for kind in mixers for field, value in _SIZES[kind][1](params, shape, hp).items()}
     (routed, _, experts), (layers, hidden) = shape(router), shape(norm)
-    layout = dict(pattern=pattern) if pattern is not None else dict(layers=layers, dense_layers=layers - routed)
+    layout = dict(pattern=pattern) if pattern is not None else dict(layers=layers, dense_layers=layers - routed, mixers=by_layer)
     return _checked(params, len(given), lambda: TrunkConfig(
         hidden=hidden, **layout, **sizes,
         experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_up")[3],
@@ -1500,7 +1633,8 @@ def _checked(params: Params, given: int, make) -> TrunkConfig:
         cfg = make()
     except ValueError as err:
         raise ValueError(f"trunk checkpoint: mismatched shapes: {err}") from err
-    expected = {**trunk_param_shapes(cfg), **trunk_buffer_shapes(cfg), HPARAMS: (given,), **({PATTERN: (cfg.layers,)} if cfg.pattern else {})}
+    expected = {**trunk_param_shapes(cfg), **trunk_buffer_shapes(cfg), HPARAMS: (given,), **({PATTERN: (cfg.layers,)} if cfg.pattern else {}),
+                **({MIXERS: (cfg.layers,)} if cfg.mixers else {})}
     got = {k: tuple(np.shape(v)) for k, v in params.items()}
     if expected != got:
         diff = set(expected) ^ set(got) or {k for k in expected if expected[k] != got[k]}

@@ -329,7 +329,8 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     extent of RoPE (``theta`` None: none of the score width; no ``k_pe``:
     all of it, or with ``rotary_dim`` the first ``rotary_dim`` columns of
     every head, rotate-half inside them; with ``q_pe`` and ``k_pe``: those
-    trailing columns alone)
+    trailing columns alone, or with ``rotary_dim`` 0 none: a latent NoPE
+    layer, whose ``q_pe`` and ``k_pe`` are more score columns)
     and whether the rotated part of k is one a key-value head (it is part
     of ``k``) or one for all heads (``k_pe`` ``[boards, 64, rope]``).
 
@@ -341,8 +342,8 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     value]`` -> bfloat16 of v's shape. The combinations the kernels do
     not compute are refused."""
     latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
-    if all(latent) and theta is not None and rotary_dim is None:
-        return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta, interpret)
+    if all(latent) and theta is not None and rotary_dim in (None, 0):  # 0: the same pair under tables that turn nothing (``_latent_tables``)
+        return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta if rotary_dim is None else None, interpret)
     if latent == (True, True, False, False) and head_dim is not None:  # the grouped form without its norm: the gains are not read
         ones = jnp.ones((head_dim,), jnp.float32)
         return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False, rotary_dim)
@@ -449,13 +450,19 @@ def latent_column_order(heads: int, nope: int, rope: int) -> np.ndarray:
     return np.concatenate([(per_head + np.arange(nope)[None, :]).reshape(-1), (per_head + nope + halves[None, :]).reshape(-1)])
 
 
-def _latent_tables(theta: float, rope: int) -> np.ndarray:
+def _latent_tables(theta: Optional[float], rope: int) -> np.ndarray:
     """``[1 + per, 3, 64, 128]`` float32, ``per`` = 128 // rope heads a
     tile: cos, and the sine split by which lane rotation brings the
     partner (``_rotated``), for the whole tile (index 0: ``k_pe``
-    repeated) and zero off each head's lanes (1 + j: head j of a tile)."""
+    repeated) and zero off each head's lanes (1 + j: head j of a tile).
+    ``theta`` None is the form without a rotation (a latent NoPE layer:
+    ``q_pe`` and ``k_pe`` are 64 more score columns, one key for all
+    heads): cos 1 and sin 0, so the kernels, which read the tables as an
+    operand, are the rotated form's to the last instruction, and the
+    tables still cut each head's lanes out of its tile."""
     per, half = _LANES // rope, rope // 2
-    cos, sin = rope_tables(theta, rope)  # [64, rope], the sine signed: minus on the first half
+    # [64, rope], the sine signed: minus on the first half
+    cos, sin = rope_tables(theta, rope) if theta is not None else (np.ones((SQUARES, rope), np.float32), np.zeros((SQUARES, rope), np.float32))
     cos, sin = np.tile(cos, per), np.tile(sin, per)
     first = (np.arange(_LANES) % rope) < half
     whole = np.stack([cos, np.where(first, 0.0, sin), np.where(first, sin, 0.0)])  # partner at lane - half, at lane + half
@@ -581,7 +588,7 @@ def _latent_blocks(q, q_pe, k, k_pe, v):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _latent_attention(q, q_pe, k, k_pe, v, theta: float, interpret: bool):
+def _latent_attention(q, q_pe, k, k_pe, v, theta: Optional[float], interpret: bool):
     grid, _, hg, specs = _latent_blocks(q, q_pe, k, k_pe, v)
     rope = k_pe.shape[-1]
     return pl.pallas_call(

@@ -49,6 +49,9 @@ MODEL_SCOPES = {
     "cca": ("embed", "layer00.attention", "layer00.cca", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
             "layer01.attention", "layer01.cca", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm",
             "policy_head", "value_head"),
+    # the sixth's: the mixer told by layer, the delta rule's core beside a KDA layer's scope and never inside it, the latent layer as the third's
+    "kda": ("embed", "layer00.kda", "layer00.delta", "layer00.dense", "layer01.attention", "layer01.latent", "layer01.router", "layer01.dispatch",
+            "layer01.experts", "layer01.combine", "layer01.shared", "final_norm", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
@@ -62,7 +65,9 @@ PATTERN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, qk_norm=False
 # the fifth block: compressed convolutional attention, an MLP router, one expert a token
 CCA = TrunkConfig(hidden=32, heads=4, kv_heads=2, head_dim=8, layers=2, cca=(2, 2), rotary_dim=4, router_hidden=8, experts=8, experts_per_token=1,
                   expert_width=16, value_hidden=8, held_experts=(2, 4), balance_rate=0.001)
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA}
+# the sixth block: a Kimi Delta Attention layer, then a latent layer without RoPE
+KDA = TrunkConfig(**{**LATENT.__dict__, "mixers": ("kda", "latent"), "nope_layers": (1,), "kda_heads": 2, "kda_head_dim": 16})
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA}
 
 
 def make(kind):
@@ -135,12 +140,13 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert {"forward", "backward", "optimizer"} <= phases
 
 
-@pytest.mark.parametrize("kind", ["share", "latent", "cca"])
+@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda"])
 def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
-    """The scopes of the trunk's own parts in the three configurations
-    the fixture above does not compile, as PR 46's PARENT (3036d35) named
-    them, no more and no fewer: the benchmark's reducers read these names,
-    and a lowered step's text (the step pins) carries none of them."""
+    """The scopes of the trunk's own parts in the configurations the
+    fixture above does not compile, as PR 46's PARENT (3036d35) named them
+    (the sixth block's as PR 47 brought them), no more and no fewer: the
+    benchmark's reducers read these names, and a lowered step's text (the
+    step pins) carries none of them."""
     names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))
     held = {scope for name in names for part in scopes._parts(name) for scope in [scopes._unwrap(part)[1]]}
     own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$", scope)}
@@ -313,6 +319,7 @@ STEP_KEYS = {
     "share": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max"},
     "latent": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms"},
     "pattern": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "ssm_dt_mean", "ssm_decay_min"},
+    "kda": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms", "kda_state_kept", "kda_beta"},
 }
 
 
@@ -425,7 +432,7 @@ def test_a_collected_trainer_takes_its_ring(steps):
     assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-1"}
 
 
-@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern"])
+@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern", "kda"])
 def test_collector_serves_each_scalar_of_the_latest_step(kind, steps):
     """``fishnet_train_step{trainer,key}`` for exactly the keys the kind's
     step returns, and ``fishnet_train_steps_total{trainer}``: every counter

@@ -1,7 +1,7 @@
 """What a trunk's lowered step text does not hold (``tools/step_text.py``
 prints what the step pins hash: a step takes its parameters as arguments,
 so neither their order nor their first values are in it), and the plan
-the program reads a ``TrunkConfig`` as, for the five blocks' tiny nets
+the program reads a ``TrunkConfig`` as, for the six blocks' tiny nets
 (``trunk_tiny.py BLOCKS``)."""
 
 from __future__ import annotations
@@ -33,6 +33,10 @@ KEYS_AND_INIT_SHA256 = {
     "cca": ("embed_w embed_b attn_norm wq wk wv1 wv2 conv0_w conv0_b conv1_w conv1_b temp wo moe_norm router_down router_down_b router_w1 "
             f"router_w1_b router_w2 router_w2_b router_w3 experts_gate experts_up experts_down {_HEADS}",
             "02d03546c382512b911e8fa0998f8970e7fc73249ccd4031c3ff805d34c088a3"),
+    # the sixth block, read on the tree of the PR that brought it (PR 47): the latent's tensors, then the KDA mixer's, under one ``attn_norm``
+    "kda": ("embed_w embed_b attn_norm wq wkv_a kv_norm wkv_b wo kda_q kda_k kda_v kda_conv kda_fa kda_fb kda_dt_bias kda_A_log kda_beta kda_ga "
+            f"kda_gb kda_o_norm kda_out moe_norm router_w experts_gate experts_up experts_down {_HEADS} dense_gate dense_up dense_down shared_gate "
+            "shared_up shared_down", "f59c743787cb0a103838cbb9dc8b656acf5089f5ccdc243f012bd6ec07bdad88"),
 }
 
 
@@ -76,6 +80,12 @@ PLANS = {
         Sublayer("layer03", "routed", 1, "layer_norm", 3), Sublayer("layer04", "mamba", 2, "layer_norm", 4),
         Sublayer("layer05", "attention", 0, "layer_norm", 5, True), Sublayer("layer06", "routed", 2, "layer_norm", 6)),
     "cca": _block_plan("cca", 2),
+    "kda": (  # the mixer told by layer: KDA KDA KDA MLA KDA, each indexed from the first of its kind; the latent layer without RoPE; layer 0 dense
+        Sublayer("layer00", "kda", 0, "attn_norm", 0, True), Sublayer("layer00", "dense", 0, "moe_norm", 0),
+        Sublayer("layer01", "kda", 1, "attn_norm", 1, True), Sublayer("layer01", "routed", 0, "moe_norm", 1),
+        Sublayer("layer02", "kda", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 1, "moe_norm", 2),
+        Sublayer("layer03", "latent", 0, "attn_norm", 3, False), Sublayer("layer03", "routed", 2, "moe_norm", 3),
+        Sublayer("layer04", "kda", 3, "attn_norm", 4, True), Sublayer("layer04", "routed", 3, "moe_norm", 4)),
 }
 
 
@@ -98,3 +108,23 @@ def test_the_plan_of_a_tiny_block_is_what_it_should_be(block):
         assert all(value.shape == params[name].shape[1:] for name, value in own.items())
     sliced = list(trunk._sliced(params, plan))
     assert [s for s, _ in sliced] == list(plan) and all(set(p) == set(trunk.sublayer_params(params, s)) for s, p in sliced)
+
+
+def test_a_mixed_plan_is_the_one_mixers_plan_where_every_layer_names_the_same():
+    """``mixers`` that name the latent on every layer give the third block's plan, tensors and key order (the field adds
+    nothing where it changes nothing), but for RoPE, which a latent takes off only as a layer's own of ``mixers``."""
+    import dataclasses
+
+    mla = BLOCKS["mla"][0]
+    told = dataclasses.replace(mla, mixers=("latent",) * mla.layers)
+    assert trunk.trunk_plan(told) == trunk.trunk_plan(mla) and trunk.trunk_param_shapes(told) == trunk.trunk_param_shapes(mla)
+    assert list(trunk.trunk_param_shapes(told)) == list(trunk.trunk_param_shapes(mla))
+    unrotated = dataclasses.replace(told, nope_layers=(1,))
+    assert [s.rope for s in trunk.trunk_plan(unrotated) if s.kind == "latent"] == [True, False, True]
+    with pytest.raises(ValueError, match="nope_layers"):
+        dataclasses.replace(mla, nope_layers=(1,))
+    # the rows of a kind's stacked tensors count that kind's layers alone
+    kda = BLOCKS["kda"][0]
+    shapes = trunk.trunk_param_shapes(kda)
+    assert shapes["kda_q"][0] == 4 and shapes["wq"][0] == 1 and shapes["attn_norm"][0] == shapes["moe_norm"][0] == 5
+    assert (kda.attention_layers, kda.routed_layers) == (1, 4)

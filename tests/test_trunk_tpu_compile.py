@@ -444,7 +444,7 @@ def _held_as_the_trainer_holds_it(trainer, one_chip):
 #: the bytes of each with its two moments: Nemotron's 1,856 is 14.5 lane tiles, so ``experts_up``; every other width is whole lanes.
 HELD = {"az": ("az-256x19-train", (), 0), "moe_trunk": ("lladamoe-trunk-train", (), 0), "afmoe_trunk": ("trinity-mini-trunk-train", (), 0),
         "mla_trunk": ("kanana-2-trunk-train", (), 0), "hybrid_trunk": ("nemotron-twotower-trunk-train", ("experts_up",), 3 * 4 * 3 * 8 * 2688 * 1856),
-        "cca_trunk": ("zaya1-trunk-train", (), 0)}
+        "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0)}
 
 
 @pytest.mark.parametrize("family", HELD)
@@ -720,4 +720,61 @@ def test_the_fifth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     assert not _xla_passes_over_slots(text, CCA_BOARDS * trunk.SQUARES)  # at one slot a token the slots are the tokens: still the kernels alone
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.0  # 4.79 + 7.46 GiB when this was written
+    assert ".remat" not in text
+
+
+# -- the sixth block (kimi_linear) at its published widths: hidden 2304 = 18 x 128, 16 held heads of 128 a mixer ------------------------
+
+KDA_BOARDS = 128  # kda_trunk_train_b128
+
+
+def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``board_delta`` and ``board_delta_grad`` on a batch of the cell: 16
+    held heads x 128, a head of eight boards a grid step, the six levels'
+    masks from bit operations on iotas, the cumulative sum and the solve's
+    float32 products at ``highest``, a board's ``[64, 16]`` block of dbeta
+    resident over the heads' steps."""
+    from fishnet_tpu.ops.board_delta import board_delta
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    wide = (KDA_BOARDS, 64, 16 * 128)
+    args = (sds(wide, jnp.bfloat16), sds(wide, jnp.bfloat16), sds(wide, jnp.bfloat16), sds(wide, jnp.float32), sds((KDA_BOARDS, 64, 16), jnp.float32))
+    loss = lambda *a: jnp.sum(jnp.square(board_delta(*a, False).astype(jnp.float32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(*args).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_delta_grad" in kernel for kernel in kernels) == 1 and all("board_delta" in kernel for kernel in kernels), kernels
+
+
+def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+    """The whole step of ``kda_trunk_train_b128`` from its configuration
+    file: the delta pair and the convolution pair a KDA layer (four), the
+    latent form of the attention pair on the one latent layer (under the
+    tables that turn nothing), a moved row of 2,304 = 18 lane tiles as
+    3,072, no leaf held off row-major, nothing remade to fit."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "kimi-linear-trunk-train.json").read_text())
+    trainer = importlib.import_module("benchmark.families.kda_trunk").make_trainer(config)
+    cfg = trainer.cfg
+    assert (cfg.mixers, cfg.nope_layers, cfg.kda_heads, cfg.heads, cfg.hidden) == (("kda", "kda", "kda", "latent", "kda"), (3,), 16, 16, 2304)
+    assert config["train"]["batch"] == KDA_BOARDS
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == 416_608_910  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((KDA_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((KDA_BOARDS, 4672)), "value_target": jnp.zeros((KDA_BOARDS,))}
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)  # whole-lane widths: the client's default is the step's layout
+    names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("board_delta_grad" in n for n in names) == 4 and sum("board_delta" in n for n in names) == 8, names
+    assert sum("mamba_conv_grad" in n for n in names) == 4 and sum("mamba_conv" in n for n in names) == 8
+    assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):  # the core's scope beside the mixer's, the latent's beside the attention's
+        assert all(f"{phase}/layer0{i}.delta/" in text and f"{phase}/layer0{i}.kda/" in text for i in (0, 1, 2, 4)) and f"{phase}/layer03.delta/" not in text
+        assert f"{phase}/layer03.latent/" in text and f"{phase}/layer03.attention/" in text
+    assert not _xla_passes_over_slots(text, KDA_BOARDS * trunk.SQUARES * cfg.experts_per_token)
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.0  # 4.66 + 7.88 GiB when this was written
     assert ".remat" not in text

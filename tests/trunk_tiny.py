@@ -1,6 +1,6 @@
 """What the trunk's test files share (``test_moe_trunk.py``, ``test_afmoe_trunk.py``,
-``test_mla_trunk.py``, ``test_hybrid_trunk.py``, ``test_cca_trunk.py``, and
-``tools/step_text.py``): the five blocks' tiny configurations, their
+``test_mla_trunk.py``, ``test_hybrid_trunk.py``, ``test_cca_trunk.py``, ``test_kda_trunk.py`` and
+``tools/step_text.py``): the six blocks' tiny configurations, their
 batches, the conditioned parameters, the plain formulas' norm and RoPE,
 and the tolerances with the readings they were set from. No test lives
 here: until PR 46 the first three blocks' 130 tests were one file, one
@@ -118,6 +118,21 @@ CCA = TrunkConfig(hidden=128, heads=8, kv_heads=2, head_dim=8, layers=2, cca=(2,
                   expert_width=32, rope_theta=5e6, rms_eps=1e-5, value_hidden=32, held_experts=(4, 8), balance_rate=0.001)
 
 
+# -- the sixth block (kimi_linear): the mixer told by layer, three Kimi Delta Attention layers to one latent layer without RoPE;
+# -- ``KDA_MODEL`` is the same net as the benchmark's reference reads it (benchmark/reference/kda_trunk.py) -----------------------
+
+KDA_MODEL = {"hidden_size": 64, "num_attention_heads": 2, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16,
+             "mla_use_nope": True, "kda_num_heads": 2, "kda_head_dim": 16, "short_conv_kernel_size": 4, "mixers": ["kda", "kda", "kda", "latent", "kda"],
+             "num_hidden_layers": 5, "num_dense_layers": 1, "intermediate_size": 96, "moe_intermediate_size": 32, "num_shared_experts": 1,
+             "num_experts": 8, "num_routed_experts": 16, "first_held_expert": 4, "num_experts_per_tok": 3, "route_scale": 2.446,
+             "load_balance_coeff": 0.001, "rope_theta": 10000, "rms_norm_eps": 1e-05, "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
+KDA_CONFIG = {"model": KDA_MODEL, "train": {"value_weight": 1.0}}
+KDA = TrunkConfig(hidden=64, heads=2, experts=16, experts_per_token=3, expert_width=32, rope_theta=1e4, rms_eps=1e-5, value_hidden=32,
+                  dense_layers=1, dense_width=96, shared_width=32, router_score="sigmoid", route_norm=True, route_scale=2.446,
+                  held_experts=(4, 8), balance_rate=0.001, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=64, v_head_dim=16,
+                  mixers=("kda", "kda", "kda", "latent", "kda"), nope_layers=(3,), kda_heads=2, kda_head_dim=16)
+
+
 def board_batch(seed: int, n: int = BATCH):
     """Boards as the encoder writes them: at most ONE piece plane a square (the reference's router chooses on it, with a
     margin no rounding flips), castling planes a board, the halfmove fraction, the plane of ones."""
@@ -134,6 +149,7 @@ def board_batch(seed: int, n: int = BATCH):
             "value_target": jnp.asarray(rng.uniform(-1, 1, n).astype(np.float32))}
 
 
-#: The five blocks by the names of their step pins (``test_hybrid_trunk.py PARENT_STEP_SHA256``, ``test_cca_trunk.py
+#: The six blocks by the names of their step pins (``test_hybrid_trunk.py PARENT_STEP_SHA256``, ``test_cca_trunk.py
 #: CCA_STEP_SHA256``): the tiny configuration and the batch its pin lowers (``tools/step_text.py``).
-BLOCKS = {"llada": (TINY, batch_of), "afmoe": (AFMOE, batch_of), "mla": (MLA, batch_of), "hybrid": (HYBRID, batch_of), "cca": (CCA, board_batch)}
+BLOCKS = {"llada": (TINY, batch_of), "afmoe": (AFMOE, batch_of), "mla": (MLA, batch_of), "hybrid": (HYBRID, batch_of), "cca": (CCA, board_batch),
+          "kda": (KDA, batch_of)}
