@@ -254,9 +254,14 @@ three bfloat16 results are the core's operands as they are; the l2 norms,
 the cumulative sum, the six-level cut of the decayed products, the
 triangular solve and ``Mq U`` are one Pallas kernel pair
 (``ops/board_delta.py``: ``board_delta``, ``board_delta_grad``) under
-``layerNN.delta``, one head of a few boards a grid step, nothing ``[64,
-64]`` and no state in HBM; the low-rank gates, softplus, beta, the gated
-head norm and the out-projection are XLA's under ``layerNN.kda`` beside it.
+``layerNN.delta``, one head of a few boards a grid step, no state in HBM;
+a training step's forward kernel keeps the solve's ``T``, ``U`` and the two
+score tables of every board and head (80 KB each, 160 MiB a layer at the
+cell's batch) for its gradient kernel, which makes no solve of its own (the
+forward without a gradient writes o alone), and each kernel is called under
+one ``jax.jit``, so a program traces and lowers the pair once for all its
+KDA layers; the low-rank gates, softplus, beta, the gated head norm and the
+out-projection are XLA's under ``layerNN.kda`` beside it.
 
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums

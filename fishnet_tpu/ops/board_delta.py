@@ -44,16 +44,47 @@ accumulate in float32.
 bfloat16 as the convolution writes them, ``g`` float32 in the same shape and
 ``beta`` ``[boards, 64, heads]`` float32, and gives o in q's shape,
 bfloat16. A grid step is one head of a few boards; no ``[.., heads, d]``
-view, no ``[64, 64]`` table and no state reaches HBM. ``board_delta_grad``
-recomputes the chunk form from the same inputs (the residuals are the
-inputs) and returns dq, dk, dv (bfloat16: cotangents of bfloat16 values),
-dg and dbeta (float32; a board's ``[64, heads]`` block of dbeta stays in
-VMEM over the heads' steps). With ``dM`` the cotangents of the two
+view and no state reaches HBM, and called without a gradient the kernel
+writes o alone.
+
+**The solve is made once.** The chunk form is three parts: the norms and
+``c`` (``_normed``: cheap, no chain), the two score tables (``_tables``)
+and the solve with ``U`` (``_solve``: twelve dependent ``[64, 64]`` float32
+products at ``highest``, each waiting for the one before, which is what the
+kernel's time is made of, not its bytes). The forward walks all three. When
+``board_delta`` is differentiated its forward rule calls the SAME kernel
+body told to write, beside o, what the gradient needs of the form, and
+hands it on as residuals with the five inputs (a board and head, at the
+padded size HBM holds: 80 KB, 160 MiB at 128 boards x 16 heads)::
+
+    [T | Mk]   float32  [boards, 64, heads * 128]   T in a head's lower 64 lanes, Mk in its upper: one whole tile
+    U          float32  [boards, 64, heads * d]     in q's columns
+    Mq         bfloat16 [boards, 64, heads * 128]   in a head's lower 64 lanes (the upper 64 are never written or read);
+                                                    its only reader rounds it to bfloat16 anyway: the same bits
+
+Every minor dimension is whole 128-lane tiles and every block is indexed by
+(block of boards, head) like the operands'. ``board_delta_grad`` reads the
+three, makes ``_normed`` again for itself and starts on its own work: no
+table, no solve, and every value it reads is the value it would have
+computed, bit for bit (the other two kernel pairs of the trunk recompute
+from their inputs because their tables are a product and a softmax; here
+the table is the chain). It returns dq, dk, dv (bfloat16: cotangents of
+bfloat16 values), dg and dbeta (float32; a board's ``[64, heads]`` block of
+dbeta stays in VMEM over the heads' steps). With ``dM`` the cotangents of the two
 matrices, a level's operands get theirs by three products, and ``dc = QL *
 dQL + KL * dKL - KR * dKR`` summed over the levels (``r`` has none: the
 product does not depend on it); ``dg`` is ``dc`` summed over the later
 squares. A scan longer than one chunk (a state handed on) is not computed
 here. Off the TPU both kernels run under the Pallas interpreter.
+
+**The pair is traced once a program.** The forward call (both forms) and the
+gradient call each sit under one ``jax.jit`` (bare under the interpreter:
+``mamba_mix._called``), so the four KDA layers of a step share one trace of
+each kernel body and one Mosaic lowering, which every start of the program
+pays (``setup_s``); a body works ONE board inside a ``fori_loop`` that is
+not unrolled, for the same reason (``tests/test_board_delta.py`` holds the
+loop rolled, ``tests/test_trunk_tpu_compile.py`` counts the entries into
+both bodies while the cell's step is lowered).
 """
 
 from __future__ import annotations
@@ -68,11 +99,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fishnet_tpu.ops.board_attention import SQUARES
+from fishnet_tpu.ops.mamba_mix import _called  # a kernel's call under its jax.jit on the chip, bare under the interpreter
 
 __all__ = ["board_delta"]
 
-#: Boards a grid step: a step's blocks (four bfloat16 and two float32 ``[boards, 64, 128]`` in, five out in the gradient) stay
-#: under 3 MiB double-buffered.
+#: Boards a grid step: a step's blocks (in the gradient four bfloat16 and two float32 ``[boards, 64, 128]`` and the three kept
+#: tiles in, five out: 2.5 MiB) are 5 MiB double-buffered.
 _BOARDS = 8
 _LANES = 128
 #: Under the root of the l2 norm of a head's q and of its k.
@@ -126,29 +158,38 @@ def _unit(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return x * r, r
 
 
-def _chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array):
-    """One head of one board, float32 ``[64, d]`` and beta ``[64, 1]`` -> everything of the chunk form that both kernels
-    read: the normed q and k with their reciprocal norms, c, Mq, Mk, T and U."""
-    f32 = jnp.float32
-    t, j, row = _squares()
+def _normed(q: jax.Array, k: jax.Array, g: jax.Array):
+    """One head of one board, float32 ``[64, d]`` -> what is cheap and no chain, made by both kernels: the normed q (times
+    ``d^-1/2``) and k, their reciprocal norms ``[64, 1]``, ``c = cumsum(g)`` and the scale."""
+    t, j, _ = _squares()
     scale = 1.0 / math.sqrt(q.shape[-1])
     (qn, rq), (kn, rk) = _unit(q), _unit(k)
-    qn = qn * scale
-    c = _exact((t >= j).astype(f32), g)
+    return qn * scale, kn, rq, rk, _exact((t >= j).astype(jnp.float32), g), scale
+
+
+def _tables(qn: jax.Array, kn: jax.Array, c: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The two decayed score tables ``Mq`` (j <= t) and ``Mk`` (j < t), float32 ``[64, 64]``: the diagonal and six levels."""
+    t, j, row = _squares()
     mq = jnp.where(t == j, jnp.sum(qn * kn, axis=-1, keepdims=True), 0.0)
-    mk = jnp.zeros((SQUARES, SQUARES), f32)
+    mk = jnp.zeros((SQUARES, SQUARES), jnp.float32)
     for p in range(_LEVELS):
         pairs, upper, lower = _level_decays(p, c, t, j, row)
         kr = kn * lower
         mq = mq + jnp.where(pairs, _dot(qn * upper, kr, _NT), 0.0)
         mk = mk + jnp.where(pairs, _dot(kn * upper, kr, _NT), 0.0)
+    return mq, mk
+
+
+def _solve(mk: jax.Array, v: jax.Array, beta: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``T = (I + Diag(beta) Mk)^-1`` ``[64, 64]`` and ``U = T (beta V)`` ``[64, d]``, float32: the chain of twelve dependent
+    products, walked by the forward kernel alone."""
+    t, j, row = _squares()
     a = beta * mk
-    tm = (t == j).astype(f32)
+    tm = (t == j).astype(jnp.float32)
     for p in range(_LEVELS):  # blocks of 1, 2, .. 32 joined two by two: [[T1, 0], [-T2 A21 T1, T2]]
         ap = jnp.where(_level(p, t, j, row)[0], a, 0.0)
         tm = tm - _exact(_exact(tm, ap), tm)
-    u = _exact(tm, beta * v)
-    return dict(qn=qn, kn=kn, rq=rq, rk=rk, c=c, mq=mq, mk=mk, tm=tm, u=u, scale=scale)
+    return tm, _exact(tm, beta * v)
 
 
 def _own_beta(beta_ref, i, h) -> Tuple[jax.Array, jax.Array]:
@@ -158,26 +199,34 @@ def _own_beta(beta_ref, i, h) -> Tuple[jax.Array, jax.Array]:
     return jnp.sum(jnp.where(own, block, 0.0), axis=-1, keepdims=True), own
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref):
+def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
+    """``kept_refs`` is empty (the primal) or the differentiated form's three further outputs (``_kept``)."""
     f32, h = jnp.float32, pl.program_id(1)
 
     def board(i, carry):
         beta, _ = _own_beta(beta_ref, i, h)
-        parts = _chunk(q_ref[i].astype(f32), k_ref[i].astype(f32), v_ref[i].astype(f32), g_ref[i], beta)
-        o_ref[i] = _dot(parts["mq"], parts["u"]).astype(o_ref.dtype)
+        qn, kn, _, _, c, _ = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
+        mq, mk = _tables(qn, kn, c)
+        tm, u = _solve(mk, v_ref[i].astype(f32), beta)
+        o_ref[i] = _dot(mq, u).astype(o_ref.dtype)
+        if kept_refs:
+            solve_ref, u_ref, mq_ref = kept_refs
+            solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:] = tm, mk
+            u_ref[i] = u
+            mq_ref[i, :, :SQUARES] = mq.astype(mq_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
 
 
-def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
     f32, h = jnp.float32, pl.program_id(1)
 
     def board(i, carry):
         beta, own = _own_beta(beta_ref, i, h)
         v, do = v_ref[i].astype(f32), do_ref[i]
-        parts = _chunk(q_ref[i].astype(f32), k_ref[i].astype(f32), v, g_ref[i], beta)
-        qn, kn, c, mq, mk, tm, u = (parts[name] for name in ("qn", "kn", "c", "mq", "mk", "tm", "u"))
+        qn, kn, rq, rk, c, scale = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
+        tm, mk, u, mq = solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:], u_ref[i], mq_ref[i, :, :SQUARES]
         t, j, row = _squares()
         dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
         w = _exact(tm, _dot(mq, do, _TN), _TN)  # T^T dU: the cotangent of beta * V
@@ -198,17 +247,18 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, dq_ref, dk_re
             dc = dc + ql * dql + kl * dkl - kr * dkr
         dg_ref[i] = _exact((t >= j).astype(f32), dc, _TN)  # dg_s = the sum of dc_t over t >= s
         # through the l2 norms: y = x r, dx = r (dy - y sum(y dy)); q's y is qn / scale
-        dqn = dqn * parts["scale"]
-        qy = qn * (1.0 / parts["scale"])
-        dq_ref[i] = (parts["rq"] * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
-        dk_ref[i] = (parts["rk"] * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        dqn = dqn * scale
+        qy = qn * (1.0 / scale)
+        dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
+        dk_ref[i] = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
 
 
 def _blocks(q: jax.Array, beta: jax.Array, interpret: bool):
-    """The grid (blocks of boards, heads) and the BlockSpecs of a head's columns and of a block of boards' beta."""
+    """The grid (blocks of boards, heads) and the BlockSpecs of a head's columns, of a block of boards' beta, and of a head's
+    128-lane tile of a kept array."""
     boards, squares, inner = q.shape
     heads = beta.shape[-1]
     if squares != SQUARES or inner % heads or beta.shape[:2] != (boards, SQUARES):
@@ -217,12 +267,58 @@ def _blocks(q: jax.Array, beta: jax.Array, interpret: bool):
     if d % _LANES and not interpret:
         raise ValueError(f"board_delta: a head of {d} columns is not whole {_LANES}-lane tiles")
     tb = math.gcd(boards, _BOARDS)
-    return (boards // tb, heads), pl.BlockSpec((tb, SQUARES, d), lambda i, h: (i, 0, h)), pl.BlockSpec((tb, SQUARES, heads), lambda i, h: (i, 0, 0))
+    by_head = lambda width: pl.BlockSpec((tb, SQUARES, width), lambda i, h: (i, 0, h))
+    return (boards // tb, heads), by_head(d), pl.BlockSpec((tb, SQUARES, heads), lambda i, h: (i, 0, 0)), by_head(2 * SQUARES)
+
+
+def _kept(q: jax.Array, heads: int):
+    """What the differentiated forward writes beside o and the gradient reads, a board and head (module docstring): ``[T | Mk]`` side
+    by side as one float32 tile of 128 lanes, ``U`` float32 in q's columns, ``Mq`` bfloat16 in the lower half of a tile."""
+    boards, squares, _ = q.shape
+    tile = (boards, squares, heads * 2 * SQUARES)
+    return [jax.ShapeDtypeStruct(tile, jnp.float32), jax.ShapeDtypeStruct(q.shape, jnp.float32), jax.ShapeDtypeStruct(tile, jnp.bfloat16)]
 
 
 def _operands(q, k, v, g, beta):
     bf16, f32 = jnp.bfloat16, jnp.float32
     return q.astype(bf16), k.astype(bf16), v.astype(bf16), g.astype(f32), beta.astype(f32)
+
+
+#: Both kernels are called under ``jax.jit``, as ``ops/mamba_mix.py``'s are and for its reason: a step's four KDA layers then share
+#: ONE trace of each kernel body and one Mosaic lowering a program, where a bare ``pallas_call`` is traced and lowered again at
+#: every call site, at every start (ROADMAP S11; PERF.md section 6, PR 49). The call sites' scopes still name each call's operations.
+@functools.partial(jax.jit, static_argnames=("interpret", "keep"))
+def _forward_call(q, k, v, g, beta, *, interpret: bool, keep: bool):
+    """The forward kernel in its two forms: o alone, or (``keep``) o and the three kept arrays."""
+    grid, head, betas, tile = _blocks(q, beta, interpret)
+    o = jax.ShapeDtypeStruct(q.shape, jnp.bfloat16)
+    return pl.pallas_call(
+        _forward_kernel,
+        grid=grid,
+        in_specs=[head, head, head, head, betas],
+        out_specs=[head, tile, head, tile] if keep else head,
+        out_shape=[o, *_kept(q, beta.shape[-1])] if keep else o,
+        compiler_params=_PARAMS,
+        name="board_delta",
+        interpret=interpret,
+    )(*_operands(q, k, v, g, beta))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gradient_call(q, k, v, g, beta, kept, do, *, interpret: bool):
+    grid, head, betas, tile = _blocks(q, beta, interpret)
+    like = lambda x, dtype: jax.ShapeDtypeStruct(x.shape, dtype)
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        _backward_kernel,
+        grid=grid,
+        in_specs=[head, head, head, head, betas, tile, head, tile, head],
+        out_specs=[head, head, head, head, betas],
+        out_shape=[like(q, jnp.bfloat16), like(k, jnp.bfloat16), like(v, jnp.bfloat16), like(g, jnp.float32), like(beta, jnp.float32)],
+        compiler_params=_PARAMS,
+        name="board_delta_grad",
+        interpret=interpret,
+    )(*_operands(q, k, v, g, beta), *kept, do.astype(jnp.bfloat16))
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dg.astype(g.dtype), dbeta.astype(beta.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -231,38 +327,16 @@ def board_delta(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: ja
     heads * d]`` bfloat16 (before their l2 norms), ``g`` float32 in that
     shape (a log-decay a channel, <= 0), ``beta`` ``[boards, 64, heads]``
     float32 -> o in q's shape, bfloat16."""
-    grid, head, betas = _blocks(q, beta, interpret)
-    return pl.pallas_call(
-        _forward_kernel,
-        grid=grid,
-        in_specs=[head, head, head, head, betas],
-        out_specs=head,
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.bfloat16),
-        compiler_params=_PARAMS,
-        name="board_delta",
-        interpret=interpret,
-    )(*_operands(q, k, v, g, beta))
+    return _called(_forward_call, interpret)(q, k, v, g, beta, interpret=interpret, keep=False)
 
 
 def _board_delta_fwd(q, k, v, g, beta, interpret):
-    return board_delta(q, k, v, g, beta, interpret), (q, k, v, g, beta)
+    o, *kept = _called(_forward_call, interpret)(q, k, v, g, beta, interpret=interpret, keep=True)
+    return o, (q, k, v, g, beta, tuple(kept))
 
 
 def _board_delta_bwd(interpret, residuals, do):
-    q, k, v, g, beta = residuals
-    grid, head, betas = _blocks(q, beta, interpret)
-    like = lambda x, dtype: jax.ShapeDtypeStruct(x.shape, dtype)
-    dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _backward_kernel,
-        grid=grid,
-        in_specs=[head, head, head, head, betas, head],
-        out_specs=[head, head, head, head, betas],
-        out_shape=[like(q, jnp.bfloat16), like(k, jnp.bfloat16), like(v, jnp.bfloat16), like(g, jnp.float32), like(beta, jnp.float32)],
-        compiler_params=_PARAMS,
-        name="board_delta_grad",
-        interpret=interpret,
-    )(*_operands(q, k, v, g, beta), do.astype(jnp.bfloat16))
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dg.astype(g.dtype), dbeta.astype(beta.dtype)
+    return _called(_gradient_call, interpret)(*residuals, do, interpret=interpret)
 
 
 board_delta.defvjp(_board_delta_fwd, _board_delta_bwd)

@@ -3,13 +3,17 @@ interpreter against the literal 64-step recurrence in float64, forward and
 every gradient; a board's state starts from zero and no square sees a later
 one; decays so fast that ``exp(-c)`` would overflow float32 stay finite and
 right; and two mutations of the recurrence, each a plausible misreading of
-the layer, read far outside the tolerance."""
+the layer, read far outside the tolerance. The differentiated forward keeps
+``T``, ``U`` and the two score tables, bit for bit the parent's ``_chunk``
+(written once below as the oracle); the gradient kernel reads them and
+makes no solve; the primal writes ``o`` alone."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from fishnet_tpu.ops import board_delta as kernels
 from fishnet_tpu.ops.board_delta import L2_EPS, board_delta
 
 SQUARES = 64
@@ -146,3 +150,141 @@ def test_shapes_that_are_not_heads_of_a_board_are_refused():
         board_delta(ops["q"], ops["k"], ops["v"], ops["g"], jnp.ones((3, SQUARES, 5)), True)
     with pytest.raises(ValueError, match="lane"):
         board_delta(ops["q"], ops["k"], ops["v"], ops["g"], ops["beta"], False)
+
+
+# -- what the differentiated forward keeps, and what the gradient reads ---------------------------------------------------------------
+
+
+def chunk_oracle(q, k, v, g, beta):
+    """The chunk form of one head of one board as the gradient kernel made it for itself before the forward kept it
+    (``_chunk`` of the parent commit, its arithmetic written once more here in plain ``jax.numpy`` over the module's
+    level masks and products): float32 ``[64, d]`` and beta ``[64, 1]`` -> T, U, Mk and Mq."""
+    f32 = jnp.float32
+    t, j, row = kernels._squares()
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+    qn, kn = unit(q) * (1.0 / np.sqrt(q.shape[-1])), unit(k)
+    c = kernels._exact((t >= j).astype(f32), g)
+    mq = jnp.where(t == j, jnp.sum(qn * kn, axis=-1, keepdims=True), 0.0)
+    mk = jnp.zeros((SQUARES, SQUARES), f32)
+    for p in range(6):
+        pairs, upper, lower = kernels._level_decays(p, c, t, j, row)
+        kr = kn * lower
+        mq = mq + jnp.where(pairs, kernels._dot(qn * upper, kr, kernels._NT), 0.0)
+        mk = mk + jnp.where(pairs, kernels._dot(kn * upper, kr, kernels._NT), 0.0)
+    a = beta * mk
+    tm = (t == j).astype(f32)
+    for p in range(6):
+        ap = jnp.where(kernels._level(p, t, j, row)[0], a, 0.0)
+        tm = tm - kernels._exact(kernels._exact(tm, ap), tm)
+    return tm, kernels._exact(tm, beta * v), mk, mq
+
+
+#: LLVM's optimizations off, for a compile whose result is compared bit for bit with another program's (``exactly``).
+UNCONTRACTED = {"xla_backend_optimization_level": 0}
+
+
+def exactly(fn, *args):
+    """``fn(*args)`` compiled with LLVM's optimizations off. Two differently fused XLA:CPU programs of the same arithmetic
+    differ in the last bit otherwise (a product contracted into the sum after it in one loop and not in the other: the
+    parent's ``dg`` against this tree's reads 4e-8 apart at the default level and equal at this one); on the chip Mosaic
+    reassociates nothing, and the pair is the parent's bit for bit there (PERF.md, PR 49)."""
+    return jax.jit(fn).lower(*args).compile(compiler_options=UNCONTRACTED)(*args)
+
+
+def oracle_kept(ops):
+    """The oracle's tables of every board and head in the kept arrays' layout: ``[T | Mk]`` float32 a head's 128 lanes, ``U``
+    float32 in q's columns, ``Mq`` bfloat16 in the lower 64 lanes of a head's 128 (the upper 64 are never written: zero here)."""
+    boards, _, heads = ops["beta"].shape
+    d = ops["q"].shape[-1] // heads
+
+    def board_and_head(b, h):
+        return [ops[name][b, :, h * d:(h + 1) * d].astype(jnp.float32) for name in ("q", "k", "v", "g")] + [ops["beta"][b, :, h:h + 1]]
+
+    one = jax.jit(chunk_oracle).lower(*board_and_head(0, 0)).compile(compiler_options=UNCONTRACTED)
+    solve, u, mq = np.zeros((boards, SQUARES, heads * 128), np.float32), np.zeros(ops["q"].shape, np.float32), np.zeros((boards, SQUARES, heads * 128), np.float32)
+    for b in range(boards):
+        for h in range(heads):
+            tm, u[b, :, h * d:(h + 1) * d], mk, mq[b, :, h * 128:h * 128 + SQUARES] = (np.asarray(x, np.float32) for x in one(*board_and_head(b, h)))
+            solve[b, :, h * 128:(h + 1) * 128] = np.concatenate([tm, mk], axis=-1)
+    return jnp.asarray(solve), jnp.asarray(u), jnp.asarray(mq, jnp.bfloat16)
+
+
+def written(kept):
+    """The lanes of the three kept arrays that the forward writes: all of ``[T | Mk]`` and of ``U``, the lower half of ``Mq``'s tiles."""
+    solve, u, mq = (np.asarray(x, np.float32) for x in kept)
+    return solve, u, mq.reshape(*mq.shape[:2], -1, 128)[..., :SQUARES]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_differentiated_forward_keeps_the_chunk_form_bit_for_bit(case):
+    ops = operands(case, seed=4)
+    inputs = tuple(ops[name] for name in NAMES)
+    o, (*handed_on, (solve, u, mq)) = exactly(lambda *a: kernels._board_delta_fwd(*a, True), *inputs)
+    assert np.array_equal(np.asarray(o, np.float32), np.asarray(exactly(lambda *a: board_delta(*a, True), *inputs), np.float32))
+    for name, handed, came in zip(NAMES, handed_on, inputs):  # the five inputs are handed on as they came
+        assert handed.dtype == came.dtype and np.array_equal(np.asarray(handed, np.float32), np.asarray(came, np.float32)), name
+    boards, heads, _ = CASES[case]
+    assert (solve.shape, solve.dtype, mq.shape, mq.dtype) == ((boards, SQUARES, heads * 128), jnp.float32, (boards, SQUARES, heads * 128), jnp.bfloat16)
+    assert (u.shape, u.dtype) == (ops["q"].shape, jnp.float32)
+    for name, got, want in zip(("[T | Mk]", "U", "Mq"), written((solve, u, mq)), written(oracle_kept(ops))):
+        assert np.array_equal(got, want), (name, float(np.abs(got - want).max()))
+    unit_lower = np.asarray(solve).reshape(boards, SQUARES, heads, 2, SQUARES)[:, :, :, 0].transpose(0, 2, 1, 3)  # T of a board and head
+    assert np.array_equal(np.triu(unit_lower, 1), np.zeros_like(unit_lower)) and (np.diagonal(unit_lower, axis1=-2, axis2=-1) == 1.0).all()
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_gradient_from_the_kept_tables_is_the_gradient_from_tables_made_again(case):
+    """The gradient kernel fed the oracle's tables (what it made for itself before) gives the five gradients of
+    ``jax.grad`` through the kept ones, bit for bit; and fed a ``T`` that is not the inverse it gives others: it reads, it
+    does not solve."""
+    ops = operands(case, seed=5)
+    weight = jnp.asarray(np.random.default_rng(11).standard_normal(ops["q"].shape), jnp.float32)
+    grads = exactly(jax.grad(lambda o: jnp.sum(board_delta(*(o[name] for name in NAMES), True).astype(jnp.float32) * weight)), ops)
+    inputs = tuple(ops[name] for name in NAMES)
+    from_tables = lambda *kept: kernels._board_delta_bwd(True, (*inputs, kept), weight.astype(jnp.bfloat16))
+    solve, u, mq = oracle_kept(ops)
+    for name, got in zip(NAMES, exactly(from_tables, solve, u, mq)):
+        assert got.dtype == grads[name].dtype and np.array_equal(np.asarray(got, np.float32), np.asarray(grads[name], np.float32)), name
+    other = exactly(from_tables, solve * 0.5, u, mq)
+    assert not np.array_equal(np.asarray(other[2], np.float32), np.asarray(grads["v"], np.float32))  # dv = beta T^T dU
+
+
+def _kernel_calls(jaxpr, jitted=None):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs its equations hold, each with the ``jax.jit`` equation
+    nearest around it (None for a bare call)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield jitted, eqn
+        for held in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(held, eqn if eqn.primitive.name in ("pjit", "jit") else jitted)
+
+
+@pytest.mark.parametrize("differentiated", [False, True], ids=["primal", "differentiated"])
+def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_arrays(differentiated):
+    """No gradient asked: one kernel with one output. Differentiated: the forward kernel's four outputs (o, ``[T | Mk]``,
+    ``U``, ``Mq``: 80 KB a board and head at their padded size) and a gradient kernel that reads the five inputs, the
+    three kept arrays and o's cotangent. Off the interpreter each kernel sits alone under its own ``jax.jit``, whose results
+    and arguments are the kernel's own: what the forward wrote is what the gradient reads, nothing between. A kernel's body
+    holds its loop over a grid step's boards rolled (start-up pays for every copy of a body that Mosaic lowers)."""
+    boards, heads, d = CASES["published"]
+    ops = operands("published")
+    args = tuple(ops[name] for name in NAMES)
+    fn = (lambda *a: jax.vjp(lambda *b: board_delta(*b, False), *a)[1](a[0])) if differentiated else (lambda *a: board_delta(*a, False))
+    calls = list(_kernel_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert [jitted.params["name"] for jitted, _ in calls] == (["_forward_call", "_gradient_call"] if differentiated else ["_forward_call"])
+    for jitted, call in calls:  # a jitted call is its kernel and casts that change nothing: results and kept operands pass straight through
+        assert [id(v) for v in jitted.params["jaxpr"].jaxpr.outvars] == [id(v) for v in call.outvars]
+        loops = [eqn.params for eqn in call.params["jaxpr"].eqns if eqn.primitive.name in ("scan", "while")]
+        assert [(loop["length"], loop["unroll"]) for loop in loops] == [(boards, 1)]  # ONE board's body in the kernel: unrolled, every start lowers it a board
+    names = [call.params["name"] for _, call in calls]
+    if not differentiated:
+        assert names == ["board_delta"] and [v.aval.shape for v in calls[0][1].outvars] == [ops["q"].shape]
+        return
+    assert names == ["board_delta", "board_delta_grad"]
+    (forward_jit, forward), (gradient_jit, gradient) = calls
+    kept = [(v.aval.shape, v.aval.dtype) for v in forward.outvars[1:]]
+    assert kept == [((boards, SQUARES, heads * 128), jnp.float32), (ops["q"].shape, jnp.float32), ((boards, SQUARES, heads * 128), jnp.bfloat16)]
+    assert sum(int(np.prod(shape)) * np.dtype(dtype).itemsize for shape, dtype in kept) == boards * heads * 80 * 1024  # budget 96 KB
+    assert len(gradient.invars) == 9 and len(gradient.outvars) == 5
+    assert [id(v) for v in gradient.invars[5:8]] == [id(v) for v in gradient_jit.params["jaxpr"].jaxpr.invars[5:8]]
+    assert [id(v) for v in gradient_jit.invars[5:8]] == [id(v) for v in forward_jit.outvars[1:]]  # read as they were written

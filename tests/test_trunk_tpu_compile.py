@@ -7,6 +7,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU's library, and every xdist worker imports every
 test file. Keep such tests in this one file."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -726,6 +728,31 @@ def test_the_fifth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
 # -- the sixth block (kimi_linear) at its published widths: hidden 2304 = 18 x 128, 16 held heads of 128 a mixer ------------------------
 
 KDA_BOARDS = 128  # kda_trunk_train_b128
+#: What a differentiated ``board_delta`` writes beside o at 128 boards x 16 heads: ``[T | Mk]`` and ``U`` float32, ``Mq`` bfloat16 in half a tile.
+KDA_KEPT = [("f32", "128,64,2048"), ("f32", "128,64,2048"), ("bf16", "128,64,2048")]
+
+
+def _kept_between_the_delta_pairs(text: str):
+    """From a compiled module's text, for each ``board_delta_grad``: the forward kernel it reads from, the shapes of what
+    that forward writes beside o, and for each of the three what the gradient reads in its place (operands 5-7): the index
+    of the forward's own result where it is read as it was written, else the instruction that came between (a ``copy``, a
+    ``transpose``)."""
+    import re
+
+    named = ((re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line), line) for line in text.splitlines())
+    lines = {name.group(1): line for name, line in named if name}
+    pairs = []
+    for gradient, line in lines.items():
+        if 'custom_call_target="tpu_custom_call"' not in line or "board_delta_grad" not in gradient:
+            continue
+        operands = [re.sub(r"/\*.*?\*/", "", operand).strip().lstrip("%") for operand in re.search(r" custom-call\((.*?)\), custom_call_target", line).group(1).split(",")]
+        assert len(operands) == 9, operands  # q, k, v, g, beta, [T | Mk], U, Mq, o's cotangent
+        straight = [re.search(r" get-tuple-element\(%([\w.\-]+)\), index=(\d)", lines[operand]) for operand in operands[5:8]]
+        forward = {found.group(1) for found in straight if found}
+        assert len(forward) == 1 and "board_delta" in min(forward) and "grad" not in min(forward), [lines[operand][:160] for operand in operands[5:8]]
+        written = re.findall(r"(bf16|f32)\[([\d,]+)\]", lines[min(forward)].split(" custom-call(")[0])[1:]
+        pairs.append((min(forward), written, [int(found.group(2)) if found else lines[operand].strip()[:160] for found, operand in zip(straight, operands[5:8])]))
+    return pairs
 
 
 def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_for_tpu):
@@ -733,7 +760,11 @@ def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_f
     held heads x 128, a head of eight boards a grid step, the six levels'
     masks from bit operations on iotas, the cumulative sum and the solve's
     float32 products at ``highest``, a board's ``[64, 16]`` block of dbeta
-    resident over the heads' steps."""
+    resident over the heads' steps. Differentiated, the forward writes
+    beside o the three kept arrays (``[T | Mk]`` and ``U`` float32, ``Mq``
+    bfloat16 in half of a tile: 80 KB a board and head of the 96 budgeted),
+    the gradient reads them as they were written, nothing of XLA's between;
+    the primal writes o alone."""
     from fishnet_tpu.ops.board_delta import board_delta
 
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -743,14 +774,43 @@ def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_f
     text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(*args).compile().as_text()
     kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == 2 and sum("board_delta_grad" in kernel for kernel in kernels) == 1 and all("board_delta" in kernel for kernel in kernels), kernels
+    ((_, written, read),) = _kept_between_the_delta_pairs(text)
+    assert written == KDA_KEPT and read == [1, 2, 3], (written, read)
+    sizes = {"f32": 4, "bf16": 2}
+    assert sum(sizes[dtype] * math.prod(int(n) for n in shape.split(",")) for dtype, shape in written) == KDA_BOARDS * 16 * 80 * 1024  # of the 96 KB budgeted a board and head
+    primal = jax.jit(lambda *a: board_delta(*a, False)).lower(*args).compile().as_text()
+    (alone,) = [line for line in primal.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert " = bf16[128,64,2048]" in alone and "board_delta" in alone.split(" = ")[0], alone[:200]  # one kernel, one result: no tuple
 
 
-def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+def _delta_bodies_entered(monkeypatch):
+    """A counter laid over each kernel body of ``ops/board_delta.py`` -> how often Python entered each, so far. The pair's
+    jitted calls forget the traces they hold (of another test of this process), so the next program traces its own."""
+    from fishnet_tpu.ops import board_delta
+
+    entered = {"_forward_kernel": 0, "_backward_kernel": 0}
+
+    def counting(name, body):
+        def counted(*refs):
+            entered[name] += 1
+            return body(*refs)
+        return counted
+
+    for name in entered:
+        monkeypatch.setattr(board_delta, name, counting(name, getattr(board_delta, name)))
+    board_delta._forward_call.clear_cache()
+    board_delta._gradient_call.clear_cache()
+    return entered
+
+
+def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu, monkeypatch):
     """The whole step of ``kda_trunk_train_b128`` from its configuration
     file: the delta pair and the convolution pair a KDA layer (four), the
     latent form of the attention pair on the one latent layer (under the
     tables that turn nothing), a moved row of 2,304 = 18 lane tiles as
-    3,072, no leaf held off row-major, nothing remade to fit."""
+    3,072, no leaf held off row-major, nothing remade to fit. Lowering it
+    enters each kernel body of the delta pair ONCE: the four layers share
+    the trace of a jitted call, which ``setup_s`` pays at every start."""
     import importlib
     import json
     from pathlib import Path
@@ -764,7 +824,11 @@ def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
     assert sum(v.size for v in state.params.values()) == 416_608_910  # the configuration file's reckoning
     batch = {"planes": jnp.zeros((KDA_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((KDA_BOARDS, 4672)), "value_target": jnp.zeros((KDA_BOARDS,))}
-    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
+    entered = _delta_bodies_entered(monkeypatch)
+    lowered = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch))
+    # the start-up tripwire: four KDA layers, ONE trace of each kernel body (4 and 4 for bare calls; ``tests/test_board_delta.py`` holds the boards' loop rolled)
+    assert entered == {"_forward_kernel": 1, "_backward_kernel": 1}, entered
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert not trainer._held and not _copies_of_state_arguments(text)  # whole-lane widths: the client's default is the step's layout
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -775,6 +839,9 @@ def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
         assert all(f"{phase}/layer0{i}.delta/" in text and f"{phase}/layer0{i}.kda/" in text for i in (0, 1, 2, 4)) and f"{phase}/layer03.delta/" not in text
         assert f"{phase}/layer03.latent/" in text and f"{phase}/layer03.attention/" in text
     assert not _xla_passes_over_slots(text, KDA_BOARDS * trunk.SQUARES * cfg.experts_per_token)
+    pairs = _kept_between_the_delta_pairs(text)  # every layer's gradient reads its own forward's kept arrays as they were written
+    assert len({forward for forward, _, _ in pairs}) == 4 and all(written == KDA_KEPT and read == [1, 2, 3] for _, written, read in pairs), pairs
     memory = compiled.memory_analysis()
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.0  # 4.66 + 7.88 GiB when this was written
+    # 4.66 + 8.51 GiB when this was written: 4.66 + 7.88 before the four forwards kept their tables (4 x 160 MiB), and the same 0.45 GiB of room
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.62, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert ".remat" not in text
