@@ -288,7 +288,21 @@ extent of RoPE, and whether the rotated key is one a key-value head or
 one for all heads) that takes q, k, v as the projections write them and
 works on one key-value head and its group of query heads of a few boards
 at a time in VMEM: no ``[.., heads, head_dim]`` view and no scores reach
-HBM, and the gradient recomputes the softmax from the same inputs.
+HBM, and the gradient recomputes the softmax from the same inputs. A
+gated branch (``cfg.gated_attention``: the second block) ends in
+``_gated_out``, one gradient rule round the gate and ``W_o``::
+
+    gl = n1 W_gate;  gated = mixed * sigmoid(gl) (bfloat16);  out = gated W_o
+    d_gated = bf16(d_out) W_o^T;  one pass over gl, d_gated, mixed -> d_mixed, d_gl, gated
+    d W_o = gated^T d_out;  d W_gate = n1^T d_gl;  d_n1 = d_gl W_gate^T
+
+so that every product reads and writes arrays, the sigmoid and its
+gradient are made once in float32 between them, and what is kept is the
+bfloat16 normed stream, ``mixed`` and the weights (its docstring has the
+barriers and the readings that asked for each). One algorithm, its form
+taken from the layer's own ``cfg.gated_attention`` at every size: the
+ungated branch is its special case with nothing to hold out, ``mixed W_o``
+as it always was.
 
 Mechanism, the feed-forward every token passes (the leading dense layer,
 the shared expert; ``_gated_ffn``): three XLA products round ``silu(gate)
@@ -370,7 +384,7 @@ from fishnet_tpu.ops.board_delta import board_delta
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
 from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
-from fishnet_tpu.ops.mamba_mix import mamba_conv, mamba_gate_norm
+from fishnet_tpu.ops.mamba_mix import _called, mamba_conv, mamba_gate_norm
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
@@ -864,7 +878,8 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) ->
     """The attention kind (the first, second and fourth blocks'):
     [tokens, hidden] float32, 64 tokens a board -> the branch's output,
     same shape, before its post-norm; it counts nothing. The projections
-    are XLA's; everything between them is ``board_attention``. As every
+    are XLA's; everything between them is ``board_attention``; a gated
+    branch's gate and out-projection are ``_gated_out``. As every
     kind's function it enters its own scopes (call it under none of a
     layer's): ``<layer>.attention``."""
     with jax.named_scope(f"{sublayer.layer}.attention"):
@@ -875,8 +890,85 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) ->
                                 interpret=_interpret(), rotary_dim=cfg.rotary_dim, **gains)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
-            mixed = mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, p["wgate"]))
+            return _gated_out(n1, mixed, p["wgate"], p["wo"]), {}
         return _matmul(mixed, p["wo"]), {}
+
+
+@jax.custom_vjp
+def _gated_out(n1: jax.Array, mixed: jax.Array, gate_w: jax.Array, out_w: jax.Array) -> jax.Array:
+    """The second block's gated out-projection, ``(mixed * sigmoid(n1
+    W_gate)) W_o``, as products on ARRAYS, the elementwise work between
+    them made once (``_gated_products``' method; ``n1`` the float32
+    normed stream, ``mixed`` bfloat16 as the kernel wrote it)::
+
+        gl    = n1 W_gate              float32 from the product
+        gated = mixed * sigmoid(gl)    bfloat16, float32 arithmetic, rounded once
+        out   = gated W_o              float32
+        d_out -> bfloat16              once, for its two products (the post-norm's gradient when the layer has one)
+        d_gated = d_out W_o^T          bfloat16 (the cotangent of a bfloat16 operand)
+        s = sigmoid(gl);  d_mixed = d_gated * s;  d_gl = d_gated * mixed * s (1 - s);  gated again: ONE pass, three bfloat16 arrays
+        d W_o = gated^T d_out;  d W_gate = n1^T d_gl;  d_n1 = d_gl W_gate^T   float32
+
+    Left to autodiff XLA wrote the sigmoid as a float32 ``[tokens,
+    inner]`` array, multiplied ``W_o``'s input gradient by it behind the
+    product (two results) and made the sigmoid's gradient inside the
+    operand of ``W_gate``'s input gradient from three ``[tokens, inner]``
+    arrays, two of them float32: 3.18 and 3.40 ms a layer at ``[16384,
+    4096]`` against 1.40 at the bfloat16 peak, now 1.45 and 2.41, the
+    second with the sum of the four input gradients and the input norm's
+    reduces still behind it (PERF.md section 6, PR 50). No rounding is
+    added or moved: each array is rounded where the default-precision
+    product that reads it rounded it.
+
+    What is kept for the gradient is the bfloat16 normed stream,
+    ``mixed`` and the weights: ``gl`` is asked for again, and whether the
+    forward's is held or the product made again is XLA's to settle
+    against the chip's memory (the cell's step holds it and remakes
+    ``W_q``'s product instead: as many products as before, five fewer
+    remade). Each ``optimization_barrier`` holds an array out of the
+    products that read it: ``mixed`` as ``[tokens, inner]`` bfloat16
+    (without it the cast to float32 moves before the reshape, where
+    nothing fuses it: a 0.65 ms pass of its own, forward and again
+    backward); ``gated``, so that ``W_o``'s product has no sigmoid in
+    its operand (2.05 ms against 1.76) and the gate lands where XLA puts
+    it cheapest, behind ``gl``'s product as its consumer (1.48 ms against
+    1.43 alone: held out as a pass of its own it is 0.77 more); ``d_out``'s
+    cast, with the post-norm's gradient behind it; ``gl`` and ``d_gated``
+    before the pass; the pass's three results. The forward and the
+    gradient each sit under one ``jax.jit`` on the chip (bare under the
+    interpreter, ``mamba_mix._called``), so a step's gated layers share
+    one trace and one lowering of each."""
+    return _gated_out_fwd(n1, mixed, gate_w, out_w)[0]
+
+
+@jax.jit
+def _gated_out_forward(n1, mixed, gate_w, out_w):
+    mixed = jax.lax.optimization_barrier(mixed)
+    gated = (mixed.astype(jnp.float32) * jax.nn.sigmoid(_matmul(n1, gate_w))).astype(jnp.bfloat16)
+    return _matmul(jax.lax.optimization_barrier(gated), out_w)
+
+
+@jax.jit
+def _gated_out_gradient(n1, mixed, gate_w, out_w, d_out):
+    d_out = jax.lax.optimization_barrier(d_out.astype(jnp.bfloat16))  # once, for its two products
+    d_gated = _contract(d_out, out_w.astype(jnp.bfloat16), 1, 1).astype(jnp.bfloat16)
+    gl, d_gated, mixed = jax.lax.optimization_barrier((_matmul(n1, gate_w), d_gated, mixed))
+    s, mixed, d_gated = jax.nn.sigmoid(gl), mixed.astype(jnp.float32), d_gated.astype(jnp.float32)
+    passed = (d_gated * s, d_gated * mixed * (s * (1.0 - s)), mixed * s)
+    d_mixed, d_gl, gated = jax.lax.optimization_barrier(tuple(y.astype(jnp.bfloat16) for y in passed))
+    return _contract(d_gl, gate_w.astype(jnp.bfloat16), 1, 1), d_mixed, _contract(n1, d_gl, 0, 0), _contract(gated, d_out, 0, 0)
+
+
+def _gated_out_fwd(n1, mixed, gate_w, out_w):
+    n1 = n1.astype(jnp.bfloat16)
+    return _called(_gated_out_forward, _interpret())(n1, mixed, gate_w, out_w), (n1, mixed, gate_w, out_w)
+
+
+def _gated_out_bwd(res, d_out):
+    return _called(_gated_out_gradient, _interpret())(*res, d_out)
+
+
+_gated_out.defvjp(_gated_out_fwd, _gated_out_bwd)
 
 
 def _latent_attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:

@@ -359,3 +359,57 @@ def test_moved_rows_against_a_hand_count(afmoe_trainer):
     assert float(counters["moved_rows"]) == sum(-(-int(h) // 512) * 512 for h in held)
     _, metrics = afmoe_trainer.step(afmoe_trainer.init(1), batch_of(1))
     assert 0 < float(metrics["moved_rows"]) <= 2 * BATCH * 64 * 4 and float(metrics["moved_rows"]) % 512 == 0  # in the step's metrics
+
+
+def _gated_out_case(seed: int, cfg: TrunkConfig = AFMOE):
+    """The gated out-projection's operands at the tiny net's widths as the layer hands them over: the float32 normed
+    stream, ``mixed`` bfloat16 as the kernel writes it, the two weights, the post-norm's gain and a cotangent."""
+    rng = np.random.default_rng(seed)
+    tokens, inner = BATCH * 64, cfg.heads * cfg.head_dim
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    return (normal(tokens, cfg.hidden), normal(tokens, inner).astype(jnp.bfloat16), normal(cfg.hidden, inner) / np.sqrt(cfg.hidden),
+            normal(inner, cfg.hidden) / np.sqrt(inner), 1.0 + 0.1 * normal(cfg.hidden)), normal(tokens, cfg.hidden)
+
+
+def _plain_gated_out(n1, mixed, gate_w, out_w, gain, wrong=""):
+    """The branch as ``_attention`` wrote it until PR 50, left to autodiff, and the post-norm the loop applies to it."""
+    gate = {"": jax.nn.sigmoid, "no_gate": jnp.ones_like, "silu": jax.nn.silu}[wrong]
+    return trunk._rms_norm(trunk._matmul(mixed.astype(jnp.float32) * gate(trunk._matmul(n1, gate_w)), out_w), gain, AFMOE.rms_eps)
+
+
+def _gated_out_under_its_norm(n1, mixed, gate_w, out_w, gain):
+    """The program's side of the comparison: ``trunk._gated_out`` and the same post-norm."""
+    return trunk._rms_norm(trunk._gated_out(n1, mixed, gate_w, out_w), gain, AFMOE.rms_eps)
+
+
+GATED_OUT_NAMES = ("n1", "mixed", "wgate", "wo", "post_attn_norm")
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_gated_out_projection_against_autodiff_of_the_plain_formula(seed):
+    """``_gated_out`` (one ``custom_vjp`` round the gate and ``W_o``) under
+    the post-norm against ``jax.vjp`` of ``mixed.astype(float32) *
+    sigmoid(n1 W_gate)`` through ``W_o`` and the same norm: the value is
+    the plain formula's to the bit (the rule rounds where ``_matmul``
+    does), and all five gradients are its gradients: the normed stream's
+    (float32), ``mixed``'s (bfloat16, as the kernel's result is), both
+    weights' and the post-norm's gain's, which reaches the rule as
+    ``d_out``."""
+    operands, cot = _gated_out_case(seed)
+    got, pull = jax.vjp(_gated_out_under_its_norm, *operands)
+    want, plain_pull = jax.vjp(_plain_gated_out, *operands)
+    assert got.dtype == jnp.float32 and bool(jnp.all(got == want))
+    for name, operand, g, w in zip(GATED_OUT_NAMES, operands, pull(cot), plain_pull(cot)):
+        assert g.shape == operand.shape and g.dtype == operand.dtype and rel(g, w) < 1e-2, (name, rel(g, w))
+
+
+@pytest.mark.parametrize("wrong", ["no_gate", "silu"])
+def test_the_tolerance_catches_another_gates_gradients(wrong):
+    """Against a branch without the gate, or gated by ``silu``, the same
+    comparison fails on every gradient the gate touches: the 1e-2 above
+    is not room for another function."""
+    operands, cot = _gated_out_case(2)
+    grads = jax.vjp(_gated_out_under_its_norm, *operands)[1](cot)
+    others = jax.vjp(lambda *a: _plain_gated_out(*a, wrong=wrong), *operands)[1](cot)
+    far = {name: rel(g, w) for name, g, w in zip(GATED_OUT_NAMES, grads, others)}
+    assert all(far[name] > 0.1 for name in ("n1", "mixed", "wgate", "wo")), far

@@ -255,10 +255,13 @@ def test_a_shares_ungated_experts_against_a_loop_over_the_held_experts(hidden, w
 #: the rule, so ``afmoe`` and ``mla`` hold too: the kernel form is held by ``test_moe_trunk.py`` on both sides of the rule
 #: and by ``test_trunk_tpu_compile.py`` at the dense layer's published size. PR 44 MEANT to change ``hybrid`` (the mixer's
 #: convolution with its silu and its gate with the grouped norm became two kernel pairs, ``ops/mamba_mix.py``): its pin was
-#: read anew on PR 44's tree (the parent 3160177 read b47f7635...763a); the three others passed unedited.
+#: read anew on PR 44's tree (the parent 3160177 read b47f7635...763a); the three others passed unedited. PR 50 MEANT to change
+#: ``afmoe`` (the gated out-projection became one ``custom_vjp``, ``trunk._gated_out``, taken by the layer's own
+#: ``cfg.gated_attention`` at every size): its pin was read anew on PR 50's tree (the parent 1a857f0 read 3bb78678...6f35); the
+#: three others and ``cca``'s passed unedited: no ungated block's program moved.
 PARENT_STEP_SHA256 = {
     "llada": "60f5865d293d8516a7b2474ae17766489aca839166a5b0790d0a185cee8b0c77",
-    "afmoe": "3bb78678b125d3e25ffcd3ae70ee260376b77e41d93b3becf876fd6c40f16f35",
+    "afmoe": "52990bc0282ffb143aa2d7d1bdaeb6ee266d3f634a15be8d79fcbbcaf6b65d49",
     "mla": "0fedb499d5d0ceb1b7924dbb2f29537fddb8a67cbebdbcd6d1a68efeda7719e2",
     "hybrid": "3fa4c14a61f2e5625e227cbc27f8da0358f6120b0c9c8837d6f423315ff94f71",
 }

@@ -99,31 +99,55 @@ def test_the_joined_product_and_the_gate_kernels_compile_at_published_widths(one
     assert not _xla_passes_over_slots(text, slots)
 
 
-def _opcodes_fused_with_a_product(text: str):
-    """For each fusion of a compiled module's entry computation that holds a ``convolution`` (its own or a nested fusion's,
-    which is how the TPU compiler puts a producer into a product's operand): its name and every opcode it holds."""
+def _computations(text: str):
+    """A compiled module's computations by name, each a list of its instructions as (name, opcode, operand names, the
+    computation it calls or None, the types of its results such as ``bf16[16384,4096]``), and the name of the entry computation."""
     import re
 
     computations, current = {}, None
     for line in text.splitlines():
         header = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line) if not line.startswith(" ") else None
+        instruction = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][\w\-]*)\(([^)]*)\)", line)
         if header:
             current = computations.setdefault(header.group(1), [])
-        elif current is not None and " = " in line:
-            current.append(line)
+        elif current is not None and instruction:
+            name, results, opcode, operands = instruction.groups()
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            current.append((name, opcode, re.findall(r"%([\w.\-]+)", operands), called and called.group(1), re.findall(r"\w+\[[\d,]*\]", results)))
+    return computations, re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+
+
+def _opcodes_fused_with_a_product(text: str, feeding: bool = False):
+    """For each fusion of a compiled module's entry computation that holds a ``convolution`` (its own or a nested fusion's,
+    which is how the TPU compiler puts a producer into a product's operand): its name and every opcode it holds, or with
+    ``feeding`` the opcodes that feed the convolution alone (transitively its operands inside the fusion, a nested fusion
+    whole): what sits in the product's operand, not what reads its result."""
+    computations, entry = _computations(text)
 
     def held(computation):
         opcodes = set()
-        for line in computations.get(computation, ()):
-            opcode = re.search(r" = .+? ([a-z][\w\-]*)\(", line)
-            called = re.search(r"calls=%?([\w.\-]+)", line)
-            opcodes |= held(called.group(1)) if opcode and opcode.group(1) == "fusion" and called else {opcode.group(1)} if opcode else set()
+        for _name, opcode, _operands, called, _results in computations.get(computation, ()):
+            opcodes |= held(called) if opcode == "fusion" and called else {opcode}
         return opcodes
 
-    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
-    fusions = {re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line).group(1): held(re.search(r"calls=%?([\w.\-]+)", line).group(1))
-               for line in computations[entry] if re.search(r" fusion\(.*calls=", line)}
-    return {name: opcodes for name, opcodes in fusions.items() if "convolution" in opcodes}
+    def fed(computation):
+        instructions = {name: rest for name, *rest in computations.get(computation, ())}
+        opcodes, seen, reached = set(), set(), []
+        for opcode, operands, called, _results in instructions.values():
+            if opcode == "convolution" or opcode == "fusion" and called and "convolution" in held(called):
+                reached += operands
+                opcodes |= fed(called) if opcode == "fusion" else set()
+        while reached:
+            name = reached.pop()
+            if name in instructions and name not in seen:
+                seen.add(name)
+                opcode, operands, called, _results = instructions[name]
+                opcodes |= held(called) if opcode == "fusion" and called else {opcode}
+                reached += operands
+        return opcodes
+
+    return {name: (fed if feeding else held)(called) for name, opcode, _operands, called, _results in computations[entry]
+            if opcode == "fusion" and called and "convolution" in held(called)}
 
 
 DENSE_WIDTH = 6144  # the leading dense layer of afmoe_trunk_train_b256 and mla_trunk_train_b256, on 256 x 64 tokens
@@ -296,6 +320,59 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "board_attention" in text and "board_attention_grad" in text
+
+
+def _products_results_and_operands(text: str):
+    """For each fusion of the entry computation that holds a ``convolution``: the types of its results, and of each operand
+    its type and the opcode it comes from (seen through the entry's bitcasts and tuple elements)."""
+    computations, entry = _computations(text)
+    by_name = {name: rest for name, *rest in computations[entry]}
+
+    def origin(name):
+        opcode, operands, _called, _results = by_name[name]
+        return origin(operands[0]) if opcode in ("bitcast", "get-tuple-element") else opcode
+
+    return {name: (by_name[name][3], [(t, origin(operand)) for operand in by_name[name][1] for t in by_name[operand][3][:1]])
+            for name in _opcodes_fused_with_a_product(text)}
+
+
+def test_the_gated_out_projections_products_read_and_write_arrays(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of ``_attention``, its post-norm and the residual
+    as the loop adds them, 256 boards of ``AFMOE``: since PR 50 the gate
+    and ``W_o`` are one ``custom_vjp`` (``trunk._gated_out``) whose
+    products read arrays. The fifteen products (five projections, three
+    passes each) are all there are; NOTHING that feeds a product holds an
+    ``exponential`` or a ``divide`` (the one fusion of a product that holds
+    them is the gate's forward product, whose RESULT the sigmoid reads:
+    read on the chip at 1.48 ms against 1.43 alone, PERF.md section 6,
+    PR 50); the only float32 ``[16384, 4096]`` a product reads is the
+    gradient kernel's ``dq``, in ``W_q``'s two gradient products (the
+    gate's logits go from their product to a pass, ``mixed`` is read
+    bfloat16 as the kernel wrote it); and the one product that writes
+    bfloat16 ``[16384, 4096]`` alone, ``W_o``'s input gradient, has that
+    ONE result. The parent left the branch to autodiff: four products read
+    a float32 ``[16384, 4096]`` that no kernel wrote (the gate's forward
+    product ``mixed`` as a float32 array made for it, ``W_o``'s input
+    gradient the sigmoid, ``W_gate``'s both and its weight gradient's
+    operand the same two), and ``W_o``'s input gradient had two results,
+    the sigmoid's multiply behind the product."""
+    sublayer = trunk.trunk_plan(AFMOE)[0]
+    wide = f"[{AFMOE_BOARDS * trunk.SQUARES},{AFMOE.heads * AFMOE.head_dim}]"
+
+    def loss(x, p):
+        branch = trunk._attention(x, p, AFMOE, sublayer)[0]
+        with jax.named_scope(f"{sublayer.layer}.attention"):
+            return jnp.sum(jnp.square(x + trunk._rms_norm(branch, p[sublayer.post_norm], AFMOE.rms_eps)))  # a cotangent that waits for the result
+
+    x = jax.ShapeDtypeStruct((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, _sublayer_shapes(AFMOE, sublayer, one_chip)).compile().as_text()
+    held, fed = _opcodes_fused_with_a_product(text), _opcodes_fused_with_a_product(text, feeding=True)
+    assert len(held) == 15, sorted(held)
+    assert not {name: sorted(opcodes & {"exponential", "divide"}) for name, opcodes in fed.items() if opcodes & {"exponential", "divide"}}
+    products = _products_results_and_operands(text)
+    reads_wide = {name: [source for t, source in operands if t == f"f32{wide}"] for name, (_results, operands) in products.items()}
+    assert sorted(sources for sources in reads_wide.values() if sources) == [["custom-call"], ["custom-call"]], reads_wide
+    assert [results for name, (results, _operands) in products.items() if results[0] == f"bf16{wide}" and "exponential" not in held[name]] == [[f"bf16{wide}"]]
 
 
 #: Temporaries of the program below as the parent of PR 38 compiled it, bytes: neither form may pass them by more than the
