@@ -4,7 +4,7 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes six
+tower's own policy and value heads. ``TrunkConfig`` describes seven
 published blocks as one code path at different values; no attention has
 a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
@@ -12,13 +12,13 @@ running them over a board removes nothing.
 The program reads a trunk as a LIST OF SUBLAYERS (``trunk_plan``, made once a configuration), each ``x <- x +
 [post-norm](kind(norm(x)))``, and runs ONE loop over it (``trunk_forward_counted``). A kind is one function ``(x, p, cfg,
 sublayer) -> (branch, counters by name)`` (a row of ``_KINDS``) and owns one row of ``_OWNS``, the table of stacked tensors
-by kind that ``trunk_param_shapes``, the loop's slices (``sublayer_params``) and the checkpoint reader go by. Five kinds mix
+by kind that ``trunk_param_shapes``, the loop's slices (``sublayer_params``) and the checkpoint reader go by. Six kinds mix
 tokens: ``attention`` (``_attention``), ``latent`` (``_latent_attention``: deepseek_v3's), ``cca`` (``_cca_attention``:
-zaya's), ``mamba`` (``_mamba``), ``kda`` (``_kda``: kimi_linear's); two are feed-forwards: ``dense`` (``_dense_layer``),
+zaya's), ``mamba`` (``_mamba``), ``kda`` (``_kda``: kimi_linear's), ``gdn`` (``_gdn``: qwen3_next's); two are feed-forwards: ``dense`` (``_dense_layer``),
 ``routed`` (``_routed_layer``). A
 layer of the first block is attention then routed, under ``attn_norm[i]`` and ``moe_norm[i]``; of the second, attention then
 dense or routed, a post-norm each; of the third, latent then dense or routed; of the fifth, cca then routed; of the sixth, kda
-or latent, as ``mixers[i]`` says, then dense or routed; a layer of the
+or latent, as ``mixers[i]`` says, then dense or routed; of the seventh, gdn or attention, as ``mixers[i]`` says, then routed; a layer of the
 fourth is ONE of mamba, routed and attention, as ``pattern`` says, under ``layer_norm[i]``. A new token mixer is one
 function, one row in each of the two tables, its shapes (``_kind_shapes``), its fields of ``TrunkConfig`` with their
 refusal, and one helper of the checkpoint reader (``_SIZES``): nothing inside another kind's function.
@@ -263,6 +263,43 @@ one ``jax.jit``, so a program traces and lowers the pair once for all its
 KDA layers; the low-rank gates, softplus, beta, the gated head norm and the
 out-projection are XLA's under ``layerNN.kda`` beside it.
 
+The seventh block is Qwen3-Next-80B-A3B's (Qwen, config.json,
+``model_type`` qwen3_next: hidden 2048, 48 layers, three Gated DeltaNet
+mixers (arXiv:2412.06464) of 16 key heads and 32 value heads of 128 behind
+a convolution of 4 to one gated attention layer of 16 query heads over 2
+key-value heads of 256, RoPE theta 1e7 on the first 64 columns of a head;
+every layer routed: 512 experts of width 512, softmax scores, top-10
+renormalised, beside ONE shared expert of width 512 under a sigmoid gate a
+token; RMSNorm eps 1e-6 with zero-centred gains); what its config.json
+does not say is the public ``qwen3_next`` modelling code's and the Gated
+DeltaNet paper's, listed under ``assumed`` in
+``benchmark/configs/qwen3-next-trunk-train.json`` each with its basis. ``N``
+is RMSNorm with a gain ``1 + w`` (``zero_centered_norms``: the parameter is
+``w``, from zeros; ``trunk_forward_counted`` adds the 1 once, outside every
+kind's function); K key heads, V value heads, d a head::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer i   a = x + Mixer_i(N_1(x));   y = a + MoE(N_2(a))            Mixer_i = mixers[i]; two norms a layer; no dense layer
+    gdn       [q | k | v | z] = n W_qkvz                                ``gdn_qkvz`` [hidden, 2 K d + 2 V d]: q's, k's, v's, z's columns in turn
+              [b | a] = n W_ba                                          ``gdn_ba`` [hidden, 2 V]
+              [q | k | v] <- silu(conv([q | k | v]))                    ONE depthwise causal convolution, ``conv_kernel`` taps, no bias (``gdn_conv``)
+              beta = sigmoid(b);  g = -exp(A_log[h]) * softplus(a + dt_bias[h])      [T, V] float32: ONE log-decay a VALUE head and token
+              q_h <- q_h / sqrt(|q_h|^2 + 1e-6) * d^-1/2;  k_h <- k_h / sqrt(|k_h|^2 + 1e-6);  value head h reads key head h // (V / K)
+              S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T;   o_t = S_t^T q_t        S [d, d] a value head, zero before square 0
+              out = ( N_d(o; plain gain ``gdn_o_norm`` [d]) * silu(z) ) W_out        ``gdn_out`` [V d, hidden]: the norm BEFORE the gate
+    attention the second block's (``_attention`` with ``gated_attention``, ``_gated_out``) at a head of 256 under ``rotary_dim`` 64, no post-norm
+    MoE       the first block's softmax router with ``route_norm``;  out = sigmoid(n w_s) * Shared(n) + sum over the chosen HELD of w_j E_j(n)
+              ``shared_token_gate`` [hidden, 1]: float32, a sum over the hidden columns, no product
+    out       N_final(y) -> the heads
+
+Mechanism, the seventh block: ``gdn_qkvz`` is one tensor whose z columns
+are split off on the weights' side (as ``mamba_in``'s: the convolution's
+kernel reads its operand whole), the convolution is the fourth block's
+kernel pair told three widths and a zero bias, and the core is the second
+form of ``ops/board_delta.py``'s pair, told by ``g`` in ``beta``'s shape: q
+and k at the key heads and g, beta ``[boards, 64, V]`` as the layer makes
+them, nothing repeated a value head or broadcast a channel in HBM.
+
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums
 over heads (a KDA head's state, norm and gate are its own; the latent is
@@ -392,6 +429,8 @@ Params = Dict[str, jax.Array]
 _INIT_STD = 0.02
 #: Mamba-2's ``time_step_min``, ``time_step_max`` and ``time_step_floor``: where a fresh mixer's steps lie (``init_trunk_params``).
 _TIME_STEP_MIN, _TIME_STEP_MAX, _TIME_STEP_FLOOR = 0.001, 0.1, 1e-4
+#: A fresh GDN head's rate ``exp(gdn_A_log)`` is uniform in (0, 16): this much over 0, so that no draw's logarithm is -inf.
+_GDN_RATE_FLOOR = 1e-6
 
 
 @dataclass(frozen=True)
@@ -455,6 +494,16 @@ class TrunkConfig:
     mixers: Optional[Tuple[str, ...]] = None
     kda_heads: int = 0
     kda_head_dim: int = 0
+    # What the seventh block adds (module docstring): ``mixers`` may instead name "gdn" (Gated DeltaNet: the four sizes below, named
+    # after the published keys, and ``conv_kernel``) and "attention" (the first kind, at ``heads``, ``kv_heads``, ``head_dim``);
+    # ``shared_token_gate``: the shared expert under ``sigmoid(n w)``, one gate a token; ``zero_centered_norms``: the gain of the two
+    # layer norms, the q- and k-norms and the final norm is ``1 + w``, ``w`` the parameter, from zeros.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    shared_token_gate: bool = False
+    zero_centered_norms: bool = False
 
     def __post_init__(self) -> None:
         first, count = self.held
@@ -462,6 +511,8 @@ class TrunkConfig:
         pattern = self.pattern or ""
         cca = self.cca is not None
         mixers = tuple(self.mixers or ())
+        gdn = (self.linear_num_key_heads, self.linear_num_value_heads, self.linear_key_head_dim, self.linear_value_head_dim)
+        seventh = bool(mixers) and set(mixers) <= {"gdn", "attention"}
         if mixers and self.layers in (1, len(mixers)):
             object.__setattr__(self, "layers", len(mixers))
         if pattern and self.layers in (1, len(pattern)):
@@ -503,12 +554,20 @@ class TrunkConfig:
             "columns are qk_rope_head_dim)": self.rotary_dim is not None and (latent or self.rotary_dim % 2 != 0
                                                                                   or not 0 < self.rotary_dim <= self.head_dim),
             f"router_hidden {self.router_hidden} is under 0": self.router_hidden < 0,
-            f"mixers {self.mixers} are not {self.layers} of kda and latent, or stand beside a pattern or cca (which tell the mixer themselves) "
-            "or post_norms": bool(mixers) and (len(mixers) != self.layers or bool(set(mixers) - {"kda", "latent"}) or bool(pattern) or cca
-                                               or self.post_norms),
+            f"mixers {self.mixers} are not {self.layers} of kda and latent, or of gdn and attention, or stand beside a pattern or cca (which tell "
+            "the mixer themselves) or post_norms": bool(mixers) and (len(mixers) != self.layers or not (seventh or set(mixers) <= {"kda", "latent"})
+                                                                     or bool(pattern) or cca or self.post_norms),
             "a latent among the mixers wants kv_lora_rank and its three widths": "latent" in mixers and not latent,
             f"a kda mixer wants kda_heads, kda_head_dim and conv_kernel over 0, got {(self.kda_heads, self.kda_head_dim, self.conv_kernel)}":
                 "kda" in mixers and min(self.kda_heads, self.kda_head_dim, self.conv_kernel) < 1,
+            f"a gdn mixer wants linear_num_key_heads, linear_num_value_heads, linear_key_head_dim, linear_value_head_dim and conv_kernel over 0, "
+            f"whole groups of value heads a key head and one width for both (a state is [d, d]), got {(*gdn, self.conv_kernel)}":
+                "gdn" in mixers and (min(*gdn, self.conv_kernel) < 1 or gdn[1] % gdn[0] != 0 or gdn[2] != gdn[3]),
+            f"Gated DeltaNet's sizes {gdn} stand beside no gdn mixer": any(gdn) and "gdn" not in mixers,
+            "gdn and attention mixers have no latent (kv_lora_rank)": seventh and latent,
+            "shared_token_gate and zero_centered_norms are the seventh block's (mixers of gdn and attention), and the gate wants a shared "
+            "expert (shared_width)": (self.shared_token_gate or self.zero_centered_norms) and not seventh
+                                     or self.shared_token_gate and not self.shared_width,
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -524,7 +583,7 @@ class TrunkConfig:
 
     @property
     def attention_layers(self) -> int:
-        return self.pattern.count("*") if self.pattern else self.layers - (self.mixers or ()).count("kda")
+        return self.pattern.count("*") if self.pattern else self.layers - sum(kind in ("kda", "gdn") for kind in self.mixers or ())
 
 
 class Sublayer(NamedTuple):
@@ -571,11 +630,14 @@ _OWNS = {
     "cca": ("wq", "wk", "wv1", "wv2", "conv0_w", "conv0_b", "conv1_w", "conv1_b", "temp", "wo"),
     "kda": ("kda_q", "kda_k", "kda_v", "kda_conv", "kda_fa", "kda_fb", "kda_dt_bias", "kda_A_log", "kda_beta", "kda_ga", "kda_gb", "kda_o_norm",
             "kda_out"),
+    "gdn": ("gdn_qkvz", "gdn_ba", "gdn_conv", "gdn_dt_bias", "gdn_A_log", "gdn_o_norm", "gdn_out"),
     "dense": ("dense_gate", "dense_up", "dense_down"),
     "routed": ("router_w", "experts_gate", "experts_up", "experts_down", "shared_gate", "shared_up", "shared_down", "expert_bias",
-               "router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3"),
+               "router_down", "router_down_b", "router_w1", "router_w1_b", "router_w2", "router_w2_b", "router_w3", "shared_token_gate"),
 }
-_MIXERS, _FEED_FORWARDS = ("mamba", "attention", "latent", "cca", "kda"), ("dense", "routed")
+_MIXERS, _FEED_FORWARDS = ("mamba", "attention", "latent", "cca", "kda", "gdn"), ("dense", "routed")
+#: The norms whose gain is ``1 + w`` under ``zero_centered_norms`` (``centred_gains``); a GDN head's ``gdn_o_norm`` is a plain gain.
+_ZERO_CENTERED = ("attn_norm", "moe_norm", "q_norm", "k_norm", "final_norm")
 #: What the second block added to the first's file comes after the heads, in this order: ``init_trunk_params`` deals the
 #: split of its rng out in the order of ``trunk_param_shapes``' keys, so the order is every seed's tensors.
 _LATE = ("wgate", "post_attn_norm", "post_mlp_norm", "dense_gate", "dense_up", "dense_down", "shared_gate", "shared_up", "shared_down")
@@ -603,6 +665,10 @@ def _kind_shapes(cfg: TrunkConfig, kind: str, n: int) -> Dict[str, Tuple[int, ..
         return {"kda_q": (n, h, p), "kda_k": (n, h, p), "kda_v": (n, h, p), "kda_conv": (n, 3 * p, cfg.conv_kernel),
                 "kda_fa": (n, h, d), "kda_fb": (n, d, p), "kda_dt_bias": (n, p), "kda_A_log": (n, cfg.kda_heads), "kda_beta": (n, h, cfg.kda_heads),
                 "kda_ga": (n, h, d), "kda_gb": (n, d, p), "kda_o_norm": (n, d), "kda_out": (n, p, h)}
+    if kind == "gdn":  # q and k in ``key heads x d`` columns, v and z in ``value heads x d``; the convolution over q, k and v
+        d, key, value = cfg.linear_key_head_dim, cfg.linear_num_key_heads * cfg.linear_key_head_dim, cfg.linear_num_value_heads * cfg.linear_value_head_dim
+        return {"gdn_qkvz": (n, h, 2 * key + 2 * value), "gdn_ba": (n, h, 2 * cfg.linear_num_value_heads), "gdn_conv": (n, 2 * key + value, cfg.conv_kernel),
+                "gdn_dt_bias": (n, cfg.linear_num_value_heads), "gdn_A_log": (n, cfg.linear_num_value_heads), "gdn_o_norm": (n, d), "gdn_out": (n, value, h)}
     if kind == "mamba":
         mixer, state = cfg.mamba_heads * cfg.mamba_head_dim, cfg.mamba_groups * cfg.state_size
         return {"mamba_in": (n, h, 2 * mixer + 2 * state + cfg.mamba_heads), "conv_w": (n, mixer + 2 * state, cfg.conv_kernel),
@@ -617,7 +683,8 @@ def _kind_shapes(cfg: TrunkConfig, kind: str, n: int) -> Dict[str, Tuple[int, ..
             "router_down": (n, h, rh), "router_down_b": (n, rh), "router_w1": (n, rh, rh), "router_w1_b": (n, rh),
             "router_w2": (n, rh, rh), "router_w2_b": (n, rh), "router_w3": (n, rh, cfg.experts)}
         shapes = {**router, **ffn("experts", cfg.expert_width, cfg.held[1]), **(ffn("shared", cfg.shared_width) if cfg.shared_width else {})}
-    return {name: shape for name, shape in shapes.items() if cfg.gated_ffn or not name.endswith("_gate")}
+    shapes = {name: shape for name, shape in shapes.items() if cfg.gated_ffn or not name.endswith("_gate")}
+    return {**shapes, "shared_token_gate": (n, h, 1)} if kind == "routed" and cfg.shared_token_gate else shapes
 
 
 def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
@@ -667,7 +734,11 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     softplus), the direct term ``D_skip`` 1, the convolution uniform
     within 1 / sqrt(its taps) under a zero bias; a KDA mixer's rates
     ``exp(kda_A_log)``, steps ``softplus(kda_dt_bias)`` (a channel) and
-    taps ``kda_conv`` alike. The fifth block's mix
+    taps ``kda_conv`` alike; a GDN mixer's as the public layer resets
+    them: rates ``exp(gdn_A_log)`` uniform in (0, 16), ``gdn_dt_bias``
+    ones, the head norm's plain gain ones, the taps as the others; and
+    under ``zero_centered_norms`` the five norms of ``_ZERO_CENTERED``
+    start at ZERO (their gain is ``1 + w``). The fifth block's mix
     starts as a pass: ``conv0_w`` 1 at the token's own tap and 0 at the
     earlier ones, ``conv1_w`` the identity at the token's own tap, both
     biases and the router MLP's zero, the key temperature ``temp`` 1. The
@@ -682,7 +753,11 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
     uniform = lambda name, low, high: jax.random.uniform(keys[name], shapes[name], jnp.float32, low, high)
     params: Params = {}
     for name, shape in shapes.items():
-        if name.endswith("_norm") or name in ("D_skip", "temp"):
+        if cfg.zero_centered_norms and name in _ZERO_CENTERED:
+            params[name] = jnp.zeros(shape, jnp.float32)
+        elif name == "gdn_A_log":
+            params[name] = jnp.log(uniform(name, _GDN_RATE_FLOOR, 16.0))
+        elif name.endswith("_norm") or name in ("D_skip", "temp", "gdn_dt_bias"):
             params[name] = jnp.ones(shape, jnp.float32)
         elif name == "conv0_w":  # [layers, columns, taps]: the last tap is the token's own
             params[name] = jnp.zeros(shape, jnp.float32).at[..., -1].set(1.0)
@@ -695,7 +770,7 @@ def init_trunk_params(rng: jax.Array, cfg: TrunkConfig = TrunkConfig()) -> Param
         elif name in ("dt_bias", "kda_dt_bias"):
             step = jnp.maximum(jnp.exp(uniform(name, math.log(_TIME_STEP_MIN), math.log(_TIME_STEP_MAX))), _TIME_STEP_FLOOR)
             params[name] = step + jnp.log(-jnp.expm1(-step))
-        elif name in ("conv_w", "kda_conv"):
+        elif name in ("conv_w", "kda_conv", "gdn_conv"):
             params[name] = uniform(name, -1.0, 1.0) / math.sqrt(shape[-1])
         # a bias is a vector (``wkv_b`` is a matrix), or the convolution's, a vector a mixer
         elif (name.endswith("_b") and len(shape) == 1) or name in ("value_fc2_w", "conv_b", *_STACKED_BIASES):
@@ -1047,6 +1122,37 @@ def _kda(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple
         gate = jax.nn.sigmoid(_matmul(_matmul(n, p["kda_ga"]), p["kda_gb"]))
         normed = _rms_norm(o.reshape(tokens, heads, d), p["kda_o_norm"], cfg.rms_eps)  # a head's own norm, one gain for all heads
         return _matmul(normed.reshape(tokens, inner) * gate, p["kda_out"]), counters
+
+
+def _gdn(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The gdn kind (the seventh block's): [tokens, hidden] float32, 64
+    tokens a board -> a Gated DeltaNet mixer's output, same shape, and its
+    two counters. All of it runs under ``<layer>.gdn`` but the delta
+    rule's core (``board_delta``'s second form, which also makes the l2
+    norms of q and k), under ``<layer>.delta`` beside it, never inside
+    (module docstring, "Mechanism, the seventh block"). ``gdn_qkvz``'s
+    columns are ``[q | k | v | z]``: z is split off on the weights' side,
+    q, k and v are ONE product and ONE convolution with its silu whose
+    three bfloat16 results (q and k at the key heads, v at the value
+    heads) are the core's operands as they are; ``g`` and ``beta`` go to
+    it as they are made, one a value head and token."""
+    heads, d, layer, tokens = cfg.linear_num_value_heads, cfg.linear_value_head_dim, sublayer.layer, x.shape[0]
+    key, value = cfg.linear_num_key_heads * cfg.linear_key_head_dim, heads * d
+    with jax.named_scope(f"{layer}.gdn"):
+        n = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
+        w = p["gdn_qkvz"]
+        qkv, z = _by_board(_matmul(n, w[:, :2 * key + value])), _matmul(n, w[:, 2 * key + value:])
+        q, k, v = mamba_conv(qkv, p["gdn_conv"], jnp.zeros((2 * key + value,), jnp.float32), (key, key, value), _interpret())
+        ba = _matmul(n, p["gdn_ba"])
+        beta = jax.nn.sigmoid(ba[:, :heads])
+        g = -jnp.exp(p["gdn_A_log"]) * jax.nn.softplus(ba[:, heads:] + p["gdn_dt_bias"])  # a log-decay a value head and token
+        kept, written = jax.lax.stop_gradient((g, beta))
+        counters = {"gdn_state_kept": jnp.mean(jnp.exp(kept)), "gdn_beta": jnp.mean(written)}
+    with jax.named_scope(f"{layer}.delta"):
+        o = board_delta(q, k, v, _by_board(g), _by_board(beta), _interpret())
+    with jax.named_scope(f"{layer}.gdn"):
+        normed = _rms_norm(o.reshape(tokens, heads, d), p["gdn_o_norm"], cfg.rms_eps)  # a head's own norm, one plain gain for all heads, BEFORE the gate
+        return _matmul((normed * jax.nn.silu(z.reshape(tokens, heads, d))).reshape(tokens, value), p["gdn_out"]), counters
 
 
 def _interpret() -> bool:
@@ -1478,22 +1584,27 @@ def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkCon
 
 def _routed_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The routed kind: the norm under the router's scope, the held
-    experts, the shared expert beside them; the layer's routing
-    counters. Its residual is added under the combine's scope."""
+    experts, the shared expert beside them (under its token gate where
+    the block has one); the layer's routing counters. Its residual is
+    added under the combine's scope."""
     name = sublayer.layer
     with jax.named_scope(f"{name}.router"):
         n2 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
     mixed, counters = _experts(n2, p, cfg, name)
     if cfg.shared_width:
         with jax.named_scope(f"{name}.shared"):
-            mixed = mixed + _ffn(n2, p, "shared", cfg.gated_ffn)
+            shared = _ffn(n2, p, "shared", cfg.gated_ffn)
+            if cfg.shared_token_gate:  # the seventh block's: one sigmoid gate a token, float32 (a sum over the hidden columns, no product)
+                gate = jax.nn.sigmoid(jnp.sum(n2 * p["shared_token_gate"][:, 0], axis=-1, keepdims=True))
+                shared, counters = gate * shared, {**counters, "shared_gate_mean": jnp.mean(jax.lax.stop_gradient(gate))}
+            mixed = mixed + shared
     return mixed, counters
 
 
 #: Kind of sublayer -> its function, ``(x, p, cfg, sublayer) -> (branch, counters by name)``, which opens its own scopes and is
 #: called under none, and the scope under which the loop adds the branch (through the sublayer's post-norm) to the stream.
 _KINDS = {"attention": (_attention, "attention"), "latent": (_latent_attention, "attention"), "cca": (_cca_attention, "attention"),
-          "mamba": (_mamba, "mamba"), "kda": (_kda, "kda"), "dense": (_dense_layer, "dense"), "routed": (_routed_layer, "combine")}
+          "mamba": (_mamba, "mamba"), "kda": (_kda, "kda"), "gdn": (_gdn, "gdn"), "dense": (_dense_layer, "dense"), "routed": (_routed_layer, "combine")}
 
 #: The order in which ONE layer's slices are made: nothing but the lowered text depends on it, and the step pins hold that text
 #: (``tests/test_hybrid_trunk.py PARENT_STEP_SHA256``). It is the order in which the blocks came: a block layer's feed-forward
@@ -1526,11 +1637,19 @@ def sublayer_params(params: Params, sublayer: Sublayer) -> Params:
     return next(_sliced(params, (sublayer,)))[1]
 
 
+def centred_gains(params: Params, cfg: TrunkConfig) -> Params:
+    """``params`` as every kind's function reads them: under
+    ``zero_centered_norms`` the five norms of ``_ZERO_CENTERED`` as their
+    gains ``1 + w`` (the parameter, the optimizer's weight decay and the
+    checkpoint keep ``w``); else ``params`` themselves."""
+    return {name: 1.0 + value if name in _ZERO_CENTERED else value for name, value in params.items()} if cfg.zero_centered_norms else params
+
+
 def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
     """``trunk_forward`` and the counters of the step's metrics: what
     the sublayers counted, folded over the sublayers as ``_FOLDS`` says
     (which also says what each one is), and the whole trunk's own three."""
-    b = planes.shape[0]
+    b, params = planes.shape[0], centred_gains(params, cfg)
     # Scope names are a contract (doc/observability.md "Training and compilation"): one scope a part, the layer in its name,
     # because the benchmark's scope table keeps two levels of a path (phase, then this).
     with jax.named_scope("embed"):
@@ -1580,6 +1699,9 @@ _FOLDS = {
     "ssm_decay_min": jnp.min,  # the smallest decay across a board, ``exp(c_63 - c_0)``, of any head of any mixer, mean over boards: a head that forgets a board
     "kda_state_kept": jnp.mean,  # the mean decay ``alpha = exp(g)`` a square, over tokens, heads, channels and KDA mixers: 1 a state that never forgets, 0 one that holds nothing
     "kda_beta": jnp.mean,  # the mean ``beta``, the share of a square's value written over what the state held for its key: 0 a mixer that writes nothing
+    "gdn_state_kept": jnp.mean,  # the mean decay ``exp(g)`` a square, over tokens, value heads and GDN mixers: 1 a state that never forgets, 0 one that holds nothing
+    "gdn_beta": jnp.mean,  # the mean ``beta`` of the GDN mixers, as ``kda_beta``
+    "shared_gate_mean": jnp.mean,  # the mean of the shared expert's sigmoid gate over tokens and layers: 0 a shared expert switched off, 1 one that is never gated
 }
 
 
@@ -1598,7 +1720,7 @@ def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
 #: first block alone has the first three; the rest default to it.
 HPARAMS = "trunk_hparams"
 _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_scale", "balance_rate", "sliding_window",
-            "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim")
+            "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim", "zero_centered")
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
 #: A checkpoint whose mixer is told by layer carries ``mixers``, each layer's kind as its place in ``_MIXERS``.
@@ -1613,7 +1735,8 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     a bit mask, what no shape of the fourth block gives (head_dim: there
     are no qk-norm gains to read it from; mamba_groups) and the fifth's
     rotary_dim (0: RoPE on all of a head; its kernel sizes, head width
-    and router width are shapes). A pattern's file carries the pattern
+    and router width are shapes) and the seventh's zero-centred norms (0
+    or 1; its sizes and its token gate are shapes). A pattern's file carries the pattern
     too (``trunk_pattern``, its characters as bytes), one whose mixer is
     told by layer its ``mixers`` (``trunk_mixers``).
     ``recompute_experts`` is the trainer's and in no file."""
@@ -1622,7 +1745,7 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
         cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
         cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
-        cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0], np.float64)
+        cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0, cfg.zero_centered_norms], np.float64)
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
     if cfg.mixers:
@@ -1655,6 +1778,12 @@ def _kda_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]
     return dict(kda_heads=shape("kda_A_log")[1], kda_head_dim=shape("kda_o_norm")[1], conv_kernel=shape("kda_conv")[2])
 
 
+def _gdn_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the value heads from the rates, a head's width from its norm's gain
+    heads, d, (_, channels, taps) = shape("gdn_A_log")[1], shape("gdn_o_norm")[1], shape("gdn_conv")
+    return dict(linear_num_key_heads=(channels - heads * d) // (2 * d), linear_num_value_heads=heads, linear_key_head_dim=d, linear_value_head_dim=d,
+                conv_kernel=taps)
+
+
 def _mamba_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the groups from the file, the rest from three shapes
     heads, inner, (_, channels, taps), groups = shape("dt_bias")[1], shape("mamba_norm")[1], shape("conv_w"), int(hp["mamba_groups"])
     return dict(mamba_heads=heads, mamba_head_dim=inner // heads, mamba_groups=groups, conv_kernel=taps,
@@ -1665,7 +1794,7 @@ def _mamba_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, objec
 #: ``TrunkConfig`` from those tensors' shapes (``shape``) and the file's values (``hp``).
 _SIZES = {"attention": (("wq", "wk", "wo"), _attention_sizes), "latent": (("kv_norm", "wkv_a", "wkv_b"), _latent_sizes),
           "cca": (("conv0_w", "conv1_w", "wk", "wv1", "wv2", "temp"), _cca_sizes), "mamba": (("mamba_norm", "dt_bias", "conv_w"), _mamba_sizes),
-          "kda": (("kda_A_log", "kda_o_norm", "kda_conv"), _kda_sizes)}
+          "kda": (("kda_A_log", "kda_o_norm", "kda_conv"), _kda_sizes), "gdn": (("gdn_A_log", "gdn_o_norm", "gdn_conv"), _gdn_sizes)}
 assert all(set(names) <= set(_OWNS[kind]) for kind, (names, _) in _SIZES.items())
 
 
@@ -1720,6 +1849,7 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
         held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_up")[1]),
         balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
+        shared_token_gate="shared_token_gate" in params, zero_centered_norms=bool(hp["zero_centered"]),
         router_hidden=shape("router_down")[2] if "router_down" in params else 0,
     ))
 

@@ -85,6 +85,29 @@ pays (``setup_s``); a body works ONE board inside a ``fori_loop`` that is
 not unrolled, for the same reason (``tests/test_board_delta.py`` holds the
 loop rolled, ``tests/test_trunk_tpu_compile.py`` counts the entries into
 both bodies while the cell's step is lowered).
+
+**The second form: a decay a HEAD and token** (Gated DeltaNet, ``models/trunk.py
+_gdn``). ``board_delta`` is told it by what it is handed and does not guess: ``g``
+in ``beta``'s shape, ``[boards, 64, value heads]``, one log-decay a value head
+and square, and v (and o) at ``value heads x d`` columns beside q and k at ``key
+heads x d``, value head h reading key head ``h // (value heads // key heads)``.
+A scalar decay needs no levels: ``D[t, j] = sum of g over the squares (j, t]`` is
+ONE float32 product of a triangle of ones with ``g`` masked a column (every
+entry a plain sum of the ``g`` themselves, none a difference of two cumulative
+sums), ``L = exp(D)`` for ``t >= j`` has no exponent over 0, and::
+
+    Mq = (q^ k^^T) * L   (j <= t)        Mk = (k^ k^^T) * L   (j < t)
+
+are each ONE bfloat16 product under it, made once a KEY head for all of its
+value heads. A grid step is one key head of a few boards and its value heads:
+q and k are read once a key head, v, g and beta once a value head, nothing is
+repeated or broadcast in HBM. The solve and ``Mq U`` are the first form's
+(``_solve``). The differentiated forward keeps ``T`` (a key head's value heads
+side by side in one 128-lane tile) and ``U``; the gradient kernel makes the two
+products and ``L`` again (they are no chain) and, beside the first form's
+gradients of the solve, ``dD = dMq * Mq + dMk * Mk``, ``dg`` from it by the
+triangle's transpose, ``dq^``, ``dk^`` from ``sum over the value heads of dM * L``
+by four products a key head. The first form's programs are what they were.
 """
 
 from __future__ import annotations
@@ -192,9 +215,9 @@ def _solve(mk: jax.Array, v: jax.Array, beta: jax.Array) -> Tuple[jax.Array, jax
     return tm, _exact(tm, beta * v)
 
 
-def _own_beta(beta_ref, i, h) -> Tuple[jax.Array, jax.Array]:
-    """Head ``h``'s beta of board ``i`` as a column ``[64, 1]``, and the mask of its lane in the ``[64, heads]`` block."""
-    block = beta_ref[i]
+def _own_lane(ref, i, h) -> Tuple[jax.Array, jax.Array]:
+    """Head ``h``'s lane of board ``i``'s ``[64, heads]`` block (a head's beta, or its log-decay) as a column ``[64, 1]``, and the lane's mask."""
+    block = ref[i]
     own = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == h
     return jnp.sum(jnp.where(own, block, 0.0), axis=-1, keepdims=True), own
 
@@ -204,7 +227,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
     f32, h = jnp.float32, pl.program_id(1)
 
     def board(i, carry):
-        beta, _ = _own_beta(beta_ref, i, h)
+        beta, _ = _own_lane(beta_ref, i, h)
         qn, kn, _, _, c, _ = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
         mq, mk = _tables(qn, kn, c)
         tm, u = _solve(mk, v_ref[i].astype(f32), beta)
@@ -223,7 +246,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_
     f32, h = jnp.float32, pl.program_id(1)
 
     def board(i, carry):
-        beta, own = _own_beta(beta_ref, i, h)
+        beta, own = _own_lane(beta_ref, i, h)
         v, do = v_ref[i].astype(f32), do_ref[i]
         qn, kn, rq, rk, c, scale = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
         tm, mk, u, mq = solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:], u_ref[i], mq_ref[i, :, :SQUARES]
@@ -254,6 +277,112 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_
         return carry
 
     jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+
+
+# -- the second form: a decay a head and token, value heads in groups on a key head (module docstring) -------------------------
+
+
+def _head_decay(g: jax.Array, t: jax.Array, j: jax.Array) -> jax.Array:
+    """``g`` ``[64, 1]`` (<= 0) -> ``L[t, j] = exp(sum of g over (j, t])`` for ``t >= j``, else 0: float32 ``[64, 64]``."""
+    spans = _exact((t >= j).astype(jnp.float32), jnp.where(t > j, g, 0.0))
+    return jnp.exp(jnp.where(t >= j, spans, _NEVER))
+
+
+def _key_head(q_ref, k_ref, i):
+    """What a key head's value heads share, of board ``i``: ``_unit``'s results, the scale, and the two undecayed tables."""
+    scale = 1.0 / math.sqrt(q_ref.shape[-1])
+    (qn, rq), (kn, rk) = _unit(q_ref[i].astype(jnp.float32)), _unit(k_ref[i].astype(jnp.float32))
+    qn = qn * scale
+    return qn, kn, rq, rk, scale, _dot(qn, kn, _NT), _dot(kn, kn, _NT)
+
+
+def _head_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
+    """``kept_refs`` is empty (the primal) or the differentiated form's two further outputs (``_head_kept``)."""
+    f32, key_head = jnp.float32, pl.program_id(1)
+    d = q_ref.shape[-1]
+    per = v_ref.shape[-1] // d  # value heads a key head
+
+    def board(i, carry):
+        t, j, _ = _squares()
+        _, _, _, _, _, qk, kk = _key_head(q_ref, k_ref, i)
+        for s in range(per):
+            h, columns = key_head * per + s, slice(s * d, (s + 1) * d)
+            decay = _head_decay(_own_lane(g_ref, i, h)[0], t, j)
+            beta, _ = _own_lane(beta_ref, i, h)
+            tm, u = _solve(jnp.where(t > j, kk * decay, 0.0), v_ref[i, :, columns].astype(f32), beta)
+            o_ref[i, :, columns] = _dot(qk * decay, u).astype(o_ref.dtype)
+            if kept_refs:
+                solve_ref, u_ref = kept_refs
+                solve_ref[i, :, s * SQUARES:(s + 1) * SQUARES] = tm
+                u_ref[i, :, columns] = u
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+
+
+def _head_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    f32, key_head = jnp.float32, pl.program_id(1)
+    d = q_ref.shape[-1]
+    per = v_ref.shape[-1] // d
+
+    def board(i, carry):
+        t, j, _ = _squares()
+        lower = (t >= j).astype(f32)
+        qn, kn, rq, rk, scale, qk, kk = _key_head(q_ref, k_ref, i)
+        dqk, dkk = jnp.zeros_like(qk), jnp.zeros_like(kk)
+        for s in range(per):
+            h, columns = key_head * per + s, slice(s * d, (s + 1) * d)
+            g, own = _own_lane(g_ref, i, h)
+            beta, _ = _own_lane(beta_ref, i, h)
+            decay = _head_decay(g, t, j)
+            mq, mk = qk * decay, jnp.where(t > j, kk * decay, 0.0)
+            v, do = v_ref[i, :, columns].astype(f32), do_ref[i, :, columns]
+            tm, u = solve_ref[i, :, s * SQUARES:(s + 1) * SQUARES], u_ref[i, :, columns]
+            dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
+            w = _exact(tm, _dot(mq, do, _TN), _TN)  # T^T dU: the cotangent of beta * V
+            da = -jnp.where(t > j, _exact(w, u, _NT), 0.0)
+            dv_ref[i, :, columns] = (beta * w).astype(dv_ref.dtype)
+            dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(da * mk, axis=-1, keepdims=True)
+            dbeta_ref[i] = jnp.where(own, dbeta, dbeta_ref[i])
+            dmk = beta * da
+            # dD = dL * L = dMq * Mq + dMk * Mk; D = lower (g masked a column): dg_m = the sum over j < m of (lower^T dD)[m, j]
+            spans = _exact(lower, dmq * mq + dmk * mk, _TN)
+            dg_ref[i] = jnp.where(own, jnp.sum(jnp.where(t > j, spans, 0.0), axis=-1, keepdims=True), dg_ref[i])
+            dqk, dkk = dqk + dmq * decay, dkk + dmk * decay
+        dqn = _dot(dqk, kn) * scale
+        dkn = _dot(dqk, qn, _TN) + _dot(dkk, kn) + _dot(dkk, kn, _TN)
+        # through the l2 norms: y = x r, dx = r (dy - y sum(y dy)); q's y is qn / scale
+        qy = qn * (1.0 / scale)
+        dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
+        dk_ref[i] = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+
+
+def _head_blocks(q: jax.Array, v: jax.Array, beta: jax.Array, interpret: bool):
+    """The second form's grid (blocks of boards, KEY heads) and the BlockSpecs of a key head's columns of q or k, of its value
+    heads' columns of v, of a block of boards' g or beta, and of its value heads' ``T`` side by side in whole 128-lane tiles."""
+    boards, squares, inner = q.shape
+    heads = beta.shape[-1]
+    d = v.shape[-1] // heads
+    if squares != SQUARES or v.shape[-1] % heads or inner % d or heads % (inner // d) or beta.shape[:2] != (boards, SQUARES):
+        raise ValueError(f"board_delta: q {q.shape}, v {v.shape} and a decay a head {beta.shape} are not [boards, {SQUARES}, key heads x d], "
+                         f"[boards, {SQUARES}, value heads x d] and [boards, {SQUARES}, value heads], the value heads whole groups a key head")
+    if d % _LANES and not interpret:
+        raise ValueError(f"board_delta: a head of {d} columns is not whole {_LANES}-lane tiles")
+    key_heads = inner // d
+    per = heads // key_heads
+    tb = math.gcd(boards, _BOARDS)
+    by_key_head = lambda width: pl.BlockSpec((tb, SQUARES, width), lambda i, h: (i, 0, h))
+    tile = -(-per * SQUARES // _LANES) * _LANES
+    return (boards // tb, key_heads), by_key_head(d), by_key_head(per * d), pl.BlockSpec((tb, SQUARES, heads), lambda i, h: (i, 0, 0)), by_key_head(tile), tile
+
+
+def _head_kept(v: jax.Array, key_heads: int, tile: int):
+    """What the second form's differentiated forward writes beside o: ``T`` float32 (``_head_blocks``' tile a key head) and ``U`` in v's columns."""
+    boards, squares, _ = v.shape
+    return [jax.ShapeDtypeStruct((boards, squares, key_heads * tile), jnp.float32), jax.ShapeDtypeStruct(v.shape, jnp.float32)]
 
 
 def _blocks(q: jax.Array, beta: jax.Array, interpret: bool):
@@ -289,15 +418,20 @@ def _operands(q, k, v, g, beta):
 #: every call site, at every start (ROADMAP S11; PERF.md section 6, PR 49). The call sites' scopes still name each call's operations.
 @functools.partial(jax.jit, static_argnames=("interpret", "keep"))
 def _forward_call(q, k, v, g, beta, *, interpret: bool, keep: bool):
-    """The forward kernel in its two forms: o alone, or (``keep``) o and the three kept arrays."""
-    grid, head, betas, tile = _blocks(q, beta, interpret)
-    o = jax.ShapeDtypeStruct(q.shape, jnp.bfloat16)
+    """The forward kernel of either form (module docstring), each in its two: o alone, or (``keep``) o and its kept arrays."""
+    if g.shape == beta.shape:  # a decay a head: the second form
+        grid, key_head, value_heads, betas, solve, tile = _head_blocks(q, v, beta, interpret)
+        kernel, in_specs, results, kept = _head_forward_kernel, [key_head, key_head, value_heads, betas, betas], value_heads, ([solve, value_heads], _head_kept(v, grid[1], tile))
+    else:
+        grid, head, betas, tile = _blocks(q, beta, interpret)
+        kernel, in_specs, results, kept = _forward_kernel, [head, head, head, head, betas], head, ([tile, head, tile], _kept(q, beta.shape[-1]))
+    o = jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
     return pl.pallas_call(
-        _forward_kernel,
+        kernel,
         grid=grid,
-        in_specs=[head, head, head, head, betas],
-        out_specs=[head, tile, head, tile] if keep else head,
-        out_shape=[o, *_kept(q, beta.shape[-1])] if keep else o,
+        in_specs=in_specs,
+        out_specs=[results, *kept[0]] if keep else results,
+        out_shape=[o, *kept[1]] if keep else o,
         compiler_params=_PARAMS,
         name="board_delta",
         interpret=interpret,
@@ -306,13 +440,18 @@ def _forward_call(q, k, v, g, beta, *, interpret: bool, keep: bool):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gradient_call(q, k, v, g, beta, kept, do, *, interpret: bool):
-    grid, head, betas, tile = _blocks(q, beta, interpret)
+    if g.shape == beta.shape:  # a decay a head: the second form
+        grid, key_head, value_heads, betas, solve, _ = _head_blocks(q, v, beta, interpret)
+        kernel, in_specs, out_specs = _head_backward_kernel, [key_head, key_head, value_heads, betas, betas, solve, value_heads, value_heads], [key_head, key_head, value_heads, betas, betas]
+    else:
+        grid, head, betas, tile = _blocks(q, beta, interpret)
+        kernel, in_specs, out_specs = _backward_kernel, [head, head, head, head, betas, tile, head, tile, head], [head, head, head, head, betas]
     like = lambda x, dtype: jax.ShapeDtypeStruct(x.shape, dtype)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _backward_kernel,
+        kernel,
         grid=grid,
-        in_specs=[head, head, head, head, betas, tile, head, tile, head],
-        out_specs=[head, head, head, head, betas],
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[like(q, jnp.bfloat16), like(k, jnp.bfloat16), like(v, jnp.bfloat16), like(g, jnp.float32), like(beta, jnp.float32)],
         compiler_params=_PARAMS,
         name="board_delta_grad",
@@ -326,7 +465,9 @@ def board_delta(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: ja
     """The delta rule's core (module docstring): q, k, v ``[boards, 64,
     heads * d]`` bfloat16 (before their l2 norms), ``g`` float32 in that
     shape (a log-decay a channel, <= 0), ``beta`` ``[boards, 64, heads]``
-    float32 -> o in q's shape, bfloat16."""
+    float32 -> o in q's shape, bfloat16. Handed ``g`` in ``beta``'s shape
+    (a log-decay a head) it is the second form: q and k ``[boards, 64, key
+    heads * d]``, v ``[boards, 64, value heads * d]``, o in v's shape."""
     return _called(_forward_call, interpret)(q, k, v, g, beta, interpret=interpret, keep=False)
 
 

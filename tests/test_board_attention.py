@@ -379,3 +379,54 @@ def test_a_part_that_is_odd_or_wider_than_a_head_and_a_part_of_a_latent_are_refu
             board_attention(q, k, v, g_q, g_k, THETA, EPS, True, rotary_dim=rotary_dim)
     with pytest.raises(ValueError, match="not a mixture"):
         board_attention(q, k, v, None, None, THETA, EPS, True, q_pe=q, k_pe=k[..., :16], rotary_dim=8)
+
+
+# -- the seventh block's form: qk-norm under one gain each, a head of 256, RoPE on its first 64 columns, 8 query heads a key-value head --------
+
+# (heads, key-value heads, head_dim, rotary_dim, boards): the published head and group (2 boards a grid step) on one key-value head, and tiny ones
+WIDE_CASES = [(8, 1, 256, 64, 2), (4, 2, 32, 8, 3)]
+WIDE_THETA = 10_000_000.0
+
+
+@functools.lru_cache(maxsize=None)
+def both_wide(heads, kv_heads, head_dim, rotary_dim, boards, turned=None):
+    """The kernel pair told both gains and ``rotary_dim`` against ``plain_part``'s formula under a query gain too; ``turned``: the
+    columns the plain side rotates (a misreading: all of the head)."""
+    q, k, v, g_q, g_k, cotangent = inputs(boards, heads, head_dim, seed=17, kv_heads=kv_heads)
+
+    def plain(q, k, v, g_q, g_k):
+        # ``plain_part`` norms the query without a gain and multiplies the key's: the query's gain goes in through the key's side of
+        # no product, so it is written out here: unit(q) * g_q, rotated as ``plain_part`` rotates
+        split = lambda y: y.astype(jnp.float32).reshape(boards, 64, -1, head_dim)
+        unit = lambda x: x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS)
+        part = turned or rotary_dim
+        half = part // 2
+        angle = np.arange(64)[:, None] / WIDE_THETA ** (np.arange(half) / half)[None, :]
+        cos, sin = (jnp.asarray(np.concatenate([f(angle)] * 2, -1), jnp.float32)[:, None, :] for f in (np.cos, np.sin))
+        turn = lambda x: jnp.concatenate([x[..., :part] * cos + jnp.concatenate([-x[..., half:part], x[..., :half]], -1) * sin, x[..., part:]], -1)
+        qh, kh, vh = turn(unit(split(q)) * g_q), turn(unit(split(k)) * g_k), split(v)
+        kh, vh = (jnp.repeat(y, heads // kv_heads, axis=2) for y in (kh, vh))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision="highest") / np.sqrt(head_dim)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), vh, precision="highest").reshape(boards, 64, -1)
+
+    def sides(f):
+        out, pull = jax.vjp(f, q, k, v, g_q, g_k)
+        return (out, *pull(cotangent.astype(out.dtype)))
+
+    return (jax.jit(lambda: sides(lambda *a: board_attention(*a, WIDE_THETA, EPS, True, rotary_dim=rotary_dim)))(), jax.jit(lambda: sides(plain))())
+
+
+WIDE_OUTPUTS = ["mixed", "d_q", "d_k", "d_v", "d_g_q", "d_g_k"]
+
+
+@pytest.mark.parametrize("output", WIDE_OUTPUTS)
+@pytest.mark.parametrize("heads,kv_heads,head_dim,rotary_dim,boards", WIDE_CASES)
+def test_a_head_of_256_with_64_columns_turned_under_both_gains_matches_the_plain_formula(heads, kv_heads, head_dim, rotary_dim, boards, output):
+    got, want = (side[WIDE_OUTPUTS.index(output)] for side in both_wide(heads, kv_heads, head_dim, rotary_dim, boards))
+    assert got.shape == want.shape and got.dtype == (jnp.bfloat16 if output in ("mixed", "d_v") else jnp.float32)
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL), (output, rel(got, want))
+
+
+def test_the_tolerances_catch_rope_on_all_256_columns():
+    got, want = both_wide(8, 1, 256, 64, 2, turned=256)
+    assert rel(got[0], want[0]) > 3 * FORWARD_TOL and rel(got[1], want[1]) > 3 * GRADIENT_TOL

@@ -288,3 +288,114 @@ def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_array
     assert len(gradient.invars) == 9 and len(gradient.outvars) == 5
     assert [id(v) for v in gradient.invars[5:8]] == [id(v) for v in gradient_jit.params["jaxpr"].jaxpr.invars[5:8]]
     assert [id(v) for v in gradient_jit.invars[5:8]] == [id(v) for v in forward_jit.outvars[1:]]  # read as they were written
+
+
+# -- the second form: a decay a head and token, value heads in groups on a key head (Gated DeltaNet) ----------------------------------
+
+#: (boards, key heads, value heads a key head, d): the published head with two value heads a key head on two boards, narrow heads
+#: one to one on a block of boards and a remainder, three value heads on one key head.
+HEAD_CASES = {"published_two_a_key_head": (2, 1, 2, 128), "narrow_one_a_key_head": (9, 2, 1, 16), "narrow_two_a_key_head": (3, 2, 2, 16),
+              "three_a_key_head": (2, 1, 3, 32)}
+
+
+def head_operands(case, seed=0, fastest=16.0):
+    """q and k at the key heads, v at the value heads; ``g`` one a value head and square in the layer's own range at its start,
+    ``-exp(A_log) softplus(a + dt_bias)`` with rates uniform in (0, ``fastest``) and ``dt_bias`` 1: a head near 0 never forgets, one
+    at 16 keeps e^-21 of its state a square."""
+    boards, key_heads, per, d = HEAD_CASES[case]
+    heads = key_heads * per
+    rng = np.random.default_rng([seed, heads, d])
+    bf16 = lambda y: jnp.asarray(y, jnp.bfloat16)
+    rate, step = rng.uniform(0.0, fastest, heads), np.log1p(np.exp(1.0 + 0.3 * rng.standard_normal((boards, SQUARES, heads))))
+    return {
+        "q": bf16(rng.standard_normal((boards, SQUARES, key_heads * d))), "k": bf16(rng.standard_normal((boards, SQUARES, key_heads * d))),
+        "v": bf16(rng.standard_normal((boards, SQUARES, heads * d))), "g": jnp.asarray(-rate * step, jnp.float32),
+        "beta": jnp.asarray(rng.uniform(0.05, 0.95, (boards, SQUARES, heads)), jnp.float32),
+    }
+
+
+def head_recurrence(q, k, v, g, beta, key_head_of=None, **mutation):
+    """The first form's literal recurrence on what the second form means: value head h reads key head ``h // per`` (or
+    ``key_head_of(h)``: a misreading) and every channel of a head decays alike."""
+    boards, _, heads = beta.shape
+    d = v.shape[-1] // heads
+    key_heads = q.shape[-1] // d
+    of = [(key_head_of or (lambda h: h // (heads // key_heads)))(h) for h in range(heads)]
+    by_value_head = lambda y: jnp.concatenate([y[..., i * d:(i + 1) * d] for i in of], axis=-1)
+    return recurrence(by_value_head(q), by_value_head(k), v, jnp.repeat(g, d, axis=-1), beta, **mutation)
+
+
+def head_wanted(ops, weight, **mutation):
+    with jax.enable_x64(True):
+        wide = {name: jnp.asarray(np.asarray(value, np.float64)) for name, value in ops.items()}
+        fn = lambda o: head_recurrence(*(o[name] for name in NAMES), **mutation)
+        grads = jax.grad(lambda o: jnp.sum(fn(o) * jnp.asarray(weight, jnp.float64)))(wide)
+        return np.asarray(fn(wide)), {name: np.asarray(value) for name, value in grads.items()}
+
+
+# Readings (CPU interpreter, seeds 0-1, the four cases): forward 0.0030-0.0042 of the result's norm; dq, dk, dv 0.0031-0.0046, dg
+# 0.0034-0.0062, dbeta 0.0027-0.0036. Value head h on key head h % key heads reads 1.3 forward, the decay after the correction 0.17.
+@pytest.mark.parametrize("case", HEAD_CASES)
+def test_the_second_form_against_the_literal_recurrence(case):
+    ops = head_operands(case)
+    weight = np.random.default_rng(7).standard_normal(ops["v"].shape).astype(np.float32)
+    want, want_grads = head_wanted(ops, weight)
+    got, grads = kernel(ops, weight)
+    assert got.dtype == jnp.bfloat16 and got.shape == ops["v"].shape
+    print("second form", case, rel(got, want), {name: round(rel(grads[name], want_grads[name]), 4) for name in NAMES})
+    assert rel(got, want) < TOL, rel(got, want)
+    for name in NAMES:
+        assert grads[name].shape == ops[name].shape and grads[name].dtype == ops[name].dtype, name
+        assert rel(grads[name], want_grads[name]) < TOL, (name, rel(grads[name], want_grads[name]))
+
+
+@pytest.mark.parametrize("fastest", [0.05, 60.0])
+def test_a_head_that_never_forgets_and_one_that_forgets_a_square_at_once(fastest):
+    """Rates near 0 (``exp(g)`` ~ 1: the plain delta rule, the solve at its worst conditioning) and up to 60 (a square keeps e^-80:
+    ``L`` is the identity in float32): every exponent is a sum of ``g`` <= 0, and nothing is a difference of cumulative sums."""
+    ops = head_operands("narrow_two_a_key_head", seed=3, fastest=fastest)
+    weight = np.random.default_rng(8).standard_normal(ops["v"].shape).astype(np.float32)
+    want, want_grads = head_wanted(ops, weight)
+    got, grads = kernel(ops, weight)
+    assert np.isfinite(np.asarray(got, np.float32)).all() and rel(got, want) < TOL
+    for name in NAMES:
+        assert np.isfinite(np.asarray(grads[name], np.float32)).all(), name
+        if np.linalg.norm(want_grads[name]) > 1e-6 * np.linalg.norm(np.asarray(ops[name], np.float64)):  # at 60 no square reads a decay
+            assert rel(grads[name], want_grads[name]) < TOL, (name, rel(grads[name], want_grads[name]))
+
+
+@pytest.mark.parametrize("mutation", [dict(key_head_of=lambda h: h % 2), dict(decay_first=False), dict(unit_beta=True)],
+                         ids=["value_head_h_on_key_head_h_mod_key_heads", "decay_after_the_correction", "beta_fixed_at_1"])
+def test_a_misread_second_form_is_not_the_kernels(mutation):
+    ops = head_operands("narrow_two_a_key_head", seed=2)
+    weight = np.random.default_rng(9).standard_normal(ops["v"].shape).astype(np.float32)
+    want, want_grads = head_wanted(ops, weight, **mutation)
+    got, grads = kernel(ops, weight)
+    assert rel(got, want) > 5 * TOL, rel(got, want)
+    # dg is the slowest head's (the others keep e^-10 of a state a square and less), and heads 0 and 3 read their own key head either way
+    seen_by = [name for name in NAMES if np.any(want_grads[name]) and not ("key_head_of" in mutation and name == "g")]
+    assert min(rel(grads[name], want_grads[name]) for name in seen_by) > 5 * TOL
+
+
+def test_the_second_form_reads_q_and_k_a_key_head_and_keeps_T_and_U_alone():
+    """The operands of the two ``pallas_call``s are the layer's own arrays: q and k at the KEY heads, g and beta ``[boards, 64,
+    value heads]``; nothing repeated a value head, no decay a channel. Kept: ``T`` (a key head's two value heads side by side
+    in ONE 128-lane tile) and ``U``, 48 KB a board and value head; each kernel alone under its ``jax.jit``, its loop rolled."""
+    boards, key_heads, per, d = 8, 2, 2, 128
+    shape = lambda heads, dtype: jax.ShapeDtypeStruct((boards, SQUARES, heads), dtype)
+    args = (shape(key_heads * d, jnp.bfloat16), shape(key_heads * d, jnp.bfloat16), shape(key_heads * per * d, jnp.bfloat16),
+            shape(key_heads * per, jnp.float32), shape(key_heads * per, jnp.float32))
+    calls = list(_kernel_calls(jax.make_jaxpr(lambda *a: jax.vjp(lambda *b: board_delta(*b, False), *a)[1](a[2]))(*args).jaxpr))
+    assert [jitted.params["name"] for jitted, _ in calls] == ["_forward_call", "_gradient_call"]
+    (_, forward), (_, gradient) = calls
+    assert [call.params["name"] for _, call in calls] == ["board_delta", "board_delta_grad"]
+    assert [v.aval.shape for v in forward.invars] == [a.shape for a in args]
+    assert [(v.aval.shape, v.aval.dtype) for v in forward.outvars] == [
+        (args[2].shape, jnp.bfloat16), ((boards, SQUARES, key_heads * 128), jnp.float32), (args[2].shape, jnp.float32)]
+    assert [v.aval.shape for v in gradient.invars] == [a.shape for a in args] + [(boards, SQUARES, key_heads * 128), args[2].shape, args[2].shape]
+    assert [v.aval.shape for v in gradient.outvars] == [a.shape for a in args]
+    for _, call in calls:
+        loops = [eqn.params for eqn in call.params["jaxpr"].eqns if eqn.primitive.name in ("scan", "while")]
+        assert [(loop["length"], loop["unroll"]) for loop in loops] == [(8, 1)]
+    with pytest.raises(ValueError, match="board_delta"):  # five value heads are no whole groups on two key heads
+        board_delta(jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 80)), jnp.zeros((2, SQUARES, 5)), jnp.zeros((2, SQUARES, 5)), True)

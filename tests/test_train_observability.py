@@ -52,6 +52,11 @@ MODEL_SCOPES = {
     # the sixth's: the mixer told by layer, the delta rule's core beside a KDA layer's scope and never inside it, the latent layer as the third's
     "kda": ("embed", "layer00.kda", "layer00.delta", "layer00.dense", "layer01.attention", "layer01.latent", "layer01.router", "layer01.dispatch",
             "layer01.experts", "layer01.combine", "layer01.shared", "final_norm", "policy_head", "value_head"),
+    # the seventh's: the delta rule's core beside a GDN layer's scope and never inside it, the gated attention layer under the first kind's
+    # scope, every layer routed, the shared expert with its token gate under its own
+    "gdn": ("embed", "layer00.gdn", "layer00.delta", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine", "layer00.shared",
+            "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "layer01.shared", "final_norm",
+            "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
@@ -67,7 +72,12 @@ CCA = TrunkConfig(hidden=32, heads=4, kv_heads=2, head_dim=8, layers=2, cca=(2, 
                   expert_width=16, value_hidden=8, held_experts=(2, 4), balance_rate=0.001)
 # the sixth block: a Kimi Delta Attention layer, then a latent layer without RoPE
 KDA = TrunkConfig(**{**LATENT.__dict__, "mixers": ("kda", "latent"), "nope_layers": (1,), "kda_heads": 2, "kda_head_dim": 16})
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA}
+# the seventh block: a Gated DeltaNet layer (two value heads a key head), then a gated attention layer with part of a head rotated
+GDN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, experts=8, experts_per_token=2, expert_width=16, value_hidden=8, gated_attention=True,
+                  rotary_dim=4, shared_width=16, route_norm=True, held_experts=(2, 4), balance_rate=0.001, mixers=("gdn", "attention"),
+                  linear_num_key_heads=1, linear_num_value_heads=2, linear_key_head_dim=16, linear_value_head_dim=16, shared_token_gate=True,
+                  zero_centered_norms=True)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN}
 
 
 def make(kind):
@@ -140,11 +150,11 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert {"forward", "backward", "optimizer"} <= phases
 
 
-@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda"])
+@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn"])
 def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
     """The scopes of the trunk's own parts in the configurations the
     fixture above does not compile, as PR 46's PARENT (3036d35) named them
-    (the sixth block's as PR 47 brought them), no more and no fewer: the
+    (the sixth block's as PR 47 brought them, the seventh's as PR 51), no more and no fewer: the
     benchmark's reducers read these names, and a lowered step's text (the
     step pins) carries none of them."""
     names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))

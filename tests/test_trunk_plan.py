@@ -37,6 +37,10 @@ KEYS_AND_INIT_SHA256 = {
     "kda": ("embed_w embed_b attn_norm wq wkv_a kv_norm wkv_b wo kda_q kda_k kda_v kda_conv kda_fa kda_fb kda_dt_bias kda_A_log kda_beta kda_ga "
             f"kda_gb kda_o_norm kda_out moe_norm router_w experts_gate experts_up experts_down {_HEADS} dense_gate dense_up dense_down shared_gate "
             "shared_up shared_down", "f59c743787cb0a103838cbb9dc8b656acf5089f5ccdc243f012bd6ec07bdad88"),
+    # the seventh block, read on the tree of the PR that brought it (PR 51): the attention's tensors, then the GDN mixer's, the token gate
+    # with the routed layer's own; its five zero-centred norms start at zero
+    "gdn": ("embed_w embed_b attn_norm wq wk wv q_norm k_norm wo gdn_qkvz gdn_ba gdn_conv gdn_dt_bias gdn_A_log gdn_o_norm gdn_out moe_norm "
+            f"router_w experts_gate experts_up experts_down shared_token_gate {_HEADS} wgate shared_gate shared_up shared_down", "4c22383c99139dcb3ef79e42f7e0a973317762d3bccd1a971f233811cda7e22b"),
 }
 
 
@@ -86,6 +90,11 @@ PLANS = {
         Sublayer("layer02", "kda", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 1, "moe_norm", 2),
         Sublayer("layer03", "latent", 0, "attn_norm", 3, False), Sublayer("layer03", "routed", 2, "moe_norm", 3),
         Sublayer("layer04", "kda", 3, "attn_norm", 4, True), Sublayer("layer04", "routed", 3, "moe_norm", 4)),
+    "gdn": (  # the mixer told by layer: GDN GDN GDN attention, each indexed from the first of its kind; every layer routed; RoPE where the attention reads it
+        Sublayer("layer00", "gdn", 0, "attn_norm", 0, True), Sublayer("layer00", "routed", 0, "moe_norm", 0),
+        Sublayer("layer01", "gdn", 1, "attn_norm", 1, True), Sublayer("layer01", "routed", 1, "moe_norm", 1),
+        Sublayer("layer02", "gdn", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 2, "moe_norm", 2),
+        Sublayer("layer03", "attention", 0, "attn_norm", 3, True), Sublayer("layer03", "routed", 3, "moe_norm", 3)),
 }
 
 

@@ -523,7 +523,7 @@ def _held_as_the_trainer_holds_it(trainer, one_chip):
 #: the bytes of each with its two moments: Nemotron's 1,856 is 14.5 lane tiles, so ``experts_up``; every other width is whole lanes.
 HELD = {"az": ("az-256x19-train", (), 0), "moe_trunk": ("lladamoe-trunk-train", (), 0), "afmoe_trunk": ("trinity-mini-trunk-train", (), 0),
         "mla_trunk": ("kanana-2-trunk-train", (), 0), "hybrid_trunk": ("nemotron-twotower-trunk-train", ("experts_up",), 3 * 4 * 3 * 8 * 2688 * 1856),
-        "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0)}
+        "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0), "gdn_trunk": ("qwen3-next-trunk-train", (), 0)}
 
 
 @pytest.mark.parametrize("family", HELD)
@@ -860,12 +860,12 @@ def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_f
     assert " = bf16[128,64,2048]" in alone and "board_delta" in alone.split(" = ")[0], alone[:200]  # one kernel, one result: no tuple
 
 
-def _delta_bodies_entered(monkeypatch):
-    """A counter laid over each kernel body of ``ops/board_delta.py`` -> how often Python entered each, so far. The pair's
+def _delta_bodies_entered(monkeypatch, bodies=("_forward_kernel", "_backward_kernel")):
+    """A counter laid over each of the kernel ``bodies`` of ``ops/board_delta.py`` -> how often Python entered each, so far. The pair's
     jitted calls forget the traces they hold (of another test of this process), so the next program traces its own."""
     from fishnet_tpu.ops import board_delta
 
-    entered = {"_forward_kernel": 0, "_backward_kernel": 0}
+    entered = dict.fromkeys(bodies, 0)
 
     def counting(name, body):
         def counted(*refs):
@@ -921,4 +921,125 @@ def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     memory = compiled.memory_analysis()
     # 4.66 + 8.51 GiB when this was written: 4.66 + 7.88 before the four forwards kept their tables (4 x 160 MiB), and the same 0.45 GiB of room
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 13.62, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert ".remat" not in text
+
+
+# -- the seventh block (qwen3_next) at its published widths: 16 key and 32 value GDN heads of 128, attention heads of 256 ---------------
+
+GDN_BOARDS = 128  # gdn_trunk_train_b128
+#: What the second form of a differentiated ``board_delta`` writes beside o at 128 boards: ``T`` float32, a key head's two value heads side by
+#: side in one 128-lane tile, and ``U`` float32 in v's columns: 48 KB a board and value head.
+GDN_KEPT = [("f32", "128,64,2048"), ("f32", "128,64,4096")]
+
+
+def _delta_pairs_operands(text: str):
+    """From a compiled module's text, the operand shapes of every ``board_delta`` / ``board_delta_grad`` custom call, by the call's name."""
+    import re
+
+    named = ((re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line), line) for line in text.splitlines())
+    lines = {name.group(1): line for name, line in named if name}
+    found = {}
+    for name, line in lines.items():
+        if 'custom_call_target="tpu_custom_call"' not in line or "board_delta" not in name:
+            continue
+        operands = [re.sub(r"/\*.*?\*/", "", operand).strip().lstrip("%") for operand in re.search(r" custom-call\((.*?)\), custom_call_target", line).group(1).split(",")]
+        found[name] = [re.search(r" = \(?((?:bf16|f32)\[[\d,]+\])", lines[operand]).group(1) if operand in lines else operand for operand in operands]
+    return found
+
+
+def test_the_second_form_of_the_delta_pair_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``board_delta`` and ``board_delta_grad`` told a decay a head, on a batch of the cell: q and k at 16 key heads, v at 32
+    value heads, g and beta ``[128, 64, 32]``: a key head of eight boards and its two value heads a grid step, the span sums
+    and the solve's float32 products at ``highest``, a board's ``[64, 32]`` blocks of dg and dbeta resident over the key heads'
+    steps. Differentiated, the forward writes beside o ``T`` and ``U`` alone and the gradient reads them as they were written."""
+    import re
+
+    from fishnet_tpu.ops.board_delta import board_delta
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    key, value, heads = (GDN_BOARDS, 64, 16 * 128), (GDN_BOARDS, 64, 32 * 128), (GDN_BOARDS, 64, 32)
+    args = (sds(key, jnp.bfloat16), sds(key, jnp.bfloat16), sds(value, jnp.bfloat16), sds(heads, jnp.float32), sds(heads, jnp.float32))
+    loss = lambda *a: jnp.sum(jnp.square(board_delta(*a, False).astype(jnp.float32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(*args).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_delta_grad" in kernel for kernel in kernels) == 1 and all("board_delta" in kernel for kernel in kernels), kernels
+    operands = _delta_pairs_operands(text)
+    inputs = ["bf16[128,64,2048]", "bf16[128,64,2048]", "bf16[128,64,4096]", "f32[128,64,32]", "f32[128,64,32]"]
+    forward, gradient = (next(v for k, v in operands.items() if ("grad" in k) == wanted) for wanted in (False, True))
+    assert forward == inputs, forward  # q and k ONCE a key head, one decay a value head: nothing repeated, nothing broadcast
+    assert gradient[:5] == inputs and len(gradient) == 8, gradient  # the five inputs, T, U and o's cotangent
+    (written,) = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "board_delta_grad" not in line.split(" = ")[0]]
+    assert re.findall(r"(bf16|f32)\[([\d,]+)\]", written.split(" custom-call(")[0])[1:] == GDN_KEPT, written[:300]
+    primal = jax.jit(lambda *a: board_delta(*a, False)).lower(*args).compile().as_text()
+    (alone,) = [line for line in primal.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert " = bf16[128,64,4096]" in alone and "board_delta" in alone.split(" = ")[0], alone[:200]  # one kernel, one result: no tuple
+
+
+def test_gated_attention_at_a_head_of_256_with_64_columns_turned_compiles_at_published_widths(one_chip, compiled_for_tpu):
+    """``value_and_grad`` of the seventh block's ``_attention``: the grouped normed form at ``head_dim`` 256, 8 query heads on each
+    of 2 key-value heads (2 boards a grid step), RoPE on the first 64 of 256 columns, and ``_gated_out`` at 4,096 columns: two
+    kernels, no scores kept, no per-head copy."""
+    import re
+
+    cfg = _gdn_trainer().cfg
+    sublayer = next(s for s in trunk.trunk_plan(cfg) if s.kind == "attention")
+    assert (sublayer.layer, sublayer.rope, cfg.head_dim, cfg.heads, cfg.kv_heads, cfg.rotary_dim) == ("layer03", True, 256, 16, 2, 64)
+    layer = _sublayer_shapes(cfg, sublayer, one_chip)
+    assert layer["wq"].shape == layer["wgate"].shape == (2048, 4096) and layer["wk"].shape == (2048, 512) and layer["q_norm"].shape == (256,)
+
+    def loss(x, p):
+        return jnp.sum(jnp.square(trunk._attention(x, p, cfg, sublayer)[0]))
+
+    x = jax.ShapeDtypeStruct((GDN_BOARDS * trunk.SQUARES, 2048), jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, layer).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_attention_grad" in name for name in kernels) == 1 and all("board_attention" in name for name in kernels), kernels
+    per_head = [line for line in text.splitlines() if re.search(r"\[128,16,64,64\]|\[128,64,16,256\]|\[128,16,64,256\]", line)]
+    assert not per_head, per_head[:2]
+
+
+def _gdn_trainer():
+    import importlib
+    import json
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "qwen3-next-trunk-train.json").read_text())
+    assert config["train"]["batch"] == GDN_BOARDS
+    return importlib.import_module("benchmark.families.gdn_trunk").make_trainer(config)
+
+
+def test_the_seventh_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu, monkeypatch):
+    """The whole step of ``gdn_trunk_train_b128`` from its configuration file: the delta pair (its second form) and the
+    convolution pair a GDN layer (three), the grouped normed attention pair at a head of 256 on the one attention layer, no
+    leaf held off row-major, nothing remade to fit. Lowering it enters each of the second form's kernel bodies ONCE and the
+    first form's never: the three layers share the trace of a jitted call, which ``setup_s`` pays at every start. The
+    operands of every delta call are the layer's own arrays: q and k at 16 key heads, g and beta ``[128, 64, 32]``."""
+    trainer = _gdn_trainer()
+    cfg = trainer.cfg
+    assert (cfg.mixers, cfg.linear_num_key_heads, cfg.linear_num_value_heads, cfg.head_dim, cfg.hidden) == (("gdn", "gdn", "gdn", "attention"), 16, 32, 256, 2048)
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == 346_814_094  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((GDN_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((GDN_BOARDS, 4672)), "value_target": jnp.zeros((GDN_BOARDS,))}
+    entered = _delta_bodies_entered(monkeypatch, ("_head_forward_kernel", "_head_backward_kernel", "_forward_kernel", "_backward_kernel"))
+    lowered = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch))
+    assert entered == {"_head_forward_kernel": 1, "_head_backward_kernel": 1, "_forward_kernel": 0, "_backward_kernel": 0}, entered
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)
+    names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("board_delta_grad" in n for n in names) == 3 and sum("board_delta" in n for n in names) == 6, names
+    assert sum("mamba_conv_grad" in n for n in names) == 3 and sum("mamba_conv" in n for n in names) == 6
+    assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):  # the core's scope beside the mixer's; the shared expert under its own
+        assert all(f"{phase}/layer0{i}.delta/" in text and f"{phase}/layer0{i}.gdn/" in text for i in (0, 1, 2)) and f"{phase}/layer03.delta/" not in text
+        assert f"{phase}/layer03.attention/" in text and all(f"{phase}/layer0{i}.shared/" in text for i in range(4))
+    assert not _xla_passes_over_slots(text, GDN_BOARDS * trunk.SQUARES * cfg.experts_per_token)
+    inputs = ["bf16[128,64,2048]", "bf16[128,64,2048]", "bf16[128,64,4096]", "f32[128,64,32]", "f32[128,64,32]"]
+    operands = _delta_pairs_operands(text)
+    assert len(operands) == 6 and all(shapes[:5] == inputs for shapes in operands.values()), operands
+    memory = compiled.memory_analysis()
+    # 3.88 + 9.75 GiB when this was written, of the chip's 15.75: a GDN layer keeps the convolution's float32 operand, z, the three
+    # bfloat16 results, ``T`` and ``U`` (0.9 GiB at 8,192 tokens)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 14.0, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert ".remat" not in text

@@ -153,3 +153,20 @@ def board_batch(seed: int, n: int = BATCH):
 #: CCA_STEP_SHA256``): the tiny configuration and the batch its pin lowers (``tools/step_text.py``).
 BLOCKS = {"llada": (TINY, batch_of), "afmoe": (AFMOE, batch_of), "mla": (MLA, batch_of), "hybrid": (HYBRID, batch_of), "cca": (CCA, board_batch),
           "kda": (KDA, batch_of)}
+
+
+# -- the seventh block (qwen3_next): three Gated DeltaNet layers (two value heads a key head) to one gated attention layer with part of a
+# -- head rotated, a gated shared expert, zero-centred norms; ``GDN_MODEL`` is the same net as the benchmark's reference reads it
+# -- (benchmark/reference/gdn_trunk.py) ---------------------------------------------------------------------------------------------
+
+GDN_MODEL = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "rotary_dim": 4,
+             "linear_num_key_heads": 2, "linear_num_value_heads": 4, "linear_key_head_dim": 32, "linear_value_head_dim": 32, "linear_conv_kernel_dim": 4,
+             "mixers": ["gdn", "gdn", "gdn", "attention"], "num_hidden_layers": 4, "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+             "num_experts": 8, "num_routed_experts": 16, "first_held_expert": 4, "num_experts_per_tok": 3, "load_balance_coeff": 0.001,
+             "rope_theta": 10000000, "rms_norm_eps": 1e-06, "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
+GDN_CONFIG = {"model": GDN_MODEL, "train": {"value_weight": 1.0}}
+GDN = TrunkConfig(hidden=64, heads=4, kv_heads=2, head_dim=16, experts=16, experts_per_token=3, expert_width=32, rope_theta=1e7, rms_eps=1e-6,
+                  value_hidden=32, gated_attention=True, rotary_dim=4, shared_width=32, router_score="softmax", route_norm=True, held_experts=(4, 8),
+                  balance_rate=0.001, mixers=("gdn", "gdn", "gdn", "attention"), linear_num_key_heads=2, linear_num_value_heads=4,
+                  linear_key_head_dim=32, linear_value_head_dim=32, shared_token_gate=True, zero_centered_norms=True)
+BLOCKS["gdn"] = (GDN, batch_of)
