@@ -35,7 +35,8 @@ matrix; any ``r`` between the two serves, as long as both sides read the
 same, so its precision is nobody's concern. The solve is the same cut
 upside down: ``T = (I + A)^-1`` is built from 1 x 1 blocks (1) by ``T <- T
 - T A_p T``, ``A_p`` the level's part of ``A``: block forward substitution
-as six pairs of 64 x 64 float32 products, no loop over rows. The decays,
+as five pairs of float32 products after a level 0 that needs none (``I -
+A_0``), no loop over rows. The decays,
 the cumulative sum and the solve are float32 (products at ``highest``); the
 level products, ``Mq U`` and their transposes take bfloat16 operands and
 accumulate in float32.
@@ -47,11 +48,29 @@ bfloat16. A grid step is one head of a few boards; no ``[.., heads, d]``
 view and no state reaches HBM, and called without a gradient the kernel
 writes o alone.
 
-**The solve is made once.** The chunk form is three parts: the norms and
-``c`` (``_normed``: cheap, no chain), the two score tables (``_tables``)
-and the solve with ``U`` (``_solve``: twelve dependent ``[64, 64]`` float32
-products at ``highest``, each waiting for the one before, which is what the
-kernel's time is made of, not its bytes). The forward walks all three. When
+**The solve is made once, two chains a product.** The chunk form is three
+parts: the norms and ``c`` (``_normed``: cheap, no chain), the two score
+tables (``_tables``) and the solve with ``U`` (``_solve``: a chain of
+dependent float32 products at ``highest``, each waiting for the one before,
+which is what the kernel's time is made of, not its bytes; a round trip
+through the array costs the same for ``[64, 128] x [128, 128]`` as for ``[64,
+64] x [64, 64]``, a quarter of it, and Mosaic overlaps no two chains by
+itself). So ``_solve`` takes ONE chain or a PACKED PAIR of independent chains
+``a``, ``b`` side by side, ``P = [T_a | T_b]`` float32 ``[64, 128]`` (8 whole
+vregs), and a level is ``P <- P - (P bd(A_p)) bd(P)`` with ``bd(X) = [[X_a,
+0], [0, X_b]]`` ``[128, 128]`` (the packed value laid twice along the rows
+under a select), ``U`` of both ``bd(P) [beta V_a ; beta V_b]``. **Level 0 has
+no product**: before it ``T`` is the identity, so its ``T - (T A_0) T`` is ``I
+- A_0`` and is written so; the loop of products starts at level 1. Ten
+dependent round trips and ``U``'s for two chains where the six-level single
+chain made twenty-four and two, at the same precision; the zero blocks add
+exact zeros and ``I A_0 I`` at ``highest`` is ``A_0``, so every ``T``, ``U``
+and ``o`` is the six-level single chain's bit for bit (on the chip and under
+XLA:CPU; PERF.md section 6, PR 53). Which two chains is told by shapes alone:
+the first form pairs boards ``2 t``, ``2 t + 1`` of a grid step's block (a
+loop turn is a pair; an odd block, ``gcd(boards, 8)`` 1, runs one board a
+turn), the second form a key head's value heads two by two (an odd one left
+over runs the single chain). The forward walks all three parts. When
 ``board_delta`` is differentiated its forward rule calls the SAME kernel
 body told to write, beside o, what the gradient needs of the form, and
 hands it on as residuals with the five inputs (a board and head, at the
@@ -101,9 +120,13 @@ sums), ``L = exp(D)`` for ``t >= j`` has no exponent over 0, and::
 are each ONE bfloat16 product under it, made once a KEY head for all of its
 value heads. A grid step is one key head of a few boards and its value heads:
 q and k are read once a key head, v, g and beta once a value head, nothing is
-repeated or broadcast in HBM. The solve and ``Mq U`` are the first form's
-(``_solve``). The differentiated forward keeps ``T`` (a key head's value heads
-side by side in one 128-lane tile) and ``U``; the gradient kernel makes the two
+repeated or broadcast in HBM. The solve is the first form's (``_solve``), in
+the forward a PAIR of value heads a chain: the two undecayed tables come twice
+along the lanes from one product each (k's rows laid twice), the pair's spans
+are ONE product (the triangle times ``[g_a masked | g_b masked]``), ``L``,
+``Mk`` and ``Mq`` are ``[64, 128]`` values, ``Mq U`` one product on ``bd(Mq)``,
+and the packed ``T`` IS the kept tile. The differentiated forward keeps ``T`` (a
+key head's value heads side by side in one 128-lane tile) and ``U``; the gradient kernel makes the two
 products and ``L`` again (they are no chain) and, beside the first form's
 gradients of the solve, ``dD = dMq * Mq + dMk * Mk``, ``dg`` from it by the
 triangle's transpose, ``dq^``, ``dk^`` from ``sum over the value heads of dM * L``
@@ -159,10 +182,15 @@ def _squares():
     return iota((SQUARES, SQUARES), 0), iota((SQUARES, SQUARES), 1), iota((SQUARES, 1), 0)
 
 
+def _pairs(p: int, t: jax.Array, j: jax.Array) -> jax.Array:
+    """Level ``p``'s pairs of the squares ``t``, ``j``: t in the upper, j in the lower half of one block of ``2^(p+1)`` squares."""
+    return (jnp.right_shift(jnp.bitwise_xor(t, j), p) == 1) & (t > j)
+
+
 def _level(p: int, t: jax.Array, j: jax.Array, row: jax.Array):
-    """Level ``p``'s pairs ``[64, 64]`` (t in the upper, j in the lower half of one block of ``2^(p+1)`` squares), which rows
-    are an upper half's ``[64, 1]``, and the 0/1 matrix that gives every row its block's middle row."""
-    pairs = (jnp.right_shift(jnp.bitwise_xor(t, j), p) == 1) & (t > j)
+    """Level ``p``'s pairs ``[64, 64]``, which rows are an upper half's ``[64, 1]``, and the 0/1 matrix that gives every row
+    its block's middle row."""
+    pairs = _pairs(p, t, j)
     middle = jnp.left_shift(jnp.right_shift(t, p + 1), p + 1) + (1 << p)
     return pairs, (jnp.right_shift(row, p) & 1) == 1, (j == middle).astype(jnp.float32)
 
@@ -203,16 +231,46 @@ def _tables(qn: jax.Array, kn: jax.Array, c: jax.Array) -> Tuple[jax.Array, jax.
     return mq, mk
 
 
-def _solve(mk: jax.Array, v: jax.Array, beta: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """``T = (I + Diag(beta) Mk)^-1`` ``[64, 64]`` and ``U = T (beta V)`` ``[64, d]``, float32: the chain of twelve dependent
-    products, walked by the forward kernel alone."""
-    t, j, row = _squares()
-    a = beta * mk
-    tm = (t == j).astype(jnp.float32)
-    for p in range(_LEVELS):  # blocks of 1, 2, .. 32 joined two by two: [[T1, 0], [-T2 A21 T1, T2]]
-        ap = jnp.where(_level(p, t, j, row)[0], a, 0.0)
-        tm = tm - _exact(_exact(tm, ap), tm)
-    return tm, _exact(tm, beta * v)
+def _chain_squares(chains: int, rows: int = SQUARES):
+    """``_squares`` for ``chains`` tables side by side on the lanes (a PACKED PAIR: two on 128): the square of a row and of a
+    lane WITHIN its own chain's table, of ``[rows, chains * 64]`` (``rows`` 64: the tables; 128: a pair's block diagonal), and
+    which lanes are the first chain's."""
+    shape = (rows, chains * SQUARES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) & (SQUARES - 1), lane & (SQUARES - 1), lane < SQUARES
+
+
+def _block_diagonal(x: jax.Array) -> jax.Array:
+    """A packed pair ``[X_a | X_b]`` ``[64, 128]`` -> ``[[X_a, 0], [0, X_b]]`` ``[128, 128]``: laid twice along the rows under a
+    select. One chain's ``[64, 64]`` is its own."""
+    if x.shape[-1] == SQUARES:
+        return x
+    twice = jnp.concatenate([x, x], axis=0)
+    row, lane = (jax.lax.broadcasted_iota(jnp.int32, twice.shape, axis) for axis in (0, 1))
+    return jnp.where((row < SQUARES) == (lane < SQUARES), twice, 0.0)
+
+
+def _side_by_side(x: jax.Array) -> jax.Array:
+    """A packed pair's rows ``[X_a ; X_b]`` ``[128, d]`` -> ``[X_a | X_b]`` ``[64, 2 d]``, whole 128-lane tiles moved (one chain's
+    ``[64, d]``: itself)."""
+    return jnp.concatenate([x[at:at + SQUARES] for at in range(0, x.shape[0], SQUARES)], axis=1)
+
+
+def _solve(a: jax.Array, bv: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``a = Diag(beta) Mk`` and ``bv = beta V`` -> ``T = (I + a)^-1`` and ``U = T bv``, float32: the chain of ten dependent
+    products and ``U``'s, walked by the forward kernels alone. Told by ``a``'s shape: ONE chain (``a`` ``[64, 64]``, ``bv`` ``[64, d]`` -> ``T``
+    ``[64, 64]``, ``U`` ``[64, d]``) or a PACKED PAIR of independent chains (``a = [a_a | a_b]`` ``[64, 128]``, ``bv = [bv_a ; bv_b]``
+    ``[128, d]`` -> ``[T_a | T_b]`` ``[64, 128]``, ``[U_a ; U_b]`` ``[128, d]``): every product of the pair is one ``[., 128] x [128, .]``
+    with the right operand block diagonal, whose zero blocks add exact zeros to what each chain's own product sums."""
+    chains = a.shape[-1] // SQUARES
+    t, j, _ = _chain_squares(chains)
+    tm = (t == j).astype(jnp.float32) - jnp.where(_pairs(0, t, j), a, 0.0)  # level 0 of I, or of [I | I]: I - (I A_0) I is I - A_0, no product
+    a = _block_diagonal(a)
+    t, j, _ = _chain_squares(chains, chains * SQUARES)
+    for p in range(1, _LEVELS):  # blocks of 2, 4, .. 32 joined two by two: [[T1, 0], [-T2 A21 T1, T2]]
+        ap = jnp.where(_pairs(p, t, j), a, 0.0)
+        tm = tm - _exact(_exact(tm, ap), _block_diagonal(tm))
+    return tm, _exact(_block_diagonal(tm), bv)
 
 
 def _own_lane(ref, i, h) -> Tuple[jax.Array, jax.Array]:
@@ -223,23 +281,31 @@ def _own_lane(ref, i, h) -> Tuple[jax.Array, jax.Array]:
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
-    """``kept_refs`` is empty (the primal) or the differentiated form's three further outputs (``_kept``)."""
+    """``kept_refs`` is empty (the primal) or the differentiated form's three further outputs (``_kept``). A loop turn solves
+    boards ``2 turn`` and ``2 turn + 1`` of the block as a packed pair (``_solve``), or ONE board where the block is odd."""
     f32, h = jnp.float32, pl.program_id(1)
+    together = 2 - q_ref.shape[0] % 2  # boards a turn
 
-    def board(i, carry):
+    def made(i):  # board i's two tables, its ``Diag(beta) Mk`` and its ``beta V``
         beta, _ = _own_lane(beta_ref, i, h)
         qn, kn, _, _, c, _ = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
         mq, mk = _tables(qn, kn, c)
-        tm, u = _solve(mk, v_ref[i].astype(f32), beta)
-        o_ref[i] = _dot(mq, u).astype(o_ref.dtype)
-        if kept_refs:
-            solve_ref, u_ref, mq_ref = kept_refs
-            solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:] = tm, mk
-            u_ref[i] = u
-            mq_ref[i, :, :SQUARES] = mq.astype(mq_ref.dtype)
+        return mq, mk, beta * mk, beta * v_ref[i].astype(f32)
+
+    def turn(n, carry):
+        boards = [n * together + e for e in range(together)]
+        tables = [made(i) for i in boards]
+        tm, u = _solve(jnp.concatenate([a for _, _, a, _ in tables], axis=1), jnp.concatenate([bv for _, _, _, bv in tables], axis=0))
+        for i, (mq, mk, _, _), at in zip(boards, tables, range(0, together * SQUARES, SQUARES)):
+            o_ref[i] = _dot(mq, u[at:at + SQUARES]).astype(o_ref.dtype)
+            if kept_refs:
+                solve_ref, u_ref, mq_ref = kept_refs
+                solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:] = tm[:, at:at + SQUARES], mk
+                u_ref[i] = u[at:at + SQUARES]
+                mq_ref[i, :, :SQUARES] = mq.astype(mq_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+    jax.lax.fori_loop(0, q_ref.shape[0] // together, turn, 0)
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
@@ -288,33 +354,45 @@ def _head_decay(g: jax.Array, t: jax.Array, j: jax.Array) -> jax.Array:
     return jnp.exp(jnp.where(t >= j, spans, _NEVER))
 
 
-def _key_head(q_ref, k_ref, i):
-    """What a key head's value heads share, of board ``i``: ``_unit``'s results, the scale, and the two undecayed tables."""
+def _key_head(q_ref, k_ref, i, side_by_side: int = 1):
+    """What a key head's value heads share, of board ``i``: ``_unit``'s results, the scale, and the two undecayed tables
+    (``side_by_side`` 2: each table twice along the lanes, ``[64, 128]``, for a packed pair of value heads: ONE product on k's
+    rows laid twice)."""
     scale = 1.0 / math.sqrt(q_ref.shape[-1])
     (qn, rq), (kn, rk) = _unit(q_ref[i].astype(jnp.float32)), _unit(k_ref[i].astype(jnp.float32))
     qn = qn * scale
-    return qn, kn, rq, rk, scale, _dot(qn, kn, _NT), _dot(kn, kn, _NT)
+    columns = kn if side_by_side == 1 else jnp.concatenate([kn] * side_by_side, axis=0)
+    return qn, kn, rq, rk, scale, _dot(qn, columns, _NT), _dot(kn, columns, _NT)
 
 
 def _head_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
-    """``kept_refs`` is empty (the primal) or the differentiated form's two further outputs (``_head_kept``)."""
+    """``kept_refs`` is empty (the primal) or the differentiated form's two further outputs (``_head_kept``). A key head's value
+    heads are solved two by two as packed pairs (``_solve``): their decays' spans are one product, their ``T`` is the kept tile
+    as it stands; an odd value head left over is ONE chain, on the tables' first 64 lanes."""
     f32, key_head = jnp.float32, pl.program_id(1)
     d = q_ref.shape[-1]
     per = v_ref.shape[-1] // d  # value heads a key head
 
     def board(i, carry):
         t, j, _ = _squares()
-        _, _, _, _, _, qk, kk = _key_head(q_ref, k_ref, i)
-        for s in range(per):
-            h, columns = key_head * per + s, slice(s * d, (s + 1) * d)
-            decay = _head_decay(_own_lane(g_ref, i, h)[0], t, j)
-            beta, _ = _own_lane(beta_ref, i, h)
-            tm, u = _solve(jnp.where(t > j, kk * decay, 0.0), v_ref[i, :, columns].astype(f32), beta)
-            o_ref[i, :, columns] = _dot(qk * decay, u).astype(o_ref.dtype)
+        _, _, _, _, _, qk, kk = _key_head(q_ref, k_ref, i, min(per, 2))
+        for s in range(0, per, 2):
+            chains = min(2, per - s)  # value heads s and s + 1
+            tp, jp, first = _chain_squares(chains)
+            on_lanes = lambda ref: [_own_lane(ref, i, key_head * per + s + e)[0] for e in range(chains)]
+            by_chain = lambda columns: jnp.where(first, *columns) if chains == 2 else columns[0]
+            g, beta = on_lanes(g_ref), on_lanes(beta_ref)
+            spans = _exact((t >= j).astype(f32), jnp.where(tp > jp, by_chain(g), 0.0))  # ``_head_decay`` of both
+            decay = jnp.exp(jnp.where(tp >= jp, spans, _NEVER))
+            width, columns = chains * SQUARES, slice(s * d, (s + chains) * d)
+            a = by_chain(beta) * jnp.where(tp > jp, kk[:, :width] * decay, 0.0)
+            bv = [beta[e] * v_ref[i, :, (s + e) * d:(s + e + 1) * d].astype(f32) for e in range(chains)]
+            tm, u = _solve(a, jnp.concatenate(bv, axis=0))
+            o_ref[i, :, columns] = _side_by_side(_dot(_block_diagonal(qk[:, :width] * decay), u)).astype(o_ref.dtype)
             if kept_refs:
                 solve_ref, u_ref = kept_refs
-                solve_ref[i, :, s * SQUARES:(s + 1) * SQUARES] = tm
-                u_ref[i, :, columns] = u
+                solve_ref[i, :, s * SQUARES:s * SQUARES + width] = tm
+                u_ref[i, :, columns] = _side_by_side(u)
         return carry
 
     jax.lax.fori_loop(0, q_ref.shape[0], board, 0)

@@ -4,9 +4,16 @@ every gradient; a board's state starts from zero and no square sees a later
 one; decays so fast that ``exp(-c)`` would overflow float32 stay finite and
 right; and two mutations of the recurrence, each a plausible misreading of
 the layer, read far outside the tolerance. The differentiated forward keeps
-``T``, ``U`` and the two score tables, bit for bit the parent's ``_chunk``
-(written once below as the oracle); the gradient kernel reads them and
-makes no solve; the primal writes ``o`` alone."""
+``T``, ``U`` and the two score tables, bit for bit the chunk form written
+once below as the oracle; the gradient kernel reads them and makes no
+solve; the primal writes ``o`` alone. The forward solves two chains a
+product (two boards of a head, or a key head's two value heads, side by
+side on 128 lanes): the packed solve is each chain's own, bit for bit, and
+level 0 of the solve, written without its two products, the parent's.
+``tools/delta_alone.py`` (the pair timed alone) runs at a tiny shape."""
+
+import json
+import math
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +22,7 @@ import pytest
 
 from fishnet_tpu.ops import board_delta as kernels
 from fishnet_tpu.ops.board_delta import L2_EPS, board_delta
+from tools import delta_alone
 
 SQUARES = 64
 #: (boards, heads, d): the published head on two boards, narrow heads on a block of boards and a remainder, one head.
@@ -155,6 +163,17 @@ def test_shapes_that_are_not_heads_of_a_board_are_refused():
 # -- what the differentiated forward keeps, and what the gradient reads ---------------------------------------------------------------
 
 
+def parent_solve(mk, v, beta):
+    """``_solve`` as the parent commit (be60ccd) had it, copied: ONE chain, six levels of two products each, level 0 among them."""
+    t, j, row = kernels._squares()
+    a = beta * mk
+    tm = (t == j).astype(jnp.float32)
+    for p in range(6):  # blocks of 1, 2, .. 32 joined two by two: [[T1, 0], [-T2 A21 T1, T2]]
+        ap = jnp.where(kernels._level(p, t, j, row)[0], a, 0.0)
+        tm = tm - kernels._exact(kernels._exact(tm, ap), tm)
+    return tm, kernels._exact(tm, beta * v)
+
+
 def chunk_oracle(q, k, v, g, beta):
     """The chunk form of one head of one board as the gradient kernel made it for itself before the forward kept it
     (``_chunk`` of the parent commit, its arithmetic written once more here in plain ``jax.numpy`` over the module's
@@ -171,12 +190,7 @@ def chunk_oracle(q, k, v, g, beta):
         kr = kn * lower
         mq = mq + jnp.where(pairs, kernels._dot(qn * upper, kr, kernels._NT), 0.0)
         mk = mk + jnp.where(pairs, kernels._dot(kn * upper, kr, kernels._NT), 0.0)
-    a = beta * mk
-    tm = (t == j).astype(f32)
-    for p in range(6):
-        ap = jnp.where(kernels._level(p, t, j, row)[0], a, 0.0)
-        tm = tm - kernels._exact(kernels._exact(tm, ap), tm)
-    return tm, kernels._exact(tm, beta * v), mk, mq
+    return *parent_solve(mk, v, beta), mk, mq
 
 
 #: LLVM's optimizations off, for a compile whose result is compared bit for bit with another program's (``exactly``).
@@ -249,6 +263,77 @@ def test_the_gradient_from_the_kept_tables_is_the_gradient_from_tables_made_agai
     assert not np.array_equal(np.asarray(other[2], np.float32), np.asarray(grads["v"], np.float32))  # dv = beta T^T dU
 
 
+def _two_boards_of_a_head():
+    """``Diag(beta) Mk`` and ``beta V`` of head 0 of the published case's two boards, as the first form's forward makes them."""
+    ops = operands("published", seed=6)
+    _, heads, d = CASES["published"]
+    chains = []
+    for b in range(2):
+        q, k, v, g = (ops[name][b, :, :d].astype(jnp.float32) for name in ("q", "k", "v", "g"))
+        qn, kn, _, _, c, _ = kernels._normed(q, k, g)
+        beta = ops["beta"][b, :, :1]
+        chains.append((beta * kernels._tables(qn, kn, c)[1], beta * v))
+    return chains
+
+
+def _two_value_heads_of_a_key_head():
+    """The same of the two value heads on the one key head of the second form's published case, board 0."""
+    ops = head_operands("published_two_a_key_head", seed=6, fastest=0.5)  # rates under 0.5: both heads remember, Mk is no rounding
+    d = ops["q"].shape[-1]
+    t, j, _ = kernels._squares()
+    kn, _ = kernels._unit(ops["k"][0].astype(jnp.float32))
+    kk = kernels._dot(kn, kn, kernels._NT)
+    chains = []
+    for h in range(2):
+        beta = ops["beta"][0, :, h:h + 1]
+        mk = jnp.where(t > j, kk * kernels._head_decay(ops["g"][0, :, h:h + 1], t, j), 0.0)
+        chains.append((beta * mk, beta * ops["v"][0, :, h * d:(h + 1) * d].astype(jnp.float32)))
+    return chains
+
+
+@pytest.mark.parametrize("without", [None, 0, 1], ids=["both_chains", "the_first_chain_s_Mk_zero", "the_second_chain_s_Mk_zero"])
+@pytest.mark.parametrize("chains", [_two_boards_of_a_head, _two_value_heads_of_a_key_head], ids=["two_boards_of_a_head", "two_value_heads_of_a_key_head"])
+def test_the_packed_solve_of_two_chains_is_each_chains_own_solve_bit_for_bit(chains, without):
+    """``_solve`` handed two chains side by side gives ``[T_a | T_b]`` and ``[U_a ; U_b]``, each the single ``_solve``'s of its
+    chain to the last bit (``exactly``: a 128-deep sum whose other 64 terms are exact zeros is the 64-deep sum, on XLA:CPU as
+    on the chip's array). A chain whose ``Mk`` is zero keeps ``T = I`` and ``U = beta
+    V`` beside one that does not: a block diagonal that leaked a block would show there."""
+    made = [(jnp.zeros_like(a) if n == without else a, bv) for n, (a, bv) in enumerate(chains())]
+    packed = jnp.concatenate([a for a, _ in made], axis=1), jnp.concatenate([bv for _, bv in made], axis=0)
+    assert packed[0].shape == (SQUARES, 2 * SQUARES) and packed[1].shape == (2 * SQUARES, 128)
+    tm, u = (np.asarray(x) for x in exactly(kernels._solve, *packed))
+    for n, (a, bv) in enumerate(made):
+        own_tm, own_u = (np.asarray(x) for x in exactly(kernels._solve, a, bv))
+        at = slice(n * SQUARES, (n + 1) * SQUARES)
+        assert np.array_equal(tm[:, at], own_tm) and np.array_equal(u[at], own_u), n
+        assert np.array_equal(np.triu(own_tm, 1), np.zeros_like(own_tm)) and (np.diagonal(own_tm) == 1.0).all()
+        if n == without:
+            assert np.array_equal(own_tm, np.eye(SQUARES, dtype=np.float32)) and np.array_equal(own_u, np.asarray(bv))
+        else:
+            assert np.abs(own_tm - np.eye(SQUARES)).max() > 1e-3  # a chain that is there
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["one_chain", "a_packed_pair"])
+@pytest.mark.parametrize("chains", [_two_boards_of_a_head, _two_value_heads_of_a_key_head], ids=["two_boards_of_a_head", "two_value_heads_of_a_key_head"])
+def test_level_0_in_closed_form_is_the_six_level_product_form_bit_for_bit(chains, packed):
+    """Before level 0 ``T`` is the identity, so the level's ``T - (T A_0) T`` is ``I - A_0`` and ``_solve`` writes it so, without
+    the two products: five levels of products where the parent had six, and every ``T`` and ``U`` the parent's to the last bit
+    (``I A_0 I`` at ``highest`` is ``A_0``, exactly), of one chain and of each chain of a packed pair."""
+    made = chains()
+    ones = jnp.ones((SQUARES, 1), jnp.float32)  # the chains come with beta folded in: the parent's ``beta * mk`` of them under beta 1 is themselves
+    wanted = [tuple(np.asarray(x) for x in exactly(parent_solve, a, bv, ones)) for a, bv in made]
+    if packed:
+        tm, u = (np.asarray(x) for x in exactly(kernels._solve, jnp.concatenate([a for a, _ in made], axis=1), jnp.concatenate([bv for _, bv in made], axis=0)))
+        got = [(tm[:, n * SQUARES:(n + 1) * SQUARES], u[n * SQUARES:(n + 1) * SQUARES]) for n in range(2)]
+    else:
+        got = [tuple(np.asarray(x) for x in exactly(kernels._solve, a, bv)) for a, bv in made]
+    for (tm, u), (parent_tm, parent_u) in zip(got, wanted):
+        assert np.array_equal(tm, parent_tm) and np.array_equal(u, parent_u)
+        assert np.abs(np.diagonal(tm, -1)).max() > 1e-3  # level 0's pairs are there: the closed form is no identity
+    closed = jax.make_jaxpr(kernels._solve)(*made[0])
+    assert sum(eqn.primitive.name == "dot_general" for eqn in closed.eqns) == 2 * 5 + 1  # five levels of two, and U
+
+
 def _kernel_calls(jaxpr, jitted=None):
     """Every ``pallas_call`` equation of a jaxpr and of the jaxprs its equations hold, each with the ``jax.jit`` equation
     nearest around it (None for a bare call)."""
@@ -259,23 +344,27 @@ def _kernel_calls(jaxpr, jitted=None):
             yield from _kernel_calls(held, eqn if eqn.primitive.name in ("pjit", "jit") else jitted)
 
 
+@pytest.mark.parametrize("boards", [2, 8, 9], ids=["a_block_of_2", "a_block_of_8", "nine_blocks_of_1"])
 @pytest.mark.parametrize("differentiated", [False, True], ids=["primal", "differentiated"])
-def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_arrays(differentiated):
+def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_arrays(differentiated, boards):
     """No gradient asked: one kernel with one output. Differentiated: the forward kernel's four outputs (o, ``[T | Mk]``,
     ``U``, ``Mq``: 80 KB a board and head at their padded size) and a gradient kernel that reads the five inputs, the
     three kept arrays and o's cotangent. Off the interpreter each kernel sits alone under its own ``jax.jit``, whose results
     and arguments are the kernel's own: what the forward wrote is what the gradient reads, nothing between. A kernel's body
-    holds its loop over a grid step's boards rolled (start-up pays for every copy of a body that Mosaic lowers)."""
-    boards, heads, d = CASES["published"]
-    ops = operands("published")
+    holds its loop over a grid step's boards rolled (start-up pays for every copy of a body that Mosaic lowers): the forward's
+    a PAIR of boards a turn where the block is even (two chains a product), one board where it is odd; the gradient's a board."""
+    _, heads, d = CASES["published"]
+    ops = {name: jax.ShapeDtypeStruct((boards, *value.shape[1:]), value.dtype) for name, value in operands("published").items()}
     args = tuple(ops[name] for name in NAMES)
+    block = math.gcd(boards, 8)
     fn = (lambda *a: jax.vjp(lambda *b: board_delta(*b, False), *a)[1](a[0])) if differentiated else (lambda *a: board_delta(*a, False))
     calls = list(_kernel_calls(jax.make_jaxpr(fn)(*args).jaxpr))
     assert [jitted.params["name"] for jitted, _ in calls] == (["_forward_call", "_gradient_call"] if differentiated else ["_forward_call"])
     for jitted, call in calls:  # a jitted call is its kernel and casts that change nothing: results and kept operands pass straight through
         assert [id(v) for v in jitted.params["jaxpr"].jaxpr.outvars] == [id(v) for v in call.outvars]
         loops = [eqn.params for eqn in call.params["jaxpr"].eqns if eqn.primitive.name in ("scan", "while")]
-        assert [(loop["length"], loop["unroll"]) for loop in loops] == [(boards, 1)]  # ONE board's body in the kernel: unrolled, every start lowers it a board
+        turns = block // 2 if call.params["name"] == "board_delta" and block % 2 == 0 else block
+        assert [(loop["length"], loop["unroll"]) for loop in loops] == [(turns, 1)]  # ONE turn's body in the kernel: unrolled, every start lowers it a board
     names = [call.params["name"] for _, call in calls]
     if not differentiated:
         assert names == ["board_delta"] and [v.aval.shape for v in calls[0][1].outvars] == [ops["q"].shape]
@@ -399,3 +488,21 @@ def test_the_second_form_reads_q_and_k_a_key_head_and_keeps_T_and_U_alone():
         assert [(loop["length"], loop["unroll"]) for loop in loops] == [(8, 1)]
     with pytest.raises(ValueError, match="board_delta"):  # five value heads are no whole groups on two key heads
         board_delta(jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 80)), jnp.zeros((2, SQUARES, 5)), jnp.zeros((2, SQUARES, 5)), True)
+
+
+# -- tools/delta_alone.py: the pair timed alone ----------------------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("form,shape", [("gdn", ["--heads", "1", "--per", "2"]), ("kda", ["--heads", "2"])])
+def test_delta_alone_prints_its_three_times_and_what_it_ran_on(form, shape, capsys):
+    """The tool as a builder runs it on the chip, here at a tiny shape under the interpreter (the times are the interpreter's and say
+    nothing of a device: ``interpret`` and ``device`` say so in the line): its three programs' times, and against its own tree's file
+    every array equal bit for bit."""
+    assert delta_alone.main(["--form", form, "--boards", "2", "--d", "16", "--calls", "2", "--seed", "1", *shape, "--against", kernels.__file__]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["interpret"] is True and line["device"] == jax.devices()[0].device_kind and line["form"] == form and line["finite"] is True
+    for times in (line, line["against"]):
+        for program in delta_alone.PROGRAMS:
+            assert 0.0 < times[program]["min"] <= times[program]["median"] <= times[program]["max"]
+    kept = ["kept0", "kept1"] + (["kept2"] if form == "kda" else [])
+    assert sorted(line["bit_equal"]) == sorted(["o", "o_kept", *kept, *delta_alone.GRADIENTS]) and all(same is True for same in line["bit_equal"].values())

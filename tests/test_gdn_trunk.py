@@ -32,7 +32,7 @@ from trunk_tiny import BATCH, BLOCKS, CANCELLING, GDN, GDN_CONFIG, GDN_MODEL, GR
 
 from benchmark.families import gdn_trunk as gdn_family  # noqa: E402
 from benchmark.reference import gdn_trunk as gdn_reference  # noqa: E402
-from tools.step_text import HOW_TO_SEE_WHAT_MOVED, lowered_step_text  # noqa: E402
+from tools.step_text import HOW_TO_SEE_WHAT_MOVED, lowered_step_text, without_ids  # noqa: E402
 
 
 def gdn_params(seed: int, model=GDN_MODEL):
@@ -169,10 +169,14 @@ def test_the_expert_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_
 # -- the step pins: the seventh block's own, and the sixth's, whose kernel pair shares ``ops/board_delta.py`` with it -------------------
 
 #: sha256 of the tiny lowered step programs (``tools/step_text.py --block gdn|kda``), as ``tests/test_hybrid_trunk.py
-#: PARENT_STEP_SHA256`` holds the four older blocks'. ``kda``: read on PR 51's PARENT (eb56762) and on PR 51's tree with this jax, and
-#: the same on both: the second form of the delta pair is a branch of the jitted calls that a decay a channel does not take.
-#: ``gdn``: read on PR 51's tree, the PR that brought the block. A PR that means to change either reads its own parent the same way.
-GDN_STEP_SHA256 = {"kda": "bbe2e7095d108291b51f0291d33642a4066eb3a5c5405d77827b6553d3f96eb3", "gdn": "15ad7e3f40b07f6c71e6707620b261b238b195ec641ff5bafed048de43a1f927"}
+#: PARENT_STEP_SHA256`` holds the four older blocks'. Both read on PR 53's tree, which MEANT to move both: the forward kernels solve two
+#: chains a product and level 0 without one (``ops/board_delta.py _solve``). Its parent (be60ccd) read ``kda`` bbe2e709... and ``gdn``
+#: 15ad7e3f..., the pins as PR 51 left them; the ``--no-ids`` dumps of parent and change differ inside the forward kernel's body of each
+#: delta layer (four of 954 lines that now have 1,690; three of 1,027 that now have 681), in the bound of its boards' loop (8 turns, now 4
+#: pairs: ``kda``) and in the ``jnp.where`` helpers those bodies call (a ``[64, 128]`` and a ``[128, 128]`` select more; ``gdn``'s ``[64,
+#: 64]`` masked decay now ``[64, 128]``), and nowhere else: every gradient kernel's body (four of 1,040 lines, three of 1,059) is line for
+#: line the parent's. A PR that means to change either reads its own parent the same way.
+GDN_STEP_SHA256 = {"kda": "dc1e7fa9525c9d1eab1f458a4105d982b2b5542c7baeaa3bc2513967d1ba76ee", "gdn": "776060adb956e1725db24d573c3795f504ef3f3cb02216c8a078ba9860b4ca7e"}
 
 
 @pytest.mark.parametrize("block", GDN_STEP_SHA256)
@@ -182,6 +186,20 @@ def test_the_sixth_and_seventh_blocks_lowered_steps_are_the_parents_op_for_op(bl
     text = lowered_step_text(cfg, batch(1))
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == GDN_STEP_SHA256[block], HOW_TO_SEE_WHAT_MOVED.format(block=block)
+
+
+def test_no_ids_takes_the_ssa_numbers_and_the_counters_on_function_names_and_nothing_else():
+    """``tools/step_text.py --no-ids``: an operation more renumbers every ``%123`` after it and a helper more every ``@_where_203``
+    after it; both numbers go, so that the ``diff`` of two dumps is the operations that moved. A name's own digits stay."""
+    text = ("func.func private @_where_203(%arg0: tensor<64x128xi1>) -> tensor<64x128xf32> {\n"
+            "  %12 = call @closed_call_280(%11, %c_5) : (tensor<f32>) -> tensor<f32>\n"
+            "  %13 = stablehlo.custom_call @tpu_custom_call(%12) {kernel_name = \"board_delta\"}\n"
+            "  %14 = call @layer03.attention(%13) : (tensor<8x64xf32>) -> tensor<8x64xf32>\n")
+    assert without_ids(text) == ("func.func private @_where(%arg0: tensor<64x128xi1>) -> tensor<64x128xf32> {\n"
+                                 "  % = call @closed_call(%, %c_5) : (tensor<f32>) -> tensor<f32>\n"
+                                 "  % = stablehlo.custom_call @tpu_custom_call(%) {kernel_name = \"board_delta\"}\n"
+                                 "  % = call @layer03.attention(%) : (tensor<8x64xf32>) -> tensor<8x64xf32>\n")
+    assert without_ids(without_ids(text)) == without_ids(text)
 
 
 def test_the_seventh_blocks_counters_checkpoint_and_refusals(tmp_path):

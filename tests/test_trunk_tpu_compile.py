@@ -975,6 +975,58 @@ def test_the_second_form_of_the_delta_pair_compiles_at_published_widths(one_chip
     assert " = bf16[128,64,4096]" in alone and "board_delta" in alone.split(" = ")[0], alone[:200]  # one kernel, one result: no tuple
 
 
+def _exact_products_a_turn(jaxpr):
+    """From a jaxpr (and the jaxprs its equations hold): the operand shapes of every product at ``highest`` inside the ONE rolled
+    loop of the ``board_delta`` forward kernel's body, and the loop's turns."""
+    def walk(held):
+        for eqn in held.eqns:
+            yield eqn
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(inner)
+
+    (forward,) = [eqn for eqn in walk(jaxpr) if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "board_delta"]
+    (loop,) = [eqn for eqn in forward.params["jaxpr"].eqns if eqn.primitive.name in ("scan", "while")]
+    highest = lambda precision: precision is not None and set(precision if isinstance(precision, tuple) else (precision,)) == {jax.lax.Precision.HIGHEST}
+    (body,) = [inner for inner in jax.core.jaxprs_in_params(loop.params) if any(eqn.primitive.name == "dot_general" for eqn in walk(inner))]
+    products = [tuple(v.aval.shape for v in eqn.invars) for eqn in walk(body) if eqn.primitive.name == "dot_general" and highest(eqn.params["precision"])]
+    return sorted(products), loop.params["length"]
+
+
+#: The solve's eleven products at ``highest`` a loop turn: a level's two, levels 1 to 5 (level 0 is ``I - A_0``, no product), and ``U``.
+#: Packed: two chains side by side.
+_PACKED_SOLVE = [((64, 128), (128, 128))] * 10 + [((128, 128), (128, 128))]
+_SINGLE_SOLVE = [((64, 64), (64, 64))] * 10 + [((64, 64), (64, 128))]
+_SPANS = ((64, 64), (64, 128))  # a triangle times g: the first form's cumulative sum a board, the second form's spans of a pair of value heads (of ONE: [64, 64])
+
+
+@pytest.mark.parametrize("shape,wanted", [
+    # gdn_trunk_train_b128: a key head's two value heads a turn are ONE packed chain, their spans ONE product; eight boards a block, a board a turn
+    (dict(boards=GDN_BOARDS, key_heads=16, value_heads=32), (sorted(_PACKED_SOLVE + [_SPANS]), 8)),
+    # kda_trunk_train_b128: boards 2 t and 2 t + 1 of a block of eight a turn, a cumulative sum each
+    (dict(boards=KDA_BOARDS, heads=16), (sorted(_PACKED_SOLVE + [_SPANS] * 2), 4)),
+    (dict(boards=9, heads=16), (sorted(_SINGLE_SOLVE + [_SPANS]), 1)),  # an odd block: one board a turn, the single chain
+    (dict(boards=GDN_BOARDS, key_heads=16, value_heads=16), (sorted(_SINGLE_SOLVE + [((64, 64), (64, 64))]), 8)),  # one value head a key head
+    (dict(boards=GDN_BOARDS, key_heads=16, value_heads=48), (sorted(_PACKED_SOLVE + [_SPANS] + _SINGLE_SOLVE + [((64, 64), (64, 64))]), 8)),  # three: a pair and one left over
+], ids=["gdn_cell", "kda_cell", "an_odd_block", "one_value_head_a_key_head", "three_value_heads_a_key_head"])
+def test_the_delta_forward_solves_two_chains_a_product_where_the_shapes_allow(shape, wanted):
+    """The mechanism's counter (engagement is static: a shape decides it while the body is traced). On the two cells' shapes every
+    product of the solve in the forward kernel's body is ``[64, 128] x [128, 128]`` at ``highest`` (two chains a product), ten a
+    loop turn (five levels of two: level 0 has none), and none ``[64, 64] x [64, 64]``: the packed path runs for every chain of both cells, the single one for none; an odd block of
+    boards or an odd value head shows the single chain. Nothing compiles here: the jaxpr of the traced call is read."""
+    from fishnet_tpu.ops.board_delta import board_delta
+
+    boards, sds = shape["boards"], jax.ShapeDtypeStruct
+    if "heads" in shape:  # the first form: a decay a channel
+        wide = (boards, 64, shape["heads"] * 128)
+        args = (sds(wide, jnp.bfloat16),) * 3 + (sds(wide, jnp.float32), sds((boards, 64, shape["heads"]), jnp.float32))
+    else:
+        key, value, heads = (boards, 64, shape["key_heads"] * 128), (boards, 64, shape["value_heads"] * 128), (boards, 64, shape["value_heads"])
+        args = (sds(key, jnp.bfloat16),) * 2 + (sds(value, jnp.bfloat16), sds(heads, jnp.float32), sds(heads, jnp.float32))
+    for differentiated in (False, True):
+        fn = (lambda *a: jax.vjp(lambda *b: board_delta(*b, False), *a)[0]) if differentiated else (lambda *a: board_delta(*a, False))
+        assert _exact_products_a_turn(jax.make_jaxpr(fn)(*args).jaxpr) == wanted
+
+
 def test_gated_attention_at_a_head_of_256_with_64_columns_turned_compiles_at_published_widths(one_chip, compiled_for_tpu):
     """``value_and_grad`` of the seventh block's ``_attention``: the grouped normed form at ``head_dim`` 256, 8 query heads on each
     of 2 key-value heads (2 boards a grid step), RoPE on the first 64 of 256 columns, and ``_gated_out`` at 4,096 columns: two
