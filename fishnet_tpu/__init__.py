@@ -27,6 +27,12 @@ Package layout:
     utils/      logger, stats, backoff, config, assets
 """
 
-from fishnet_tpu.version import __version__
+import time
+
+#: ``time.monotonic()`` of the package's first import: where the ``process_boot`` span ends and
+#: ``program_import`` starts (train/startup.py).
+FIRST_IMPORT = time.monotonic()
+
+from fishnet_tpu.version import __version__  # noqa: E402
 
 __all__ = ["__version__"]

@@ -48,14 +48,30 @@ named machinery actually runs):
   whose update its step runs in the client's layout)
 * ``train_first_step`` — the first ``.step`` of a trainer instance:
   trace + lower + compile or cache load + dispatch of the step program
-  (train/startup.py; the first five fields)
+  (train/startup.py; the first five fields). Both trainer stages also
+  carry small_at_start, small_at_end (``small`` below, at their two
+  ends) and trace_id, span_id: the ``program_up`` spans inside one name
+  its span_id as parent_id
+* ``program_up``  — one program brought up, on its thread: traced,
+  lowered, compiled or loaded (utils/compile_cache.py; fields: name,
+  the four disjoint phases trace_s, lower_s, cache_load_s, compile_s,
+  cache = hit | miss | none, traced = up to 8 rows [fun_name, calls,
+  self_s] of the functions traced inside it that cost most SELF time
+  (an interval less what is nested in it), small = [count, seconds] of
+  the programs so far that hit the cache or asked none and took under
+  10 ms: those are counted there and are no spans)
+* ``process_boot`` — once a process: its start (/proc/self/stat) to the
+  package's first import; absent where the start cannot be read
+  (train/startup.py; no fields)
+* ``program_import`` — once a process: the package's first import to
+  the first trainer's construction (train/startup.py; no fields)
 
 Recording is OFF by default: every instrumentation site is gated on
 ``fishnet_tpu.telemetry.enabled()``, so with telemetry disabled the
 device-dispatch critical path pays one attribute read per step and the
-rings stay empty. The one exception is the trainers' two start-up
-stages above: two spans per trainer per process, over before anything
-could enable telemetry, recorded always (a step after the first pays
+rings stay empty. The one exception is the five start-up stages above:
+over before anything could enable telemetry, recorded always, and only
+when JAX traces, lowers, loads or compiles (a step after the first pays
 one attribute test for them). When enabled, ``record()`` is one ``time.monotonic()``
 call plus a slot store into a preallocated per-thread ring — no lock,
 single writer per ring.
@@ -100,6 +116,7 @@ EVENT_STAGES = (
     "recover", "coalesce", "dispatch_issue", "dispatch_wait",
     "mcts_collect", "queue_wait", "submit", "admit", "cache_probe",
     "drain", "control", "train_init", "train_first_step",
+    "program_up", "process_boot", "program_import",
 )
 
 #: Span-dump header format. /2 added the additive causal-trace fields
@@ -192,10 +209,12 @@ class SpanRecorder:
         started: float,
         trace=None,
         links=None,
+        ended: Optional[float] = None,
         **fields,
     ) -> None:
         """Record a span that began at monotonic time ``started`` and
-        ends now. Call sites gate on ``telemetry.enabled()``.
+        ends now (at monotonic ``ended`` where the span is recorded after
+        the fact). Call sites gate on ``telemetry.enabled()``.
 
         ``trace`` (a tracing.TraceContext) adds the causal-tree fields;
         ``links`` adds the shared-span fan-in list — both additive on
@@ -213,7 +232,7 @@ class SpanRecorder:
                 fields["parent_id"] = trace.parent_id
         if links:
             fields["links"] = [list(lk) for lk in links]
-        dur = time.monotonic() - started
+        dur = (time.monotonic() if ended is None else ended) - started
         ring.append((stage, started, dur, fields))
         obs = STAGE_OBSERVER
         if obs is not None:

@@ -12,7 +12,7 @@ to a host count: no ``device_get``, no ``float()``, no
 ``block_until_ready``, no lock. A reader pays for the transfer
 (``StepRecord.read``: one ``jax.device_get`` over what the ring holds);
 the exporter's series are a collector that fetches the latest step's
-metrics when scraped and costs nothing when never scraped. Like the two
+metrics when scraped and costs nothing when never scraped. Like the
 start-up spans it is always on: the benchmark never enables telemetry, and
 a step's metrics are outputs of the step program, never donated, a few
 dozen scalars a step.
@@ -113,7 +113,9 @@ class StepRecorder:
         self._collector: Optional[int] = None
 
     def attach(self, kind: str) -> StepRecord:
-        """A new trainer's record; the trainer holds the one strong reference."""
+        """A new trainer's record; the trainer holds the one strong reference.
+        The process's first is where its ``program_import`` span ends."""
+        startup.record_process_spans()
         with self._lock:
             record = StepRecord(kind, self._made)
             self._made += 1

@@ -15,21 +15,23 @@ never hits.
 ``configure()`` also installs the process's compile recorder (once,
 however often it is called): ``jax.monitoring`` listeners that feed
 ``fishnet_compile_seconds_total{phase}`` and
-``fishnet_compiles_total{cache}`` (doc/observability.md "Training and
-compilation") and keep the last compile events, so that a start-up span
-can total what fell inside it. The listeners run only when something
-traces or compiles: a window with no compilation pays nothing.
+``fishnet_compiles_total{cache}`` and record every program brought up as
+one ``program_up`` span of the span flight recorder, with its name, its
+four phases and the functions whose traces cost most
+(doc/observability.md "Training and compilation"). The listeners run
+only when something traces or compiles: a window with no compilation
+pays nothing.
 """
 
 from __future__ import annotations
 
 import os
 import threading
-import time
-from collections import deque
+from contextlib import contextmanager
 from pathlib import Path
-from typing import Deque, Dict, Optional, Tuple
+from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
+from fishnet_tpu.telemetry import spans, tracing
 from fishnet_tpu.telemetry.registry import REGISTRY, MetricsRegistry
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -38,12 +40,14 @@ ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-#: ``jax.monitoring`` duration events -> the ``phase`` label.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: ``jax.monitoring`` events with a time span or a duration -> the ``phase`` label.
 PHASE_OF_EVENT = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    _TRACE_EVENT: "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "backend",
-    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+    _LOAD_EVENT: "cache_load",
 }
 #: ``jax.monitoring`` plain events -> the ``cache`` label.
 CACHE_OF_EVENT = {
@@ -51,30 +55,76 @@ CACHE_OF_EVENT = {
     "/jax/compilation_cache/cache_misses": "miss",
 }
 
-#: One kept compile event: monotonic time it ended, ``phase`` (or
-#: ``hit``/``miss``, with 0 seconds), seconds, thread that compiled.
-Event = Tuple[float, str, float, int]
+#: A program that hit the cache or asked none, and whose four phases took
+#: less than this together, is counted in ``small`` and is no span.
+SMALL_PROGRAM_S = 0.010
+#: Rows of a ``program_up`` span's ``traced``: the functions traced inside
+#: the program that cost most self time.
+TRACED_KEPT = 8
+#: Finished traces a thread holds for a lowering before it forgets them
+#: (a thread that only ever traces: ``jax.eval_shape`` in a loop).
+TRACES_HELD = 1 << 15
 
-EVENTS_KEPT = 256
+
+#: A finished trace no finished trace holds yet, a plain tuple (a step program sends thousands): its
+#: interval on JAX's clock (``time.time()``), its name, and its rows of ``_Held.rows``, its own last.
+_Trace = Tuple[float, float, str, int, int]
+_START, _END, _NAME, _FIRST_ROW, _LAST_ROW = range(5)
+
+
+class _Program(NamedTuple):
+    """A lowered program waiting for its compilation."""
+
+    name: str
+    start: float  # its trace's, or its lowering's where it had none
+    lowered: float  # its lowering's end
+    trace_s: float
+    lower_s: float
+    rows: List[Tuple[str, float]]
+
+
+class _Held(threading.local):
+    """What one thread has of the program it is bringing up."""
+
+    def __init__(self) -> None:
+        self.rows: List[Tuple[str, float]] = []  # (function, self seconds) of every finished trace, as they finished
+        self.outermost: List[_Trace] = []
+        self.program: Optional[_Program] = None
+        self.cache, self.cache_load_s = "none", 0.0  # of the program being compiled
+        self.totals = {"compile_s": 0.0, "cache_load_s": 0.0, "trace_lower_s": 0.0, "cache_misses": 0}
+        self.parent: Optional[tracing.TraceContext] = None  # the start-up span open on this thread
 
 
 class CompileRecorder:
-    """Counts what JAX reports of tracing and compiling, by phase.
+    """Counts what JAX reports of tracing and compiling, by phase, and
+    folds the events of each program into one ``program_up`` span.
 
-    The phases are disjoint, so their seconds add up to the time spent:
+    JAX 0.9.0 sends, on the thread that brings a program up and in this
+    order: the time span of every function traced (the nested first, the
+    program's own last), of its lowering (``jit(<name>)``; a kernel's body
+    is traced inside it), then, inside the time span of
+    ``backend_compile``, ``cache_hits`` and the duration of the retrieval,
+    or ``cache_misses`` once it is compiled and written; no cache event
+    where no cache is asked. The span is recorded when ``backend_compile``
+    ends. The phases are disjoint, so their seconds add up to the time
+    spent:
 
     * JAX times every traced function, those traced inside another too
       (one trainer step: ~1,500 events, nearly all nested in the step's
-      own). A trace is held back until a program of its name is lowered
-      on the same thread and is counted then, once, with what it traced
-      inside it; traces that lead to no program are not counted.
-    * ``backend_compile_duration`` fires for a program loaded from the
-      persistent cache as well, and then holds the load. ``backend`` is
-      counted less the ``cache_load`` that came just before it on the
-      same thread: it is real compilation only.
+      own). A trace is held back until a program is lowered on the same
+      thread: the last outermost trace of the program's name (of another
+      name only where none has it) is the program's and is counted then,
+      once, with what it traced inside it; what else was held led to no
+      program (``jax.eval_shape``) and is forgotten. A trace's **self**
+      time is its interval less the intervals of the traces directly
+      inside it; same-named traces add up.
+    * ``backend_compile`` covers a program loaded from the persistent
+      cache as well, and then holds the load. ``compile_s`` is counted
+      less the ``cache_load`` that came inside it: it is real compilation
+      only.
     """
 
-    def __init__(self, registry: MetricsRegistry = REGISTRY) -> None:
+    def __init__(self, registry: MetricsRegistry = REGISTRY, span_recorder: Optional[spans.SpanRecorder] = None) -> None:
         self._seconds = registry.counter(
             "fishnet_compile_seconds_total",
             "Seconds JAX spent bringing programs up, by disjoint phase",
@@ -85,74 +135,125 @@ class CompileRecorder:
             "Programs asked of the persistent compile cache, by outcome",
             labelnames=("cache",),
         )
-        self._events: Deque[Event] = deque(maxlen=EVENTS_KEPT)
-        self._lock = threading.Lock()  # compiles are rare and slow: not a hot path
-        self._thread = threading.local()  # .traced: function name -> seconds, since the last lowering
+        self._spans = span_recorder or spans.RECORDER
+        self._held = _Held()
+        self._lock = threading.Lock()  # ``small`` alone: programs are rare and slow, not a hot path
+        self._small = [0, 0.0]
 
-    def on_duration(self, event: str, seconds: float, fun_name: str = "", **_kw) -> None:
+    # -- the listeners ------------------------------------------------------
+
+    def on_span(self, event: str, start: float, end: float, fun_name: str = "", **_kw) -> None:
+        if event == _TRACE_EVENT:  # thousands a step program: one interval pushed, those it holds popped
+            held = self._held
+            rows, outermost = held.rows, held.outermost
+            if len(rows) >= TRACES_HELD:
+                rows.clear()
+                outermost.clear()
+            self_s, first_row = end - start, len(rows)
+            while outermost and outermost[-1][_START] >= start:
+                nested = outermost.pop()
+                self_s -= nested[_END] - nested[_START]
+                first_row = nested[_FIRST_ROW]
+            outermost.append((start, end, fun_name, first_row, len(rows)))
+            rows.append((fun_name, self_s))
+            return
         phase = PHASE_OF_EVENT.get(event)
-        if phase is None:
-            return
-        if phase == "trace":
-            self._traced()[fun_name] = seconds
-            return
-        if phase == "lower":
-            traced = self._traced()
+        if phase in ("lower", "backend"):
             name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
-            # the program's own trace; by another name (pmap), the longest held
-            self._count("trace", traced.get(name) or max(traced.values(), default=0.0))
-            traced.clear()
-        elif phase == "backend":
-            seconds = max(0.0, seconds - self._load_just_before())
-        self._count(phase, seconds)
+            (self._lowered if phase == "lower" else self._compiled)(name, start, end)
+
+    def on_duration(self, event: str, seconds: float, **_kw) -> None:
+        """The one phase JAX sends no time span of (the three others' durations come with their spans)."""
+        if event == _LOAD_EVENT:
+            self._held.cache_load_s += seconds
+            self._count("cache_load", "cache_load_s", seconds)
 
     def on_event(self, event: str, **_kw) -> None:
         cache = CACHE_OF_EVENT.get(event)
         if cache is None:
             return
-        with self._lock:
-            self._events.append((time.monotonic(), cache, 0.0, threading.get_ident()))
+        held = self._held
+        held.cache = cache
+        held.totals["cache_misses"] += cache == "miss"
         self._compiles.inc(cache=cache)
 
-    def _traced(self) -> Dict[str, float]:
-        try:
-            return self._thread.traced
-        except AttributeError:
-            self._thread.traced = {}
-            return self._thread.traced
+    # -- one program ----------------------------------------------------------
 
-    def _count(self, phase: str, seconds: float) -> None:
-        with self._lock:
-            self._events.append((time.monotonic(), phase, seconds, threading.get_ident()))
+    def _lowered(self, name: str, start: float, end: float) -> None:
+        held = self._held
+        if held.program is not None:  # lowered and never compiled (``.lower()`` alone)
+            self._record(held.program, held.program.lowered, 0.0)
+        before = [trace for trace in held.outermost if trace[_START] < start]
+        own = next((trace for trace in reversed(before) if trace[_NAME] == name), before[-1] if before else None)
+        rows: List[Tuple[str, float]] = []
+        for trace in ([own] if own else []) + held.outermost[len(before):]:  # its own, and what its lowering traced
+            rows += held.rows[trace[_FIRST_ROW]:trace[_LAST_ROW] + 1]
+        held.rows.clear()
+        held.outermost.clear()
+        trace_s = own[_END] - own[_START] if own else 0.0
+        held.program = _Program(name, own[_START] if own else start, end, trace_s, end - start, rows)
+        self._count("trace", "trace_lower_s", trace_s)
+        self._count("lower", "trace_lower_s", end - start)
+
+    def _compiled(self, name: str, start: float, end: float) -> None:
+        held = self._held
+        program = held.program or _Program(name, start, start, 0.0, 0.0, [])  # lowered on another thread, or long ago
+        compile_s = max(0.0, end - start - held.cache_load_s)
+        self._count("backend", "compile_s", compile_s)
+        self._record(program, end, compile_s)
+        held.program, held.cache, held.cache_load_s = None, "none", 0.0
+
+    def _count(self, phase: str, total: str, seconds: float) -> None:
+        self._held.totals[total] += seconds
         self._seconds.inc(seconds, phase=phase)
 
-    def _load_just_before(self) -> float:
-        thread = threading.get_ident()
-        for _ended, phase, seconds, who in reversed(self.events()):
-            if who == thread:
-                return seconds if phase == "cache_load" else 0.0
-        return 0.0
+    def _record(self, program: _Program, end: float, compile_s: float) -> None:
+        held = self._held
+        seconds = program.trace_s + program.lower_s + held.cache_load_s + compile_s
+        if held.cache != "miss" and seconds < SMALL_PROGRAM_S:
+            with self._lock:
+                self._small[0] += 1
+                self._small[1] += seconds
+            return
+        traced: Dict[str, List[float]] = {}
+        for name, self_s in program.rows:
+            row = traced.setdefault(name, [0, 0.0])
+            row[0] += 1
+            row[1] += self_s
+        costliest = sorted(traced.items(), key=lambda item: -item[1][1])[:TRACED_KEPT]
+        to_monotonic = self._spans.epoch_offset  # JAX's clock is time.time(), the span recorder's time.monotonic()
+        self._spans.record(
+            "program_up", program.start - to_monotonic, ended=end - to_monotonic,
+            trace=held.parent and held.parent.child(),
+            name=program.name, trace_s=round(program.trace_s, 6), lower_s=round(program.lower_s, 6),
+            cache_load_s=round(held.cache_load_s, 6), compile_s=round(compile_s, 6), cache=held.cache,
+            traced=[[name, calls, round(self_s, 6)] for name, (calls, self_s) in costliest], small=self.small(),
+        )
 
-    def events(self) -> Tuple[Event, ...]:
-        """The last ``EVENTS_KEPT`` compile events, oldest first."""
+    # -- for a start-up span ----------------------------------------------------
+
+    def small(self) -> List[float]:
+        """``[count, seconds]`` of the programs brought up so far that were too small to be spans."""
         with self._lock:
-            return tuple(self._events)
+            return [self._small[0], round(self._small[1], 6)]
 
-    def totals_since(self, started: float) -> Dict[str, float]:
-        """What this thread's kept events since monotonic ``started`` add
-        up to, under the names of a start-up span's fields."""
-        thread = threading.get_ident()
-        mine = [(phase, seconds) for ended, phase, seconds, who in self.events() if who == thread and ended >= started]
+    def mark(self) -> Dict[str, float]:
+        """This thread's running totals now, for ``totals_since``."""
+        return dict(self._held.totals)
 
-        def total(*phases: str) -> float:
-            return round(sum(seconds for phase, seconds in mine if phase in phases), 6)
+    def totals_since(self, mark: Dict[str, float]) -> Dict[str, float]:
+        """What this thread has brought up since ``mark()``, under the
+        names of a start-up span's fields."""
+        return {key: round(value - mark[key], 6) for key, value in self._held.totals.items()}
 
-        return {
-            "compile_s": total("backend"),
-            "cache_load_s": total("cache_load"),
-            "trace_lower_s": total("trace", "lower"),
-            "cache_misses": sum(phase == "miss" for phase, _seconds in mine),
-        }
+    @contextmanager
+    def parent_of_programs(self, context: tracing.TraceContext) -> Iterator[None]:
+        """While open, a ``program_up`` of this thread is a child of ``context``'s span."""
+        self._held.parent = context
+        try:
+            yield
+        finally:
+            self._held.parent = None
 
 
 #: The process's recorder, installed by the first ``configure()``.
@@ -168,6 +269,7 @@ def configure_recorder() -> CompileRecorder:
             import jax
 
             recorder = CompileRecorder()
+            jax.monitoring.register_event_time_span_listener(recorder.on_span)
             jax.monitoring.register_event_duration_secs_listener(recorder.on_duration)
             jax.monitoring.register_event_listener(recorder.on_event)
             RECORDER = recorder

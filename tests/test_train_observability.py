@@ -1,7 +1,7 @@
 """The learner names its own work (doc/observability.md "Training and
 compilation"): named scopes through both step programs, the compile
-recorder that ``compile_cache.configure()`` installs, and the trainers'
-two start-up spans. CPU, toy widths."""
+recorder that ``compile_cache.configure()`` installs with its one span a
+program brought up, and the start-up spans. CPU, toy widths."""
 
 import contextlib
 import os
@@ -17,7 +17,7 @@ from fishnet_tpu import telemetry
 from fishnet_tpu.models.az import AzConfig
 from fishnet_tpu.models.trunk import TrunkConfig
 from fishnet_tpu.telemetry.registry import MetricsRegistry
-from fishnet_tpu.telemetry.spans import EVENT_STAGES, RECORDER
+from fishnet_tpu.telemetry.spans import EVENT_STAGES, RECORDER, SpanRecorder
 from fishnet_tpu.train import step_metrics
 from fishnet_tpu.train.az_trainer import AzTrainer
 from fishnet_tpu.train.model import NetConfig
@@ -215,6 +215,45 @@ def test_scopes_are_metadata_only(scoped, monkeypatch):
 # -- B. the compile recorder -----------------------------------------------------
 
 
+def near(expected, within=1e-4):
+    """JAX's clock is ``time.time()``: a float of today's date resolves 0.2 us, and 600 intervals add that up."""
+    return pytest.approx(expected, abs=within)
+
+
+def own_recorder():
+    """A compile recorder on a registry and a span recorder of its own."""
+    registry, spans = MetricsRegistry(), SpanRecorder()
+    return compile_cache.CompileRecorder(registry, spans), registry, spans
+
+
+def program_ups(spans):
+    return [span for span in spans.spans() if span["stage"] == "program_up"]
+
+
+def bring_up(recorder, name, at, trace_s, lower_s, backend_s, cache=None, load_s=0.0, nested=()):
+    """The events of one program as JAX 0.9.0 sends them (``ev.py``-style
+    log of a ``jax.jit``): every nested trace's time span, the program's
+    own, its lowering's under ``jit(<name>)``, then the cache's events and
+    the time span of ``backend_compile`` that holds them. ``nested`` are
+    ``(function, offset into the trace, seconds)``. Returns the end."""
+    for fun_name, offset, seconds in nested:
+        recorder.on_span(TRACE, at + offset, at + offset + seconds, fun_name=fun_name)
+    recorder.on_span(TRACE, at, at + trace_s, fun_name=name)
+    lowered = at + trace_s + 0.001
+    recorder.on_span(LOWER, lowered, lowered + lower_s, fun_name=f"jit({name})")
+    compiled = lowered + lower_s + 0.001
+    recorder.on_event("/jax/compilation_cache/compile_requests_use_cache")  # not ours
+    if cache == HIT:
+        recorder.on_event(HIT)
+        recorder.on_duration("/jax/compilation_cache/compile_time_saved_sec", 9.0)  # not ours
+        recorder.on_duration(LOAD, load_s)
+    elif cache == MISS:
+        recorder.on_event(MISS)
+    recorder.on_duration(BACKEND, backend_s, fun_name=f"jit({name})")  # JAX sends the duration too: it is the span's
+    recorder.on_span(BACKEND, compiled, compiled + backend_s, fun_name=f"jit({name})")
+    return compiled + backend_s
+
+
 def test_configure_twice_installs_one_listener():
     compile_cache.configure()
     recorder = compile_cache.RECORDER
@@ -222,73 +261,242 @@ def test_configure_twice_installs_one_listener():
     assert compile_cache.configure_recorder() is recorder is compile_cache.RECORDER
     compiles = telemetry.REGISTRY.counter("fishnet_compiles_total", "", labelnames=("cache",))
     seconds = telemetry.REGISTRY.counter("fishnet_compile_seconds_total", "", labelnames=("phase",))
-    before = compiles.value(cache="hit"), seconds.value(phase="lower"), len(recorder.events())
+    before = compiles.value(cache="hit"), seconds.value(phase="lower"), recorder.mark()
+    now = time.time()
     jax.monitoring.record_event(HIT)  # one listener: counted once
-    jax.monitoring.record_event_duration_secs(LOWER, 0.5, fun_name="jit(nothing)")
+    jax.monitoring.record_event_time_span(LOWER, now - 0.5, now, fun_name="jit(nothing)")
+    jax.monitoring.record_event_duration_secs(LOWER, 0.5, fun_name="jit(nothing)")  # the same lowering: JAX sends both
     assert compiles.value(cache="hit") == before[0] + 1
-    assert seconds.value(phase="lower") == pytest.approx(before[1] + 0.5)
-    assert len(recorder.events()) == min(before[2] + 3, compile_cache.EVENTS_KEPT)  # hit, trace (0 s), lower
+    assert seconds.value(phase="lower") == near(before[1] + 0.5)
+    assert recorder.totals_since(before[2])["trace_lower_s"] == near(0.5)
+    jax.monitoring.record_event_time_span(BACKEND, now, now, fun_name="jit(nothing)")  # leave no program half brought up
 
 
-def test_a_miss_then_a_hit_against_a_fresh_cache_directory(tmp_path):
+def test_a_miss_then_a_hit_against_a_fresh_cache_directory(tmp_path, monkeypatch):
+    """A real cold and warm ``jax.jit`` on the CPU, under the test's own
+    cache directory: the counters, the thread's totals, and one
+    ``program_up`` each, ``cache: miss`` then ``hit``."""
     compile_cache.configure()
+    monkeypatch.setattr(compile_cache, "SMALL_PROGRAM_S", 0.0)  # a miss is a span whatever it took; this hit takes ~5 ms
     hits = lambda: telemetry.REGISTRY.counter("fishnet_compiles_total", "", labelnames=("cache",))
     seconds = lambda: telemetry.REGISTRY.counter("fishnet_compile_seconds_total", "", labelnames=("phase",))
+    mine = lambda: [span for span in program_ups(RECORDER) if span["name"] == "warm_and_cold"]
     with persistent_cache(str(tmp_path)):
-        fn = lambda x: jax.numpy.tanh(x) * 3.25 + 0.125
+        def warm_and_cold(x):
+            return jax.numpy.tanh(x) * 3.25 + 0.125
+
         x = jax.numpy.ones((7,), jax.numpy.float32)
         x.block_until_ready()  # its own programs compile before the counts are read
         counts = {k: hits().value(cache=k) for k in ("hit", "miss")}
-        started = time.monotonic()
-        jax.jit(fn)(x).block_until_ready()
+        mark = compile_cache.RECORDER.mark()
+        jax.jit(warm_and_cold)(x).block_until_ready()
         assert hits().value(cache="miss") == counts["miss"] + 1
         assert hits().value(cache="hit") == counts["hit"]
-        cold = compile_cache.RECORDER.totals_since(started)
+        cold = compile_cache.RECORDER.totals_since(mark)
         assert cold["cache_misses"] == 1 and cold["compile_s"] > 0 and cold["cache_load_s"] == 0
         assert cold["trace_lower_s"] > 0
 
         jax.clear_caches()
         loaded = seconds().value(phase="cache_load")
-        started = time.monotonic()
-        jax.jit(fn)(x).block_until_ready()
+        mark = compile_cache.RECORDER.mark()
+        jax.jit(warm_and_cold)(x).block_until_ready()
         assert hits().value(cache="hit") == counts["hit"] + 1
         assert hits().value(cache="miss") == counts["miss"] + 1
         assert seconds().value(phase="cache_load") > loaded
-        warm = compile_cache.RECORDER.totals_since(started)
+        warm = compile_cache.RECORDER.totals_since(mark)
         assert warm["cache_misses"] == 0 and warm["cache_load_s"] > 0
+    first, second = mine()
+    assert (first["cache"], second["cache"]) == ("miss", "hit")
+    assert first["compile_s"] == cold["compile_s"] and first["trace_s"] + first["lower_s"] == near(cold["trace_lower_s"], within=2e-6)
+    assert second["cache_load_s"] == warm["cache_load_s"] and second["compile_s"] == warm["compile_s"]
+    assert {row[0] for row in first["traced"]} >= {"warm_and_cold", "tanh"}
+    assert "parent_id" not in first  # no start-up span was open
 
 
-def test_recorder_counts_disjoint_phases():
+def test_recorder_counts_disjoint_phases(monkeypatch):
     """Fed the events of one cold and one warm program (names and order as
-    JAX 0.9.0 sends them): nested traces count once, a load is not a compile."""
-    registry = MetricsRegistry()
-    recorder = compile_cache.CompileRecorder(registry)
-    started = time.monotonic()
-    for _ in range(600):  # more than the events kept: nested traces are not kept
-        recorder.on_duration(TRACE, 0.001, fun_name="relu")
-    recorder.on_duration(TRACE, 2.0, fun_name="_step")
-    recorder.on_duration(LOWER, 0.5, fun_name="jit(_step)")
-    recorder.on_event(MISS)
-    recorder.on_duration(BACKEND, 30.0, fun_name="jit(_step)")
-    recorder.on_duration("/jax/compilation_cache/compile_time_saved_sec", 9.0)  # not ours
-    recorder.on_duration(TRACE, 1.0, fun_name="_step")
-    recorder.on_duration(LOWER, 0.25, fun_name="jit(_step)")
-    recorder.on_event(HIT)
-    recorder.on_duration(LOAD, 1.5)
-    recorder.on_duration(BACKEND, 1.75, fun_name="jit(_step)")
+    JAX 0.9.0 sends them): nested traces count once, a load is not a
+    compile, and the thread's totals are what the kept events used to give."""
+    recorder, registry, spans = own_recorder()
+    mark = recorder.mark()
+    at = time.time()
+    nested = [("relu", 0.003 * i, 0.001) for i in range(600)]  # one after another inside _step's own 2 s
+    at = bring_up(recorder, "_step", at, trace_s=2.0, lower_s=0.5, backend_s=30.0, cache=MISS, nested=nested)
+    bring_up(recorder, "_step", at, trace_s=1.0, lower_s=0.25, backend_s=1.75, cache=HIT, load_s=1.5)
     seconds = registry.counter("fishnet_compile_seconds_total", "", labelnames=("phase",))
-    assert seconds.value(phase="trace") == pytest.approx(3.0)
-    assert seconds.value(phase="lower") == pytest.approx(0.75)
-    assert seconds.value(phase="backend") == pytest.approx(30.25)
-    assert seconds.value(phase="cache_load") == pytest.approx(1.5)
+    assert seconds.value(phase="trace") == near(3.0)
+    assert seconds.value(phase="lower") == near(0.75)
+    assert seconds.value(phase="backend") == near(30.25)
+    assert seconds.value(phase="cache_load") == near(1.5)
     compiles = registry.counter("fishnet_compiles_total", "", labelnames=("cache",))
     assert (compiles.value(cache="miss"), compiles.value(cache="hit")) == (1, 1)
-    assert recorder.totals_since(started) == {
-        "compile_s": pytest.approx(30.25), "cache_load_s": 1.5, "trace_lower_s": 3.75, "cache_misses": 1}
-    assert len(recorder.events()) == 9 <= compile_cache.EVENTS_KEPT
-    for _ in range(300):
-        recorder.on_event(HIT)
-    assert len(recorder.events()) == compile_cache.EVENTS_KEPT
+    assert recorder.totals_since(mark) == {
+        "compile_s": near(30.25), "cache_load_s": 1.5, "trace_lower_s": near(3.75), "cache_misses": 1}
+    cold, warm = program_ups(spans)
+    assert cold["traced"] == [["_step", 1, near(1.4)], ["relu", 600, near(0.6)]]  # ONE row of 600 calls
+    assert (warm["trace_s"], warm["lower_s"], warm["cache_load_s"], warm["compile_s"]) == near((1.0, 0.25, 1.5, 0.25))
+
+
+def test_one_program_is_one_record_with_its_name_phases_and_cache():
+    recorder, _registry, spans = own_recorder()
+    at = time.time()
+    ended = bring_up(recorder, "balanced_bias", at, trace_s=0.4, lower_s=0.2, backend_s=1.3, cache=HIT, load_s=0.9)
+    (span,) = spans.spans()
+    assert (span["stage"], span["name"], span["cache"]) == ("program_up", "balanced_bias", "hit")
+    assert (span["trace_s"], span["lower_s"], span["cache_load_s"], span["compile_s"]) == near((0.4, 0.2, 0.9, 0.4))
+    # its start and its duration are the program's own, on the span recorder's clock
+    assert span["t"] == near(at - spans.epoch_offset, within=1e-5) and span["dur_ms"] == near(1e3 * (ended - at), within=1e-2)
+    assert span["traced"] == [["balanced_bias", 1, near(0.4)]] and span["small"] == [0, 0.0]
+    bring_up(recorder, "uncached", ended, trace_s=0.1, lower_s=0.1, backend_s=0.1)  # no cache asked: no cache event
+    assert [(s["name"], s["cache"], s["compile_s"]) for s in program_ups(spans)][1] == ("uncached", "none", near(0.1))
+
+
+def test_same_named_nested_traces_add_up():
+    recorder, _registry, spans = own_recorder()
+    nested = [("wrapped", 0.1, 0.25), ("wrapped", 0.5, 0.5), ("dot", 1.2, 0.125)]
+    bring_up(recorder, "_step", time.time(), trace_s=2.0, lower_s=0.1, backend_s=0.1, nested=nested)
+    (span,) = program_ups(spans)
+    assert span["traced"] == [["_step", 1, near(1.125)], ["wrapped", 2, near(0.75)], ["dot", 1, near(0.125)]]
+    assert span["trace_s"] == near(2.0)  # the outermost trace alone: what it holds is inside it
+
+
+def test_self_time_is_the_interval_less_what_is_nested_in_it():
+    """A parent of 1.0 s holding children of 0.3 and 0.2 reads 0.5; a
+    grandchild comes off its parent, not off the root."""
+    recorder, _registry, spans = own_recorder()
+    nested = [("leaf", 0.15, 0.1), ("child_a", 0.1, 0.3), ("child_b", 0.6, 0.2)]  # as they end: leaf lies inside child_a
+    bring_up(recorder, "parent", time.time(), trace_s=1.0, lower_s=0.1, backend_s=0.1, nested=nested)
+    (span,) = program_ups(spans)
+    assert {name: (calls, self_s) for name, calls, self_s in span["traced"]} == {
+        "parent": (1, near(0.5)), "child_a": (1, near(0.2)), "child_b": (1, near(0.2)), "leaf": (1, near(0.1))}
+    assert len(span["traced"]) <= compile_cache.TRACED_KEPT
+    many = [(f"f{i}", 0.01 * i, 0.001 * (i + 1)) for i in range(20)]
+    bring_up(recorder, "wide", time.time(), trace_s=1.0, lower_s=0.1, backend_s=0.1, nested=many)
+    kept = program_ups(spans)[1]["traced"]
+    assert [row[0] for row in kept] == ["wide"] + [f"f{i}" for i in range(19, 12, -1)]  # the eight that cost most, costliest first
+
+
+def test_a_program_lowered_under_another_name_takes_the_outermost_trace():
+    """Not the longest by seconds: a stray trace that led to no program
+    (``jax.eval_shape``) may be longer than the program's own. What the
+    lowering itself traced (a kernel's body) is the program's too."""
+    recorder, registry, spans = own_recorder()
+    at = time.time()
+    recorder.on_span(TRACE, at, at + 5.0, fun_name="_init")  # eval_shape: no lowering follows
+    recorder.on_span(TRACE, at + 6.1, at + 6.3, fun_name="inner")
+    recorder.on_span(TRACE, at + 6.0, at + 7.0, fun_name="call_wrapped")
+    recorder.on_span(TRACE, at + 7.2, at + 7.3, fun_name="kernel_body")  # inside the lowering
+    recorder.on_span(LOWER, at + 7.1, at + 7.5, fun_name="pmap(step)")
+    recorder.on_span(BACKEND, at + 7.6, at + 7.7, fun_name="pmap(step)")
+    (span,) = program_ups(spans)
+    assert (span["name"], span["trace_s"]) == ("pmap(step)", near(1.0))
+    assert registry.counter("fishnet_compile_seconds_total", "", labelnames=("phase",)).value(phase="trace") == near(1.0)
+    assert [row[0] for row in span["traced"]] == ["call_wrapped", "inner", "kernel_body"]
+    assert span["t"] == near(at + 6.0 - spans.epoch_offset, within=1e-5)
+    # the stray was forgotten at the lowering: the next program does not find it
+    bring_up(recorder, "next", at + 8.0, trace_s=0.1, lower_s=0.1, backend_s=0.1)
+    assert [row[0] for row in program_ups(spans)[1]["traced"]] == ["next"]
+
+
+def test_a_lowering_that_is_never_compiled_is_recorded_at_the_next():
+    recorder, _registry, spans = own_recorder()
+    at = time.time()
+    recorder.on_span(TRACE, at, at + 0.5, fun_name="_step")
+    recorder.on_span(LOWER, at + 0.5, at + 1.0, fun_name="jit(_step)")  # ``.lower()`` alone
+    assert program_ups(spans) == []
+    bring_up(recorder, "other", at + 2.0, trace_s=0.1, lower_s=0.1, backend_s=0.1, cache=MISS)
+    first, second = program_ups(spans)
+    assert (first["name"], first["cache"], first["compile_s"], first["dur_ms"]) == ("_step", "none", 0.0, near(1000.0, within=1e-2))
+    assert (second["name"], second["cache"]) == ("other", "miss")
+    # a backend compile with no lowering on its thread (lowered elsewhere) is a program of that phase alone
+    recorder.on_span(BACKEND, at + 3.0, at + 3.5, fun_name="jit(elsewhere)")
+    last = program_ups(spans)[2]
+    assert (last["name"], last["trace_s"], last["lower_s"], last["compile_s"], last["traced"]) == ("elsewhere", 0.0, 0.0, near(0.5), [])
+
+
+def test_events_of_two_threads_interleaved_do_not_mix():
+    import threading
+
+    recorder, _registry, spans = own_recorder()
+    at = time.time()
+    turns = [threading.Semaphore(0), threading.Semaphore(0)]
+    totals = {}
+
+    def bring(me, name, scale):
+        """One event a turn, the other thread's next event between each two of mine."""
+        events = [
+            lambda: recorder.on_span(TRACE, at + 0.1, at + 0.1 + 0.2 * scale, fun_name="inner_" + name),
+            lambda: recorder.on_span(TRACE, at, at + 1.0 * scale, fun_name=name),
+            lambda: recorder.on_span(LOWER, at + 2.0, at + 2.0 + 0.5 * scale, fun_name=f"jit({name})"),
+            lambda: recorder.on_event(MISS if me else HIT),
+            lambda: recorder.on_duration(LOAD, 0.0 if me else 0.25),
+            lambda: recorder.on_span(BACKEND, at + 3.0, at + 3.0 + 1.0 * scale, fun_name=f"jit({name})"),
+        ]
+        mark = recorder.mark()
+        for event in events:
+            assert turns[me].acquire(timeout=30)
+            event()
+            turns[1 - me].release()
+        totals[name] = recorder.totals_since(mark)
+
+    threads = [threading.Thread(target=bring, args=(0, "first", 1.0), name="bringer-0"),
+               threading.Thread(target=bring, args=(1, "second", 2.0), name="bringer-1")]
+    for thread in threads:
+        thread.start()
+    turns[0].release()
+    for thread in threads:
+        thread.join(timeout=60)
+        assert not thread.is_alive()
+    first, second = sorted(program_ups(spans), key=lambda span: span["name"])
+    assert (first["thread"], first["cache"], first["trace_s"], first["cache_load_s"], first["compile_s"]) == (
+        "bringer-0", "hit", near(1.0), 0.25, near(0.75))
+    assert (second["thread"], second["cache"], second["trace_s"], second["cache_load_s"], second["compile_s"]) == (
+        "bringer-1", "miss", near(2.0), 0.0, near(2.0))
+    assert [row[0] for row in first["traced"]] == ["first", "inner_first"] and [row[0] for row in second["traced"]] == ["second", "inner_second"]
+    assert totals["first"] == {"compile_s": near(0.75), "cache_load_s": 0.25, "trace_lower_s": near(1.5), "cache_misses": 0}
+    assert totals["second"] == {"compile_s": near(2.0), "cache_load_s": 0.0, "trace_lower_s": near(3.0), "cache_misses": 1}
+
+
+def test_a_program_under_ten_milliseconds_is_counted_in_small_and_is_no_span():
+    recorder, registry, spans = own_recorder()
+    at = time.time()
+    for i in range(1000):  # a thousand eager one-op programs flood no ring
+        at = bring_up(recorder, "add", at, trace_s=0.001, lower_s=0.002, backend_s=0.004, cache=HIT if i % 2 else None, load_s=0.003)
+    assert program_ups(spans) == []
+    count, seconds = recorder.small()
+    assert count == 1000 and seconds == near(500 * 0.007 + 500 * 0.007)
+    assert registry.counter("fishnet_compile_seconds_total", "", labelnames=("phase",)).value(phase="trace") == near(1.0)  # counted all the same
+    bring_up(recorder, "add", at, trace_s=0.001, lower_s=0.002, backend_s=0.004, cache=MISS)  # a miss is a span whatever it took
+    bring_up(recorder, "slow_add", at + 1.0, trace_s=0.004, lower_s=0.004, backend_s=0.004)
+    missed, slow = program_ups(spans)
+    assert (missed["name"], missed["cache"], slow["name"], slow["cache"]) == ("add", "miss", "slow_add", "none")
+    assert missed["small"] == slow["small"] == [1000, near(7.0)] == recorder.small()  # as of each span's end
+
+
+def test_a_program_inside_a_startup_span_is_its_child(monkeypatch):
+    """``program_up`` inside an open ``train_init`` carries its ``span_id``
+    as ``parent_id``; one outside carries none. The start-up span's self
+    time is then its duration less its children's."""
+    from fishnet_tpu.train import startup
+
+    recorder, _registry, spans = own_recorder()
+    monkeypatch.setattr(compile_cache, "RECORDER", recorder)  # what configure_recorder() hands startup.py
+    monkeypatch.setattr(startup, "RECORDER", spans)
+    bring_up(recorder, "before", time.time(), trace_s=0.1, lower_s=0.1, backend_s=0.1)
+    with startup.init_span("az", layout_held_leaves=0):
+        now = time.time()
+        bring_up(recorder, "init", now - 0.5, trace_s=0.1, lower_s=0.1, backend_s=0.2, cache=MISS)
+        bring_up(recorder, "tiny", now, trace_s=0.001, lower_s=0.001, backend_s=0.001)
+    bring_up(recorder, "after", time.time(), trace_s=0.1, lower_s=0.1, backend_s=0.1)
+    by_name = {span.get("name", span["stage"]): span for span in spans.spans()}
+    init_span = by_name["train_init"]
+    assert by_name["init"]["parent_id"] == init_span["span_id"] and by_name["init"]["trace_id"] == init_span["trace_id"]
+    assert by_name["init"]["span_id"] != init_span["span_id"] and "parent_id" not in init_span
+    for outside in ("before", "after"):
+        assert not {"parent_id", "span_id", "trace_id"} & set(by_name[outside])
+    assert (init_span["trainer"], init_span["layout_held_leaves"], init_span["cache_misses"]) == ("az", 0, 1)
+    assert (init_span["compile_s"], init_span["trace_lower_s"]) == near((0.201, 0.202))  # the small one's seconds too
+    assert init_span["small_at_start"] == [0, 0.0] and init_span["small_at_end"] == [1, near(0.003)]
 
 
 # -- B. the start-up spans -------------------------------------------------------
@@ -316,6 +524,78 @@ def test_startup_spans_once_a_trainer_with_telemetry_disabled(kind):
     other, _ = make(kind)
     other.step(other.init(2), batch)
     assert [s["stage"] for s in mine()].count("train_first_step") == 2  # once a trainer INSTANCE
+
+
+def test_process_spans_once_a_process_however_many_trainers(monkeypatch, steps):
+    """``process_boot`` runs from the process's start (read off
+    ``/proc/self/stat``) to the package's first import, ``program_import``
+    from there to the first trainer's construction, and the two meet."""
+    import fishnet_tpu
+    from fishnet_tpu.train import startup
+
+    spans = SpanRecorder()
+    monkeypatch.setattr(startup, "RECORDER", spans)
+    monkeypatch.setattr(startup, "_process_spans_pending", True)
+    before = time.monotonic()
+    for kind in ("az", "nnue", "az"):
+        make(kind)
+    boot, imported = spans.spans()  # once, whatever was made after
+    assert (boot["stage"], imported["stage"]) == ("process_boot", "program_import")
+    assert boot["t"] + boot["dur_ms"] / 1e3 == pytest.approx(fishnet_tpu.FIRST_IMPORT, abs=2e-3) == pytest.approx(imported["t"], abs=1e-6)
+    assert before <= imported["t"] + imported["dur_ms"] / 1e3 <= time.monotonic()
+    assert 0 < boot["dur_ms"] / 1e3 < 3600 and boot["t"] < fishnet_tpu.FIRST_IMPORT  # this process's age, not the machine's
+    assert startup.process_started() == pytest.approx(boot["t"], abs=0.011)  # to a clock tick
+
+
+@pytest.mark.parametrize("stat", [None, "1 (python) S 1 2", "1 (a b) c) S " + " ".join(["0"] * 18) + " 99999999999999 0"])
+def test_process_boot_is_absent_not_zero_where_the_start_cannot_be_read(monkeypatch, steps, tmp_path, stat):
+    """No ``/proc``, a line too short, a start in the future: no
+    ``process_boot``; ``program_import`` needs none of it."""
+    import builtins
+
+    from fishnet_tpu.train import startup
+
+    real_open = builtins.open
+
+    def no_proc(path, *args, **kwargs):
+        if path != "/proc/self/stat":
+            return real_open(path, *args, **kwargs)
+        if stat is None:
+            raise FileNotFoundError(path)
+        (tmp_path / "stat").write_text(stat)
+        return real_open(tmp_path / "stat")
+
+    spans = SpanRecorder()
+    monkeypatch.setattr(startup, "RECORDER", spans)
+    monkeypatch.setattr(startup, "_process_spans_pending", True)
+    monkeypatch.setattr(builtins, "open", no_proc)
+    assert startup.process_started() is None
+    make("nnue")
+    assert [span["stage"] for span in spans.spans()] == ["program_import"]
+
+
+def test_the_exporter_lists_the_new_stages_under_spans():
+    import json
+    import urllib.request
+
+    from fishnet_tpu.telemetry.exporter import MetricsExporter
+
+    assert {"program_up", "process_boot", "program_import"} <= set(EVENT_STAGES)
+    trainer, batch = make("nnue")  # a process that has made and stepped a trainer
+    trainer.step(trainer.init(3), batch)
+    exporter = MetricsExporter(port=0)
+    try:
+        with urllib.request.urlopen(f"{exporter.url}/spans", timeout=10) as res:
+            served = json.loads(res.read())["spans"]
+    finally:
+        exporter.close()
+    stages = {span["stage"] for span in served}
+    assert {"program_import", "train_init", "train_first_step", "program_up"} <= stages
+    assert ("process_boot" in stages) == os.path.exists("/proc/self/stat")
+    step = next(span for span in reversed(served) if span["stage"] == "program_up" and span["name"] == "_step")
+    first_step = next(span for span in reversed(served) if span["stage"] == "train_first_step")
+    assert step["parent_id"] == first_step["span_id"] and step["traced"][0][0] == "_step"
+    assert {"name", "trace_s", "lower_s", "cache_load_s", "compile_s", "cache", "traced", "small"} <= set(step)
 
 
 # -- C. the step recorder ----------------------------------------------------------
