@@ -260,8 +260,14 @@ score tables of every board and head (80 KB each, 160 MiB a layer at the
 cell's batch) for its gradient kernel, which makes no solve of its own (the
 forward without a gradient writes o alone), and each kernel is called under
 one ``jax.jit``, so a program traces and lowers the pair once for all its
-KDA layers; the low-rank gates, softplus, beta, the gated head norm and the
-out-projection are XLA's under ``layerNN.kda`` beside it.
+KDA layers; after the core the head norm under its gate, ``N_d(o) *
+sigmoid(gate)``, is one more kernel pair under ``layerNN.kda``
+(``ops/mamba_mix.py``: ``head_norm_gate``, ``head_norm_gate_grad``, told
+``sigmoid``): it reads o as the core writes it and the gate's logits as
+their product writes them, makes the sigmoid itself and writes what the
+out-projection reads, so no ``[tokens, heads, d]`` view of o reaches HBM;
+the low-rank gates' products, softplus, beta and the out-projection are
+XLA's under ``layerNN.kda`` beside it.
 
 The seventh block is Qwen3-Next-80B-A3B's (Qwen, config.json,
 ``model_type`` qwen3_next: hidden 2048, 48 layers, three Gated DeltaNet
@@ -298,7 +304,11 @@ kernel reads its operand whole), the convolution is the fourth block's
 kernel pair told three widths and a zero bias, and the core is the second
 form of ``ops/board_delta.py``'s pair, told by ``g`` in ``beta``'s shape: q
 and k at the key heads and g, beta ``[boards, 64, V]`` as the layer makes
-them, nothing repeated a value head or broadcast a channel in HBM.
+them, nothing repeated a value head or broadcast a channel in HBM. After
+the core, ``N_d(o) * silu(z)`` is the sixth block's kernel pair
+(``head_norm_gate``, ``head_norm_gate_grad``) told ``silu``, under
+``layerNN.gdn``: o as the core writes it and z as its product writes it in,
+the out-projection's bfloat16 operand out, one pass each way.
 
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums
@@ -421,7 +431,7 @@ from fishnet_tpu.ops.board_delta import board_delta
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
 from fishnet_tpu.ops.expert_gate import expert_gate, expert_gate_grad, gated_activation, squared_relu
-from fishnet_tpu.ops.mamba_mix import _called, mamba_conv, mamba_gate_norm
+from fishnet_tpu.ops.mamba_mix import _called, head_norm_gate, mamba_conv, mamba_gate_norm
 from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_covered, rows_out, rows_out_dot, rows_sum
 
 Params = Dict[str, jax.Array]
@@ -1104,7 +1114,9 @@ def _kda(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple
     docstring, "Mechanism, the sixth block"). q, k and v are ONE product
     on the joined weights and ONE convolution with its silu (the fourth
     block's kernel pair, told three widths and a zero bias), whose three
-    bfloat16 results are the core's operands as they are."""
+    bfloat16 results are the core's operands as they are; the head norm
+    under the sigmoid of the low-rank gate is ``head_norm_gate``, which
+    takes the gate's logits."""
     heads, d, layer, tokens = cfg.kda_heads, cfg.kda_head_dim, sublayer.layer, x.shape[0]
     inner = heads * d
     with jax.named_scope(f"{layer}.kda"):
@@ -1119,9 +1131,9 @@ def _kda(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple
     with jax.named_scope(f"{layer}.delta"):
         o = board_delta(q, k, v, _by_board(g), _by_board(beta), _interpret())
     with jax.named_scope(f"{layer}.kda"):
-        gate = jax.nn.sigmoid(_matmul(_matmul(n, p["kda_ga"]), p["kda_gb"]))
-        normed = _rms_norm(o.reshape(tokens, heads, d), p["kda_o_norm"], cfg.rms_eps)  # a head's own norm, one gain for all heads
-        return _matmul(normed.reshape(tokens, inner) * gate, p["kda_out"]), counters
+        gate = _matmul(_matmul(n, p["kda_ga"]), p["kda_gb"])  # the logits: the sigmoid is the kernel's
+        normed = head_norm_gate(o.reshape(tokens, inner), gate, p["kda_o_norm"], "sigmoid", cfg.rms_eps, _interpret())  # a head's own norm, one gain for all heads
+        return _matmul(normed, p["kda_out"]), counters
 
 
 def _gdn(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -1135,7 +1147,8 @@ def _gdn(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple
     q, k and v are ONE product and ONE convolution with its silu whose
     three bfloat16 results (q and k at the key heads, v at the value
     heads) are the core's operands as they are; ``g`` and ``beta`` go to
-    it as they are made, one a value head and token."""
+    it as they are made, one a value head and token; the head norm under
+    ``silu(z)`` is ``head_norm_gate``."""
     heads, d, layer, tokens = cfg.linear_num_value_heads, cfg.linear_value_head_dim, sublayer.layer, x.shape[0]
     key, value = cfg.linear_num_key_heads * cfg.linear_key_head_dim, heads * d
     with jax.named_scope(f"{layer}.gdn"):
@@ -1151,8 +1164,8 @@ def _gdn(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple
     with jax.named_scope(f"{layer}.delta"):
         o = board_delta(q, k, v, _by_board(g), _by_board(beta), _interpret())
     with jax.named_scope(f"{layer}.gdn"):
-        normed = _rms_norm(o.reshape(tokens, heads, d), p["gdn_o_norm"], cfg.rms_eps)  # a head's own norm, one plain gain for all heads, BEFORE the gate
-        return _matmul((normed * jax.nn.silu(z.reshape(tokens, heads, d))).reshape(tokens, value), p["gdn_out"]), counters
+        normed = head_norm_gate(o.reshape(tokens, value), z, p["gdn_o_norm"], "silu", cfg.rms_eps, _interpret())  # a head's own norm, one plain gain for all heads, BEFORE the gate
+        return _matmul(normed, p["gdn_out"]), counters
 
 
 def _interpret() -> bool:

@@ -1,10 +1,11 @@
 """Pallas TPU kernels for the two float32 chains of a Mamba-2 mixer over
 the 64 squares of a board (``models/trunk.py _mamba``), one on each side
 of the scan (``ops/board_scan.py``): the convolution with its silu, and
-the gate with its grouped norm. Each is a memory-bound pass that reads
-its operands once and writes its results once, a few boards in VMEM a
-grid step; left to XLA each was a dozen float32 passes (PERF.md section
-6, PR 44).
+the gate with its grouped norm; and, since PR 55, for the chain after the
+core of the two delta mixers (``_gdn``, ``_kda``): each head's norm under
+its gate. Each is a memory-bound pass that reads its operands once and
+writes its results once, a few boards in VMEM a grid step; left to XLA
+each was a dozen float32 passes (PERF.md section 6, PR 44 and PR 55).
 
 ``mamba_conv(u, conv_w, conv_b, widths)`` with ``u`` float32 ``[boards,
 64, columns]``, the x B C product's result as it is written, ``conv_w``
@@ -48,10 +49,37 @@ and writes y's cotangent (bfloat16, what ``board_scan_grad`` takes) and
 z's (rounded to bfloat16, below) once; the gain's gradient is resident
 across the grid as the taps' is.
 
+``head_norm_gate(o, z, gain, gate, eps)`` with ``o`` bfloat16 ``[tokens,
+heads x d]`` (the delta rule's result as ``ops/board_delta.py`` writes
+it), ``z`` float32 ``[tokens, heads x d]`` (a product's result:
+``gdn_qkvz``'s z columns; the logits ``(n W_ga) W_gb`` of KDA's low-rank
+gate), ONE ``gain`` ``[d]`` for all heads and ``gate`` ``"silu"`` (Gated
+DeltaNet) or ``"sigmoid"`` (Kimi Delta Attention) gives what the
+out-projection reads, bfloat16 ``[tokens, heads x d]``. A different
+equation of different published blocks than the pair above (the norm
+BEFORE the gate, a head its own group, one gain), so a pair of its own
+that shares the grid, the row loop and the helpers, and no body::
+
+    n   = o * rsqrt(mean over the head's d columns of o^2 + eps) * gain     float32; at d = 128 a head is ONE lane tile
+    out = n * silu(z)      or      n * sigmoid(z)                           rounded once to bfloat16
+
+The heads are found from the shapes (``heads x d`` columns under a gain of
+``d``); the bodies read the gain laid along them, a gain a column, so that
+the grid, the blocks and their checks are ``mamba_gate_norm``'s; inside a
+grid step the bodies work 32 rows at a time and eight heads a turn of a
+loop over the heads (``_HEAD_ROWS``, ``_HEADS_A_TURN``: all heads unrolled
+ran no faster and cost seconds of every start).
+``head_norm_gate_grad`` reads o, z and the result's bfloat16 cotangent
+once, makes ``n`` and the gate again (the one thing recomputed), and
+writes o's cotangent (bfloat16, what ``board_delta_grad`` takes) and z's
+(rounded to bfloat16, below) once; the gain's gradient is resident across
+the grid, partial rows ``[8, heads x d]``, summed over the 8 and over the
+heads outside.
+
 Every value is rounded where JAX's own formula and its transposes round
-it: the arithmetic is float32, the results that a bfloat16 product or
-the scan reads are bfloat16, and so are their cotangents. **The
-cotangents of ``u`` and of ``z``** are float32 in name (the gradient
+it: the arithmetic is float32, the results that a bfloat16 product, the
+scan or the delta rule reads are bfloat16, and so are their cotangents.
+**The cotangents of ``u`` and of ``z``** (both pairs' ``z``) are float32 in name (the gradient
 rules return them so, as a float32 operand's must be) and bfloat16 in
 value: each is the result of a product with bfloat16 operands
 (``trunk._matmul``), and all that reads its cotangent are that product's
@@ -59,7 +87,10 @@ two transposes, which round it to bfloat16 first. The kernels write it
 rounded, once, in half the bytes; XLA folds the widening and the
 products' narrowing away and the products read the kernels' arrays as
 they are (``tests/test_trunk_tpu_compile.py``). A caller that feeds ``u``
-or ``z`` from anything but such a product gets its cotangent to 8 bits.
+or ``z`` from anything but such a product gets its cotangent to 8 bits;
+all three callers meet it: ``_mamba``'s z and ``_gdn``'s are columns of
+the layer's in-projection, ``_kda``'s the second product of its low-rank
+gate.
 The kernels
 take every width that is whole 128-lane tiles (a group too); off the TPU
 they run under the Pallas interpreter, which takes any.
@@ -79,7 +110,7 @@ from jax.experimental.pallas import tpu as pltpu
 from fishnet_tpu.ops.board_attention import SQUARES
 from fishnet_tpu.ops.cca_mix import _earlier, _later  # a shift along the squares: a rotation of sublanes and a select, here of ONE board's rows
 
-__all__ = ["mamba_conv", "mamba_gate_norm"]
+__all__ = ["head_norm_gate", "mamba_conv", "mamba_gate_norm"]
 
 _LANES = 128
 #: Boards a grid step of the convolution pair: at 6,144 columns the gradient's blocks (u and its cotangent float32, three
@@ -255,9 +286,9 @@ def _mamba_conv_bwd(widths, interpret, residuals, cotangents):
 mamba_conv.defvjp(_mamba_conv_fwd, _mamba_conv_bwd)
 
 
-def _row_chunks(rows: int, body) -> None:
-    """``body(rows)`` for every ``_NORM_CHUNK`` rows of a block, in turn."""
-    chunk = math.gcd(rows, _NORM_CHUNK)
+def _row_chunks(rows: int, body, at_a_time: int = 0) -> None:
+    """``body(rows)`` for every ``_NORM_CHUNK`` (or ``at_a_time``) rows of a block, in turn."""
+    chunk = math.gcd(rows, at_a_time or _NORM_CHUNK)
 
     def turn(at, _):
         body(pl.ds(pl.multiple_of(at * chunk, chunk), chunk))
@@ -309,11 +340,11 @@ def _gate_norm_grad_kernel(y_ref, z_ref, gain_ref, d_ref, dy_ref, dz_ref, dgain_
     _row_chunks(y_ref.shape[0], body)
 
 
-def _norm_specs(y: jax.Array, z: jax.Array, gain: jax.Array, groups: int, interpret: bool):
+def _norm_specs(y: jax.Array, z: jax.Array, gain: jax.Array, groups: int, interpret: bool, name: str = "mamba_gate_norm"):
     tokens, inner = y.shape
     if z.shape != y.shape or gain.shape != (inner,) or inner % groups:
-        raise ValueError(f"mamba_gate_norm: y {y.shape}, z {z.shape} and gain {gain.shape} are not [tokens, inner] twice and [inner] in {groups} groups")
-    _whole_tiles("mamba_gate_norm", interpret, inner // groups)
+        raise ValueError(f"{name}: y {y.shape}, z {z.shape} and gain {gain.shape} are not [tokens, inner] twice and [inner] in {groups} groups")
+    _whole_tiles(name, interpret, inner // groups)
     rows = math.gcd(tokens, _NORM_ROWS)
     return tokens // rows, pl.BlockSpec((rows, inner), lambda i: (i, 0)), _whole(1, inner)
 
@@ -366,3 +397,131 @@ def _mamba_gate_norm_bwd(groups, eps, interpret, residuals, d):
 
 
 mamba_gate_norm.defvjp(_mamba_gate_norm_fwd, _mamba_gate_norm_bwd)
+
+
+# -- the head norm under its gate: the delta mixers' (``trunk._gdn``, ``trunk._kda``) ------------------------------------------------
+
+_GATES = ("silu", "sigmoid")
+#: Heads unrolled a turn of the loop over a block's heads, and rows the pair works at a time. A loop's turns do not overlap, so a turn
+#: has to hold enough independent heads and rows to hide a sum across lanes, and every unrolled head is traced and lowered at every
+#: start. On a v5e, ``[8192, 4096]`` in 32 heads, forward + gradient ms (PERF.md section 6, PR 55): all 32 heads unrolled at 16 rows
+#: 0.387 + 0.499, and 3.2 s more of ``setup_s`` than the parent's XLA lines; 8 heads at 32 rows 0.391 + 0.504 for a quarter of the
+#: trace; 8 at 16 rows 0.417 + 0.591, 4 at 64 0.397 + 0.504, 4 at 16 0.510 + 0.939, 1 at 16 1.811 + 3.111.
+_HEADS_A_TURN = 8
+_HEAD_ROWS = 32
+_head_jit = functools.partial(jax.jit, static_argnames=("gate", "eps", "interpret"))
+
+
+def _head_normed(o_ref, z_ref, gain_ref, rows, lanes, gate: str, eps: float):
+    """A head's ``o r`` with ``r = rsqrt(mean o^2 + eps)`` ``[rows, 1]``, ``r``, ``n = o r gain``, ``z``, ``sigmoid(z)`` and the gate's value."""
+    o, z = o_ref[rows, lanes].astype(jnp.float32), z_ref[rows, lanes]
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    s = jax.nn.sigmoid(z)
+    unit = o * r
+    return unit, r, unit * gain_ref[:, lanes], z, s, (z * s if gate == "silu" else s)
+
+
+def _head_turns(heads: int, width: int, body) -> None:
+    """``body(lanes)`` for every head of a block's columns: ``_HEADS_A_TURN`` heads unrolled a turn of a loop over the rest, at
+    dynamic offsets of whole heads (tile-aligned at a head of whole lane tiles)."""
+    unrolled = math.gcd(heads, _HEADS_A_TURN)
+
+    def turn(at, _):
+        for head in range(unrolled):
+            body(pl.ds(pl.multiple_of((at * unrolled + head) * width, width), width))
+        return 0
+
+    jax.lax.fori_loop(0, heads // unrolled, turn, 0)
+
+
+def _head_norm_kernel(o_ref, z_ref, gain_ref, out_ref, *, heads: int, gate: str, eps: float):
+    def body(rows):
+        def head(lanes):
+            _, _, n, _, _, a = _head_normed(o_ref, z_ref, gain_ref, rows, lanes, gate, eps)
+            out_ref[rows, lanes] = (n * a).astype(out_ref.dtype)
+
+        _head_turns(heads, o_ref.shape[1] // heads, head)
+
+    _row_chunks(o_ref.shape[0], body, _HEAD_ROWS)
+
+
+def _head_norm_grad_kernel(o_ref, z_ref, gain_ref, d_ref, do_ref, dz_ref, dgain_ref, *, heads: int, gate: str, eps: float):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dgain_ref[...] = jnp.zeros(dgain_ref.shape, jnp.float32)
+
+    def body(rows):
+        def head(lanes):
+            unit, r, n, z, s, a = _head_normed(o_ref, z_ref, gain_ref, rows, lanes, gate, eps)
+            d = d_ref[rows, lanes].astype(jnp.float32)
+            slope = s * (1.0 + z * (1.0 - s)) if gate == "silu" else s * (1.0 - s)  # silu'(z) = s + z s (1 - s); sigmoid'(z) = s (1 - s)
+            dz_ref[rows, lanes] = (d * n * slope).astype(dz_ref.dtype)
+            dn = d * a
+            dgain_ref[:, lanes] = dgain_ref[:, lanes] + _partial_rows(dn * unit)
+            du = dn * gain_ref[:, lanes]
+            do_ref[rows, lanes] = (r * (du - unit * jnp.mean(du * unit, axis=-1, keepdims=True))).astype(do_ref.dtype)
+
+        _head_turns(heads, o_ref.shape[1] // heads, head)
+
+    _row_chunks(o_ref.shape[0], body, _HEAD_ROWS)
+
+
+def _by_head(o: jax.Array, gain: jax.Array, gate: str):
+    """The heads, and the one gain laid along them: a gain a column, as ``_norm_specs`` checks it and the bodies read it."""
+    if gate not in _GATES or o.ndim != 2 or gain.ndim != 1 or o.shape[1] % gain.shape[0]:
+        raise ValueError(f"head_norm_gate: o {o.shape} under a gain {gain.shape} and the gate {gate!r} is not [tokens, heads x d] under [d] and one of {_GATES}")
+    heads = o.shape[1] // gain.shape[0]
+    return heads, jnp.tile(gain.astype(jnp.float32), heads)
+
+
+@_head_jit
+def _head_norm_call(o, z, gain, *, gate: str, eps: float, interpret: bool):
+    heads, gains = _by_head(o, gain, gate)
+    steps, by_rows, whole = _norm_specs(o, z, gains, heads, interpret, "head_norm_gate")
+    return pl.pallas_call(
+        functools.partial(_head_norm_kernel, heads=heads, gate=gate, eps=eps),
+        grid=(steps,),
+        in_specs=[by_rows, by_rows, whole],
+        out_specs=by_rows,
+        out_shape=jax.ShapeDtypeStruct(o.shape, jnp.bfloat16),
+        compiler_params=_PARAMS,
+        name="head_norm_gate",
+        interpret=interpret,
+    )(o.astype(jnp.bfloat16), z.astype(jnp.float32), gains.reshape(1, -1))
+
+
+@_head_jit
+def _head_norm_grad_call(o, z, gain, d, *, gate: str, eps: float, interpret: bool):
+    heads, gains = _by_head(o, gain, gate)
+    steps, by_rows, whole = _norm_specs(o, z, gains, heads, interpret, "head_norm_gate")
+    do, dz, dgain = pl.pallas_call(
+        functools.partial(_head_norm_grad_kernel, heads=heads, gate=gate, eps=eps),
+        grid=(steps,),
+        in_specs=[by_rows, by_rows, whole, by_rows],
+        out_specs=[by_rows, by_rows, _whole(_SUBLANES, o.shape[1])],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, jnp.bfloat16), jax.ShapeDtypeStruct(z.shape, jnp.bfloat16), jax.ShapeDtypeStruct((_SUBLANES, o.shape[1]), jnp.float32)],
+        compiler_params=_PARAMS,
+        name="head_norm_gate_grad",
+        interpret=interpret,
+    )(o.astype(jnp.bfloat16), z.astype(jnp.float32), gains.reshape(1, -1), d.astype(jnp.bfloat16))
+    return do.astype(o.dtype), dz.astype(z.dtype), dgain.reshape(-1, gain.shape[0]).sum(axis=0).astype(gain.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def head_norm_gate(o: jax.Array, z: jax.Array, gain: jax.Array, gate: str, eps: float, interpret: bool = False) -> jax.Array:
+    """Each head's RMS norm of ``o`` under the one ``gain``, times
+    ``silu(z)`` or ``sigmoid(z)`` (module docstring): ``o`` bfloat16 and
+    ``z`` float32 ``[tokens, heads x d]``, ``gain`` ``[d]`` -> bfloat16
+    ``[tokens, heads x d]``."""
+    return _called(_head_norm_call, interpret)(o, z, gain, gate=gate, eps=eps, interpret=interpret)
+
+
+def _head_norm_gate_fwd(o, z, gain, gate, eps, interpret):
+    return head_norm_gate(o, z, gain, gate, eps, interpret), (o, z, gain)
+
+
+def _head_norm_gate_bwd(gate, eps, interpret, residuals, d):
+    return _called(_head_norm_grad_call, interpret)(*residuals, d, gate=gate, eps=eps, interpret=interpret)
+
+
+head_norm_gate.defvjp(_head_norm_gate_fwd, _head_norm_gate_bwd)

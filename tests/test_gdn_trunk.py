@@ -169,14 +169,16 @@ def test_the_expert_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_
 # -- the step pins: the seventh block's own, and the sixth's, whose kernel pair shares ``ops/board_delta.py`` with it -------------------
 
 #: sha256 of the tiny lowered step programs (``tools/step_text.py --block gdn|kda``), as ``tests/test_hybrid_trunk.py
-#: PARENT_STEP_SHA256`` holds the four older blocks'. Both read on PR 53's tree, which MEANT to move both: the forward kernels solve two
-#: chains a product and level 0 without one (``ops/board_delta.py _solve``). Its parent (be60ccd) read ``kda`` bbe2e709... and ``gdn``
-#: 15ad7e3f..., the pins as PR 51 left them; the ``--no-ids`` dumps of parent and change differ inside the forward kernel's body of each
-#: delta layer (four of 954 lines that now have 1,690; three of 1,027 that now have 681), in the bound of its boards' loop (8 turns, now 4
-#: pairs: ``kda``) and in the ``jnp.where`` helpers those bodies call (a ``[64, 128]`` and a ``[128, 128]`` select more; ``gdn``'s ``[64,
-#: 64]`` masked decay now ``[64, 128]``), and nowhere else: every gradient kernel's body (four of 1,040 lines, three of 1,059) is line for
-#: line the parent's. A PR that means to change either reads its own parent the same way.
-GDN_STEP_SHA256 = {"kda": "dc1e7fa9525c9d1eab1f458a4105d982b2b5542c7baeaa3bc2513967d1ba76ee", "gdn": "776060adb956e1725db24d573c3795f504ef3f3cb02216c8a078ba9860b4ca7e"}
+#: PARENT_STEP_SHA256`` holds the four older blocks'. Both read on PR 55's tree, which MEANT to move both: the head norm under its gate
+#: after the core is one kernel pair (``ops/mamba_mix.py head_norm_gate``), whose two bodies the interpreter lays into the step as loops.
+#: Its parent (4fccc6c) read ``kda`` dc1e7fa9... and ``gdn`` 776060ad..., the pins as PR 53 left them (that PR MEANT to move both too: two
+#: chains a product in the forward solve). The ``--no-ids`` dumps of parent and change differ in every delta layer and in the numbering of
+#: what follows: the one ``rsqrt`` a layer of ``_rms_norm`` over the ``[tokens, heads, d]`` view and its transpose give way to an ``rsqrt``
+#: a head and body (30 -> 51 in ``gdn``'s dump, three layers of four heads; 40 -> 52 in ``kda``'s, four of two) inside six loops more a
+#: layer (the grid, the rows and the heads' turns, forward and gradient: 96 -> 114 and 110 -> 134 ``stablehlo.while``); ``hybrid``, whose mixer runs the
+#: file's four older kernels, reads what it read (``tests/test_hybrid_trunk.py``). A PR that means to change either reads its own parent the
+#: same way.
+GDN_STEP_SHA256 = {"kda": "2e221aae44ec95f39a1ef1ccecb6a4c8720815b460b6217b41ac0fc3120f9b0a", "gdn": "323fe50e85ccc08b5c979ffac60946b86dbb86bf1c0d41a9e7f00725ab464491"}
 
 
 @pytest.mark.parametrize("block", GDN_STEP_SHA256)

@@ -802,6 +802,86 @@ def test_the_fifth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     assert ".remat" not in text
 
 
+# -- the delta mixers' gated head norm (PR 55): ONE kernel pair for the sixth and the seventh block, 128 boards of a head of 128 ----------
+
+HEAD_NORMS = {"gdn": (32, "silu"), "kda": (16, "sigmoid")}  # gdn_trunk_train_b128: 32 value heads under silu(z); kda_trunk_train_b128: 16 held heads under sigmoid(gate)
+
+
+@pytest.mark.parametrize("heads,gate", HEAD_NORMS.values(), ids=HEAD_NORMS)
+def test_the_gated_head_norm_pair_compiles_at_published_widths(one_chip, compiled_for_tpu, heads, gate):
+    """``head_norm_gate`` / ``head_norm_gate_grad`` on a batch's 8,192 tokens: ``[8192, 4096]`` in 32 heads under ``silu``,
+    ``[8192, 2048]`` in 16 under ``sigmoid``; 128 rows a grid step twice buffered beside the gain's resident gradient, 8 heads of 32 rows a turn
+    at dynamic, tile-aligned lane offsets."""
+    from fishnet_tpu.ops.mamba_mix import head_norm_gate
+
+    sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    loss = lambda *a: jnp.sum(jnp.square(head_norm_gate(*a, gate, 1e-6, False).astype(jnp.float32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(sds((8192, heads * 128), jnp.bfloat16), sds((8192, heads * 128)), sds((128,))).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("head_norm_gate_grad" in kernel for kernel in kernels) == 1 and all("head_norm_gate" in kernel for kernel in kernels), kernels
+
+
+def _xla_passes_over_a_head_norm(text: str, heads: int):
+    """The instructions of a compiled module's entry computation, under a ``layerNN.gdn`` / ``layerNN.kda`` scope or under none,
+    whose result is the ``[8192, heads, 128]`` view of a delta mixer's o, its relayout ``[1024, 8, heads, 128]`` or a float32
+    ``[128, 64, heads x 128]``, and that XLA itself computes: neither a kernel, nor a view or an asynchronous move of another's
+    result. Until PR 55 a layer held a dozen: a convert, three relayout copies, two broadcasts written out, two reshape copies."""
+    import re
+
+    shaped = re.compile(rf"= \(?(?:\S*\[(?:8192,{heads},128|1024,8,{heads},128)\]|f32\[128,64,{heads * 128}\])")
+    entry = text[re.search(r"^ENTRY ", text, re.M).start():]
+    return [line for line in entry.splitlines() if shaped.search(line) and (re.search(r"layer\d+\.(?:gdn|kda)", line) or "op_name=" not in line)
+            and not re.search(r" (custom-call|get-tuple-element|bitcast|copy-start|copy-done|slice-start|slice-done)\(", line)]
+
+
+def _delta_trainer(block: str):
+    """The trainer of a delta cell from its configuration file, as its family makes it."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    name, family = {"gdn": ("qwen3-next-trunk-train", "gdn_trunk"), "kda": ("kimi-linear-trunk-train", "kda_trunk")}[block]
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / f"{name}.json").read_text())
+    assert config["train"]["batch"] == 128
+    return importlib.import_module(f"benchmark.families.{family}").make_trainer(config)
+
+
+@pytest.mark.parametrize("block", HEAD_NORMS)
+def test_a_delta_mixer_at_published_widths_is_its_products_the_core_and_three_kernel_pairs(one_chip, compiled_for_tpu, block):
+    """``value_and_grad`` of ``_gdn`` and of ``_kda`` as a step runs them (one norm, the residual) on 128 boards, from the
+    cells' configuration files: exactly six kernels, and no XLA pass over the ``[tokens, heads, 128]`` view of o or a float32
+    copy of it. The gradient kernel's ``d_o`` and ``d_z`` leave it bfloat16: ``board_delta_grad`` reads the first through a
+    view, the transposed products of z's product read the second as it is, and nothing widens either on the way."""
+    import re
+
+    cfg = _delta_trainer(block).cfg
+    mixer, heads = next(s for s in trunk.trunk_plan(cfg) if s.kind == block), HEAD_NORMS[block][0]
+    layer = _sublayer_shapes(cfg, mixer, one_chip)
+    assert set(layer) == {"attn_norm", *trunk._OWNS[block]} and layer[f"{block}_o_norm"].shape == (128,)
+
+    def loss(x, p):
+        return jnp.sum(jnp.square(x + trunk._KINDS[block][0](x, p, cfg, mixer)[0]))  # a cotangent that waits for the result
+
+    x = jax.ShapeDtypeStruct((128 * trunk.SQUARES, cfg.hidden), jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, layer).compile().as_text()
+    named = ((re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line), line) for line in text.splitlines())
+    lines = {name.group(1): line for name, line in named if name}
+    kernels = sorted(name.split(".")[0] for name, line in lines.items() if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == ["board_delta", "board_delta_grad", "head_norm_gate", "head_norm_gate_grad", "mamba_conv", "mamba_conv_grad"], kernels
+    assert f"bf16[128,64,{heads * 128}]" in text and f"f32[8192,{heads * 128}]" in text  # o and z are there to be looked for
+    assert not _xla_passes_over_a_head_norm(text, heads), _xla_passes_over_a_head_norm(text, heads)[:3]
+    (gradient,) = [name for name in lines if name.startswith("head_norm_gate_grad")]
+    wide = f"bf16[8192,{heads * 128}]"
+    assert re.search(rf"= \({re.escape(wide)}\S*, {re.escape(wide)}\S*, f32\[8,{heads * 128}\]", lines[gradient]), lines[gradient][:300]
+    results = {int(re.search(r"index=(\d)", line).group(1)): name for name, line in lines.items() if f" get-tuple-element(%{gradient})" in line}
+    reads = lambda name: [reader for reader, line in lines.items() if re.search(rf"[(, ]%{re.escape(name)}[,)]", line.split(" = ", 1)[1])]
+    views = [reader for reader in reads(results[0]) if " bitcast(" in lines[reader]]
+    assert any("board_delta_grad" in reader for view in views for reader in reads(view)), (views, reads(results[0]))  # d_o: a view, then the core's gradient
+    products = _opcodes_fused_with_a_product(text)
+    readers = [reader for reader in reads(results[1]) if "-start(" not in lines[reader]]  # but XLA's own prefetch of it
+    assert readers and all(reader in products for reader in readers), [lines[reader][:200] for reader in readers]  # d_z: the transposed products, nothing between
+
+
 # -- the sixth block (kimi_linear) at its published widths: hidden 2304 = 18 x 128, 16 held heads of 128 a mixer ------------------------
 
 KDA_BOARDS = 128  # kda_trunk_train_b128
@@ -860,24 +940,34 @@ def test_the_delta_kernel_pair_compiles_at_published_widths(one_chip, compiled_f
     assert " = bf16[128,64,2048]" in alone and "board_delta" in alone.split(" = ")[0], alone[:200]  # one kernel, one result: no tuple
 
 
-def _delta_bodies_entered(monkeypatch, bodies=("_forward_kernel", "_backward_kernel")):
-    """A counter laid over each of the kernel ``bodies`` of ``ops/board_delta.py`` -> how often Python entered each, so far. The pair's
-    jitted calls forget the traces they hold (of another test of this process), so the next program traces its own."""
-    from fishnet_tpu.ops import board_delta
-
+def _bodies_entered(monkeypatch, module, bodies, calls):
+    """A counter laid over each of the kernel ``bodies`` of ``module`` -> how often Python entered each, so far. The jitted ``calls``
+    that hold them forget the traces they hold (of another test of this process), so the next program traces its own."""
     entered = dict.fromkeys(bodies, 0)
 
     def counting(name, body):
-        def counted(*refs):
+        def counted(*refs, **static):
             entered[name] += 1
-            return body(*refs)
+            return body(*refs, **static)
         return counted
 
     for name in entered:
-        monkeypatch.setattr(board_delta, name, counting(name, getattr(board_delta, name)))
-    board_delta._forward_call.clear_cache()
-    board_delta._gradient_call.clear_cache()
+        monkeypatch.setattr(module, name, counting(name, getattr(module, name)))
+    for call in calls:
+        getattr(module, call).clear_cache()
     return entered
+
+
+def _delta_bodies_entered(monkeypatch, bodies=("_forward_kernel", "_backward_kernel")):
+    from fishnet_tpu.ops import board_delta
+
+    return _bodies_entered(monkeypatch, board_delta, bodies, ("_forward_call", "_gradient_call"))
+
+
+def _head_norm_bodies_entered(monkeypatch):
+    from fishnet_tpu.ops import mamba_mix
+
+    return _bodies_entered(monkeypatch, mamba_mix, ("_head_norm_kernel", "_head_norm_grad_kernel"), ("_head_norm_call", "_head_norm_grad_call"))
 
 
 def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu, monkeypatch):
@@ -901,16 +991,18 @@ def test_the_sixth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
     assert sum(v.size for v in state.params.values()) == 416_608_910  # the configuration file's reckoning
     batch = {"planes": jnp.zeros((KDA_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((KDA_BOARDS, 4672)), "value_target": jnp.zeros((KDA_BOARDS,))}
-    entered = _delta_bodies_entered(monkeypatch)
+    entered, normed = _delta_bodies_entered(monkeypatch), _head_norm_bodies_entered(monkeypatch)
     lowered = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch))
     # the start-up tripwire: four KDA layers, ONE trace of each kernel body (4 and 4 for bare calls; ``tests/test_board_delta.py`` holds the boards' loop rolled)
-    assert entered == {"_forward_kernel": 1, "_backward_kernel": 1}, entered
+    assert entered == {"_forward_kernel": 1, "_backward_kernel": 1} and normed == {"_head_norm_kernel": 1, "_head_norm_grad_kernel": 1}, (entered, normed)
     compiled = lowered.compile()
     text = compiled.as_text()
     assert not trainer._held and not _copies_of_state_arguments(text)  # whole-lane widths: the client's default is the step's layout
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("board_delta_grad" in n for n in names) == 4 and sum("board_delta" in n for n in names) == 8, names
     assert sum("mamba_conv_grad" in n for n in names) == 4 and sum("mamba_conv" in n for n in names) == 8
+    assert sum("head_norm_gate_grad" in n for n in names) == 4 and sum("head_norm_gate" in n for n in names) == 8  # since PR 55: once a KDA layer
+    assert not _xla_passes_over_a_head_norm(text, cfg.kda_heads), _xla_passes_over_a_head_norm(text, cfg.kda_heads)[:3]
     assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
     for phase in ("jvp(forward)", "transpose(jvp(forward))"):  # the core's scope beside the mixer's, the latent's beside the attention's
         assert all(f"{phase}/layer0{i}.delta/" in text and f"{phase}/layer0{i}.kda/" in text for i in (0, 1, 2, 4)) and f"{phase}/layer03.delta/" not in text
@@ -1033,7 +1125,7 @@ def test_gated_attention_at_a_head_of_256_with_64_columns_turned_compiles_at_pub
     kernels, no scores kept, no per-head copy."""
     import re
 
-    cfg = _gdn_trainer().cfg
+    cfg = _delta_trainer("gdn").cfg
     sublayer = next(s for s in trunk.trunk_plan(cfg) if s.kind == "attention")
     assert (sublayer.layer, sublayer.rope, cfg.head_dim, cfg.heads, cfg.kv_heads, cfg.rotary_dim) == ("layer03", True, 256, 16, 2, 64)
     layer = _sublayer_shapes(cfg, sublayer, one_chip)
@@ -1050,23 +1142,13 @@ def test_gated_attention_at_a_head_of_256_with_64_columns_turned_compiles_at_pub
     assert not per_head, per_head[:2]
 
 
-def _gdn_trainer():
-    import importlib
-    import json
-    from pathlib import Path
-
-    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "qwen3-next-trunk-train.json").read_text())
-    assert config["train"]["batch"] == GDN_BOARDS
-    return importlib.import_module("benchmark.families.gdn_trunk").make_trainer(config)
-
-
 def test_the_seventh_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu, monkeypatch):
     """The whole step of ``gdn_trunk_train_b128`` from its configuration file: the delta pair (its second form) and the
     convolution pair a GDN layer (three), the grouped normed attention pair at a head of 256 on the one attention layer, no
     leaf held off row-major, nothing remade to fit. Lowering it enters each of the second form's kernel bodies ONCE and the
     first form's never: the three layers share the trace of a jitted call, which ``setup_s`` pays at every start. The
     operands of every delta call are the layer's own arrays: q and k at 16 key heads, g and beta ``[128, 64, 32]``."""
-    trainer = _gdn_trainer()
+    trainer = _delta_trainer("gdn")
     cfg = trainer.cfg
     assert (cfg.mixers, cfg.linear_num_key_heads, cfg.linear_num_value_heads, cfg.head_dim, cfg.hidden) == (("gdn", "gdn", "gdn", "attention"), 16, 32, 256, 2048)
     on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
@@ -1074,14 +1156,18 @@ def test_the_seventh_blocks_step_compiles_at_published_widths_and_fits_one_chip(
     assert sum(v.size for v in state.params.values()) == 346_814_094  # the configuration file's reckoning
     batch = {"planes": jnp.zeros((GDN_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((GDN_BOARDS, 4672)), "value_target": jnp.zeros((GDN_BOARDS,))}
     entered = _delta_bodies_entered(monkeypatch, ("_head_forward_kernel", "_head_backward_kernel", "_forward_kernel", "_backward_kernel"))
+    normed = _head_norm_bodies_entered(monkeypatch)
     lowered = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch))
     assert entered == {"_head_forward_kernel": 1, "_head_backward_kernel": 1, "_forward_kernel": 0, "_backward_kernel": 0}, entered
+    assert normed == {"_head_norm_kernel": 1, "_head_norm_grad_kernel": 1}, normed  # three GDN layers, ONE trace of each body of the head norm's pair
     compiled = lowered.compile()
     text = compiled.as_text()
     assert not trainer._held and not _copies_of_state_arguments(text)
     names = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("board_delta_grad" in n for n in names) == 3 and sum("board_delta" in n for n in names) == 6, names
     assert sum("mamba_conv_grad" in n for n in names) == 3 and sum("mamba_conv" in n for n in names) == 6
+    assert sum("head_norm_gate_grad" in n for n in names) == 3 and sum("head_norm_gate" in n for n in names) == 6  # since PR 55: once a GDN layer
+    assert not _xla_passes_over_a_head_norm(text, cfg.linear_num_value_heads), _xla_passes_over_a_head_norm(text, cfg.linear_num_value_heads)[:3]
     assert sum("board_attention_grad" in n for n in names) == 1 and sum("board_attention" in n for n in names) == 2
     for phase in ("jvp(forward)", "transpose(jvp(forward))"):  # the core's scope beside the mixer's; the shared expert under its own
         assert all(f"{phase}/layer0{i}.delta/" in text and f"{phase}/layer0{i}.gdn/" in text for i in (0, 1, 2)) and f"{phase}/layer03.delta/" not in text
