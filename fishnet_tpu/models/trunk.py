@@ -4,7 +4,7 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes seven
+tower's own policy and value heads. ``TrunkConfig`` describes eight
 published blocks as one code path at different values; no attention has
 a causal mask here, and a board is far shorter than any's window, so
 running them over a board removes nothing.
@@ -18,7 +18,8 @@ zaya's), ``mamba`` (``_mamba``), ``kda`` (``_kda``: kimi_linear's), ``gdn`` (``_
 ``routed`` (``_routed_layer``). A
 layer of the first block is attention then routed, under ``attn_norm[i]`` and ``moe_norm[i]``; of the second, attention then
 dense or routed, a post-norm each; of the third, latent then dense or routed; of the fifth, cca then routed; of the sixth, kda
-or latent, as ``mixers[i]`` says, then dense or routed; of the seventh, gdn or attention, as ``mixers[i]`` says, then routed; a layer of the
+or latent, as ``mixers[i]`` says, then dense or routed; of the seventh, gdn or attention, as ``mixers[i]`` says, then routed; of the eighth,
+attention (turned by its layer kind's table, ``Sublayer.rope_type``) then routed; a layer of the
 fourth is ONE of mamba, routed and attention, as ``pattern`` says, under ``layer_norm[i]``. A new token mixer is one
 function, one row in each of the two tables, its shapes (``_kind_shapes``), its fields of ``TrunkConfig`` with their
 refusal, and one helper of the checkpoint reader (``_SIZES``): nothing inside another kind's function.
@@ -310,6 +311,47 @@ the core, ``N_d(o) * silu(z)`` is the sixth block's kernel pair
 ``layerNN.gdn``: o as the core writes it and z as its product writes it in,
 the out-projection's bfloat16 operand out, one pass each way.
 
+The eighth block is Mellum2-12B-A2.5B's (JetBrains, config.json,
+``model_type`` mellum: hidden 2304, 28 layers by ``layer_types``, three
+``sliding_attention`` layers (window 1,024) to one ``full_attention``
+layer, each 32 query heads over 4 key-value heads of 128 with qk-norm and
+RoPE by ``rope_parameters``, a table a layer KIND: plain at theta 500,000 on
+the sliding layers; YaRN, factor 16 over an original 8,192, beta 32 / 1,
+``attention_factor`` 1.27726, on the full ones; every layer routed: 64
+experts of width 896, softmax scores, top-8 renormalised, no shared expert,
+no dense layer; RMSNorm eps 1e-6); what its config.json does not say is
+the Qwen3-MoE family's block, whose keys it carries, listed under
+``assumed`` in ``benchmark/configs/mellum2-trunk-train.json`` each with its
+basis (a multi-token-prediction head is named there as LEFT OUT: no key
+defines it). ``TrunkConfig.full_attention_layers`` are the layers of the
+second kind; the window masks nothing on a board, as the second block's::
+
+    embed     x = t W_in + b_in                                         (no scale)
+    layer i   a = x + Attn_kind(i)(N_in(x));   y = a + MoE(N_post(a))   kind(i) = full_attention where i is among ``full_attention_layers``; two norms a layer
+    Attn      the first block's at 8 query heads a key-value head: q, k <- RMSNorm over head_dim, one gain each, no gate, no bias, then RoPE,
+              rotate-half over all of head_dim, position = square index, pair j of head_dim / 2, by the layer's kind:
+                sliding_attention   f_j = theta^(-2j / head_dim);  cos, sin of position x f_j                    (``rope_tables``)
+                full_attention      YaRN (``yarn_rope_tables``): c(b) = head_dim ln(original / (2 pi b)) / (2 ln theta);
+                                    lo = floor(c(beta_fast)), hi = ceil(c(beta_slow))                            (18 and 35 as published)
+                                    r_j = clip((j - lo) / (hi - lo), 0, 1);   f_j = (1 - r_j) theta^(-2j / head_dim) + r_j theta^(-2j / head_dim) / rope_factor
+                                    cos, sin <- attention_factor x cos, sin: q AND k carry it, the layer's scores attention_factor^2 = 1.6314 times the plain ones'
+              out = concat_h(softmax(q_h k_{h // 8}^T / sqrt(head_dim)) v_{h // 8}) W_o
+    MoE       the first block's softmax router with ``route_norm`` (over ALL the chosen, held here or not), the choice on score + expert_bias
+              (the seventh block's departure, for its reason); out = sum over the chosen HELD of w_j E_j(n), nothing beside them
+    out       N_final(y) -> the heads
+
+Mechanism, the eighth block: no kind, no tensor and no kernel of its own. A
+layer kind's table is made on the host (float64, then float32, the sine
+signed for rotate-half, the attention factor folded into both tables) and
+handed to ``board_attention`` as ``tables``; the kernel pair reads cos and
+sin as an operand and assumes nothing of their rows, so a scaled rotation is
+turned forward and un-turned in the gradient (its transpose, ``d * cos +
+turned(d * sin)``) by the same instructions as a plain one
+(``tests/test_mellum_trunk.py`` holds the gradient to ``jax.vjp`` of the
+float32 formula). The block's own cost is elsewhere: a moved row of 2,304 =
+18 lane tiles goes as 3,072 (``_whole_rows``), and 8 of 64 experts at top-8
+hold ONE slot a token on average, eight times the other shares' rows.
+
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums
 over heads (a KDA head's state, norm and gate are its own; the latent is
@@ -426,7 +468,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tg
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
-from fishnet_tpu.ops.board_attention import SQUARES, board_attention
+from fishnet_tpu.ops.board_attention import SQUARES, board_attention, yarn_rope_tables
 from fishnet_tpu.ops.board_delta import board_delta
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
@@ -514,6 +556,18 @@ class TrunkConfig:
     linear_value_head_dim: int = 0
     shared_token_gate: bool = False
     zero_centered_norms: bool = False
+    # What the eighth block adds (module docstring): RoPE by LAYER KIND, named after the published ``layer_types`` and ``rope_parameters``.
+    # ``full_attention_layers``: the attention layers of the kind ``full_attention``, which turn by the table of ``rope_type`` ("yarn": the five
+    # numbers below, the published ``factor`` as ``rope_factor``; ``ops.board_attention.yarn_rope_tables``) at ``rope_theta``; every other
+    # attention layer (``sliding_attention``) turns by the plain table of ``rope_theta``, or by none where it is among ``nope_layers``.
+    # (): one table a trunk, the seven blocks above, and the six fields after it are not read.
+    full_attention_layers: Tuple[int, ...] = ()
+    rope_type: str = "default"
+    rope_factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
     def __post_init__(self) -> None:
         first, count = self.held
@@ -523,6 +577,8 @@ class TrunkConfig:
         mixers = tuple(self.mixers or ())
         gdn = (self.linear_num_key_heads, self.linear_num_value_heads, self.linear_key_head_dim, self.linear_value_head_dim)
         seventh = bool(mixers) and set(mixers) <= {"gdn", "attention"}
+        yarn = (self.rope_factor, self.original_max_position_embeddings, self.beta_fast, self.beta_slow, self.attention_factor)
+        full = self.full_attention_layers
         if mixers and self.layers in (1, len(mixers)):
             object.__setattr__(self, "layers", len(mixers))
         if pattern and self.layers in (1, len(pattern)):
@@ -578,6 +634,18 @@ class TrunkConfig:
             "shared_token_gate and zero_centered_norms are the seventh block's (mixers of gdn and attention), and the gate wants a shared "
             "expert (shared_width)": (self.shared_token_gate or self.zero_centered_norms) and not seventh
                                      or self.shared_token_gate and not self.shared_width,
+            f"full_attention_layers {full} are not attention layers of the {self.layers} (a pattern's *, a layer of mixers that is attention)":
+                any(not 0 <= i < self.layers or (pattern[i:i + 1] or "*") != "*" or (mixers[i:i + 1] or ("attention",)) != ("attention",) for i in full),
+            "full_attention_layers turn by a table of their own: all of a head (no rotary_dim), a key a key-value head (no latent, no cca), and "
+            f"turned at all (nope_layers {self.nope_layers} name none of them)":
+                bool(full) and (self.rotary_dim is not None or latent or cca or bool(set(full) & set(self.nope_layers))),
+            f"rope_type {self.rope_type!r} is neither default nor yarn, is told without full_attention_layers to turn by it, or is default "
+            f"beside full_attention_layers {full}, which then turn as every other layer does (name none)":
+                self.rope_type not in ("default", "yarn") or (self.rope_type != "default") != bool(full),
+            f"yarn wants rope_factor of 1 or more, original_max_position_embeddings, beta_fast over beta_slow over 0 and attention_factor over 0, "
+            f"got {yarn}": self.rope_type == "yarn" and not (self.rope_factor >= 1.0 and self.original_max_position_embeddings > 0
+                                                              and self.beta_fast > self.beta_slow > 0.0 and self.attention_factor > 0.0),
+            f"YaRN's numbers {yarn} stand beside rope_type default": self.rope_type == "default" and yarn != (1.0, 0, 32.0, 1.0, 1.0),
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -605,6 +673,7 @@ class Sublayer(NamedTuple):
     norm_index: int  # ... and its row there, which is its post-norm's too
     rope: bool = False  # an attention's: RoPE on its queries and keys
     post_norm: Optional[str] = None
+    rope_type: str = "default"  # where it turns, WHICH table by: "default" the plain one of ``rope_theta``, "yarn" the ``full_attention`` layers' (``_attention``)
 
 
 @functools.lru_cache(maxsize=None)
@@ -615,9 +684,11 @@ def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
     the whole trunk) and a feed-forward under ``moe_norm[i]``, dense in
     the leading ``dense_layers``. Everything between ``embed`` and
     ``final_norm`` reads this and not the fields it is made from."""
+    table = lambda i: cfg.rope_type if i in cfg.full_attention_layers else "default"  # the layer's kind's: full_attention or sliding_attention
     if cfg.pattern:
         kinds = [{"M": "mamba", "E": "routed", "*": "attention"}[kind] for kind in cfg.pattern]
-        return tuple(Sublayer(f"layer{i:02d}", kind, kinds[:i].count(kind), "layer_norm", i, rope=kind == "attention") for i, kind in enumerate(kinds))
+        return tuple(Sublayer(f"layer{i:02d}", kind, kinds[:i].count(kind), "layer_norm", i, rope=kind == "attention", rope_type=table(i))
+                     for i, kind in enumerate(kinds))
     mixer = "cca" if cfg.cca is not None else "latent" if cfg.kv_lora_rank is not None else "attention"
     mixers = cfg.mixers or (mixer,) * cfg.layers  # a layer's mixer is row ``index`` of its kind's tensors: its place among that kind's layers
     # the attention branch's post-norm is the first kind's alone (a latent beside ``post_norms`` holds ``post_attn_norm`` and never reads it)
@@ -625,7 +696,7 @@ def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
     plan = []
     for i in range(cfg.layers):
         ffn = ("dense", i) if i < cfg.dense_layers else ("routed", i - cfg.dense_layers)
-        plan += [Sublayer(f"layer{i:02d}", mixers[i], mixers[:i].count(mixers[i]), "attn_norm", i, i not in cfg.nope_layers, after_mixer),
+        plan += [Sublayer(f"layer{i:02d}", mixers[i], mixers[:i].count(mixers[i]), "attn_norm", i, i not in cfg.nope_layers, after_mixer, table(i)),
                  Sublayer(f"layer{i:02d}", *ffn, "moe_norm", i, post_norm=after_ffn)]
     return tuple(plan)
 
@@ -972,11 +1043,18 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) ->
         q, k, v = (_by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
         gains = dict(g_q=p["q_norm"], g_k=p["k_norm"]) if cfg.qk_norm else dict(g_q=None, g_k=None, head_dim=cfg.head_dim)
         mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if sublayer.rope else None, eps=cfg.rms_eps,
-                                interpret=_interpret(), rotary_dim=cfg.rotary_dim, **gains)
+                                interpret=_interpret(), rotary_dim=cfg.rotary_dim, tables=_yarn_tables(cfg) if sublayer.rope_type == "yarn" else None, **gains)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
             return _gated_out(n1, mixed, p["wgate"], p["wo"]), {}
         return _matmul(mixed, p["wo"]), {}
+
+
+def _yarn_tables(cfg: TrunkConfig) -> Tuple[np.ndarray, np.ndarray]:
+    """The ``full_attention`` layers' tables: YaRN at ``rope_theta`` over all of a head, the attention factor in both (made once a such layer,
+    for the kernel and its gradient alike: 64 x ``head_dim`` cosines on the host)."""
+    return yarn_rope_tables(cfg.rope_theta, cfg.head_dim, cfg.rope_factor, cfg.original_max_position_embeddings, cfg.beta_fast, cfg.beta_slow,
+                            cfg.attention_factor)
 
 
 @jax.custom_vjp
@@ -1734,6 +1812,8 @@ def balanced_bias(bias: jax.Array, slots: jax.Array, rate: float) -> jax.Array:
 HPARAMS = "trunk_hparams"
 _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_scale", "balance_rate", "sliding_window",
             "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim", "zero_centered")
+#: What a file with ``full_attention_layers`` carries after them (no other file: one of the seven older blocks is what it was).
+_ROPE_HPARAMS = ("full_mask", "yarn", "rope_factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor")
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
 #: A checkpoint whose mixer is told by layer carries ``mixers``, each layer's kind as its place in ``_MIXERS``.
@@ -1749,21 +1829,31 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     are no qk-norm gains to read it from; mamba_groups) and the fifth's
     rotary_dim (0: RoPE on all of a head; its kernel sizes, head width
     and router width are shapes) and the seventh's zero-centred norms (0
-    or 1; its sizes and its token gate are shapes). A pattern's file carries the pattern
+    or 1; its sizes and its token gate are shapes); after them, in a file
+    with ``full_attention_layers`` alone (a file of the seven older blocks
+    is what it was), the eighth's: those layers as a bit mask,
+    ``rope_type`` yarn 0 or 1, and YaRN's five numbers. A pattern's file carries the pattern
     too (``trunk_pattern``, its characters as bytes), one whose mixer is
     told by layer its ``mixers`` (``trunk_mixers``).
     ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
-    arrays[HPARAMS] = np.asarray([
-        cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
-        cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
-        cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
-        cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0, cfg.zero_centered_norms], np.float64)
+    arrays[HPARAMS] = _hparams(cfg)[:None if cfg.full_attention_layers else len(_HPARAMS)]
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
     if cfg.mixers:
         arrays[MIXERS] = np.asarray([_MIXERS.index(kind) for kind in cfg.mixers], np.uint8)
     return arrays
+
+
+def _hparams(cfg: TrunkConfig) -> np.ndarray:
+    """Every value ``_HPARAMS`` and ``_ROPE_HPARAMS`` name, of ``cfg``."""
+    return np.asarray([
+        cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
+        cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
+        cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
+        cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0, cfg.zero_centered_norms,
+        sum(1 << i for i in cfg.full_attention_layers), cfg.rope_type == "yarn", cfg.rope_factor, cfg.original_max_position_embeddings,
+        cfg.beta_fast, cfg.beta_slow, cfg.attention_factor], np.float64)
 
 
 def _attention_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the head width from the qk-norm's gains, or the file's
@@ -1828,10 +1918,10 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     if missing:
         raise ValueError(not_one(missing))
     given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
-    defaults = trunk_checkpoint({}, TrunkConfig())[HPARAMS]
-    if not 3 <= len(given) <= len(_HPARAMS):
-        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS)}")
-    hp = dict(zip(_HPARAMS, [*given, *defaults[len(given):]]))
+    defaults = _hparams(TrunkConfig())
+    if not 3 <= len(given) <= len(_HPARAMS) + len(_ROPE_HPARAMS):
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS) + len(_ROPE_HPARAMS)}")
+    hp = dict(zip((*_HPARAMS, *_ROPE_HPARAMS), [*given, *defaults[len(given):]]))
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
     width_of = lambda name: shape(name)[2] if name in params else 0
     if pattern is not None:  # the mixers its characters name
@@ -1863,6 +1953,9 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_up")[1]),
         balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
         shared_token_gate="shared_token_gate" in params, zero_centered_norms=bool(hp["zero_centered"]),
+        full_attention_layers=tuple(i for i in range(layers) if int(hp["full_mask"]) >> i & 1), rope_type="yarn" if hp["yarn"] else "default",
+        rope_factor=hp["rope_factor"], original_max_position_embeddings=int(hp["original_max_position_embeddings"]),
+        beta_fast=hp["beta_fast"], beta_slow=hp["beta_slow"], attention_factor=hp["attention_factor"],
         router_hidden=shape("router_down")[2] if "router_down" in params else 0,
     ))
 

@@ -53,6 +53,13 @@ split by which of two lane rotations brings a column's partner
 the ROTATED width wraps. A group of 4 query heads a key-value head takes
 4 boards a grid step (16 board-head pairs, as every group does).
 
+A layer that does not turn by the plain table of ``theta`` hands the
+normed form its TABLES (``tables``: cos and signed sine ``[64, head_dim]``,
+e.g. ``yarn_rope_tables``': YaRN's frequencies with the attention factor in
+both). They are an operand like any other table: the kernels and their
+programs are the plain layer's, and a row that is a scaled rotation is
+un-turned in the gradient by the transpose of what turned it.
+
 The same pair has a second, latent form (the end of this file): no norm,
 RoPE on a trailing part of the score width, one rotated key for all
 heads. ``board_attention`` is told which, and does not guess.
@@ -72,7 +79,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SQUARES", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables"]
+__all__ = ["SQUARES", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables", "yarn_rope_tables"]
 
 SQUARES = 64
 
@@ -99,6 +106,36 @@ def rope_tables(theta: float, head_dim: int) -> Tuple[np.ndarray, np.ndarray]:
     angle = np.arange(SQUARES, dtype=np.float64)[:, None] * inv_freq[None, :]
     cos = np.concatenate([np.cos(angle)] * 2, axis=-1)
     sin = np.concatenate([-np.sin(angle), np.sin(angle)], axis=-1)
+    return cos.astype(np.float32), sin.astype(np.float32)
+
+
+def yarn_rope_tables(theta: float, head_dim: int, factor: float, original_max_position_embeddings: int, beta_fast: float, beta_slow: float,
+                     attention_factor: float) -> Tuple[np.ndarray, np.ndarray]:
+    """``rope_tables`` under YaRN (arXiv:2309.00071, as the published
+    ``rope_parameters`` of ``rope_type`` "yarn" name its numbers): pair
+    ``j`` of the ``head_dim // 2`` turns at its plain frequency
+    ``theta^(-2j / head_dim)`` where that makes more than ``beta_fast``
+    turns over the original context, at the frequency divided by
+    ``factor`` where it makes fewer than ``beta_slow``, and on a linear
+    ramp between (``lo`` and ``hi`` the pairs, ``dim`` = ``head_dim``)::
+
+        c(b) = dim ln(original / (2 pi b)) / (2 ln theta);   lo = max(floor(c(beta_fast)), 0);   hi = min(ceil(c(beta_slow)), dim - 1)
+        r_j  = clip((j - lo) / (hi - lo), 0, 1);             f_j = (1 - r_j) theta^(-2j / dim) + r_j theta^(-2j / dim) / factor
+
+    and BOTH tables carry ``attention_factor``: a query and a key each
+    turned by them give scores ``attention_factor^2`` times the plain
+    ones'. Float64, then float32, the sine signed as ``rope_tables``'; a
+    row is no unit rotation (cos^2 + sin^2 = ``attention_factor^2``), which
+    the kernels never assume: the gradient's un-rotation is the
+    transpose of ``x * cos + turned(x) * sin`` whatever the tables hold."""
+    half = head_dim // 2
+    at = lambda turns: head_dim * math.log(original_max_position_embeddings / (turns * 2.0 * math.pi)) / (2.0 * math.log(theta))
+    lo, hi = max(math.floor(at(beta_fast)), 0), min(math.ceil(at(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - lo) / ((hi - lo) or 0.001), 0.0, 1.0)
+    plain = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+    angle = np.arange(SQUARES, dtype=np.float64)[:, None] * ((1.0 - ramp) * plain + ramp * plain / factor)[None, :]
+    cos = attention_factor * np.concatenate([np.cos(angle)] * 2, axis=-1)
+    sin = attention_factor * np.concatenate([-np.sin(angle), np.sin(angle)], axis=-1)
     return cos.astype(np.float32), sin.astype(np.float32)
 
 
@@ -300,14 +337,16 @@ def _gain_spec(g_k: jax.Array, whole):
     return whole(1) if g_k.ndim == 1 else pl.BlockSpec((1, g_k.shape[-1]), lambda i, h: (0, h))
 
 
-def _operands(g_q, g_k, theta: Optional[float], rotary_dim: Optional[int] = None):
-    head_dim = g_q.shape[-1]
+def _tables(theta: Optional[float], head_dim: int, rotary_dim: Optional[int]) -> Tuple[np.ndarray, np.ndarray]:
+    """The plain tables a caller that hands ``board_attention`` none is given: of ``theta`` over all of a head or its first ``rotary_dim`` columns."""
     if rotary_dim is None:
-        cos, sin = rope_tables(theta or 1.0, head_dim)  # not read without RoPE
-    else:
-        cos, sin = part_rope_tables(theta or 1.0, head_dim, rotary_dim)
+        return rope_tables(theta or 1.0, head_dim)  # not read without RoPE
+    return part_rope_tables(theta or 1.0, head_dim, rotary_dim)
+
+
+def _operands(g_q, g_k, tables: Tuple[np.ndarray, np.ndarray]):
     gain = lambda g: g.astype(jnp.float32).reshape(1, -1)  # a gain a key-value head: its heads side by side along the lanes
-    return gain(g_q), gain(g_k), jnp.asarray(cos), jnp.asarray(sin)
+    return gain(g_q), gain(g_k), jnp.asarray(tables[0]), jnp.asarray(tables[1])
 
 
 def _unroll(interpret: bool, unroll: int, group: int) -> int:
@@ -319,7 +358,7 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
                     theta: Optional[float], eps: float, interpret: bool = False,
                     q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None, head_dim: Optional[int] = None,
-                    rotary_dim: Optional[int] = None) -> jax.Array:
+                    rotary_dim: Optional[int] = None, tables: Optional[Tuple[np.ndarray, np.ndarray]] = None) -> jax.Array:
     """The attention core (module docstring). What it is told, and does
     not guess: the norm (the gains ``[head_dim]``, or None for none, and
     then ``head_dim`` itself unless the form is the latent one; ``g_q``
@@ -332,7 +371,14 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     trailing columns alone, or with ``rotary_dim`` 0 none: a latent NoPE
     layer, whose ``q_pe`` and ``k_pe`` are more score columns)
     and whether the rotated part of k is one a key-value head (it is part
-    of ``k``) or one for all heads (``k_pe`` ``[boards, 64, rope]``).
+    of ``k``) or one for all heads (``k_pe`` ``[boards, 64, rope]``), and,
+    where a layer does not turn by the plain table of ``theta``, the
+    TABLES themselves (``tables``: cos and signed sin ``[64, head_dim]``
+    float32 as ``rope_tables`` lays them out, e.g. ``yarn_rope_tables``';
+    the normed form over all of a head under a ``theta``, which then only
+    says that the layer turns). The kernels read the tables as an operand
+    and assume nothing of them: rows that are scaled rotations are turned
+    and un-turned as they are.
 
     Normed form: q float32 ``[boards, 64, heads * head_dim]``, k float32
     and v bfloat16 ``[boards, 64, kv_heads * head_dim]`` -> bfloat16 of
@@ -342,18 +388,21 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     value]`` -> bfloat16 of v's shape. The combinations the kernels do
     not compute are refused."""
     latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
+    if tables is not None and (theta is None or rotary_dim is not None or latent[2] or latent[3]):
+        raise ValueError("board_attention takes tables for the normed form's RoPE over all of a head (a theta, no rotary_dim, no q_pe or k_pe)")
     if all(latent) and theta is not None and rotary_dim in (None, 0):  # 0: the same pair under tables that turn nothing (``_latent_tables``)
         return _latent_attention(q, q_pe, k, k_pe, v.astype(jnp.bfloat16), theta if rotary_dim is None else None, interpret)
+    told = lambda width: tables or _tables(theta, width, rotary_dim)  # the caller's tables, or the plain ones of ``theta`` for a head of ``width``
     if latent == (True, True, False, False) and head_dim is not None:  # the grouped form without its norm: the gains are not read
         ones = jnp.ones((head_dim,), jnp.float32)
-        return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False, rotary_dim)
+        return _normed_attention(q, k, v, ones, ones, theta, eps, interpret, False, rotary_dim, True, told(head_dim))
     if latent == (True, False, False, False) and head_dim is None:  # both normed, the query without a gain (which is not read)
-        return _normed_attention(q, k, v, jnp.ones((g_k.shape[-1],), jnp.float32), g_k, theta, eps, interpret, True, rotary_dim, False)
+        return _normed_attention(q, k, v, jnp.ones((g_k.shape[-1],), jnp.float32), g_k, theta, eps, interpret, True, rotary_dim, False, told(g_k.shape[-1]))
     if any(latent) or head_dim is not None:
         raise ValueError("board_attention computes qk-norm (or, told head_dim in the gains' place, no norm; or the query's norm without a "
                          "gain) with RoPE over all, none or the first rotary_dim columns of head_dim, or no norm with RoPE over trailing "
                          "columns q_pe and one k_pe for all heads; not a mixture of these")
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, True, rotary_dim)
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, True, rotary_dim, True, told(g_q.shape[-1]))
 
 
 def _form(theta: Optional[float], rotary_dim: Optional[int], q_gain: bool) -> dict:
@@ -364,48 +413,47 @@ def _form(theta: Optional[float], rotary_dim: Optional[int], q_gain: bool) -> di
     return {"rope": theta is not None, **part, **({} if q_gain else {"q_gain": False})}
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool, norm: bool = True,
-                      rotary_dim: Optional[int] = None, q_gain: bool = True):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, interpret: bool, norm: bool, rotary_dim: Optional[int], q_gain: bool,
+                      tables: Tuple[np.ndarray, np.ndarray]):
+    """``tables``: what ``board_attention`` was told, or the plain ones of ``theta`` it made (``_tables``); ``theta`` itself only says whether a layer turns."""
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
-    tables = SQUARES if rotary_dim is None else 2 * SQUARES
     return pl.pallas_call(
         functools.partial(_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
-        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables)],
+        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables[1].shape[0])],
         out_specs=per_group,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.bfloat16),
         compiler_params=_PARAMS,
         name="board_attention",
         interpret=interpret,
-    )(q, k, v, *_operands(g_q, g_k, theta, rotary_dim))
+    )(q, k, v, *_operands(g_q, g_k, tables))
 
 
-def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret, norm=True, rotary_dim=None, q_gain=True):
-    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, norm, rotary_dim, q_gain), (q, k, v, g_q, g_k)
+def _board_attention_fwd(q, k, v, g_q, g_k, theta, eps, interpret, norm, rotary_dim, q_gain, tables):
+    return _normed_attention(q, k, v, g_q, g_k, theta, eps, interpret, norm, rotary_dim, q_gain, tables), (q, k, v, g_q, g_k)
 
 
-def _board_attention_bwd(theta, eps, interpret, norm, rotary_dim, q_gain, residuals, d_mixed):
+def _board_attention_bwd(theta, eps, interpret, norm, rotary_dim, q_gain, tables, residuals, d_mixed):
     q, k, v, g_q, g_k = residuals
     boards, _, inner = q.shape
     head_dim = g_q.shape[-1]
     kv_heads = k.shape[-1] // head_dim
     grid, group, per_head, per_group, whole, partial = _blocks(boards, inner // head_dim, kv_heads, head_dim)
     sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
-    tables = SQUARES if rotary_dim is None else 2 * SQUARES
     dq, dk, dv, dgq, dgk = pl.pallas_call(
         functools.partial(_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
-        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables), per_group],
+        in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables[1].shape[0]), per_group],
         out_specs=[per_group, per_head, per_head, partial, partial],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype), sums, sums],
         compiler_params=_PARAMS,
         name="board_attention_grad",
         interpret=interpret,
-    )(q, k, v, *_operands(g_q, g_k, theta, rotary_dim), d_mixed)
+    )(q, k, v, *_operands(g_q, g_k, tables), d_mixed)
     total = lambda s, g: s.reshape(-1, kv_heads, head_dim).sum(axis=(0, 1) if g.ndim == 1 else 0).astype(g.dtype)
     return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
 

@@ -57,6 +57,9 @@ MODEL_SCOPES = {
     "gdn": ("embed", "layer00.gdn", "layer00.delta", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine", "layer00.shared",
             "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "layer01.shared", "final_norm",
             "policy_head", "value_head"),
+    # the eighth's: the first kind's scopes and no other, on a sliding layer and on the full one alike (a layer kind's table is an operand, no scope)
+    "mellum": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
+               "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
@@ -77,7 +80,11 @@ GDN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, experts=8, expert
                   rotary_dim=4, shared_width=16, route_norm=True, held_experts=(2, 4), balance_rate=0.001, mixers=("gdn", "attention"),
                   linear_num_key_heads=1, linear_num_value_heads=2, linear_key_head_dim=16, linear_value_head_dim=16, shared_token_gate=True,
                   zero_centered_norms=True)
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN}
+# the eighth block: a sliding layer under the plain table, then a full layer under YaRN's; 4 of 8 experts held, no shared expert
+MELLUM = TrunkConfig(hidden=32, heads=4, kv_heads=1, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8, route_norm=True,
+                     held_experts=(2, 4), balance_rate=0.001, sliding_window=1024, rope_theta=1e4, full_attention_layers=(1,), rope_type="yarn", rope_factor=16.0,
+                     original_max_position_embeddings=2048, attention_factor=1.2772588722239782)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN, "mellum": MELLUM}
 
 
 def make(kind):
@@ -150,11 +157,11 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert {"forward", "backward", "optimizer"} <= phases
 
 
-@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn"])
+@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn", "mellum"])
 def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
     """The scopes of the trunk's own parts in the configurations the
     fixture above does not compile, as PR 46's PARENT (3036d35) named them
-    (the sixth block's as PR 47 brought them, the seventh's as PR 51), no more and no fewer: the
+    (the sixth block's as PR 47 brought them, the seventh's as PR 51, the eighth's as PR 56), no more and no fewer: the
     benchmark's reducers read these names, and a lowered step's text (the
     step pins) carries none of them."""
     names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))

@@ -41,6 +41,10 @@ KEYS_AND_INIT_SHA256 = {
     # with the routed layer's own; its five zero-centred norms start at zero
     "gdn": ("embed_w embed_b attn_norm wq wk wv q_norm k_norm wo gdn_qkvz gdn_ba gdn_conv gdn_dt_bias gdn_A_log gdn_o_norm gdn_out moe_norm "
             f"router_w experts_gate experts_up experts_down shared_token_gate {_HEADS} wgate shared_gate shared_up shared_down", "4c22383c99139dcb3ef79e42f7e0a973317762d3bccd1a971f233811cda7e22b"),
+    # the eighth block, read on the tree of the PR that brought it (PR 56): the first kind's tensors and no other (no gate: no ``wgate``), the key-value
+    # heads' ``wk`` and ``wv`` narrower than ``wq``; the two layer kinds' tables are no tensor
+    "mellum": (f"embed_w embed_b attn_norm wq wk wv q_norm k_norm wo moe_norm router_w experts_gate experts_up experts_down {_HEADS}",
+               "3db25edba706c732ef6cf70b43ee6225d3dfa1848b9d94fb503af150dc914347"),
 }
 
 
@@ -95,6 +99,11 @@ PLANS = {
         Sublayer("layer01", "gdn", 1, "attn_norm", 1, True), Sublayer("layer01", "routed", 1, "moe_norm", 1),
         Sublayer("layer02", "gdn", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 2, "moe_norm", 2),
         Sublayer("layer03", "attention", 0, "attn_norm", 3, True), Sublayer("layer03", "routed", 3, "moe_norm", 3)),
+    "mellum": (  # attention then routed, four times; every attention turns, the full layer (the last) by the YaRN table and the sliding ones by the plain one
+        Sublayer("layer00", "attention", 0, "attn_norm", 0, True), Sublayer("layer00", "routed", 0, "moe_norm", 0),
+        Sublayer("layer01", "attention", 1, "attn_norm", 1, True), Sublayer("layer01", "routed", 1, "moe_norm", 1),
+        Sublayer("layer02", "attention", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 2, "moe_norm", 2),
+        Sublayer("layer03", "attention", 3, "attn_norm", 3, True, rope_type="yarn"), Sublayer("layer03", "routed", 3, "moe_norm", 3)),
 }
 
 

@@ -523,7 +523,8 @@ def _held_as_the_trainer_holds_it(trainer, one_chip):
 #: the bytes of each with its two moments: Nemotron's 1,856 is 14.5 lane tiles, so ``experts_up``; every other width is whole lanes.
 HELD = {"az": ("az-256x19-train", (), 0), "moe_trunk": ("lladamoe-trunk-train", (), 0), "afmoe_trunk": ("trinity-mini-trunk-train", (), 0),
         "mla_trunk": ("kanana-2-trunk-train", (), 0), "hybrid_trunk": ("nemotron-twotower-trunk-train", ("experts_up",), 3 * 4 * 3 * 8 * 2688 * 1856),
-        "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0), "gdn_trunk": ("qwen3-next-trunk-train", (), 0)}
+        "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0), "gdn_trunk": ("qwen3-next-trunk-train", (), 0),
+        "mellum_trunk": ("mellum2-trunk-train", (), 0)}
 
 
 @pytest.mark.parametrize("family", HELD)
@@ -1180,4 +1181,49 @@ def test_the_seventh_blocks_step_compiles_at_published_widths_and_fits_one_chip(
     # 3.88 + 9.75 GiB when this was written, of the chip's 15.75: a GDN layer keeps the convolution's float32 operand, z, the three
     # bfloat16 results, ``T`` and ``U`` (0.9 GiB at 8,192 tokens)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 14.0, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert ".remat" not in text
+
+
+# -- the eighth block (mellum) at its published widths: hidden 2304 = 18 x 128 (a moved row padded to 3,072), expert width 896 = 7 x 128 --------
+
+MELLUM_BOARDS = 256  # mellum_trunk_train_b256
+
+
+def test_the_eighth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+    """The whole step of ``mellum_trunk_train_b256`` from its configuration file: the grouped normed attention pair on all four
+    layers (8 query heads a key-value head of 128 at hidden 2,304: 2 boards a grid step), each told its tables as an OPERAND
+    (``f32[64,128]`` twice a call: the plain ones on the three sliding layers, YaRN's on the full one, the kernels the same), the
+    grouped products at 896 and 1,792 columns over a contraction of 3,072 (a moved row of 2,304 = 18 lane tiles goes as 24), no
+    leaf held off row-major, nothing remade to fit, arguments and temporaries under the chip's memory with ``recompute_experts``
+    (3.18 + 7.03 GiB when this was written; without it 3.18 + 11.65 and XLA's own rematerialisation)."""
+    import importlib
+    import json
+    import re
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "mellum2-trunk-train.json").read_text())
+    assert config["train"]["batch"] == MELLUM_BOARDS and config["train"]["recompute_experts"] is True
+    trainer = importlib.import_module("benchmark.families.mellum_trunk").make_trainer(config)
+    cfg = trainer.cfg
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.expert_width, cfg.held, cfg.full_attention_layers) == (2304, 32, 4, 128, 896, (0, 8), (3,))
+    assert trunk._whole_rows(cfg.hidden) == 3072 and trunk._whole_lanes(cfg.expert_width) == 896
+    assert (trunk._tiling(131_072, 3072, 1792), trunk._tiling(131_072, 896, 3072)) == ((512, 1024, 896), (512, 896, 1024))
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == 284_016_718  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((MELLUM_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((MELLUM_BOARDS, 4672)), "value_target": jnp.zeros((MELLUM_BOARDS,))}
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "board_attention" in line]
+    assert len(calls) == 8 and sum("board_attention_grad" in line.split(" = ")[0] for line in calls) == 4  # four layers, forward and gradient
+    for line in calls:  # q a group of eight query heads wide, k and v at the four key-value heads, the two tables as operands
+        shapes = re.findall(r"(?:f32|bf16)\[[\d,]*\]", line.split("custom-call(")[1])
+        assert shapes[:3] == ["f32[256,64,4096]", "f32[256,64,512]", "bf16[256,64,512]"] and shapes[5:7] == ["f32[64,128]", "f32[64,128]"], shapes
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):
+        assert all(f"{phase}/layer0{i}.{part}/" in text for i in range(4) for part in ("attention", "router", "dispatch", "experts", "combine"))
+        assert ".shared/" not in text and ".dense/" not in text and ".latent/" not in text
+    assert not _xla_passes_over_slots(text, MELLUM_BOARDS * trunk.SQUARES * cfg.experts_per_token)
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 11.0, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert ".remat" not in text

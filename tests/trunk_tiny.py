@@ -170,3 +170,23 @@ GDN = TrunkConfig(hidden=64, heads=4, kv_heads=2, head_dim=16, experts=16, exper
                   balance_rate=0.001, mixers=("gdn", "gdn", "gdn", "attention"), linear_num_key_heads=2, linear_num_value_heads=4,
                   linear_key_head_dim=32, linear_value_head_dim=32, shared_token_gate=True, zero_centered_norms=True)
 BLOCKS["gdn"] = (GDN, batch_of)
+
+
+# -- the eighth block (mellum): grouped-query attention without a gate under TWO RoPE tables by layer kind (three sliding layers plain, the full
+# -- layer YaRN with its attention factor), a renormalised softmax router over experts of which a share is held, no shared expert, no dense
+# -- layer; ``MELLUM_MODEL`` is the same net as the benchmark's reference reads it (benchmark/reference/mellum_trunk.py). At a head of 16 and
+# -- theta 1e4 an original context of 2,048 puts YaRN's ramp on pairs 2 to 6 of 8 (the published numbers put it on 18 to 35 of 64)
+
+MELLUM_ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 10000, "factor": 16, "original_max_position_embeddings": 2048, "beta_fast": 32,
+                                  "beta_slow": 1, "attention_factor": 1.2772588722239782},
+               "sliding_attention": {"rope_type": "default", "rope_theta": 10000}}
+MELLUM_MODEL = {"hidden_size": 64, "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 4, "num_dense_layers": 0,
+                "kept_layer_types": ["sliding_attention", "sliding_attention", "sliding_attention", "full_attention"], "rope_parameters": MELLUM_ROPE,
+                "sliding_window": 1024, "moe_intermediate_size": 32, "num_experts": 8, "num_routed_experts": 16, "first_held_expert": 4,
+                "num_experts_per_tok": 3, "load_balance_coeff": 0.001, "rms_norm_eps": 1e-06, "input_planes": 19, "value_hidden": 32, "policy_planes": 73}
+MELLUM_CONFIG = {"model": MELLUM_MODEL, "train": {"value_weight": 1.0}}
+MELLUM = TrunkConfig(hidden=64, heads=8, kv_heads=2, head_dim=16, layers=4, experts=16, experts_per_token=3, expert_width=32, rope_theta=1e4, rms_eps=1e-6,
+                     value_hidden=32, sliding_window=1024, router_score="softmax", route_norm=True, held_experts=(4, 8), balance_rate=0.001,
+                     full_attention_layers=(3,), rope_type="yarn", rope_factor=16.0, original_max_position_embeddings=2048, beta_fast=32.0, beta_slow=1.0,
+                     attention_factor=1.2772588722239782)
+BLOCKS["mellum"] = (MELLUM, batch_of)
