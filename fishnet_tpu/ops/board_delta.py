@@ -96,12 +96,47 @@ product does not depend on it); ``dg`` is ``dc`` summed over the later
 squares. A scan longer than one chunk (a state handed on) is not computed
 here. Off the TPU both kernels run under the Pallas interpreter.
 
+**The gradient works two chains a product too**, told by the same shapes
+as the forward and for its reason (its time was the same dependent round
+trips: ``Mq^T dO``, ``w = T^T .``, ``dA = -w U^T``, then the levels or the
+spans, each waiting for the one before). The first form's loop turn takes
+boards ``2 t``, ``2 t + 1`` of the block STACKED ALONG THE ROWS: q^, k^,
+``c``, v, ``U``, o's cotangent and every level's decays are ``[128, d]``
+values, the tables ``T``, ``Mq``, ``dMq``, ``dMk`` ONE block diagonal ``[128,
+128]`` each (``_squares(2)``: a column of the other board's table is square
+64, after every square, so the triangle's, the diagonal's and every level's
+masks cut the off-diagonal blocks by themselves and the lines are the single
+board's), and each of a board's products is the pair's one: ``c`` and ``dg``
+on ``bd`` of the triangle, ``w`` on ``bd(T)``, ``dMq`` and ``dA`` the
+diagonal blocks of ``[128, d] x [d, 128]``, a level's ``dQL`` and ``dKL``
+the halves of ONE product on ``[dMq_p ; dMk_p]`` and the two halves of
+``dKR`` one each, the six levels' ``r`` ONE product of their six 0/1
+matrices stacked (``_middles``): 25 products a pair of boards where one
+board made 36. An odd block (``gcd(boards, 8)`` 1) runs one board a turn,
+the same lines on ``[64, .]``. The second form takes a key head's value
+heads two by two as the forward does: ``L``, ``Mq``, ``Mk``, ``dMq``, ``dMk``
+``[64, 128]`` side by side, their spans ONE product, ``T^T dU`` of both ONE
+product on ``bd`` of the kept tile as it stands, ``dMq`` and ``dA`` the
+diagonal blocks of ONE ``[128, d] x [d, 128]`` each on v's, ``U``'s and
+the cotangent's rows stacked, ``dD``'s sums ONE product; the key head's two
+undecayed tables are the halves of one product on ``[q^ ; k^]`` and its four
+closing products two (``[dqk ; dkk] k^`` and ``bd([dqk | dkk])^T [q^ ;
+k^]``): 9 products a board and key head of two value heads where there were
+18; an odd value head left over is one chain. No two products are joined
+along a contraction, a chain's own sums (``dbeta``, ``dg``, ``dqk``,
+``dkk``) are made on its own lanes in the single chain's order, a zero block
+adds exact zeros and a dropped block changes no kept element: dq, dk, dv,
+dg, dbeta are the single chain's bit for bit, on the chip and under XLA:CPU
+(``tests/test_board_delta.py`` keeps the single-chain bodies as its oracle;
+PERF.md section 6, PR 58).
+
 **The pair is traced once a program.** The forward call (both forms) and the
 gradient call each sit under one ``jax.jit`` (bare under the interpreter:
 ``mamba_mix._called``), so the four KDA layers of a step share one trace of
 each kernel body and one Mosaic lowering, which every start of the program
-pays (``setup_s``); a body works ONE board inside a ``fori_loop`` that is
-not unrolled, for the same reason (``tests/test_board_delta.py`` holds the
+pays (``setup_s``); a body works ONE turn (a board, or a pair of boards on
+twice the rows) inside a ``fori_loop`` that is not unrolled, for the same
+reason (``tests/test_board_delta.py`` holds the
 loop rolled, ``tests/test_trunk_tpu_compile.py`` counts the entries into
 both bodies while the cell's step is lowered).
 
@@ -127,10 +162,10 @@ are ONE product (the triangle times ``[g_a masked | g_b masked]``), ``L``,
 ``Mk`` and ``Mq`` are ``[64, 128]`` values, ``Mq U`` one product on ``bd(Mq)``,
 and the packed ``T`` IS the kept tile. The differentiated forward keeps ``T`` (a
 key head's value heads side by side in one 128-lane tile) and ``U``; the gradient kernel makes the two
-products and ``L`` again (they are no chain) and, beside the first form's
+tables and ``L`` again (they are no chain) and, beside the first form's
 gradients of the solve, ``dD = dMq * Mq + dMk * Mk``, ``dg`` from it by the
 triangle's transpose, ``dq^``, ``dk^`` from ``sum over the value heads of dM * L``
-by four products a key head. The first form's programs are what they were.
+by two products a key head.
 """
 
 from __future__ import annotations
@@ -176,10 +211,18 @@ def _dot(a: jax.Array, b: jax.Array, dims=_NN) -> jax.Array:
     return jax.lax.dot_general(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims, preferred_element_type=jnp.float32)
 
 
-def _squares():
-    """The square of a row and of a column of a ``[64, 64]`` table, and of a row of a ``[64, 1]`` column."""
+def _squares(boards: int = 1):
+    """The square of a row and of a column of a ``[64, 64]`` table, and of a row of a ``[64, 1]`` column. ``boards`` 2: of two
+    boards' tables as ONE block diagonal ``[128, 128]`` (the boards stacked along the rows, ``[128, 1]``), each square within its
+    own board; a column of the OTHER board's table is square 64, after every square: in no triangle, no level's pairs and on no
+    diagonal, and nobody's middle, so every mask made of the three cuts the off-diagonal blocks by itself."""
     iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-    return iota((SQUARES, SQUARES), 0), iota((SQUARES, SQUARES), 1), iota((SQUARES, 1), 0)
+    if boards == 1:
+        return iota((SQUARES, SQUARES), 0), iota((SQUARES, SQUARES), 1), iota((SQUARES, 1), 0)
+    rows = boards * SQUARES
+    t, j = iota((rows, rows), 0), iota((rows, rows), 1)
+    own = jnp.right_shift(t, _LEVELS) == jnp.right_shift(j, _LEVELS)
+    return t & (SQUARES - 1), jnp.where(own, j & (SQUARES - 1), SQUARES), iota((rows, 1), 0) & (SQUARES - 1)
 
 
 def _pairs(p: int, t: jax.Array, j: jax.Array) -> jax.Array:
@@ -195,12 +238,21 @@ def _level(p: int, t: jax.Array, j: jax.Array, row: jax.Array):
     return pairs, (jnp.right_shift(row, p) & 1) == 1, (j == middle).astype(jnp.float32)
 
 
-def _level_decays(p: int, c: jax.Array, t: jax.Array, j: jax.Array, row: jax.Array):
+def _level_decays(p: int, c: jax.Array, t: jax.Array, j: jax.Array, row: jax.Array, r=None):
     """Level ``p``'s pairs and its two decays ``[64, d]``: ``exp(c - r)`` on the rows of the upper halves and ``exp(r - c)``
-    on those of the lower, 0 on the others (selected before the exponential: nothing overflows)."""
+    on those of the lower, 0 on the others (selected before the exponential: nothing overflows). ``r``: the level's rows of
+    ``_middles``, where the caller made all six at once."""
     pairs, upper, select = _level(p, t, j, row)
-    r = jnp.dot(select, c, preferred_element_type=jnp.float32)
+    if r is None:
+        r = jnp.dot(select, c, preferred_element_type=jnp.float32)
     return pairs, jnp.exp(jnp.where(upper, c - r, _NEVER)), jnp.exp(jnp.where(upper, _NEVER, r - c))
+
+
+def _middles(c: jax.Array, t: jax.Array, j: jax.Array, row: jax.Array):
+    """Every level's ``r`` (each row's block's middle row of ``c``) as ONE product: the six 0/1 matrices stacked along the rows
+    share ``c``, and a row of the result is the row the level's own product gives."""
+    stacked = jnp.dot(jnp.concatenate([_level(p, t, j, row)[2] for p in range(_LEVELS)], axis=0), c, preferred_element_type=jnp.float32)
+    return [stacked[at:at + c.shape[0]] for at in range(0, stacked.shape[0], c.shape[0])]
 
 
 def _unit(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -210,9 +262,10 @@ def _unit(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def _normed(q: jax.Array, k: jax.Array, g: jax.Array):
-    """One head of one board, float32 ``[64, d]`` -> what is cheap and no chain, made by both kernels: the normed q (times
-    ``d^-1/2``) and k, their reciprocal norms ``[64, 1]``, ``c = cumsum(g)`` and the scale."""
-    t, j, _ = _squares()
+    """One head of one board, float32 ``[64, d]`` (or of two boards stacked along the rows, ``[128, d]``: the gradient's pair)
+    -> what is cheap and no chain, made by both kernels: the normed q (times ``d^-1/2``) and k, their reciprocal norms ``[64,
+    1]``, ``c = cumsum(g)`` a board and the scale."""
+    t, j, _ = _squares(q.shape[0] // SQUARES)
     scale = 1.0 / math.sqrt(q.shape[-1])
     (qn, rq), (kn, rk) = _unit(q), _unit(k)
     return qn * scale, kn, rq, rk, _exact((t >= j).astype(jnp.float32), g), scale
@@ -254,6 +307,16 @@ def _side_by_side(x: jax.Array) -> jax.Array:
     """A packed pair's rows ``[X_a ; X_b]`` ``[128, d]`` -> ``[X_a | X_b]`` ``[64, 2 d]``, whole 128-lane tiles moved (one chain's
     ``[64, d]``: itself)."""
     return jnp.concatenate([x[at:at + SQUARES] for at in range(0, x.shape[0], SQUARES)], axis=1)
+
+
+def _diagonal_blocks(x: jax.Array, axis: int) -> jax.Array:
+    """A pair's product ``[[X_a, .], [., X_b]]`` ``[128, 128]`` -> its diagonal blocks side by side ``[X_a | X_b]`` ``[64, 128]``
+    (``axis`` 1) or stacked ``[X_a ; X_b]`` ``[128, 64]`` (``axis`` 0): one select between the two halves, the off-diagonal
+    blocks dropped. One chain's ``[64, 64]`` is its own."""
+    if x.shape[0] == SQUARES:
+        return x
+    halves = (x[:SQUARES], x[SQUARES:]) if axis == 1 else (x[:, :SQUARES], x[:, SQUARES:])
+    return jnp.where(jax.lax.broadcasted_iota(jnp.int32, halves[0].shape, axis) < SQUARES, *halves)
 
 
 def _solve(a: jax.Array, bv: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -309,59 +372,77 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs):
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """A loop turn works boards ``2 turn`` and ``2 turn + 1`` of the block STACKED along the rows (``[128, d]`` values, the tables
+    one block diagonal ``[128, 128]`` under ``_squares(2)``: every product of the pair is one, whose zero blocks add exact zeros
+    to what each board's own product sums), or ONE board where the block is odd: the same lines on ``[64, .]``."""
     f32, h = jnp.float32, pl.program_id(1)
+    together = 2 - q_ref.shape[0] % 2  # boards a turn
 
-    def board(i, carry):
-        beta, own = _own_lane(beta_ref, i, h)
-        v, do = v_ref[i].astype(f32), do_ref[i]
-        qn, kn, rq, rk, c, scale = _normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
-        tm, mk, u, mq = solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:], u_ref[i], mq_ref[i, :, :SQUARES]
-        t, j, row = _squares()
+    def turn(n, carry):
+        boards = [n * together + e for e in range(together)]
+        stacked = lambda ref, lanes=slice(None): jnp.concatenate([ref[i, :, lanes] for i in boards], axis=0)
+        diagonal = lambda ref: _block_diagonal(jnp.concatenate([ref[i, :, :SQUARES] for i in boards], axis=1))
+        betas, own = zip(*(_own_lane(beta_ref, i, h) for i in boards))
+        beta = jnp.concatenate(betas, axis=0)
+        v, do, u, mk = stacked(v_ref).astype(f32), stacked(do_ref), stacked(u_ref), stacked(solve_ref, slice(SQUARES, None))
+        qn, kn, rq, rk, c, scale = _normed(stacked(q_ref).astype(f32), stacked(k_ref).astype(f32), stacked(g_ref))
+        tm, mq = diagonal(solve_ref), diagonal(mq_ref)
+        t, j, row = _squares(together)
         dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
         w = _exact(tm, _dot(mq, do, _TN), _TN)  # T^T dU: the cotangent of beta * V
         da = -jnp.where(t > j, _exact(w, u, _NT), 0.0)
-        dv_ref[i] = (beta * w).astype(dv_ref.dtype)
-        dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(da * mk, axis=-1, keepdims=True)
-        dbeta_ref[i] = jnp.where(own, dbeta, dbeta_ref[i])
+        dv = (beta * w).astype(dv_ref.dtype)
+        dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(_diagonal_blocks(da, 0) * mk, axis=-1, keepdims=True)
         dmk = beta * da
         on_diagonal = jnp.sum(jnp.where(t == j, dmq, 0.0), axis=-1, keepdims=True)
         dqn, dkn, dc = on_diagonal * kn, on_diagonal * qn, jnp.zeros_like(c)
-        for p in range(_LEVELS):
-            pairs, upper, lower = _level_decays(p, c, t, j, row)
+        for p, r in enumerate(_middles(c, t, j, row)):
+            pairs, upper, lower = _level_decays(p, c, t, j, row, r)
             ql, kl, kr = qn * upper, kn * upper, kn * lower
             dq_pairs, dk_pairs = jnp.where(pairs, dmq, 0.0), jnp.where(pairs, dmk, 0.0)
-            dql, dkl = _dot(dq_pairs, kr), _dot(dk_pairs, kr)
+            straight = _dot(jnp.concatenate([dq_pairs, dk_pairs], axis=0), kr)  # [dMq_p ; dMk_p] KR: ONE product
+            dql, dkl = straight[:dq_pairs.shape[0]], straight[dq_pairs.shape[0]:]
             dkr = _dot(dq_pairs, ql, _TN) + _dot(dk_pairs, kl, _TN)
             dqn, dkn = dqn + dql * upper, dkn + dkl * upper + dkr * lower
             dc = dc + ql * dql + kl * dkl - kr * dkr
-        dg_ref[i] = _exact((t >= j).astype(f32), dc, _TN)  # dg_s = the sum of dc_t over t >= s
+        dg = _exact((t >= j).astype(f32), dc, _TN)  # dg_s = the sum of dc_t over t >= s
         # through the l2 norms: y = x r, dx = r (dy - y sum(y dy)); q's y is qn / scale
         dqn = dqn * scale
         qy = qn * (1.0 / scale)
-        dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
-        dk_ref[i] = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        dq = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
+        dk = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        for e, i in enumerate(boards):
+            rows = slice(e * SQUARES, (e + 1) * SQUARES)
+            dq_ref[i], dk_ref[i], dv_ref[i], dg_ref[i] = dq[rows], dk[rows], dv[rows], dg[rows]
+            dbeta_ref[i] = jnp.where(own[e], dbeta[rows], dbeta_ref[i])
         return carry
 
-    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+    jax.lax.fori_loop(0, q_ref.shape[0] // together, turn, 0)
 
 
 # -- the second form: a decay a head and token, value heads in groups on a key head (module docstring) -------------------------
 
 
 def _head_decay(g: jax.Array, t: jax.Array, j: jax.Array) -> jax.Array:
-    """``g`` ``[64, 1]`` (<= 0) -> ``L[t, j] = exp(sum of g over (j, t])`` for ``t >= j``, else 0: float32 ``[64, 64]``."""
-    spans = _exact((t >= j).astype(jnp.float32), jnp.where(t > j, g, 0.0))
+    """``g`` ``[64, 1]`` (<= 0) -> ``L[t, j] = exp(sum of g over (j, t])`` for ``t >= j``, else 0: float32 ``[64, 64]``. Of a packed
+    pair of value heads (``g`` ``[g_a | g_b]`` a lane, ``t``, ``j`` ``_chain_squares(2)``'s): ``[L_a | L_b]`` ``[64, 128]``, the two
+    decays' spans ONE product of the triangle."""
+    rows, columns, _ = _squares()
+    spans = _exact((rows >= columns).astype(jnp.float32), jnp.where(t > j, g, 0.0))
     return jnp.exp(jnp.where(t >= j, spans, _NEVER))
 
 
-def _key_head(q_ref, k_ref, i, side_by_side: int = 1):
+def _key_head(q_ref, k_ref, i, side_by_side: int = 1, stacked: bool = False):
     """What a key head's value heads share, of board ``i``: ``_unit``'s results, the scale, and the two undecayed tables
     (``side_by_side`` 2: each table twice along the lanes, ``[64, 128]``, for a packed pair of value heads: ONE product on k's
-    rows laid twice)."""
+    rows laid twice; ``stacked``: the two tables the halves of ONE product on ``[q^ ; k^]``)."""
     scale = 1.0 / math.sqrt(q_ref.shape[-1])
     (qn, rq), (kn, rk) = _unit(q_ref[i].astype(jnp.float32)), _unit(k_ref[i].astype(jnp.float32))
     qn = qn * scale
     columns = kn if side_by_side == 1 else jnp.concatenate([kn] * side_by_side, axis=0)
+    if stacked:
+        both = _dot(jnp.concatenate([qn, kn], axis=0), columns, _NT)
+        return qn, kn, rq, rk, scale, both[:SQUARES], both[SQUARES:]
     return qn, kn, rq, rk, scale, _dot(qn, columns, _NT), _dot(kn, columns, _NT)
 
 
@@ -382,8 +463,7 @@ def _head_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs
             on_lanes = lambda ref: [_own_lane(ref, i, key_head * per + s + e)[0] for e in range(chains)]
             by_chain = lambda columns: jnp.where(first, *columns) if chains == 2 else columns[0]
             g, beta = on_lanes(g_ref), on_lanes(beta_ref)
-            spans = _exact((t >= j).astype(f32), jnp.where(tp > jp, by_chain(g), 0.0))  # ``_head_decay`` of both
-            decay = jnp.exp(jnp.where(tp >= jp, spans, _NEVER))
+            decay = _head_decay(by_chain(g), tp, jp)
             width, columns = chains * SQUARES, slice(s * d, (s + chains) * d)
             a = by_chain(beta) * jnp.where(tp > jp, kk[:, :width] * decay, 0.0)
             bv = [beta[e] * v_ref[i, :, (s + e) * d:(s + e + 1) * d].astype(f32) for e in range(chains)]
@@ -399,6 +479,9 @@ def _head_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *kept_refs
 
 
 def _head_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """A key head's value heads two by two as packed pairs, as the forward solves them: the tables ``[64, 128]`` side by side (the
+    kept tile IS the packed ``T``), v, ``U`` and o's cotangent stacked along the rows ``[128, d]``; a product of the pair is one, on
+    a block diagonal or with its off-diagonal blocks dropped. An odd value head left over is ONE chain: the same lines on ``[64, 64]``."""
     f32, key_head = jnp.float32, pl.program_id(1)
     d = q_ref.shape[-1]
     per = v_ref.shape[-1] // d
@@ -406,29 +489,38 @@ def _head_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref
     def board(i, carry):
         t, j, _ = _squares()
         lower = (t >= j).astype(f32)
-        qn, kn, rq, rk, scale, qk, kk = _key_head(q_ref, k_ref, i)
-        dqk, dkk = jnp.zeros_like(qk), jnp.zeros_like(kk)
-        for s in range(per):
-            h, columns = key_head * per + s, slice(s * d, (s + 1) * d)
-            g, own = _own_lane(g_ref, i, h)
-            beta, _ = _own_lane(beta_ref, i, h)
-            decay = _head_decay(g, t, j)
-            mq, mk = qk * decay, jnp.where(t > j, kk * decay, 0.0)
-            v, do = v_ref[i, :, columns].astype(f32), do_ref[i, :, columns]
-            tm, u = solve_ref[i, :, s * SQUARES:(s + 1) * SQUARES], u_ref[i, :, columns]
-            dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
-            w = _exact(tm, _dot(mq, do, _TN), _TN)  # T^T dU: the cotangent of beta * V
-            da = -jnp.where(t > j, _exact(w, u, _NT), 0.0)
-            dv_ref[i, :, columns] = (beta * w).astype(dv_ref.dtype)
-            dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(da * mk, axis=-1, keepdims=True)
-            dbeta_ref[i] = jnp.where(own, dbeta, dbeta_ref[i])
-            dmk = beta * da
+        qn, kn, rq, rk, scale, qk, kk = _key_head(q_ref, k_ref, i, min(per, 2), stacked=True)
+        dqk, dkk = jnp.zeros((SQUARES, SQUARES), f32), jnp.zeros((SQUARES, SQUARES), f32)
+        for s in range(0, per, 2):
+            chains = min(2, per - s)  # value heads s and s + 1
+            tp, jp, first = _chain_squares(chains)
+            (g, own), (beta, _) = (zip(*(_own_lane(ref, i, key_head * per + s + e) for e in range(chains))) for ref in (g_ref, beta_ref))
+            by_chain = lambda columns: jnp.where(first, *columns) if chains == 2 else columns[0]
+            stacked = lambda ref: jnp.concatenate([ref[i, :, (s + e) * d:(s + e + 1) * d] for e in range(chains)], axis=0)
+            width = chains * SQUARES
+            decay = _head_decay(by_chain(g), tp, jp)
+            mq, mk = qk[:, :width] * decay, jnp.where(tp > jp, kk[:, :width] * decay, 0.0)
+            v, do, u = stacked(v_ref).astype(f32), stacked(do_ref), stacked(u_ref)
+            tm = _block_diagonal(solve_ref[i, :, s * SQUARES:s * SQUARES + width])
+            dmq = jnp.where(tp >= jp, _diagonal_blocks(_dot(do, u, _NT), 1), 0.0)
+            w = _exact(tm, _dot(_block_diagonal(mq), do, _TN), _TN)  # T^T dU: the cotangent of beta * V
+            da = -jnp.where(tp > jp, _diagonal_blocks(_exact(w, u, _NT), 1), 0.0)
+            dmk = by_chain(beta) * da
             # dD = dL * L = dMq * Mq + dMk * Mk; D = lower (g masked a column): dg_m = the sum over j < m of (lower^T dD)[m, j]
             spans = _exact(lower, dmq * mq + dmk * mk, _TN)
-            dg_ref[i] = jnp.where(own, jnp.sum(jnp.where(t > j, spans, 0.0), axis=-1, keepdims=True), dg_ref[i])
-            dqk, dkk = dqk + dmq * decay, dkk + dmk * decay
-        dqn = _dot(dqk, kn) * scale
-        dkn = _dot(dqk, qn, _TN) + _dot(dkk, kn) + _dot(dkk, kn, _TN)
+            reads, keeps, dqk_pair, dkk_pair = jnp.sum(w * v, axis=-1, keepdims=True), da * mk, dmq * decay, dmk * decay
+            for e in range(chains):  # each chain's own half of the pair's values (its rows of the stacked, its lanes of the side by side), summed in the single chain's order
+                half = slice(e * SQUARES, (e + 1) * SQUARES)
+                dv_ref[i, :, (s + e) * d:(s + e + 1) * d] = (beta[e] * w[half]).astype(dv_ref.dtype)
+                dbeta = reads[half] + jnp.sum(keeps[:, half], axis=-1, keepdims=True)
+                dbeta_ref[i] = jnp.where(own[e], dbeta, dbeta_ref[i])
+                dg_ref[i] = jnp.where(own[e], jnp.sum(jnp.where(t > j, spans[:, half], 0.0), axis=-1, keepdims=True), dg_ref[i])
+                dqk, dkk = dqk + dqk_pair[:, half], dkk + dkk_pair[:, half]
+        # the four products of a key head as two: [dqk ; dkk] k^, and bd([dqk | dkk])^T [q^ ; k^]
+        straight = _dot(jnp.concatenate([dqk, dkk], axis=0), kn)
+        turned = _dot(_block_diagonal(jnp.concatenate([dqk, dkk], axis=1)), jnp.concatenate([qn, kn], axis=0), _TN)
+        dqn = straight[:SQUARES] * scale
+        dkn = turned[:SQUARES] + straight[SQUARES:] + turned[SQUARES:]
         # through the l2 norms: y = x r, dx = r (dy - y sum(y dy)); q's y is qn / scale
         qy = qn * (1.0 / scale)
         dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)

@@ -9,9 +9,15 @@ once below as the oracle; the gradient kernel reads them and makes no
 solve; the primal writes ``o`` alone. The forward solves two chains a
 product (two boards of a head, or a key head's two value heads, side by
 side on 128 lanes): the packed solve is each chain's own, bit for bit, and
-level 0 of the solve, written without its two products, the parent's.
-``tools/delta_alone.py`` (the pair timed alone) runs at a tiny shape."""
+level 0 of the solve, written without its two products, the parent's. The
+gradient works two chains a product too (two boards of a head stacked
+along the rows, a key head's two value heads packed as the forward packs
+them): its five gradients are the single-chain bodies' of the parent commit,
+kept below as a test's helper, bit for bit, and its bodies hold half the
+products. ``tools/delta_alone.py`` (the pair timed alone) runs at a tiny
+shape."""
 
+import contextlib
 import json
 import math
 
@@ -25,8 +31,9 @@ from fishnet_tpu.ops.board_delta import L2_EPS, board_delta
 from tools import delta_alone
 
 SQUARES = 64
-#: (boards, heads, d): the published head on two boards, narrow heads on a block of boards and a remainder, one head.
-CASES = {"published": (2, 2, 128), "narrow": (9, 4, 16), "one_head": (3, 1, 32)}
+#: (boards, heads, d): the published head on two boards (a block of 2), narrow heads on nine blocks of one board, one head on
+#: three, and one head on a block of 8 (four loop turns of a pair of boards).
+CASES = {"published": (2, 2, 128), "narrow": (9, 4, 16), "one_head": (3, 1, 32), "a_block_of_8": (8, 1, 32)}
 NAMES = ("q", "k", "v", "g", "beta")
 
 
@@ -351,8 +358,8 @@ def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_array
     ``U``, ``Mq``: 80 KB a board and head at their padded size) and a gradient kernel that reads the five inputs, the
     three kept arrays and o's cotangent. Off the interpreter each kernel sits alone under its own ``jax.jit``, whose results
     and arguments are the kernel's own: what the forward wrote is what the gradient reads, nothing between. A kernel's body
-    holds its loop over a grid step's boards rolled (start-up pays for every copy of a body that Mosaic lowers): the forward's
-    a PAIR of boards a turn where the block is even (two chains a product), one board where it is odd; the gradient's a board."""
+    holds its loop over a grid step's boards rolled (start-up pays for every copy of a body that Mosaic lowers): a PAIR of
+    boards a turn where the block is even (two chains a product), in the forward and in the gradient, one board where it is odd."""
     _, heads, d = CASES["published"]
     ops = {name: jax.ShapeDtypeStruct((boards, *value.shape[1:]), value.dtype) for name, value in operands("published").items()}
     args = tuple(ops[name] for name in NAMES)
@@ -363,7 +370,7 @@ def test_the_primal_writes_o_alone_and_the_differentiated_forward_the_kept_array
     for jitted, call in calls:  # a jitted call is its kernel and casts that change nothing: results and kept operands pass straight through
         assert [id(v) for v in jitted.params["jaxpr"].jaxpr.outvars] == [id(v) for v in call.outvars]
         loops = [eqn.params for eqn in call.params["jaxpr"].eqns if eqn.primitive.name in ("scan", "while")]
-        turns = block // 2 if call.params["name"] == "board_delta" and block % 2 == 0 else block
+        turns = block // 2 if block % 2 == 0 else block
         assert [(loop["length"], loop["unroll"]) for loop in loops] == [(turns, 1)]  # ONE turn's body in the kernel: unrolled, every start lowers it a board
     names = [call.params["name"] for _, call in calls]
     if not differentiated:
@@ -490,14 +497,215 @@ def test_the_second_form_reads_q_and_k_a_key_head_and_keeps_T_and_U_alone():
         board_delta(jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 32)), jnp.zeros((2, SQUARES, 80)), jnp.zeros((2, SQUARES, 5)), jnp.zeros((2, SQUARES, 5)), True)
 
 
+# -- the gradient, two chains a product: against the parent's single-chain bodies -------------------------------------------------------
+
+
+def parent_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, mq_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """``_backward_kernel`` as the parent commit (be9aa8a) had it, copied: ONE board a loop turn, every product a ``[64, .]`` one."""
+    f32, h = jnp.float32, kernels.pl.program_id(1)
+    _dot, _exact, _NT, _TN = kernels._dot, kernels._exact, kernels._NT, kernels._TN
+
+    def board(i, carry):
+        beta, own = kernels._own_lane(beta_ref, i, h)
+        v, do = v_ref[i].astype(f32), do_ref[i]
+        qn, kn, rq, rk, c, scale = kernels._normed(q_ref[i].astype(f32), k_ref[i].astype(f32), g_ref[i])
+        tm, mk, u, mq = solve_ref[i, :, :SQUARES], solve_ref[i, :, SQUARES:], u_ref[i], mq_ref[i, :, :SQUARES]
+        t, j, row = kernels._squares()
+        dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
+        w = _exact(tm, _dot(mq, do, _TN), _TN)
+        da = -jnp.where(t > j, _exact(w, u, _NT), 0.0)
+        dv_ref[i] = (beta * w).astype(dv_ref.dtype)
+        dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(da * mk, axis=-1, keepdims=True)
+        dbeta_ref[i] = jnp.where(own, dbeta, dbeta_ref[i])
+        dmk = beta * da
+        on_diagonal = jnp.sum(jnp.where(t == j, dmq, 0.0), axis=-1, keepdims=True)
+        dqn, dkn, dc = on_diagonal * kn, on_diagonal * qn, jnp.zeros_like(c)
+        for p in range(6):
+            pairs, upper, lower = kernels._level_decays(p, c, t, j, row)
+            ql, kl, kr = qn * upper, kn * upper, kn * lower
+            dq_pairs, dk_pairs = jnp.where(pairs, dmq, 0.0), jnp.where(pairs, dmk, 0.0)
+            dql, dkl = _dot(dq_pairs, kr), _dot(dk_pairs, kr)
+            dkr = _dot(dq_pairs, ql, _TN) + _dot(dk_pairs, kl, _TN)
+            dqn, dkn = dqn + dql * upper, dkn + dkl * upper + dkr * lower
+            dc = dc + ql * dql + kl * dkl - kr * dkr
+        dg_ref[i] = _exact((t >= j).astype(f32), dc, _TN)
+        dqn = dqn * scale
+        qy = qn * (1.0 / scale)
+        dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
+        dk_ref[i] = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+
+
+def parent_head_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, solve_ref, u_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """``_head_backward_kernel`` as the parent commit (be9aa8a) had it, copied: a key head's value heads one after another, each its
+    own chain of ``[64, 64]`` products, and four products a key head after them."""
+    f32, key_head = jnp.float32, kernels.pl.program_id(1)
+    _dot, _exact, _NT, _TN = kernels._dot, kernels._exact, kernels._NT, kernels._TN
+    d = q_ref.shape[-1]
+    per = v_ref.shape[-1] // d
+
+    def board(i, carry):
+        t, j, _ = kernels._squares()
+        lower = (t >= j).astype(f32)
+        qn, kn, rq, rk, scale, qk, kk = kernels._key_head(q_ref, k_ref, i)
+        dqk, dkk = jnp.zeros_like(qk), jnp.zeros_like(kk)
+        for s in range(per):
+            h, columns = key_head * per + s, slice(s * d, (s + 1) * d)
+            g, own = kernels._own_lane(g_ref, i, h)
+            beta, _ = kernels._own_lane(beta_ref, i, h)
+            decay = kernels._head_decay(g, t, j)
+            mq, mk = qk * decay, jnp.where(t > j, kk * decay, 0.0)
+            v, do = v_ref[i, :, columns].astype(f32), do_ref[i, :, columns]
+            tm, u = solve_ref[i, :, s * SQUARES:(s + 1) * SQUARES], u_ref[i, :, columns]
+            dmq = jnp.where(t >= j, _dot(do, u, _NT), 0.0)
+            w = _exact(tm, _dot(mq, do, _TN), _TN)
+            da = -jnp.where(t > j, _exact(w, u, _NT), 0.0)
+            dv_ref[i, :, columns] = (beta * w).astype(dv_ref.dtype)
+            dbeta = jnp.sum(w * v, axis=-1, keepdims=True) + jnp.sum(da * mk, axis=-1, keepdims=True)
+            dbeta_ref[i] = jnp.where(own, dbeta, dbeta_ref[i])
+            dmk = beta * da
+            spans = _exact(lower, dmq * mq + dmk * mk, _TN)
+            dg_ref[i] = jnp.where(own, jnp.sum(jnp.where(t > j, spans, 0.0), axis=-1, keepdims=True), dg_ref[i])
+            dqk, dkk = dqk + dmq * decay, dkk + dmk * decay
+        dqn = _dot(dqk, kn) * scale
+        dkn = _dot(dqk, qn, _TN) + _dot(dkk, kn) + _dot(dkk, kn, _TN)
+        qy = qn * (1.0 / scale)
+        dq_ref[i] = (rq * (dqn - qy * jnp.sum(qy * dqn, axis=-1, keepdims=True))).astype(dq_ref.dtype)
+        dk_ref[i] = (rk * (dkn - kn * jnp.sum(kn * dkn, axis=-1, keepdims=True))).astype(dk_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], board, 0)
+
+
+PARENT_BODIES = {"_backward_kernel": parent_backward_kernel, "_head_backward_kernel": parent_head_backward_kernel}
+
+
+@contextlib.contextmanager
+def gradient_bodies(monkeypatch, parent: bool):
+    """This tree's gradient bodies, or (``parent``) the parent's single-chain bodies in their place while a call is traced: the call,
+    its grid and its BlockSpecs are the tree's either way. A jitted call keeps its trace, so each side starts from none."""
+    with monkeypatch.context() as patched:
+        for name, body in PARENT_BODIES.items() if parent else ():
+            patched.setattr(kernels, name, body)
+        kernels._gradient_call.clear_cache()
+        yield
+    kernels._gradient_call.clear_cache()
+
+
+def gradient_call(monkeypatch, parent: bool):
+    """``board_delta``'s gradient call (``residuals, do -> dq, dk, dv, dg, dbeta``) under the interpreter, over either side's bodies."""
+    def call(residuals, do):
+        with gradient_bodies(monkeypatch, parent):
+            return kernels._gradient_call.__wrapped__(*residuals, do, interpret=True)  # bare: traced here, under whichever bodies stand
+    return call
+
+
+#: Both forms' cases, each with the turn it takes: the first form's blocks of 2, of 8 and of 1 (nine boards, and three), the second
+#: form's pairs of value heads, single value heads and three on a key head (a pair and one left over); and, where a turn is a packed
+#: pair, the pair with its first or its second chain forgetting everything at once (``Mk`` zero, ``T`` the identity).
+PACKED = [("first", "published"), ("first", "a_block_of_8"), ("second", "published_two_a_key_head")]
+GRADIENT_CASES = [("first", case, None) for case in CASES] + [("second", case, None) for case in HEAD_CASES] + [(*packed, without) for packed in PACKED for without in (0, 1)]
+CHAINS = {None: "both_chains", 0: "the_first_chain_s_Mk_zero", 1: "the_second_chain_s_Mk_zero"}
+
+
+@pytest.mark.parametrize("form,case,without", GRADIENT_CASES, ids=[f"{form}_form_{case}_{CHAINS[without]}" for form, case, without in GRADIENT_CASES])
+def test_the_packed_gradient_is_the_parents_single_chain_gradient_bit_for_bit(form, case, without, monkeypatch):
+    """Every one of dq, dk, dv, dg, dbeta, to the last bit (``exactly``): no two products are joined along a contraction, a
+    chain's sums are made on its own lanes in the parent's order, a block diagonal's zero blocks add exact zeros and a dropped
+    off-diagonal block changes no kept element. ``published``, ``a_block_of_8`` and ``published_two_a_key_head`` are the packed
+    paths; ``narrow`` (nine boards: blocks of one) and ``one_head`` (three) the first form's fall-back, ``narrow_one_a_key_head``
+    the second form's, ``three_a_key_head`` a pair and one left over in one body. ``without``: that chain of every packed pair (the
+    even or the odd boards; a key head's first or second value head) decays by e^-1000 a square, so its ``Mk`` is zero and its ``T``
+    the identity beside a chain that remembers, as ``test_the_packed_solve_of_two_chains_...`` has it of the forward: a block
+    diagonal that leaked a block, or a select that kept the wrong half, would show there."""
+    ops = operands(case, seed=12) if form == "first" else head_operands(case, seed=12, fastest=0.5)  # rates under 0.5: every head remembers
+    if without is not None:
+        chain = np.arange(ops["g"].shape[0])[:, None, None] if form == "first" else np.arange(ops["g"].shape[-1])[None, None, :]
+        ops["g"] = jnp.where(chain % 2 == without, -1e3, ops["g"])
+    inputs = tuple(ops[name] for name in NAMES)
+    do = jnp.asarray(np.random.default_rng(13).standard_normal(ops["v"].shape), jnp.bfloat16)
+    _, residuals = exactly(lambda *a: kernels._board_delta_fwd(*a, True), *inputs)
+    if without is not None:  # the case is what it says: the kept T of that chain is the identity, of the other it is not
+        boards = ops["g"].shape[0]
+        tables = np.asarray(residuals[-1][0]).reshape(boards, SQUARES, -1, SQUARES).transpose(0, 2, 1, 3)  # [board, 64-lane table, t, j]
+        if form == "first":  # a head's tile is [T | Mk]: of board b, T is table 2 h
+            forgetful, mindful = tables[without::2, 0::2], tables[1 - without::2, 0::2]
+        else:  # a key head's tile is [T_a | T_b]
+            forgetful, mindful = tables[:, without::2], tables[:, 1 - without::2]
+        assert (forgetful == np.eye(SQUARES, dtype=np.float32)).all() and np.abs(mindful - np.eye(SQUARES)).max() > 1e-3
+    got, want = (exactly(gradient_call(monkeypatch, parent), residuals, do) for parent in (False, True))
+    for name, mine, parents in zip(NAMES, got, want):
+        mine, parents = np.asarray(mine, np.float32), np.asarray(parents, np.float32)
+        assert np.isfinite(parents).all() and np.abs(parents).max() > 1e-3, name  # a gradient that is there
+        assert np.array_equal(mine, parents), (name, float(np.abs(mine - parents).max()))
+
+
+def _products(jaxpr):
+    """The operand shapes of every ``dot_general`` of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield tuple(v.aval.shape for v in eqn.invars)
+        for held in jax.core.jaxprs_in_params(eqn.params):
+            yield from _products(held)
+
+
+def _cell(form, boards=128, d=128, **heads):
+    """The operands' shapes of ``board_delta`` on a cell's batch: the sixth trunk's 16 heads, the seventh's 16 key and 32 value heads."""
+    sds = jax.ShapeDtypeStruct
+    if form == "first":
+        wide = (boards, SQUARES, heads.get("heads", 16) * d)
+        return (sds(wide, jnp.bfloat16),) * 3 + (sds(wide, jnp.float32), sds((boards, SQUARES, heads.get("heads", 16)), jnp.float32))
+    key_heads, value_heads = heads.get("key_heads", 16), heads.get("value_heads", 32)
+    by_head = sds((boards, SQUARES, value_heads), jnp.float32)
+    return (sds((boards, SQUARES, key_heads * d), jnp.bfloat16),) * 2 + (sds((boards, SQUARES, value_heads * d), jnp.bfloat16), by_head, by_head)
+
+
+@pytest.mark.parametrize("form,shape,a_turn,turns", [
+    # kda_trunk_train_b128: boards 2 t, 2 t + 1 a turn; the parent's 36 products a BOARD (c, dMq, Mq^T dO, w, dA, six levels of r and
+    # four, dg) are 25 a PAIR: every one of them one product of the pair, the six r ONE product, a level's dQL and dKL the halves of one
+    ("first", {}, 25, 4),
+    ("first", dict(boards=9), 25, 1),  # an odd block: one board a turn, the same lines on [64, .]
+    # gdn_trunk_train_b128: the parent's 2 + 6 a value head + 4 = 18 a board and key head are 1 + 6 a PAIR + 2 = 9
+    ("second", {}, 9, 8),
+    ("second", dict(value_heads=16), 9, 8),  # one value head a key head: the single chain, the key head's products joined all the same
+    ("second", dict(value_heads=48), 15, 8),  # three: a pair and one left over, six each
+], ids=["kda_cell", "an_odd_block", "gdn_cell", "one_value_head_a_key_head", "three_value_heads_a_key_head"])
+def test_the_gradient_bodies_hold_a_pair_s_products_once(form, shape, a_turn, turns, monkeypatch):
+    """The mechanism's counter (engagement is static: shapes decide it while the body is traced): the ``dot_general``s of each
+    gradient body on the two cells' shapes, this tree's against the parent's single-chain body traced in its place. A loop turn of
+    the first form holds 25 products for TWO boards where the parent's held 36 for one; the second form's 9 for a key head's two value
+    heads where the parent's held 18. On the cells' shapes no product of a pair is a single chain's ``[64, 64] x [64, .]``
+    but the second form's two triangles (``g`` and ``dD`` meet the triangle of ONE board). The loop stays rolled."""
+    args = _cell(form, **shape)
+    counted = {}
+    for parent in (False, True):
+        with gradient_bodies(monkeypatch, parent):
+            traced = jax.make_jaxpr(lambda *a: jax.vjp(lambda *b: board_delta(*b, False), *a)[1](a[2]))(*args)
+        (gradient,) = [call.params["jaxpr"] for _, call in _kernel_calls(traced.jaxpr) if call.params["name"] == "board_delta_grad"]
+        (loop,) = [eqn.params for eqn in gradient.eqns if eqn.primitive.name in ("scan", "while")]
+        counted[parent] = (list(_products(gradient)), loop["length"], loop["unroll"])
+    products, length, unroll = counted[False]
+    assert (len(products), length, unroll) == (a_turn, turns, 1), (len(products), length, unroll)
+    per = shape.get("value_heads", 32) // 16
+    parents, parent_length, _ = counted[True]
+    assert (len(parents), parent_length) == ((36, math.gcd(shape.get("boards", 128), 8)) if form == "first" else (2 + 6 * per + 4, 8))
+    if not shape:  # a cell: a pair's products are a pair's
+        single = [product for product in products if product[0] == (SQUARES, SQUARES) and product[1][0] == SQUARES]
+        assert single == ([] if form == "first" else [((SQUARES, SQUARES), (SQUARES, 2 * SQUARES))] * 2), single
+        assert len(products) * (1 if form == "second" else 0.5) <= len(parents) / 2  # a board's share: half the parent's, or less
+
+
 # -- tools/delta_alone.py: the pair timed alone ----------------------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("form,shape", [("gdn", ["--heads", "1", "--per", "2"]), ("kda", ["--heads", "2"])])
-def test_delta_alone_prints_its_three_times_and_what_it_ran_on(form, shape, capsys):
+def test_delta_alone_prints_its_four_times_and_what_it_ran_on(form, shape, capsys):
     """The tool as a builder runs it on the chip, here at a tiny shape under the interpreter (the times are the interpreter's and say
-    nothing of a device: ``interpret`` and ``device`` say so in the line): its three programs' times, and against its own tree's file
-    every array equal bit for bit."""
+    nothing of a device: ``interpret`` and ``device`` say so in the line): its four programs' times, the gradient kernel's by itself
+    among them, and against its own tree's file every array equal bit for bit."""
+    assert delta_alone.PROGRAMS == ("forward_ms", "forward_kept_ms", "forward_and_gradient_ms", "gradient_ms")
     assert delta_alone.main(["--form", form, "--boards", "2", "--d", "16", "--calls", "2", "--seed", "1", *shape, "--against", kernels.__file__]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["interpret"] is True and line["device"] == jax.devices()[0].device_kind and line["form"] == form and line["finite"] is True

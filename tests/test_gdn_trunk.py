@@ -169,16 +169,18 @@ def test_the_expert_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_
 # -- the step pins: the seventh block's own, and the sixth's, whose kernel pair shares ``ops/board_delta.py`` with it -------------------
 
 #: sha256 of the tiny lowered step programs (``tools/step_text.py --block gdn|kda``), as ``tests/test_hybrid_trunk.py
-#: PARENT_STEP_SHA256`` holds the four older blocks'. Both read on PR 55's tree, which MEANT to move both: the head norm under its gate
-#: after the core is one kernel pair (``ops/mamba_mix.py head_norm_gate``), whose two bodies the interpreter lays into the step as loops.
-#: Its parent (4fccc6c) read ``kda`` dc1e7fa9... and ``gdn`` 776060ad..., the pins as PR 53 left them (that PR MEANT to move both too: two
-#: chains a product in the forward solve). The ``--no-ids`` dumps of parent and change differ in every delta layer and in the numbering of
-#: what follows: the one ``rsqrt`` a layer of ``_rms_norm`` over the ``[tokens, heads, d]`` view and its transpose give way to an ``rsqrt``
-#: a head and body (30 -> 51 in ``gdn``'s dump, three layers of four heads; 40 -> 52 in ``kda``'s, four of two) inside six loops more a
-#: layer (the grid, the rows and the heads' turns, forward and gradient: 96 -> 114 and 110 -> 134 ``stablehlo.while``); ``hybrid``, whose mixer runs the
-#: file's four older kernels, reads what it read (``tests/test_hybrid_trunk.py``). A PR that means to change either reads its own parent the
-#: same way.
-GDN_STEP_SHA256 = {"kda": "2e221aae44ec95f39a1ef1ccecb6a4c8720815b460b6217b41ac0fc3120f9b0a", "gdn": "323fe50e85ccc08b5c979ffac60946b86dbb86bf1c0d41a9e7f00725ab464491"}
+#: PARENT_STEP_SHA256`` holds the four older blocks'. Both read on PR 58's tree (PR 57's, which was thrown away unmeasured: the same bodies), which MEANT to move both: the gradient kernels of
+#: ``ops/board_delta.py`` work two chains a product, and what of a single chain's products shares an operand is one product too. Its
+#: parent (be9aa8a) read ``kda`` 2e221aae... and ``gdn`` 323fe50e..., the pins as PR 55 left them (that PR MEANT to move both: the head
+#: norm under its gate as one kernel pair; PR 53 before it: two chains a product in the forward solve). The ``--no-ids`` dumps of parent and
+#: change differ inside ``board_delta_grad``'s loops alone, in every delta layer, and in the numbering of what follows: the tiny batch is ONE
+#: board a block, so the first form's body runs its single chain, and its products are 25 where they were 36 a board and head in ``kda``'s dump
+#: (the six levels' ``r`` one product, a level's ``dQL`` and ``dKL`` one: 550 -> 506 ``stablehlo.dot_general``, four layers); the second form's
+#: packs the tiny net's two value heads a key head, 9 products where they were 18 a board and key head in ``gdn``'s (the spans, ``Mq^T dO``, ``T^T
+#: dU``, ``dMq``, ``dA`` and ``dD``'s sums of the pair one each, the key head's six three: 231 -> 204, three layers); the loops are as many (134 and 114 ``stablehlo.while``) and every forward body reads what it
+#: read; ``hybrid``, whose mixer runs ``ops/mamba_mix.py``'s kernels, reads what it read (``tests/test_hybrid_trunk.py``). A PR that means to
+#: change either reads its own parent the same way.
+GDN_STEP_SHA256 = {"kda": "9e78ff9cc17bc116b82bf620c05b68429ea8c71c5ee77b045dbec8e3ab674f17", "gdn": "473e6939bbc3afe2f6503d6fba36537da237d79b4ff192f1aa879116cec3e1f6"}
 
 
 @pytest.mark.parametrize("block", GDN_STEP_SHA256)

@@ -1120,6 +1120,28 @@ def test_the_delta_forward_solves_two_chains_a_product_where_the_shapes_allow(sh
         assert _exact_products_a_turn(jax.make_jaxpr(fn)(*args).jaxpr) == wanted
 
 
+@pytest.mark.parametrize("shape", [dict(boards=9, heads=2), dict(boards=8, key_heads=2, value_heads=6), dict(boards=8, key_heads=2, value_heads=2), dict(boards=9, key_heads=2, value_heads=4)],
+                         ids=["an_odd_block", "three_value_heads_a_key_head", "one_value_head_a_key_head", "a_pair_of_value_heads_on_an_odd_block"])
+def test_the_delta_pairs_single_chain_fall_backs_compile(one_chip, compiled_for_tpu, shape):
+    """What no cell runs but the shapes allow: the pair, forward and gradient, where a block of boards is odd (one board a loop turn,
+    the pair's lines on ``[64, .]``) and where a key head's value heads are odd (a pair and one left over in one body, or one alone), at
+    a head of 128 columns. Mosaic takes each (the interpreter takes anything): two kernels, no fall-back of XLA's."""
+    from fishnet_tpu.ops.board_delta import board_delta
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    boards = shape["boards"]
+    if "heads" in shape:
+        wide = (boards, 64, shape["heads"] * 128)
+        args = (sds(wide, jnp.bfloat16),) * 3 + (sds(wide, jnp.float32), sds((boards, 64, shape["heads"]), jnp.float32))
+    else:
+        by_head = sds((boards, 64, shape["value_heads"]), jnp.float32)
+        args = (sds((boards, 64, shape["key_heads"] * 128), jnp.bfloat16),) * 2 + (sds((boards, 64, shape["value_heads"] * 128), jnp.bfloat16), by_head, by_head)
+    loss = lambda *a: jnp.sum(jnp.square(board_delta(*a, False).astype(jnp.float32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(*args).compile().as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and sum("board_delta_grad" in kernel for kernel in kernels) == 1 and all("board_delta" in kernel for kernel in kernels), kernels
+
+
 def test_gated_attention_at_a_head_of_256_with_64_columns_turned_compiles_at_published_widths(one_chip, compiled_for_tpu):
     """``value_and_grad`` of the seventh block's ``_attention``: the grouped normed form at ``head_dim`` 256, 8 query heads on each
     of 2 key-value heads (2 boards a grid step), RoPE on the first 64 of 256 columns, and ``_gated_out`` at 4,096 columns: two

@@ -5,11 +5,13 @@
 
 prints one JSON line: the device, the shape, and the host clock's median, least
 and most over ``--calls`` calls (after one warm call, each ended by
-``block_until_ready``) of the three programs a step holds of the pair::
+``block_until_ready``) of the three programs a step holds of the pair, and of
+the gradient kernel by itself::
 
     forward_ms                the primal: ``board_delta`` writes o alone
     forward_kept_ms           the differentiated forward: o and the kept arrays
     forward_and_gradient_ms   ``value_and_grad`` of a weighted sum of o: both kernels
+    gradient_ms               ``board_delta_grad`` alone, on the kept arrays the differentiated forward wrote
 
 The defaults are the two cells' shapes: ``gdn`` 128 boards x 16 key heads x 2
 value heads a key head (``gdn_trunk_train_b128``), ``kda`` 128 boards x 16
@@ -40,7 +42,7 @@ import numpy as np
 
 REPO = Path(__file__).resolve().parent.parent
 
-PROGRAMS = ("forward_ms", "forward_kept_ms", "forward_and_gradient_ms")
+PROGRAMS = ("forward_ms", "forward_kept_ms", "forward_and_gradient_ms", "gradient_ms")
 GRADIENTS = ("dq", "dk", "dv", "dg", "dbeta")
 
 
@@ -68,17 +70,19 @@ def load(path: Path):
 
 
 def measure(module, ops, weight, interpret: bool, calls: int):
-    """The three programs of ``module``'s pair on ``ops``: what each made (float32 on the host) and its times."""
+    """The four programs of ``module``'s pair on ``ops``: what each made (float32 on the host) and its times."""
     forward = jax.jit(lambda *a: module.board_delta(*a, interpret))
     kept = jax.jit(lambda *a: module._board_delta_fwd(*a, interpret))
     both = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(module.board_delta(*a, interpret).astype(jnp.float32) * weight), argnums=tuple(range(5))))
+    gradient = jax.jit(lambda residuals, do: module._board_delta_bwd(interpret, residuals, do))
     times, made = {}, {}
-    for name, fn in zip(PROGRAMS, (forward, kept, both)):
-        made[name] = jax.block_until_ready(fn(*ops))  # the warm call
+    for name, fn in zip(PROGRAMS, (forward, kept, both, gradient)):
+        args = ops if name != "gradient_ms" else (made["forward_kept_ms"][1], weight.astype(jnp.bfloat16))  # o's cotangent as ``both`` hands it on
+        made[name] = jax.block_until_ready(fn(*args))  # the warm call
         taken = []
         for _ in range(calls):
             start = time.perf_counter()
-            jax.block_until_ready(fn(*ops))
+            jax.block_until_ready(fn(*args))
             taken.append((time.perf_counter() - start) * 1e3)
         times[name] = {"median": float(np.median(taken)), "min": min(taken), "max": max(taken)}
     o_kept, (*_, tables) = made["forward_kept_ms"]
