@@ -21,7 +21,7 @@ TPU shaping choices:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Union
+from typing import Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -107,11 +107,15 @@ def az_forward(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):
     return az_forward_counted(params, planes, cfg)[:2]
 
 
-def az_forward_counted(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):
+def az_forward_counted(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig(), square_masked: Optional[jax.Array] = None):
     """``az_forward`` and the network's counters for the training step's
-    metrics: none for the tower, the routing counters for the trunk."""
+    metrics: none for the tower, the routing counters for the trunk. Told
+    a batch's ``square_masked`` (a block-diffusion trunk's training
+    forward) the denoiser's logits follow the counters."""
     if isinstance(cfg, TrunkConfig):
-        return trunk_forward_counted(params, planes, cfg)
+        return trunk_forward_counted(params, planes, cfg, square_masked)
+    if square_masked is not None:
+        raise ValueError("square_masked is a block-diffusion trunk's: the tower has no denoiser")
     return (*policy_value_heads(params, _tower(params, planes, cfg)), {})
 
 

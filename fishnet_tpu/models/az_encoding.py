@@ -24,6 +24,8 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 INPUT_PLANES = 19
+#: The first planes are the pieces' (own, then the opponent's, ``_PIECE_ORDER`` each): at most one is set a square. The rest are board-wide.
+PIECE_PLANES = 12
 POLICY_SIZE = 64 * 73
 
 _PIECE_ORDER = "PNBRQK"

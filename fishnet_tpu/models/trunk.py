@@ -4,10 +4,11 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes eight
-published blocks as one code path at different values; no attention has
-a causal mask here, and a board is far shorter than any's window, so
-running them over a board removes nothing.
+tower's own policy and value heads. ``TrunkConfig`` describes nine
+published blocks as one code path at different values; no attention but
+the ninth block's (block diffusion: its mask is what it trains under) has a
+causal mask here, and a board is far shorter than any's window, so running
+them over a board removes nothing.
 
 The program reads a trunk as a LIST OF SUBLAYERS (``trunk_plan``, made once a configuration), each ``x <- x +
 [post-norm](kind(norm(x)))``, and runs ONE loop over it (``trunk_forward_counted``). A kind is one function ``(x, p, cfg,
@@ -352,6 +353,49 @@ float32 formula). The block's own cost is elsewhere: a moved row of 2,304 =
 18 lane tiles goes as 3,072 (``_whole_rows``), and 8 of 64 experts at top-8
 hold ONE slot a token on average, eight times the other shares' rows.
 
+The ninth block is SDAR-30B-A3B-Chat's (JetLM, config.json, ``model_type``
+sdar_moe: hidden 2048, 48 identical layers, 32 query heads over 4 key-value
+heads of 128 with qk-norm, RoPE theta 1e6 without scaling, 128 experts of
+width 768, softmax scores, top-8 renormalised, no shared expert, no dense
+layer, no window; RMSNorm eps 1e-6): the eighth block's layer under ONE
+plain table, and what it adds is no number of a layer but how the net is
+TRAINED, generation by diffusion over blocks (SDAR, arXiv:2510.06303, trained
+as BD3-LMs are, arXiv:2503.09573); what its config.json does not say is listed
+under ``assumed`` in ``benchmark/configs/sdar-30b-a3b-trunk-train.json``.
+``TrunkConfig.block_length`` L: a board's 64 squares in the trunk's order are
+64 / L blocks, ``blk(s) = s // L``. A training batch carries the noise
+(``train/data.py block_noise``): a level ``t_b`` a board and block, a mask
+``m_s`` a square, masked with probability ``t_blk(s)``::
+
+    two streams, one set of weights, 128 tokens a board: rows 0-63 the clean copy, 64-127 the noised one
+    embed     x^c_s = t_s W_in + b_in;   x^n_s = t~_s W_in + b_in + m_s e_mask      t~_s: t_s with its 12 piece planes zeroed where m_s = 1 (the 7
+                                                                        board-wide planes stay); ``mask_embed`` [hidden], learned
+    layer     the eighth block's, on both streams alike: a = x + Attn(N_in(x));  y = a + MoE(N_post(a))
+    Attn      q, k, v, qk-norm and RoPE as the eighth block's plain layers', position = SQUARE index in both copies; one softmax over the allowed keys:
+                a clean query i sees the clean keys j with blk(j) <= blk(i), never a noised key
+                a noised query i sees the noised keys j with blk(j) = blk(i) and the clean keys j with blk(j) < blk(i)
+    MoE       a token of either stream: the eighth block's router and held experts
+    out       N_final on both streams; the policy and value heads read the CLEAN stream; the denoiser reads the NOISED one:
+              z_s = N_final(x^n_s) W_d + b_d  [13]: a square's class, empty or one of the 12 piece planes (``denoise_w``, ``denoise_b``)
+    loss      policy + value_weight x value + denoise_weight x (1 / (boards x 64)) sum_s m_s (1 / t_blk(s)) CE(z_s, class_s)      (``train/az_trainer.py _loss``)
+    served    (``trunk_forward``: no noise) the clean stream alone under its block-causal rule, 64 tokens a board: the training forward's
+              clean stream exactly, which no noised token reaches
+
+Mechanism, the ninth block: no kind and no tensor of a layer of its own.
+``trunk_forward_counted`` told ``square_masked`` lays the two copies side by
+side, ``[boards x 128, hidden]``, and everything a token at a time
+(projections, norms, the router, the moves, the grouped products, the
+combine) runs on 128 tokens a board as it runs on 64: nothing in the routed
+path knows a board's length. The attention core alone has to know which rows
+are which copy: ``Sublayer.streams`` tells ``_attention``, which hands
+``board_attention`` the block length and the streams, and the core is a
+kernel pair of its own (``ops/board_attention.py``: ``board_attention_blocks``,
+``board_attention_blocks_grad``): a key-value head's k, both copies, normed,
+turned and read once for the clean and the noised queries of its group, the
+64 x 64 and 128 x 64 scores in VMEM, what the mask forbids taken out before
+the softmax's maximum and sums, dk and dv of the clean copy summed over both
+copies' queries before they are written.
+
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums
 over heads (a KDA head's state, norm and gate are its own; the latent is
@@ -466,7 +510,7 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tgmm as megablox_tgmm
 
-from fishnet_tpu.models.az_encoding import INPUT_PLANES
+from fishnet_tpu.models.az_encoding import INPUT_PLANES, PIECE_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
 from fishnet_tpu.ops.board_attention import SQUARES, board_attention, yarn_rope_tables
 from fishnet_tpu.ops.board_delta import board_delta
@@ -479,6 +523,8 @@ from fishnet_tpu.ops.row_move import held_places, row_view, rows_back, rows_cove
 Params = Dict[str, jax.Array]
 
 _INIT_STD = 0.02
+#: What the ninth block's denoiser tells apart on a square: empty, or one of the 12 piece planes.
+SQUARE_CLASSES = 1 + PIECE_PLANES
 #: Mamba-2's ``time_step_min``, ``time_step_max`` and ``time_step_floor``: where a fresh mixer's steps lie (``init_trunk_params``).
 _TIME_STEP_MIN, _TIME_STEP_MAX, _TIME_STEP_FLOOR = 0.001, 0.1, 1e-4
 #: A fresh GDN head's rate ``exp(gdn_A_log)`` is uniform in (0, 16): this much over 0, so that no draw's logarithm is -inf.
@@ -568,6 +614,11 @@ class TrunkConfig:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    # What the ninth block adds (module docstring): block diffusion over a board. ``block_length``: the squares of a block; every attention layer is
+    # then under the block mask (``ops.board_attention.block_mask``): the clean copy alone where the net is served, a clean and a noised copy where
+    # it is trained (``trunk_forward_counted`` told ``square_masked``), and the trunk has a mask embedding and a third head, the denoiser. 0: the
+    # eight blocks above, no mask.
+    block_length: int = 0
 
     def __post_init__(self) -> None:
         first, count = self.held
@@ -646,6 +697,11 @@ class TrunkConfig:
             f"got {yarn}": self.rope_type == "yarn" and not (self.rope_factor >= 1.0 and self.original_max_position_embeddings > 0
                                                               and self.beta_fast > self.beta_slow > 0.0 and self.attention_factor > 0.0),
             f"YaRN's numbers {yarn} stand beside rope_type default": self.rope_type == "default" and yarn != (1.0, 0, 32.0, 1.0, 1.0),
+            f"block_length {self.block_length} does not divide the {SQUARES} squares of a board, or stands beside what the block-masked core does not "
+            "compute: it is the first kind's attention on every layer (no latent, cca, pattern or mixers) with qk-norm and RoPE over all of a head on "
+            "every layer under one table (no rotary_dim, nope_layers or full_attention_layers)":
+                self.block_length != 0 and (not 0 < self.block_length <= SQUARES or SQUARES % self.block_length != 0 or latent or cca or bool(pattern)
+                                            or bool(mixers) or not self.qk_norm or self.rotary_dim is not None or bool(self.nope_layers) or bool(full)),
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -674,16 +730,20 @@ class Sublayer(NamedTuple):
     rope: bool = False  # an attention's: RoPE on its queries and keys
     post_norm: Optional[str] = None
     rope_type: str = "default"  # where it turns, WHICH table by: "default" the plain one of ``rope_theta``, "yarn" the ``full_attention`` layers' (``_attention``)
+    streams: int = 1  # an attention's under ``block_length``: the copies of a board side by side along its rows, 2 where a noised copy follows the clean one
 
 
 @functools.lru_cache(maxsize=None)
-def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
+def trunk_plan(cfg: TrunkConfig, streams: int = 1) -> Tuple[Sublayer, ...]:
     """The trunk's sublayers in order: one a character of a pattern, each
     under ``layer_norm[i]``; else two a layer, a token mixer under
     ``attn_norm[i]`` (the layer's own of ``mixers``, or the one mixer of
     the whole trunk) and a feed-forward under ``moe_norm[i]``, dense in
     the leading ``dense_layers``. Everything between ``embed`` and
-    ``final_norm`` reads this and not the fields it is made from."""
+    ``final_norm`` reads this and not the fields it is made from.
+    ``streams``: the copies of a board that ride side by side (2: the
+    ninth block's training forward), which its attention sublayers are
+    told and nothing else needs to know."""
     table = lambda i: cfg.rope_type if i in cfg.full_attention_layers else "default"  # the layer's kind's: full_attention or sliding_attention
     if cfg.pattern:
         kinds = [{"M": "mamba", "E": "routed", "*": "attention"}[kind] for kind in cfg.pattern]
@@ -696,7 +756,7 @@ def trunk_plan(cfg: TrunkConfig) -> Tuple[Sublayer, ...]:
     plan = []
     for i in range(cfg.layers):
         ffn = ("dense", i) if i < cfg.dense_layers else ("routed", i - cfg.dense_layers)
-        plan += [Sublayer(f"layer{i:02d}", mixers[i], mixers[:i].count(mixers[i]), "attn_norm", i, i not in cfg.nope_layers, after_mixer, table(i)),
+        plan += [Sublayer(f"layer{i:02d}", mixers[i], mixers[:i].count(mixers[i]), "attn_norm", i, i not in cfg.nope_layers, after_mixer, table(i), streams),
                  Sublayer(f"layer{i:02d}", *ffn, "moe_norm", i, post_norm=after_ffn)]
     return tuple(plan)
 
@@ -774,7 +834,7 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
     over the sublayers of that kind, none where the plan has none. The
     ORDER of the keys is part of the result (``_LATE``)."""
     plan, h = trunk_plan(cfg), cfg.hidden
-    shapes = {"embed_w": (INPUT_PLANES, h), "embed_b": (h,)}
+    shapes = {"embed_w": (INPUT_PLANES, h), "embed_b": (h,), **({"mask_embed": (h,)} if cfg.block_length else {})}
     for kinds in (_MIXERS, _FEED_FORWARDS):  # the token mixers' norm and tensors, then the feed-forwards'
         shapes.update({s.norm: (cfg.layers, h) for s in plan if s.kind in kinds and s.norm not in shapes})
         for kind in kinds:
@@ -790,6 +850,7 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
         "value_w": (1, 1, h, 4), "value_b": (4,),
         "value_fc1_w": (4 * SQUARES, cfg.value_hidden), "value_fc1_b": (cfg.value_hidden,),
         "value_fc2_w": (cfg.value_hidden, 1), "value_fc2_b": (1,),
+        **({"denoise_w": (h, SQUARE_CLASSES), "denoise_b": (SQUARE_CLASSES,)} if cfg.block_length else {}),
     })
     return {**{name: shape for name, shape in shapes.items() if name not in _LATE}, **{name: shapes[name] for name in _LATE if name in shapes}}
 
@@ -982,9 +1043,9 @@ def _dense_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) 
         return _gated_ffn(_rms_norm(x, p[sublayer.norm], cfg.rms_eps), p, "dense"), {}
 
 
-def _by_board(y: jax.Array) -> jax.Array:
-    """``[tokens, columns]`` as the kernels take it, ``[boards, 64, columns]``."""
-    return y.reshape(-1, SQUARES, y.shape[-1])
+def _by_board(y: jax.Array, streams: int = 1) -> jax.Array:
+    """``[tokens, columns]`` as the kernels take it, ``[boards, 64, columns]`` (``streams`` copies of a board side by side: ``64 x streams`` rows)."""
+    return y.reshape(-1, SQUARES * streams, y.shape[-1])
 
 
 def _mamba(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -1032,7 +1093,9 @@ def _shifted_values(v12: jax.Array, kv_heads: int) -> jax.Array:
 
 def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The attention kind (the first, second and fourth blocks'):
-    [tokens, hidden] float32, 64 tokens a board -> the branch's output,
+    [tokens, hidden] float32, 64 tokens a board (``64 x sublayer.streams``
+    under ``block_length``: a board's copies side by side, which the core
+    alone is told) -> the branch's output,
     same shape, before its post-norm; it counts nothing. The projections
     are XLA's; everything between them is ``board_attention``; a gated
     branch's gate and out-projection are ``_gated_out``. As every
@@ -1040,10 +1103,12 @@ def _attention(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) ->
     layer's): ``<layer>.attention``."""
     with jax.named_scope(f"{sublayer.layer}.attention"):
         n1 = _rms_norm(x, p[sublayer.norm], cfg.rms_eps)
-        q, k, v = (_by_board(_matmul(n1, p[name])) for name in ("wq", "wk", "wv"))
+        q, k, v = (_by_board(_matmul(n1, p[name]), sublayer.streams) for name in ("wq", "wk", "wv"))
         gains = dict(g_q=p["q_norm"], g_k=p["k_norm"]) if cfg.qk_norm else dict(g_q=None, g_k=None, head_dim=cfg.head_dim)
+        masked = dict(block_length=cfg.block_length, streams=sublayer.streams) if cfg.block_length else {}  # the ninth block: which rows are which copy
         mixed = board_attention(q, k, v.astype(jnp.bfloat16), theta=cfg.rope_theta if sublayer.rope else None, eps=cfg.rms_eps,
-                                interpret=_interpret(), rotary_dim=cfg.rotary_dim, tables=_yarn_tables(cfg) if sublayer.rope_type == "yarn" else None, **gains)
+                                interpret=_interpret(), rotary_dim=cfg.rotary_dim, tables=_yarn_tables(cfg) if sublayer.rope_type == "yarn" else None, **gains,
+                                **masked)
         mixed = mixed.reshape(x.shape[0], -1)
         if cfg.gated_attention:
             return _gated_out(n1, mixed, p["wgate"], p["wo"]), {}
@@ -1669,7 +1734,7 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
 
 
 def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
-    """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]), float32."""
+    """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]), float32: what is served (the ninth block: the clean stream alone)."""
     return trunk_forward_counted(params, planes, cfg)[:2]
 
 
@@ -1736,18 +1801,43 @@ def centred_gains(params: Params, cfg: TrunkConfig) -> Params:
     return {name: 1.0 + value if name in _ZERO_CENTERED else value for name, value in params.items()} if cfg.zero_centered_norms else params
 
 
-def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
+def _two_streams(planes: jax.Array, square_masked: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A batch's planes ``[boards, 8, 8, 19]`` and its mask ``[boards,
+    64]`` -> the tokens of the clean and the noised copy of every board
+    side by side, ``[boards x 128, 19]`` (a masked square's 12 piece
+    planes zeroed in the noised copy, the 7 board-wide planes as they
+    are), and which of them take the mask embedding, ``[boards x 128,
+    1]`` float32: the noised copy's masked squares."""
+    tokens = planes.reshape(planes.shape[0], SQUARES, INPUT_PLANES)
+    m = square_masked.astype(jnp.float32)[:, :, None]
+    pieces = (np.arange(INPUT_PLANES) < PIECE_PLANES).astype(np.float32)
+    both, marked = jnp.concatenate([tokens, tokens * (1.0 - m * pieces)], axis=1), jnp.concatenate([jnp.zeros_like(m), m], axis=1)
+    return both.reshape(-1, INPUT_PLANES), marked.reshape(-1, 1)
+
+
+def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig, square_masked: Optional[jax.Array] = None):
     """``trunk_forward`` and the counters of the step's metrics: what
     the sublayers counted, folded over the sublayers as ``_FOLDS`` says
-    (which also says what each one is), and the whole trunk's own three."""
+    (which also says what each one is), and the whole trunk's own three.
+    Told ``square_masked`` (bool ``[B, 64]``, a batch's noise: the ninth
+    block's training forward) it carries a clean and a noised copy of
+    every board through the layers, 128 tokens a board, the heads read
+    the clean copy and a fourth result follows the counters: the
+    denoiser's logits ``[B, 64, 13]`` float32 off the noised copy."""
     b, params = planes.shape[0], centred_gains(params, cfg)
+    streams = 1 if square_masked is None else 2
+    if streams == 2 and not cfg.block_length:
+        raise ValueError("square_masked is a block-diffusion trunk's (block_length): this one has no mask embedding and no denoiser")
     # Scope names are a contract (doc/observability.md "Training and compilation"): one scope a part, the layer in its name,
     # because the benchmark's scope table keeps two levels of a path (phase, then this).
     with jax.named_scope("embed"):
-        x = _matmul(planes.reshape(b * SQUARES, INPUT_PLANES), params["embed_w"]) + params["embed_b"]
+        tokens, marked = (planes.reshape(b * SQUARES, INPUT_PLANES), None) if streams == 1 else _two_streams(planes, square_masked)
+        x = _matmul(tokens, params["embed_w"]) + params["embed_b"]
+        if marked is not None:  # both copies went through the one embedding; the mask embedding on the noised copy's masked squares
+            x = x + marked * params["mask_embed"]
         x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
     counters = []
-    for sublayer, p in _sliced(params, trunk_plan(cfg)):
+    for sublayer, p in _sliced(params, trunk_plan(cfg, streams)):
         run, scope = _KINDS[sublayer.kind]
         branch, counted = run(x, p, cfg, sublayer)
         with jax.named_scope(f"{sublayer.layer}.{scope}"):
@@ -1755,6 +1845,8 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
         counters.append(counted)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
+    if streams == 2:
+        x, noised = (x.reshape(b, 2, SQUARES, cfg.hidden)[:, copy] for copy in range(2))
     features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
     slots = jnp.stack([c["expert_slots"] for c in counters if "expert_slots" in c])
     heads = policy_value_heads(params, features)
@@ -1769,6 +1861,9 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig):
             folded[name] = jnp.sum(slots[:, cfg.held[0]:sum(cfg.held)])
         elif name == "expert_bias_abs_max" and "expert_bias" in params:
             folded[name] = jnp.max(jnp.abs(params["expert_bias"]))
+    if streams == 2:
+        with jax.named_scope("denoise"):
+            return (*heads, folded, _matmul(noised.reshape(b * SQUARES, cfg.hidden), params["denoise_w"]).reshape(b, SQUARES, -1) + params["denoise_b"])
     return (*heads, folded)
 
 
@@ -1814,6 +1909,8 @@ _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_
             "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim", "zero_centered")
 #: What a file with ``full_attention_layers`` carries after them (no other file: one of the seven older blocks is what it was).
 _ROPE_HPARAMS = ("full_mask", "yarn", "rope_factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor")
+#: What a file with ``block_length`` carries after both (no other file).
+_BLOCK_HPARAMS = ("block_length",)
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
 #: A checkpoint whose mixer is told by layer carries ``mixers``, each layer's kind as its place in ``_MIXERS``.
@@ -1832,12 +1929,15 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     or 1; its sizes and its token gate are shapes); after them, in a file
     with ``full_attention_layers`` alone (a file of the seven older blocks
     is what it was), the eighth's: those layers as a bit mask,
-    ``rope_type`` yarn 0 or 1, and YaRN's five numbers. A pattern's file carries the pattern
+    ``rope_type`` yarn 0 or 1, and YaRN's five numbers; after those, in a
+    file with ``block_length`` alone, the ninth's block length (its mask
+    embedding and denoiser are tensors). A pattern's file carries the pattern
     too (``trunk_pattern``, its characters as bytes), one whose mixer is
     told by layer its ``mixers`` (``trunk_mixers``).
     ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
-    arrays[HPARAMS] = _hparams(cfg)[:None if cfg.full_attention_layers else len(_HPARAMS)]
+    later = (_ROPE_HPARAMS + _BLOCK_HPARAMS) if cfg.block_length else _ROPE_HPARAMS if cfg.full_attention_layers else ()
+    arrays[HPARAMS] = _hparams(cfg)[:len(_HPARAMS) + len(later)]
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
     if cfg.mixers:
@@ -1846,14 +1946,14 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
 
 
 def _hparams(cfg: TrunkConfig) -> np.ndarray:
-    """Every value ``_HPARAMS`` and ``_ROPE_HPARAMS`` name, of ``cfg``."""
+    """Every value ``_HPARAMS``, ``_ROPE_HPARAMS`` and ``_BLOCK_HPARAMS`` name, of ``cfg``."""
     return np.asarray([
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
         cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
         cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
         cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0, cfg.zero_centered_norms,
         sum(1 << i for i in cfg.full_attention_layers), cfg.rope_type == "yarn", cfg.rope_factor, cfg.original_max_position_embeddings,
-        cfg.beta_fast, cfg.beta_slow, cfg.attention_factor], np.float64)
+        cfg.beta_fast, cfg.beta_slow, cfg.attention_factor, cfg.block_length], np.float64)
 
 
 def _attention_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the head width from the qk-norm's gains, or the file's
@@ -1919,9 +2019,12 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         raise ValueError(not_one(missing))
     given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
     defaults = _hparams(TrunkConfig())
-    if not 3 <= len(given) <= len(_HPARAMS) + len(_ROPE_HPARAMS):
-        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(_HPARAMS) + len(_ROPE_HPARAMS)}")
-    hp = dict(zip((*_HPARAMS, *_ROPE_HPARAMS), [*given, *defaults[len(given):]]))
+    names = (*_HPARAMS, *_ROPE_HPARAMS, *_BLOCK_HPARAMS)
+    if not 3 <= len(given) <= len(names):
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(names)}")
+    hp = dict(zip(names, [*given, *defaults[len(given):]]))
+    if len(given) == len(names) and not hp["block_length"]:  # only a file with a block length carries one (``trunk_checkpoint``)
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, the last a block length of 0: a file without one has {len(names) - 1} at most")
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
     width_of = lambda name: shape(name)[2] if name in params else 0
     if pattern is not None:  # the mixers its characters name
@@ -1956,7 +2059,7 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
         full_attention_layers=tuple(i for i in range(layers) if int(hp["full_mask"]) >> i & 1), rope_type="yarn" if hp["yarn"] else "default",
         rope_factor=hp["rope_factor"], original_max_position_embeddings=int(hp["original_max_position_embeddings"]),
         beta_fast=hp["beta_fast"], beta_slow=hp["beta_slow"], attention_factor=hp["attention_factor"],
-        router_hidden=shape("router_down")[2] if "router_down" in params else 0,
+        router_hidden=shape("router_down")[2] if "router_down" in params else 0, block_length=int(hp["block_length"]),
     ))
 
 

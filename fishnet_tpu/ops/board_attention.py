@@ -60,9 +60,16 @@ both). They are an operand like any other table: the kernels and their
 programs are the plain layer's, and a row that is a scaled rotation is
 un-turned in the gradient by the transpose of what turned it.
 
-The same pair has a second, latent form (the end of this file): no norm,
+The same pair has a second, latent form (further down): no norm,
 RoPE on a trailing part of the score width, one rotated key for all
 heads. ``board_attention`` is told which, and does not guess.
+
+A third form is a kernel pair of its own, ``board_attention_blocks`` and
+``board_attention_blocks_grad`` (the end of this file): the normed form
+under a BLOCK MASK, told ``block_length`` and how many ``streams`` of a
+board ride side by side along the rows, a clean copy alone (64 rows,
+block-causal) or a clean and a noised copy (128 rows: block-diffusion
+training, ``block_mask``). The pair above is not touched by it.
 
 Off the TPU both kernels run under the Pallas interpreter.
 """
@@ -79,7 +86,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SQUARES", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables", "yarn_rope_tables"]
+__all__ = ["SQUARES", "block_mask", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables", "yarn_rope_tables"]
 
 SQUARES = 64
 
@@ -243,6 +250,19 @@ def _rounded(x: jax.Array) -> jax.Array:
     return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
+def _score_gradients(p: jax.Array, kb: jax.Array, qb: jax.Array, vb: jax.Array, do: jax.Array, scale) -> Tuple[jax.Array, jax.Array]:
+    """What both normed gradient kernels do with one query head's
+    probabilities ``[key, query]`` (float32; exactly 0 where a mask
+    forbade) and its output's cotangent: the turned queries' cotangent,
+    rounded as float32 code reads a bfloat16 value's, and this head's part of
+    the turned keys', float32 for the caller's sum over the heads."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    dp = _rounded(jax.lax.dot_general(vb, do, (((1,), (1,)), ((), ())), preferred_element_type=f32))
+    ds = (p * (dp - jnp.sum(dp * p, axis=0, keepdims=True)) * scale).astype(bf16)
+    dq_rot = _rounded(jax.lax.dot_general(ds, kb, (((0,), (0,)), ((), ())), preferred_element_type=f32))
+    return dq_rot, jnp.dot(ds, qb, preferred_element_type=f32)
+
+
 def _unrope_unnorm(d_rot: jax.Array, unit: jax.Array, r: jax.Array, gain: Optional[jax.Array], cos: jax.Array, sin: jax.Array, rope: bool,
                    half: Optional[int] = None):
     """The cotangent of a normed and rotated ``[64, head_dim]`` back to
@@ -294,10 +314,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_r
             dv = dv_g if g == 0 else dv + dv_g  # float32 sums over the group's query heads, rounded once
             if g == group - 1:
                 dv_ref[b] = dv.astype(dv_ref.dtype)
-            dp = _rounded(jax.lax.dot_general(vb, do, (((1,), (1,)), ((), ())), preferred_element_type=f32))
-            ds = (p * (dp - jnp.sum(dp * p, axis=0, keepdims=True)) * scale).astype(bf16)
-            dq_rot = _rounded(jax.lax.dot_general(ds, kb, (((0,), (0,)), ((), ())), preferred_element_type=f32))
-            dk_g = jnp.dot(ds, qb, preferred_element_type=f32)
+            dq_rot, dk_g = _score_gradients(p, kb, qb, vb, do, scale)
             dk_rot = dk_g if g == 0 else dk_rot + dk_g
             dq, dgq_g = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope, half)
             dgq = dgq + dgq_g
@@ -358,7 +375,8 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
                     theta: Optional[float], eps: float, interpret: bool = False,
                     q_pe: Optional[jax.Array] = None, k_pe: Optional[jax.Array] = None, head_dim: Optional[int] = None,
-                    rotary_dim: Optional[int] = None, tables: Optional[Tuple[np.ndarray, np.ndarray]] = None) -> jax.Array:
+                    rotary_dim: Optional[int] = None, tables: Optional[Tuple[np.ndarray, np.ndarray]] = None,
+                    block_length: Optional[int] = None, streams: int = 1) -> jax.Array:
     """The attention core (module docstring). What it is told, and does
     not guess: the norm (the gains ``[head_dim]``, or None for none, and
     then ``head_dim`` itself unless the form is the latent one; ``g_q``
@@ -378,7 +396,12 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     the normed form over all of a head under a ``theta``, which then only
     says that the layer turns). The kernels read the tables as an operand
     and assume nothing of them: rows that are scaled rotations are turned
-    and un-turned as they are.
+    and un-turned as they are. ``block_length`` (None: no mask, every form
+    above) puts the normed form with both gains and RoPE over all of a head
+    under the block mask of ``block_mask(block_length, streams)``: q, k, v
+    are then ``[boards, 64 * streams, ..]``, a board's clean copy on rows
+    ``[0, 64)`` and, at ``streams`` 2, its noised copy on rows ``[64,
+    128)``, both turned by the square index (the end of this file).
 
     Normed form: q float32 ``[boards, 64, heads * head_dim]``, k float32
     and v bfloat16 ``[boards, 64, kv_heads * head_dim]`` -> bfloat16 of
@@ -388,6 +411,10 @@ def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.
     value]`` -> bfloat16 of v's shape. The combinations the kernels do
     not compute are refused."""
     latent = (g_q is None, g_k is None, q_pe is not None, k_pe is not None)
+    if block_length is not None:
+        if any(latent) or head_dim is not None or theta is None or rotary_dim is not None or g_k.ndim != 1:
+            raise ValueError("board_attention puts the normed form with both gains and RoPE over all of a head under a block mask, and no other")
+        return _blocks_attention(q, k, v, g_q, g_k, eps, interpret, block_length, streams, tables or _tables(theta, g_q.shape[-1], None))
     if tables is not None and (theta is None or rotary_dim is not None or latent[2] or latent[3]):
         raise ValueError("board_attention takes tables for the normed form's RoPE over all of a head (a theta, no rotary_dim, no q_pe or k_pe)")
     if all(latent) and theta is not None and rotary_dim in (None, 0):  # 0: the same pair under tables that turn nothing (``_latent_tables``)
@@ -674,3 +701,183 @@ def _latent_attention_bwd(theta, interpret, residuals, d_mixed):
 
 
 _latent_attention.defvjp(_latent_attention_fwd, _latent_attention_bwd)
+
+
+# -- the block-masked form ----------------------------------------------------------------------------------------
+#
+# ``board_attention(q, k, v, g_q, g_k, theta, eps, interpret, block_length=L, streams=S)``: block-diffusion training
+# over a board (SDAR, arXiv:2510.06303, trained as BD3-LMs are, arXiv:2503.09573). The 64 squares are 64 / L blocks,
+# ``blk(s) = s // L``. With S = 2 a board's rows are its CLEAN copy (rows 0-63) and its NOISED copy (rows 64-127) of
+# the same squares under one set of weights, and (``block_mask``):
+#
+#     a clean query i sees the clean keys j with blk(j) <= blk(i), never a noised key;
+#     a noised query i sees the noised keys j with blk(j) = blk(i) AND the clean keys j with blk(j) < blk(i), ONE softmax
+#     over both; never a clean key of its own or a later block, never a noised key of another block.
+#
+# With S = 1 there is the clean copy alone under its first rule: what is served. Both copies are normed and turned as
+# the plain form's rows are, position = SQUARE index in both (a noised square and its clean twin turn alike: the tables
+# are laid twice along the rows). A grid step is one key-value head of a few boards and its group of query heads: the
+# head's k (both copies) is normed, turned and read ONCE for the clean and the noised queries of the group; the scores
+# stand ``[key, query]``, 64 x 64 for the clean queries and 128 x 64 for the noised ones (the clean queries x noised
+# keys quarter, which is all masked, is never made), and never reach HBM; what is not allowed is taken out BEFORE the
+# softmax's maximum and sums by a bias of -inf that is an operand like the tables (``_block_bias``; its probability is
+# exactly 0, forward and in the gradient's recomputation, and so is its ``ds``); the gradient sums dk, dv of the clean
+# copy over both copies' queries and over the group in VMEM, float32, rounded once.
+
+
+def block_mask(block_length: int, streams: int = 2) -> np.ndarray:
+    """Who sees whom, bool ``[query, key]`` over a board's ``64 * streams``
+    rows (the comment above): row and column ``s`` the clean square ``s``,
+    ``64 + s`` the noised one."""
+    if not 0 < block_length <= SQUARES or SQUARES % block_length or streams not in (1, 2):
+        raise ValueError(f"a block of {block_length} squares does not divide the {SQUARES} of a board, or {streams} streams are neither the clean copy alone nor both")
+    blk = np.arange(SQUARES) // block_length
+    clean = blk[None, :] <= blk[:, None]
+    if streams == 1:
+        return clean
+    none = np.zeros_like(clean)
+    return np.block([[clean, none], [blk[None, :] < blk[:, None], blk[None, :] == blk[:, None]]])
+
+
+def _block_bias(block_length: int, streams: int) -> np.ndarray:
+    """The mask as the kernels add it to their ``[key, query]`` scores, 0
+    where allowed and -inf where not, float32: rows ``[0, 64)`` the clean
+    keys under the clean queries, then (two streams) rows ``[64, 192)``
+    all 128 keys under the noised queries."""
+    mask = block_mask(block_length, streams)
+    parts = [mask[:SQUARES, :SQUARES].T] + ([mask[SQUARES:].T] if streams == 2 else [])
+    return np.where(np.concatenate(parts), 0.0, -np.inf).astype(np.float32)
+
+
+def _stream(s: int) -> slice:
+    """The rows of copy ``s`` of a board: 0 the clean one, 1 the noised one."""
+    return slice(s * SQUARES, (s + 1) * SQUARES)
+
+
+def _blocks_forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, bias_ref, out_ref, *, eps: float, unroll: int):
+    cos, sin, gq, gk = cos_ref[...], sin_ref[...], gq_ref[...], gk_ref[...]
+    head_dim, streams = k_ref.shape[-1], q_ref.shape[1] // SQUARES
+    bias = [bias_ref[:SQUARES]] + ([bias_ref[SQUARES:]] if streams == 2 else [])
+    prepared = lambda x, gain: _rope(_unit(x, eps)[0] * gain, cos, sin).astype(jnp.bfloat16)
+
+    def board(b, carry):
+        kb, vb = prepared(k_ref[b], gk), v_ref[b]
+        for g in range(q_ref.shape[-1] // head_dim):
+            lanes = slice(g * head_dim, (g + 1) * head_dim)
+            qb = prepared(q_ref[b, :, lanes], gq)
+            for s in range(streams):  # copy s's queries over the keys of the copies up to it
+                keys = SQUARES * (s + 1)
+                p = _softmax(_scores(kb[:keys], qb[_stream(s)]) + bias[s]).astype(jnp.bfloat16)
+                mixed = jax.lax.dot_general(p, vb[:keys], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                out_ref[b, _stream(s), lanes] = mixed.astype(out_ref.dtype)
+        return carry
+
+    _each_board(q_ref.shape[0], board, 0, unroll)
+
+
+def _blocks_backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, bias_ref, do_ref,
+                            dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, unroll: int):
+    cos, sin, gq, gk = cos_ref[...], sin_ref[...], gq_ref[...], gk_ref[...]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    rows, head_dim = k_ref.shape[1:]
+    streams, scale = rows // SQUARES, np.float32(1.0 / math.sqrt(head_dim))
+    bias = [bias_ref[:SQUARES]] + ([bias_ref[SQUARES:]] if streams == 2 else [])
+    joined = lambda parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    added = lambda total, part: part if total is None else total + part
+
+    def board(b, carry):
+        dgq, dgk = carry
+        uk, rk = _unit(k_ref[b], eps)
+        kb, vb = _rope(uk * gk, cos, sin).astype(bf16), v_ref[b]
+        dk_rot, dv = [None] * streams, [None] * streams  # float32 sums a copy's keys over the group's query heads and the copies that see them
+        for g in range(q_ref.shape[-1] // head_dim):
+            lanes = slice(g * head_dim, (g + 1) * head_dim)
+            uq, rq = _unit(q_ref[b, :, lanes], eps)
+            qb, do = _rope(uq * gq, cos, sin).astype(bf16), do_ref[b, :, lanes]
+            dq_rot = []
+            for s in range(streams):
+                keys = SQUARES * (s + 1)
+                qs, dos, ks, vs = qb[_stream(s)], do[_stream(s)], kb[:keys], vb[:keys]
+                p = _softmax(_scores(ks, qs) + bias[s])  # exactly 0 where the mask forbids, and so is ds
+                dv_s = jnp.dot(p.astype(bf16), dos, preferred_element_type=f32)
+                dq_s, dk_s = _score_gradients(p, ks, qs, vs, dos, scale)
+                dq_rot.append(dq_s)
+                for t in range(s + 1):
+                    dv[t], dk_rot[t] = added(dv[t], dv_s[_stream(t)]), added(dk_rot[t], dk_s[_stream(t)])
+            dq_ref[b, :, lanes], dgq_g = _unrope_unnorm(joined(dq_rot), uq, rq, gq, cos, sin, True)
+            dgq = dgq + dgq_g
+        dk_ref[b], dgk_b = _unrope_unnorm(_rounded(joined(dk_rot)), uk, rk, gk, cos, sin, True)
+        dv_ref[b] = joined(dv).astype(dv_ref.dtype)
+        return dgq, dgk + dgk_b
+
+    zero = jnp.zeros((rows, head_dim), f32)
+    dgq, dgk = _each_board(q_ref.shape[0], board, (zero, zero), unroll)
+    dgq_ref[0] = jnp.sum(dgq, axis=0, keepdims=True)
+    dgk_ref[0] = jnp.sum(dgk, axis=0, keepdims=True)
+
+
+def _stream_blocks(q: jax.Array, k: jax.Array, head_dim: int, streams: int):
+    """``_blocks`` for boards of ``64 * streams`` rows (a step takes 1 / streams of the boards, so that its blocks are
+    the plain form's bytes), and the mask's block."""
+    boards, rows, inner = q.shape
+    heads, kv_heads = inner // head_dim, k.shape[-1] // head_dim
+    if rows != SQUARES * streams or k.shape[1] != rows or heads % kv_heads:
+        raise ValueError(f"q {q.shape} and k {k.shape} are not {streams} copies of a board's {SQUARES} squares side by side, or {heads} query heads "
+                         f"do not divide over {kv_heads} key-value heads")
+    group = heads // kv_heads
+    tb = math.gcd(boards, max(1, _BOARDS // (group * streams)))
+    per_head = pl.BlockSpec((tb, rows, head_dim), lambda i, h: (i, 0, h))
+    per_group = pl.BlockSpec((tb, rows, group * head_dim), lambda i, h: (i, 0, h))
+    whole = lambda size: pl.BlockSpec((size, head_dim), lambda i, h: (0, 0))
+    mask = pl.BlockSpec(((2 * streams - 1) * SQUARES, SQUARES), lambda i, h: (0, 0))
+    partial = pl.BlockSpec((1, 1, head_dim), lambda i, h: (i, 0, h))
+    return (boards // tb, kv_heads), group * streams, per_head, per_group, whole, mask, partial
+
+
+def _blocks_operands(g_q, g_k, tables, block_length: int, streams: int):
+    """The gains, the tables laid once a copy along the rows (a noised square turns as its clean twin) and the mask's bias."""
+    g_q, g_k, cos, sin = _operands(g_q, g_k, tables)
+    return g_q, g_k, jnp.tile(cos, (streams, 1)), jnp.tile(sin, (streams, 1)), jnp.asarray(_block_bias(block_length, streams))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _blocks_attention(q, k, v, g_q, g_k, eps: float, interpret: bool, block_length: int, streams: int, tables: Tuple[np.ndarray, np.ndarray]):
+    head_dim = g_q.shape[-1]
+    grid, pairs, per_head, per_group, whole, mask, _ = _stream_blocks(q, k, head_dim, streams)
+    return pl.pallas_call(
+        functools.partial(_blocks_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL, pairs)),
+        grid=grid,
+        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(q.shape[1]), whole(q.shape[1]), mask],
+        out_specs=per_group,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.bfloat16),
+        compiler_params=_PARAMS,
+        name="board_attention_blocks",
+        interpret=interpret,
+    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams))
+
+
+def _blocks_attention_fwd(q, k, v, g_q, g_k, eps, interpret, block_length, streams, tables):
+    return _blocks_attention(q, k, v, g_q, g_k, eps, interpret, block_length, streams, tables), (q, k, v, g_q, g_k)
+
+
+def _blocks_attention_bwd(eps, interpret, block_length, streams, tables, residuals, d_mixed):
+    q, k, v, g_q, g_k = residuals
+    head_dim = g_q.shape[-1]
+    kv_heads = k.shape[-1] // head_dim
+    grid, pairs, per_head, per_group, whole, mask, partial = _stream_blocks(q, k, head_dim, streams)
+    sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
+    dq, dk, dv, dgq, dgk = pl.pallas_call(
+        functools.partial(_blocks_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD, pairs)),
+        grid=grid,
+        in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(q.shape[1]), whole(q.shape[1]), mask, per_group],
+        out_specs=[per_group, per_head, per_head, partial, partial],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype), sums, sums],
+        compiler_params=_PARAMS,
+        name="board_attention_blocks_grad",
+        interpret=interpret,
+    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams), d_mixed)
+    total = lambda s, g: s.reshape(-1, head_dim).sum(axis=0).astype(g.dtype)
+    return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
+
+
+_blocks_attention.defvjp(_blocks_attention_fwd, _blocks_attention_bwd)

@@ -11,7 +11,10 @@ uses so both families train on one mesh layout.
 
 Loss is the AlphaZero recipe: cross-entropy between the policy head and
 MCTS visit-count targets, MSE between the value head and the game
-outcome (or a teacher value), plus weight decay via the optimizer.
+outcome (or a teacher value), plus weight decay via the optimizer. A
+block-diffusion trunk (``TrunkConfig.block_length``) adds the denoising
+loss of its noised copy: a batch then carries its noise
+(``train/data.py block_noise``), and the step draws nothing.
 
 The reference has no training subsystem at all (SURVEY.md §2: nets are
 opaque embedded blobs); training being first-class here is what lets the
@@ -29,6 +32,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
+from fishnet_tpu.models.az_encoding import PIECE_PLANES
 from fishnet_tpu.models.trunk import KERNEL_OPERANDS, balanced_bias
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup, step_metrics
@@ -38,7 +42,8 @@ from fishnet_tpu.utils import compile_cache
 Batch = Dict[str, jax.Array]
 # keys: planes float32 [B,8,8,19]; policy_target float32 [B,4672]
 #       (normalized visit counts, zero off legal moves);
-#       value_target float32 [B] in [-1, 1].
+#       value_target float32 [B] in [-1, 1]; for a block-diffusion trunk
+#       also block_level float32 [B, 64 / L] and square_masked bool [B,64].
 
 
 class AzTrainState(NamedTuple):
@@ -102,7 +107,24 @@ def az_batch_specs() -> Dict[str, P]:
         "planes": P(DATA_AXIS),
         "policy_target": P(DATA_AXIS),
         "value_target": P(DATA_AXIS),
+        "block_level": P(DATA_AXIS),  # a block-diffusion trunk's noise, a board's with its board
+        "square_masked": P(DATA_AXIS),
     }
+
+
+def _denoise_terms(logits: jax.Array, batch: Batch, block_length: int) -> Dict[str, jax.Array]:
+    """A block-diffusion trunk's third term and its two counters, float32:
+    the cross-entropy of the denoiser's ``logits`` [B, 64, 13] with a
+    square's class (empty, or its piece plane) on the MASKED squares, each
+    weighted by 1 / its block's level, over ALL B x 64 squares (the
+    MDLM / BD3-LM objective under the linear schedule); the squares masked
+    in the batch; the mean level."""
+    pieces = batch["planes"].reshape(logits.shape[0], -1, batch["planes"].shape[-1])[..., :PIECE_PLANES]
+    square_class = jnp.where(jnp.any(pieces > 0, axis=-1), 1 + jnp.argmax(pieces, axis=-1), 0)
+    cross_entropy = -jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1), square_class[..., None], axis=-1)[..., 0]
+    masked = batch["square_masked"].astype(jnp.float32)
+    level = jnp.repeat(batch["block_level"], block_length, axis=1)  # a square's is its block's
+    return {"denoise_loss": jnp.mean(masked * cross_entropy / level), "masked_squares": jnp.sum(masked), "noise_level_mean": jnp.mean(batch["block_level"])}
 
 
 def _constrain_params(params, mesh: Optional[Mesh]):
@@ -120,10 +142,12 @@ class AzTrainer:
         learning_rate: float = 2e-3,
         value_weight: float = 1.0,
         optimizer: Optional[optax.GradientTransformation] = None,
+        denoise_weight: float = 1.0,
     ) -> None:
         self.cfg = cfg
         self.mesh = mesh
         self.value_weight = value_weight
+        self.denoise_weight = denoise_weight  # of a block-diffusion trunk's third term; no other net has it
         self.optimizer = optimizer or optax.adamw(learning_rate, weight_decay=1e-4)
         compile_cache.configure()  # before the first jit
         self._hold_on(jax.devices()[0] if mesh is None else mesh.devices.flat[0])
@@ -152,8 +176,10 @@ class AzTrainer:
     def _loss(self, params, batch: Batch, buffers: Dict[str, jax.Array] = {}) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         # forward / loss / optimizer: the scope contract both trainers
         # share (doc/observability.md "Training and compilation").
+        denoising = bool(getattr(self.cfg, "block_length", 0))  # the batch then carries its noise, and a batch without it is an error
         with jax.named_scope("forward"):
-            logits, value, counters = az_forward_counted({**params, **buffers}, batch["planes"], self.cfg)
+            logits, value, counters, *denoiser = az_forward_counted({**params, **buffers}, batch["planes"], self.cfg,
+                                                                    batch["square_masked"] if denoising else None)
         with jax.named_scope("loss"):
             target = batch["policy_target"]
             # Masked cross-entropy: zero-probability targets (illegal moves)
@@ -162,10 +188,16 @@ class AzTrainer:
             policy_loss = -jnp.mean(jnp.sum(target * logp, axis=-1))
             value_loss = jnp.mean((value - batch["value_target"]) ** 2)
             loss = policy_loss + self.value_weight * value_loss
+            denoised = {}
+            if denoising:
+                with jax.named_scope("denoise"):
+                    denoised = _denoise_terms(denoiser[0], batch, self.cfg.block_length)
+                    loss = loss + self.denoise_weight * denoised["denoise_loss"]
         return loss, {
             "loss": loss,
             "policy_loss": policy_loss,
             "value_loss": value_loss,
+            **denoised,
             **counters,  # the trunk's routing counters (models/trunk.py trunk_forward_counted)
         }
 

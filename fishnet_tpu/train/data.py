@@ -9,6 +9,10 @@ the same TPU microbatches as serving, so labeling throughput scales
 with batch width — and outcomes come from the game results.
 
 Output batches feed fishnet_tpu.train.Trainer directly.
+
+``block_noise`` is the other trainer's: the noise a block-diffusion trunk
+is trained under (``models/trunk.py``, the ninth block), which every
+maker of its batches draws here and nowhere else.
 """
 
 from __future__ import annotations
@@ -21,6 +25,23 @@ import numpy as np
 from fishnet_tpu.chess.board import Board
 from fishnet_tpu.protocol.types import STARTPOS
 from fishnet_tpu.search.service import SearchService
+
+
+def block_noise(rng: np.random.Generator, boards: int, block_length: int, t_min: float = 1e-3) -> Tuple[np.ndarray, np.ndarray]:
+    """The noise of ``boards`` boards under block diffusion with blocks
+    of ``block_length`` squares (the linear schedule of MDLM / BD3-LMs):
+    ``block_level`` float32 ``[boards, 64 / block_length]``, a level ``t``
+    a board and block, uniform on ``[t_min, 1]``, and ``square_masked``
+    bool ``[boards, 64]``, each square masked independently with its
+    block's level as the probability. THE maker of this noise: the
+    learner's batches (``train/selfplay.py selfplay_batch``) and the
+    benchmark's (``benchmark/families/sdar_trunk.py``) call it, and
+    ``AzTrainer``'s step reads the two arrays from its batch and draws
+    nothing. ``t_min`` over 0 keeps the loss's ``1 / t`` finite."""
+    if not 0 < block_length <= 64 or 64 % block_length or not 0.0 < t_min <= 1.0:
+        raise ValueError(f"blocks of {block_length} squares do not divide a board's 64, or t_min {t_min} is not in (0, 1]")
+    block_level = rng.uniform(t_min, 1.0, (boards, 64 // block_length)).astype(np.float32)
+    return block_level, rng.random((boards, 64)) < np.repeat(block_level, block_length, axis=1)
 
 
 def playout_positions(

@@ -28,6 +28,7 @@ from fishnet_tpu.chess.board import Board
 from fishnet_tpu.models.az_encoding import INPUT_PLANES, POLICY_SIZE, board_planes, move_to_index
 from fishnet_tpu.protocol.types import STARTPOS
 from fishnet_tpu.search.mcts import MctsPool
+from fishnet_tpu.train.data import block_noise
 
 
 @dataclass(frozen=True)
@@ -156,5 +157,13 @@ def selfplay_batch(
     cfg: SelfPlayConfig = SelfPlayConfig(),
     seed: int = 0,
 ) -> Dict[str, np.ndarray]:
-    """One generation: play games, return a training batch."""
-    return games_to_batch(play_games(pool, cfg, seed))
+    """One generation: play games, return a training batch. Where the
+    net the pool plays for is a block-diffusion trunk (its
+    ``TrunkConfig.block_length``, the one source of L) the batch carries
+    its noise (``data.block_noise``)."""
+    batch = games_to_batch(play_games(pool, cfg, seed))
+    block_length = getattr(pool.cfg.az, "block_length", 0)
+    if block_length:
+        rng = np.random.default_rng([int(seed), 0x6E6F697365])
+        batch["block_level"], batch["square_masked"] = block_noise(rng, len(batch["planes"]), block_length)
+    return batch

@@ -20,6 +20,7 @@ from fishnet_tpu.telemetry.registry import MetricsRegistry
 from fishnet_tpu.telemetry.spans import EVENT_STAGES, RECORDER, SpanRecorder
 from fishnet_tpu.train import step_metrics
 from fishnet_tpu.train.az_trainer import AzTrainer
+from fishnet_tpu.train.data import block_noise
 from fishnet_tpu.train.model import NetConfig
 from fishnet_tpu.train.trainer import Trainer
 from fishnet_tpu.utils import compile_cache
@@ -60,6 +61,10 @@ MODEL_SCOPES = {
     # the eighth's: the first kind's scopes and no other, on a sliding layer and on the full one alike (a layer kind's table is an operand, no scope)
     "mellum": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
                "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm", "policy_head", "value_head"),
+    # the ninth's: the eighth's scopes on both copies of a board (the embedding's holds the mask embedding) and ``denoise``: the denoiser's logits under
+    # ``forward``, the third term under ``loss``
+    "sdar": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
+             "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm", "denoise", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
@@ -84,7 +89,10 @@ GDN = TrunkConfig(hidden=32, heads=2, kv_heads=1, head_dim=16, experts=8, expert
 MELLUM = TrunkConfig(hidden=32, heads=4, kv_heads=1, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8, route_norm=True,
                      held_experts=(2, 4), balance_rate=0.001, sliding_window=1024, rope_theta=1e4, full_attention_layers=(1,), rope_type="yarn", rope_factor=16.0,
                      original_max_position_embeddings=2048, attention_factor=1.2772588722239782)
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN, "mellum": MELLUM}
+# the ninth block: the eighth's layer under one plain table, trained by block diffusion (a batch carries its noise)
+SDAR = TrunkConfig(hidden=32, heads=4, kv_heads=1, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8, route_norm=True,
+                   held_experts=(2, 4), balance_rate=0.001, rope_theta=1e6, rms_eps=1e-6, block_length=4)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN, "mellum": MELLUM, "sdar": SDAR}
 
 
 def make(kind):
@@ -108,6 +116,8 @@ def make(kind):
             "policy_target": np.full((B, 4672), 1 / 4672, np.float32),
             "value_target": np.zeros((B,), np.float32),
         }
+        if kind == "sdar":  # a block-diffusion trunk's batch carries its noise (train/data.py block_noise)
+            batch["block_level"], batch["square_masked"] = block_noise(np.random.default_rng(0), B, SDAR.block_length, 0.05)
     return trainer, batch
 
 
@@ -157,16 +167,16 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert {"forward", "backward", "optimizer"} <= phases
 
 
-@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn", "mellum"])
+@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn", "mellum", "sdar"])
 def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
     """The scopes of the trunk's own parts in the configurations the
     fixture above does not compile, as PR 46's PARENT (3036d35) named them
-    (the sixth block's as PR 47 brought them, the seventh's as PR 51, the eighth's as PR 56), no more and no fewer: the
+    (the sixth block's as PR 47 brought them, the seventh's as PR 51, the eighth's as PR 56, the ninth's as PR 59), no more and no fewer: the
     benchmark's reducers read these names, and a lowered step's text (the
     step pins) carries none of them."""
     names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))
     held = {scope for name in names for part in scopes._parts(name) for scope in [scopes._unwrap(part)[1]]}
-    own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$", scope)}
+    own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$|denoise$", scope)}
     assert own == set(MODEL_SCOPES[kind]) - {"policy_head", "value_head"} and {"policy_head", "value_head"} <= held
 
 
@@ -617,6 +627,8 @@ STEP_KEYS = {
     "latent": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms"},
     "pattern": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "ssm_dt_mean", "ssm_decay_min"},
     "kda": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms", "kda_state_kept", "kda_beta"},
+    # the ninth block's third term and the two counters of its batch's noise
+    "sdar": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "denoise_loss", "masked_squares", "noise_level_mean"},
 }
 
 
@@ -729,7 +741,7 @@ def test_a_collected_trainer_takes_its_ring(steps):
     assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-1"}
 
 
-@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern", "kda"])
+@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern", "kda", "sdar"])
 def test_collector_serves_each_scalar_of_the_latest_step(kind, steps):
     """``fishnet_train_step{trainer,key}`` for exactly the keys the kind's
     step returns, and ``fishnet_train_steps_total{trainer}``: every counter

@@ -45,6 +45,9 @@ KEYS_AND_INIT_SHA256 = {
     # heads' ``wk`` and ``wv`` narrower than ``wq``; the two layer kinds' tables are no tensor
     "mellum": (f"embed_w embed_b attn_norm wq wk wv q_norm k_norm wo moe_norm router_w experts_gate experts_up experts_down {_HEADS}",
                "3db25edba706c732ef6cf70b43ee6225d3dfa1848b9d94fb503af150dc914347"),
+    # the ninth block, read on the tree of the PR that brought it (PR 59): the eighth's tensors, the mask embedding after the embedding and the denoiser after
+    # the value head (no tensor of a layer is its own: the block mask and the two streams are no parameter)
+    "sdar": (f"embed_w embed_b mask_embed {_FIRST} {_HEADS} denoise_w denoise_b", "ee1d1dbcebdc3c0407685b5c7dd0bfda5aa25b89e3a314608d7076a7283b8979"),
 }
 
 
@@ -61,7 +64,7 @@ def test_a_blocks_tensors_come_in_the_parents_order_and_start_where_the_parents_
     assert sha.hexdigest() == digest
     # the table and the shapes agree: a kind's tensors are its row's, and nothing but norms, the embedding and the heads is no kind's
     owned = {name for names in trunk._OWNS.values() for name in names}
-    plain = {"embed_w", "embed_b", *_HEADS.split()} | {norm for s in trunk.trunk_plan(cfg) for norm in (s.norm, s.post_norm) if norm}
+    plain = {"embed_w", "embed_b", *_HEADS.split(), *(("mask_embed", "denoise_w", "denoise_b") if cfg.block_length else ())} | {norm for s in trunk.trunk_plan(cfg) for norm in (s.norm, s.post_norm) if norm}
     assert set(keys.split()) <= owned | plain and not owned & plain
 
 
@@ -104,6 +107,7 @@ PLANS = {
         Sublayer("layer01", "attention", 1, "attn_norm", 1, True), Sublayer("layer01", "routed", 1, "moe_norm", 1),
         Sublayer("layer02", "attention", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 2, "moe_norm", 2),
         Sublayer("layer03", "attention", 3, "attn_norm", 3, True, rope_type="yarn"), Sublayer("layer03", "routed", 3, "moe_norm", 3)),
+    "sdar": _block_plan("attention", 2),  # what is served: the first block's plan, one copy of a board (``streams`` 1); the training forward's is below
 }
 
 
@@ -126,6 +130,16 @@ def test_the_plan_of_a_tiny_block_is_what_it_should_be(block):
         assert all(value.shape == params[name].shape[1:] for name, value in own.items())
     sliced = list(trunk._sliced(params, plan))
     assert [s for s, _ in sliced] == list(plan) and all(set(p) == set(trunk.sublayer_params(params, s)) for s, p in sliced)
+
+
+def test_the_ninth_blocks_training_plan_tells_its_attention_sublayers_two_streams_and_nothing_else():
+    """``trunk_plan(cfg, 2)`` (``trunk_forward_counted`` told a batch's noise) is the served plan but for ``streams`` on the attention sublayers: the routed
+    path is not told a board's length; the older blocks' plans have one stream whatever they are asked."""
+    cfg = BLOCKS["sdar"][0]
+    training = trunk.trunk_plan(cfg, 2)
+    assert [s._replace(streams=1) for s in training] == list(PLANS["sdar"]) and [s.streams for s in training] == [2, 1, 2, 1]
+    assert training is trunk.trunk_plan(cfg, 2) and trunk.trunk_plan(cfg, 1) == trunk.trunk_plan(cfg)
+    assert all(s.streams == 1 for block, (other, _) in BLOCKS.items() if block != "sdar" for s in trunk.trunk_plan(other))
 
 
 def test_a_mixed_plan_is_the_one_mixers_plan_where_every_layer_names_the_same():

@@ -1249,3 +1249,74 @@ def test_the_eighth_blocks_step_compiles_at_published_widths_and_fits_one_chip(o
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 11.0, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert ".remat" not in text
+
+
+# -- the ninth block (sdar_moe) at its published widths: block diffusion over a board, 128 tokens a board under the block mask ------------------
+
+SDAR_BOARDS = 128  # sdar_trunk_train_b128
+
+
+def test_the_masked_kernel_pair_compiles_at_published_widths_for_both_copies_and_for_the_clean_one(one_chip, compiled_for_tpu):
+    """``board_attention_blocks`` and its gradient at the cell's shape (128 boards of 128 rows, 8 query heads a key-value head of 128: ONE board
+    a grid step, so that a step's blocks are the plain pair's bytes) and at the served one (the clean copy alone, 64 rows, 2 boards a step):
+    Mosaic takes the row slices of a copy, the 128 x 64 scores under a bias of -inf and the sums of dk, dv over both copies' queries; the mask
+    is an operand (``f32[192,64]``, ``f32[64,64]``), the tables are laid once a copy (``f32[128,128]``), and no scores are kept between the two."""
+    import re
+
+    from fishnet_tpu.ops.board_attention import board_attention
+
+    for streams, bias in ((2, "f32[192,64]"), (1, "f32[64,64]")):
+        rows = 64 * streams
+        shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in (
+            ((SDAR_BOARDS, rows, 4096), jnp.float32), ((SDAR_BOARDS, rows, 512), jnp.float32), ((SDAR_BOARDS, rows, 512), jnp.bfloat16), ((128,), jnp.float32), ((128,), jnp.float32))]
+        loss = lambda q, k, v, g_q, g_k: jnp.sum(jnp.square(board_attention(q, k, v, g_q, g_k, 1e6, 1e-6, False, block_length=4, streams=streams).astype(jnp.float32)))
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*shapes).compile().as_text()
+        calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 2 and "board_attention_blocks_grad" in calls[1].split(" = ")[0] and "board_attention_blocks" in calls[0].split(" = ")[0]
+        for line in calls:
+            operands = re.findall(r"(?:f32|bf16)\[[\d,]*\]", line.split("custom-call(")[1])
+            assert operands[:3] == [f"f32[{SDAR_BOARDS},{rows},4096]", f"f32[{SDAR_BOARDS},{rows},512]", f"bf16[{SDAR_BOARDS},{rows},512]"], operands
+            assert operands[5:8] == [f"f32[{rows},128]", f"f32[{rows},128]", bias], operands
+        assert not re.search(r"f32\[%d,\d+,(?:64|128),(?:64|128)\]" % SDAR_BOARDS, text)  # no scores between the kernels
+
+
+def test_the_ninth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+    """The whole step of ``sdar_trunk_train_b128`` from its configuration file: 128 boards of a clean and a noised copy, 16,384 tokens, the
+    masked kernel pair on all five layers (never the plain one), the batch's two noise arrays as arguments (the step draws nothing: no
+    random bits in it), the routed path on 128 tokens a board (131,072 slots a layer, no XLA pass over them), the denoiser under its own
+    scope and the third term under ``loss``, no leaf held off row-major and no state argument relaid, nothing remade to fit, arguments and
+    temporaries under the chip's 15.75 GiB with ``recompute_experts`` (3.19 + 7.05 GiB when this was written)."""
+    import importlib
+    import json
+    import re
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "sdar-30b-a3b-trunk-train.json").read_text())
+    assert config["train"]["batch"] == SDAR_BOARDS and config["train"]["recompute_experts"] is True
+    trainer = importlib.import_module("benchmark.families.sdar_trunk").make_trainer(config)
+    cfg = trainer.cfg
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.expert_width, cfg.experts, cfg.held, cfg.layers, cfg.block_length) == (2048, 32, 4, 128, 768, 128, (0, 8), 5, 4)
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == config["published"]["parameters_here"] == 284_743_515  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((SDAR_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((SDAR_BOARDS, 4672)), "value_target": jnp.zeros((SDAR_BOARDS,)),
+             "block_level": jnp.ones((SDAR_BOARDS, 16)), "square_masked": jnp.zeros((SDAR_BOARDS, 64), bool)}
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "board_attention" in line]
+    assert len(calls) == 10 and all("board_attention_blocks" in line.split(" = ")[0] for line in calls)  # five layers, forward and gradient, the masked pair alone
+    assert sum("board_attention_blocks_grad" in line.split(" = ")[0] for line in calls) == 5
+    for line in calls:  # both copies of a board side by side: 128 rows
+        shapes = re.findall(r"(?:f32|bf16)\[[\d,]*\]", line.split("custom-call(")[1])
+        assert shapes[:3] == ["f32[128,128,4096]", "f32[128,128,512]", "bf16[128,128,512]"] and shapes[5:8] == ["f32[128,128]", "f32[128,128]", "f32[192,64]"], shapes
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):
+        assert all(f"{phase}/layer0{i}.{part}/" in text for i in range(5) for part in ("attention", "router", "dispatch", "experts", "combine"))
+        assert f"{phase}/denoise/" in text and f"{phase}/embed/" in text and ".shared/" not in text and ".dense/" not in text
+    assert "jvp(loss)/denoise/" in text and "transpose(jvp(loss))/denoise/" in text
+    assert "rng-bit-generator" not in text and "rng_bit_generator" not in text and "threefry" not in text  # the noise is the batch's
+    assert not _xla_passes_over_slots(text, SDAR_BOARDS * 2 * trunk.SQUARES * cfg.experts_per_token)
+    memory = compiled.memory_analysis()
+    print("sdar step", memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert 4.0 < (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 12.5, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert ".remat" not in text

@@ -190,3 +190,28 @@ MELLUM = TrunkConfig(hidden=64, heads=8, kv_heads=2, head_dim=16, layers=4, expe
                      full_attention_layers=(3,), rope_type="yarn", rope_factor=16.0, original_max_position_embeddings=2048, beta_fast=32.0, beta_slow=1.0,
                      attention_factor=1.2772588722239782)
 BLOCKS["mellum"] = (MELLUM, batch_of)
+
+
+# -- the ninth block (sdar_moe): the eighth block's layer under ONE plain table, trained by block diffusion: a clean and a noised copy of every
+# -- board under the three-part block mask, a mask embedding and a denoiser; ``SDAR_MODEL`` is the same net as the benchmark's reference reads it
+# -- (benchmark/reference/sdar_trunk.py). Its batches carry their noise (``noised_batch``: the program's own maker, ``train/data.py block_noise``)
+
+SDAR_MODEL = {"hidden_size": 64, "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2, "num_dense_layers": 0,
+              "rope_theta": 1000000, "moe_intermediate_size": 32, "num_experts": 8, "num_routed_experts": 16, "first_held_expert": 4,
+              "num_experts_per_tok": 3, "load_balance_coeff": 0.001, "rms_norm_eps": 1e-06, "input_planes": 19, "value_hidden": 32, "policy_planes": 73,
+              "block_length": 4, "t_min": 0.001}
+SDAR_CONFIG = {"model": SDAR_MODEL, "train": {"value_weight": 1.0, "denoise_weight": 1.0}}
+SDAR = TrunkConfig(hidden=64, heads=8, kv_heads=2, head_dim=16, layers=2, experts=16, experts_per_token=3, expert_width=32, rope_theta=1e6, rms_eps=1e-6,
+                   value_hidden=32, router_score="softmax", route_norm=True, held_experts=(4, 8), balance_rate=0.001, block_length=4)
+
+
+def noised_batch(seed: int, n: int = BATCH, block_length: int = 4, t_min: float = 0.05):
+    """``board_batch`` (one piece plane a square: a square has a class) with its noise. ``t_min`` 0.05, not the cell's 0.001: eight boards'
+    512 squares are too few for a weight of 1,000 on one of them to be a term among many."""
+    from fishnet_tpu.train.data import block_noise
+
+    block_level, square_masked = block_noise(np.random.default_rng([seed, 0x6E]), n, block_length, t_min)
+    return {**board_batch(seed, n), "block_level": jnp.asarray(block_level), "square_masked": jnp.asarray(square_masked)}
+
+
+BLOCKS["sdar"] = (SDAR, noised_batch)
