@@ -512,7 +512,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm, tg
 
 from fishnet_tpu.models.az_encoding import INPUT_PLANES, PIECE_PLANES
 from fishnet_tpu.models.heads import policy_value_heads
-from fishnet_tpu.ops.board_attention import SQUARES, board_attention, yarn_rope_tables
+from fishnet_tpu.ops.board_attention import SQUARES, board_attention, paired_heads, yarn_rope_tables
 from fishnet_tpu.ops.board_delta import board_delta
 from fishnet_tpu.ops.board_scan import board_scan
 from fishnet_tpu.ops.cca_mix import cca_mix
@@ -2076,3 +2076,14 @@ def _checked(params: Params, given: int, make) -> TrunkConfig:
         diff = set(expected) ^ set(got) or {k for k in expected if expected[k] != got[k]}
         raise ValueError(f"trunk checkpoint does not match any {cfg}: mismatched keys {sorted(diff)}")
     return cfg
+
+
+def attention_heads_paired(cfg: TrunkConfig) -> float:
+    """The share of the plan's (attention layer, query head)s whose scores
+    ``board_attention`` makes two a product: the plain pair's, by the group
+    alone (``paired_heads``); 0 of a latent layer's and of a block-masked
+    one's, whose kernels are other bodies. Static: the choice is the
+    shapes'. ``AzTrainer``'s ``train_init`` span carries it."""
+    cores = [sublayer for sublayer in trunk_plan(cfg) if sublayer.kind in ("attention", "latent", "cca")]
+    plain = sum(sublayer.kind != "latent" and not cfg.block_length for sublayer in cores)
+    return plain * paired_heads(cfg.heads, cfg.kv_heads or cfg.heads) / (len(cores) * cfg.heads) if cores else 0.0

@@ -23,12 +23,29 @@ A grid step's block is one key-value head of a few boards, ``(boards,
 arrays as they are, and the group of query heads that attend it,
 ``(boards, 64, group * head_dim)`` of q: k is normed and rotated once a
 group, and the gradient's dk, dv sum over the group in VMEM. No ``[..,
-heads, head_dim]`` view ever exists outside VMEM, and the 64 x 64
-scores never reach HBM.
+heads, head_dim]`` view ever exists outside VMEM, and the scores never
+reach HBM.
 Inside, the scores stand ``[key, query]``, the keys down the sublanes,
-so that the softmax's sums run over whole vregs: both kernels are bound
-by the unit that moves data across lanes (the norms' sums, RoPE's
-rotation), not by bytes or products (PERF.md section 5).
+so that the softmax's sums run down whole vregs, and a group's query
+heads are taken TWO AT A TIME (since PR 61; PR 60 brought the same
+change, the driver measured it and refused it on one pair of runs of
+``train_pos_per_s``, and PR 61 asked again): the pair's normed, turned,
+rounded queries and cotangents stacked along the rows, ``[128,
+head_dim]``, so that the scores of both against the shared ``kb`` are
+one ``[64 keys, 128 queries]`` tile that fills the 128 lanes, the
+softmax and the score gradients pass over 8 full vregs a pair where
+they passed over 16 half-filled ones, and the sums over a pair that
+``dv`` and ``dk`` need are the products' own contraction over 128
+queries. A pair costs the two forward and five gradient products one
+head cost; an odd group's last head runs alone, and a group of 1 is the
+one-head kernel PR 32 wrote, to the operation. What bounds the kernels,
+as measured with parts taken out (PERF.md section 5): the PRODUCTS and
+the DMA, 59% of the forward and 63% of the gradient before the pairing
+at a group of 8 (86% at a group of 1, PR 32's table, whose closing
+sentence said otherwise) and 61% / 62% of the shorter kernels after it;
+the rest is the per-head norm and RoPE with their transposes (the unit
+that moves data across lanes), which the pairing does not touch; the
+softmax costs the gradient nothing measurable and the forward a tenth.
 
 The gradient is a second kernel that recomputes the normed and rotated
 q, k, the scores and the softmax from the same inputs (the residuals
@@ -51,7 +68,8 @@ rotate-half inside them, and passes the rest: the tables are then
 split by which of two lane rotations brings a column's partner
 (``part_rope_tables``), since a rotation of a whole 128-lane head by half
 the ROTATED width wraps. A group of 4 query heads a key-value head takes
-4 boards a grid step (16 board-head pairs, as every group does).
+8 boards a grid step (32 board-head pairs, as every group of 2 or more
+does; a group of 1 takes 16).
 
 A layer that does not turn by the plain table of ``theta`` hands the
 normed form its TABLES (``tables``: cos and signed sine ``[64, head_dim]``,
@@ -86,19 +104,28 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SQUARES", "block_mask", "board_attention", "latent_column_order", "part_rope_tables", "rope_tables", "yarn_rope_tables"]
+__all__ = ["SQUARES", "block_mask", "board_attention", "latent_column_order", "paired_heads", "part_rope_tables", "rope_tables", "yarn_rope_tables"]
 
 SQUARES = 64
 
 #: (Board, query head) pairs a grid step, and pairs unrolled in one loop
 #: body of the forward and of the gradient kernel: the fastest of 4-64 and
-#: 1-16 on a v5e at [512, 64, 16 x 128] (PERF.md section 5). A group of g
-#: query heads takes 1/g of the boards, so that the blocks of a step,
-#: double-buffered, stay at 3 MiB (forward) and 5.5 MiB (gradient) of
-#: VMEM; 64 would pass the 16 MiB a kernel gets by default.
+#: 1-16 on a v5e at [512, 64, 16 x 128] (PERF.md section 5), at a group of
+#: 1. The latent and the block-masked forms divide them by their heads a
+#: step, so that the blocks of a step, double-buffered, stay at 3 MiB
+#: (forward) and 5.5 MiB (gradient) of VMEM; 64 would pass the 16 MiB a
+#: kernel gets by default.
 _BOARDS = 16
 _UNROLL = 4
 _UNROLL_GRAD = 8
+
+#: The same two counts where a key-value head's query heads go two a product (a group of 2 or more, the
+#: plain pair alone): the fastest of 1-8 boards a step x 1-4 a body at [256, 64, 32 x 128] over 4 key-value
+#: heads, of 2-16 x 1-2 at 8 over 2 and of 8-16 x 1-8 at a group of 2 (PERF.md section 5; PR 60's builder, taken by PR 61 unswept). A loop body
+#: of 4 pairs is the fastest in BOTH kernels (8 pairs cost the gradient +3%, 16 +33%); boards a step are
+#: flat to 2%. Double-buffered, a step's blocks are 7 MiB (forward) and 11.5 MiB (gradient) at a head of 256, half that at 128.
+_PAIRED_BOARDS = 32
+_PAIRED_UNROLL = 8
 
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
 
@@ -223,6 +250,28 @@ def _head(b, g: int, head_dim: int, group: int):
     return b if group == 1 else (b, slice(None), slice(g * head_dim, (g + 1) * head_dim))
 
 
+def _pairs(group: int):
+    """A group's query heads as the plain pair's bodies take them: two at a time, an odd group's last head alone."""
+    return [range(g, min(g + 2, group)) for g in range(0, group, 2)]
+
+
+def paired_heads(heads: int, kv_heads: int) -> int:
+    """Of ``heads`` query heads over ``kv_heads`` key-value heads, those whose scores the plain pair makes two a product: every head of an
+    even group, all but the last of an odd one, none at a group of 1."""
+    return kv_heads * sum(len(pair) for pair in _pairs(heads // kv_heads) if len(pair) == 2)
+
+
+def _stacked(parts):
+    """A pair's ``[64, head_dim]`` operands along the rows, ``[128, head_dim]`` (a placement at a tile boundary); a lone head's as it is. The
+    block-masked gradient lays a board's two copies along the rows by it too."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _rows(x: jax.Array, i: int, parts: int) -> jax.Array:
+    """Head ``i``'s 64 rows of what ``_stacked`` laid along the rows."""
+    return x if parts == 1 else x[i * SQUARES:(i + 1) * SQUARES]
+
+
 def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps: float, rope: bool, unroll: int, norm: bool = True,
                     half: Optional[int] = None, q_gain: bool = True):
     cos, sin = cos_ref[...], sin_ref[...]
@@ -233,13 +282,15 @@ def _forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_r
 
     def board(b, carry):
         group = q_ref.shape[-1] // head_dim
-        for g in range(group):
-            qb = turn(normed(q_ref[_head(b, g, head_dim, group)], gq)).astype(jnp.bfloat16)
+        for heads in _pairs(group):
+            g = heads[0]
+            qb = _stacked([turn(normed(q_ref[_head(b, h, head_dim, group)], gq)).astype(jnp.bfloat16) for h in heads])
             if g == 0:  # after the first query head's, as PR 32's one-head kernel had it
                 kb = turn(normed(k_ref[b], gk)).astype(jnp.bfloat16)
             p = _softmax(_scores(kb, qb)).astype(jnp.bfloat16)
             mixed = jax.lax.dot_general(p, v_ref[b], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            out_ref[_head(b, g, head_dim, group)] = mixed.astype(out_ref.dtype)
+            for h in heads:
+                out_ref[_head(b, h, head_dim, group)] = _rows(mixed, h - g, len(heads)).astype(out_ref.dtype)
         return carry
 
     _each_board(q_ref.shape[0], board, 0, unroll)
@@ -300,29 +351,31 @@ def _backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_r
         # dk held to the end read 0.5 ms a step slower at [512, 64, 16 x 128] (PERF.md section 6, PR 33).
         dgq, dgk = carry
         group = q_ref.shape[-1] // head_dim
-        for g in range(group):
-            uq, rq = unit(q_ref[_head(b, g, head_dim, group)])
+        for heads in _pairs(group):
+            g = heads[0]
+            uq, rq = zip(*(unit(q_ref[_head(b, h, head_dim, group)]) for h in heads))
             if g == 0:
                 uk, rk = unit(k_ref[b])
-            qb = turn(gained(uq, gq)).astype(bf16)
+            qb = _stacked([turn(gained(u, gq)).astype(bf16) for u in uq])
             if g == 0:
                 kb = turn(gained(uk, gk)).astype(bf16)
                 vb = v_ref[b]
-            do = do_ref[_head(b, g, head_dim, group)]
+            do = _stacked([do_ref[_head(b, h, head_dim, group)] for h in heads])
             p = _softmax(_scores(kb, qb))
-            dv_g = jnp.dot(p.astype(bf16), do, preferred_element_type=f32)
-            dv = dv_g if g == 0 else dv + dv_g  # float32 sums over the group's query heads, rounded once
-            if g == group - 1:
+            dv_g = jnp.dot(p.astype(bf16), do, preferred_element_type=f32)  # over a pair's 128 queries: the pair's sum is the product's own
+            dv = dv_g if g == 0 else dv + dv_g  # float32 sums over the group's pairs, rounded once
+            if heads[-1] == group - 1:
                 dv_ref[b] = dv.astype(dv_ref.dtype)
             dq_rot, dk_g = _score_gradients(p, kb, qb, vb, do, scale)
             dk_rot = dk_g if g == 0 else dk_rot + dk_g
-            dq, dgq_g = _unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope, half)
-            dgq = dgq + dgq_g
-            if g == group - 1:
-                dk, dgk_b = _unrope_unnorm(_rounded(dk_rot), uk, rk, gk, cos, sin, rope, half)
-                dq_ref[_head(b, g, head_dim, group)], dk_ref[b] = dq, dk
-            else:
-                dq_ref[_head(b, g, head_dim, group)] = dq
+            for h in heads:
+                dq, dgq_g = _unrope_unnorm(_rows(dq_rot, h - g, len(heads)), uq[h - g], rq[h - g], gq, cos, sin, rope, half)
+                dgq = dgq + dgq_g
+                if h == group - 1:
+                    dk, dgk_b = _unrope_unnorm(_rounded(dk_rot), uk, rk, gk, cos, sin, rope, half)
+                    dq_ref[_head(b, h, head_dim, group)], dk_ref[b] = dq, dk
+                else:
+                    dq_ref[_head(b, h, head_dim, group)] = dq
         return dgq, dgk + dgk_b
 
     zero = jnp.zeros((SQUARES, head_dim), f32)
@@ -340,7 +393,7 @@ def _blocks(boards: int, heads: int, kv_heads: int, head_dim: int):
     if heads % kv_heads:
         raise ValueError(f"{heads} query heads do not divide over {kv_heads} key-value heads")
     group = heads // kv_heads
-    tb = math.gcd(boards, max(1, _BOARDS // group))
+    tb = math.gcd(boards, max(1, (_BOARDS if group == 1 else _PAIRED_BOARDS) // group))
     per_head = pl.BlockSpec((tb, SQUARES, head_dim), lambda i, h: (i, 0, h))
     per_group = pl.BlockSpec((tb, SQUARES, group * head_dim), lambda i, h: (i, 0, h))
     whole = lambda rows: pl.BlockSpec((rows, head_dim), lambda i, h: (0, 0))
@@ -370,6 +423,11 @@ def _unroll(interpret: bool, unroll: int, group: int) -> int:
     """Boards to a loop body. Unrolling is for Mosaic's scheduler; the
     interpreter pays for every emitted operation and gains nothing."""
     return 1 if interpret else max(1, unroll // group)
+
+
+def _plain_unroll(interpret: bool, unroll: int, group: int) -> int:
+    """``_unroll`` for the plain pair: ``unroll`` (board, head)s at a group of 1, ``_PAIRED_UNROLL`` where the heads go two a product."""
+    return _unroll(interpret, unroll if group == 1 else _PAIRED_UNROLL, group)
 
 
 def board_attention(q: jax.Array, k: jax.Array, v: jax.Array, g_q: Optional[jax.Array], g_k: Optional[jax.Array],
@@ -448,7 +506,7 @@ def _normed_attention(q, k, v, g_q, g_k, theta: Optional[float], eps: float, int
     head_dim = g_q.shape[-1]
     grid, group, per_head, per_group, whole, _ = _blocks(boards, inner // head_dim, k.shape[-1] // head_dim, head_dim)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
+        functools.partial(_forward_kernel, eps=eps, unroll=_plain_unroll(interpret, _UNROLL, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables[1].shape[0])],
         out_specs=per_group,
@@ -471,7 +529,7 @@ def _board_attention_bwd(theta, eps, interpret, norm, rotary_dim, q_gain, tables
     grid, group, per_head, per_group, whole, partial = _blocks(boards, inner // head_dim, kv_heads, head_dim)
     sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
     dq, dk, dv, dgq, dgk = pl.pallas_call(
-        functools.partial(_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
+        functools.partial(_backward_kernel, eps=eps, unroll=_plain_unroll(interpret, _UNROLL_GRAD, group), norm=norm, **_form(theta, rotary_dim, q_gain)),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), _gain_spec(g_k, whole), whole(SQUARES), whole(tables[1].shape[0]), per_group],
         out_specs=[per_group, per_head, per_head, partial, partial],
@@ -782,7 +840,6 @@ def _blocks_backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_re
     rows, head_dim = k_ref.shape[1:]
     streams, scale = rows // SQUARES, np.float32(1.0 / math.sqrt(head_dim))
     bias = [bias_ref[:SQUARES]] + ([bias_ref[SQUARES:]] if streams == 2 else [])
-    joined = lambda parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
     added = lambda total, part: part if total is None else total + part
 
     def board(b, carry):
@@ -804,10 +861,10 @@ def _blocks_backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_re
                 dq_rot.append(dq_s)
                 for t in range(s + 1):
                     dv[t], dk_rot[t] = added(dv[t], dv_s[_stream(t)]), added(dk_rot[t], dk_s[_stream(t)])
-            dq_ref[b, :, lanes], dgq_g = _unrope_unnorm(joined(dq_rot), uq, rq, gq, cos, sin, True)
+            dq_ref[b, :, lanes], dgq_g = _unrope_unnorm(_stacked(dq_rot), uq, rq, gq, cos, sin, True)
             dgq = dgq + dgq_g
-        dk_ref[b], dgk_b = _unrope_unnorm(_rounded(joined(dk_rot)), uk, rk, gk, cos, sin, True)
-        dv_ref[b] = joined(dv).astype(dv_ref.dtype)
+        dk_ref[b], dgk_b = _unrope_unnorm(_rounded(_stacked(dk_rot)), uk, rk, gk, cos, sin, True)
+        dv_ref[b] = _stacked(dv).astype(dv_ref.dtype)
         return dgq, dgk + dgk_b
 
     zero = jnp.zeros((rows, head_dim), f32)

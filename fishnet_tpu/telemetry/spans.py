@@ -45,7 +45,9 @@ named machinery actually runs):
   (train/startup.py; fields: trainer, compile_s, cache_load_s,
   trace_lower_s, cache_misses; ``AzTrainer``'s also layout_held_leaves,
   layout_held_bytes: the state's leaves the client holds off row-major,
-  whose update its step runs in the client's layout)
+  whose update its step runs in the client's layout; of a trunk also
+  attention_heads_paired: the share of its attention layers' query heads
+  whose scores the kernel pair makes two a product)
 * ``train_first_step`` — the first ``.step`` of a trainer instance:
   trace + lower + compile or cache load + dispatch of the step program
   (train/startup.py; the first five fields). Both trainer stages also

@@ -33,7 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
 from fishnet_tpu.models.az_encoding import PIECE_PLANES
-from fishnet_tpu.models.trunk import KERNEL_OPERANDS, balanced_bias
+from fishnet_tpu.models.trunk import KERNEL_OPERANDS, TrunkConfig, attention_heads_paired, balanced_bias
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup, step_metrics
 from fishnet_tpu.train.trainer import _constrain
@@ -164,6 +164,8 @@ class AzTrainer:
         self._held = held_layouts(state.params, self.mesh, device)
         followed = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0] if getattr(path[-1], "key", None) in self._held]
         self._held_fields = {"layout_held_leaves": len(followed), "layout_held_bytes": sum(leaf.size * leaf.dtype.itemsize for leaf in followed)}
+        # what a trunk's plan says of its attention cores, static as the layouts are: the share of their query heads that go two a product
+        self._plan_fields = {"attention_heads_paired": attention_heads_paired(self.cfg)} if isinstance(self.cfg, TrunkConfig) else {}
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -222,7 +224,7 @@ class AzTrainer:
     # -- public api -------------------------------------------------------
 
     def init(self, seed: int = 0) -> AzTrainState:
-        with startup.init_span("az", **self._held_fields):
+        with startup.init_span("az", **self._held_fields, **self._plan_fields):
             return self._init_jit(jax.random.PRNGKey(seed))
 
     def step(self, state: AzTrainState, batch: Batch):

@@ -85,10 +85,12 @@ def _bringing_up(stage: str) -> Iterator[Dict[str, Any]]:
 
 
 @contextmanager
-def init_span(trainer: str, **fields: int) -> Iterator[None]:
+def init_span(trainer: str, **fields: float) -> Iterator[None]:
     """Round ``Trainer.init`` / ``AzTrainer.init``; ``trainer`` is ``nnue`` or ``az``,
     ``fields`` what the trainer knows of the state it makes (``AzTrainer``: the leaves
-    the client holds off row-major, and their bytes)."""
+    the client holds off row-major, and their bytes; of a trunk also
+    ``attention_heads_paired``, the share of its attention layers' query heads whose
+    scores the kernel pair makes two a product)."""
     with _bringing_up("train_init") as span:
         yield
     RECORDER.record("train_init", trainer=trainer, **fields, **span)

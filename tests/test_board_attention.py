@@ -16,18 +16,30 @@ with room (the readings, CPU: 0.0033-0.0037 forward, 0.0036-0.0061
 gradients). Three wrong formulas show that the tolerances would catch a
 missing piece: they miss by 7x to 57x (no scale 35-52x, no RoPE 31-57x,
 no gain 7-10x).
+
+Since PR 61 a key-value head's query heads go two a product inside both
+kernels (an odd group's last head alone, a group of 1 as before). The
+grouped cases hold every form at an even and an odd group to the plain
+formula under the same tolerances; the parent's one-head-at-a-time
+bodies are kept below as a test's helper (``PARENT_BODIES``) and the
+packed bodies' six outputs are theirs to float32 rounding.
+``tools/attention_alone.py`` (the pair timed alone) runs at a tiny shape.
 """
 
 from __future__ import annotations
 
 import functools
+import json
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from fishnet_tpu.ops import board_attention as kernels
 from fishnet_tpu.ops.board_attention import board_attention, rope_tables
+from tools import attention_alone
 
 THETA, EPS = 50000.0, 1e-5
 ROUNDING = 2.0 ** -8
@@ -107,7 +119,10 @@ def test_kernels_match_the_plain_formula(heads, head_dim, boards, output):
 # published lane block (2 boards a grid step); groups of 2 and 4 with batches the block does and does not
 # divide; None is a layer without RoPE, with and without a group. dk, dv sum over a group's query heads: the
 # tolerance stays, because the sum is float32 and rounded once.
-GROUPED_CASES = [(4, 2, 16, 12, THETA), (8, 2, 16, 5, THETA), (8, 1, 128, 2, THETA), (4, 2, 16, 6, None), (2, 2, 16, 8, None)]
+# Since PR 61 the heads of a group go two a product: 6 over 2 is an ODD group (a pair and a lone head in one grid step), 4 over 2 at the
+# published lane block a group of one pair, 6 over 2 without RoPE the odd group on the other branch.
+GROUPED_CASES = [(4, 2, 16, 12, THETA), (8, 2, 16, 5, THETA), (8, 1, 128, 2, THETA), (4, 2, 16, 6, None), (2, 2, 16, 8, None),
+                 (6, 2, 16, 5, THETA), (4, 2, 128, 2, THETA), (6, 2, 16, 3, None)]
 
 
 @functools.lru_cache(maxsize=None)
@@ -309,7 +324,7 @@ def test_a_mixture_of_the_two_forms_is_refused():
 # (heads, key-value heads, head_dim, rotary_dim, boards): the published 8 over 2 of 128 with 64 rotated (4 query heads a
 # key-value head: 4 boards a grid step) on a batch the block divides and one it does not; a tiny group of 4; all of a
 # head rotated through the partial tables; a quarter of it.
-PART_CASES = [(8, 2, 128, 64, 4), (8, 2, 16, 8, 6), (4, 1, 16, 8, 5), (4, 2, 16, 16, 3), (2, 2, 16, 4, 8)]
+PART_CASES = [(8, 2, 128, 64, 4), (8, 2, 16, 8, 6), (4, 1, 16, 8, 5), (4, 2, 16, 16, 3), (2, 2, 16, 4, 8), (6, 2, 16, 8, 3)]  # the last: an odd group (PR 61)
 PART_OUTPUTS = ["mixed", "d_q", "d_k", "d_v", "d_gain"]
 PART_THETA = 5_000_000.0
 
@@ -383,8 +398,8 @@ def test_a_part_that_is_odd_or_wider_than_a_head_and_a_part_of_a_latent_are_refu
 
 # -- the seventh block's form: qk-norm under one gain each, a head of 256, RoPE on its first 64 columns, 8 query heads a key-value head --------
 
-# (heads, key-value heads, head_dim, rotary_dim, boards): the published head and group (2 boards a grid step) on one key-value head, and tiny ones
-WIDE_CASES = [(8, 1, 256, 64, 2), (4, 2, 32, 8, 3)]
+# (heads, key-value heads, head_dim, rotary_dim, boards): the published head and group (4 boards a grid step since PR 61; here 2) on one key-value head, and tiny ones
+WIDE_CASES = [(8, 1, 256, 64, 2), (4, 2, 32, 8, 3), (6, 2, 32, 8, 2)]  # the last: an odd group (PR 61)
 WIDE_THETA = 10_000_000.0
 
 
@@ -430,3 +445,190 @@ def test_a_head_of_256_with_64_columns_turned_under_both_gains_matches_the_plain
 def test_the_tolerances_catch_rope_on_all_256_columns():
     got, want = both_wide(8, 1, 256, 64, 2, turned=256)
     assert rel(got[0], want[0]) > 3 * FORWARD_TOL and rel(got[1], want[1]) > 3 * GRADIENT_TOL
+
+
+# -- the form without a norm (the fourth block's: told ``head_dim`` in the gains' place), at an even and an odd group ---------------------
+
+# (heads, key-value heads, head_dim, boards, theta)
+UNNORMED_CASES = [(4, 2, 16, 6, THETA), (8, 2, 16, 3, None), (6, 2, 16, 4, THETA)]
+UNNORMED_OUTPUTS = ["mixed", "d_q", "d_k", "d_v"]
+
+
+@functools.lru_cache(maxsize=None)
+def both_unnormed(heads, kv_heads, head_dim, boards, theta):
+    q, k, v, _, _, cotangent = inputs(boards, heads, head_dim, seed=19, kv_heads=kv_heads)
+    q, k = q / 1.5, k / 1.5  # unnormed rows of unit scale: scores O(1), as the tolerances assume
+
+    def plain_unnormed(q, k, v):
+        split = lambda y: y.astype(jnp.float32).reshape(boards, 64, -1, head_dim)
+        turn = (lambda x: x) if theta is None else (lambda x: rope(x, head_dim))
+        qh, kh, vh = turn(split(q)), turn(split(k)), split(v)
+        kh, vh = (jnp.repeat(y, heads // kv_heads, axis=2) for y in (kh, vh))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision="highest") / np.sqrt(head_dim)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), vh, precision="highest").reshape(boards, 64, -1)
+
+    def sides(f):
+        out, pull = jax.vjp(f, q, k, v)
+        return (out, *pull(cotangent.astype(out.dtype)))
+
+    return (jax.jit(lambda: sides(lambda q, k, v: board_attention(q, k, v, None, None, theta, EPS, True, head_dim=head_dim)))(),
+            jax.jit(lambda: sides(plain_unnormed))())
+
+
+@pytest.mark.parametrize("output", UNNORMED_OUTPUTS)
+@pytest.mark.parametrize("heads,kv_heads,head_dim,boards,theta", UNNORMED_CASES)
+def test_the_form_without_a_norm_matches_the_plain_formula_at_even_and_odd_groups(heads, kv_heads, head_dim, boards, theta, output):
+    got, want = (side[UNNORMED_OUTPUTS.index(output)] for side in both_unnormed(heads, kv_heads, head_dim, boards, theta))
+    assert got.shape == want.shape and got.dtype == (jnp.bfloat16 if output in ("mixed", "d_v") else jnp.float32)
+    assert rel(got, want) < (FORWARD_TOL if output == "mixed" else GRADIENT_TOL), (output, rel(got, want))
+
+
+# -- two query heads a product (PR 61) against the parent's bodies, one head at a time ------------------------------------------------------
+#
+# The bodies of ``_forward_kernel`` and ``_backward_kernel`` as the parent commit (8be8117; the file is 572dbfe's) had them, kept HERE as the reference and not in
+# the program: inside a grid step the group's heads run one after another against the same ``kb``, ``vb``, and ``dv``, ``dk_rot`` are
+# float32 sums of the heads' parts. The helpers they call are the tree's own (PR 61 changed none of them).
+
+
+def parent_forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, out_ref, *, eps, rope, unroll, norm=True, half=None, q_gain=True):
+    cos, sin = cos_ref[...], sin_ref[...]
+    gq, gk = gq_ref[...] if q_gain else None, gk_ref[...]
+    head_dim = k_ref.shape[-1]
+    turn = (lambda x: kernels._rope(x, cos, sin) if half is None else kernels._part_rope(x, cos, sin, half)) if rope else (lambda x: x)
+    normed = (lambda x, gain: kernels._unit(x, eps)[0] if gain is None else kernels._unit(x, eps)[0] * gain) if norm else (lambda x, gain: x)
+
+    def board(b, carry):
+        group = q_ref.shape[-1] // head_dim
+        for g in range(group):
+            qb = turn(normed(q_ref[kernels._head(b, g, head_dim, group)], gq)).astype(jnp.bfloat16)
+            if g == 0:
+                kb = turn(normed(k_ref[b], gk)).astype(jnp.bfloat16)
+            p = kernels._softmax(kernels._scores(kb, qb)).astype(jnp.bfloat16)
+            mixed = jax.lax.dot_general(p, v_ref[b], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            out_ref[kernels._head(b, g, head_dim, group)] = mixed.astype(out_ref.dtype)
+        return carry
+
+    kernels._each_board(q_ref.shape[0], board, 0, unroll)
+
+
+def parent_backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, do_ref, dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *,
+                           eps, rope, unroll, norm=True, half=None, q_gain=True):
+    cos, sin = cos_ref[...], sin_ref[...]
+    gq, gk = gq_ref[...] if q_gain else None, gk_ref[...]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    head_dim = k_ref.shape[-1]
+    scale = np.float32(1.0 / math.sqrt(head_dim))
+    turn = (lambda x: kernels._rope(x, cos, sin) if half is None else kernels._part_rope(x, cos, sin, half)) if rope else (lambda x: x)
+    unit = (lambda x: kernels._unit(x, eps)) if norm else (lambda x: (x, None))
+    gained = (lambda u, gain: u if gain is None else u * gain) if norm else (lambda u, gain: u)
+
+    def board(b, carry):
+        dgq, dgk = carry
+        group = q_ref.shape[-1] // head_dim
+        for g in range(group):
+            uq, rq = unit(q_ref[kernels._head(b, g, head_dim, group)])
+            if g == 0:
+                uk, rk = unit(k_ref[b])
+            qb = turn(gained(uq, gq)).astype(bf16)
+            if g == 0:
+                kb = turn(gained(uk, gk)).astype(bf16)
+                vb = v_ref[b]
+            do = do_ref[kernels._head(b, g, head_dim, group)]
+            p = kernels._softmax(kernels._scores(kb, qb))
+            dv_g = jnp.dot(p.astype(bf16), do, preferred_element_type=f32)
+            dv = dv_g if g == 0 else dv + dv_g
+            if g == group - 1:
+                dv_ref[b] = dv.astype(dv_ref.dtype)
+            dq_rot, dk_g = kernels._score_gradients(p, kb, qb, vb, do, scale)
+            dk_rot = dk_g if g == 0 else dk_rot + dk_g
+            dq, dgq_g = kernels._unrope_unnorm(dq_rot, uq, rq, gq, cos, sin, rope, half)
+            dgq = dgq + dgq_g
+            if g == group - 1:
+                dk, dgk_b = kernels._unrope_unnorm(kernels._rounded(dk_rot), uk, rk, gk, cos, sin, rope, half)
+                dq_ref[kernels._head(b, g, head_dim, group)], dk_ref[b] = dq, dk
+            else:
+                dq_ref[kernels._head(b, g, head_dim, group)] = dq
+        return dgq, dgk + dgk_b
+
+    zero = jnp.zeros((64, head_dim), f32)
+    dgq, dgk = kernels._each_board(q_ref.shape[0], board, (zero, zero), unroll)
+    dgq_ref[0] = jnp.sum(dgq, axis=0, keepdims=True)
+    dgk_ref[0] = jnp.sum(dgk, axis=0, keepdims=True)
+
+
+PARENT_BODIES = {"_forward_kernel": parent_forward_kernel, "_backward_kernel": parent_backward_kernel}
+
+
+def under(patches, trace, *args):
+    """``trace(*args)`` with ``patches`` over the kernels' module's names while it runs (none: the tree's own)."""
+    with pytest.MonkeyPatch.context() as patched:
+        for name, value in patches.items():
+            patched.setattr(kernels, name, value)
+        return trace(*args)
+
+
+def six_outputs(patches, args):
+    """The pair's six outputs under the interpreter, bare: traced here, under whatever ``patches`` put in the module."""
+    return under(patches, functools.partial(value_and_gradients, kernel), *args)
+
+
+#: What a float32 sum of a pair's two parts in another order, or one product's accumulation in their place, can move: the last bit of a
+#: float32 before a rounding to bfloat16 that may then fall the other way on a few elements of thousands (relative L2). A tenth of the
+#: tightest tolerance. The readings here are 0.0 for all six: XLA:CPU accumulates a product down its rows in order, so one product over
+#: a pair's 128 queries IS the two halves added; the MXU's order is its own (``tools/attention_alone.py --against``, on the chip).
+REORDERED_SUM = ROUNDING / 10
+
+
+@pytest.mark.parametrize("output", OUTPUTS)
+def test_two_heads_a_product_give_what_the_parents_one_head_bodies_gave(output):
+    """A group of 4 (two pairs a key-value head), two key-value heads, 3 boards: the packed bodies against the parent's, every output."""
+    got, want = packed_and_parent()
+    i = OUTPUTS.index(output)
+    assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
+    assert rel(got[i], want[i]) < REORDERED_SUM, (output, rel(got[i], want[i]))
+
+
+@functools.lru_cache(maxsize=None)
+def packed_and_parent():
+    args = inputs(3, 8, 16, seed=23, kv_heads=2)
+    return six_outputs({}, args), six_outputs(PARENT_BODIES, args)
+
+
+def test_the_packed_bodies_hold_half_the_parents_products_and_a_lone_head_its_own():
+    """Products in the traced bodies, a board: 7 a query head in the parent's (2 forward, 5 in the gradient), 7 a PAIR here; an odd
+    group's last head is the one-head body, and a group of 1 the parent's count."""
+    def products(group, bodies):
+        traced = under(bodies, jax.make_jaxpr(functools.partial(value_and_gradients, kernel)), *inputs(1, group, 16, kv_heads=1))
+        return str(traced).count("dot_general")
+
+    assert [products(group, {}) for group in (1, 2, 3, 4)] == [7, 7, 14, 14]
+    assert [products(group, PARENT_BODIES) for group in (1, 2, 3, 4)] == [7, 14, 21, 28]
+
+
+def test_the_tolerances_catch_a_pair_written_to_each_others_lanes():
+    """Head g + 1's rows of a packed result written to head g's lanes (and back): ``mixed`` and ``d_q`` miss by far."""
+    args = inputs(3, 8, 16, seed=23, kv_heads=2)
+    wrong = six_outputs({"_rows": lambda x, i, parts: x if parts == 1 else x[(1 - i) * 64:(2 - i) * 64]}, args)
+    want = jax.jit(functools.partial(value_and_gradients, plain))(*args)
+    assert rel(wrong[0], want[0]) > 3 * FORWARD_TOL and rel(wrong[1], want[1]) > 3 * GRADIENT_TOL
+    assert rel(wrong[3], want[3]) < GRADIENT_TOL  # d_v sums over a pair's queries either way: it cannot tell
+
+
+# -- tools/attention_alone.py: the pair timed alone -----------------------------------------------------------------------------------------
+
+
+def test_attention_alone_prints_its_three_times_and_what_it_ran_on(capsys):
+    """The tool as a builder runs it on the chip, here at a tiny shape under the interpreter (the times are the interpreter's and say
+    nothing of a device: ``interpret`` and ``device`` say so in the line): its three programs' times, the gradient kernel's by itself
+    among them, and against its own tree's file every output equal."""
+    assert attention_alone.PROGRAMS == ("forward_ms", "forward_and_gradient_ms", "gradient_ms")
+    shape = ["--boards", "2", "--heads", "4", "--kv-heads", "2", "--d", "16"]
+    assert attention_alone.main([*shape, "--calls", "2", "--seed", "3000000019", "--against", kernels.__file__]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["interpret"] is True and line["device"] == jax.devices()[0].device_kind and line["finite"] is True
+    assert (line["boards"], line["heads"], line["kv_heads"], line["d"]) == (2, 4, 2, 16)
+    for times in (line, line["against"]):
+        for program in attention_alone.PROGRAMS:
+            assert 0.0 < times[program]["min"] <= times[program]["median"] <= times[program]["max"]
+    assert sorted(line["largest_difference"]) == sorted(attention_alone.OUTPUTS) and all(d == 0.0 for d in line["largest_difference"].values())
+    assert all(line["scale"][name] > 0.0 for name in attention_alone.OUTPUTS)

@@ -297,8 +297,11 @@ def test_the_counters_read_the_references_mix_temperature_and_chosen_score():
 
 #: sha256 of the fifth block's tiny lowered step program, as ``tests/test_hybrid_trunk.py PARENT_STEP_SHA256`` holds the four
 #: older blocks': read on PR 44's parent (3160177) and on PR 44's tree with this jax, and the same on both (PR 44 changed the
-#: fourth block's mixer, which no ``cca`` layer runs). A PR that means to change it reads its own parent the same way.
-CCA_STEP_SHA256 = "a835ede221ce0bab9447436bfb275c77f53ad24369a01935cc2fdcf61d9fb41a"
+#: fourth block's mixer, which no ``cca`` layer runs). A PR that means to change it reads its own parent the same way. PR 61 MEANT
+#: to move it (the attention core's query heads two a product; this net runs a group of 4; PR 60 brought the same change, was measured by the driver and refused on one pair of runs of ``train_pos_per_s``, its tree thrown away; PR 61 asked again): read anew on
+#: PR 61's tree, its parent 8be8117 read a835ede2...b41a; the ``tools/step_text.py --block cca --no-ids`` dumps differ inside the two kernels' calls
+#: alone, 236 -> 208 ``stablehlo.dot_general``, 38 loops both: with the parent's two bodies (``tests/test_board_attention.py PARENT_BODIES``) and its 16 (board, head)s a step patched over the module, the text hashes to the parent's pin.
+CCA_STEP_SHA256 = "001950aaae35b7f24da16745185cd5d6a642a6e93d943c6bb62009fc6ce4d585"
 
 
 def test_the_fifth_blocks_lowered_step_is_the_parents_op_for_op():

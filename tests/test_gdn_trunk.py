@@ -179,8 +179,11 @@ def test_the_expert_shares_and_the_gated_shared_expert_once_add_up_to_the_uncut_
 #: packs the tiny net's two value heads a key head, 9 products where they were 18 a board and key head in ``gdn``'s (the spans, ``Mq^T dO``, ``T^T
 #: dU``, ``dMq``, ``dA`` and ``dD``'s sums of the pair one each, the key head's six three: 231 -> 204, three layers); the loops are as many (134 and 114 ``stablehlo.while``) and every forward body reads what it
 #: read; ``hybrid``, whose mixer runs ``ops/mamba_mix.py``'s kernels, reads what it read (``tests/test_hybrid_trunk.py``). A PR that means to
-#: change either reads its own parent the same way.
-GDN_STEP_SHA256 = {"kda": "9e78ff9cc17bc116b82bf620c05b68429ea8c71c5ee77b045dbec8e3ab674f17", "gdn": "473e6939bbc3afe2f6503d6fba36537da237d79b4ff192f1aa879116cec3e1f6"}
+#: change either reads its own parent the same way. PR 61 MEANT to move ``gdn`` (its one attention layer's query heads go two a
+#: product in ``ops/board_attention.py``'s plain pair; PR 60 brought the same change, was measured by the driver and refused on one pair of runs of ``train_pos_per_s``, its tree thrown away; PR 61 asked again): read anew on PR 61's tree, its parent 8be8117 read 473e6939...e1f6; the
+#: ``tools/step_text.py --block gdn --no-ids`` dumps differ inside that layer's two kernel calls alone, 204 -> 197 ``stablehlo.dot_general``, 114 loops
+#: both: with the parent's two bodies (``tests/test_board_attention.py PARENT_BODIES``) and its 16 (board, head)s a step patched over the module, the text hashes to the parent's pin; ``kda``, whose attention layer is the latent pair, passed UNEDITED.
+GDN_STEP_SHA256 = {"kda": "9e78ff9cc17bc116b82bf620c05b68429ea8c71c5ee77b045dbec8e3ab674f17", "gdn": "a753d2e34b4ec08949cbdb9ba29a7eaa7995eb90464020c6dad60d36f8bb5110"}
 
 
 @pytest.mark.parametrize("block", GDN_STEP_SHA256)

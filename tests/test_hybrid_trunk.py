@@ -258,12 +258,19 @@ def test_a_shares_ungated_experts_against_a_loop_over_the_held_experts(hidden, w
 #: read anew on PR 44's tree (the parent 3160177 read b47f7635...763a); the three others passed unedited. PR 50 MEANT to change
 #: ``afmoe`` (the gated out-projection became one ``custom_vjp``, ``trunk._gated_out``, taken by the layer's own
 #: ``cfg.gated_attention`` at every size): its pin was read anew on PR 50's tree (the parent 1a857f0 read 3bb78678...6f35); the
-#: three others and ``cca``'s passed unedited: no ungated block's program moved.
+#: three others and ``cca``'s passed unedited: no ungated block's program moved. PR 61 MEANT to move ``afmoe`` and ``hybrid`` (and
+#: ``cca``'s, ``gdn``'s and ``mellum``'s in their files): inside ``board_attention`` / ``board_attention_grad`` a key-value head's query
+#: heads go two a product (the tiny nets run groups of 2 and 4), and a grid step of a paired group takes ``_PAIRED_BOARDS // group``
+#: boards (PR 60 brought the same change, was measured by the driver and refused on one pair of runs of ``train_pos_per_s``, its tree thrown away; PR 61 asked again). Both read anew on PR 61's tree (its parent 8be8117 read 52990bc0...5d49 and 3fa4c14a...4f71); the ``tools/step_text.py
+#: --block <name> --no-ids`` dumps of parent and change differ inside the two kernels' calls alone, in every attention layer (the grid loop's
+#: block of boards and the body: ``afmoe`` 141 -> 120 ``stablehlo.dot_general``, ``hybrid`` 158 -> 151, the loops as many, 46 and 100
+#: ``stablehlo.while``): with the parent's two bodies (``tests/test_board_attention.py PARENT_BODIES``) and its 16 (board, head)s a step patched over the module, the text hashes to the parent's pin.
+#: ``llada`` (a group of 1: the one-head body, PR 32's program) and ``mla`` (the latent pair, another body) passed UNEDITED.
 PARENT_STEP_SHA256 = {
     "llada": "60f5865d293d8516a7b2474ae17766489aca839166a5b0790d0a185cee8b0c77",
-    "afmoe": "52990bc0282ffb143aa2d7d1bdaeb6ee266d3f634a15be8d79fcbbcaf6b65d49",
+    "afmoe": "504621b28996cfe07348a59c69cd7f729153834fa739d196e7cc30e9e31468a3",
     "mla": "0fedb499d5d0ceb1b7924dbb2f29537fddb8a67cbebdbcd6d1a68efeda7719e2",
-    "hybrid": "3fa4c14a61f2e5625e227cbc27f8da0358f6120b0c9c8837d6f423315ff94f71",
+    "hybrid": "e8c8c0db1e151e16e86b918c0737e9f9a86cfc6ab916ae60a66277720c62e822",
 }
 
 

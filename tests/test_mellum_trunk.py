@@ -347,8 +347,11 @@ def test_full_attention_layers_name_attention_layers_of_the_other_layouts_too():
 
 #: sha256 of the tiny lowered step program (``tools/step_text.py --block mellum``), as ``tests/test_hybrid_trunk.py PARENT_STEP_SHA256`` holds the four
 #: older blocks': read on PR 56's tree, which brought the block. The seven older blocks' pins (``test_hybrid_trunk.py``, ``test_cca_trunk.py``,
-#: ``test_gdn_trunk.py``) pass UNEDITED on it: the kernel pair is told its tables and their lowered steps are the parent's.
-MELLUM_STEP_SHA256 = "8e5c63d1354f6c708f640448b5b3ea6dde896a7bd54ed3193f5abc65a3fd1ac0"
+#: ``test_gdn_trunk.py``) pass UNEDITED on it: the kernel pair is told its tables and their lowered steps are the parent's. PR 61 MEANT
+#: to move it (the kernel pair's query heads two a product, two pairs a key-value head here; PR 60 brought the same change, was measured by the driver and refused on one pair of runs of ``train_pos_per_s``, its tree thrown away; PR 61 asked again): read anew on PR 61's tree, its parent
+#: 8be8117 read 8e5c63d1...1ac0; the ``tools/step_text.py --block mellum --no-ids`` dumps differ inside the two kernels' calls alone, 190 -> 134
+#: ``stablehlo.dot_general``, 66 loops both: with the parent's two bodies (``tests/test_board_attention.py PARENT_BODIES``) and its 16 (board, head)s a step patched over the module, the text hashes to the parent's pin.
+MELLUM_STEP_SHA256 = "beb7b7a96e7367c1d16c228f6ce01c8884cd8a20daf366b5ee7ae2f1eddbadaf"
 
 
 def test_the_eighth_blocks_lowered_step_is_pinned():

@@ -160,3 +160,32 @@ def test_a_mixed_plan_is_the_one_mixers_plan_where_every_layer_names_the_same():
     shapes = trunk.trunk_param_shapes(kda)
     assert shapes["kda_q"][0] == 4 and shapes["wq"][0] == 1 and shapes["attn_norm"][0] == shapes["moe_norm"][0] == 5
     assert (kda.attention_layers, kda.routed_layers) == (1, 4)
+
+
+# -- the counter PR 61 brings: how much of a plan's attention the kernel pair takes two query heads a product -------------------------------
+
+#: by the shapes alone: the first block a group of 1, the third's layers and the sixth's one attention layer latent, the ninth's block-masked
+PAIRED = {"llada": 0.0, "afmoe": 1.0, "mla": 0.0, "hybrid": 1.0, "cca": 1.0, "kda": 0.0, "gdn": 1.0, "mellum": 1.0, "sdar": 0.0}
+
+
+@pytest.mark.parametrize("block", PAIRED)
+def test_the_share_of_a_plans_query_heads_that_go_two_a_product_follows_the_shapes(block):
+    assert set(PAIRED) == set(BLOCKS)
+    assert trunk.attention_heads_paired(BLOCKS[block][0]) == PAIRED[block]
+
+
+def test_an_odd_group_pairs_all_but_its_last_head_and_the_init_span_says_so():
+    import dataclasses
+    import time
+
+    from fishnet_tpu.ops.board_attention import paired_heads
+    from fishnet_tpu.telemetry.spans import RECORDER
+    from fishnet_tpu.train.az_trainer import AzTrainer
+
+    assert [paired_heads(heads, kv) for heads, kv in ((16, 16), (32, 4), (8, 2), (6, 2), (3, 1), (2, 1))] == [0, 32, 8, 4, 2, 2]
+    cfg = dataclasses.replace(BLOCKS["afmoe"][0], heads=6)  # 6 over 2: a pair and a lone head a key-value head
+    assert trunk.attention_heads_paired(cfg) == 4 / 6
+    started = time.monotonic()
+    AzTrainer(cfg).init(0)
+    span = [s for s in RECORDER.spans() if s["t"] >= started and s["stage"] == "train_init"][-1]
+    assert span["trainer"] == "az" and span["attention_heads_paired"] == 4 / 6 and "layout_held_leaves" in span

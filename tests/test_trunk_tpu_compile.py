@@ -269,8 +269,9 @@ def _sublayer_shapes(cfg, sublayer, sharding):
 def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_chip, compiled_for_tpu):
     """``value_and_grad`` of ``_attention`` as the cell runs it, 16 heads x
     128 over 512 boards' tokens: the core is the two Pallas kernels, the
-    64 x 64 scores never exist as an array, and no copy changes
-    ``[512, 64, 2048]`` into a ``[.., 16, 128]`` view."""
+    64 x 64 scores (or a pair of heads' 64 x 128, the form the grouped
+    layers' kernels make since PR 61) never exist as an array, and no copy
+    changes ``[512, 64, 2048]`` into a ``[.., 16, 128]`` view."""
     import re
 
     cfg = trunk.TrunkConfig()
@@ -286,7 +287,7 @@ def test_attention_at_published_widths_is_two_kernels_and_keeps_no_scores(one_ch
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "board_attention" in text and "board_attention_grad" in text
-    per_head = [line for line in text.splitlines() if re.search(r"\[512,16,64,64\]|\[512,64,16,128\]|\[512,16,64,128\]", line)]
+    per_head = [line for line in text.splitlines() if re.search(r"\[512,16,64,64\]|\[512,8,64,128\]|\[512,64,16,128\]|\[512,16,64,128\]", line)]
     assert not per_head, per_head[:2]
 
 
@@ -303,7 +304,11 @@ AFMOE_BOARDS = 256
 def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled_for_tpu, rope):
     """``value_and_grad`` of ``_attention`` with 8 query heads a key-value
     head, the output gate and the post-norm, with and without RoPE: still
-    the two kernels, a grid step's blocks inside the 16 MiB a kernel gets."""
+    the two kernels, a grid step's blocks (4 boards of a key-value head and
+    its 8 query heads since PR 61) inside the 16 MiB a kernel gets, and
+    neither a head's ``[64, 64]`` scores nor a pair's ``[64, 128]`` in HBM."""
+    import re
+
     cfg, inner, kv_inner = AFMOE, 32 * 128, 4 * 128
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     layer = {"attn_norm": sds((HIDDEN,), jnp.float32), "post_attn_norm": sds((HIDDEN,), jnp.float32),
@@ -320,6 +325,8 @@ def test_grouped_query_attention_compiles_at_published_widths(one_chip, compiled
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(sds((AFMOE_BOARDS * trunk.SQUARES, HIDDEN), jnp.float32), layer).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "board_attention" in text and "board_attention_grad" in text
+    scores = [line for line in text.splitlines() if re.search(r"\[256,32,64,64\]|\[256,16,64,128\]|\[256,64,32,128\]|\[256,32,64,128\]", line)]
+    assert not scores, scores[:2]
 
 
 def _products_results_and_operands(text: str):
