@@ -61,10 +61,11 @@ async-smoke:
 	env JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_async_dispatch.py -q \
 		-k "xla or overlap or ping_pong or no_async_env"
 
-# Placement-aware mesh serving contract (doc/sharding.md, ≤60 s, 8
-# virtual devices): the mesh run must spread dispatches over more than
-# one shard with analyses bit-identical to the single-device path and
-# the exactly-once ledger clean; FISHNET_NO_MESH=1 restores the
+# Multi-chip serving contract (doc/sharding.md, ≤60 s, 8 virtual
+# devices; the shard router is the one multi-chip serving path): the
+# mesh run must spread dispatches over more than one shard with
+# analyses bit-identical to the single-device path and the
+# exactly-once ledger clean; FISHNET_NO_MESH=1 restores the
 # single-device service byte-for-byte; a per-shard device fault
 # degrades ONLY its shard's ladder rung without changing output.
 multichip-smoke:

@@ -103,46 +103,14 @@ def validate_mesh(opt: Opt) -> None:
         raise ConfigError(f"--mesh {mesh_spec} needs {data * model} devices, found {n}")
 
 
-def build_sharded_evaluator(opt: Opt, weights, logger: Logger):
-    """The LEGACY multi-chip tier: one ShardedEvaluator (shard_map) that
-    splits every eval microbatch over a single mesh-wide program. Only
-    built for an EXPLICIT --mesh DxM with model > 1 — a tensor-parallel
-    request the placement-aware serving mesh (per-shard placement,
-    doc/sharding.md) cannot express. "auto" and data-only meshes return
-    None: SearchService drives those per shard from the coalescer."""
-    mesh_spec = opt.resolved_mesh()
-    if mesh_spec in ("off", "auto"):
-        return None
-    import jax
-
-    validate_mesh(opt)
-    data, model = (int(x) for x in mesh_spec.split("x"))
-    if model <= 1:
-        return None  # data-only: the placement-aware path serves it
-    from fishnet_tpu.nnue.jax_eval import params_from_weights
-    from fishnet_tpu.parallel.mesh import ShardedEvaluator, make_mesh
-
-    mesh = make_mesh(jax.devices()[: data * model], data=data, model=model)
-    logger.info(
-        f"Sharding eval batches over a {mesh.devices.shape[0]}x"
-        f"{mesh.devices.shape[1]} device mesh (single fused program)."
-    )
-    return ShardedEvaluator(
-        params_from_weights(weights),
-        mesh=mesh,
-        batch_capacity=opt.resolved_microbatch(),
-    )
-
-
-def resolve_mesh_devices(opt: Opt, evaluator, logger: Logger):
+def resolve_mesh_devices(opt: Opt, logger: Logger):
     """The placement-aware serving mesh request for SearchService
     (doc/sharding.md): "auto" follows the visible devices, an explicit
-    data-only DxM pins the shard count, and anything served by the
-    legacy evaluator (or --mesh off) stays single-device. The service
-    itself degrades to the single-device path when fewer than two
-    devices remain (or FISHNET_NO_MESH=1)."""
+    DxM pins the shard count at D * M, and --mesh off stays
+    single-device. The service itself degrades to the single-device
+    path when fewer than two devices remain (or FISHNET_NO_MESH=1)."""
     mesh_spec = opt.resolved_mesh()
-    if evaluator is not None or mesh_spec == "off":
+    if mesh_spec == "off":
         return None
     if mesh_spec == "auto":
         import jax
@@ -173,11 +141,10 @@ def build_search_service(opt: Opt, logger: Logger, psqt_path=None):
     host phase (fiber stepping, feature extraction) of one group can
     overlap the other group's device wait. With >1 visible
     device (or an explicit --mesh) the service drives the whole mesh
-    from the coalescer — per-shard placed dispatches, doc/sharding.md —
-    while an explicit model-parallel DxM falls back to the legacy
-    single-program ShardedEvaluator. ``psqt_path`` requests a
-    rung of the eval-path lattice (the degradation ladder's seam,
-    resilience/supervisor.py); None = auto-select."""
+    from the coalescer — per-shard placed dispatches, doc/sharding.md.
+    ``psqt_path`` requests a rung of the eval-path lattice (the
+    degradation ladder's seam, resilience/supervisor.py); None =
+    auto-select."""
     from fishnet_tpu.nnue.weights import NnueWeights
     from fishnet_tpu.search.service import SearchService, suggest_pipeline_depth
 
@@ -215,25 +182,20 @@ def build_search_service(opt: Opt, logger: Logger, psqt_path=None):
     from fishnet_tpu.utils import compile_cache
 
     compile_cache.configure()
-    evaluator = build_sharded_evaluator(opt, weights, logger)
-    mesh_devices = resolve_mesh_devices(opt, evaluator, logger)
+    mesh_devices = resolve_mesh_devices(opt, logger)
 
     depth = opt.pipeline
     dispatch_probe = None
     if depth is None:
         # Probe at the production microbatch size: overlap ratios are
-        # shape-dependent (dispatch overhead vs compute time). When a
-        # sharded evaluator is installed, probe THAT — the
-        # single-device jit's overlap says nothing about the sharded
-        # computation serving will actually run. The same probe run
-        # reports the fixed-vs-marginal dispatch cost that seeds the
-        # dispatch coalescer's width policy. A probe that cannot
-        # dispatch means the service cannot either: its error is the
-        # start-up error.
+        # shape-dependent (dispatch overhead vs compute time). The
+        # same probe run reports the fixed-vs-marginal dispatch cost
+        # that seeds the dispatch coalescer's width policy. A probe
+        # that cannot dispatch means the service cannot either: its
+        # error is the start-up error.
         depth, dispatch_probe = suggest_pipeline_depth(
             weights,
             size=max(64, min(opt.resolved_microbatch(), 4096)),
-            eval_fn=evaluator,
             return_probe=True,
         )
         logger.info(
@@ -253,7 +215,6 @@ def build_search_service(opt: Opt, logger: Logger, psqt_path=None):
         net_path=opt.nnue_file,  # native pool reads the original file
         batch_capacity=opt.resolved_microbatch(),
         pipeline_depth=depth,
-        evaluator=evaluator,
         mesh_devices=mesh_devices,
         driver_threads=opt.resolved_search_threads(),
         psqt_path=psqt_path,

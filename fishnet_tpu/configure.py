@@ -199,9 +199,9 @@ class Opt:
     #: round-trip; subprocess/mock engines keep the reference's
     #: one-worker-per-core model.
     search_concurrency: Optional[int] = None
-    #: Device-mesh policy for the serving evaluator: "auto" (shard the
-    #: eval batch whenever >1 device is visible), "off" (single device),
-    #: or an explicit "DATAxMODEL" shape such as "4x2".
+    #: Device-mesh policy for the serving evaluator: "auto" (one shard a
+    #: visible device whenever >1 is visible), "off" (single device), or
+    #: an explicit "DATAxMODEL" shape such as "4x2": DATA * MODEL shards.
     mesh: Optional[str] = None
     #: Telemetry exposition port (doc/observability.md). None = telemetry
     #: off (the default; hot paths pay one flag check); 0 = an ephemeral
@@ -346,8 +346,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "concurrently), 1 per core for uci/mock.")
     p.add_argument("--mesh", default=None,
                    help="Device mesh for the serving evaluator: auto (default; "
-                        "shard eval batches over all visible devices), off "
-                        "(single device), or DATAxMODEL (e.g. 4x2).")
+                        "one shard a visible device), off (single device), "
+                        "or DATAxMODEL (e.g. 4x2: eight shards).")
     p.add_argument("--metrics-port", type=int, default=None,
                    help="Serve live telemetry (/metrics Prometheus text, "
                         "/json snapshot) on this port and arm the SIGUSR2 "

@@ -147,8 +147,8 @@ def derive_segment_offsets(parent: jax.Array, seg_rows: jax.Array,
     (4 rows per full entry, 1 per delta), but each segment's padding
     clamps into ITS OWN sentinel block at ``seg_rows[k]`` and the whole
     segment shifts by ``k*tier`` — offsets never cross a segment
-    boundary, the same invariant the sharded repack enforces per shard
-    (search/service.py _dispatch_sharded_packed). Returns flat int32
+    boundary, so a fused dispatch reads each group's rows and tables
+    alone (search/service.py _dispatch_segmented). Returns flat int32
     [K*size] offsets into the concatenated row stream."""
     parent = parent.astype(jnp.int32)
     k_segs = parent.shape[0]

@@ -1,22 +1,16 @@
-"""Multi-chip parallelism: device meshes, shardings, and the sharded
-NNUE evaluator. See mesh.py for the design rationale."""
+"""Multi-chip parallelism: the trainers' device meshes and the serving
+shards' placement. See mesh.py for the design rationale."""
 
 from fishnet_tpu.parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
-    ShardedEvaluator,
-    batch_sharding,
     factor_mesh,
     make_mesh,
-    replicated,
 )
 
 __all__ = [
     "DATA_AXIS",
     "MODEL_AXIS",
-    "ShardedEvaluator",
-    "batch_sharding",
     "factor_mesh",
     "make_mesh",
-    "replicated",
 ]

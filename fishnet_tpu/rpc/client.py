@@ -4,10 +4,11 @@ Two entry points, one per search family (doc/disaggregation.md):
 
 * :class:`RemoteBackend` IS a ``SearchService`` whose evaluator ships
   each group's padded microbatch over this frontend's ring link instead
-  of running a local jit — the external-evaluator seam
-  (``search/service.py _dispatch_eval``) already produces exactly the
-  self-contained dense arrays the wire carries, so alpha-beta drivers,
-  the engine factories and ``train/selfplay.py`` ride unchanged. The
+  of running a local jit — the remote-evaluator seam
+  (``search/service.py _remote_evaluator``, ``_dispatch_eval``)
+  produces exactly the self-contained dense arrays the wire carries, so
+  alpha-beta drivers, the engine factories and ``train/selfplay.py``
+  ride unchanged. The
   evaluator returns a LAZY handle; the service's ``_resolve_eval``
   materializes it one loop iteration later, which preserves the
   per-group pipeline overlap across the process boundary.
@@ -177,12 +178,10 @@ class _PendingEval:
 
 
 class RemoteEvaluator:
-    """The external-evaluator callable ``(params, feats, buckets,
-    parents, material) -> lazy int32 [B]`` the service seam expects:
+    """The callable ``(params, feats, buckets, parents, material) ->
+    lazy int32 [B]`` that ``SearchService._remote_evaluator`` names:
     packs the full padded microbatch into one self-contained submit
     record and returns a :class:`_PendingEval`."""
-
-    size_multiple = 1
 
     def __init__(self, client: _RpcClient) -> None:
         self._client = client
@@ -211,13 +210,15 @@ class RemoteBackend(SearchService):
         client = _RpcClient(rpc_dir)
         client._on_evaluator_lost = self._cancel_inflight_anchors
         self._rpc = client
-        kwargs["evaluator"] = RemoteEvaluator(client)
         kwargs.setdefault("backend", "jax")
         super().__init__(*args, **kwargs)
 
+    def _remote_evaluator(self) -> RemoteEvaluator:
+        return RemoteEvaluator(self._rpc)
+
     def _cancel_inflight_anchors(self) -> None:
         """Evaluator death fences every group's device anchor state via
-        the existing cancellation path. External-evaluator mode never
+        the existing cancellation path. A remote evaluator never
         enables persistent anchors (in-batch refs only), so this is the
         same no-op-safe call the in-process cache-skip path makes —
         kept so a future anchor-carrying wire inherits the fencing."""

@@ -11,7 +11,7 @@ near-full buckets (``fishnet_rpc_fused_rows_total`` over
 ``fishnet_rpc_fused_slots_total``; no test measures the fill).
 
 Parity: NNUE records carry the exact padded dense arrays the
-external-evaluator seam emits, replayed through the same
+remote-evaluator seam emits, replayed through the same
 ``evaluate_batch`` graph (row independence makes concat+pad
 bit-identical — the host-material rung contract); AZ records carry the
 exact uint8 plane wire, replayed through the identical jitted forward

@@ -468,7 +468,7 @@ def _validate_header(path: str, header: np.ndarray, size: int) -> None:
 
 # -- wire payload codecs -----------------------------------------------------
 # Self-contained per-record formats shared by client and host. NNUE
-# submits carry the exact padded arrays the external-evaluator seam
+# submits carry the exact padded arrays the remote-evaluator seam
 # produces (search/service.py _dispatch_eval) so the host can replay
 # them through evaluate_batch verbatim; AZ records carry the exact
 # uint8 plane wire / fp16 logits wire the shared AZ plane uses, so a

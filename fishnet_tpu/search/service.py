@@ -234,7 +234,7 @@ def choose_coalesce_width(fixed_ms: float, marginal_ms_per_kslot: float,
 
 def suggest_pipeline_depth(weights: "NnueWeights", size: int = 1024,
                            rounds: int = 4, device_params=None,
-                           eval_fn=None, return_probe: bool = False):
+                           return_probe: bool = False):
     """Probe whether concurrent device dispatches overlap, and suggest a
     pipeline depth for SearchService.
 
@@ -251,26 +251,15 @@ def suggest_pipeline_depth(weights: "NnueWeights", size: int = 1024,
     dispatch coalescer's width policy (choose_coalesce_width)."""
     import time
 
+    import jax
+
     from fishnet_tpu.nnue import spec
+    from fishnet_tpu.nnue.jax_eval import evaluate_batch_jit, params_from_weights
 
-    mult = 1
-    if eval_fn is None:
-        import jax
-
-        from fishnet_tpu.nnue.jax_eval import evaluate_batch_jit, params_from_weights
-
-        eval_fn = evaluate_batch_jit
-        params = device_params
-        if params is None:
-            params = jax.device_put(params_from_weights(weights))
-    else:
-        # Probing an external evaluator (e.g. ShardedEvaluator): it holds
-        # its own device params and must be probed itself — the dispatch
-        # overlap of the single-device jit says nothing about a sharded
-        # computation's.
-        params = device_params
-        mult = max(1, int(getattr(eval_fn, "size_multiple", 1)))
-        size = _round_up(size, mult)
+    eval_fn = evaluate_batch_jit
+    params = device_params
+    if params is None:
+        params = jax.device_put(params_from_weights(weights))
     feats = np.full((size, 2, spec.MAX_ACTIVE_FEATURES), spec.NUM_FEATURES, np.uint16)
     buckets = np.zeros((size,), np.int32)
     np.asarray(eval_fn(params, feats, buckets))  # compile + warm
@@ -298,7 +287,7 @@ def suggest_pipeline_depth(weights: "NnueWeights", size: int = 1024,
     if not return_probe:
         return depth
 
-    small = _round_up(max(32, size // 16), mult)
+    small = max(32, size // 16)
     feats_s = np.full(
         (small, 2, spec.MAX_ACTIVE_FEATURES), spec.NUM_FEATURES, np.uint16
     )
@@ -315,10 +304,6 @@ def suggest_pipeline_depth(weights: "NnueWeights", size: int = 1024,
         small, size,
     )
     return depth, probe
-
-
-def _round_up(n: int, multiple: int) -> int:
-    return -(-n // multiple) * multiple
 
 
 #: ``SearchService.counters()`` key -> (metric name, type, help). The
@@ -1452,19 +1437,15 @@ class SearchService(CoalesceBackend):
         backend: str = "jax",  # "jax" | "scalar"
         eval_sizes: Optional[Sequence[int]] = None,
         pipeline_depth: int = 1,
-        evaluator=None,
         driver_threads: int = 1,
         psqt_path: Optional[str] = None,
         dispatch_probe: Optional[DispatchProbe] = None,
         mesh_devices=None,
     ) -> None:
-        """``evaluator``: optional callable ``(params, indices, buckets) ->
-        int32 [B]`` replacing the built-in single-device
-        ``evaluate_batch_jit`` — the multi-chip seam (a
-        ``parallel.mesh.ShardedEvaluator`` shards each microbatch over a
-        device mesh). Its optional ``size_multiple`` attribute forces
-        every eval-size bucket to a multiple so sharded batches split
-        evenly across devices.
+        """``backend="jax"`` is the packed wire into the built-in
+        anchored evaluator (nnue/jax_eval.evaluate_packed_anchored), on
+        one device or per shard (``mesh_devices``); ``"scalar"``
+        evaluates in the native core.
 
         ``psqt_path``: request a rung of the eval-path lattice instead
         of auto-selection — the degradation ladder's seam
@@ -1472,9 +1453,7 @@ class SearchService(CoalesceBackend):
         kernel (realized in interpreter mode off-TPU, the parity
         fixtures' venue); ``"xla"`` pins the bit-identical XLA twin;
         ``"host-material"`` restores the legacy host-material wire.
-        All rungs produce bit-identical analysis output; only the
-        builtin single-device evaluator honors the request (sharded
-        meshes always run host-material).
+        All rungs produce bit-identical analysis output.
 
         ``dispatch_probe``: a pre-measured DispatchProbe (e.g. from
         ``suggest_pipeline_depth(..., return_probe=True)``) seeding the
@@ -1509,16 +1488,9 @@ class SearchService(CoalesceBackend):
             net_path = self._tmp.name
         self.net_path = str(net_path)
         self.backend = backend
-        # Every batch shipped to a sharded evaluator must split evenly
-        # across its devices; force capacities and size buckets to
-        # multiples of the evaluator's shard count. Sharded mode also
-        # needs every SHARD to hold at least one maximal eval block
-        # (emit_block never splits a block across a shard boundary —
-        # the no-cross-shard-gather invariant — so a shard smaller than
-        # EVAL_BLOCK_MAX could never place one).
-        mult = max(1, int(getattr(evaluator, "size_multiple", 1)))
-        self.batch_capacity = batch_capacity = _round_up(
-            max(batch_capacity, MIN_BATCH_CAPACITY * mult), mult
+        evaluator = self._remote_evaluator()
+        self.batch_capacity = batch_capacity = max(
+            batch_capacity, MIN_BATCH_CAPACITY
         )
         # Pipeline depth: the pool's slots are partitioned into this many
         # groups, each with its own in-flight device batch. While group
@@ -1558,15 +1530,6 @@ class SearchService(CoalesceBackend):
         if not self._pool:
             raise NativeCoreError("failed to create search pool")
 
-        self.shard_multiple = mult
-        # Single source of truth for the packed-capable mesh predicate:
-        # _eval_fn selection below and _dispatch_eval's wire branch must
-        # never disagree (a split would hand the dense expansion to the
-        # packed entry point or vice versa).
-        self._sharded_packed = (
-            backend == "jax" and evaluator is not None
-            and getattr(evaluator, "supports_packed", False) and mult > 1
-        )
         self._params = None
         self._eval_fn = None
         #: What evaluates this service's microbatches, as JAX names it
@@ -1576,19 +1539,7 @@ class SearchService(CoalesceBackend):
         self.platform = self.device_kind = ""
         if backend == "jax":
             if evaluator is not None:
-                # Packed-capable meshes get the per-shard repacked row
-                # stream (see _dispatch_sharded_packed); anything else
-                # receives the dense expansion.
-                if self._sharded_packed:
-                    self._eval_fn = evaluator.packed_eval
-                else:
-                    self._eval_fn = evaluator
-                mesh = getattr(evaluator, "mesh", None)
-                if mesh is not None:
-                    dev = mesh.devices.flat[0]
-                    self.platform, self.device_kind = (
-                        dev.platform, dev.device_kind
-                    )
+                self._eval_fn = evaluator
             else:
                 import jax
 
@@ -1611,54 +1562,41 @@ class SearchService(CoalesceBackend):
         # together still fill one batch_capacity of in-flight work —
         # without this, k groups each padding up to the full capacity
         # bucket would multiply the host->device bytes by k.
-        # In sharded mode each group's SHARD (group_capacity / mult) must
-        # still hold one maximal eval block, or aligned emission could
-        # never place it (cpp/src/pool.cpp fc_pool_step align contract)
-        # — hence the MIN * mult floor after the pipeline-depth split.
-        self._group_capacity = _round_up(
-            max(MIN_BATCH_CAPACITY * mult, cap // self.pipeline_depth), mult
+        self._group_capacity = max(
+            MIN_BATCH_CAPACITY, cap // self.pipeline_depth
         )
         # Shape buckets for _evaluate. Each distinct size is one XLA
         # compile (seconds each on the TPU) — callers with a known
         # steady-state load should pass just two or three sizes.
-        # SHARDED mode uses exactly one bucket (the group capacity):
-        # block emission is aligned to the shard size of the shipped
-        # batch, and only a single static size keeps that alignment a
-        # constant the pool can honor.
-        if mult > 1:
-            self._eval_sizes = [self._group_capacity]
-            self._shard_align = self._group_capacity // mult
+        if eval_sizes is not None:
+            sizes = {min(int(s), cap) for s in eval_sizes if s > 0}
         else:
-            if eval_sizes is not None:
-                sizes = {min(int(s), cap) for s in eval_sizes if s > 0}
-            else:
-                sizes = set()
-                s = 64
-                while s < cap:
-                    sizes.add(s)
-                    s *= 2
-            sizes.add(self._group_capacity)  # groups fill to this bucket
-            # Clamp every bucket to the GROUP capacity: fc_pool_step is
-            # called with _group_capacity, so a group microbatch can
-            # never exceed it — buckets past it were dead weight (one
-            # wasted XLA compile each) AND they starved the largest
-            # REACHABLE bucket of its finer row tiers (_row_tiers keys
-            # on the last bucket), which is why rounds 2-5 measured a
-            # constant wire_mb_per_step across windows with very
-            # different occupancy: every step shipped the one maximal
-            # all-full tier of the group bucket regardless of content.
-            self._eval_sizes = sorted(
-                {min(s, self._group_capacity) for s in sizes}
-            )
-            self._shard_align = 0
+            sizes = set()
+            s = 64
+            while s < cap:
+                sizes.add(s)
+                s *= 2
+        sizes.add(self._group_capacity)  # groups fill to this bucket
+        # Clamp every bucket to the GROUP capacity: fc_pool_step is
+        # called with _group_capacity, so a group microbatch can
+        # never exceed it — buckets past it were dead weight (one
+        # wasted XLA compile each) AND they starved the largest
+        # REACHABLE bucket of its finer row tiers (_row_tiers keys
+        # on the last bucket), which is why rounds 2-5 measured a
+        # constant wire_mb_per_step across windows with very
+        # different occupancy: every step shipped the one maximal
+        # all-full tier of the group bucket regardless of content.
+        self._eval_sizes = sorted(
+            {min(s, self._group_capacity) for s in sizes}
+        )
         # COMPACT WIRE: the pool emits a packed uint16 row stream (full
         # entry = 4 rows of [2][8], delta entry = 1 row) — deltas ship
         # 32 bytes instead of 128 (VERDICT r3 item 4). The built-in
         # evaluator expands on DEVICE (jax_eval.expand_packed) and
         # derives row offsets there too (cumsum over parent codes), so
         # only rows + buckets + parents + material ride the wire; the
-        # offsets buffer below feeds the sharded repack and the dense
-        # host expansion for external evaluators.
+        # offsets buffer below feeds the dense host expansion a remote
+        # evaluator receives.
         # One buffer set per group: a group's buffers must stay
         # untouched while its dispatched eval is still in flight, and
         # each group is only ever touched by its owning thread.
@@ -1694,12 +1632,6 @@ class SearchService(CoalesceBackend):
                 for _ in range(self._n_groups)
             ]
             self._lib.fc_pool_set_anchors(self._pool, 1)
-        # (_sharded_packed — the packed-capable mesh predicate — is set
-        # once above, before the _eval_fn selection.) Sharded evaluators
-        # that understand the packed wire get the service-side per-shard
-        # repack instead of the dense host expansion — the multi-chip
-        # path previously paid the exact 4x wire cost the packed format
-        # was built to delete (VERDICT r4 item 4 / weak 5).
         self._packed_wire = backend == "jax" and evaluator is None
         # DEVICE-RESIDENT PSQT (ABI 9): with the built-in anchored
         # evaluator the fused gather pass also produces the PSQT
@@ -1711,7 +1643,7 @@ class SearchService(CoalesceBackend):
         # explicit ``psqt_path`` request (the degradation ladder) wins
         # over both the env var and auto-selection.
         if not self._packed_wire:
-            requested = None  # external evaluators: host-material only
+            requested = None  # a remote evaluator: host-material only
         else:
             requested = psqt_path
         if requested is None:
@@ -1725,8 +1657,6 @@ class SearchService(CoalesceBackend):
         # backends, XLA twin elsewhere).
         self._eval_force = None
         if not self._packed_wire:
-            # External evaluators (sharded meshes, test doubles) keep
-            # the host-material wire.
             self.psqt_path = "host-material"
         elif not self._device_psqt:
             self.psqt_path = "host-material"
@@ -1850,8 +1780,8 @@ class SearchService(CoalesceBackend):
         # n_groups separate ones — the fixed per-dispatch transport
         # cost (DispatchProbe; 1.5 ms at PR 21's start-up on a v5e) is paid
         # once per fused batch instead of once per group, which is the
-        # whole bill at low occupancy. Builtin packed wire only: the
-        # sharded mesh and external evaluators keep per-group dispatch.
+        # whole bill at low occupancy. Builtin packed wire only: a
+        # remote evaluator keeps per-group dispatch.
         # FISHNET_NO_COALESCE=1 is the escape hatch (no coalescer is
         # built at all: byte-for-byte the old dispatch loop);
         # FISHNET_COALESCE_WIDTH pins the width instead of the policy.
@@ -1918,7 +1848,7 @@ class SearchService(CoalesceBackend):
         # probe/insert site gates on it), per-group Zobrist-hash export
         # buffers (fc_pool_batch_hashes, ABI 10) and cache-probe value
         # scratch. Only meaningful on the builtin packed wire — the
-        # scalar backend and external evaluators never step a batch.
+        # scalar backend and a remote evaluator never step a batch.
         from fishnet_tpu.search import eval_cache as _eval_cache_mod
 
         self._eval_cache = (
@@ -2124,6 +2054,15 @@ class SearchService(CoalesceBackend):
                 with self._lock:
                     self._latency_active -= 1
 
+    def _remote_evaluator(self):
+        """The callable ``(params, feats, buckets, parents, material) ->
+        int32 [B]`` that evaluates this service's dense microbatches in
+        another process, or None: the built-in anchored evaluator on
+        this process's devices. rpc/client.py RemoteBackend overrides
+        it; a remote evaluator gets the host-material wire, in-batch
+        anchors only, and per-group dispatch."""
+        return None
+
     def _row_tiers(self, size: int) -> List[int]:
         """Packed-row shape buckets for an entry bucket of ``size``.
         Rows range from ~size (all-delta) to 4*size (all-full) + the 4
@@ -2139,13 +2078,6 @@ class SearchService(CoalesceBackend):
             return [2 * size + 4, 3 * size + 4, 4 * size + 4]
         return [4 * size + 4]
 
-    def _shard_row_tiers(self, shard: int) -> List[int]:
-        """Per-SHARD row tiers for the sharded packed wire: every shard
-        pads its rows to one common tier so the stacked stream's leading
-        axis splits evenly over the mesh. 4*shard+4 always fits (all-full
-        plus the shard's trailing sentinel block)."""
-        return [2 * shard + 4, 3 * shard + 4, 4 * shard + 4]
-
     def warmup(self) -> None:
         """Compile every (entry bucket x packed-row tier) with dummy
         data. Call before timing anything: a first-touch compile
@@ -2159,26 +2091,6 @@ class SearchService(CoalesceBackend):
             if self._warmed:
                 return
             for s in self._eval_sizes:
-                if self._sharded_packed:
-                    # Compile each per-shard row tier of the mesh path.
-                    shard = s // self.shard_multiple
-                    for rt in self._shard_row_tiers(shard):
-                        if self._stopping:
-                            return
-                        packed = np.full(
-                            (self.shard_multiple * rt, 2, 8),
-                            spec.NUM_FEATURES, np.uint16,
-                        )
-                        np.asarray(
-                            self._eval_fn(
-                                self._params, packed,
-                                np.full((s,), rt - 4, np.int32),
-                                np.zeros((s,), np.int32),
-                                np.full((s,), -1, np.int32),
-                                np.zeros((s,), np.int32),
-                            )
-                        )
-                    continue
                 for tier in self._row_tiers(s):
                     if self._stopping:  # close() during startup
                         return
@@ -2851,12 +2763,8 @@ class SearchService(CoalesceBackend):
                 )
             )
             return values, acct
-        if self._sharded_packed:
-            return self._dispatch_sharded_packed(
-                size, n, rows, packed, offsets, buckets, parents, material
-            )
-        # External evaluator (non-packed: test doubles, legacy meshes):
-        # hand it the dense expansion.
+        # A remote evaluator (_remote_evaluator) replays the dense
+        # microbatch: hand it the expansion.
         from fishnet_tpu.nnue.jax_eval import expand_packed_np
 
         feats = expand_packed_np(
@@ -2866,54 +2774,6 @@ class SearchService(CoalesceBackend):
         return self._eval_fn(
             self._params, feats, buckets[:size], parents[:size],
             material[:size],
-        ), acct
-
-    def _dispatch_sharded_packed(self, size, n, rows, packed, offsets,
-                                 buckets, parents, material):
-        """Repack the pool's row stream into a per-shard fixed row tier
-        and ship it to the sharded evaluator's packed path.
-
-        The pool's aligned emission (fc_pool_step `align`) already keeps
-        every entry's rows, and every delta's anchor, inside one shard's
-        ENTRY span; here the ROW stream is cut at the shard boundaries
-        (each boundary entry starts its own block, so its offset IS the
-        cut), each shard's slice padded with sentinel rows to one common
-        tier, and offsets rewritten shard-local. One ~MB-scale memcpy
-        per step — in exchange the mesh path stops paying the 4x dense
-        wire plus the host-side expand_packed_np the packed format was
-        built to delete."""
-        mult = self.shard_multiple
-        shard = size // mult
-        bounds = np.empty(mult + 1, np.int64)
-        for k in range(mult):
-            idx = k * shard
-            bounds[k] = offsets[idx] if idx < n else rows
-        bounds[mult] = rows
-        shard_rows = np.diff(bounds)
-        need = int(shard_rows.max()) + 4
-        tier = self._shard_row_tiers(shard)[-1]
-        for rt in self._shard_row_tiers(shard):
-            if need <= rt:
-                tier = rt
-                break
-        out_packed = np.full((mult * tier, 2, 8), spec.NUM_FEATURES,
-                             np.uint16)
-        out_offsets = np.empty(size, np.int32)
-        for k in range(mult):
-            rs, re = int(bounds[k]), int(bounds[k + 1])
-            out_packed[k * tier : k * tier + (re - rs)] = packed[rs:re]
-            lo, hi = k * shard, (k + 1) * shard
-            real_hi = min(hi, n)
-            if lo < real_hi:
-                out_offsets[lo:real_hi] = offsets[lo:real_hi] - rs
-            if real_hi < hi:
-                # Padding entries decode as all-sentinel fulls from the
-                # shard's own trailing sentinel block.
-                out_offsets[real_hi:hi] = tier - 4
-        acct = (size, mult * tier * 2 * 8 * 2 + size * 3 * 4, size * 4)
-        return self._eval_fn(
-            self._params, out_packed, out_offsets, buckets[:size],
-            parents[:size], material[:size],
         ), acct
 
     def _dispatch_segmented(self, tickets: List[_CoalesceTicket]) -> None:
@@ -3397,7 +3257,8 @@ class SearchService(CoalesceBackend):
                     self._pool, g, packed_ptrs[g], offset_ptrs[g],
                     bucket_ptrs[g], slot_ptrs[g],
                     parent_ptrs[g], material_ptrs[g], self._group_capacity,
-                    self._shard_align, ctypes.byref(rows),
+                    0,  # align: a microbatch is one device's, never split
+                    ctypes.byref(rows),
                 )
                 # Step-trace root: each eval microbatch gets a fresh
                 # trace at pack time; device_step chains under it and

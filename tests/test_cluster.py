@@ -289,11 +289,14 @@ async def test_sigterm_drains_real_process_to_exit_zero(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-async def test_sigkill_reassignment_fleet_smoke():
+async def test_sigkill_reassignment_fleet_smoke(monkeypatch):
     """kill -9 mid-dispatch on one process of a two-process fleet: the
     server's reassignment sweep hands its work out again, the
     supervisor restarts it under budget, and the fleet ledger ends
     exactly-once — 0 lost, 0 duplicated."""
+    # A position takes 50 ms, as a real analysis takes time: a process
+    # holds a unit nearly always, not for the instant its submit flies.
+    monkeypatch.setenv("FISHNET_MOCK_ENGINE_DELAY", "0.05")
     lichess = FakeLichess(require_key=False)
     lichess.auto_refill = 4
     lichess.refill_move_every = 4
@@ -309,9 +312,31 @@ async def test_sigkill_reassignment_fleet_smoke():
             drain_deadline=5.0,
         )
         await supervisor.start()
+        # KA's plan counts its ticks from KA's first unit: a process
+        # still starting when the kill comes leaves nothing to reassign.
+        ka = supervisor.procs["KA"]
+        plan, ka.plan = ka.plan, None
         try:
-            t0 = time.monotonic()
-            while time.monotonic() - t0 < 9.0:
+            # Wait for what is asserted below, not for the clock: on a
+            # loaded machine each step comes when it comes.
+            # The drain waits for the restarted KA's first unit too: a
+            # SIGTERM before its handler is up ends it with -15.
+            deadline = time.monotonic() + 45.0
+            restarted_at = None
+            handed = lichess.fleet.acquires_by_proc
+            while time.monotonic() < deadline:
+                if ka.plan is None and "KA" in handed:
+                    ka.plan = plan
+                seen = {k for _, _, k in supervisor.events}
+                if restarted_at is None and "restart" in seen:
+                    restarted_at = time.monotonic()
+                if (
+                    "kill" in seen
+                    and restarted_at is not None
+                    and handed["KA"][-1] > restarted_at
+                    and lichess.fleet_report()["reassigned"] >= 1
+                ):
+                    break
                 await asyncio.sleep(0.25)
             exit_codes = await supervisor.drain()
         except BaseException:

@@ -267,17 +267,20 @@ def test_choose_coalesce_width(fixed, marginal, slots, n_groups, expected):
     assert choose_coalesce_width(fixed, marginal, slots, n_groups) == expected
 
 
-def test_suggest_pipeline_depth_returns_probe():
+def test_suggest_pipeline_depth_returns_probe(monkeypatch):
     """return_probe=True: the startup probe reports the fixed/marginal
     decomposition alongside the depth, through the same harness."""
+    from fishnet_tpu.nnue import jax_eval
+
     calls = []
 
     def instant_eval(params, feats, buckets):
         calls.append(len(buckets))
         return np.zeros((len(buckets),), np.int32)
 
+    monkeypatch.setattr(jax_eval, "evaluate_batch_jit", instant_eval)
     depth, probe = suggest_pipeline_depth(
-        None, size=1024, rounds=3, eval_fn=instant_eval, return_probe=True
+        None, size=1024, rounds=3, device_params={}, return_probe=True
     )
     assert depth in (1, 2, 4)
     assert isinstance(probe, DispatchProbe)

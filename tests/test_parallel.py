@@ -1,105 +1,17 @@
-"""Sharded evaluator: multi-device integer eval must be bit-identical to
-the single-device jit."""
+"""Multi-chip serving: the shard router, per-shard dispatch parity on
+every rung, and the SearchService-level mesh smoke. Sharded analyses
+must be bit-identical to the single-device service's."""
 
 import asyncio
+import functools
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from fishnet_tpu.nnue import spec
-from fishnet_tpu.nnue.jax_eval import evaluate_batch_jit, params_from_weights
+from fishnet_tpu.nnue.jax_eval import params_from_weights
 from fishnet_tpu.nnue.weights import NnueWeights
-from fishnet_tpu.parallel.mesh import ShardedEvaluator, make_mesh
-
-
-def test_sharded_eval_matches_single_device():
-    weights = NnueWeights.random(seed=11)
-    params = params_from_weights(weights)
-    mesh = make_mesh()
-    evaluator = ShardedEvaluator(params, mesh=mesh, batch_capacity=64)
-    assert evaluator.batch_capacity % mesh.devices.size == 0
-
-    rng = np.random.default_rng(3)
-    n = evaluator.batch_capacity
-    indices = np.full((n, 2, spec.MAX_ACTIVE_FEATURES), spec.NUM_FEATURES, np.int32)
-    for b in range(n):
-        k = int(rng.integers(4, spec.MAX_ACTIVE_FEATURES + 1))
-        for p in range(2):
-            indices[b, p, :k] = np.sort(
-                rng.choice(spec.NUM_FEATURES, k, replace=False)
-            )
-    buckets = rng.integers(0, 8, n, dtype=np.int32)
-
-    sharded = np.asarray(evaluator(None, jnp.asarray(indices), jnp.asarray(buckets)))
-    single = np.asarray(evaluate_batch_jit(params, jnp.asarray(indices), jnp.asarray(buckets)))
-    np.testing.assert_array_equal(sharded, single)
-
-
-def test_sharded_eval_compiles_without_collectives():
-    """VERDICT r2 weak #5: GSPMD resolved cross-shard delta references
-    with an all-gather of the [B, 2, 1024] int32 accumulators (~134 MB
-    per 16k step over ICI). The shard_map formulation plus the pool's
-    shard-aligned block emission make the compiled program collective-
-    free BY CONSTRUCTION — pinned here against the HLO text."""
-    params = params_from_weights(NnueWeights.random(seed=11))
-    evaluator = ShardedEvaluator(params, mesh=make_mesh(), batch_capacity=64)
-    n = evaluator.batch_capacity
-    indices = np.full(
-        (n, 2, spec.MAX_ACTIVE_FEATURES), spec.NUM_FEATURES, np.uint16
-    )
-    buckets = np.zeros((n,), np.int32)
-    parent = np.full((n,), -1, np.int32)
-    material = np.zeros((n,), np.int32)
-    hlo = (
-        evaluator._fn_mat.lower(
-            evaluator.params, indices, buckets, parent, material
-        )
-        .compile()
-        .as_text()
-    )
-    for collective in (
-        "all-gather", "all-reduce", "all-to-all", "collective-permute",
-        "ragged-all-to-all",
-    ):
-        assert collective not in hlo, f"sharded eval emits {collective}"
-
-
-def test_sharded_delta_blocks_match_single_device():
-    """Shard-aligned incremental blocks (the production wire shape) must
-    evaluate bit-identically sharded and single-device: the evaluator
-    rebases anchor codes shard-locally and every anchor lives in the
-    same shard as its children (the pool's emit alignment guarantees
-    it; a cross-shard reference raises)."""
-    import pytest
-    from test_ops import _block_batch
-
-    params = params_from_weights(NnueWeights.random(seed=19))
-    mesh = make_mesh()
-    evaluator = ShardedEvaluator(params, mesh=mesh, batch_capacity=64)
-    n = evaluator.batch_capacity
-    n_dev = mesh.devices.size
-    shard = n // n_dev
-    rng = np.random.default_rng(7)
-    # One block per shard: every delta's anchor is its shard's entry 0.
-    idx, parent, _ = _block_batch(
-        spec.NUM_FEATURES, spec.MAX_ACTIVE_FEATURES, n // shard, shard, rng
-    )
-    buckets = rng.integers(0, 8, n).astype(np.int32)
-    sharded = np.asarray(
-        evaluator(None, np.asarray(idx), buckets, np.asarray(parent))
-    )
-    single = np.asarray(
-        evaluate_batch_jit(params, idx, jnp.asarray(buckets), parent)
-    )
-    np.testing.assert_array_equal(sharded, single)
-
-    # A cross-shard reference must be rejected loudly, not silently
-    # resolved against the wrong shard's accumulator.
-    bad = np.asarray(parent).copy()
-    bad[shard + 1] = 0 << 1  # second shard's child anchored in the first
-    with pytest.raises(ValueError, match="outside its mesh shard"):
-        evaluator(None, np.asarray(idx), buckets, bad)
 
 
 def test_graft_entry_dryrun():
@@ -113,94 +25,76 @@ def test_graft_entry_dryrun():
     ge.dryrun_multichip(8)
 
 
-def test_sharded_service_rounds_buckets_to_shard_multiple():
-    """Every eval-size bucket (and the capacities) must split evenly
-    across the mesh, or the sharded jit would reject the batch shape."""
-    from fishnet_tpu.search.service import SearchService
+# ---------------------------------------------------------------------------
+# Placement-aware serving mesh (doc/sharding.md): what --mesh asks the
+# router for, shard router units, per-shard segmented-dispatch parity,
+# the per-shard programs' zero collectives, and the SearchService-level
+# mesh smoke (parity, escape hatch, per-shard ladder isolation, drain
+# re-routing).
+# ---------------------------------------------------------------------------
 
-    weights = NnueWeights.random(seed=5)
-    evaluator = ShardedEvaluator(
-        params_from_weights(weights), mesh=make_mesh(), batch_capacity=64
+
+def _mesh_opt(mesh_spec):
+    from fishnet_tpu.configure import Opt, parse_mesh
+
+    return Opt(
+        mesh=parse_mesh(mesh_spec), microbatch=320, pipeline=2,
+        search_threads=4,
     )
-    svc = SearchService(
-        weights=weights,
-        pool_slots=16,
-        batch_capacity=100,  # deliberately not a multiple of 8
-        tt_bytes=4 << 20,
-        evaluator=evaluator,
-        eval_sizes=(50, 100),
-    )
-    try:
-        n_dev = evaluator.size_multiple
-        assert svc.batch_capacity % n_dev == 0
-        assert svc._group_capacity % n_dev == 0
-        assert all(s % n_dev == 0 for s in svc._eval_sizes)
-    finally:
-        svc.close()
 
 
-async def test_client_e2e_on_sharded_path(anyio_backend):
-    """The multi-chip serving slice: fake lichess server -> Client ->
-    workers -> shared SearchService whose leaf microbatches are sharded
-    over the 8-device mesh (VERDICT round 1: serving must not hardcode
-    the single-device evaluator)."""
-    import asyncio
+@pytest.mark.parametrize(
+    "mesh_spec, shards", [("4x2", 8), ("8x1", 8), ("2x1", 2), ("1x1", None)]
+)
+def test_mesh_spec_asks_the_router_for_data_times_model_shards(
+    mesh_spec, shards, capsys
+):
+    """An explicit --mesh DxM is D * M shards of the placement-aware
+    mesh whatever M is; one device is the single-device service."""
+    from fishnet_tpu import __main__ as cli
+    from fishnet_tpu.utils.logger import Logger
 
-    from fishnet_tpu.client import Client
-    from fishnet_tpu.engine.tpu_engine import TpuNnueEngineFactory
+    assert cli.resolve_mesh_devices(_mesh_opt(mesh_spec), Logger()) == shards
+    said = capsys.readouterr().out
+    assert ("Placement-aware serving mesh" in said) == (shards is not None)
+    if shards:
+        assert f"over {shards} devices" in said
+
+
+def test_mesh_spec_larger_than_the_devices_is_a_config_error():
+    """--mesh 16x1 on eight devices fails before any service is built."""
+    from fishnet_tpu import __main__ as cli
+    from fishnet_tpu.configure import ConfigError
+    from fishnet_tpu.utils.logger import Logger
+
+    with pytest.raises(ConfigError, match="needs 16 devices, found 8"):
+        cli.resolve_mesh_devices(_mesh_opt("16x1"), Logger())
+    with pytest.raises(ConfigError, match="needs 16 devices"):
+        cli.validate_mesh(_mesh_opt("16x1"))
+
+
+def test_mesh_4x2_builds_a_service_of_eight_shards(monkeypatch, capsys):
+    """`Mesh = 4x2` starts on the path the chip has run: the CLI's
+    service has one shard a device, each with its own params replica."""
+    from fishnet_tpu import __main__ as cli
     from fishnet_tpu.search.service import SearchService
     from fishnet_tpu.utils.logger import Logger
-    from tests.fake_server import VALID_KEY, FakeServer
 
-    weights = NnueWeights.random(seed=11)
-    evaluator = ShardedEvaluator(
-        params_from_weights(weights), mesh=make_mesh(), batch_capacity=64
-    )
-    service = SearchService(
-        weights=weights,
-        pool_slots=64,
-        batch_capacity=64,
-        tt_bytes=16 << 20,
-        evaluator=evaluator,
-    )
+    monkeypatch.delenv("FISHNET_NO_MESH", raising=False)
+    monkeypatch.delenv("FISHNET_RPC", raising=False)
+    svc = cli.build_search_service(_mesh_opt("4x2"), Logger())
     try:
-        async with FakeServer() as server:
-            work_id = server.lichess.add_analysis_job(
-                moves="e2e4 c7c5 g1f3", nodes=300
-            )
-            client = Client(
-                endpoint=server.endpoint,
-                key=VALID_KEY,
-                cores=2,
-                engine_factory=TpuNnueEngineFactory(service),
-                logger=Logger(),
-                max_backoff=0.2,
-            )
-            await client.start()
-            deadline = asyncio.get_running_loop().time() + 120.0
-            while asyncio.get_running_loop().time() < deadline:
-                if work_id in server.lichess.analyses:
-                    break
-                await asyncio.sleep(0.05)
-            await client.stop()
-            assert work_id in server.lichess.analyses, (
-                "analysis not completed within deadline on the sharded path"
-            )
-            parts = server.lichess.analyses[work_id]["analysis"]
-            assert len(parts) == 4
-            for part in parts:
-                assert "score" in part
-                assert part["nodes"] >= 1
+        assert type(svc) is SearchService
+        report = svc.shard_report()
+        assert report["n_shards"] == 8 and all(report["alive"])
+        assert [len(g) for g in report["groups"]] == [1] * 8
+        placed = {
+            next(iter(p["ft_w"].devices())) for p in svc._shard_params
+        }
+        assert len(placed) == 8
+        assert "over 8 devices" in capsys.readouterr().out
     finally:
-        service.close()
-
-
-# ---------------------------------------------------------------------------
-# Placement-aware serving mesh (doc/sharding.md): shard router units,
-# per-shard segmented-dispatch parity, the shard_map reference
-# semantics, and the SearchService-level mesh smoke (parity, escape
-# hatch, per-shard ladder isolation, drain re-routing).
-# ---------------------------------------------------------------------------
+        svc.close()
 
 
 def test_serving_devices_resolution_and_escape_hatch(monkeypatch):
@@ -359,42 +253,69 @@ def test_per_shard_dispatch_matches_fused_and_single(rung, monkeypatch):
         assert np.array_equal(fused[2][k], ref_pt), (rung, k)
 
 
-def test_sharded_segmented_evaluator_parity_and_no_collectives(monkeypatch):
-    """The shard_map reference semantics for the serving topology:
-    ShardedSegmentedEvaluator over 2 devices is bit-identical to the
-    single-device segmented evaluator, its compiled HLO contains ZERO
-    collectives (segment-locality makes every shard self-contained),
-    and a segment count that does not divide over the mesh is rejected
-    loudly."""
-    import jax
+def _lower(fn, *args):
+    """Lower a rung's executor: a jitted function, or one under a
+    functools.partial that pins (use_pallas, interpret)."""
+    if isinstance(fn, functools.partial):
+        return fn.func.lower(*fn.args, *args, **fn.keywords)
+    return fn.lower(*args)
 
-    from fishnet_tpu.nnue.jax_eval import evaluate_packed_anchored_segmented
-    from fishnet_tpu.parallel.mesh import ShardedSegmentedEvaluator
 
-    params = params_from_weights(NnueWeights.random(seed=37))
-    segs, size, _ = _shard_split_segments("host-material", monkeypatch)
-    wire = _cat_segments(segs, size)
+@pytest.mark.parametrize("rung", ["xla", "host-material"])
+def test_per_shard_programs_hold_no_collectives_and_name_one_device(rung):
+    """What the router dispatches never crosses devices: the solo and
+    the fused program of a shard other than the first, compiled from
+    that shard's replica and its group's tables, hold no collective and
+    run on that shard's device alone."""
+    from fishnet_tpu.search.service import SearchService
 
-    evaluator = ShardedSegmentedEvaluator(devices=jax.devices()[:2])
-    got = tuple(map(np.asarray, evaluator(params, *wire)))
-    ref = tuple(map(np.asarray, evaluate_packed_anchored_segmented(
-        params, *wire, use_pallas=False
-    )))
-    for g, r, what in zip(got, ref, ("values", "anchor tabs", "psqt tabs")):
-        assert np.array_equal(g, r), f"sharded segmented diverged: {what}"
-
-    hlo = (
-        evaluator._fn_mat.lower(params, *wire).compile().as_text()
+    svc = SearchService(
+        weights=NnueWeights.random(seed=41), pool_slots=8,
+        batch_capacity=256, tt_bytes=8 << 20, pipeline_depth=4,
+        driver_threads=1, mesh_devices="auto", psqt_path=rung,
     )
-    for collective in (
-        "all-gather", "all-reduce", "all-to-all", "collective-permute",
-        "ragged-all-to-all",
-    ):
-        assert collective not in hlo, f"sharded segmented emits {collective}"
+    try:
+        assert svc._router is not None and svc.psqt_path == rung
+        shard = svc._n_shards - 1
+        group = svc._router.groups_of(shard)[0]
+        params, eval_fn, seg_fn, ship_material, dev = svc._eval_state(group)
+        assert dev == svc._shard_devices[shard] != svc._shard_devices[0]
+        assert ship_material == (rung == "host-material")
+        size = svc._eval_sizes[0]
+        tier = svc._row_tiers(size)[0]
 
-    with pytest.raises(ValueError, match="does not divide"):
-        bad = [segs[0], segs[1], segs[2]]
-        evaluator(params, *_cat_segments(bad, size))
+        def wire(k):
+            return (
+                np.full((k * tier, 2, 8), spec.NUM_FEATURES, np.uint16),
+                np.zeros((k * size,), np.int32),
+                np.full((k * size,), -1, np.int32),
+                np.zeros((k * size,), np.int32) if ship_material else None,
+            )
+
+        tab, ptab = svc._anchor_tabs[group], svc._psqt_tabs[group]
+        programs = {
+            "solo": _lower(
+                eval_fn, params, *wire(1), tab, np.zeros((1,), np.int32),
+                ptab,
+            ),
+            "fused": _lower(
+                seg_fn, params, *wire(2), jnp.stack([tab, tab]),
+                np.zeros((2,), np.int32), jnp.stack([ptab, ptab]),
+            ),
+        }
+        for name, lowered in programs.items():
+            compiled = lowered.compile()
+            hlo = compiled.as_text()
+            for collective in (
+                "all-gather", "all-reduce", "collective-permute",
+                "all-to-all",
+            ):
+                assert collective not in hlo, (rung, name, collective)
+            assert compiled.runtime_executable().local_devices() == [dev], (
+                rung, name,
+            )
+    finally:
+        svc.close()
 
 
 def _mesh_smoke(weights, mesh_devices):
@@ -563,43 +484,3 @@ def test_mesh_drain_reroutes_groups_to_siblings():
     finally:
         svc.gate.set()
         svc.close()
-
-
-async def test_sharded_packed_search_parity(anyio_backend):
-    """The sharded PACKED wire (service-side per-shard repack +
-    on-device expansion inside the shard_map) must reproduce the
-    single-device backend's search results exactly — scores, mate
-    flags, and best moves, position by position. Sequential submission
-    + pinned prefetch, like every cross-backend parity suite (the TT
-    evolution must be a deterministic function of the sequence)."""
-    from fishnet_tpu.search.service import SearchService
-    from tests.test_search import _parity_results, _random_fens
-
-    weights = NnueWeights.random(seed=23)
-    fens = _random_fens(10, seed=123)
-
-    single = await _parity_results("jax", weights, fens, depth=3, prefetch=4)
-
-    evaluator = ShardedEvaluator(
-        params_from_weights(weights), mesh=make_mesh(), batch_capacity=64
-    )
-    svc = SearchService(
-        weights=weights, pool_slots=16, batch_capacity=64,
-        tt_bytes=64 << 20, evaluator=evaluator,
-    )
-    svc.set_prefetch(4, adaptive=False)
-    try:
-        assert svc._sharded_packed, "mesh path fell back to dense wire"
-        sharded = []
-        for fen in fens:
-            r = await svc.search(fen, [], depth=3)
-            line = [l for l in r.lines if l.multipv == 1][-1]
-            sharded.append((line.value, line.is_mate, r.best_move))
-    finally:
-        svc.close()
-    mismatches = [
-        (fen, s, j) for fen, s, j in zip(fens, single, sharded) if s != j
-    ]
-    assert not mismatches, (
-        f"{len(mismatches)} of {len(fens)} diverged; first: {mismatches[0]}"
-    )
