@@ -2080,10 +2080,10 @@ def _checked(params: Params, given: int, make) -> TrunkConfig:
 
 def attention_heads_paired(cfg: TrunkConfig) -> float:
     """The share of the plan's (attention layer, query head)s whose scores
-    ``board_attention`` makes two a product: the plain pair's, by the group
-    alone (``paired_heads``); 0 of a latent layer's and of a block-masked
-    one's, whose kernels are other bodies. Static: the choice is the
-    shapes'. ``AzTrainer``'s ``train_init`` span carries it."""
+    ``board_attention`` makes two a product: the plain pair's and (since PR
+    63) the block-masked pair's, by the group alone (``paired_heads``); 0 of
+    a latent layer's, whose kernels are another body. Static: the choice is
+    the shapes'. ``AzTrainer``'s ``train_init`` span carries it."""
     cores = [sublayer for sublayer in trunk_plan(cfg) if sublayer.kind in ("attention", "latent", "cca")]
-    plain = sum(sublayer.kind != "latent" and not cfg.block_length for sublayer in cores)
-    return plain * paired_heads(cfg.heads, cfg.kv_heads or cfg.heads) / (len(cores) * cfg.heads) if cores else 0.0
+    grouped = sum(sublayer.kind != "latent" for sublayer in cores)
+    return grouped * paired_heads(cfg.heads, cfg.kv_heads or cfg.heads) / (len(cores) * cfg.heads) if cores else 0.0

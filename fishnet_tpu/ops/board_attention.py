@@ -87,7 +87,9 @@ A third form is a kernel pair of its own, ``board_attention_blocks`` and
 under a BLOCK MASK, told ``block_length`` and how many ``streams`` of a
 board ride side by side along the rows, a clean copy alone (64 rows,
 block-causal) or a clean and a noised copy (128 rows: block-diffusion
-training, ``block_mask``). The pair above is not touched by it.
+training, ``block_mask``). The pair above is not touched by it. Since PR
+63 it takes a group's query heads two at a time as the plain pair does
+(the same ``_pairs``, ``_stacked``, ``_rows``).
 
 Off the TPU both kernels run under the Pallas interpreter.
 """
@@ -126,6 +128,14 @@ _UNROLL_GRAD = 8
 #: flat to 2%. Double-buffered, a step's blocks are 7 MiB (forward) and 11.5 MiB (gradient) at a head of 256, half that at 128.
 _PAIRED_BOARDS = 32
 _PAIRED_UNROLL = 8
+
+#: The same two counts for the block-masked pair where a key-value head's query heads go two a product (since PR 63), in (board, head, copy)s:
+#: at a group of 8 under both copies 4 boards a grid step AND a loop body (4 pairs x 2 copies a board), the fastest of 1-4 x 1-4 on a v5e at
+#: [128, 128, 32 x 128] over 4 key-value heads (PERF.md section 5). Here a LONGER body is faster, unlike the plain pair's: 1 / 2 / 4 boards a body
+#: read 1.29 / 1.19 / 1.13 ms forward and 1.83 / 1.71 / 1.68 gradient; boards a step are flat. Double-buffered, a step's blocks are 6.75 MiB
+#: (forward) and 11.5 MiB (gradient) of VMEM at a head of 128: 8 boards would pass the 16 MiB a kernel gets by default.
+_BLOCKS_PAIRED_BOARDS = 64
+_BLOCKS_PAIRED_UNROLL = 64
 
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
 
@@ -775,12 +785,17 @@ _latent_attention.defvjp(_latent_attention_fwd, _latent_attention_bwd)
 # With S = 1 there is the clean copy alone under its first rule: what is served. Both copies are normed and turned as
 # the plain form's rows are, position = SQUARE index in both (a noised square and its clean twin turn alike: the tables
 # are laid twice along the rows). A grid step is one key-value head of a few boards and its group of query heads: the
-# head's k (both copies) is normed, turned and read ONCE for the clean and the noised queries of the group; the scores
-# stand ``[key, query]``, 64 x 64 for the clean queries and 128 x 64 for the noised ones (the clean queries x noised
-# keys quarter, which is all masked, is never made), and never reach HBM; what is not allowed is taken out BEFORE the
-# softmax's maximum and sums by a bias of -inf that is an operand like the tables (``_block_bias``; its probability is
-# exactly 0, forward and in the gradient's recomputation, and so is its ``ds``); the gradient sums dk, dv of the clean
-# copy over both copies' queries and over the group in VMEM, float32, rounded once.
+# head's k (both copies) is normed, turned and read ONCE for the clean and the noised queries of the group; the group's
+# query heads go TWO A PRODUCT (since PR 63, as the plain pair's since PR 61: an odd group's last head alone, a group of
+# 1 one head at a time): the pair's raw queries stacked along the rows, ``[2 * rows, head_dim]``, are normed and turned in
+# ONE pass (and taken back through RoPE and the norm in one in the gradient: the chip read the gradient 4-6% faster so and
+# the forward no slower), and copy ``s``'s queries of both heads stand side by side on the lanes; the scores
+# stand ``[key, query]``, 64 x 128 for a pair's clean queries and 128 x 128 for its noised ones (the clean queries x noised
+# keys quarter, which is all masked, is never made), full 128-lane tiles, and never reach HBM; what is not allowed is taken
+# out BEFORE the softmax's maximum and sums by a bias of -inf that is an operand like the tables (``_block_bias``, laid twice
+# along a pair's queries; its probability is exactly 0, forward and in the gradient's recomputation, and so is its ``ds``);
+# the gradient sums dk, dv of the clean copy over both copies' queries and over the group in VMEM, float32, rounded once: a
+# pair's sum is its products' own contraction over 128 queries.
 
 
 def block_mask(block_length: int, streams: int = 2) -> np.ndarray:
@@ -797,14 +812,20 @@ def block_mask(block_length: int, streams: int = 2) -> np.ndarray:
     return np.block([[clean, none], [blk[None, :] < blk[:, None], blk[None, :] == blk[:, None]]])
 
 
-def _block_bias(block_length: int, streams: int) -> np.ndarray:
+def _block_bias(block_length: int, streams: int, heads: int = 1) -> np.ndarray:
     """The mask as the kernels add it to their ``[key, query]`` scores, 0
     where allowed and -inf where not, float32: rows ``[0, 64)`` the clean
     keys under the clean queries, then (two streams) rows ``[64, 192)``
-    all 128 keys under the noised queries."""
+    all 128 keys under the noised queries; laid ``heads`` times along the
+    queries for the query heads of one product."""
     mask = block_mask(block_length, streams)
     parts = [mask[:SQUARES, :SQUARES].T] + ([mask[SQUARES:].T] if streams == 2 else [])
-    return np.where(np.concatenate(parts), 0.0, -np.inf).astype(np.float32)
+    return np.tile(np.where(np.concatenate(parts), 0.0, -np.inf).astype(np.float32), (1, heads))
+
+
+def _bias(bias_ref, s: int, heads: int) -> jax.Array:
+    """Copy ``s``'s rows of the bias (``_block_bias``) under the queries of ``heads`` heads side by side: an odd group's last head reads the first 64 lanes."""
+    return bias_ref[:SQUARES, :heads * SQUARES] if s == 0 else bias_ref[SQUARES:, :heads * SQUARES]
 
 
 def _stream(s: int) -> slice:
@@ -812,22 +833,35 @@ def _stream(s: int) -> slice:
     return slice(s * SQUARES, (s + 1) * SQUARES)
 
 
+def _pair_tables(cos_ref, sin_ref, group: int) -> dict:
+    """The tables by how many query heads an operand stacks along the rows: as the operand lays them for one head (and the keys), laid twice for a pair."""
+    one = cos_ref[...], sin_ref[...]
+    return {1: one} if group == 1 else {1: one, 2: tuple(jnp.concatenate([table, table]) for table in one)}
+
+
+def _copy(i: int, s: int, rows: int) -> slice:
+    """Copy ``s`` of head ``i`` in a pair's ``[2 * rows, head_dim]`` operand: each head's copies one under the other, the heads likewise."""
+    return slice(i * rows + s * SQUARES, i * rows + (s + 1) * SQUARES)
+
+
 def _blocks_forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, bias_ref, out_ref, *, eps: float, unroll: int):
-    cos, sin, gq, gk = cos_ref[...], sin_ref[...], gq_ref[...], gk_ref[...]
-    head_dim, streams = k_ref.shape[-1], q_ref.shape[1] // SQUARES
-    bias = [bias_ref[:SQUARES]] + ([bias_ref[SQUARES:]] if streams == 2 else [])
-    prepared = lambda x, gain: _rope(_unit(x, eps)[0] * gain, cos, sin).astype(jnp.bfloat16)
+    gq, gk = gq_ref[...], gk_ref[...]
+    rows, head_dim = k_ref.shape[1:]
+    streams, group = rows // SQUARES, q_ref.shape[-1] // head_dim
+    tables = _pair_tables(cos_ref, sin_ref, group)
+    prepared = lambda x, gain: _rope(_unit(x, eps)[0] * gain, *tables[x.shape[0] // rows]).astype(jnp.bfloat16)
 
     def board(b, carry):
         kb, vb = prepared(k_ref[b], gk), v_ref[b]
-        for g in range(q_ref.shape[-1] // head_dim):
-            lanes = slice(g * head_dim, (g + 1) * head_dim)
-            qb = prepared(q_ref[b, :, lanes], gq)
-            for s in range(streams):  # copy s's queries over the keys of the copies up to it
+        for heads in _pairs(group):
+            n = len(heads)
+            qb = prepared(_stacked([q_ref[b, :, _lanes(h, head_dim)] for h in heads]), gq)  # the pair's heads normed and turned in one pass
+            for s in range(streams):  # copy s's queries, the pair's side by side on the lanes, over the keys of the copies up to it
                 keys = SQUARES * (s + 1)
-                p = _softmax(_scores(kb[:keys], qb[_stream(s)]) + bias[s]).astype(jnp.bfloat16)
+                p = _softmax(_scores(kb[:keys], _stacked([qb[_copy(i, s, rows)] for i in range(n)])) + _bias(bias_ref, s, n)).astype(jnp.bfloat16)
                 mixed = jax.lax.dot_general(p, vb[:keys], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-                out_ref[b, _stream(s), lanes] = mixed.astype(out_ref.dtype)
+                for i, h in enumerate(heads):
+                    out_ref[b, _stream(s), _lanes(h, head_dim)] = _rows(mixed, i, n).astype(out_ref.dtype)
         return carry
 
     _each_board(q_ref.shape[0], board, 0, unroll)
@@ -835,35 +869,39 @@ def _blocks_forward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref
 
 def _blocks_backward_kernel(q_ref, k_ref, v_ref, gq_ref, gk_ref, cos_ref, sin_ref, bias_ref, do_ref,
                             dq_ref, dk_ref, dv_ref, dgq_ref, dgk_ref, *, eps: float, unroll: int):
-    cos, sin, gq, gk = cos_ref[...], sin_ref[...], gq_ref[...], gk_ref[...]
+    gq, gk = gq_ref[...], gk_ref[...]
     bf16, f32 = jnp.bfloat16, jnp.float32
     rows, head_dim = k_ref.shape[1:]
-    streams, scale = rows // SQUARES, np.float32(1.0 / math.sqrt(head_dim))
-    bias = [bias_ref[:SQUARES]] + ([bias_ref[SQUARES:]] if streams == 2 else [])
+    streams, group, scale = rows // SQUARES, q_ref.shape[-1] // head_dim, np.float32(1.0 / math.sqrt(head_dim))
+    tables = _pair_tables(cos_ref, sin_ref, group)
     added = lambda total, part: part if total is None else total + part
 
     def board(b, carry):
         dgq, dgk = carry
         uk, rk = _unit(k_ref[b], eps)
-        kb, vb = _rope(uk * gk, cos, sin).astype(bf16), v_ref[b]
-        dk_rot, dv = [None] * streams, [None] * streams  # float32 sums a copy's keys over the group's query heads and the copies that see them
-        for g in range(q_ref.shape[-1] // head_dim):
-            lanes = slice(g * head_dim, (g + 1) * head_dim)
-            uq, rq = _unit(q_ref[b, :, lanes], eps)
-            qb, do = _rope(uq * gq, cos, sin).astype(bf16), do_ref[b, :, lanes]
-            dq_rot = []
+        kb, vb = _rope(uk * gk, *tables[1]).astype(bf16), v_ref[b]
+        dk_rot, dv = [None] * streams, [None] * streams  # float32 sums a copy's keys over the group's pairs and the copies that see them
+        for heads in _pairs(group):
+            n = len(heads)
+            uq, rq = _unit(_stacked([q_ref[b, :, _lanes(h, head_dim)] for h in heads]), eps)
+            qb = _rope(uq * gq, *tables[n]).astype(bf16)
+            dq_rot = [None] * (n * streams)  # by head, then copy: the rows of ``qb``
             for s in range(streams):
                 keys = SQUARES * (s + 1)
-                qs, dos, ks, vs = qb[_stream(s)], do[_stream(s)], kb[:keys], vb[:keys]
-                p = _softmax(_scores(ks, qs) + bias[s])  # exactly 0 where the mask forbids, and so is ds
-                dv_s = jnp.dot(p.astype(bf16), dos, preferred_element_type=f32)
+                qs, dos = _stacked([qb[_copy(i, s, rows)] for i in range(n)]), _stacked([do_ref[b, _stream(s), _lanes(h, head_dim)] for h in heads])
+                ks, vs = kb[:keys], vb[:keys]
+                p = _softmax(_scores(ks, qs) + _bias(bias_ref, s, n))  # exactly 0 where the mask forbids, and so is ds
+                dv_s = jnp.dot(p.astype(bf16), dos, preferred_element_type=f32)  # over a pair's 128 queries: the pair's sum is the product's own
                 dq_s, dk_s = _score_gradients(p, ks, qs, vs, dos, scale)
-                dq_rot.append(dq_s)
+                for i in range(n):
+                    dq_rot[i * streams + s] = _rows(dq_s, i, n)
                 for t in range(s + 1):
                     dv[t], dk_rot[t] = added(dv[t], dv_s[_stream(t)]), added(dk_rot[t], dk_s[_stream(t)])
-            dq_ref[b, :, lanes], dgq_g = _unrope_unnorm(_stacked(dq_rot), uq, rq, gq, cos, sin, True)
-            dgq = dgq + dgq_g
-        dk_ref[b], dgk_b = _unrope_unnorm(_rounded(_stacked(dk_rot)), uk, rk, gk, cos, sin, True)
+            dq, dgq_g = _unrope_unnorm(_stacked(dq_rot), uq, rq, gq, *tables[n], True)  # the pair's heads back through RoPE and the norm in one pass
+            for i, h in enumerate(heads):
+                own = slice(i * rows, (i + 1) * rows)  # head i's rows of the stacked operand
+                dq_ref[b, :, _lanes(h, head_dim)], dgq = dq[own], dgq + dgq_g[own]
+        dk_ref[b], dgk_b = _unrope_unnorm(_rounded(_stacked(dk_rot)), uk, rk, gk, *tables[1], True)
         dv_ref[b] = _stacked(dv).astype(dv_ref.dtype)
         return dgq, dgk + dgk_b
 
@@ -882,27 +920,33 @@ def _stream_blocks(q: jax.Array, k: jax.Array, head_dim: int, streams: int):
         raise ValueError(f"q {q.shape} and k {k.shape} are not {streams} copies of a board's {SQUARES} squares side by side, or {heads} query heads "
                          f"do not divide over {kv_heads} key-value heads")
     group = heads // kv_heads
-    tb = math.gcd(boards, max(1, _BOARDS // (group * streams)))
+    tb = math.gcd(boards, max(1, (_BOARDS if group == 1 else _BLOCKS_PAIRED_BOARDS) // (group * streams)))
     per_head = pl.BlockSpec((tb, rows, head_dim), lambda i, h: (i, 0, h))
     per_group = pl.BlockSpec((tb, rows, group * head_dim), lambda i, h: (i, 0, h))
     whole = lambda size: pl.BlockSpec((size, head_dim), lambda i, h: (0, 0))
-    mask = pl.BlockSpec(((2 * streams - 1) * SQUARES, SQUARES), lambda i, h: (0, 0))
+    mask = pl.BlockSpec(((2 * streams - 1) * SQUARES, min(group, 2) * SQUARES), lambda i, h: (0, 0))
     partial = pl.BlockSpec((1, 1, head_dim), lambda i, h: (i, 0, h))
-    return (boards // tb, kv_heads), group * streams, per_head, per_group, whole, mask, partial
+    return (boards // tb, kv_heads), group, per_head, per_group, whole, mask, partial
 
 
-def _blocks_operands(g_q, g_k, tables, block_length: int, streams: int):
-    """The gains, the tables laid once a copy along the rows (a noised square turns as its clean twin) and the mask's bias."""
+def _blocks_operands(g_q, g_k, tables, block_length: int, streams: int, group: int):
+    """The gains, the tables laid once a copy along the rows (a noised square turns as its clean twin) and the mask's bias, laid twice along
+    the queries where a group's heads go two a product."""
     g_q, g_k, cos, sin = _operands(g_q, g_k, tables)
-    return g_q, g_k, jnp.tile(cos, (streams, 1)), jnp.tile(sin, (streams, 1)), jnp.asarray(_block_bias(block_length, streams))
+    return g_q, g_k, jnp.tile(cos, (streams, 1)), jnp.tile(sin, (streams, 1)), jnp.asarray(_block_bias(block_length, streams, min(group, 2)))
+
+
+def _blocks_unroll(interpret: bool, unroll: int, group: int, streams: int) -> int:
+    """``_unroll`` for the block-masked pair: ``unroll`` (board, head, copy)s at a group of 1, ``_BLOCKS_PAIRED_UNROLL`` where the heads go two a product."""
+    return _unroll(interpret, unroll if group == 1 else _BLOCKS_PAIRED_UNROLL, group * streams)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _blocks_attention(q, k, v, g_q, g_k, eps: float, interpret: bool, block_length: int, streams: int, tables: Tuple[np.ndarray, np.ndarray]):
     head_dim = g_q.shape[-1]
-    grid, pairs, per_head, per_group, whole, mask, _ = _stream_blocks(q, k, head_dim, streams)
+    grid, group, per_head, per_group, whole, mask, _ = _stream_blocks(q, k, head_dim, streams)
     return pl.pallas_call(
-        functools.partial(_blocks_forward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL, pairs)),
+        functools.partial(_blocks_forward_kernel, eps=eps, unroll=_blocks_unroll(interpret, _UNROLL, group, streams)),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(q.shape[1]), whole(q.shape[1]), mask],
         out_specs=per_group,
@@ -910,7 +954,7 @@ def _blocks_attention(q, k, v, g_q, g_k, eps: float, interpret: bool, block_leng
         compiler_params=_PARAMS,
         name="board_attention_blocks",
         interpret=interpret,
-    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams))
+    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams, group))
 
 
 def _blocks_attention_fwd(q, k, v, g_q, g_k, eps, interpret, block_length, streams, tables):
@@ -921,10 +965,10 @@ def _blocks_attention_bwd(eps, interpret, block_length, streams, tables, residua
     q, k, v, g_q, g_k = residuals
     head_dim = g_q.shape[-1]
     kv_heads = k.shape[-1] // head_dim
-    grid, pairs, per_head, per_group, whole, mask, partial = _stream_blocks(q, k, head_dim, streams)
+    grid, group, per_head, per_group, whole, mask, partial = _stream_blocks(q, k, head_dim, streams)
     sums = jax.ShapeDtypeStruct((grid[0], 1, kv_heads * head_dim), jnp.float32)
     dq, dk, dv, dgq, dgk = pl.pallas_call(
-        functools.partial(_blocks_backward_kernel, eps=eps, unroll=_unroll(interpret, _UNROLL_GRAD, pairs)),
+        functools.partial(_blocks_backward_kernel, eps=eps, unroll=_blocks_unroll(interpret, _UNROLL_GRAD, group, streams)),
         grid=grid,
         in_specs=[per_group, per_head, per_head, whole(1), whole(1), whole(q.shape[1]), whole(q.shape[1]), mask, per_group],
         out_specs=[per_group, per_head, per_head, partial, partial],
@@ -932,7 +976,7 @@ def _blocks_attention_bwd(eps, interpret, block_length, streams, tables, residua
         compiler_params=_PARAMS,
         name="board_attention_blocks_grad",
         interpret=interpret,
-    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams), d_mixed)
+    )(q, k, v, *_blocks_operands(g_q, g_k, tables, block_length, streams, group), d_mixed)
     total = lambda s, g: s.reshape(-1, head_dim).sum(axis=0).astype(g.dtype)
     return dq, dk, dv, total(dgq, g_q), total(dgk, g_k)
 

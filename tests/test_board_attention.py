@@ -617,16 +617,17 @@ def test_the_tolerances_catch_a_pair_written_to_each_others_lanes():
 # -- tools/attention_alone.py: the pair timed alone -----------------------------------------------------------------------------------------
 
 
-def test_attention_alone_prints_its_three_times_and_what_it_ran_on(capsys):
+@pytest.mark.parametrize("masked", [{}, {"block_length": 4, "streams": 2}], ids=["plain", "block_masked"])
+def test_attention_alone_prints_its_three_times_and_what_it_ran_on(capsys, masked):
     """The tool as a builder runs it on the chip, here at a tiny shape under the interpreter (the times are the interpreter's and say
     nothing of a device: ``interpret`` and ``device`` say so in the line): its three programs' times, the gradient kernel's by itself
-    among them, and against its own tree's file every output equal."""
+    among them, and against its own tree's file every output equal; the plain pair, and the block-masked one under both copies."""
     assert attention_alone.PROGRAMS == ("forward_ms", "forward_and_gradient_ms", "gradient_ms")
-    shape = ["--boards", "2", "--heads", "4", "--kv-heads", "2", "--d", "16"]
+    shape = ["--boards", "2", "--heads", "4", "--kv-heads", "2", "--d", "16", *(str(word) for name, value in masked.items() for word in ("--" + name.replace("_", "-"), value))]
     assert attention_alone.main([*shape, "--calls", "2", "--seed", "3000000019", "--against", kernels.__file__]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["interpret"] is True and line["device"] == jax.devices()[0].device_kind and line["finite"] is True
-    assert (line["boards"], line["heads"], line["kv_heads"], line["d"]) == (2, 4, 2, 16)
+    assert (line["boards"], line["heads"], line["kv_heads"], line["d"]) == (2, 4, 2, 16) and {name: line.get(name) for name in masked} == masked
     for times in (line, line["against"]):
         for program in attention_alone.PROGRAMS:
             assert 0.0 < times[program]["min"] <= times[program]["median"] <= times[program]["max"]

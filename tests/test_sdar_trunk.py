@@ -107,8 +107,9 @@ def value_and_gradients(f, q, k, v, g_q, g_k, cotangent):
 
 
 OUTPUTS = ["mixed", "d_q", "d_k", "d_v", "d_q_norm", "d_k_norm"]
-#: (heads, key-value heads, boards, block length, streams): a group of 1 and of 8 (this block's) under both copies, and the served form (the clean copy alone).
-CASES = {"group_1": (2, 2, 3, 4, 2), "group_8": (8, 1, 2, 8, 2), "served_group_8": (8, 1, 2, 4, 1)}
+#: (heads, key-value heads, boards, block length, streams): a group of 1 and of 8 (this block's: four pairs a product each) under both copies, the
+#: served form (the clean copy alone), and an odd group (a pair and a last head alone) under both and served.
+CASES = {"group_1": (2, 2, 3, 4, 2), "group_8": (8, 1, 2, 8, 2), "served_group_8": (8, 1, 2, 4, 1), "group_3": (3, 1, 2, 4, 2), "served_group_3": (3, 1, 2, 8, 1)}
 
 
 @functools.lru_cache(maxsize=None)
@@ -177,6 +178,23 @@ def test_a_key_that_is_not_allowed_changes_nothing(leak):
     seen = rows(0, [0]) if queries[0] >= SQUARES else rows(0, [20])  # a clean square of an earlier block; the clean query's own
     seen_k = k.at[:, seen].set(jnp.asarray(noise, k.dtype)[:, seen])
     assert not np.array_equal(np.asarray(run(seen_k, v)[0][:, queries], np.float32), np.asarray(mixed[:, queries], np.float32))
+
+
+@pytest.mark.parametrize("streams", [2, 1])
+def test_the_two_heads_of_a_product_get_each_its_own_rows_back(streams):
+    """Bit for bit: heads 0 and 1 (one product since PR 63) and head 2 (a lone last head) are given different queries and cotangents; head 0's
+    ``mixed`` and ``d_q`` are what they are with head 1's queries and cotangent overwritten, and head 1's move: a ``_rows`` that hands a head
+    its neighbour's half, or a stacked norm that reads the neighbour's rows, is seen."""
+    q, k, v, g_q, g_k, cotangent = core_inputs(2, 3, 1, 16, streams, seed=11)
+    run = jax.jit(lambda q, cotangent: value_and_gradients(lambda *a: board_attention(*a, THETA, EPS, True, block_length=4, streams=streams), q, k, v, g_q, g_k, cotangent)[:2])
+    noise = np.random.default_rng(13).standard_normal(q.shape)
+    head = lambda x, h: np.asarray(x, np.float32)[..., 16 * h:16 * (h + 1)]
+    other_q, other_cotangent = (y.at[..., 16:32].set(jnp.asarray(3.0 * noise, y.dtype)[..., 16:32]) for y in (q, cotangent))
+    (mixed, d_q), (other_mixed, other_d_q) = run(q, cotangent), run(other_q, other_cotangent)
+    for h in (0, 2):
+        assert np.array_equal(head(mixed, h), head(other_mixed, h)) and np.array_equal(head(d_q, h), head(other_d_q, h)) and np.any(head(d_q, h))
+    assert not np.array_equal(head(mixed, 1), head(other_mixed, 1)) and not np.array_equal(head(d_q, 1), head(other_d_q, 1))
+    assert not np.array_equal(head(mixed, 0), head(mixed, 1)) and not np.array_equal(head(d_q, 0), head(d_q, 1))
 
 
 # -- the noise and its maker -------------------------------------------------------------------------------------------------------------------
@@ -430,9 +448,11 @@ def test_the_new_field_is_refused_beside_what_the_masked_core_does_not_compute(w
 
 
 #: sha256 of the tiny lowered step program (``tools/step_text.py --block sdar``), as ``tests/test_hybrid_trunk.py PARENT_STEP_SHA256`` holds the four
-#: older blocks': read on PR 59's tree, which brought the block. The eight older blocks' pins (``test_hybrid_trunk.py``, ``test_cca_trunk.py``,
+#: older blocks': read anew on PR 63's tree, whose blocks pair takes the tiny plan's 4 heads over 1 two a product (``tools/step_text.py --block
+#: sdar --no-ids`` on both trees: the diff is the pair's bodies and the interpreter's loops round its two calls a layer, 8 boards a grid step
+#: where 1 was and the bias ``[192, 128]``). The eight older blocks' pins (``test_hybrid_trunk.py``, ``test_cca_trunk.py``,
 #: ``test_gdn_trunk.py``, ``test_mellum_trunk.py``) pass UNEDITED on it: the masked form is a kernel pair of its own beside theirs.
-SDAR_STEP_SHA256 = "7bd02235d5f3d1f9f3a62eb32dae0df5c525b5947d9d9305d48346f57c0a5352"
+SDAR_STEP_SHA256 = "c614434c85efc16f1043508f08916f32c1a86ecffc1c6dc4e22cd4cb77bb10fd"
 
 
 def test_the_ninth_blocks_lowered_step_is_pinned():

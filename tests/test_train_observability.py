@@ -519,6 +519,16 @@ def test_a_program_inside_a_startup_span_is_its_child(monkeypatch):
 # -- B. the start-up spans -------------------------------------------------------
 
 
+def test_the_block_diffusion_trainers_init_span_says_its_heads_go_two_a_product():
+    """Since PR 63 the block-masked kernel pair takes a key-value head's query heads two a product as the plain pair does: the ninth block's
+    learner (4 heads over 1: two pairs a layer) reads 1.0 on its ``train_init`` span where it read 0.0."""
+    started = time.monotonic()
+    trainer, _ = make("sdar")
+    trainer.init(1)
+    span = [s for s in RECORDER.spans() if s["t"] >= started and s["stage"] == "train_init"][-1]
+    assert span["trainer"] == "az" and span["attention_heads_paired"] == 1.0
+
+
 @pytest.mark.parametrize("kind", ["nnue", "az"])
 def test_startup_spans_once_a_trainer_with_telemetry_disabled(kind):
     assert not telemetry.enabled()

@@ -164,14 +164,25 @@ def test_a_mixed_plan_is_the_one_mixers_plan_where_every_layer_names_the_same():
 
 # -- the counter PR 61 brings: how much of a plan's attention the kernel pair takes two query heads a product -------------------------------
 
-#: by the shapes alone: the first block a group of 1, the third's layers and the sixth's one attention layer latent, the ninth's block-masked
-PAIRED = {"llada": 0.0, "afmoe": 1.0, "mla": 0.0, "hybrid": 1.0, "cca": 1.0, "kda": 0.0, "gdn": 1.0, "mellum": 1.0, "sdar": 0.0}
+#: by the shapes alone: the first block a group of 1, the third's layers and the sixth's one attention layer latent; the ninth's block-masked
+#: layers pair by their group as the plain ones do (since PR 63: the tiny plan 4 over 1)
+PAIRED = {"llada": 0.0, "afmoe": 1.0, "mla": 0.0, "hybrid": 1.0, "cca": 1.0, "kda": 0.0, "gdn": 1.0, "mellum": 1.0, "sdar": 1.0}
 
 
 @pytest.mark.parametrize("block", PAIRED)
 def test_the_share_of_a_plans_query_heads_that_go_two_a_product_follows_the_shapes(block):
     assert set(PAIRED) == set(BLOCKS)
     assert trunk.attention_heads_paired(BLOCKS[block][0]) == PAIRED[block]
+
+
+@pytest.mark.parametrize("heads,kv_heads,share", [(32, 4, 1.0), (6, 2, 4 / 6), (16, 16, 0.0)], ids=["published_32_over_4", "odd_group", "group_of_1"])
+def test_a_block_masked_plan_pairs_by_its_group_as_a_plain_one_does(heads, kv_heads, share):
+    """``block_length`` moves nothing in the count: the blocks pair's bodies take a group's heads by the same ``_pairs``."""
+    import dataclasses
+
+    masked = dataclasses.replace(BLOCKS["sdar"][0], heads=heads, kv_heads=kv_heads)
+    assert masked.block_length == 4 and trunk.attention_heads_paired(masked) == share
+    assert trunk.attention_heads_paired(dataclasses.replace(masked, block_length=0)) == share
 
 
 def test_an_odd_group_pairs_all_but_its_last_head_and_the_init_span_says_so():

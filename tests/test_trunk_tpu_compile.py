@@ -1264,18 +1264,24 @@ SDAR_BOARDS = 128  # sdar_trunk_train_b128
 
 
 def test_the_masked_kernel_pair_compiles_at_published_widths_for_both_copies_and_for_the_clean_one(one_chip, compiled_for_tpu):
-    """``board_attention_blocks`` and its gradient at the cell's shape (128 boards of 128 rows, 8 query heads a key-value head of 128: ONE board
-    a grid step, so that a step's blocks are the plain pair's bytes) and at the served one (the clean copy alone, 64 rows, 2 boards a step):
-    Mosaic takes the row slices of a copy, the 128 x 64 scores under a bias of -inf and the sums of dk, dv over both copies' queries; the mask
-    is an operand (``f32[192,64]``, ``f32[64,64]``), the tables are laid once a copy (``f32[128,128]``), and no scores are kept between the two."""
+    """``board_attention_blocks`` and its gradient at the cell's shape (128 boards of 128 rows, 8 query heads a key-value head of 128, the heads
+    two a product since PR 63: FOUR boards a grid step and a loop body, the fastest of PR 63's sweep on the chip; double-buffered the gradient's
+    blocks are 11.5 MiB of VMEM) and at the served one (the clean copy alone, 64 rows, 8 boards a step): Mosaic takes the row slices of a copy,
+    a pair's queries stacked along the rows (256 rows normed and turned a pass), the 128 x 128 scores under a bias of -inf and the sums of dk, dv
+    over both copies' queries; the mask is an operand laid twice along a pair's queries (``f32[192,128]``, ``f32[64,128]``), the tables are laid
+    once a copy (``f32[128,128]``), and no scores are kept between the two."""
     import re
 
+    from fishnet_tpu.ops import board_attention as kernels
     from fishnet_tpu.ops.board_attention import board_attention
 
-    for streams, bias in ((2, "f32[192,64]"), (1, "f32[64,64]")):
+    assert (kernels._BLOCKS_PAIRED_BOARDS, kernels._BLOCKS_PAIRED_UNROLL) == (64, 64)  # (board, head, copy)s: 4 boards of 8 heads x 2 copies a step, and a body
+    for streams, bias, boards_a_step in ((2, "f32[192,128]", 4), (1, "f32[64,128]", 8)):
         rows = 64 * streams
         shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in (
             ((SDAR_BOARDS, rows, 4096), jnp.float32), ((SDAR_BOARDS, rows, 512), jnp.float32), ((SDAR_BOARDS, rows, 512), jnp.bfloat16), ((128,), jnp.float32), ((128,), jnp.float32))]
+        grid, group, *_ = kernels._stream_blocks(shapes[0], shapes[1], 128, streams)
+        assert (grid, group) == ((SDAR_BOARDS // boards_a_step, 4), 8) and kernels._blocks_unroll(False, kernels._UNROLL_GRAD, group, streams) == boards_a_step
         loss = lambda q, k, v, g_q, g_k: jnp.sum(jnp.square(board_attention(q, k, v, g_q, g_k, 1e6, 1e-6, False, block_length=4, streams=streams).astype(jnp.float32)))
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*shapes).compile().as_text()
         calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -1316,7 +1322,7 @@ def test_the_ninth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     assert sum("board_attention_blocks_grad" in line.split(" = ")[0] for line in calls) == 5
     for line in calls:  # both copies of a board side by side: 128 rows
         shapes = re.findall(r"(?:f32|bf16)\[[\d,]*\]", line.split("custom-call(")[1])
-        assert shapes[:3] == ["f32[128,128,4096]", "f32[128,128,512]", "bf16[128,128,512]"] and shapes[5:8] == ["f32[128,128]", "f32[128,128]", "f32[192,64]"], shapes
+        assert shapes[:3] == ["f32[128,128,4096]", "f32[128,128,512]", "bf16[128,128,512]"] and shapes[5:8] == ["f32[128,128]", "f32[128,128]", "f32[192,128]"], shapes  # the mask laid twice along a pair's queries
     for phase in ("jvp(forward)", "transpose(jvp(forward))"):
         assert all(f"{phase}/layer0{i}.{part}/" in text for i in range(5) for part in ("attention", "router", "dispatch", "experts", "combine"))
         assert f"{phase}/denoise/" in text and f"{phase}/embed/" in text and ".shared/" not in text and ".dense/" not in text

@@ -1,6 +1,6 @@
-"""The attention core's plain kernel pair (``fishnet_tpu/ops/board_attention.py``) timed ALONE on a cell's shape.
+"""The attention core's plain kernel pair, or its block-masked one (``fishnet_tpu/ops/board_attention.py``), timed ALONE on a cell's shape.
 
-    python3 tools/attention_alone.py [--boards 256 --heads 32 --kv-heads 4 --d 128] [--calls 10] [--seed 0]
+    python3 tools/attention_alone.py [--boards 256 --heads 32 --kv-heads 4 --d 128] [--block-length L --streams S] [--calls 10] [--seed 0]
                                      [--against other/tree/fishnet_tpu/ops/board_attention.py]
 
 prints one JSON line: the device, the shape, and the host clock's median, least
@@ -10,11 +10,16 @@ the gradient kernel by itself::
 
     forward_ms                ``board_attention``: the normed form, both gains, RoPE over all of a head
     forward_and_gradient_ms   ``value_and_grad`` of a weighted sum of ``mixed``: both kernels
-    gradient_ms               ``board_attention_grad`` alone, on the inputs (the pair keeps nothing else)
+    gradient_ms               the gradient kernel alone, on the inputs (the pair keeps nothing else)
 
 The defaults are ``mellum_trunk_train_b256``'s shape (and
 ``afmoe_trunk_train_b256``'s): 256 boards x 32 query heads over 4 key-value
 heads of 128 columns, q and k float32, v bfloat16, from ``--seed``.
+``--block-length L`` times ``board_attention_blocks`` and its gradient instead,
+under the block mask of ``L`` squares with ``--streams`` copies of a board
+along the rows (1: the clean copy alone, what is served; 2: block-diffusion
+training); ``sdar_trunk_train_b128``'s shape is ``--boards 128 --heads 32
+--kv-heads 4 --d 128 --block-length 4 --streams 2``.
 ``--against`` loads ANOTHER tree's ``board_attention.py`` by its path, times it
 the same way in the same process (``against``) and gives the largest
 difference of ``mixed`` and the five gradients between the two trees
@@ -47,10 +52,10 @@ OUTPUTS = ("mixed", "dq", "dk", "dv", "dg_q", "dg_k")
 THETA, EPS = 10000.0, 1e-6
 
 
-def operands(boards: int, heads: int, kv_heads: int, d: int, seed: int):
-    """q, k float32 and v bfloat16 as the projections write them, and the two gains near 1."""
+def operands(boards: int, heads: int, kv_heads: int, d: int, seed: int, streams: int = 1):
+    """q, k float32 and v bfloat16 as the projections write them (``streams`` copies of a board along the rows), and the two gains near 1."""
     rng = np.random.default_rng([seed, heads, kv_heads, d])
-    normal = lambda width, dtype, scale=1.0: jnp.asarray(scale * rng.standard_normal((boards, 64, width), np.float32), dtype)
+    normal = lambda width, dtype, scale=1.0: jnp.asarray(scale * rng.standard_normal((boards, 64 * streams, width), np.float32), dtype)
     gain = lambda: jnp.asarray(1.0 + 0.1 * rng.standard_normal(d), jnp.float32)
     return normal(heads * d, jnp.float32, 1.5), normal(kv_heads * d, jnp.float32, 1.5), normal(kv_heads * d, jnp.bfloat16), gain(), gain()
 
@@ -63,9 +68,10 @@ def load(path: Path):
     return module
 
 
-def measure(module, ops, weight, interpret: bool, calls: int):
-    """The three programs of ``module``'s pair on ``ops``: what each made (float32 on the host) and its times."""
-    core = lambda *a: module.board_attention(*a, THETA, EPS, interpret)
+def measure(module, ops, weight, interpret: bool, calls: int, masked: dict):
+    """The three programs of ``module``'s pair on ``ops`` (``masked``: the block-masked pair's ``block_length`` and ``streams``, or nothing):
+    what each made (float32 on the host) and its times."""
+    core = lambda *a: module.board_attention(*a, THETA, EPS, interpret, **masked)
     forward = jax.jit(core)
     both = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(core(*a).astype(jnp.float32) * weight), argnums=tuple(range(5))))
     gradient = jax.jit(lambda do, *a: jax.vjp(core, *a)[1](do))  # the forward it also names has no reader: the gradient kernel alone is left
@@ -89,6 +95,8 @@ def main(argv=None) -> int:
     parser.add_argument("--heads", type=int, default=32, help="query heads")
     parser.add_argument("--kv-heads", type=int, default=4, help="key-value heads: a grid step is one of them and its heads / kv-heads query heads")
     parser.add_argument("--d", type=int, default=128, help="columns a head")
+    parser.add_argument("--block-length", type=int, help="squares a block: the block-masked pair (board_attention_blocks, _grad) in the plain pair's place")
+    parser.add_argument("--streams", type=int, default=1, help="copies of a board along the rows under --block-length: 1 the clean one alone, 2 with its noised one")
     parser.add_argument("--calls", type=int, default=10)
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--against", type=Path, help="another tree's board_attention.py: timed the same way, and its six outputs compared")
@@ -96,17 +104,20 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(REPO))
     from fishnet_tpu.ops import board_attention as module
 
+    if args.streams != 1 and args.block_length is None:
+        parser.error("--streams is the block-masked pair's: give --block-length")
+    masked = {} if args.block_length is None else {"block_length": args.block_length, "streams": args.streams}
     interpret = jax.default_backend() != "tpu"
-    ops = operands(args.boards, args.heads, args.kv_heads, args.d, args.seed)
+    ops = operands(args.boards, args.heads, args.kv_heads, args.d, args.seed, args.streams)
     weight = jnp.asarray(np.random.default_rng(args.seed).standard_normal(ops[0].shape, np.float32))
     out = {"device": jax.devices()[0].device_kind, "interpret": interpret, "boards": args.boards, "heads": args.heads, "kv_heads": args.kv_heads,
-           "d": args.d, "calls": args.calls, "seed": args.seed}
-    values, times = measure(module, ops, weight, interpret, args.calls)
+           "d": args.d, **masked, "calls": args.calls, "seed": args.seed}
+    values, times = measure(module, ops, weight, interpret, args.calls, masked)
     out.update(times)
     out["finite"] = bool(all(np.isfinite(value).all() for value in values.values()))
     out["scale"] = {name: float(np.abs(value).max()) for name, value in values.items()}
     if args.against:
-        other_values, out["against"] = measure(load(args.against), ops, weight, interpret, args.calls)
+        other_values, out["against"] = measure(load(args.against), ops, weight, interpret, args.calls, masked)
         out["largest_difference"] = {name: float(np.abs(value - other_values[name]).max()) for name, value in values.items()}
     print(json.dumps(out))
     return 0
