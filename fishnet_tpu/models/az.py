@@ -36,6 +36,7 @@ from fishnet_tpu.models.trunk import (
     trunk_buffer_shapes,
     trunk_checkpoint,
     trunk_config_from_params,
+    trunk_forward,
     trunk_forward_counted,
 )
 
@@ -102,8 +103,12 @@ def az_forward(params: Params, planes: jax.Array, cfg: NetConfig = AzConfig()):
     The configuration's type selects the network: an ``AzConfig`` the
     conv tower below, a ``TrunkConfig`` the sparse-expert trunk
     (``models/trunk.py``). Compute runs in bfloat16; logits/value are
-    returned in float32.
+    returned in float32. This is what is SERVED: a looped trunk's answer
+    is a position's pass by its exit rule (``trunk_forward``), where the
+    counted forward below returns every pass's.
     """
+    if isinstance(cfg, TrunkConfig):
+        return trunk_forward(params, planes, cfg)
     return az_forward_counted(params, planes, cfg)[:2]
 
 
@@ -111,7 +116,9 @@ def az_forward_counted(params: Params, planes: jax.Array, cfg: NetConfig = AzCon
     """``az_forward`` and the network's counters for the training step's
     metrics: none for the tower, the routing counters for the trunk. Told
     a batch's ``square_masked`` (a block-diffusion trunk's training
-    forward) the denoiser's logits follow the counters."""
+    forward) the denoiser's logits follow the counters; a looped trunk's
+    heads are every pass's, ``[T, B, ..]``, and its exit gates' logits
+    ``[T, B]`` follow the counters."""
     if isinstance(cfg, TrunkConfig):
         return trunk_forward_counted(params, planes, cfg, square_masked)
     if square_masked is not None:
@@ -155,16 +162,19 @@ def az_config_from_params(params: Params) -> NetConfig:
     a non-default config (--az-net-file) reconstructs the right config
     instead of crashing shape-mismatched inside the jitted forward. A
     trunk checkpoint is told from a tower's by its router (one product,
-    or the fifth block's MLP).
+    or the fifth block's MLP: the nine routed trunks' files, as always)
+    or, a trunk of dense layers alone having none, by the embedding and
+    the final norm every trunk has and no tower does; an error names the
+    tensor that told.
     """
-    if "router_w" in params or "router_down" in params:
+    if "router_w" in params or "router_down" in params or ("embed_w" in params and "final_norm" in params):
         return trunk_config_from_params(params)
     required = ("stem_b", "policy_b", "value_fc1_b")
     missing = [k for k in required if k not in params]
     if missing:
         raise ValueError(
-            f"not an AZ checkpoint: missing parameter(s) {missing}; "
-            f"got keys {sorted(params)[:8]}..."
+            f"not an AZ checkpoint (read as a tower's: it has no router_w or router_down, and not both embed_w and final_norm, a trunk's): "
+            f"missing parameter(s) {missing}; got keys {sorted(params)[:8]}..."
         )
     blocks = 0
     while f"res{blocks}_w1" in params:

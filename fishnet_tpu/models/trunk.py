@@ -4,7 +4,7 @@ The second network family behind ``az_forward``: where ``models/az.py``
 runs a convolution tower over the 8x8x19 planes, this runs a
 bidirectional transformer over 64 tokens (one a square, 19 features
 each) whose feed-forward is a routed mixture of experts, and ends in the
-tower's own policy and value heads. ``TrunkConfig`` describes nine
+tower's own policy and value heads. ``TrunkConfig`` describes ten
 published blocks as one code path at different values; no attention but
 the ninth block's (block diffusion: its mask is what it trains under) has a
 causal mask here, and a board is far shorter than any's window, so running
@@ -396,6 +396,46 @@ turned and read once for the clean and the noised queries of its group, the
 the softmax's maximum and sums, dk and dv of the clean copy summed over both
 copies' queries before they are written.
 
+The tenth block is Ouro-2.6B's (ByteDance, config.json, ``model_type``
+ouro: hidden 2048, 48 identical layers all ``full_attention``, 16 query heads
+over 16 key-value heads of 128, RoPE theta 1e6 without scaling, a SiLU-gated
+feed-forward of 5632, RMSNorm eps 1e-6, no bias, no window, no router:
+EVERY feed-forward is dense), and what it adds is no layer but how the stack
+is RUN: ``total_ut_steps`` 4 times over the same weights, an exit at every
+pass (arXiv:2510.25741, "Scaling Latent Reasoning via Looped Language
+Models"); what its config.json does not say is listed under ``assumed`` in
+``benchmark/configs/ouro-2.6b-trunk-train.json``. ``TrunkConfig.loop_steps``
+T, ``exit_threshold`` the published ``early_exit_threshold``::
+
+    embed     h_0 = t W_in + b_in                                       (no scale)
+    layer l   a = h + N2_l(Attn_l(N1_l(h)));   h' = a + N4_l(FFN_l(N3_l(a)))       four norms a layer (``post_norms``), plain gains; the weights
+                                                                        of layer l are the same at every pass
+    Attn      the first block's at a group of ONE without qk-norm: q, k, v = n W_q, n W_k, n W_v, rotate-half RoPE over all of head_dim,
+              softmax(q k^T / sqrt(head_dim)) v within a board, then W_o
+    FFN       (silu(n W_g) * (n W_u)) W_d at ``dense_width``            (``dense_layers == layers``: a trunk WITHOUT a routed layer)
+    loop      h_t = (Layer_{L-1} o ... o Layer_0)(h_{t-1}),  t = 1..T
+    exits     f_t = N_final(h_t) (one gain for all t);  (policy_t, value_t) = heads(f_t) (one set of head weights)
+              g_t = mean over a board's 64 squares of (f_t w_g + b_g);  lambda_t = sigmoid(g_t)      ``exit_gate_w`` [hidden, 1], ``exit_gate_b`` [1]:
+                                                                        ONE gate a board (the published gate is a token's; the loss it weighs
+                                                                        here is a position's)
+    exit distribution   p_1 = lambda_1;  p_t = lambda_t prod_{j<t} (1 - lambda_j), 1 < t < T;  p_T = prod_{j<T} (1 - lambda_j)      (``exit_log_distribution``)
+    loss      mean over boards of [ sum_t p_t l_t - exit_entropy_weight H(p) ],  l_t = CE(policy_t) + value_weight (value_t - z)^2      (``train/az_trainer.py _loss``)
+    served    (``trunk_forward``) a position's output is that of the first pass t with p_1 + ... + p_t >= exit_threshold, the last pass
+              where none is (at the published 1.0: the last); all T passes are computed for a batch and a position's is selected
+
+Mechanism, the tenth block: no kind, no tensor of a layer and no kernel of its
+own. ``trunk_forward_counted`` walks the plan ``loop_steps`` times, a Python
+loop whose passes read the SAME slices of the stacked tensors, so a weight's
+gradient is autodiff's sum over its T uses and a scope ``layerNN.attention``
+names a layer's T passes (a ``lax.scan`` over the passes would put ``while``
+where the benchmark's scope table keeps a layer's name: PERF.md section 6, PR
+64). The T streams are stacked ``[T, tokens, hidden]`` and the final norm, the
+two heads and the gate run ONCE on all of them (one product on T x boards, not
+T products); the forward returns heads ``[T, B, ..]`` and, after the counters,
+the gate's logits ``[T, B]``. The gate is a float32 sum over the hidden columns
+(no product), the distribution is made in log space (``log_sigmoid``), so no
+``p_t`` is ever a product that underflows before its logarithm is taken.
+
 **Held heads.** A mixer's head count (``heads``, ``kda_heads``) is the
 heads HELD here, as ``held_experts`` is the experts': both mixers are sums
 over heads (a KDA head's state, norm and gate are its own; the latent is
@@ -619,6 +659,13 @@ class TrunkConfig:
     # it is trained (``trunk_forward_counted`` told ``square_masked``), and the trunk has a mask embedding and a third head, the denoiser. 0: the
     # eight blocks above, no mask.
     block_length: int = 0
+    # What the tenth block adds (module docstring): the stack of layers run ``loop_steps`` times over the same weights (named after the published
+    # ``total_ut_steps``), the final norm, the heads and an exit gate (``exit_gate_w``, ``exit_gate_b``) reading the stream after EVERY pass;
+    # ``exit_threshold`` (the published ``early_exit_threshold``): what is served is a position's first pass at which the exit distribution's
+    # cumulative sum reaches it. 1: the nine blocks above, one pass, no gate, and the threshold is not read. Its feed-forwards are all dense
+    # (``dense_layers == layers``: no router, no expert), which any trunk of block layers may be.
+    loop_steps: int = 1
+    exit_threshold: float = 1.0
 
     def __post_init__(self) -> None:
         first, count = self.held
@@ -658,8 +705,8 @@ class TrunkConfig:
             f"router_score {self.router_score!r} is neither softmax nor sigmoid": self.router_score not in ("softmax", "sigmoid"),
             f"held_experts {self.held_experts} is not a range of the {self.experts} experts":
                 not (0 <= first and 1 <= count and first + count <= self.experts),
-            f"{self.dense_layers} dense layers of {self.layers} leave no routed layer, or have no width":
-                not 0 <= self.dense_layers < self.layers or (self.dense_layers > 0) != (self.dense_width > 0),
+            f"{self.dense_layers} dense layers are not 0 to the {self.layers} layers, or have no width":
+                not 0 <= self.dense_layers <= self.layers or (self.dense_layers > 0) != (self.dense_width > 0),
             f"nope_layers {self.nope_layers} are not layers": any(not 0 <= i < self.layers for i in self.nope_layers),
             f"cca {self.cca} is not two kernel sizes of 1 to {SQUARES}": cca and not (len(self.cca) == 2 and all(1 <= t <= SQUARES for t in self.cca)),
             "compressed convolutional attention wants kv_heads (its keys and values live in kv_heads x head_dim columns) and an even "
@@ -702,6 +749,10 @@ class TrunkConfig:
             "every layer under one table (no rotary_dim, nope_layers or full_attention_layers)":
                 self.block_length != 0 and (not 0 < self.block_length <= SQUARES or SQUARES % self.block_length != 0 or latent or cca or bool(pattern)
                                             or bool(mixers) or not self.qk_norm or self.rotary_dim is not None or bool(self.nope_layers) or bool(full)),
+            f"loop_steps {self.loop_steps} is under 1 (the stack runs at least once), or the loop stands beside what it is not computed under: a pattern "
+            "(one sublayer a layer) or block_length (two streams under a mask)": self.loop_steps < 1 or self.loop_steps > 1 and (bool(pattern) or self.block_length != 0),
+            f"exit_threshold {self.exit_threshold} is not over 0 and at most 1 (a cumulative probability), or stands beside no loop (loop_steps 1 has no "
+            "exit to choose)": not 0.0 < self.exit_threshold <= 1.0 or self.exit_threshold != 1.0 and self.loop_steps == 1,
         }
         if any(wrong.values()):
             raise ValueError("; ".join(k for k, v in wrong.items() if v))
@@ -851,6 +902,7 @@ def trunk_param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
         "value_fc1_w": (4 * SQUARES, cfg.value_hidden), "value_fc1_b": (cfg.value_hidden,),
         "value_fc2_w": (cfg.value_hidden, 1), "value_fc2_b": (1,),
         **({"denoise_w": (h, SQUARE_CLASSES), "denoise_b": (SQUARE_CLASSES,)} if cfg.block_length else {}),
+        **({"exit_gate_w": (h, 1), "exit_gate_b": (1,)} if cfg.loop_steps > 1 else {}),
     })
     return {**{name: shape for name, shape in shapes.items() if name not in _LATE}, **{name: shapes[name] for name in _LATE if name in shapes}}
 
@@ -1734,8 +1786,38 @@ def _experts(n2: jax.Array, p: Params, cfg: TrunkConfig, layer: str) -> Tuple[ja
 
 
 def trunk_forward(params: Params, planes: jax.Array, cfg: TrunkConfig = TrunkConfig()):
-    """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]), float32: what is served (the ninth block: the clean stream alone)."""
-    return trunk_forward_counted(params, planes, cfg)[:2]
+    """planes [B, 8, 8, 19] -> (policy_logits [B, 4672], value [B]), float32: what is served (the ninth block: the clean stream alone; the tenth: a
+    position's pass by the exit rule, ``served_pass``, out of all ``loop_steps`` computed for the batch)."""
+    logits, value, _, *gates = trunk_forward_counted(params, planes, cfg)
+    if cfg.loop_steps == 1:
+        return logits, value
+    chosen = served_pass(gates[0], cfg.exit_threshold)
+    return jnp.take_along_axis(logits, chosen[None, :, None], axis=0)[0], jnp.take_along_axis(value, chosen[None, :], axis=0)[0]
+
+
+def exit_log_distribution(gate_logits: jax.Array) -> jax.Array:
+    """A looped trunk's exit distribution from its gates' logits ``[T, B]``
+    (pass, board), as logarithms ``[T, B]`` float32: ``p_1 = lambda_1``,
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``, and the LAST pass takes
+    what is left, ``p_T = prod_{j<T} (1 - lambda_j)`` (its own gate is not
+    read: the T probabilities sum to 1 exactly), ``lambda = sigmoid(g)``.
+    Made from ``log_sigmoid``, so a gate that saturates leaves a large
+    negative logarithm and never the logarithm of a product that
+    underflowed."""
+    g = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g[:-1]), axis=0)  # log prod_{j<=t} (1 - lambda_j), t = 1 .. T - 1
+    before = jnp.concatenate([jnp.zeros_like(g[:1]), stay[:-1]], axis=0)  # log prod_{j<t} (1 - lambda_j), t = 1 .. T - 1
+    return jnp.concatenate([jax.nn.log_sigmoid(g[:-1]) + before, stay[-1:]], axis=0)
+
+
+def served_pass(gate_logits: jax.Array, threshold: float) -> jax.Array:
+    """The pass each board is served from, int32 ``[B]`` in ``[0, T)``: the
+    first at which the exit distribution's cumulative sum reaches
+    ``threshold`` (the published ``early_exit_threshold``), the last where
+    none does: in float32 the T probabilities may sum to a last bit under
+    1, and at the published 1.0 that is the last pass too."""
+    reached = jnp.cumsum(jnp.exp(exit_log_distribution(gate_logits)), axis=0) >= threshold
+    return jnp.where(jnp.any(reached, axis=0), jnp.argmax(reached, axis=0), gate_logits.shape[0] - 1).astype(jnp.int32)
 
 
 def _routed_layer(x: jax.Array, p: Params, cfg: TrunkConfig, sublayer: Sublayer) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -1815,6 +1897,18 @@ def _two_streams(planes: jax.Array, square_masked: jax.Array) -> Tuple[jax.Array
     return both.reshape(-1, INPUT_PLANES), marked.reshape(-1, 1)
 
 
+def _one_pass(x: jax.Array, params: Params, cfg: TrunkConfig, plan: Tuple[Sublayer, ...]):
+    """The plan walked once over the stream ``x``: the stream after its last sublayer, and what each sublayer counted."""
+    counters = []
+    for sublayer, p in _sliced(params, plan):
+        run, scope = _KINDS[sublayer.kind]
+        branch, counted = run(x, p, cfg, sublayer)
+        with jax.named_scope(f"{sublayer.layer}.{scope}"):
+            x = x + (_rms_norm(branch, p[sublayer.post_norm], cfg.rms_eps) if sublayer.post_norm else branch)
+        counters.append(counted)
+    return x, counters
+
+
 def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig, square_masked: Optional[jax.Array] = None):
     """``trunk_forward`` and the counters of the step's metrics: what
     the sublayers counted, folded over the sublayers as ``_FOLDS`` says
@@ -1823,47 +1917,63 @@ def trunk_forward_counted(params: Params, planes: jax.Array, cfg: TrunkConfig, s
     block's training forward) it carries a clean and a noised copy of
     every board through the layers, 128 tokens a board, the heads read
     the clean copy and a fourth result follows the counters: the
-    denoiser's logits ``[B, 64, 13]`` float32 off the noised copy."""
+    denoiser's logits ``[B, 64, 13]`` float32 off the noised copy. A
+    looped trunk (``loop_steps`` T over 1: the tenth block) walks the
+    plan T times over the same weights and exits after every pass: the
+    heads come back ``[T, B, ..]``, a sublayer's counters fold over its
+    passes too, and the fourth result is the exit gates' logits ``[T,
+    B]`` float32 (``exit_log_distribution`` makes the distribution,
+    ``trunk_forward`` serves by it)."""
     b, params = planes.shape[0], centred_gains(params, cfg)
     streams = 1 if square_masked is None else 2
     if streams == 2 and not cfg.block_length:
         raise ValueError("square_masked is a block-diffusion trunk's (block_length): this one has no mask embedding and no denoiser")
     # Scope names are a contract (doc/observability.md "Training and compilation"): one scope a part, the layer in its name,
-    # because the benchmark's scope table keeps two levels of a path (phase, then this).
+    # because the benchmark's scope table keeps two levels of a path (phase, then this): a pass of a loop is no level of it.
     with jax.named_scope("embed"):
         tokens, marked = (planes.reshape(b * SQUARES, INPUT_PLANES), None) if streams == 1 else _two_streams(planes, square_masked)
         x = _matmul(tokens, params["embed_w"]) + params["embed_b"]
         if marked is not None:  # both copies went through the one embedding; the mask embedding on the noised copy's masked squares
             x = x + marked * params["mask_embed"]
         x = _row_major(x * cfg.embed_scale if cfg.embed_scale != 1.0 else x)
-    counters = []
-    for sublayer, p in _sliced(params, trunk_plan(cfg, streams)):
-        run, scope = _KINDS[sublayer.kind]
-        branch, counted = run(x, p, cfg, sublayer)
-        with jax.named_scope(f"{sublayer.layer}.{scope}"):
-            x = x + (_rms_norm(branch, p[sublayer.post_norm], cfg.rms_eps) if sublayer.post_norm else branch)
-        counters.append(counted)
+    plan, passes, counters = trunk_plan(cfg, streams), [], []
+    for _ in range(cfg.loop_steps):  # the same plan over the same slices: a weight's gradient is the sum over its passes
+        x, counted = _one_pass(x, params, cfg, plan)
+        passes.append(x)
+        counters += counted
+    if cfg.loop_steps > 1:
+        x = jnp.stack(passes)  # [T, tokens, hidden]: one final norm, one call of the heads, one gate for all the exits
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     if streams == 2:
         x, noised = (x.reshape(b, 2, SQUARES, cfg.hidden)[:, copy] for copy in range(2))
-    features = x.reshape(b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
-    slots = jnp.stack([c["expert_slots"] for c in counters if "expert_slots" in c])
+    features = x.reshape(cfg.loop_steps * b, 8, 8, cfg.hidden).astype(jnp.bfloat16)
+    routed = [c["expert_slots"] for c in counters if "expert_slots" in c]  # none: a trunk of dense layers alone
+    slots = jnp.stack(routed) if routed else None
+    if slots is not None and cfg.loop_steps > 1:  # an expert's slots of a step: its layer's passes summed
+        slots = jnp.sum(slots.reshape(cfg.loop_steps, -1, cfg.experts), axis=0)
     heads = policy_value_heads(params, features)
     folded = {}
-    for name, fold in _FOLDS.items():  # the whole trunk's own three are made where the result's key order has them
+    for name, fold in _FOLDS.items():  # the whole trunk's own are made where the result's key order has them
         values = [c[name] for c in counters if name in c]
         if fold and values:
             folded[name] = fold(jnp.stack(values))
-        elif name == "expert_slots":
+        elif name == "expert_slots" and slots is not None:
             folded[name] = slots
-        elif name == "held_slots" and cfg.held_experts:
+        elif name == "held_slots" and cfg.held_experts and slots is not None:
             folded[name] = jnp.sum(slots[:, cfg.held[0]:sum(cfg.held)])
         elif name == "expert_bias_abs_max" and "expert_bias" in params:
             folded[name] = jnp.max(jnp.abs(params["expert_bias"]))
+        elif name == "loop_update_rms" and cfg.loop_steps > 1:
+            before, after = jax.lax.stop_gradient((passes[-2], passes[-1]))
+            folded[name] = jnp.sqrt(jnp.mean(jnp.square(after - before)) / jnp.mean(jnp.square(before)))
     if streams == 2:
         with jax.named_scope("denoise"):
             return (*heads, folded, _matmul(noised.reshape(b * SQUARES, cfg.hidden), params["denoise_w"]).reshape(b, SQUARES, -1) + params["denoise_b"])
+    if cfg.loop_steps > 1:
+        with jax.named_scope("exit_gate"):  # float32, a sum over the hidden columns and a mean over a board's squares: no product
+            gate = jnp.mean(jnp.sum(x * params["exit_gate_w"][:, 0], axis=-1).reshape(cfg.loop_steps, b, SQUARES), axis=-1) + params["exit_gate_b"]
+        return (*(head.reshape(cfg.loop_steps, b, *head.shape[1:]) for head in heads), folded, gate)
     return (*heads, folded)
 
 
@@ -1888,6 +1998,8 @@ _FOLDS = {
     "gdn_state_kept": jnp.mean,  # the mean decay ``exp(g)`` a square, over tokens, value heads and GDN mixers: 1 a state that never forgets, 0 one that holds nothing
     "gdn_beta": jnp.mean,  # the mean ``beta`` of the GDN mixers, as ``kda_beta``
     "shared_gate_mean": jnp.mean,  # the mean of the shared expert's sigmoid gate over tokens and layers: 0 a shared expert switched off, 1 one that is never gated
+    "loop_update_rms": None,  # a looped trunk's: the root mean square of what the LAST pass changed, ``h_T - h_{T-1}``, over that of ``h_{T-1}``: near 0 a loop that
+    # has stopped moving (its last pass buys nothing), over 1 one that runs away
 }
 
 
@@ -1909,8 +2021,10 @@ _HPARAMS = ("experts_per_token", "rope_theta", "rms_eps", "embed_scale", "route_
             "sigmoid", "route_norm", "first_held", "nope_mask", "head_dim", "mamba_groups", "rotary_dim", "zero_centered")
 #: What a file with ``full_attention_layers`` carries after them (no other file: one of the seven older blocks is what it was).
 _ROPE_HPARAMS = ("full_mask", "yarn", "rope_factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor")
-#: What a file with ``block_length`` carries after both (no other file).
+#: What a file with ``block_length`` carries after both (no other file but a looped trunk's, where it reads 0).
 _BLOCK_HPARAMS = ("block_length",)
+#: What a looped trunk's file (``loop_steps`` over 1) carries after all three (no other file).
+_LOOP_HPARAMS = ("loop_steps", "exit_threshold")
 #: A pattern's checkpoint carries the pattern itself, its characters as bytes.
 PATTERN = "trunk_pattern"
 #: A checkpoint whose mixer is told by layer carries ``mixers``, each layer's kind as its place in ``_MIXERS``.
@@ -1931,12 +2045,16 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
     is what it was), the eighth's: those layers as a bit mask,
     ``rope_type`` yarn 0 or 1, and YaRN's five numbers; after those, in a
     file with ``block_length`` alone, the ninth's block length (its mask
-    embedding and denoiser are tensors). A pattern's file carries the pattern
+    embedding and denoiser are tensors); after that, in a looped trunk's
+    file alone, the tenth's ``loop_steps`` and ``exit_threshold`` (its gate
+    is two tensors; that it has no routed layer is the absence of a
+    router's). A pattern's file carries the pattern
     too (``trunk_pattern``, its characters as bytes), one whose mixer is
     told by layer its ``mixers`` (``trunk_mixers``).
     ``recompute_experts`` is the trainer's and in no file."""
     arrays = {k: np.asarray(v) for k, v in params.items()}
-    later = (_ROPE_HPARAMS + _BLOCK_HPARAMS) if cfg.block_length else _ROPE_HPARAMS if cfg.full_attention_layers else ()
+    later = (_ROPE_HPARAMS + _BLOCK_HPARAMS + _LOOP_HPARAMS) if cfg.loop_steps > 1 else (_ROPE_HPARAMS + _BLOCK_HPARAMS) if cfg.block_length else \
+        _ROPE_HPARAMS if cfg.full_attention_layers else ()
     arrays[HPARAMS] = _hparams(cfg)[:len(_HPARAMS) + len(later)]
     if cfg.pattern:
         arrays[PATTERN] = np.frombuffer(cfg.pattern.encode("ascii"), np.uint8)
@@ -1946,14 +2064,14 @@ def trunk_checkpoint(params: Params, cfg: TrunkConfig) -> Dict[str, np.ndarray]:
 
 
 def _hparams(cfg: TrunkConfig) -> np.ndarray:
-    """Every value ``_HPARAMS``, ``_ROPE_HPARAMS`` and ``_BLOCK_HPARAMS`` name, of ``cfg``."""
+    """Every value ``_HPARAMS``, ``_ROPE_HPARAMS``, ``_BLOCK_HPARAMS`` and ``_LOOP_HPARAMS`` name, of ``cfg``."""
     return np.asarray([
         cfg.experts_per_token, cfg.rope_theta, cfg.rms_eps, cfg.embed_scale, cfg.route_scale, cfg.balance_rate,
         cfg.sliding_window or 0, cfg.router_score == "sigmoid", cfg.route_norm,
         cfg.held_experts[0] if cfg.held_experts else -1, sum(1 << i for i in cfg.nope_layers),
         cfg.head_dim, cfg.mamba_groups, cfg.rotary_dim or 0, cfg.zero_centered_norms,
         sum(1 << i for i in cfg.full_attention_layers), cfg.rope_type == "yarn", cfg.rope_factor, cfg.original_max_position_embeddings,
-        cfg.beta_fast, cfg.beta_slow, cfg.attention_factor, cfg.block_length], np.float64)
+        cfg.beta_fast, cfg.beta_slow, cfg.attention_factor, cfg.block_length, cfg.loop_steps, cfg.exit_threshold], np.float64)
 
 
 def _attention_sizes(params: Params, shape, hp: Dict[str, float]) -> Dict[str, object]:  # the head width from the qk-norm's gains, or the file's
@@ -2012,19 +2130,29 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     by_layer = None if places is None else tuple(_MIXERS[i] for i in places)
     router = "router_w3" if "router_down" in params else "router_w"  # the MLP router's last matrix: its columns are the experts
     norm = "attn_norm" if pattern is None else "layer_norm"
-    required = (router, "experts_gate", "wq", "wo", norm, "experts_up") if pattern is None and by_layer is None else (router, "experts_up", norm)
-    not_one = lambda missing: f"not a trunk checkpoint: missing {missing}; got keys {sorted(params)[:8]}..."
+    # A trunk of dense layers alone (the tenth block) has no tensor of a routed layer at all: the file is read as one by that absence beside a dense
+    # feed-forward's, and a file with SOME of a routed layer's tensors is still a routed trunk's with the others missing.
+    routerless = "dense_up" in params and not any(name in params for name in _OWNS["routed"])
+    if routerless:
+        required, told_by = ("wq", "wo", norm, "dense_up"), "dense_up without a routed layer's tensors"
+    else:
+        required = (router, "experts_gate", "wq", "wo", norm, "experts_up") if pattern is None and by_layer is None else (router, "experts_up", norm)
+        told_by = next((f"its {name}" for name in ("router_w", "router_down", PATTERN, MIXERS, "embed_w") if name in params), "nothing of a trunk's")
+    not_one = lambda missing: f"not a trunk checkpoint (read as one by {told_by}): missing {missing}; got keys {sorted(params)[:8]}..."
     missing = [k for k in (*required, "value_fc1_b", "policy_b", HPARAMS) if k not in params]
     if missing:
         raise ValueError(not_one(missing))
     given = [float(v) for v in np.asarray(params[HPARAMS]).reshape(-1)]
     defaults = _hparams(TrunkConfig())
-    names = (*_HPARAMS, *_ROPE_HPARAMS, *_BLOCK_HPARAMS)
+    names = (*_HPARAMS, *_ROPE_HPARAMS, *_BLOCK_HPARAMS, *_LOOP_HPARAMS)
     if not 3 <= len(given) <= len(names):
         raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, not 3 to {len(names)}")
     hp = dict(zip(names, [*given, *defaults[len(given):]]))
-    if len(given) == len(names) and not hp["block_length"]:  # only a file with a block length carries one (``trunk_checkpoint``)
-        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, the last a block length of 0: a file without one has {len(names) - 1} at most")
+    with_block = len(names) - len(_LOOP_HPARAMS)
+    if len(given) == with_block and not hp["block_length"]:  # only a file with a block length or a loop carries one (``trunk_checkpoint``)
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, the last a block length of 0: a file without one has {with_block - 1} at most")
+    if len(given) > with_block and hp["loop_steps"] < 2:  # only a looped trunk's file carries the loop's numbers
+        raise ValueError(f"trunk checkpoint: {HPARAMS} has {len(given)} values, loop_steps {hp['loop_steps']:g} among them: a file without a loop has {with_block} at most")
     shape = lambda name: tuple(int(n) for n in np.shape(params[name]))
     width_of = lambda name: shape(name)[2] if name in params else 0
     if pattern is not None:  # the mixers its characters name
@@ -2041,25 +2169,26 @@ def trunk_config_from_params(params: Params) -> TrunkConfig:
     if missing:
         raise ValueError(f"trunk checkpoint: {what} without {missing}" if what else not_one(missing))
     sizes = {field: value for kind in mixers for field, value in _SIZES[kind][1](params, shape, hp).items()}
-    (routed, _, experts), (layers, hidden) = shape(router), shape(norm)
+    (routed, _, experts), (layers, hidden) = (0, 0, TrunkConfig.experts) if routerless else shape(router), shape(norm)
     layout = dict(pattern=pattern) if pattern is not None else dict(layers=layers, dense_layers=layers - routed, mixers=by_layer)
     return _checked(params, len(given), lambda: TrunkConfig(
         hidden=hidden, **layout, **sizes,
-        experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=shape("experts_up")[3],
+        experts=experts, experts_per_token=int(round(hp["experts_per_token"])), expert_width=TrunkConfig.expert_width if routerless else shape("experts_up")[3],
         rope_theta=hp["rope_theta"], rms_eps=hp["rms_eps"],
         value_hidden=shape("value_fc1_b")[0], policy_planes=shape("policy_b")[0],
         nope_layers=tuple(i for i in range(layers) if int(hp["nope_mask"]) >> i & 1),
         sliding_window=int(hp["sliding_window"]) or None,
         gated_attention="wgate" in params, post_norms="post_attn_norm" in params, embed_scale=hp["embed_scale"],
-        dense_width=width_of("dense_up"), shared_width=width_of("shared_up"), gated_ffn="experts_gate" in params,
+        dense_width=width_of("dense_up"), shared_width=width_of("shared_up"), gated_ffn=("dense_gate" if routerless else "experts_gate") in params,
         router_score="sigmoid" if hp["sigmoid"] else "softmax", route_norm=bool(hp["route_norm"]), route_scale=hp["route_scale"],
-        held_experts=None if hp["first_held"] < 0 else (int(hp["first_held"]), shape("experts_up")[1]),
+        held_experts=None if hp["first_held"] < 0 or routerless else (int(hp["first_held"]), shape("experts_up")[1]),
         balance_rate=hp["balance_rate"], rotary_dim=int(hp["rotary_dim"]) or None,
         shared_token_gate="shared_token_gate" in params, zero_centered_norms=bool(hp["zero_centered"]),
         full_attention_layers=tuple(i for i in range(layers) if int(hp["full_mask"]) >> i & 1), rope_type="yarn" if hp["yarn"] else "default",
         rope_factor=hp["rope_factor"], original_max_position_embeddings=int(hp["original_max_position_embeddings"]),
         beta_fast=hp["beta_fast"], beta_slow=hp["beta_slow"], attention_factor=hp["attention_factor"],
         router_hidden=shape("router_down")[2] if "router_down" in params else 0, block_length=int(hp["block_length"]),
+        loop_steps=int(round(hp["loop_steps"])), exit_threshold=hp["exit_threshold"],
     ))
 
 

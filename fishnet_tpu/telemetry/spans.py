@@ -47,7 +47,8 @@ named machinery actually runs):
   layout_held_bytes: the state's leaves the client holds off row-major,
   whose update its step runs in the client's layout; of a trunk also
   attention_heads_paired: the share of its attention layers' query heads
-  whose scores the kernel pair makes two a product)
+  whose scores the kernel pair makes two a product, loop_steps: the times
+  its plan is walked a forward pass, layer_passes: loop_steps x layers)
 * ``train_first_step`` — the first ``.step`` of a trainer instance:
   trace + lower + compile or cache load + dispatch of the step program
   (train/startup.py; the first five fields). Both trainer stages also

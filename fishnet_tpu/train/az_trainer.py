@@ -14,7 +14,10 @@ MCTS visit-count targets, MSE between the value head and the game
 outcome (or a teacher value), plus weight decay via the optimizer. A
 block-diffusion trunk (``TrunkConfig.block_length``) adds the denoising
 loss of its noised copy: a batch then carries its noise
-(``train/data.py block_noise``), and the step draws nothing.
+(``train/data.py block_noise``), and the step draws nothing. A looped
+trunk (``TrunkConfig.loop_steps`` over 1) exits after every pass, and its
+loss is the expected loss under the exit distribution its gates give, less
+an entropy term (``_expected_exit_terms``).
 
 The reference has no training subsystem at all (SURVEY.md §2: nets are
 opaque embedded blobs); training being first-class here is what lets the
@@ -33,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fishnet_tpu.models.az import AzConfig, NetConfig, az_checkpoint, az_forward_counted, init_az_buffers, init_az_params
 from fishnet_tpu.models.az_encoding import PIECE_PLANES
-from fishnet_tpu.models.trunk import KERNEL_OPERANDS, TrunkConfig, attention_heads_paired, balanced_bias
+from fishnet_tpu.models.trunk import KERNEL_OPERANDS, TrunkConfig, attention_heads_paired, balanced_bias, exit_log_distribution
 from fishnet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from fishnet_tpu.train import startup, step_metrics
 from fishnet_tpu.train.trainer import _constrain
@@ -127,6 +130,31 @@ def _denoise_terms(logits: jax.Array, batch: Batch, block_length: int) -> Dict[s
     return {"denoise_loss": jnp.mean(masked * cross_entropy / level), "masked_squares": jnp.sum(masked), "noise_level_mean": jnp.mean(batch["block_level"])}
 
 
+def _expected_exit_terms(policy: jax.Array, value: jax.Array, gate_logits: jax.Array, value_weight: float, entropy_weight: float) -> Dict[str, jax.Array]:
+    """A looped trunk's loss and its counters, float32, from every pass's
+    two terms a board (``policy``, ``value`` ``[T, B]``: the cross-entropy
+    and the squared error of that pass's heads) and the gates' logits ``[T,
+    B]``: with ``p`` the exit distribution a board
+    (``models/trunk.py exit_log_distribution``) and ``l_t = policy_t +
+    value_weight x value_t``, the loss is the mean over boards of ``sum_t
+    p_t l_t - entropy_weight x H(p)`` (the expected loss under the learned
+    exits, and an entropy term against a gate that collapses onto one
+    pass: arXiv:2510.25741, the first training stage). ``policy_loss`` and
+    ``value_loss`` are the expected terms; ``exit_step_mean`` is ``sum_t t
+    p_t`` in [1, T]; ``loss_first_pass`` and ``loss_last_pass`` are ``l_1``
+    and ``l_T``, what iterating bought."""
+    log_p = exit_log_distribution(gate_logits)
+    p = jnp.exp(log_p)
+    entropy = -jnp.sum(p * log_p, axis=0)
+    expected_policy, expected_value = jnp.mean(jnp.sum(p * policy, axis=0)), jnp.mean(jnp.sum(p * value, axis=0))
+    each = jnp.mean(policy + value_weight * value, axis=1)  # [T]: a pass's loss, were every board served from it
+    steps = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)[:, None]
+    return {"loss": expected_policy + value_weight * expected_value - entropy_weight * jnp.mean(entropy),
+            "policy_loss": expected_policy, "value_loss": expected_value,
+            "exit_step_mean": jnp.mean(jnp.sum(steps * p, axis=0)), "exit_entropy": jnp.mean(entropy),
+            "loss_first_pass": each[0], "loss_last_pass": each[-1]}
+
+
 def _constrain_params(params, mesh: Optional[Mesh]):
     specs = {k: az_param_spec(k, v) for k, v in params.items()}
     return _constrain(params, specs, mesh)
@@ -143,11 +171,13 @@ class AzTrainer:
         value_weight: float = 1.0,
         optimizer: Optional[optax.GradientTransformation] = None,
         denoise_weight: float = 1.0,
+        exit_entropy_weight: float = 0.1,
     ) -> None:
         self.cfg = cfg
         self.mesh = mesh
         self.value_weight = value_weight
         self.denoise_weight = denoise_weight  # of a block-diffusion trunk's third term; no other net has it
+        self.exit_entropy_weight = exit_entropy_weight  # of a looped trunk's entropy term; no other net has it
         self.optimizer = optimizer or optax.adamw(learning_rate, weight_decay=1e-4)
         compile_cache.configure()  # before the first jit
         self._hold_on(jax.devices()[0] if mesh is None else mesh.devices.flat[0])
@@ -164,8 +194,10 @@ class AzTrainer:
         self._held = held_layouts(state.params, self.mesh, device)
         followed = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0] if getattr(path[-1], "key", None) in self._held]
         self._held_fields = {"layout_held_leaves": len(followed), "layout_held_bytes": sum(leaf.size * leaf.dtype.itemsize for leaf in followed)}
-        # what a trunk's plan says of its attention cores, static as the layouts are: the share of their query heads that go two a product
-        self._plan_fields = {"attention_heads_paired": attention_heads_paired(self.cfg)} if isinstance(self.cfg, TrunkConfig) else {}
+        # what a trunk's plan says, static as the layouts are: the share of its attention cores' query heads that go two a product, the times
+        # the plan is walked a forward pass and the layer passes that makes
+        self._plan_fields = {"attention_heads_paired": attention_heads_paired(self.cfg), "loop_steps": self.cfg.loop_steps,
+                             "layer_passes": self.cfg.loop_steps * self.cfg.layers} if isinstance(self.cfg, TrunkConfig) else {}
 
     # -- jitted bodies ----------------------------------------------------
 
@@ -180,8 +212,16 @@ class AzTrainer:
         # share (doc/observability.md "Training and compilation").
         denoising = bool(getattr(self.cfg, "block_length", 0))  # the batch then carries its noise, and a batch without it is an error
         with jax.named_scope("forward"):
-            logits, value, counters, *denoiser = az_forward_counted({**params, **buffers}, batch["planes"], self.cfg,
+            # ``after``: what follows the counters: a block-diffusion trunk's denoiser's logits, a looped trunk's exit gates' logits
+            logits, value, counters, *after = az_forward_counted({**params, **buffers}, batch["planes"], self.cfg,
                                                                     batch["square_masked"] if denoising else None)
+        if getattr(self.cfg, "loop_steps", 1) > 1:  # heads [T, B, ..]: the same two terms a pass and a board, then their expectation over the exits
+            with jax.named_scope("loss"):
+                policy = -jnp.sum(batch["policy_target"] * jax.nn.log_softmax(logits, axis=-1), axis=-1)
+                squared = (value - batch["value_target"]) ** 2
+                with jax.named_scope("exit"):
+                    terms = _expected_exit_terms(policy, squared, after[0], self.value_weight, self.exit_entropy_weight)
+            return terms["loss"], {**terms, **counters}
         with jax.named_scope("loss"):
             target = batch["policy_target"]
             # Masked cross-entropy: zero-probability targets (illegal moves)
@@ -193,7 +233,7 @@ class AzTrainer:
             denoised = {}
             if denoising:
                 with jax.named_scope("denoise"):
-                    denoised = _denoise_terms(denoiser[0], batch, self.cfg.block_length)
+                    denoised = _denoise_terms(after[0], batch, self.cfg.block_length)
                     loss = loss + self.denoise_weight * denoised["denoise_loss"]
         return loss, {
             "loss": loss,

@@ -90,7 +90,8 @@ def init_span(trainer: str, **fields: float) -> Iterator[None]:
     ``fields`` what the trainer knows of the state it makes (``AzTrainer``: the leaves
     the client holds off row-major, and their bytes; of a trunk also
     ``attention_heads_paired``, the share of its attention layers' query heads whose
-    scores the kernel pair makes two a product)."""
+    scores the kernel pair makes two a product, ``loop_steps``, the times its plan is
+    walked a forward pass, and ``layer_passes``, that times its layers)."""
     with _bringing_up("train_init") as span:
         yield
     RECORDER.record("train_init", trainer=trainer, **fields, **span)

@@ -65,6 +65,8 @@ MODEL_SCOPES = {
     # ``forward``, the third term under ``loss``
     "sdar": ("embed", "layer00.attention", "layer00.router", "layer00.dispatch", "layer00.experts", "layer00.combine",
              "layer01.attention", "layer01.router", "layer01.dispatch", "layer01.experts", "layer01.combine", "final_norm", "denoise", "policy_head", "value_head"),
+    # the tenth's: no routed layer's scopes at all; a layer's names cover its passes (the pass is no level of a name) and ``exit_gate`` stands beside the heads
+    "ouro": ("embed", "layer00.attention", "layer00.dense", "layer01.attention", "layer01.dense", "final_norm", "exit_gate", "policy_head", "value_head"),
 }
 TRUNK = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, experts=4, experts_per_token=2, expert_width=16, value_hidden=8)
 # a share of the experts held and balanced (the second block's routing), and the same with latent attention (the third's)
@@ -92,7 +94,10 @@ MELLUM = TrunkConfig(hidden=32, heads=4, kv_heads=1, head_dim=16, layers=2, expe
 # the ninth block: the eighth's layer under one plain table, trained by block diffusion (a batch carries its noise)
 SDAR = TrunkConfig(hidden=32, heads=4, kv_heads=1, head_dim=16, layers=2, experts=8, experts_per_token=2, expert_width=16, value_hidden=8, route_norm=True,
                    held_experts=(2, 4), balance_rate=0.001, rope_theta=1e6, rms_eps=1e-6, block_length=4)
-TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN, "mellum": MELLUM, "sdar": SDAR}
+# the tenth block: two layers of attention (a group of one, no qk-norm) and a dense feed-forward between sandwich norms, walked three times, an exit a pass
+OURO = TrunkConfig(hidden=32, heads=2, head_dim=16, layers=2, rope_theta=1e6, rms_eps=1e-6, value_hidden=8, qk_norm=False, post_norms=True, dense_layers=2,
+                   dense_width=48, loop_steps=3)
+TRUNKS = {"trunk": TRUNK, "share": SHARE, "latent": LATENT, "pattern": PATTERN, "cca": CCA, "kda": KDA, "gdn": GDN, "mellum": MELLUM, "sdar": SDAR, "ouro": OURO}
 
 
 def make(kind):
@@ -167,16 +172,16 @@ def test_step_text_holds_the_scope_contract(scoped):
     assert {"forward", "backward", "optimizer"} <= phases
 
 
-@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn", "mellum", "sdar"])
+@pytest.mark.parametrize("kind", ["share", "latent", "cca", "kda", "gdn", "mellum", "sdar", "ouro"])
 def test_a_blocks_own_scopes_are_exactly_the_parents(kind):
     """The scopes of the trunk's own parts in the configurations the
     fixture above does not compile, as PR 46's PARENT (3036d35) named them
-    (the sixth block's as PR 47 brought them, the seventh's as PR 51, the eighth's as PR 56, the ninth's as PR 59), no more and no fewer: the
+    (the sixth block's as PR 47 brought them, the seventh's as PR 51, the eighth's as PR 56, the ninth's as PR 59, the tenth's as PR 64), no more and no fewer: the
     benchmark's reducers read these names, and a lowered step's text (the
     step pins) carries none of them."""
     names = set(re.findall(r'op_name="([^"]*)"', step_text(kind)))
     held = {scope for name in names for part in scopes._parts(name) for scope in [scopes._unwrap(part)[1]]}
-    own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$|denoise$", scope)}
+    own = {scope for scope in held if re.match(r"layer\d\d\.|embed$|final_norm$|denoise$|exit_gate$", scope)}
     assert own == set(MODEL_SCOPES[kind]) - {"policy_head", "value_head"} and {"policy_head", "value_head"} <= held
 
 
@@ -639,6 +644,8 @@ STEP_KEYS = {
     "kda": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "latent_rms", "kda_state_kept", "kda_beta"},
     # the ninth block's third term and the two counters of its batch's noise
     "sdar": LOSSES["az"] | ROUTING | {"held_slots", "expert_bias_abs_max", "denoise_loss", "masked_squares", "noise_level_mean"},
+    # the tenth block's: no routing counter (no routed layer), the exits' four and the loop's own
+    "ouro": LOSSES["az"] | {"exit_step_mean", "exit_entropy", "loss_first_pass", "loss_last_pass", "loop_update_rms"},
 }
 
 
@@ -751,7 +758,7 @@ def test_a_collected_trainer_takes_its_ring(steps):
     assert {dict(key)["trainer"] for key in series(registry, "fishnet_train_step")} == {"nnue-1"}
 
 
-@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern", "kda", "sdar"])
+@pytest.mark.parametrize("kind", ["nnue", "az", "trunk", "share", "latent", "pattern", "kda", "sdar", "ouro"])
 def test_collector_serves_each_scalar_of_the_latest_step(kind, steps):
     """``fishnet_train_step{trainer,key}`` for exactly the keys the kind's
     step returns, and ``fishnet_train_steps_total{trainer}``: every counter

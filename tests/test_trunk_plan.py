@@ -48,6 +48,10 @@ KEYS_AND_INIT_SHA256 = {
     # the ninth block, read on the tree of the PR that brought it (PR 59): the eighth's tensors, the mask embedding after the embedding and the denoiser after
     # the value head (no tensor of a layer is its own: the block mask and the two streams are no parameter)
     "sdar": (f"embed_w embed_b mask_embed {_FIRST} {_HEADS} denoise_w denoise_b", "ee1d1dbcebdc3c0407685b5c7dd0bfda5aa25b89e3a314608d7076a7283b8979"),
+    # the tenth block, read on the tree of the PR that brought it (PR 64): the first kind's tensors without the qk-norm's gains, NO tensor of a routed layer, the
+    # exit gate after the value head, and what the second block brought (the two post-norms, the dense feed-forward) after the heads as it always comes
+    "ouro": (f"embed_w embed_b attn_norm wq wk wv wo moe_norm {_HEADS} exit_gate_w exit_gate_b post_attn_norm post_mlp_norm dense_gate dense_up dense_down",
+             "def521065847d86957b71567ba2dd4ff7db87b1db5b7c9a867add0ec471124d2"),
 }
 
 
@@ -64,7 +68,8 @@ def test_a_blocks_tensors_come_in_the_parents_order_and_start_where_the_parents_
     assert sha.hexdigest() == digest
     # the table and the shapes agree: a kind's tensors are its row's, and nothing but norms, the embedding and the heads is no kind's
     owned = {name for names in trunk._OWNS.values() for name in names}
-    plain = {"embed_w", "embed_b", *_HEADS.split(), *(("mask_embed", "denoise_w", "denoise_b") if cfg.block_length else ())} | {norm for s in trunk.trunk_plan(cfg) for norm in (s.norm, s.post_norm) if norm}
+    plain = {"embed_w", "embed_b", *_HEADS.split(), *(("mask_embed", "denoise_w", "denoise_b") if cfg.block_length else ()),
+             *(("exit_gate_w", "exit_gate_b") if cfg.loop_steps > 1 else ())} | {norm for s in trunk.trunk_plan(cfg) for norm in (s.norm, s.post_norm) if norm}
     assert set(keys.split()) <= owned | plain and not owned & plain
 
 
@@ -108,6 +113,7 @@ PLANS = {
         Sublayer("layer02", "attention", 2, "attn_norm", 2, True), Sublayer("layer02", "routed", 2, "moe_norm", 2),
         Sublayer("layer03", "attention", 3, "attn_norm", 3, True, rope_type="yarn"), Sublayer("layer03", "routed", 3, "moe_norm", 3)),
     "sdar": _block_plan("attention", 2),  # what is served: the first block's plan, one copy of a board (``streams`` 1); the training forward's is below
+    "ouro": _block_plan("attention", 2, dense=2, post=True),  # ONE pass of the loop: every feed-forward dense, a post-norm a sublayer; ``loop_steps`` is no part of the plan
 }
 
 
@@ -166,7 +172,7 @@ def test_a_mixed_plan_is_the_one_mixers_plan_where_every_layer_names_the_same():
 
 #: by the shapes alone: the first block a group of 1, the third's layers and the sixth's one attention layer latent; the ninth's block-masked
 #: layers pair by their group as the plain ones do (since PR 63: the tiny plan 4 over 1)
-PAIRED = {"llada": 0.0, "afmoe": 1.0, "mla": 0.0, "hybrid": 1.0, "cca": 1.0, "kda": 0.0, "gdn": 1.0, "mellum": 1.0, "sdar": 1.0}
+PAIRED = {"llada": 0.0, "afmoe": 1.0, "mla": 0.0, "hybrid": 1.0, "cca": 1.0, "kda": 0.0, "gdn": 1.0, "mellum": 1.0, "sdar": 1.0, "ouro": 0.0}
 
 
 @pytest.mark.parametrize("block", PAIRED)

@@ -531,7 +531,7 @@ def _held_as_the_trainer_holds_it(trainer, one_chip):
 HELD = {"az": ("az-256x19-train", (), 0), "moe_trunk": ("lladamoe-trunk-train", (), 0), "afmoe_trunk": ("trinity-mini-trunk-train", (), 0),
         "mla_trunk": ("kanana-2-trunk-train", (), 0), "hybrid_trunk": ("nemotron-twotower-trunk-train", ("experts_up",), 3 * 4 * 3 * 8 * 2688 * 1856),
         "cca_trunk": ("zaya1-trunk-train", (), 0), "kda_trunk": ("kimi-linear-trunk-train", (), 0), "gdn_trunk": ("qwen3-next-trunk-train", (), 0),
-        "mellum_trunk": ("mellum2-trunk-train", (), 0)}
+        "mellum_trunk": ("mellum2-trunk-train", (), 0), "ouro_trunk": ("ouro-2.6b-trunk-train", (), 0)}
 
 
 @pytest.mark.parametrize("family", HELD)
@@ -1331,5 +1331,55 @@ def test_the_ninth_blocks_step_compiles_at_published_widths_and_fits_one_chip(on
     assert not _xla_passes_over_slots(text, SDAR_BOARDS * 2 * trunk.SQUARES * cfg.experts_per_token)
     memory = compiled.memory_analysis()
     print("sdar step", memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert 4.0 < (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 12.5, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
+    assert ".remat" not in text
+
+
+# -- the tenth block (ouro) at its published widths: six layers walked four times over the same weights, an exit a pass ---------------------------
+
+OURO_BOARDS = 32  # ouro_trunk_train_b32
+
+
+def test_the_tenth_blocks_step_compiles_at_published_widths_and_fits_one_chip(one_chip, compiled_for_tpu):
+    """The whole step of ``ouro_trunk_train_b32`` from its configuration file: 32 boards, 2,048 tokens a pass, the six layers walked
+    ``total_ut_steps`` = 4 times over the SAME weights (24 calls of the plain attention pair each way, at a group of ONE without a norm: the
+    one-head program; 24 of the gate kernel pair between the joined products: a gate weight of 44 MiB is over ``_FUSED_GATE_BYTES``), no
+    routed layer (no router, dispatch, experts or combine scope, no grouped product), every layer's scopes under the same names whatever
+    the pass, the exits' four scopes and the loss's, no leaf held off row-major and no state argument relaid, nothing remade to fit,
+    arguments and temporaries under the chip's 15.75 GiB (3.45 + 5.95 GiB when this was written: what PERF.md quotes)."""
+    import importlib
+    import json
+    import re
+    from pathlib import Path
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "ouro-2.6b-trunk-train.json").read_text())
+    assert config["train"]["batch"] == OURO_BOARDS
+    trainer = importlib.import_module("benchmark.families.ouro_trunk").make_trainer(config)
+    cfg = trainer.cfg
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.dense_width, cfg.layers, cfg.dense_layers, cfg.loop_steps, cfg.exit_threshold) == (
+        2048, 16, None, 128, 5632, 6, 6, 4, 1.0) and not cfg.qk_norm and cfg.post_norms and cfg.routed_layers == 0
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(trainer._init, jax.random.PRNGKey(0))
+    assert sum(v.size for v in state.params.values()) == config["published"]["parameters_here"] == 308_599_375  # the configuration file's reckoning
+    batch = {"planes": jnp.zeros((OURO_BOARDS, 8, 8, 19)), "policy_target": jnp.zeros((OURO_BOARDS, 4672)), "value_target": jnp.zeros((OURO_BOARDS,))}
+    compiled = _held_as_the_trainer_holds_it(trainer, one_chip)._step_jit.lower(on_chip(state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    assert not trainer._held and not _copies_of_state_arguments(text)
+    passes = cfg.loop_steps * cfg.layers
+    calls = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    core = [name for name in calls if "board_attention" in name]
+    assert len(core) == 2 * passes and sum("board_attention_grad" in name for name in core) == passes and not any("blocks" in name for name in core)
+    assert sum("expert_gate_grad" in name for name in calls) == passes and sum("expert_gate" in name for name in calls) == 2 * passes
+    assert len(calls) == 4 * passes  # and no other kernel: no grouped product, no row move
+    for line in text.splitlines():  # the one-head program: q, k, v alike, 16 heads of 128 over 32 boards
+        if 'custom_call_target="tpu_custom_call"' in line and "board_attention" in line.split(" = ")[0]:
+            assert re.findall(r"(?:f32|bf16)\[[\d,]*\]", line.split("custom-call(")[1])[:3] == ["f32[32,64,2048]", "f32[32,64,2048]", "bf16[32,64,2048]"]
+    for phase in ("jvp(forward)", "transpose(jvp(forward))"):
+        assert all(f"{phase}/layer0{i}.{part}/" in text for i in range(6) for part in ("attention", "dense"))
+        assert all(f"{phase}/{scope}/" in text for scope in ("embed", "final_norm", "policy_head", "value_head", "exit_gate"))
+    assert "jvp(loss)/exit/" in text and "transpose(jvp(loss))/exit/" in text
+    assert not re.search(r"layer\d\d\.(router|dispatch|experts|combine|shared)/", text) and "/while/" not in text  # no routed layer; the passes are no loop of XLA's
+    memory = compiled.memory_analysis()
+    print("ouro step", memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert 4.0 < (memory.argument_size_in_bytes + memory.temp_size_in_bytes) / 2 ** 30 < 12.5, (memory.argument_size_in_bytes / 2 ** 30, memory.temp_size_in_bytes / 2 ** 30)
     assert ".remat" not in text

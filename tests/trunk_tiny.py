@@ -215,3 +215,16 @@ def noised_batch(seed: int, n: int = BATCH, block_length: int = 4, t_min: float 
 
 
 BLOCKS["sdar"] = (SDAR, noised_batch)
+
+
+# -- the tenth block (ouro): two layers walked ``total_ut_steps`` = 3 times over the same weights, an exit (final norm, the two heads, a gate a board)
+# -- after every pass; sandwich norms, attention at a group of one without qk-norm, EVERY feed-forward dense: the first trunk without a routed layer;
+# -- ``OURO_MODEL`` is the same net as the benchmark's reference reads it (benchmark/reference/ouro_trunk.py)
+
+OURO_MODEL = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16, "num_hidden_layers": 2, "intermediate_size": 96,
+              "rope_theta": 1000000, "rms_norm_eps": 1e-06, "total_ut_steps": 3, "early_exit_threshold": 1, "input_planes": 19, "value_hidden": 32,
+              "policy_planes": 73}
+OURO_CONFIG = {"model": OURO_MODEL, "train": {"value_weight": 1.0, "exit_entropy_weight": 0.1}}
+OURO = TrunkConfig(hidden=64, heads=4, head_dim=16, layers=2, rope_theta=1e6, rms_eps=1e-6, value_hidden=32, qk_norm=False, post_norms=True,
+                   dense_layers=2, dense_width=96, loop_steps=3)
+BLOCKS["ouro"] = (OURO, batch_of)

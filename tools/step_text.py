@@ -1,6 +1,6 @@
 """The tiny lowered step program that a step pin hashes, as text.
 
-    python3 tools/step_text.py --block llada|afmoe|mla|hybrid|cca|kda|gdn|mellum|sdar [--no-ids] > text
+    python3 tools/step_text.py --block llada|afmoe|mla|hybrid|cca|kda|gdn|mellum|sdar|ouro [--no-ids] > text
 
 ``tests/test_hybrid_trunk.py PARENT_STEP_SHA256`` and
 ``tests/test_cca_trunk.py CCA_STEP_SHA256`` hold the sha256 of what this
@@ -60,7 +60,7 @@ def lowered_step_text(cfg, batch, ids: bool = True) -> str:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("--block", required=True, choices=("llada", "afmoe", "mla", "hybrid", "cca", "kda", "gdn", "mellum", "sdar"))
+    parser.add_argument("--block", required=True, choices=("llada", "afmoe", "mla", "hybrid", "cca", "kda", "gdn", "mellum", "sdar", "ouro"))
     parser.add_argument("--no-ids", action="store_true", help="replace the SSA numbers and the counters on function names: a diff then shows the operations that moved")
     args = parser.parse_args(argv)
     sys.path[:0] = [str(REPO), str(REPO / "tests")]
